@@ -143,33 +143,39 @@ def _attention_dispatch(cfg: GPTConfig, mesh=None):
         if mesh is None:
             return flash_attention.causal_attention
 
-        # The Pallas kernel is a single program whose packed-lane cells
-        # (128 lanes = up to 128/hd sub-heads, ops/flash_attention._btd_pack)
-        # must never be SPLIT by the partitioner: GSPMD sharding q's head
-        # axis over tp can land a shard boundary inside one cell, and the
-        # interpret-mode lowering of the kernel then computes garbage
-        # (observed: head_dim=16 → pack=8 one-cell geometry, fwd AND grads
-        # wrong under tp=2 — the llama hd16/GQA divergence; head_dim=64 →
-        # pack=2 only survived because tp=2 happened to split on a cell
-        # boundary). Batch-dim sharding is the one partitioning the kernel
-        # is safe under, so pin q/k/v/out to batch-only: a no-op for the
-        # dp/fsdp training path, an explicit head all-gather for the
-        # non-tp-manual tp>1 corner (correct first; the aligned-head tp
-        # cases run the manual-tp path and never see this wrapper).
-        from jax.sharding import NamedSharding
+        # A compiled Pallas kernel is a Mosaic custom call, and a custom
+        # call has no partitioning rule: left to GSPMD, q/k/v are gathered
+        # and every chip runs the kernel over the whole global batch —
+        # right answers, n_devices times the attention work. shard_map
+        # makes the split explicit instead: each device runs the kernel on
+        # its own batch rows (BATCH_AXES), and on its own heads when tp
+        # divides them. Attention is independent per row and per head, so
+        # the region holds no collective, and each shard is a whole
+        # program whose packed-lane cells (ops/flash_attention._btd_pack)
+        # the partitioner can never split. Heads tp does not divide stay
+        # whole on every tp rank (gathered on entry: correct, redundant).
         from jax.sharding import PartitionSpec as PSpec
 
-        from mingpt_distributed_tpu.parallel.mesh import BATCH_AXES
+        tp = mesh.shape.get("tp", 1)
+        heads_split = tp > 1 and cfg.n_head % tp == 0 and cfg.kv_heads % tp == 0
+        spec = PSpec(BATCH_AXES, None, "tp" if heads_split else None)
 
-        batch_only = NamedSharding(mesh, PSpec(BATCH_AXES))
+        def flash_sharded(q, k, v, *, attn_pdrop=0.0, dropout_key=None,
+                          deterministic=True, **kw):
+            if not deterministic and attn_pdrop > 0.0:
+                # no kernel under attention dropout: the op routes this
+                # call to the einsum oracle, which is plain HLO — GSPMD
+                # partitions it and draws the masks per global row
+                return flash_attention.causal_attention(
+                    q, k, v, attn_pdrop=attn_pdrop, dropout_key=dropout_key,
+                    deterministic=False, **kw)
+            return jax.shard_map(
+                lambda q, k, v: flash_attention.causal_attention(q, k, v, **kw),
+                mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                check_vma=False,
+            )(q, k, v)
 
-        def flash_batch_partitioned(q, k, v, **kw):
-            cst = lambda a: jax.lax.with_sharding_constraint(a, batch_only)
-            out = flash_attention.causal_attention(
-                cst(q), cst(k), cst(v), **kw)
-            return jax.lax.with_sharding_constraint(out, batch_only)
-
-        return flash_batch_partitioned
+        return flash_sharded
     if cfg.attention == "ring":
         from mingpt_distributed_tpu.parallel import ring_attention
 

@@ -11,7 +11,12 @@ transport (ICI within a slice, DCN across slices — SURVEY §2.3).
 
 On single-host (or under test) this is a no-op, so the same train.py runs
 unchanged from a laptop CPU to a pod slice — the debuggability the reference
-lacked by hard-coding NCCL (SURVEY §5.8).
+lacked by hard-coding NCCL (SURVEY §5.8). "Single-host" is decided by the
+absence of a coordinator address and by nothing else: a one-host TPU VM
+exports ``TPU_WORKER_HOSTNAMES=localhost`` and ``TPU_WORKER_ID=0`` like a
+pod worker does, and ``jax.distributed.initialize()`` with no arguments
+would go looking for a cluster from them, on a machine that may have no
+network.
 """
 
 from __future__ import annotations
@@ -32,9 +37,8 @@ def initialize(
     """Join the multi-host job if one is configured; otherwise no-op.
 
     Resolution order: explicit args > env (COORDINATOR_ADDRESS / NUM_PROCESSES
-    / PROCESS_ID — set by launch/tpu_pod_run.sh) > TPU metadata autodetection
-    (jax.distributed.initialize() with no args on Cloud TPU). Single-process
-    when nothing is configured.
+    / PROCESS_ID — set by launch/tpu_pod_run.sh). Single-process, and no
+    network touched, when no coordinator address is given.
     """
     global _initialized
     if _initialized:
@@ -50,11 +54,6 @@ def initialize(
             process_id=process_id,
         )
         _initialized = True
-    elif os.environ.get("TPU_WORKER_HOSTNAMES") and _int_env("TPU_WORKER_ID") is not None:
-        # Cloud TPU pod: jax autodetects topology from the metadata server.
-        jax.distributed.initialize()
-        _initialized = True
-    # else: single-process run; nothing to do.
 
 
 def shutdown() -> None:

@@ -33,7 +33,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mingpt_distributed_tpu.config import MeshConfig
 from mingpt_distributed_tpu.utils.pytree import leaf_name
-from mingpt_distributed_tpu.utils import compat
 
 # pp outermost: pipeline stages exchange activations point-to-point once per
 # microbatch tick — the least bandwidth-hungry axis, so it can cross DCN;
@@ -122,7 +121,7 @@ def dropped_attention_shard_map(shard, mesh: Mesh, spec: P, pdrop: float,
             key = jax.random.fold_in(key, jax.lax.axis_index(head_axis))
         return shard(q, k, v, pdrop=pdrop, key=key)
 
-    return compat.shard_map(
+    return jax.shard_map(
         dropped, mesh=mesh, in_specs=(spec, spec, spec, P()),
         out_specs=spec, check_vma=False,
     )

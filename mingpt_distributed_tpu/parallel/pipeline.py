@@ -74,7 +74,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mingpt_distributed_tpu.parallel.mesh import BATCH_AXES
-from mingpt_distributed_tpu.utils import compat
 
 
 def _split_diff(tree):
@@ -322,7 +321,7 @@ def pipeline_blocks(
 
     seq_ax = "sp" if seq_sharded else None
     x_spec = P(BATCH_AXES, seq_ax, *([None] * (x.ndim - 2)))
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(x_spec, xs_specs if xs_specs is not None else P("pp"), P()),

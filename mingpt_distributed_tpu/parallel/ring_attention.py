@@ -40,7 +40,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.parallel.mesh import BATCH_AXES
-from mingpt_distributed_tpu.utils import compat
 
 NEG_INF = -1e30
 
@@ -496,7 +495,7 @@ def ring_causal_attention(
             head_axis=head_ax if mesh.shape.get("tp", 1) > 1 else None,
         )
         return fn(q, k, v, dropout_key)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -30,7 +30,6 @@ from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.ops import flash_attention as flash
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.parallel.mesh import BATCH_AXES
-from mingpt_distributed_tpu.utils import compat
 
 
 def _ulysses_shard(q, k, v, *, axis_name: str, window=None, softcap=None,
@@ -121,7 +120,7 @@ def ulysses_causal_attention(
             shard, mesh, spec, attn_pdrop, head_axis=None,
         )
         return fn(q, k, v, dropout_key)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         shard,
         mesh=mesh,
         in_specs=(spec, spec, spec),

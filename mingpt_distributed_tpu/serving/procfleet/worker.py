@@ -627,6 +627,9 @@ def main(argv: Optional[List[str]] = None) -> int:
         return 2
     with open(args[0]) as f:
         spec = json.load(f)
+    from mingpt_distributed_tpu.utils import startup
+
+    startup.enable_compile_cache()
     worker = build_worker_from_spec(spec)
     httpd = RpcHttpServer(worker, port=int(spec.get("port", 0)))
     if worker.flight is not None:

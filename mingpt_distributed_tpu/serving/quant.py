@@ -1,7 +1,7 @@
 """Quantized KV-cache / weight-leaf storage (ISSUE 18 tentpole).
 
 Symmetric per-channel quantization for the serving stack: KV rows are
-stored as int8 (or fp8 where the backend dtype exists) with an fp32
+stored as int8 (or fp8, ``float8_e4m3fn``) with an fp32
 *scale plane* living beside the data, and dequantized inside the traced
 attention block. One design decision carries the whole PR:
 
@@ -51,7 +51,6 @@ __all__ = [
     "data_names",
     "dequantize",
     "dequantize_lane",
-    "fp8_dtype",
     "init_quant_cache",
     "max_abs_logit_error",
     "quantize",
@@ -89,12 +88,6 @@ class KVQuant:
         return self.name
 
 
-def fp8_dtype():
-    """The backend's e4m3 dtype, or None when this jax build lacks one
-    (the gate that keeps fp8 optional without new dependencies)."""
-    return getattr(jnp, "float8_e4m3fn", None)
-
-
 def resolve_kv_dtype(name: Optional[str]) -> Optional[KVQuant]:
     """None (store fp32, the byte-identical default path) or a KVQuant."""
     if name is None or name in ("fp32", "float32"):
@@ -104,11 +97,7 @@ def resolve_kv_dtype(name: Optional[str]) -> Optional[KVQuant]:
     if name == "int8":
         return KVQuant("int8", jnp.dtype(jnp.int8), 127.0)
     if name == "fp8":
-        dt = fp8_dtype()
-        if dt is None:
-            raise ValueError(
-                "kv_dtype='fp8' needs a jax with jnp.float8_e4m3fn; this "
-                "build lacks it — use 'int8' or 'fp32'")
+        dt = jnp.float8_e4m3fn
         return KVQuant("fp8", jnp.dtype(dt), float(jnp.finfo(dt).max))
     raise ValueError(f"unknown kv_dtype {name!r} (choose from {KV_DTYPES})")
 

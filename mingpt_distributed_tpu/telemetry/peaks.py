@@ -68,14 +68,25 @@ PEAK_HBM_CAPACITY: Dict[str, float] = {
 
 
 def _chip_lookup(table: Dict[str, float]) -> Optional[float]:
-    # longest-prefix-wins by dict order (see the ordering note above)
+    """This process's chip in ``table`` — longest-prefix-wins by dict
+    order (see the ordering note above). A non-TPU backend has no peak
+    (None: callers drop the roofline figures). A TPU whose ``device_kind``
+    is missing from the table is an error, not a default: a utilization
+    quietly dropped, or computed against another chip's peak, reads as a
+    measurement."""
     import jax  # lazy: the telemetry package must import without a backend
 
-    kind = jax.devices()[0].device_kind
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
     for name, val in table.items():
-        if kind.startswith(name):
+        if device.device_kind.startswith(name):
             return val
-    return None
+    raise LookupError(
+        f"TPU device_kind {device.device_kind!r} is not in the peak tables "
+        f"of telemetry/peaks.py (known: {sorted(table)}); add its published "
+        "peak there before asking for a utilization on it"
+    )
 
 
 def peak_flops_per_chip() -> Optional[float]:

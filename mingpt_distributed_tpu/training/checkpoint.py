@@ -126,12 +126,16 @@ def save_snapshot(
     so write amplification tracks per-host state. The *contents* are
     layout-independent (each leaf contiguously chunked), so any shard
     count restores against any other; the shard count is a property of
-    the write, not of the checkpoint.
+    the write, not of the checkpoint. A state too large for one data
+    object (``durability.max_object_bytes``) is split further, whatever
+    ``shards`` says.
     """
     state = {
         "params": _to_host(snap.params),
         "opt_state": _to_host(snap.opt_state),
     }
+    nbytes = sum(x.nbytes for x in jax.tree.leaves(state))
+    shards = max(shards, -(-nbytes // durability.max_object_bytes()))
     if shards <= 1:
         payload = {
             "version": SNAPSHOT_VERSION,

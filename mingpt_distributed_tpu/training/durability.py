@@ -41,6 +41,7 @@ import hashlib
 import json
 import os
 import random
+import resource
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, List, Optional, Tuple
@@ -72,6 +73,9 @@ _PERMANENT_OSERRORS = (
     IsADirectoryError,
     NotADirectoryError,
 )
+# EFBIG: the object is larger than the machine lets one file be; the same
+# bytes fail the same way on every attempt.
+_PERMANENT_ERRNOS = {errno.EFBIG}
 
 
 def classify_io_error(exc: BaseException) -> str:
@@ -89,6 +93,8 @@ def classify_io_error(exc: BaseException) -> str:
     if isinstance(exc, OSError):
         if exc.errno in _MISSING_ERRNOS:
             return MISSING
+        if exc.errno in _PERMANENT_ERRNOS:
+            return PERMANENT
         # covers TimeoutError, ConnectionError, BlockingIOError, and the
         # anonymous OSErrors object-store backends raise on flaky transport
         return TRANSIENT
@@ -179,6 +185,23 @@ def sha256_hex(blob: bytes) -> str:
 
 
 # -- byte transport (retry-wrapped, atomic where the backend allows) --------
+
+#: the size one data object of a checkpoint is kept to. A state larger than
+#: this is committed as several shard objects (schema v2) instead of one
+#: blob of any size: GPT-2 124M with its Adam moments is 1.96 GB, and a
+#: machine that limits file size refuses that as one file (EFBIG).
+MAX_OBJECT_BYTES = 64 * 2**20
+
+
+def max_object_bytes() -> int:
+    """Largest data object a save should write: ``MAX_OBJECT_BYTES``, or
+    half of this process's file-size limit (``RLIMIT_FSIZE``) where the
+    machine sets a smaller one — half, because an object carries msgpack
+    framing on top of its arrays."""
+    soft, _ = resource.getrlimit(resource.RLIMIT_FSIZE)
+    if soft == resource.RLIM_INFINITY:
+        return MAX_OBJECT_BYTES
+    return max(1, min(MAX_OBJECT_BYTES, soft // 2))
 
 
 def _is_local(path: str) -> bool:

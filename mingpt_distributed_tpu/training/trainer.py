@@ -101,7 +101,22 @@ def make_train_step(
     their canonical sharding by the output constraint. Composes with
     ``grad_accum`` unchanged — accumulation happens before the sharded
     update phase.
+
+    ``attention="flash"`` is a promise that the step runs the Pallas
+    kernel. The op itself routes calls it cannot tile, and calls under
+    attention dropout, to the einsum oracle (its contract for decode and
+    odd shapes) — so a training config that can only ever get the oracle
+    is refused here, by name, instead of training slowly under the wrong
+    label: attention dropout at build time, an untileable T at trace time.
     """
+    if cfg.attention == "flash" and cfg.attn_pdrop > 0.0:
+        raise ConfigError(
+            f"gpt_config.attention=flash with attn_pdrop={cfg.attn_pdrop}: "
+            "the Pallas kernel has no attention dropout, so every training "
+            "step would run the einsum oracle instead. Set "
+            "gpt_config.attn_pdrop=0.0 (embd_pdrop and resid_pdrop do not "
+            "gate the kernel) or gpt_config.attention=einsum."
+        )
 
     def loss_and_grads(params, x, y, rng, deterministic):
         def loss_fn(p):
@@ -118,6 +133,18 @@ def make_train_step(
 
     def train_step(state: TrainState, batch, base_rng):
         x, y = batch
+        if cfg.attention == "flash":
+            from mingpt_distributed_tpu.ops import flash_attention
+
+            if flash_attention.supported_block(x.shape[1]) is None:
+                raise ConfigError(
+                    f"gpt_config.attention=flash with sequence length "
+                    f"T={x.shape[1]}: the Pallas kernel needs T to be a "
+                    "multiple of 128, or at most 128 and a multiple of 8, "
+                    "so every training step would run the einsum oracle "
+                    "instead. Pick such a data_config.block_size or "
+                    "gpt_config.attention=einsum."
+                )
         rng = jax.random.fold_in(base_rng, state["step"])
         deterministic = (
             cfg.embd_pdrop == 0.0 and cfg.resid_pdrop == 0.0 and cfg.attn_pdrop == 0.0

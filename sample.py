@@ -43,7 +43,9 @@ def main(argv=None) -> int:
     from mingpt_distributed_tpu.data.token_dataset import make_dataset
     from mingpt_distributed_tpu.models import generate as gen
     from mingpt_distributed_tpu.training import checkpoint as ckpt_lib
+    from mingpt_distributed_tpu.utils import startup
 
+    startup.enable_compile_cache()
     cfg = load_config(args.config, args.overrides)
     # same tokenizer dispatch as train.py: the snapshot being sampled was
     # trained on this config's vocabulary

@@ -146,9 +146,8 @@ def build_argparser() -> argparse.ArgumentParser:
                    help="KV-cache storage dtype (ISSUE 18): int8 stores "
                         "quantized K/V payloads + fp32 scale planes "
                         "(~0.27x the pool bytes at head_dim>=64 — ~4x "
-                        "the decode lanes per chip); fp8 needs a jax "
-                        "with float8_e4m3fn; fp32 is the byte-identical "
-                        "default path")
+                        "the decode lanes per chip); fp32 is the "
+                        "byte-identical default path")
     p.add_argument("--selftest-quant", action="store_true",
                    help="ISSUE 18 gate: int8 KV pool with chunked "
                         "prefill + prefix store + speculation composed "
@@ -855,8 +854,7 @@ def selftest_quant(args) -> int:
     executables); zero post-warmup recompiles on both servers;
     HBMLedger kv_pool+kv_scales <= 0.27x the fp32 kv_pool bytes; the
     ``mingpt_serve_kv_dtype`` build-info gauge and a sampled
-    ``mingpt_serve_quant_logit_err_max``; and the fp8 gate (resolves on
-    a backend with float8_e4m3fn, refuses loudly otherwise)."""
+    ``mingpt_serve_quant_logit_err_max``; and that ``fp8`` resolves."""
     import jax
 
     from mingpt_distributed_tpu.config import GPTConfig
@@ -972,20 +970,10 @@ def selftest_quant(args) -> int:
               f"{err}")
         rc = 1
 
-    # the fp8 gate: resolves only where the backend dtype exists
-    if quant_lib.fp8_dtype() is None:
-        try:
-            quant_lib.resolve_kv_dtype("fp8")
-            print("selftest-quant FAIL: fp8 resolved without a backend "
-                  "float8_e4m3fn")
-            rc = 1
-        except ValueError:
-            pass
-    else:
-        q = quant_lib.resolve_kv_dtype("fp8")
-        if q is None or q.name != "fp8":
-            print(f"selftest-quant FAIL: fp8 resolved to {q!r}")
-            rc = 1
+    q = quant_lib.resolve_kv_dtype("fp8")
+    if q is None or q.name != "fp8":
+        print(f"selftest-quant FAIL: fp8 resolved to {q!r}")
+        rc = 1
 
     print(f"selftest-quant bytes: int8 kv_pool+kv_scales={kv8} "
           f"fp32 kv_pool={pd32['kv_pool']} ratio={ratio:.4f}")
@@ -2623,6 +2611,20 @@ def _autoscale_spec(args):
     return spec
 
 
+def _check_isolation(isolation: str, platform: str) -> None:
+    """Refuse ``--isolation process`` where it cannot work. A chip belongs
+    to one process at a time: this parent takes it the moment it puts the
+    restored parameters on the device, and every replica worker it then
+    spawns inherits its environment and asks for the same chip."""
+    if isolation == "process" and platform == "tpu":
+        raise SystemExit(
+            "--isolation process cannot run on a TPU backend: a chip "
+            "belongs to one process, this parent holds it once the "
+            "snapshot is on the device, and each spawned replica worker "
+            "would then fail or hang asking for the same chip — use "
+            "--isolation thread.")
+
+
 def main(argv=None) -> int:
     args = build_argparser().parse_args(argv)
     if args.selftest_procfleet:
@@ -2650,6 +2652,11 @@ def main(argv=None) -> int:
     from mingpt_distributed_tpu.data.token_dataset import make_dataset
     from mingpt_distributed_tpu.serving import InferenceServer
     from mingpt_distributed_tpu.training import checkpoint as ckpt_lib
+    from mingpt_distributed_tpu.utils import startup
+
+    startup.enable_compile_cache()
+    print(f"[serve] {startup.device_line()}", file=sys.stderr)
+    _check_isolation(args.isolation, jax.default_backend())
 
     cfg = load_config(args.config, args.overrides)
     dataset = make_dataset(cfg.data_config)
@@ -2871,7 +2878,7 @@ def main(argv=None) -> int:
         return 0
 
     if args.prompts_file:
-        with open(args.prompts_file) as f:
+        with open(args.prompts_file, encoding="utf-8") as f:
             lines = [ln.rstrip("\n") for ln in f if ln.strip()]
         server = build_backend(None)
         # per-request isolation: one bad prompt (encode failure, validation

@@ -104,7 +104,7 @@ def test_quantize_weight_per_output_channel():
     assert np.all(err <= np.asarray(s) / 2 + 1e-12)
 
 
-def test_resolve_kv_dtype_vocabulary_and_fp8_gate():
+def test_resolve_kv_dtype_vocabulary():
     assert quant_lib.resolve_kv_dtype(None) is None
     assert quant_lib.resolve_kv_dtype("fp32") is None
     q = quant_lib.resolve_kv_dtype("int8")
@@ -112,11 +112,7 @@ def test_resolve_kv_dtype_vocabulary_and_fp8_gate():
     assert quant_lib.resolve_kv_dtype(q) is q  # already-resolved passthrough
     with pytest.raises(ValueError):
         quant_lib.resolve_kv_dtype("int4")
-    if quant_lib.fp8_dtype() is None:
-        with pytest.raises(ValueError, match="fp8"):
-            quant_lib.resolve_kv_dtype("fp8")
-    else:
-        assert quant_lib.resolve_kv_dtype("fp8").name == "fp8"
+    assert quant_lib.resolve_kv_dtype("fp8").name == "fp8"
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,9 @@
 #!/usr/bin/env python
 """On-chip A/B: native-(B,T,D) flash kernels vs the transpose path.
 
-Round-5 lever #1 (BASELINE.md): the (B,T,H,hd)<->(B*H,T,hd) transposes at
-the custom-vjp boundary. FLASH_LAYOUT=bh forces the old path; auto takes
-the native-layout kernels. End-to-end wall clock only (the relay's
-profiler traces are cost-model replays — r4 honesty finding).
+Round-5 lever #1: the (B,T,H,hd)<->(B*H,T,hd) transposes at the
+custom-vjp boundary. FLASH_LAYOUT=bh forces the old path; auto takes the
+native-layout kernels. End-to-end wall clock only.
 """
 import json
 import os
@@ -79,8 +78,7 @@ def main():
 def main_ab():
     """Fused-vs-split backward A/B (round-5 chip validation of
     _dqkv_kernel_btd): b32 both ways, then b16 fused. Exits non-zero when
-    NO run succeeded so the harvest stage is retried at the next contact
-    window instead of being marked permanently ok over pure error lines."""
+    NO run succeeded, so pure error lines never read as a pass."""
     ok = 0
     for batch, fused in ((32, True), (32, False), (16, True)):
         os.environ["FLASH_FUSED_BWD"] = "1" if fused else "0"

@@ -81,7 +81,7 @@ def main():
             except Exception as e:  # noqa: BLE001
                 rec = {"batch": batch, "cast": cast, "error": repr(e)[:200]}
             print(json.dumps(rec), flush=True)
-    if not ok:  # all-error output must fail the harvest stage (retry)
+    if not ok:  # all-error output must not read as a pass
         sys.exit(1)
 
 

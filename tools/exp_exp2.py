@@ -8,7 +8,7 @@ is exactly (cost(exp) - cost(exp2)) per score element, if any.
 
 Method (the tools/exp_flash.py discipline): a Pallas kernel holds a block
 in VMEM and applies the op REPS times via fori_loop — chained work inside
-one dispatch, so the ~1.5 ms relay floor and HBM bandwidth both cancel.
+one dispatch, so per-dispatch latency and HBM bandwidth both cancel.
 exp(-|y|) keeps values in (0, 1] so the chain neither over- nor
 underflows.
 """

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mingpt_distributed_tpu.utils import compat
-
 from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.ops import flash_attention as fa
 
@@ -134,7 +132,7 @@ def flash_fwd_btd(q, k, v, h, scale, block, window=None, softcap=None):
             pltpu.VMEM((2, block, 1), jnp.float32),
             pltpu.VMEM((2, block, hd), jnp.float32),
         ],
-        compiler_params=compat.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")
         ),
@@ -331,7 +329,7 @@ def flash_bwd_btd(q, k, v, do, lse, delta, h, scale, block):
         out_specs=[io_q],
         out_shape=[jax.ShapeDtypeStruct((b, t, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((2, block, hd), jnp.float32)],
-        compiler_params=compat.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=fa._interpret(),
@@ -357,7 +355,7 @@ def flash_bwd_btd(q, k, v, do, lse, delta, h, scale, block):
                    jax.ShapeDtypeStruct((b, t, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((2, block, hd), jnp.float32),
                         pltpu.VMEM((2, block, hd), jnp.float32)],
-        compiler_params=compat.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=fa._interpret(),

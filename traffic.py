@@ -395,7 +395,9 @@ def main(argv=None) -> int:
         run_sweep,
     )
     from mingpt_distributed_tpu.trafficlab.report import dump_report
+    from mingpt_distributed_tpu.utils import startup
 
+    startup.enable_compile_cache()
     spec = _sweep_spec(args)
     cfg = load_config(args.config, args.overrides)
     gpt_cfg = dataclasses.replace(
