@@ -1,0 +1,370 @@
+"""A serving cell: ``InferenceServer`` built as ``serve.py`` builds it, fed
+by the benchmark's own load generator on the wall clock.
+
+One thread does everything, as ``serve.py``'s own loops do: before each
+scheduling round it submits what is due (open loop) or what a client whose
+last request has completed sends next (closed loop), then calls ``step()``.
+Every token is stamped in ``on_token`` with ``time.perf_counter``, the
+clock the server stamps ``first_token_time`` with; the server's own
+``ServingMetrics`` latencies are not used (they start at ``submit()``, not
+at the due time, and their percentiles come from a histogram ladder).
+
+Timeline of a run, in seconds after the origin: traffic starts at 0, the
+measured window is [ramp_s, ramp_s + seconds), and the run watches for at
+most ``drain_s`` more. A request is attempted if it is due (open) or sent
+(closed) inside the window. It fails if it is refused, errors, or, when the
+watch ends, is unfinished and has had no token for ``stall_s``: starved or
+stuck. A request that is still streaming then is neither failed nor
+finished; what is left in the server is cancelled before the check.
+(Waiting for every request to finish would cost a whole request's life after
+every window: 70 s for 512 tokens at the gaps PR 22 measured.)
+
+A closed-loop mix whose lengths are dealt in a cycle (``traffic``: ``order``,
+``cycle_length``) comes in blocks of that length. The window then opens in the
+round that sends the first request of a block, and ``serve_tok_s`` is the
+median, over the whole blocks sent inside the window, of a block's tokens
+over the time from its first request's sending to the next block's. Every
+block is the same work in the same order and the loop is always full, so in
+the cycle the loop settles into, each block's rate is the server's; the
+median of seven of them does not move when the host is held for half a
+second once in a window, which the window's own tokens over its length
+(``serve_tok_s_window`` in the notes, and the metric itself where a mix has no
+blocks) does by the whole length of the stall.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import os
+import time
+from typing import Dict, List, Optional
+
+import numpy as np
+
+from benchmarks.harness import check, spec, trace, traffic
+
+
+def init_params(gpt_cfg, seed: int):
+    """Seeded weights on the device in one jitted call, float32 as the
+    program serves them. The program zero-initialises the learned position
+    table; here it is drawn like the other embeddings, or no check could see
+    a position that is looked up wrongly."""
+    import jax
+    from mingpt_distributed_tpu.models import gpt
+
+    def make(key):
+        k_model, k_pos = jax.random.split(key)
+        params = gpt.init(k_model, gpt_cfg)
+        params["wpe"] = 0.02 * jax.random.normal(
+            k_pos, params["wpe"].shape, params["wpe"].dtype)
+        return params
+
+    return jax.jit(make)(jax.random.key(seed))
+
+
+@dataclasses.dataclass
+class Sent:
+    """One request as the generator saw it."""
+    req: traffic.Req
+    handle: object
+    t_ref: float            # due time (open loop) or send time (closed)
+    late_s: float           # how long after t_ref submit() was called
+
+
+class Driver:
+    """The load generator and its records. ``play`` can run more than once
+    on one server (the rate sweep does), each time from an idle server."""
+
+    def __init__(self, cell: spec.Cell, seed: int, traced: bool):
+        from mingpt_distributed_tpu.serving import InferenceServer
+        from mingpt_distributed_tpu.telemetry import SpanTracer
+
+        self.cell = cell
+        self.gpt_cfg = spec.gpt_config(cell, training=False)
+        self.stamps: Dict[str, List[float]] = {}
+        self.tracer = SpanTracer(capacity=1 << 16, enabled=traced)
+        self.server = InferenceServer(
+            init_params(self.gpt_cfg, seed), self.gpt_cfg,
+            on_token=self._on_token, warmup=True, tracer=self.tracer,
+            **spec.server_options(cell))
+
+    def _on_token(self, handle, _token) -> None:
+        self.stamps[handle.request_id].append(time.perf_counter())
+
+    def _submit(self, req: traffic.Req, t_ref: float) -> Sent:
+        from mingpt_distributed_tpu.serving import Request
+
+        rid = f"r{req.index}"
+        self.stamps[rid] = []
+        handle = self.server.submit(Request(
+            prompt=req.prompt.tolist(), max_new_tokens=req.max_new_tokens,
+            do_sample=False, request_id=rid))
+        return Sent(req, handle, t_ref, time.perf_counter() - t_ref)
+
+    def play(self, reqs: List[traffic.Req], seconds: float, *,
+             compiles=None, traced: bool = False) -> "Play":
+        mix, server = self.cell.mix, self.server
+        closed = mix["loop"] == "closed"
+        block = traffic.cycle_length(mix)
+        ramp_s, drain_s = float(mix["ramp_s"]), float(mix["drain_s"])
+        parked = self.gpt_cfg.block_size - 1
+        trace_dir = os.path.join(spec.WORK, self.cell.name, "trace")
+        self.stamps.clear()
+        sent: List[Sent] = []
+        nxt = 0                             # next request of the list
+        clients: List[Optional[Sent]] = (
+            [None] * int(mix.get("clients") or server.engine.n_slots)
+            if closed else [])
+        play = Play(n_slots=server.engine.n_slots,
+                    block_size=self.gpt_cfg.block_size, block_requests=block)
+        tracing = None          # the profiler's capture, once it has started
+        traced_now = False      # inside the traced window
+        t_trace = (ramp_s + float(mix["trace_after_s"]),
+                   ramp_s + float(mix["trace_after_s"]) + float(mix["trace_s"])
+                   ) if traced else (float("inf"), float("inf"))
+
+        origin = time.perf_counter()
+        play.w0, play.w1 = origin + ramp_s, origin + ramp_s + seconds
+        while True:
+            now = time.perf_counter()
+            # opens between rounds and, where the traffic comes in blocks,
+            # in the round that sends the first request of one
+            if play.open_counters is None and now >= play.w0 and (
+                    not block or (nxt % block == 0 and any(
+                        c is None or c.handle.finished for c in clients))):
+                play.w0, play.w1 = now, now + seconds
+                play.open_counters = self._counters()
+                if compiles is not None:
+                    compiles.open_window()
+            if play.close_counters is None and now >= play.w1:
+                play.close_counters = self._counters()
+                if compiles is not None:
+                    play.compiled_in_window = compiles.close_window()
+            if tracing is None and now - origin >= t_trace[0]:
+                tracing = trace.capture(trace_dir)
+                tracing.__enter__()
+                traced_now = True
+                play.trace_open = self._counters()
+            if traced_now and now - origin >= t_trace[1]:
+                tracing.__exit__(None, None, None)
+                traced_now = False
+                play.trace_close = self._counters()
+                now = time.perf_counter()
+            if play.close_counters is not None and not traced_now:
+                attempted = [s for s in sent if play.w0 <= s.t_ref < play.w1]
+                if all(s.handle.finished for s in attempted) \
+                        or now >= play.w1 + drain_s:
+                    break
+
+            with trace.annotate("submit"):
+                if closed:
+                    for c, last in enumerate(clients):
+                        if (last is None or last.handle.finished) \
+                                and now < play.w1:
+                            if nxt >= len(reqs):
+                                raise RuntimeError(
+                                    f"the mix's pool of {len(reqs)} requests "
+                                    "ran out: make it larger")
+                            clients[c] = self._submit(reqs[nxt], now)
+                            sent.append(clients[c])
+                            nxt += 1
+                else:
+                    while nxt < len(reqs) and origin + reqs[nxt].due_s <= now:
+                        sent.append(self._submit(
+                            reqs[nxt], origin + reqs[nxt].due_s))
+                        nxt += 1
+            with trace.annotate("round"):
+                busy = server.step()
+            if play.open_counters is not None and play.close_counters is None:
+                play.rounds += 1
+            if traced_now:
+                # rows of the pool that hold a token, after this round
+                pos = server.slots.positions
+                play.trace_rounds += 1
+                play.trace_live_rows += int(pos[pos != parked].sum())
+            if not busy:
+                due = origin + reqs[nxt].due_s \
+                    if not closed and nxt < len(reqs) else now + 1e-3
+                time.sleep(max(0.0, min(due - time.perf_counter(), 1e-3)))
+
+        play.drained_at = time.perf_counter()
+        play.stall_s = float(mix["stall_s"])
+        # the check needs an empty pool: cancel what is still in the server
+        for s in sent:
+            if not s.handle.finished:
+                server.cancel(s.handle.request_id)
+        play.sent = sent
+        play.stamps = {k: np.asarray(v) for k, v in self.stamps.items()}
+        play.watchdog_recompiles = server.watchdog.recompiles
+        return play
+
+    def _counters(self) -> Dict[str, float]:
+        """The program's own counters (``ServingMetrics``), read as they
+        are; a window's value is the difference of two readings."""
+        m = self.server.metrics
+        return {
+            "steps": m.steps,
+            "lanes": (m.slot_utilization or 0.0) * m.steps * m.n_slots,
+            "prefill_tokens": m.prefill_tokens,
+            "prefill_padded_tokens": m.prefill_padded_tokens,
+            "queue_depth": len(self.server.queue),
+            "slots_occupied": self.server.slots.occupied,
+        }
+
+
+@dataclasses.dataclass
+class Play:
+    n_slots: int
+    block_size: int
+    block_requests: int = 0     # closed loop in a cycle: requests a block
+    w0: float = 0.0
+    w1: float = 0.0
+    drained_at: float = 0.0
+    stall_s: float = 0.0
+    sent: List[Sent] = dataclasses.field(default_factory=list)
+    stamps: Dict[str, np.ndarray] = dataclasses.field(default_factory=dict)
+    rounds: int = 0             # scheduling rounds inside the window
+    trace_rounds: int = 0       # and inside the traced window, with the rows
+    trace_live_rows: int = 0    # of the pool that held a token after each
+    open_counters: Optional[Dict] = None
+    close_counters: Optional[Dict] = None
+    trace_open: Optional[Dict] = None
+    trace_close: Optional[Dict] = None
+    compiled_in_window: int = 0
+    watchdog_recompiles: int = 0
+
+    def summary(self) -> Dict:
+        """The window's numbers, from the benchmark's own stamps."""
+        pct = lambda a, q: float(np.percentile(a, q)) if len(a) else float("nan")
+        w0, w1 = self.w0, self.w1
+        seconds = w1 - w0
+        attempted = [s for s in self.sent if w0 <= s.t_ref < w1]
+        ok = lambda s: s.handle.finish_reason in ("length", "eos")
+
+        def streaming(s) -> bool:
+            """Cancelled by the benchmark at the watch's end while tokens
+            were still coming."""
+            stamps = self.stamps[s.handle.request_id]
+            return (s.handle.finish_reason == "cancelled" and len(stamps) > 0
+                    and stamps[-1] >= self.drained_at - self.stall_s)
+
+        failed = [s for s in attempted if not ok(s) and not streaming(s)]
+        failed_ids = {id(s) for s in failed}
+        # a failed request counts as the worst wait seen: until the watch's end
+        ttft = np.asarray([
+            (self.drained_at if id(s) in failed_ids
+             else s.handle.first_token_time) - s.t_ref for s in attempted])
+        gaps = np.concatenate([np.zeros(0)] + [
+            np.diff(t)[(t[1:] >= w0) & (t[1:] < w1)]
+            for t in self.stamps.values() if len(t) > 1])
+        done_in = [s for s in self.sent if ok(s)
+                   and w0 <= s.handle.last_token_time < w1]
+        tokens_done = sum(len(s.handle.prompt_used) + len(s.handle.tokens)
+                          for s in done_in)
+        late = np.asarray([s.late_s for s in attempted] or [0.0])
+        c0, c1 = self.open_counters, self.close_counters
+        block_tok_s = self.block_rates()
+        return {
+            "attempted": len(attempted), "failed": len(failed),
+            "streaming_at_end": sum(bool(streaming(s)) for s in attempted),
+            "ttft_p50_ms": 1e3 * pct(ttft, 50),
+            "ttft_p90_ms": 1e3 * pct(ttft, 90),
+            "ttft_p99_ms": 1e3 * pct(ttft, 99),
+            "itl_p50_ms": 1e3 * pct(gaps, 50),
+            "itl_p90_ms": 1e3 * pct(gaps, 90),
+            "itl_gaps": int(len(gaps)),
+            "serve_tok_s": float(np.median(block_tok_s))
+            if len(block_tok_s) else tokens_done / seconds,
+            "serve_tok_s_window": tokens_done / seconds,
+            "blocks": int(len(block_tok_s)),
+            "block_tok_s": [float(r) for r in block_tok_s],
+            "completed_req_s": len(done_in) / seconds,
+            "offered_req_s": len(attempted) / seconds,
+            "generator_late_ms_p50": 1e3 * pct(late, 50),
+            "generator_late_ms_p99": 1e3 * pct(late, 99),
+            "queue_open": c0["queue_depth"], "queue_close": c1["queue_depth"],
+            "slots_open": c0["slots_occupied"],
+            "slots_close": c1["slots_occupied"],
+            "rounds": self.rounds,
+            # how well the bucket ladder fits the traffic: padded over real
+            "prefill_pad_ratio": (
+                (c1["prefill_padded_tokens"] - c0["prefill_padded_tokens"])
+                / max(c1["prefill_tokens"] - c0["prefill_tokens"], 1)),
+            "round_ms_mean": 1e3 * seconds / max(self.rounds, 1),
+        }
+
+    def block_rates(self) -> np.ndarray:
+        """Tokens a second of each whole block sent inside the window: the
+        tokens of its requests (prompt and generated, all of which have to
+        have finished) over the time from the sending of its first request
+        to the sending of the next block's first."""
+        k = self.block_requests
+        if not k:
+            return np.zeros(0)
+        by_index = {s.req.index: s for s in self.sent}
+        firsts = sorted(i for i, s in by_index.items()
+                        if i % k == 0 and s.t_ref >= self.w0)
+        rates = []
+        for i in firsts:
+            members = [by_index.get(j) for j in range(i, i + k)]
+            if i + k not in by_index or not all(
+                    s.handle.finish_reason in ("length", "eos")
+                    for s in members):
+                continue
+            tokens = sum(len(s.handle.prompt_used) + len(s.handle.tokens)
+                         for s in members)
+            rates.append(tokens / (by_index[i + k].t_ref - by_index[i].t_ref))
+        return np.asarray(rates)
+
+
+def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
+        devices: List, t_process: float, compiles) -> Dict:
+    """One run of a serving cell: the end-to-end metrics, the evidence the
+    per-layer readers take their numbers from, and the verdict."""
+    mix, found = cell.mix, cell.found
+    reference = spec.load_reference(cell.config)
+    driver = Driver(cell, seed, traced)
+    rate = found.get("rate_req_s")
+    reqs = traffic.requests(
+        mix, driver.gpt_cfg.vocab_size, seed, rate=rate,
+        horizon_s=float(mix["ramp_s"]) + seconds + 1.0,
+        warm_inflight=int(found.get("warm_inflight", 0)))
+    t_origin = time.perf_counter()
+    play = driver.play(reqs, seconds, compiles=compiles, traced=traced)
+    memory = [d.memory_stats() for d in devices]    # before the check's own
+    summary = play.summary()
+
+    # -- outside the window: the engine's own programs against the reference
+    prompts = check.pick_prompts(reqs, driver.server.engine.buckets,
+                                 int(mix["check_prompts"]))
+    if driver.server.engine.pool.used_count:
+        verdict = {"ok": False, "cases": [],
+                   "why": "the server did not drain: no empty pool to check in"}
+    else:
+        verdict = check.serve_verdict(reference, cell.config, driver.server,
+                                      prompts, int(mix["check_decode_steps"]))
+    recompiled = play.compiled_in_window + play.watchdog_recompiles
+    verdict["compiled_in_window"] = recompiled
+    verdict["ok"] = verdict["ok"] and recompiled == 0
+
+    tr = trace.load(os.path.join(spec.WORK, cell.name, "trace"), devices) \
+        if traced else None
+    evidence = {
+        "kind": "serve", "cell": cell, "chips": len(devices),
+        "device_kind": devices[0].device_kind,
+        "trace": tr, "play": play,
+        "program_spans": driver.tracer.records() if traced else [],
+    }
+    end_to_end = {k: summary[k] for k in
+                  ("serve_tok_s", "ttft_p50_ms", "itl_p50_ms")}
+    return {
+        "attempted": summary["attempted"], "failed": summary["failed"],
+        # the ramp is part of what it takes to reach the window
+        "setup_s": play.w0 - t_process,
+        "end_to_end": end_to_end,
+        "evidence": evidence, "verdict": verdict,
+        "memory_stats": memory,
+        "notes": {**summary, "traffic_digest": traffic.digest(reqs),
+                  "requests_generated": len(reqs),
+                  "warm_s": t_origin - t_process},
+    }
