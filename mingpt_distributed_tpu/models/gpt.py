@@ -275,63 +275,65 @@ def _block(
     else:
         k_attn = k_resid1 = k_resid2 = None
 
-    h = _norm(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
-    q = L.dense(h, blk["wq"], blk.get("bq")).reshape(b, t, nh, hd)
-    k = L.dense(h, blk["wk"], blk.get("bk")).reshape(b, t, kv, hd)
-    v = L.dense(h, blk["wv"], blk.get("bv")).reshape(b, t, kv, hd)
-    if rope is not None:
-        cos, sin = rope
-        q = attn_ops.apply_rope(q, cos, sin)
-        k = attn_ops.apply_rope(k, cos, sin)
-    # window/softcap compose with every attention impl, including the
-    # manual-sp attn_fn override inside pipeline stages
-    attn_kw = {}
-    if cfg.attention_window:
-        attn_kw["window"] = cfg.attention_window
-    if cfg.attn_logit_softcap:
-        attn_kw["logit_softcap"] = cfg.attn_logit_softcap
-    att = (attn_fn or _attention_dispatch(cfg, mesh))(
-        q, k, v,
-        attn_pdrop=cfg.attn_pdrop,
-        dropout_key=k_attn,
-        deterministic=deterministic,
-        **attn_kw,
-    ).reshape(b, t, nh * hd)
-    if tp_axis is not None:
-        att = jax.lax.psum(L.dense(att, blk["wo"]), tp_axis)
-        if blk.get("bo") is not None:
-            att = att + blk["bo"].astype(att.dtype)
-    else:
-        att = L.dense(att, blk["wo"], blk.get("bo"))
-    att = L.dropout(att, cfg.resid_pdrop, k_resid1, deterministic)
-    x = x + att
-
-    h2 = _norm(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.n_experts:
-        from mingpt_distributed_tpu.ops import moe
-
-        m, aux = moe.moe_mlp(
-            h2, blk["w_router"], blk["w_e1"], blk["w_e2"],
-            top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
-            w_gate=blk.get("w_eg"), ep_axis=ep_axis,
-        )
-    elif cfg.swiglu:
+    with jax.named_scope("attn"):
+        h = _norm(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
+        q = L.dense(h, blk["wq"], blk.get("bq")).reshape(b, t, nh, hd)
+        k = L.dense(h, blk["wk"], blk.get("bk")).reshape(b, t, kv, hd)
+        v = L.dense(h, blk["wv"], blk.get("bv")).reshape(b, t, kv, hd)
+        if rope is not None:
+            cos, sin = rope
+            q = attn_ops.apply_rope(q, cos, sin)
+            k = attn_ops.apply_rope(k, cos, sin)
+        # window/softcap compose with every attention impl, including the
+        # manual-sp attn_fn override inside pipeline stages
+        attn_kw = {}
+        if cfg.attention_window:
+            attn_kw["window"] = cfg.attention_window
+        if cfg.attn_logit_softcap:
+            attn_kw["logit_softcap"] = cfg.attn_logit_softcap
+        att = (attn_fn or _attention_dispatch(cfg, mesh))(
+            q, k, v,
+            attn_pdrop=cfg.attn_pdrop,
+            dropout_key=k_attn,
+            deterministic=deterministic,
+            **attn_kw,
+        ).reshape(b, t, nh * hd)
         if tp_axis is not None:
-            inner = jax.nn.silu(L.dense(h2, blk["w_gate"])) * L.dense(h2, blk["w_up"])
-            m = jax.lax.psum(L.dense(inner, blk["w_down"]), tp_axis)
+            att = jax.lax.psum(L.dense(att, blk["wo"]), tp_axis)
+            if blk.get("bo") is not None:
+                att = att + blk["bo"].astype(att.dtype)
         else:
-            m = L.mlp_swiglu(h2, blk["w_gate"], blk["w_up"], blk["w_down"])
-    else:
-        if tp_axis is not None:
-            inner = L.gelu(L.dense(h2, blk["w_fc"], blk.get("b_fc")))
-            m = jax.lax.psum(L.dense(inner, blk["w_proj"]), tp_axis)
-            if blk.get("b_proj") is not None:
-                m = m + blk["b_proj"].astype(m.dtype)
+            att = L.dense(att, blk["wo"], blk.get("bo"))
+        att = L.dropout(att, cfg.resid_pdrop, k_resid1, deterministic)
+        x = x + att
+
+    with jax.named_scope("mlp"):
+        h2 = _norm(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
+        aux = jnp.zeros((), jnp.float32)
+        if cfg.n_experts:
+            from mingpt_distributed_tpu.ops import moe
+
+            m, aux = moe.moe_mlp(
+                h2, blk["w_router"], blk["w_e1"], blk["w_e2"],
+                top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
+                w_gate=blk.get("w_eg"), ep_axis=ep_axis,
+            )
+        elif cfg.swiglu:
+            if tp_axis is not None:
+                inner = jax.nn.silu(L.dense(h2, blk["w_gate"])) * L.dense(h2, blk["w_up"])
+                m = jax.lax.psum(L.dense(inner, blk["w_down"]), tp_axis)
+            else:
+                m = L.mlp_swiglu(h2, blk["w_gate"], blk["w_up"], blk["w_down"])
         else:
-            m = L.mlp_gelu(h2, blk["w_fc"], blk.get("b_fc"), blk["w_proj"], blk.get("b_proj"))
-    m = L.dropout(m, cfg.resid_pdrop, k_resid2, deterministic)
-    return x + m, aux
+            if tp_axis is not None:
+                inner = L.gelu(L.dense(h2, blk["w_fc"], blk.get("b_fc")))
+                m = jax.lax.psum(L.dense(inner, blk["w_proj"]), tp_axis)
+                if blk.get("b_proj") is not None:
+                    m = m + blk["b_proj"].astype(m.dtype)
+            else:
+                m = L.mlp_gelu(h2, blk["w_fc"], blk.get("b_fc"), blk["w_proj"], blk.get("b_proj"))
+        m = L.dropout(m, cfg.resid_pdrop, k_resid2, deterministic)
+        return x + m, aux
 
 
 def forward(
@@ -619,6 +621,7 @@ def cross_entropy(logits: jax.Array, targets: jax.Array) -> jax.Array:
     return -(ll * valid).sum() / jnp.maximum(valid.sum(), 1)
 
 
+@jax.named_scope("ce")
 def chunked_cross_entropy(
     x: jax.Array, w_head: jax.Array, targets: jax.Array, n_chunks: int,
     softcap: Optional[float] = None,

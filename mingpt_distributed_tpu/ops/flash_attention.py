@@ -332,6 +332,7 @@ def _flash_fwd(q, k, v, scale, block, causal=True, window=None, softcap=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="flash_fwd",
         interpret=_interpret(),
     )(q, k, v)
     return out, lse
@@ -528,6 +529,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, block, causal=True, dlse=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="flash_bwd_dq",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)[0]
 
@@ -570,6 +572,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, block, causal=True, dlse=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="flash_bwd_dkv",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -1080,6 +1083,7 @@ def _flash_fwd_btd(q, k, v, h, scale, block, window=None, softcap=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_fwd",
         interpret=_interpret(),
     )(q, k, v)
     return out, lse
@@ -1143,6 +1147,7 @@ def _flash_bwd_btd(q, k, v, out, lse, do, h, scale, block, window=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_bwd_dq",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)[0]
 
@@ -1162,6 +1167,7 @@ def _flash_bwd_btd(q, k, v, out, lse, do, h, scale, block, window=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_bwd_dkv",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -1198,6 +1204,7 @@ def _flash_bwd_btd_fused(q, k, v, do, lse, delta, b, t, hd, pack, nb,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
+        name="flash_bwd_fused",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -76,10 +76,24 @@ from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.serving import quant as quant_lib
 from mingpt_distributed_tpu.serving.kv_pool import PrefixKVStore, SlotKVPool
+from mingpt_distributed_tpu.telemetry.spans import SpanTracer
 
 #: smallest default bucket — prompts below this pay one 64-token forward,
 #: which already beats a block_size² prefill by >100x at block_size 1024
 DEFAULT_MIN_BUCKET = 64
+
+
+def bind_static(fn, **static):
+    """``functools.partial(fn, **static)`` under ``fn``'s own name. XLA names
+    a jitted program after the callable it was given, and a bare partial
+    reaches it as ``jit__unknown``: bound this way the module is
+    ``jit_<fn.__name__>`` (``jit__decode_impl``), which is how a profile, and
+    the benchmark's readers, tell the engine's programs apart."""
+    @functools.wraps(fn)
+    def bound(*args, **kwargs):
+        return fn(*args, **static, **kwargs)
+
+    return bound
 
 
 def kv_pool_spec(tp_axis: str = "tp"):
@@ -145,6 +159,7 @@ def bucket_ladder(
     return tuple(sorted(vals))
 
 
+@jax.named_scope("sample")
 def _select_next_slots(
     logits: jax.Array,      # (S, V) fp32
     keys: jax.Array,        # (S,) typed PRNG keys
@@ -179,6 +194,7 @@ def _select_next_slots(
     return jnp.where(do_sample, sampled, greedy).astype(jnp.int32)
 
 
+@jax.named_scope("kv_layout")
 def _slot_lane(cache, slot):
     """The (L, 1, S, KV, hd) cache lane of one slot (scale planes, when
     present, slice the same way with their collapsed trailing axis)."""
@@ -190,6 +206,7 @@ def _slot_lane(cache, slot):
     return out
 
 
+@jax.named_scope("kv_layout")
 def _install_lane(cache, lane, slot):
     return {
         name: jax.lax.dynamic_update_slice(
@@ -254,12 +271,14 @@ def _decode_impl(
     def one_slot(tok, cache_slot, pos):
         # re-grow the batch axis the vmap stripped so the lane is exactly
         # solo generate's (B=1, T=1) decode body
-        cache_b = jax.tree.map(lambda a: a[:, None], cache_slot)
+        with jax.named_scope("kv_layout"):
+            cache_b = jax.tree.map(lambda a: a[:, None], cache_slot)
         lane = _dequant_lane(cache_b, kv_quant, cfg)
         logits, lane = gen._forward_cached(
             params, tok[None, None], lane, pos, cfg)
         cache_b = _requant_lane(lane, kv_quant)
-        return logits[0], jax.tree.map(lambda a: a[:, 0], cache_b)
+        with jax.named_scope("kv_layout"):
+            return logits[0], jax.tree.map(lambda a: a[:, 0], cache_b)
 
     logits, cache = jax.vmap(one_slot, in_axes=(0, 1, 0), out_axes=(0, 1))(
         tokens, cache, safe_pos)
@@ -319,8 +338,12 @@ class DecodeEngine:
         mesh: Optional[jax.sharding.Mesh] = None,
         tp_axis: str = "tp",
         kv_dtype: Optional[str] = None,
+        tracer: Optional[SpanTracer] = None,
     ):
         self.cfg = cfg
+        # the server's tracer, for the two halves of a decode step; alone,
+        # the engine has a disabled one and span() is a shared no-op
+        self.tracer = tracer if tracer is not None else SpanTracer(enabled=False)
         self.mesh = mesh
         self.tp_axis = tp_axis
         # ISSUE 18: "fp32" (default; byte-identical to the pre-quant
@@ -381,20 +404,18 @@ class DecodeEngine:
         kv = self.kv_sharding
         kq = self.kv_quant
         self._prefill_jit = jax.jit(
-            functools.partial(
-                _prefill_impl, cfg=cfg, kv_sharding=kv, kv_quant=kq),
+            bind_static(_prefill_impl, cfg=cfg, kv_sharding=kv, kv_quant=kq),
             donate_argnums=(1,))
         self._decode_jit = jax.jit(
-            functools.partial(
-                _decode_impl, cfg=cfg, kv_sharding=kv, kv_quant=kq),
+            bind_static(_decode_impl, cfg=cfg, kv_sharding=kv, kv_quant=kq),
             donate_argnums=(1,))
         # prefix copy programs: `rows` is static, so one jit wrapper traces
         # once per bucket-quantized prefix length
         self._extract_jit = jax.jit(
-            functools.partial(_extract_prefix_impl, kv_sharding=kv),
+            bind_static(_extract_prefix_impl, kv_sharding=kv),
             static_argnames=("rows",))
         self._install_jit = jax.jit(
-            functools.partial(_install_prefix_impl, kv_sharding=kv),
+            bind_static(_install_prefix_impl, kv_sharding=kv),
             donate_argnums=(0,))
 
     @property
@@ -607,18 +628,28 @@ class DecodeEngine:
         top_ks: np.ndarray,
         top_ps: np.ndarray,
         do_sample: np.ndarray,
-        keys: jax.Array,
+        keys,
     ) -> np.ndarray:
-        """Advance every slot one token; caller masks inactive lanes."""
-        nxt, cache = self._decode_jit(
-            self.params, self.pool.cache,
-            jnp.asarray(tokens, jnp.int32), jnp.asarray(positions, jnp.int32),
-            jnp.asarray(temps, jnp.float32), jnp.asarray(top_ks, jnp.int32),
-            jnp.asarray(top_ps, jnp.float32), jnp.asarray(do_sample),
-            keys,
-        )
-        self.pool.cache = cache
-        return np.asarray(jax.device_get(nxt))
+        """Advance every slot one token; caller masks inactive lanes.
+        ``keys`` is the (S,) key array, or the S per-slot keys still to be
+        stacked. Two spans split the host's part: ``serve.decode_launch``
+        is the staging of the arguments and the jit call up to its return
+        (the enqueue), ``serve.decode_sync`` the wait for the tokens."""
+        with self.tracer.span("serve.decode_launch"):
+            if not isinstance(keys, jax.Array):
+                keys = jnp.stack(keys)
+            nxt, cache = self._decode_jit(
+                self.params, self.pool.cache,
+                jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(positions, jnp.int32),
+                jnp.asarray(temps, jnp.float32),
+                jnp.asarray(top_ks, jnp.int32),
+                jnp.asarray(top_ps, jnp.float32), jnp.asarray(do_sample),
+                keys,
+            )
+            self.pool.cache = cache
+        with self.tracer.span("serve.decode_sync"):
+            return np.asarray(jax.device_get(nxt))
 
     def compile_counts(self) -> Dict[str, int]:
         """Distinct traces per program family. After warmup: decode 1,

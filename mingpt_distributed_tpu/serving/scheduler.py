@@ -114,12 +114,63 @@ from mingpt_distributed_tpu.telemetry.tracing import (
 )
 
 
-def _trace_attrs(handle: RequestHandle) -> Dict[str, Any]:
-    """trace_id attr for the process-level SpanTracer spans, so the
-    wall-time spans of ISSUE 5 land in the per-request timeline too."""
-    if handle.trace is None:
-        return {}
-    return {"trace_id": handle.trace.trace_id}
+class _Phase:
+    """One phase of a scheduling round, timed at one call site.
+
+    Entering opens a span of the server's :class:`SpanTracer` (and with it
+    a profiler annotation). Where a :class:`TraceRecorder` is wired and a
+    request has a trace, the same phase is filed into that trace: at the
+    end for the request the phase was opened for, or by :meth:`file` for
+    each request of a phase that serves many. The recorder's times come
+    from the server's injectable ``clock``, read here and nowhere else in
+    the phase, so on a virtual clock the per-request records stay exact;
+    ``dur_s`` is on that clock too, for the metrics that want the phase's
+    length."""
+
+    __slots__ = ("_server", "name", "_handle", "fields", "_span", "_t0",
+                 "dur_s")
+
+    def __init__(self, server: "InferenceServer", name: str,
+                 handle: Optional[RequestHandle], fields: Dict[str, Any]):
+        self._server = server
+        self.name = name
+        self._handle = handle
+        self.fields = fields
+        if handle is not None:
+            fields = {"request_id": handle.request_id, **fields}
+        self._span = server.tracer.span(name, **fields)
+        self.dur_s = 0.0
+
+    def __enter__(self) -> "_Phase":
+        self._span.__enter__()
+        self._t0 = self._server.clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.lap()
+        self._span.__exit__(*exc)
+        if self._handle is not None and exc[0] is None:
+            self.file(self._handle)
+        return False
+
+    def set(self, **fields: Any) -> None:
+        """Fields learned inside the phase (a chunk's padded length)."""
+        self.fields.update(fields)
+        self._span.set(**fields)
+
+    def lap(self) -> None:
+        """Fix ``dur_s`` at the phase's length up to now."""
+        self.dur_s = self._server.clock() - self._t0
+
+    def file(self, handle: RequestHandle, name: Optional[str] = None,
+             **fields: Any) -> None:
+        """The phase, from its start to the last :meth:`lap`, as a span of
+        ``handle``'s trace."""
+        rec = self._server.trace_recorder
+        if rec is not None and handle.trace is not None:
+            rec.add_span(handle.trace, name or self.name, ts=self._t0,
+                         dur_s=self.dur_s, **self.fields, **fields,
+                         request_id=handle.request_id)
 
 
 class SlotTable:
@@ -222,6 +273,11 @@ class InferenceServer:
         kv_dtype: Optional[str] = None,
     ):
         self.cfg = cfg
+        # disabled-by-default tracer: span() returns a shared no-op, so the
+        # scheduling loop pays nothing unless telemetry is wired in. The
+        # engine gets the same one: the two halves of its decode step are
+        # children of this server's serve.decode_round
+        self.tracer = tracer if tracer is not None else SpanTracer(enabled=False)
         # mesh passes through untouched: the scheduler owns slots
         # (ownership), the engine's sharding owns placement — the two
         # never interact, so every scheduling decision below is
@@ -231,6 +287,7 @@ class InferenceServer:
             prefill_buckets=prefill_buckets, prefill_chunk=prefill_chunk,
             prefix_cache_mb=prefix_cache_mb,
             mesh=mesh, tp_axis=tp_axis, kv_dtype=kv_dtype,
+            tracer=self.tracer,
         )
         # speculative decoding (serving/speculative.py): a draft model +
         # spec_k >= 1 turn the decode round into propose→verify→accept-n.
@@ -257,9 +314,6 @@ class InferenceServer:
         self.spec_enabled = True
         self.metrics = metrics or ServingMetrics(
             n_slots, log_every=log_every, registry=registry)
-        # disabled-by-default tracer: span() returns a shared no-op, so the
-        # scheduling loop pays nothing unless telemetry is wired in
-        self.tracer = tracer if tracer is not None else SpanTracer(enabled=False)
         # post-warmup recompile watchdog over the compiled program families
         # (the merged server-level counts, so draft/verify traces are
         # watched too; armed after warmup(); checked every round)
@@ -575,6 +629,23 @@ class InferenceServer:
                 return True
         return False
 
+    def _phase(self, name: str, handle: Optional[RequestHandle] = None,
+               **fields: Any) -> _Phase:
+        return _Phase(self, name, handle, fields)
+
+    def _queue_wait(self, handle: RequestHandle) -> None:
+        """``serve.queue_wait``: submit to admit, a wait that began in
+        another call, so it is filed whole at its end: into the tracer's
+        ring always, into the request's trace where there is one."""
+        wait = handle.admit_time - handle.submit_time
+        self.tracer.add_span("serve.queue_wait", wait,
+                             request_id=handle.request_id)
+        rec = self.trace_recorder
+        if rec is not None and handle.trace is not None:
+            rec.add_span(handle.trace, "serve.queue_wait",
+                         ts=handle.submit_time, dur_s=wait,
+                         request_id=handle.request_id)
+
     def _admit(self, handle: RequestHandle) -> None:
         """Claim a slot and start admission: a shared-prefix hit installs
         its rows now (device copy); prompt tokens beyond it prefill in the
@@ -588,23 +659,14 @@ class InferenceServer:
             self.spec.bind(slot)
         handle.prefilling = True
         handle.admit_time = self.clock()
-        rec = self.trace_recorder
-        if rec is not None and handle.trace is not None:
-            rec.add_span(
-                handle.trace, "serve.queue_wait", ts=handle.submit_time,
-                dur_s=handle.admit_time - handle.submit_time,
-                request_id=handle.request_id)
+        self._queue_wait(handle)
         self.slots.bind(slot, handle, handle.request.seed)
-        t0 = self.clock()
-        hit = self.engine.try_load_prefix(slot, handle.prompt_used)
+        with self._phase("serve.prefix_lookup", handle) as ph:
+            hit = self.engine.try_load_prefix(slot, handle.prompt_used)
+            ph.set(hit_rows=hit)
         if self.attrib is not None and hit > 0:
-            self.attrib.observe_call("prefix_load", self.clock() - t0,
+            self.attrib.observe_call("prefix_load", ph.dur_s,
                                      variant=f"b{hit}")
-        if rec is not None and handle.trace is not None:
-            rec.add_span(
-                handle.trace, "serve.prefix_lookup", ts=t0,
-                dur_s=self.clock() - t0, hit_rows=hit,
-                request_id=handle.request_id)
         self.metrics.on_prefix_lookup(
             hit > 0, hit, enabled=self.engine.prefix_store is not None)
         handle.prefix_rows = hit
@@ -630,21 +692,18 @@ class InferenceServer:
             # deterministic and row-wise), so parity is unaffected — we
             # trade a few redundant row-FLOPs for a bounded program count.
             off = self.cfg.block_size - bucket
-        t0 = self.clock()
-        tok, padded = self.engine.prefill_chunk_call(
-            slot, prompt[off:end], off,
-            req.temperature, req.top_k, req.top_p, req.do_sample,
-            jax.random.fold_in(self.slots.req_keys[slot], 0),
-        )
-        t1 = self.clock()
-        self.metrics.on_prefill_chunk(end - pos, padded, t1 - t0)
+        with self._phase("serve.prefill_chunk", handle,
+                         pos=pos, tokens=end - pos) as ph:
+            tok, padded = self.engine.prefill_chunk_call(
+                slot, prompt[off:end], off,
+                req.temperature, req.top_k, req.top_p, req.do_sample,
+                jax.random.fold_in(self.slots.req_keys[slot], 0),
+            )
+            ph.set(padded=padded)
+        self.metrics.on_prefill_chunk(end - pos, padded, ph.dur_s)
         if self.attrib is not None:
-            self.attrib.observe_call("prefill", t1 - t0, variant=f"b{padded}")
-        if self.trace_recorder is not None and handle.trace is not None:
-            self.trace_recorder.add_span(
-                handle.trace, "serve.prefill_chunk", ts=t0, dur_s=t1 - t0,
-                pos=pos, tokens=end - pos, padded=padded,
-                request_id=handle.request_id)
+            self.attrib.observe_call("prefill", ph.dur_s,
+                                     variant=f"b{padded}")
         handle.prefill_pos = end
         if not last:
             return
@@ -699,8 +758,7 @@ class InferenceServer:
             h = self.queue[idx]
             del self.queue[idx]
             self.admission_policy.on_admit(h)
-            with self.tracer.span("serve.admit", request_id=h.request_id,
-                                  **_trace_attrs(h)):
+            with self.tracer.span("serve.admit", request_id=h.request_id):
                 self._admit(h)
 
         # one chunk per prefilling slot per round: a long prompt's
@@ -708,18 +766,19 @@ class InferenceServer:
         # is bounded by one chunk forward, not one full-prompt forward
         for h in self.slots.live_handles():
             if h.prefilling:
-                with self.tracer.span(
-                        "serve.prefill_chunk", request_id=h.request_id,
-                        pos=h.prefill_pos, **_trace_attrs(h)):
-                    self._prefill_one_chunk(h)
+                self._prefill_one_chunk(h)
 
         active = self.slots.decoding_slots()
         if active:
-            with self.tracer.span("serve.decode_round", lanes=len(active)):
-                td0 = self.clock()
+            # serve.decode_round and its four children, one of each a
+            # round: serve.fold_keys and serve.emit here, serve.decode_launch
+            # and serve.decode_sync inside engine.decode_step
+            with self._phase("serve.decode_round",
+                             lanes=len(active)) as round_:
                 st = self.slots
-                for s in active:
-                    st.fold_key(s, len(st.handles[s].tokens))
+                with self.tracer.span("serve.fold_keys", lanes=len(active)):
+                    for s in active:
+                        st.fold_key(s, len(st.handles[s].tokens))
                 # speculation split: greedy lanes with k+1 rows of window
                 # headroom run propose→verify→accept-n; sampled lanes and
                 # near-window tails keep the plain one-token step (parity
@@ -740,12 +799,12 @@ class InferenceServer:
                         pos = np.where(pmask, st.positions, st.parked)
                         nxt = self.engine.decode_step(
                             st.tokens, pos, st.temps, st.top_ks,
-                            st.top_ps, st.do_sample, st.stacked_keys(),
+                            st.top_ps, st.do_sample, st.keys,
                         )
                     else:
                         nxt = self.engine.decode_step(
                             st.tokens, st.positions, st.temps, st.top_ks,
-                            st.top_ps, st.do_sample, st.stacked_keys(),
+                            st.top_ps, st.do_sample, st.keys,
                         )
                     if self.attrib is not None:
                         self.attrib.observe_call("decode",
@@ -793,54 +852,46 @@ class InferenceServer:
                 # emit ends its (solo-owned) trace, and a later-arriving
                 # span would be dropped as an orphan
                 if self.trace_recorder is not None:
-                    td1 = self.clock()
+                    round_.lap()
                     for s in active:
-                        h = st.handles[s]
-                        if h.trace is None:
-                            continue
                         if s in spec_slots:
-                            self.trace_recorder.add_span(
-                                h.trace, "serve.spec_round", ts=td0,
-                                dur_s=td1 - td0, lanes=len(active),
-                                proposed=self.spec.k,
-                                accepted=len(burst[s]) - 1,
-                                request_id=h.request_id)
+                            round_.file(st.handles[s], "serve.spec_round",
+                                        proposed=self.spec.k,
+                                        accepted=len(burst[s]) - 1)
                         else:
-                            self.trace_recorder.add_span(
-                                h.trace, "serve.decode_round", ts=td0,
-                                dur_s=td1 - td0, lanes=len(active),
-                                request_id=h.request_id)
+                            round_.file(st.handles[s])
                 # chaos fault point: a raise here loses this round's
                 # computed tokens (the whole accepted burst included)
                 # before any of them is emitted — the crash-mid-decode
                 # case the fleet retry must survive without double-
                 # emission
                 self._fire_fault("decode_round")
-                for s in active:
-                    handle = st.handles[s]
-                    toks = burst[s]
-                    if s in spec_slots:
-                        handle.spec_proposed += self.spec.k
-                        handle.spec_accepted += len(toks) - 1
-                        self.metrics.on_spec_round(self.spec.k, len(toks))
-                    for token in toks:
-                        ok = self._emit(handle, token)
-                        st.tokens[s] = token
-                        st.positions[s] += 1
-                        if not ok:
-                            self._fail(handle, "error")
-                            break
-                        if self._check_stop(handle, token):
-                            self._retire(handle)
-                            break
-                        # mid-burst deadline: a burst is the new round
-                        # granularity, so expiry is enforced between
-                        # tokens too — the tail of the burst is dropped
-                        # and both the target and draft slots free now
-                        if (handle.deadline is not None
-                                and self.clock() >= handle.deadline):
-                            self._fail(handle, "deadline")
-                            break
+                with self.tracer.span("serve.emit"):
+                    for s in active:
+                        handle = st.handles[s]
+                        toks = burst[s]
+                        if s in spec_slots:
+                            handle.spec_proposed += self.spec.k
+                            handle.spec_accepted += len(toks) - 1
+                            self.metrics.on_spec_round(self.spec.k, len(toks))
+                        for token in toks:
+                            ok = self._emit(handle, token)
+                            st.tokens[s] = token
+                            st.positions[s] += 1
+                            if not ok:
+                                self._fail(handle, "error")
+                                break
+                            if self._check_stop(handle, token):
+                                self._retire(handle)
+                                break
+                            # mid-burst deadline: a burst is the new round
+                            # granularity, so expiry is enforced between
+                            # tokens too — the tail of the burst is dropped
+                            # and both the target and draft slots free now
+                            if (handle.deadline is not None
+                                    and self.clock() >= handle.deadline):
+                                self._fail(handle, "deadline")
+                                break
 
         occupied = self.slots.occupied
         self.metrics.on_step(len(self.queue), occupied, lanes_used=len(active))

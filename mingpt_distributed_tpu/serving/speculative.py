@@ -37,7 +37,6 @@ propose round's queries attend is real, not stale.
 
 from __future__ import annotations
 
-import functools
 from typing import Dict, Optional, Sequence
 
 import jax
@@ -54,6 +53,7 @@ from .engine import (
     _requant_lane,
     _select_next_slots,
     _slot_lane,
+    bind_static,
 )
 
 __all__ = ["DraftEngine", "SpeculativeDecoder"]
@@ -170,9 +170,9 @@ class SpeculativeDecoder:
         self.draft = DraftEngine(draft_params, draft_cfg, target)
         self._parked = target.cfg.block_size - 1
         self._verify_jit = jax.jit(
-            functools.partial(_verify_impl, cfg=target.cfg,
-                              kv_sharding=target.kv_sharding,
-                              kv_quant=target.kv_quant),
+            bind_static(_verify_impl, cfg=target.cfg,
+                        kv_sharding=target.kv_sharding,
+                        kv_quant=target.kv_quant),
             donate_argnums=(1,))
         # migrated draft state parked until the owning request re-primes
         # (ISSUE 17): prompt-prefix key -> lane-dict rows, device-side

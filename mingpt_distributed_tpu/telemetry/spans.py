@@ -1,26 +1,40 @@
-"""Low-overhead span tracer (ISSUE 5 tentpole, part 1).
+"""The span primitive: nested wall-time spans, also profiler annotations.
 
-Nested wall-time spans on the monotonic clock, recorded into a bounded
-ring buffer (a deque with ``maxlen`` — a stuck exporter can never grow
-host memory) and optionally streamed to the versioned JSONL sink. The
-trainer wraps its step/snapshot/eval phases; the serving scheduler wraps
-admission → prefill-chunk → decode-round. ``tools/trace_summary.py``
-accepts the span JSONL as an alternate input alongside profiler traces.
+``SpanTracer.span(name, **attrs)`` times a phase of the host's work. The
+trainer wraps its step / snapshot / eval phases; the serving scheduler and
+engine wrap admission, prefill chunks and the decode round with its four
+children (the names, parents and attributes are listed in
+``docs/architecture.md``, "Telemetry"). A closed span goes two ways:
 
-Overhead discipline: a disabled tracer returns one shared no-op context
-manager (no allocation per call), and an enabled span costs two clock
-reads, one dict build and a deque append — no locks on the hot path
-beyond the deque's internal one. Multi-process runs gate the *default*
-tracer to process 0 (``telemetry.get_tracer()``), the same single-writer
-convention as MetricsLogger.
+* into a bounded ring (a deque with ``maxlen``: a stuck exporter can never
+  grow host memory) and, where a sink is attached, the versioned JSONL
+  stream. ``benchmarks/`` reads the ring (``evidence["program_spans"]``) for
+  its per-layer span metrics and to label the device's idle gaps;
+* while it is open, a ``jax.profiler.TraceAnnotation`` of the same name is
+  held open, so any profile taken meanwhile (the benchmark's capture, the
+  trainer's ``profile_dir``) shows the span in the host plane on the
+  profiler's own clock, beside the device lines. Outside a capture an
+  annotation is one flag test.
 
 Record layout (also the JSONL ``kind: "span"`` payload):
-``{"name", "ts" (epoch s, start), "dur_s", "depth", <attrs...>}``.
-Point events (``tracer.event``) carry ``{"name", "ts", <attrs...>}``.
+``{"name", "ts" (epoch s, start), "dur_s" (perf_counter), "depth", "id",
+"parent", <attrs...>}``. ``id`` counts up per tracer; ``parent`` is the
+``id`` of the span open around this one on the same thread, or None: self
+time is a span's duration minus its children's. Point events
+(``tracer.event``) carry ``{"name", "ts", "depth", <attrs...>}``.
+
+Overhead discipline: a disabled tracer returns one shared no-op context
+manager (no allocation per call, no annotation), and an enabled span costs
+three clock reads, one dict, one deque append and one annotation: no
+locks on the hot path beyond the deque's internal one. Multi-process runs
+gate the *default* tracer to process 0 (``telemetry.get_tracer()``), the
+same single-writer convention as MetricsLogger. Nothing here initialises a
+backend: an annotation only talks to the profiler.
 """
 
 from __future__ import annotations
 
+import itertools
 import sys
 import threading
 import time
@@ -43,40 +57,74 @@ def process_index() -> int:
         return 0
 
 
+_annotation = None  # jax.profiler.TraceAnnotation, or False where jax is absent
+
+
+def _open_annotation(name: str):
+    """A host span in the profiler's own trace, open until its ``__exit__``
+    is called; None where there is no JAX to ask."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        except Exception:
+            _annotation = False
+    if not _annotation:
+        return None
+    ann = _annotation(name)
+    ann.__enter__()
+    return ann
+
+
 class _NoopSpan:
     __slots__ = ()
 
-    def __enter__(self) -> None:
-        return None
+    def __enter__(self) -> "_NoopSpan":
+        return self
 
     def __exit__(self, *exc) -> bool:
         return False
+
+    def set(self, **attrs: Any) -> None:
+        pass
 
 
 _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_ts")
+    __slots__ = ("_tracer", "name", "attrs", "id", "parent", "_stack",
+                 "_ann", "_t0", "_ts")
 
     def __init__(self, tracer: "SpanTracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
 
+    def set(self, **attrs: Any) -> None:
+        """Attributes learned inside the span (a chunk's padded length)."""
+        self.attrs.update(attrs)
+
     def __enter__(self) -> "_Span":
+        self._stack = stack = self._tracer._open_spans()
+        self.parent = stack[-1] if stack else None
+        self.id = next(self._tracer._ids)
+        stack.append(self.id)
+        self._ann = _open_annotation(self.name)
         self._ts = time.time()
-        self._tracer._depth_tls.depth = getattr(
-            self._tracer._depth_tls, "depth", 0) + 1
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         dur = time.perf_counter() - self._t0
-        depth = getattr(self._tracer._depth_tls, "depth", 1) - 1
-        self._tracer._depth_tls.depth = depth
-        rec = {"name": self.name, "ts": self._ts,
-               "dur_s": dur, "depth": depth}
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._stack.pop()
+        rec = {"name": self.name, "ts": self._ts, "dur_s": dur,
+               "depth": len(self._stack), "id": self.id,
+               "parent": self.parent}
         if self.attrs:
             rec.update(self.attrs)
         self._tracer._record("span", rec)
@@ -99,7 +147,16 @@ class SpanTracer:
         self.sink = sink
         self.emitted = 0  # total ever recorded; ring keeps the newest
         self._ring: deque = deque(maxlen=capacity)
-        self._depth_tls = threading.local()
+        self._ids = itertools.count(1)
+        self._tls = threading.local()
+
+    def _open_spans(self) -> List[int]:
+        """Ids of the spans open on this thread, outermost first."""
+        try:
+            return self._tls.stack
+        except AttributeError:
+            self._tls.stack = []
+            return self._tls.stack
 
     def span(self, name: str, **attrs: Any):
         """Context manager timing a nested phase. Near-free when the
@@ -108,13 +165,27 @@ class SpanTracer:
             return _NOOP
         return _Span(self, name, attrs)
 
+    def add_span(self, name: str, dur_s: float, **attrs: Any) -> None:
+        """A span that ends now and lasted ``dur_s`` by the caller's own
+        clock: a wait that began in another call (a request's time in the
+        queue), which no ``with`` block can wrap. It is a child of whatever
+        span is open here, and has no profiler annotation."""
+        if not self.enabled:
+            return
+        stack = self._open_spans()
+        rec = {"name": name, "ts": time.time() - dur_s, "dur_s": dur_s,
+               "depth": len(stack), "id": next(self._ids),
+               "parent": stack[-1] if stack else None}
+        rec.update(attrs)
+        self._record("span", rec)
+
     def event(self, name: str, **attrs: Any) -> None:
         """A point-in-time event (no duration) — watchdog firings, log
         lines, phase markers."""
         if not self.enabled:
             return
         rec = {"name": name, "ts": time.time(),
-               "depth": getattr(self._depth_tls, "depth", 0)}
+               "depth": len(self._open_spans())}
         rec.update(attrs)
         self._record("event", rec)
 

@@ -186,28 +186,29 @@ def make_train_step(
                 state["params"], x, y, rng, deterministic
             )
 
-        if zero_plan is not None:
-            # ZeRO update phase: shard grads+params into the update view,
-            # step the optimizer on the local 1/dp shard, gather back.
-            gview = zero_lib.constrain(
-                zero_lib.update_view(grads, zero_plan), zero_plan
-            )
-            pview = zero_lib.constrain(
-                zero_lib.update_view(state["params"], zero_plan), zero_plan
-            )
-            updates, new_opt = optimizer.update(
-                gview, state["opt_state"], pview
-            )
-            # allgather happens here: from_view restores canonical shapes
-            # and the step's out_shardings pin the canonical param layout
-            new_params = zero_lib.from_view(
-                optax.apply_updates(pview, updates), zero_plan
-            )
-        else:
-            updates, new_opt = optimizer.update(
-                grads, state["opt_state"], state["params"]
-            )
-            new_params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            if zero_plan is not None:
+                # ZeRO update phase: shard grads+params into the update view,
+                # step the optimizer on the local 1/dp shard, gather back.
+                gview = zero_lib.constrain(
+                    zero_lib.update_view(grads, zero_plan), zero_plan
+                )
+                pview = zero_lib.constrain(
+                    zero_lib.update_view(state["params"], zero_plan), zero_plan
+                )
+                updates, new_opt = optimizer.update(
+                    gview, state["opt_state"], pview
+                )
+                # allgather happens here: from_view restores canonical shapes
+                # and the step's out_shardings pin the canonical param layout
+                new_params = zero_lib.from_view(
+                    optax.apply_updates(pview, updates), zero_plan
+                )
+            else:
+                updates, new_opt = optimizer.update(
+                    grads, state["opt_state"], state["params"]
+                )
+                new_params = optax.apply_updates(state["params"], updates)
         metrics = {
             "loss": loss,
             # pre-clip gradient norm (global: GSPMD psums sharded leaves)

@@ -1,0 +1,242 @@
+"""The spans of a scheduling round (ISSUE 23): one span system, read three
+ways: the tracer's ring, the per-request traces, the profiler's host plane.
+CPU, tiny width, `not slow` tier."""
+
+import glob
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mingpt_distributed_tpu.config import GPTConfig
+from mingpt_distributed_tpu.models import gpt
+from mingpt_distributed_tpu.serving import InferenceServer, Request
+from mingpt_distributed_tpu.serving.engine import DecodeEngine
+from mingpt_distributed_tpu.telemetry import SpanTracer, TraceRecorder
+
+CHILDREN = ["serve.fold_keys", "serve.decode_launch", "serve.decode_sync",
+            "serve.emit"]
+GOLDEN = os.path.join(os.path.dirname(__file__), "data",
+                      "request_trace_golden.json")
+PROMPTS = [[1, 2, 3, 4, 5, 6, 7, 8, 9], [5, 6, 7],
+           [1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11]]
+
+
+@pytest.fixture(scope="module")
+def cfg_params():
+    cfg = GPTConfig.make(
+        n_layer=2, n_head=2, n_embd=32, vocab_size=50, block_size=32,
+        embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32",
+    )
+    return cfg, gpt.init(jax.random.key(0), cfg)
+
+
+def spans_of(tracer, name=None):
+    return [r for r in tracer.records() if r["kind"] == "span"
+            and (name is None or r["name"] == name)]
+
+
+def served(cfg_params, tracer, **kw):
+    cfg, params = cfg_params
+    return InferenceServer(params, cfg, n_slots=2, tracer=tracer, **kw)
+
+
+def test_one_round_has_one_of_each_child_under_one_decode_round(cfg_params):
+    tracer = SpanTracer()
+    srv = served(cfg_params, tracer)
+    for i, p in enumerate(PROMPTS[:2]):
+        srv.submit(Request(prompt=p, max_new_tokens=4, request_id=f"r{i}"))
+    srv.step()
+    [round_] = spans_of(tracer, "serve.decode_round")
+    assert round_["parent"] is None and round_["lanes"] == 2
+    kids = [r for r in spans_of(tracer) if r["parent"] == round_["id"]]
+    # one of each a round (never one a lane), in the order the round runs them
+    assert sorted(kids, key=lambda r: r["ts"]) == kids
+    assert [r["name"] for r in kids] == CHILDREN
+    assert all(r["depth"] == 1 for r in kids)
+    assert kids[0]["lanes"] == 2
+    assert 0 < sum(r["dur_s"] for r in kids) <= round_["dur_s"]
+    # a second round adds exactly one more of each
+    srv.step()
+    for name in CHILDREN + ["serve.decode_round"]:
+        assert len(spans_of(tracer, name)) == 2, name
+
+
+def test_queue_wait_and_prefill_spans_need_no_trace_recorder(cfg_params):
+    tracer = SpanTracer()
+    srv = served(cfg_params, tracer, prefill_chunk=4, prefill_buckets=(4, 8))
+    assert srv.trace_recorder is None
+    srv.submit(Request(prompt=PROMPTS[0], max_new_tokens=2, request_id="q"))
+    srv.run_until_drained()
+    [wait] = spans_of(tracer, "serve.queue_wait")
+    [admit] = spans_of(tracer, "serve.admit")
+    [lookup] = spans_of(tracer, "serve.prefix_lookup")
+    assert wait["request_id"] == "q" and wait["dur_s"] >= 0
+    assert wait["parent"] == admit["id"] == lookup["parent"]
+    assert lookup["hit_rows"] == 0
+    chunks = spans_of(tracer, "serve.prefill_chunk")
+    assert [(c["pos"], c["tokens"], c["padded"]) for c in chunks] == [
+        (0, 4, 4), (4, 4, 4), (8, 1, 4)]
+    assert all(c["request_id"] == "q" and c["parent"] is None for c in chunks)
+    assert "trace_id" not in admit        # the ring carries no per-trace ids
+
+
+def test_disabled_tracer_records_nothing_through_the_server(cfg_params):
+    srv = served(cfg_params, None)
+    assert not srv.tracer.enabled and srv.engine.tracer is srv.tracer
+    srv.submit(Request(prompt=PROMPTS[1], max_new_tokens=3))
+    srv.run_until_drained()
+    assert srv.tracer.records() == [] and srv.tracer.emitted == 0
+
+
+def test_the_engine_alone_has_a_disabled_tracer(cfg_params):
+    cfg, params = cfg_params
+    eng = DecodeEngine(params, cfg, 2)
+    assert not eng.tracer.enabled
+
+
+class _Sink:
+    schema = "mingpt-trace/1"
+
+    def __init__(self):
+        self.out = []
+
+    def write(self, kind, payload):
+        self.out.append(dict(payload, kind=kind))
+
+    def close(self):
+        pass
+
+
+class _RoundClock:
+    """Moves only between rounds: every stamp of a round is the same."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+@pytest.mark.parametrize("mode", ["plain", "spec"])
+def test_request_traces_are_field_for_field_what_they_were(cfg_params, mode):
+    """The per-request records of three requests through two slots, against
+    the same run of the commit before the call sites were merged (PR 22:
+    chunked prefill with a prefix hit, and speculation)."""
+    cfg, params = cfg_params
+    sink, clock = _Sink(), _RoundClock()
+    rec = TraceRecorder(sink=sink, sample=1.0)
+    kw = (dict(draft_params=params, draft_cfg=cfg, spec_k=2) if mode == "spec"
+          else dict(prefill_chunk=4, prefix_cache_mb=1.0,
+                    prefill_buckets=(4, 8)))
+    tracer = SpanTracer()
+    srv = InferenceServer(params, cfg, n_slots=2, clock=clock,
+                          trace_recorder=rec, tracer=tracer, **kw)
+    for i, p in enumerate(PROMPTS):
+        srv.submit(Request(prompt=p, max_new_tokens=3 + i,
+                           request_id=f"g{i}"))
+    while True:
+        clock.t += 0.25
+        if not srv.step():
+            break
+    with open(GOLDEN, encoding="utf-8") as f:
+        want = json.load(f)[mode]
+    assert len(sink.out) == len(want)
+    for got, exp in zip(sink.out, want):
+        assert got == exp
+    # and the ring saw the same phases, once each
+    n = lambda name, recs: sum(r.get("name") == name for r in recs)
+    for name in ("serve.queue_wait", "serve.prefix_lookup",
+                 "serve.prefill_chunk"):
+        assert n(name, spans_of(tracer)) == n(name, want), name
+    if mode == "spec":
+        # a round of speculating lanes only: the shared phases and no more
+        names = {r["name"] for r in spans_of(tracer)}
+        assert {"serve.decode_round", "serve.fold_keys", "serve.emit"} <= names
+
+
+def test_a_profile_of_two_rounds_holds_the_span_names(cfg_params, tmp_path):
+    """Spans are profiler annotations too: a capture shows them in a host
+    plane, on the profiler's clock."""
+    from jax.profiler import ProfileData
+
+    tracer = SpanTracer()
+    srv = served(cfg_params, tracer, warmup=True)
+    for i, p in enumerate(PROMPTS[:2]):
+        srv.submit(Request(prompt=p, max_new_tokens=4, request_id=f"p{i}"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        srv.step()
+        srv.step()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("serve."):
+                        seen[e.name] = seen.get(e.name, 0) + 1
+    for name in CHILDREN + ["serve.decode_round"]:
+        assert seen.get(name) == 2, (name, seen)
+    assert seen.get("serve.admit") == 2 and seen.get("serve.prefill_chunk") == 2
+    # a wait filed at its end is a ring record and no annotation
+    assert "serve.queue_wait" not in seen
+    assert len(spans_of(tracer, "serve.queue_wait")) == 2
+
+
+@pytest.mark.parametrize("attr,part", [
+    ("_decode_jit", "decode_impl"), ("_prefill_jit", "prefill_impl"),
+    ("_extract_jit", "extract_prefix_impl"),
+    ("_install_jit", "install_prefix_impl")])
+def test_engine_programs_are_jitted_under_their_own_names(cfg_params, attr, part):
+    cfg, params = cfg_params
+    eng = DecodeEngine(params, cfg, 2, prefix_cache_mb=1.0)
+    fn = getattr(eng, attr)
+    assert part in fn.__name__ and "unknown" not in fn.__name__
+    s, key = eng.n_slots, jax.random.key(0)
+    cache = eng.pool.cache
+    args = {
+        "_decode_jit": lambda: (eng.params, cache, jnp.zeros(s, jnp.int32),
+                                jnp.zeros(s, jnp.int32), jnp.ones(s),
+                                jnp.zeros(s, jnp.int32), jnp.ones(s),
+                                jnp.zeros(s, bool), jnp.stack([key] * s)),
+        "_prefill_jit": lambda: (eng.params, cache, jnp.zeros(32, jnp.int32),
+                                 np.int32(3), np.int32(0), np.int32(0),
+                                 np.float32(1), np.int32(0), np.float32(1),
+                                 np.bool_(False), key),
+        "_extract_jit": lambda: (cache, np.int32(0)),
+        "_install_jit": lambda: (
+            cache, {n: a[:, :1, :32] for n, a in cache.items()}, np.int32(0)),
+    }[attr]()
+    kwargs = {"rows": 32} if attr == "_extract_jit" else {}
+    text = fn.lower(*args, **kwargs).as_text()
+    assert f"module @jit_{fn.__name__}" in text
+
+
+def test_the_verify_program_is_named_too(cfg_params):
+    cfg, params = cfg_params
+    srv = InferenceServer(params, cfg, n_slots=2, draft_params=params,
+                          draft_cfg=cfg, spec_k=2)
+    assert srv.spec._verify_jit.__name__ == "_verify_impl"
+
+
+def test_decode_step_stacks_a_list_of_keys_itself(cfg_params):
+    cfg, params = cfg_params
+    keys = [jax.random.key(3), jax.random.key(4)]
+    outs = []
+    for given in (keys, jnp.stack(keys)):
+        eng = DecodeEngine(params, cfg, 2)
+        outs.append(eng.decode_step(
+            np.array([1, 2], np.int32), np.array([0, 0], np.int32),
+            np.ones(2, np.float32), np.zeros(2, np.int32),
+            np.ones(2, np.float32), np.ones(2, bool), given))
+    assert outs[0].tolist() == outs[1].tolist()

@@ -345,6 +345,94 @@ def test_spans_nest_and_record_depth():
     assert outer["kind"] == "span"
 
 
+def test_span_ids_and_parents_nest_per_thread():
+    """``parent`` is the id of the span open around this one on the SAME
+    thread; ids are unique across threads."""
+    import threading
+
+    tr = SpanTracer()
+    inside = threading.Event()
+    release = threading.Event()
+
+    def worker():
+        with tr.span("w.outer"):
+            inside.set()
+            release.wait(5)
+            with tr.span("w.inner"):
+                pass
+
+    with tr.span("m.outer"):
+        t = threading.Thread(target=worker)
+        t.start()
+        assert inside.wait(5)
+        # opened while the worker's span is open: still a child of m.outer
+        with tr.span("m.inner"):
+            tr.event("m.mark")
+        release.set()
+        t.join()
+    by = {r["name"]: r for r in tr.records()}
+    assert by["m.outer"]["parent"] is None and by["w.outer"]["parent"] is None
+    assert by["m.inner"]["parent"] == by["m.outer"]["id"]
+    assert by["w.inner"]["parent"] == by["w.outer"]["id"]
+    assert by["m.inner"]["depth"] == by["w.inner"]["depth"] == 1
+    assert by["m.mark"]["depth"] == 2 and "id" not in by["m.mark"]
+    ids = [r["id"] for r in tr.records() if r["kind"] == "span"]
+    assert len(set(ids)) == 4
+    # self time: a parent's duration covers its children's
+    assert by["m.outer"]["dur_s"] >= by["m.inner"]["dur_s"]
+
+
+def test_add_span_files_a_finished_wait_under_the_open_span():
+    tr = SpanTracer()
+    with tr.span("serve.admit", request_id="r"):
+        tr.add_span("serve.queue_wait", 0.5, request_id="r")
+    wait, admit = tr.records()
+    assert wait["kind"] == "span" and wait["dur_s"] == 0.5
+    assert wait["parent"] == admit["id"] and wait["depth"] == 1
+    assert wait["ts"] == pytest.approx(admit["ts"] - 0.5, abs=0.05)
+    assert wait["request_id"] == "r" and wait["id"] != admit["id"]
+
+
+def test_span_attrs_can_be_set_inside_the_span():
+    tr = SpanTracer()
+    with tr.span("serve.prefill_chunk", pos=0) as sp:
+        sp.set(padded=64)
+    [rec] = tr.records()
+    assert rec["pos"] == 0 and rec["padded"] == 64
+    with SpanTracer(enabled=False).span("x") as noop:
+        noop.set(padded=64)      # the shared no-op takes it and keeps nothing
+
+
+def test_enabled_span_holds_a_profiler_annotation_open(monkeypatch):
+    from mingpt_distributed_tpu.telemetry import spans as spans_mod
+
+    log = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("open", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("close", self.name))
+
+    monkeypatch.setattr(spans_mod, "_annotation", Ann)
+    tr = SpanTracer()
+    with tr.span("a"):
+        with tr.span("b", k=1):
+            assert log == [("open", "a"), ("open", "b")]
+    assert log[2:] == [("close", "b"), ("close", "a")]
+    # a disabled tracer opens none, through span(), add_span() or event()
+    del log[:]
+    off = SpanTracer(enabled=False)
+    with off.span("a"):
+        off.add_span("w", 1.0)
+        off.event("e")
+    assert log == [] and off.records() == [] and off.emitted == 0
+
+
 def test_span_ring_is_bounded():
     tr = SpanTracer(capacity=8)
     for i in range(20):
