@@ -918,13 +918,12 @@ def serving_probe() -> dict:
     }
 
     eng = server.engine
-    key = jax.random.key(1)
 
     def prefill_ms(n_tokens: int, offset: int = 0) -> float:
         ids = list(range(1, n_tokens + 1))
         t0 = time.perf_counter()
         for _ in range(5):
-            eng.prefill_chunk_call(0, ids, offset, 1.0, None, None, False, key)
+            eng.prefill_chunk_call(0, ids, offset, 1.0, None, None, False, 1)
         return (time.perf_counter() - t0) / 5 * 1e3
 
     short_ms = prefill_ms(16)            # 16-token prompt, bucket 16
@@ -952,7 +951,7 @@ def serving_probe() -> dict:
         step = lambda: e.decode_step(  # noqa: E731
             zeros, zeros, np.ones(n, np.float32), zeros,
             np.ones(n, np.float32), np.zeros(n, bool),
-            jax.random.split(jax.random.key(2), n))
+            np.full(n, 2, np.uint32), zeros)
         step()  # compile
         t0 = time.perf_counter()
         for _ in range(20):

@@ -234,18 +234,49 @@ def _requant_lane(lane, kv_quant):
     return quant_lib.quantize_lane(lane, kv_quant)
 
 
+def lane_keys(seeds: jax.Array, token_index: Optional[jax.Array] = None):
+    """(S,) typed keys, ``fold_in(key(seed), token_index)`` a lane: the key
+    under which the request of that seed samples its token of that index
+    (``token_index`` None is index 0, a request's first token). Called
+    inside the traced program that samples with them, so a round's keys
+    cost the host two small vectors and the device S threefry hashes —
+    not one eager dispatch a lane. ``seeds`` that are typed keys already
+    (``benchmarks/rehearse.py`` lowers the programs with them) pass
+    through as they are."""
+    if jnp.issubdtype(seeds.dtype, jax.dtypes.prng_key):
+        return seeds
+    if token_index is None:
+        token_index = jnp.zeros(seeds.shape, jnp.int32)
+    return jax.vmap(
+        lambda s, i: jax.random.fold_in(jax.random.key(s), i)
+    )(seeds, token_index)
+
+
+def request_seeds(seeds) -> np.ndarray:
+    """Request seeds as the uint32 the programs take: a seed's low 32
+    bits, which is all ``jax.random.key`` keeps of it. A caller that
+    still holds typed keys made by ``key(seed)`` (the benchmark's
+    correctness check does) may pass those: a threefry key's low word is
+    its seed."""
+    if isinstance(seeds, jax.Array) and jnp.issubdtype(
+            seeds.dtype, jax.dtypes.prng_key):
+        seeds = np.asarray(jax.random.key_data(seeds))[..., -1]
+    return np.asarray(seeds).astype(np.uint32, copy=False)
+
+
 def _prefill_impl(
     params, cache, chunk, length, offset, slot,
-    temp, top_k, top_p, do_sample, key,
+    temp, top_k, top_p, do_sample, seed,
     *, cfg: GPTConfig, kv_sharding=None, kv_quant=None,
 ):
     """chunk: (bucket,) right-padded tokens; length/offset/slot traced
-    scalars. Forwards the chunk at absolute position ``offset`` against
-    the slot's cache lane (attending everything written before it) and
-    writes the lane back. Returns (token sampled at within-chunk position
-    ``length - 1`` (scalar int32), updated pool cache) — the caller only
-    uses the token on the final chunk of a prompt. A quantized engine
-    (``kv_quant``) dequantizes the lane before the forward and
+    scalars; seed the request's (uint32, traced). Forwards the chunk at
+    absolute position ``offset`` against the slot's cache lane (attending
+    everything written before it) and writes the lane back. Returns (token
+    sampled at within-chunk position ``length - 1`` under
+    ``fold_in(key(seed), 0)`` (scalar int32), updated pool cache) — the
+    caller only uses the token on the final chunk of a prompt. A quantized
+    engine (``kv_quant``) dequantizes the lane before the forward and
     requantizes the whole lane after — both inside this traced program,
     so the dtype rides the compile key and no collective is added."""
     lane = _dequant_lane(_slot_lane(cache, slot), kv_quant, cfg)
@@ -254,18 +285,21 @@ def _prefill_impl(
     h_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
     logits = gen._head_logits(params, h_last, cfg)[:, 0]  # (1, V)
     tok = _select_next_slots(
-        logits, key[None], temp[None], top_k[None], top_p[None],
+        logits, lane_keys(seed[None]), temp[None], top_k[None], top_p[None],
         do_sample[None],
     )[0]
     return tok, _pin_kv(_install_lane(cache, lane, slot), kv_sharding)
 
 
 def _decode_impl(
-    params, cache, tokens, positions, temps, top_ks, top_ps, do_sample, keys,
+    params, cache, tokens, positions, temps, top_ks, top_ps, do_sample,
+    seeds, token_index=None,
     *, cfg: GPTConfig, kv_sharding=None, kv_quant=None,
 ):
     """One token for every slot: tokens/positions (S,), sampling arrays
-    (S,), keys (S,). Returns (next tokens (S,), updated pool cache)."""
+    (S,), request seeds (S,) uint32 and the index (S,) of the token each
+    lane samples, from which the lanes' keys are derived here
+    (:func:`lane_keys`). Returns (next tokens (S,), updated pool cache)."""
     safe_pos = jnp.clip(positions, 0, cfg.block_size - 1)
 
     def one_slot(tok, cache_slot, pos):
@@ -282,7 +316,8 @@ def _decode_impl(
 
     logits, cache = jax.vmap(one_slot, in_axes=(0, 1, 0), out_axes=(0, 1))(
         tokens, cache, safe_pos)
-    nxt = _select_next_slots(logits, keys, temps, top_ks, top_ps, do_sample)
+    nxt = _select_next_slots(logits, lane_keys(seeds, token_index),
+                             temps, top_ks, top_ps, do_sample)
     return nxt, _pin_kv(cache, kv_sharding)
 
 
@@ -450,12 +485,13 @@ class DecodeEngine:
         top_k: Optional[int],
         top_p: Optional[float],
         do_sample: bool,
-        key: jax.Array,
+        seed,
     ) -> Tuple[int, int]:
         """Prefill ``chunk_ids`` into ``slot`` at absolute ``offset``.
-        Returns (sampled token at the chunk's last real position — only
-        meaningful on a prompt's final chunk — and the padded bucket
-        length actually forwarded)."""
+        ``seed`` is the request's (:func:`request_seeds`). Returns (sampled
+        token at the chunk's last real position — only meaningful on a
+        prompt's final chunk — and the padded bucket length actually
+        forwarded)."""
         n = len(chunk_ids)
         if n < 1:
             raise ValueError("empty prefill chunk")
@@ -469,12 +505,12 @@ class DecodeEngine:
         padded = np.zeros(bucket, np.int32)
         padded[:n] = np.asarray(chunk_ids, np.int32)
         tok, cache = self._prefill_jit(
-            self.params, self.pool.cache, jnp.asarray(padded),
+            self.params, self.pool.cache, padded,
             np.int32(n), np.int32(offset), np.int32(slot),
             np.float32(temperature),
             np.int32(0 if top_k is None else top_k),
             np.float32(1.0 if top_p is None else top_p),
-            np.bool_(do_sample), key,
+            np.bool_(do_sample), request_seeds(seed)[()],
         )
         self.pool.cache = cache
         return int(jax.device_get(tok)), bucket
@@ -600,17 +636,16 @@ class DecodeEngine:
         only while the pool has no tenants — warmup scribbles over slot
         0's cache rows, which the stale-row invariant makes harmless."""
         assert self.pool.used_count == 0, "warmup requires an empty pool"
-        key = jax.random.key(0)
         for b in self.buckets:
             self.prefill_chunk_call(
-                0, [0] * b, 0, 1.0, None, None, False, key)
+                0, [0] * b, 0, 1.0, None, None, False, 0)
         s = self.n_slots
         self.decode_step(
             np.zeros(s, np.int32),
             np.full(s, self.cfg.block_size - 1, np.int32),
             np.ones(s, np.float32), np.zeros(s, np.int32),
             np.ones(s, np.float32), np.zeros(s, bool),
-            jnp.stack([key] * s),
+            np.zeros(s, np.uint32), np.zeros(s, np.int32),
         )
         if self.prefix_store is not None:
             for b in self.buckets:
@@ -628,24 +663,30 @@ class DecodeEngine:
         top_ks: np.ndarray,
         top_ps: np.ndarray,
         do_sample: np.ndarray,
-        keys,
+        seeds,
+        token_index: Optional[np.ndarray] = None,
     ) -> np.ndarray:
         """Advance every slot one token; caller masks inactive lanes.
-        ``keys`` is the (S,) key array, or the S per-slot keys still to be
-        stacked. Two spans split the host's part: ``serve.decode_launch``
-        is the staging of the arguments and the jit call up to its return
-        (the enqueue), ``serve.decode_sync`` the wait for the tokens."""
+        Lane ``s`` samples under ``fold_in(key(seeds[s]), token_index[s])``,
+        derived inside the program: ``seeds`` are the (S,) request seeds
+        (:func:`request_seeds`), ``token_index`` how many tokens each
+        request has emitted (None: 0 a lane). The host vectors go to the
+        one jit call as they are; no other program is dispatched. Two
+        spans split the host's part: ``serve.decode_launch`` is the
+        staging of the arguments and the jit call up to its return (the
+        enqueue), ``serve.decode_sync`` the wait for the tokens."""
         with self.tracer.span("serve.decode_launch"):
-            if not isinstance(keys, jax.Array):
-                keys = jnp.stack(keys)
+            if token_index is None:
+                token_index = np.zeros(len(tokens), np.int32)
             nxt, cache = self._decode_jit(
                 self.params, self.pool.cache,
-                jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(positions, jnp.int32),
-                jnp.asarray(temps, jnp.float32),
-                jnp.asarray(top_ks, jnp.int32),
-                jnp.asarray(top_ps, jnp.float32), jnp.asarray(do_sample),
-                keys,
+                np.asarray(tokens, np.int32),
+                np.asarray(positions, np.int32),
+                np.asarray(temps, np.float32),
+                np.asarray(top_ks, np.int32),
+                np.asarray(top_ps, np.float32),
+                np.asarray(do_sample, bool),
+                request_seeds(seeds), np.asarray(token_index, np.int32),
             )
             self.pool.cache = cache
         with self.tracer.span("serve.decode_sync"):
@@ -675,14 +716,13 @@ class DecodeEngine:
         cheap. Family names mirror ``compile_counts()`` keys (prefixed
         for a draft engine); prefill/prefix variants are per ladder
         bucket."""
-        key = jax.random.key(0)
         for b in self.buckets:
             ledger.register_aot(
                 family_prefix + "prefill", self._prefill_jit,
                 (self.params, self.pool.cache, jnp.zeros(b, jnp.int32),
                  np.int32(b), np.int32(0), np.int32(0),
                  np.float32(1.0), np.int32(0), np.float32(1.0),
-                 np.bool_(False), key),
+                 np.bool_(False), np.uint32(0)),
                 clock, variant=f"b{b}")
         s = self.n_slots
         ledger.register_aot(
@@ -691,7 +731,7 @@ class DecodeEngine:
              jnp.zeros(s, jnp.int32), jnp.zeros(s, jnp.int32),
              jnp.ones(s, jnp.float32), jnp.zeros(s, jnp.int32),
              jnp.ones(s, jnp.float32), jnp.zeros(s, bool),
-             jnp.stack([key] * s)),
+             jnp.zeros(s, jnp.uint32), jnp.zeros(s, jnp.int32)),
             clock)
         if self.prefix_store is not None:
             for b in self.buckets:

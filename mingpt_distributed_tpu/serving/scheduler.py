@@ -32,9 +32,13 @@ The loop the server runs (``step()`` = one scheduling round):
 
 Determinism: policy-ordered admission (FIFO default; every shipped
 policy tie-breaks by queue position), lowest-free-slot placement, and per-request
-PRNG keys derived as ``fold_in(key(seed), token_index)`` — a sampled
-request's output depends only on (params, prompt, sampling params, seed),
-never on which other requests share the batch. Greedy requests are
+PRNG keys ``fold_in(key(seed), token_index)`` — a sampled request's
+output depends only on (params, prompt, sampling params, seed), never on
+which other requests share the batch. The keys are derived inside the
+compiled program that samples with them (``engine.lane_keys``): the host
+keeps a request's seed in its slot and hands each round the vector of
+seeds and the vector of token indices, so no ``jax.random`` call runs
+eagerly between admission and the emitted token. Greedy requests are
 token-identical to solo ``generate()`` on the same prompt under every
 combination of bucketing, chunking and prefix reuse (asserted in
 tests/test_serving.py): chunked prefill is row-equivalent to the
@@ -81,8 +85,6 @@ import time
 from collections import deque
 from typing import Any, Callable, Deque, Dict, List, Optional, Sequence
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from mingpt_distributed_tpu.config import GPTConfig
@@ -196,17 +198,18 @@ class SlotTable:
         self.top_ks = np.zeros(n_slots, np.int32)
         self.top_ps = np.ones(n_slots, np.float32)
         self.do_sample = np.zeros(n_slots, bool)
-        self.keys: List[jax.Array] = [jax.random.key(0)] * n_slots
-        self.req_keys: List[Optional[jax.Array]] = [None] * n_slots
+        # request seeds, the low 32 bits (all jax.random.key keeps of a
+        # seed); free lanes hold 0
+        self.seeds = np.zeros(n_slots, np.uint32)
 
     def bind(self, slot: int, handle: RequestHandle, seed: int) -> None:
         handle.slot = slot
         self.handles[slot] = handle
-        self.req_keys[slot] = jax.random.key(seed)
+        self.seeds[slot] = seed & 0xFFFFFFFF
 
     def release(self, slot: int) -> None:
         self.handles[slot] = None
-        self.req_keys[slot] = None
+        self.seeds[slot] = 0
         self.positions[slot] = self.parked
 
     def start_decode(self, slot: int, token: int, position: int,
@@ -220,11 +223,15 @@ class SlotTable:
         self.top_ps[slot] = 1.0 if req.top_p is None else req.top_p
         self.do_sample[slot] = req.do_sample
 
-    def fold_key(self, slot: int, token_index: int) -> None:
-        self.keys[slot] = jax.random.fold_in(self.req_keys[slot], token_index)
-
-    def stacked_keys(self) -> jax.Array:
-        return jnp.stack(self.keys)
+    def token_indices(self, slots: Sequence[int]) -> np.ndarray:
+        """(n_slots,) int32: for each of ``slots`` the index of the token
+        its request samples next (how many it has emitted), 0 elsewhere.
+        With ``seeds`` this is all the decode program needs to derive the
+        round's keys."""
+        index = np.zeros(self.n_slots, np.int32)
+        for s in slots:
+            index[s] = len(self.handles[s].tokens)
+        return index
 
     def live_handles(self) -> List[RequestHandle]:
         return [h for h in self.handles if h is not None]
@@ -697,7 +704,7 @@ class InferenceServer:
             tok, padded = self.engine.prefill_chunk_call(
                 slot, prompt[off:end], off,
                 req.temperature, req.top_k, req.top_p, req.do_sample,
-                jax.random.fold_in(self.slots.req_keys[slot], 0),
+                self.slots.seeds[slot],
             )
             ph.set(padded=padded)
         self.metrics.on_prefill_chunk(end - pos, padded, ph.dur_s)
@@ -719,8 +726,7 @@ class InferenceServer:
             # migration parked this prompt's draft rows on us — a
             # device-side row install plus at most a tail chunk
             tp0 = self.clock()
-            mode = self.spec.prime(
-                slot, prompt, jax.random.fold_in(self.slots.req_keys[slot], 0))
+            mode = self.spec.prime(slot, prompt, self.slots.seeds[slot])
             self.metrics.on_spec_prime(mode)
             if self.attrib is not None:
                 b = self.spec.draft.engine.bucket_for(len(prompt))
@@ -776,9 +782,10 @@ class InferenceServer:
             with self._phase("serve.decode_round",
                              lanes=len(active)) as round_:
                 st = self.slots
+                # the keys themselves are folded inside the programs; what
+                # the host builds of them is the index vector
                 with self.tracer.span("serve.fold_keys", lanes=len(active)):
-                    for s in active:
-                        st.fold_key(s, len(st.handles[s].tokens))
+                    index = st.token_indices(active)
                 # speculation split: greedy lanes with k+1 rows of window
                 # headroom run propose→verify→accept-n; sampled lanes and
                 # near-window tails keep the plain one-token step (parity
@@ -791,21 +798,17 @@ class InferenceServer:
                 burst: Dict[int, List[int]] = {}
                 if plain:
                     tdp = self.clock()
+                    pos = st.positions
                     if spec_slots:
                         # park speculating lanes: the verify program is
                         # their row-writer this round
                         pmask = np.zeros(st.n_slots, bool)
                         pmask[plain] = True
                         pos = np.where(pmask, st.positions, st.parked)
-                        nxt = self.engine.decode_step(
-                            st.tokens, pos, st.temps, st.top_ks,
-                            st.top_ps, st.do_sample, st.keys,
-                        )
-                    else:
-                        nxt = self.engine.decode_step(
-                            st.tokens, st.positions, st.temps, st.top_ks,
-                            st.top_ps, st.do_sample, st.keys,
-                        )
+                    nxt = self.engine.decode_step(
+                        st.tokens, pos, st.temps, st.top_ks,
+                        st.top_ps, st.do_sample, st.seeds, index,
+                    )
                     if self.attrib is not None:
                         self.attrib.observe_call("decode",
                                                  self.clock() - tdp)
@@ -816,7 +819,7 @@ class InferenceServer:
                     smask[spec_slots] = True
                     tdr = self.clock()
                     proposals = self.spec.propose(
-                        st.tokens, st.positions, smask, st.stacked_keys())
+                        st.tokens, st.positions, smask, st.seeds, index)
                     if self.attrib is not None:
                         self.attrib.observe_call(
                             "draft_decode", self.clock() - tdr,
@@ -831,7 +834,7 @@ class InferenceServer:
                         g = self.spec.verify(
                             s, rows, int(st.positions[s]),
                             float(st.temps[s]), int(st.top_ks[s]),
-                            float(st.top_ps[s]), st.keys[s])
+                            float(st.top_ps[s]), st.seeds[s], index[s])
                         if self.attrib is not None:
                             self.attrib.observe_call(
                                 "verify", self.clock() - tv0,
@@ -846,7 +849,7 @@ class InferenceServer:
                             fill_toks[s] = int(proposals[s][-1])
                             fill_pos[s] = int(st.positions[s]) + self.spec.k
                     self.spec.backfill(
-                        fill_toks, fill_pos, fill_mask, st.stacked_keys())
+                        fill_toks, fill_pos, fill_mask, st.seeds, index)
                 # per-request decode-round spans cover the compiled
                 # step(s) and are recorded BEFORE emission: a retiring
                 # emit ends its (solo-owned) trace, and a later-arriving

@@ -54,13 +54,16 @@ from .engine import (
     _select_next_slots,
     _slot_lane,
     bind_static,
+    lane_keys,
+    request_seeds,
 )
 
 __all__ = ["DraftEngine", "SpeculativeDecoder"]
 
 
 def _verify_impl(
-    params, cache, tokens, offset, slot, temp, top_k, top_p, key,
+    params, cache, tokens, offset, slot, temp, top_k, top_p,
+    seed, token_index,
     *, cfg: GPTConfig, kv_sharding=None, kv_quant=None,
 ):
     """Score ``tokens`` (rows = k+1, static) at absolute positions
@@ -68,15 +71,18 @@ def _verify_impl(
     target's next-token choice at EVERY row. The sampler is
     ``_select_next_slots`` with the slot's own (greedy) parameters — not
     a raw argmax — so fp tie-breaking is bit-identical to the plain
-    decode path and parity holds even on tied logits. A quantized pool
-    dequantizes the lane before the forward and requantizes the whole
-    lane on the way back in, same as the prefill/decode bodies."""
+    decode path and parity holds even on tied logits. The rows' keys are
+    split from the slot's ``fold_in(key(seed), token_index)``, derived
+    here like every other program's (``engine.lane_keys``). A quantized
+    pool dequantizes the lane before the forward and requantizes the
+    whole lane on the way back in, same as the prefill/decode bodies."""
     rows = tokens.shape[0]
     lane = _dequant_lane(_slot_lane(cache, slot), kv_quant, cfg)
     x, lane = gen._forward_cached_hidden(params, tokens[None], lane, offset, cfg)
     lane = _requant_lane(lane, kv_quant)
     logits = gen._head_logits(params, x, cfg)[0]  # (rows, V) fp32
-    keys = jax.random.split(key, rows)
+    keys = jax.random.split(
+        lane_keys(seed[None], token_index[None])[0], rows)
     nxt = _select_next_slots(
         logits, keys,
         jnp.full((rows,), temp, jnp.float32),
@@ -134,11 +140,11 @@ class DraftEngine:
     def release(self, slot: int) -> None:
         self.engine.pool.free(slot)
 
-    def prime(self, slot: int, prompt_ids: Sequence[int], key) -> None:
+    def prime(self, slot: int, prompt_ids: Sequence[int], seed) -> None:
         """Prefill the draft lane with the full prompt in one call (the
         ladder always covers prefill_len, so one bucket suffices)."""
         self.engine.prefill_chunk_call(
-            slot, list(prompt_ids), 0, 1.0, None, None, False, key)
+            slot, list(prompt_ids), 0, 1.0, None, None, False, seed)
 
 
 class SpeculativeDecoder:
@@ -190,7 +196,7 @@ class SpeculativeDecoder:
     def release(self, slot: int) -> None:
         self.draft.release(slot)
 
-    def prime(self, slot: int, prompt_ids: Sequence[int], key) -> str:
+    def prime(self, slot: int, prompt_ids: Sequence[int], seed) -> str:
         """Fill the draft lane for a freshly-prefilled request. Normally
         one full un-chunked draft prefill; when migration parked draft
         rows for this prompt (``adopt_draft_rows``), install them
@@ -214,10 +220,10 @@ class SpeculativeDecoder:
             if rows < len(prompt):
                 self.draft.engine.prefill_chunk_call(
                     slot, prompt[rows:], rows, 1.0, None, None, False,
-                    key)
+                    seed)
             self.prime_adopted += 1
             return "adopted"
-        self.draft.prime(slot, prompt, key)
+        self.draft.prime(slot, prompt, seed)
         self.prime_full += 1
         return "full"
 
@@ -270,7 +276,8 @@ class SpeculativeDecoder:
         tokens: np.ndarray,      # (S,) last emitted token per slot
         positions: np.ndarray,   # (S,) its absolute position
         spec_mask: np.ndarray,   # (S,) bool, lanes speculating this round
-        keys,                    # (S,) typed keys (unused: greedy draft)
+        seeds: np.ndarray,       # (S,) request seeds and the round's
+        token_index: np.ndarray,  # (S,) token indices (unused: greedy draft)
     ) -> np.ndarray:
         """k greedy draft decode steps over every speculating lane at
         once; non-speculating lanes ride along parked (their draft rows
@@ -285,7 +292,8 @@ class SpeculativeDecoder:
         out = np.zeros((s, self.k), np.int32)
         for j in range(self.k):
             nxt = self.draft.engine.decode_step(
-                toks, pos, ones_f, zeros_i, ones_f, greedy, keys)
+                toks, pos, ones_f, zeros_i, ones_f, greedy, seeds,
+                token_index)
             out[:, j] = nxt
             toks = np.where(spec_mask, nxt, 0).astype(np.int32)
             pos = np.where(spec_mask, pos + 1, self._parked).astype(np.int32)
@@ -299,7 +307,8 @@ class SpeculativeDecoder:
         temperature: float,
         top_k: Optional[int],
         top_p: Optional[float],
-        key,
+        seed,
+        token_index: int,
     ) -> np.ndarray:
         """One batched target forward over the k+1 rows at
         ``offset..offset+k``; returns the target's greedy choice at every
@@ -315,12 +324,12 @@ class SpeculativeDecoder:
                 "gates eligibility on window headroom)")
         nxt, cache = self._verify_jit(
             self.target.params, self.target.pool.cache,
-            jnp.asarray(np.asarray(row_tokens, np.int32)),
+            np.asarray(row_tokens, np.int32),
             np.int32(offset), np.int32(slot),
             np.float32(temperature),
             np.int32(0 if top_k is None else top_k),
             np.float32(1.0 if top_p is None else top_p),
-            key,
+            request_seeds(seed)[()], np.int32(token_index),
         )
         self.target.pool.cache = cache
         return np.asarray(jax.device_get(nxt))
@@ -340,7 +349,8 @@ class SpeculativeDecoder:
         tokens: np.ndarray,      # (S,) d_k per fully-accepted slot
         positions: np.ndarray,   # (S,) pos + k for those slots
         fill_mask: np.ndarray,   # (S,) bool, fully-accepted lanes
-        keys,
+        seeds: np.ndarray,
+        token_index: np.ndarray,
     ) -> None:
         """On full acceptance the draft cache's row ``pos+k`` was never
         written (the k-th draft step read it as a query input, not a
@@ -354,7 +364,7 @@ class SpeculativeDecoder:
         pos = np.where(fill_mask, positions, self._parked).astype(np.int32)
         self.draft.engine.decode_step(
             toks, pos, np.ones(s, np.float32), np.zeros(s, np.int32),
-            np.ones(s, np.float32), np.zeros(s, bool), keys)
+            np.ones(s, np.float32), np.zeros(s, bool), seeds, token_index)
 
     # -- warmup / accounting -------------------------------------------
     def warmup(self) -> None:
@@ -364,8 +374,7 @@ class SpeculativeDecoder:
         assert self.target.pool.used_count == 0, \
             "spec warmup requires an empty target pool"
         self.draft.engine.warmup()
-        key = jax.random.key(0)
-        self.verify(0, [0] * self.rows, 0, 1.0, None, None, key)
+        self.verify(0, [0] * self.rows, 0, 1.0, None, None, 0, 0)
 
     def compile_counts(self) -> Dict[str, int]:
         """Speculation's program families: verify stays at 1 for the
@@ -386,13 +395,13 @@ class SpeculativeDecoder:
         jit-cache-neutral exactly like ``DecodeEngine.register_attrib``.
         ``family_prefix`` prefixes every family (graftaudit registers a
         quantized decoder beside the fp32 one as ``q8_*``)."""
-        key = jax.random.key(0)
         ledger.register_aot(
             f"{family_prefix}verify", self._verify_jit,
             (self.target.params, self.target.pool.cache,
              jnp.zeros(self.rows, jnp.int32),
              np.int32(0), np.int32(0),
-             np.float32(1.0), np.int32(0), np.float32(1.0), key),
+             np.float32(1.0), np.int32(0), np.float32(1.0),
+             np.uint32(0), np.int32(0)),
             clock, variant=f"k{self.k}")
         self.draft.engine.register_attrib(
             ledger, clock, family_prefix=f"{family_prefix}draft_")

@@ -220,11 +220,10 @@ def test_migrated_quantized_rows_resume_bit_identical(cfg_params):
     with drifting scales the migrated replica would fork."""
     cfg, params = cfg_params
     prompt = list(range(5, 21))  # 16 tokens: a ladder bucket
-    key = jax.random.key(7)
 
     def prefill(eng):
         tok, _ = eng.prefill_chunk_call(
-            0, prompt, 0, 1.0, None, None, False, key)
+            0, prompt, 0, 1.0, None, None, False, 7)
         return int(tok)
 
     def decode(eng, first_tok):
@@ -235,7 +234,7 @@ def test_migrated_quantized_rows_resume_bit_identical(cfg_params):
                 np.asarray([len(prompt) + i], np.int32),
                 np.ones(1, np.float32), np.zeros(1, np.int32),
                 np.ones(1, np.float32), np.zeros(1, bool),
-                jax.random.split(jax.random.key(11 + i), 1),
+                np.asarray([11], np.uint32), np.asarray([i], np.int32),
             )
             tok = int(nxt[0])
             toks.append(tok)

@@ -183,6 +183,172 @@ def test_sampled_request_reproducible_by_seed(cfg_params):
     assert run(extra=False) == run(extra=True)
 
 
+# seeds whose low 32 bits are all jax.random.key keeps: the edges of int32,
+# uint32 and the first bit past them
+KEY_SEEDS = [0, 1, 2**31 - 1, 2**31, 2**32 - 1]
+
+
+@pytest.mark.parametrize("index", [0, 1, 511])
+@pytest.mark.parametrize("seed", KEY_SEEDS)
+def test_lane_keys_match_eager_fold_in(seed, index):
+    """The keys the decode and prefill programs derive inside their traces
+    are, bit for bit, the keys the scheduler used to fold eagerly."""
+    from mingpt_distributed_tpu.serving.engine import lane_keys, request_seeds
+
+    want = np.asarray(jax.random.key_data(
+        jax.random.fold_in(jax.random.key(seed), index)))
+    # the decode program's form: (S,) seeds and indices, other lanes beside
+    seeds = request_seeds([7, seed, 2**40 + seed])
+    assert seeds.dtype == np.uint32 and seeds[1] == seeds[2] == seed
+    got = jax.jit(lambda s, i: jax.random.key_data(lane_keys(s, i)))(
+        seeds, np.array([3, index, index], np.int32))
+    assert np.array_equal(np.asarray(got)[1], want)
+    assert np.array_equal(np.asarray(got)[2], want)
+    if index == 0:
+        # the prefill program's form: one traced scalar seed, index 0
+        one = jax.jit(lambda s: jax.random.key_data(lane_keys(s[None])))(
+            request_seeds(seed)[()])
+        assert np.array_equal(np.asarray(one)[0], want)
+    # typed keys (what the benchmark's check still holds) give the seed back
+    assert request_seeds(jax.random.key(seed)) == seed
+
+
+def test_programs_sample_as_under_eagerly_folded_keys(cfg_params):
+    """Sampled lanes: the programs fed seeds and indices emit what the same
+    programs emit when handed ``fold_in(key(seed), index)`` folded eagerly,
+    the parent's way (typed keys pass through ``lane_keys`` as they are)."""
+    cfg, params = cfg_params
+    from mingpt_distributed_tpu.serving import DecodeEngine
+
+    seeds, prompt = [2**31, 5], [1, 2, 3, 4]
+    by_seed, by_key = (DecodeEngine(params, cfg, 2) for _ in range(2))
+    vec = lambda x, dt: np.full(2, x, dt)
+    toks = {0: [], 1: []}
+    for which, eng in enumerate((by_seed, by_key)):
+        first = []
+        for slot, seed in enumerate(seeds):
+            if which == 0:
+                tok, _ = eng.prefill_chunk_call(
+                    slot, prompt, 0, 1.3, None, None, True, seed)
+            else:
+                padded = np.zeros(eng.bucket_for(len(prompt)), np.int32)
+                padded[:len(prompt)] = prompt
+                tok, eng.pool.cache = eng._prefill_jit(
+                    eng.params, eng.pool.cache, padded, np.int32(len(prompt)),
+                    np.int32(0), np.int32(slot), np.float32(1.3), np.int32(0),
+                    np.float32(1.0), np.bool_(True),
+                    jax.random.fold_in(jax.random.key(seed), 0))
+            first.append(int(tok))
+        cur = np.asarray(first, np.int32)
+        toks[which].append(cur.tolist())
+        for i in range(1, 6):
+            pos = vec(len(prompt) + i - 1, np.int32)
+            if which == 0:
+                cur = eng.decode_step(
+                    cur, pos, vec(1.3, np.float32), vec(0, np.int32),
+                    vec(1.0, np.float32), vec(True, bool),
+                    np.asarray(seeds), vec(i, np.int32))
+            else:
+                keys = jnp.stack([jax.random.fold_in(jax.random.key(s), i)
+                                  for s in seeds])
+                cur, eng.pool.cache = eng._decode_jit(
+                    eng.params, eng.pool.cache, cur, pos,
+                    vec(1.3, np.float32), vec(0, np.int32),
+                    vec(1.0, np.float32), vec(True, bool), keys)
+                cur = np.asarray(cur)
+            toks[which].append(cur.tolist())
+    assert toks[0] == toks[1]
+    # and the two lanes, same prompt and parameters, differ by seed alone
+    assert [t[0] for t in toks[0]] != [t[1] for t in toks[0]]
+
+
+def test_mixed_lanes_and_a_reused_slot_keep_each_requests_seed(cfg_params):
+    """Greedy and sampled lanes in one round, and a slot that a second
+    sampled request with another seed takes over: every greedy request is
+    solo ``generate()``'s, every sampled one is what it is alone in a fresh
+    server (no stale seed in a released slot), and the seed vector holds
+    exactly the live requests' seeds."""
+    cfg, params = cfg_params
+    sampled = dict(prompt=PROMPTS[1], do_sample=True, temperature=1.4,
+                   top_k=20)
+
+    def alone(**kw):
+        srv = InferenceServer(params, cfg, n_slots=2)
+        h = srv.submit(Request(**kw))
+        srv.run_until_drained(max_steps=100)
+        return h.tokens
+
+    server = InferenceServer(params, cfg, n_slots=2)
+    g1 = server.submit(Request(prompt=PROMPTS[0], max_new_tokens=14))
+    s1 = server.submit(Request(max_new_tokens=4, seed=2**32 + 5, **sampled))
+    s2 = server.submit(Request(max_new_tokens=6, seed=9, **sampled))
+    server.step()
+    assert server.slots.seeds.tolist() == [0, 5]  # the low 32 bits
+    while not s1.finished:
+        server.step()
+    while s2.slot is None:
+        server.step()
+    # s2 took s1's slot while g1 still decodes beside it
+    assert s2.slot == 1 and not g1.finished
+    assert server.slots.seeds.tolist() == [0, 9]
+    g2 = server.submit(Request(prompt=PROMPTS[2], max_new_tokens=5))
+    server.run_until_drained(max_steps=100)
+    assert g1.tokens == solo_greedy(params, cfg, PROMPTS[0], 14)
+    assert g2.tokens == solo_greedy(params, cfg, PROMPTS[2], 5)
+    assert s1.tokens == alone(max_new_tokens=4, seed=5, **sampled)
+    assert s2.tokens == alone(max_new_tokens=6, seed=9, **sampled)
+    assert s2.tokens[:4] != s1.tokens
+    assert not server.slots.seeds.any()
+    assert not any(isinstance(v, jax.Array)
+                   for v in vars(server.slots).values())
+
+
+def test_a_round_dispatches_nothing_but_its_programs(cfg_params, monkeypatch):
+    """After warmup the plain server's admit -> prefill -> decode path makes
+    no eager ``jax.random.fold_in`` / ``jax.random.key`` / ``jnp.stack``
+    call: a round is one call of the decode program (plus one prefill
+    program a chunk), and nothing compiles."""
+    cfg, params = cfg_params
+    server = InferenceServer(params, cfg, n_slots=4, warmup=True,
+                             prefill_buckets=(8, 32))
+    counts = server.compile_counts()
+    assert counts["decode"] == 1 and counts["prefill"] <= 2
+    handles = [server.submit(Request(
+        prompt=p, max_new_tokens=9, do_sample=bool(i % 2), seed=i))
+        for i, p in enumerate(PROMPTS[:3])]
+    server.step()
+    assert len(server.slots.decoding_slots()) == 3
+
+    def refuse(name):
+        def raiser(*a, **k):
+            raise AssertionError(f"eager {name} on the serving path")
+        return raiser
+
+    monkeypatch.setattr(jax.random, "fold_in", refuse("jax.random.fold_in"))
+    monkeypatch.setattr(jax.random, "key", refuse("jax.random.key"))
+    monkeypatch.setattr(jnp, "stack", refuse("jnp.stack"))
+    decode_calls = []
+    real_decode = server.engine.decode_step
+    monkeypatch.setattr(
+        server.engine, "decode_step",
+        lambda *a: decode_calls.append(1) or real_decode(*a))
+    rounds = 0
+    for _ in range(3):
+        server.step()
+        rounds += 1
+    # an admission and its prefill in between, then to the end
+    late = server.submit(Request(prompt=PROMPTS[3], max_new_tokens=3,
+                                 do_sample=True, seed=2**31))
+    while server.step():
+        rounds += 1
+    rounds += 1
+    monkeypatch.undo()
+    assert all(h.finished for h in handles) and late.finished
+    assert 0 < len(decode_calls) <= rounds
+    assert server.compile_counts() == counts
+    assert server.watchdog.recompiles == 0
+
+
 def test_long_prompt_cropped_and_max_new_clamped(cfg_params):
     cfg, params = cfg_params
     server = InferenceServer(params, cfg, n_slots=1)
@@ -556,7 +722,7 @@ def test_prefill_flops_scale_with_bucket(cfg_params):
             params, engine.pool.cache,
             jnp.zeros(bucket, jnp.int32), np.int32(1), np.int32(0),
             np.int32(0), np.float32(1.0), np.int32(0), np.float32(1.0),
-            np.bool_(False), jax.random.key(0),
+            np.bool_(False), np.uint32(0),
         )
         compiled = engine._prefill_jit.lower(*args).compile()
         cost = compiled.cost_analysis()

@@ -202,17 +202,18 @@ def test_engine_programs_are_jitted_under_their_own_names(cfg_params, attr, part
     eng = DecodeEngine(params, cfg, 2, prefix_cache_mb=1.0)
     fn = getattr(eng, attr)
     assert part in fn.__name__ and "unknown" not in fn.__name__
-    s, key = eng.n_slots, jax.random.key(0)
+    s = eng.n_slots
     cache = eng.pool.cache
     args = {
         "_decode_jit": lambda: (eng.params, cache, jnp.zeros(s, jnp.int32),
                                 jnp.zeros(s, jnp.int32), jnp.ones(s),
                                 jnp.zeros(s, jnp.int32), jnp.ones(s),
-                                jnp.zeros(s, bool), jnp.stack([key] * s)),
+                                jnp.zeros(s, bool), jnp.zeros(s, jnp.uint32),
+                                jnp.zeros(s, jnp.int32)),
         "_prefill_jit": lambda: (eng.params, cache, jnp.zeros(32, jnp.int32),
                                  np.int32(3), np.int32(0), np.int32(0),
                                  np.float32(1), np.int32(0), np.float32(1),
-                                 np.bool_(False), key),
+                                 np.bool_(False), np.uint32(0)),
         "_extract_jit": lambda: (cache, np.int32(0)),
         "_install_jit": lambda: (
             cache, {n: a[:, :1, :32] for n, a in cache.items()}, np.int32(0)),
@@ -229,14 +230,22 @@ def test_the_verify_program_is_named_too(cfg_params):
     assert srv.spec._verify_jit.__name__ == "_verify_impl"
 
 
-def test_decode_step_stacks_a_list_of_keys_itself(cfg_params):
+def test_decode_step_reads_typed_keys_as_request_keys(cfg_params):
+    """What ``benchmarks/harness/check.py`` still passes: the (S,) typed
+    keys ``key(seed)`` where the seeds go, and no token index. They run the
+    one decode program, as the seeds they were made from."""
     cfg, params = cfg_params
-    keys = [jax.random.key(3), jax.random.key(4)]
+    eng = DecodeEngine(params, cfg, 2)
     outs = []
-    for given in (keys, jnp.stack(keys)):
-        eng = DecodeEngine(params, cfg, 2)
+    for given in (np.array([3, 4], np.uint32),
+                  jnp.stack([jax.random.key(3), jax.random.key(4)])):
         outs.append(eng.decode_step(
             np.array([1, 2], np.int32), np.array([0, 0], np.int32),
             np.ones(2, np.float32), np.zeros(2, np.int32),
             np.ones(2, np.float32), np.ones(2, bool), given))
     assert outs[0].tolist() == outs[1].tolist()
+    assert eng.compile_counts()["decode"] == 1
+    tok, _ = eng.prefill_chunk_call(0, [1, 2, 3], 0, 1.0, None, None, True, 9)
+    again, _ = eng.prefill_chunk_call(
+        0, [1, 2, 3], 0, 1.0, None, None, True, jax.random.key(9))
+    assert tok == again and eng.compile_counts()["prefill"] == 1
