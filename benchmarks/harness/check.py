@@ -5,6 +5,25 @@ equations (``hidden``, ``logits``, ``loss``) and ``weights_from_program``,
 which reads the program's parameters under the reference's own names, so
 nothing here knows an architecture's parameter names.
 
+What a reference owes the check (``sizes`` is the configuration file as
+loaded, so a reference reads its published keys under their own names):
+
+  ``hidden(weights, tokens (B, T) int32, sizes)`` -> ``(x, ks, vs)``: ``x``
+      (B, T, d) the hidden states after the final norm, float32; ``ks`` and
+      ``vs`` (L, B, T, KV, hd) every layer's keys and values *as the
+      architecture caches them*: after any norm of the projected keys and
+      after any rotation by position (a rotary model's keys are cached
+      rotated, so the reference returns them rotated), with the KV heads
+      the architecture keeps (not repeated up to the query heads). The
+      serving check holds the rows the engine's programs left in a slot to
+      exactly these; a cache that is not ``{"k", "v"}`` of this layout (a
+      latent, a state, a window ring) needs a ``benchmark`` PR here first.
+  ``logits(weights, x (..., d))`` -> float32 (..., V), any final softcap in.
+  ``loss(weights, tokens, targets, sizes)`` -> mean cross-entropy over the
+      targets that are not -1 (training cells).
+  ``weights_from_program(params)`` -> the reference's weights: renames of
+      the program's arrays, nothing copied or cast.
+
 Every tolerance stands beside its check with the reason for it. They are
 set from what bf16 arithmetic must give and what the chip measured
 (PERF.md, Findings), tight enough that a lower precision than the
@@ -41,6 +60,13 @@ TRAIN_FIRST_LOSS_TOL = 0.1
 #: 12 layers and 1.32-1.34% at 48, the same to a few percent for every prompt
 #: and seed (my chip runs, PR 22). The tolerance is a quarter above a power
 #: law through both; an int8 or fp8 cache adds about 1% of its own and fails.
+#: The law is a dense block's, and it is the yardstick's: no reference sets
+#: a tolerance for itself. A block that makes a discrete choice (routed
+#: experts) sends the few tokens whose choice is a near-tie another way in
+#: bf16 than the reference does in float32; their rows then differ by tens
+#: of percent and the verdict is false from the first layer after such a
+#: block on (PERF.md, PR 25: what the chip read, and the cure, a check that
+#: follows the program's routing). The per-layer notes of a case say where.
 SERVE_KV_REL_TOL_12_LAYERS = 1.1e-2
 SERVE_KV_DEPTH_POWER = 0.3
 #: Each token the engine emitted greedily must be the reference's best or
@@ -94,8 +120,9 @@ def serve_verdict(reference, sizes: Dict, server, prompts: List[np.ndarray],
         raise RuntimeError("the correctness check needs an empty pool")
     weights = reference.weights_from_program(eng.params)
     n_slots, parked = eng.n_slots, cfg.block_size - 1
-    key = jax.random.key(0)
-    keys = jnp.stack([key] * n_slots)
+    # greedy lanes: the seed is never used, every request's is 0
+    seeds = np.zeros(n_slots, np.uint32)
+    token_index = np.zeros(n_slots, np.int32)
 
     @jax.jit
     def ref_forward(w, seq, n_prompt):
@@ -108,22 +135,33 @@ def serve_verdict(reference, sizes: Dict, server, prompts: List[np.ndarray],
     def kv_errors(cache, ref_k, ref_v, slot, n_rows):
         t = ref_k.shape[1]
         live = (jnp.arange(t) < n_rows)[None, :, None, None]
-        out = []
+        out = {}
         for name, ref in (("k", ref_k), ("v", ref_v)):
             got = jax.lax.dynamic_index_in_dim(
                 cache[name], slot, axis=1, keepdims=False)[:, :t]
             diff = jnp.where(live, got.astype(jnp.float32) - ref, 0.0)
-            out.append(jnp.sqrt(jnp.sum(diff ** 2)
-                                / jnp.sum(jnp.where(live, ref, 0.0) ** 2)))
-            out.append(jnp.max(jnp.abs(diff)))
-        return jnp.stack(out)
+            ref = jnp.where(live, ref, 0.0)
+            out[name + "_rel"] = jnp.sqrt(jnp.sum(diff ** 2)
+                                          / jnp.sum(ref ** 2))
+            out[name + "_max_abs"] = jnp.max(jnp.abs(diff))
+            # the same error a layer: a verdict that fails says where
+            out[name + "_rel_layers"] = jnp.sqrt(
+                jnp.sum(diff ** 2, axis=(1, 2, 3))
+                / jnp.sum(ref ** 2, axis=(1, 2, 3)))
+            # and a layer's median over the live positions of a position's
+            # own error: the bulk of the tokens, whatever a few of them do
+            by_position = jnp.sqrt(jnp.sum(diff ** 2, axis=(2, 3))
+                                   / jnp.sum(ref ** 2, axis=(2, 3)))
+            out[name + "_rel_p50_layers"] = jnp.nanmedian(
+                jnp.where(live[:, :, 0, 0], by_position, jnp.nan), axis=1)
+        return out
 
     cases = []
     for prompt in prompts:
         slot = eng.pool.allocate()
         n = len(prompt)
         tok, bucket = eng.prefill_chunk_call(
-            slot, prompt.tolist(), 0, 1.0, None, None, False, key)
+            slot, prompt.tolist(), 0, 1.0, None, None, False, 0)
         emitted = [tok]
         for i in range(decode_steps):
             tokens = np.zeros(n_slots, np.int32)
@@ -132,7 +170,7 @@ def serve_verdict(reference, sizes: Dict, server, prompts: List[np.ndarray],
             nxt = eng.decode_step(
                 tokens, positions, np.ones(n_slots, np.float32),
                 np.zeros(n_slots, np.int32), np.ones(n_slots, np.float32),
-                np.zeros(n_slots, bool), keys)
+                np.zeros(n_slots, bool), seeds, token_index)
             emitted.append(int(nxt[slot]))
         # the reference runs the prompt and the tokens that were fed back,
         # padded to one length per prefill bucket (causal: the padding after
@@ -144,16 +182,17 @@ def serve_verdict(reference, sizes: Dict, server, prompts: List[np.ndarray],
         seq[:n] = prompt
         seq[n:n + decode_steps] = emitted[:-1]
         ref_logits, ref_k, ref_v = ref_forward(weights, seq, np.int32(n))
-        errs = np.asarray(kv_errors(eng.pool.cache, ref_k, ref_v,
-                                    np.int32(slot), np.int32(n + decode_steps)))
+        errs = jax.device_get(kv_errors(
+            eng.pool.cache, ref_k, ref_v, np.int32(slot),
+            np.int32(n + decode_steps)))
         ref_logits = np.asarray(ref_logits)
         gaps = [float(ref_logits[i].max() - ref_logits[i, t])
                 for i, t in enumerate(emitted)]
         eng.pool.free(slot)
         cases.append({
             "prompt_len": n, "bucket": bucket,
-            "k_rel": float(errs[0]), "k_max_abs": float(errs[1]),
-            "v_rel": float(errs[2]), "v_max_abs": float(errs[3]),
+            **{k: float(v) if v.ndim == 0 else [float(e) for e in v]
+               for k, v in errs.items()},
             "max_logit_gap": max(gaps),
             "tokens_equal_argmax": sum(g == 0.0 for g in gaps),
         })

@@ -46,20 +46,60 @@ from benchmarks.harness import check, spec, trace, traffic
 
 def init_params(gpt_cfg, seed: int):
     """Seeded weights on the device in one jitted call, float32 as the
-    program serves them. The program zero-initialises the learned position
-    table; here it is drawn like the other embeddings, or no check could see
-    a position that is looked up wrongly."""
+    program serves them. Where the architecture has a learned position
+    table the program zero-initialises it; here it is drawn like the other
+    embeddings, or no check could see a position that is looked up wrongly.
+    A rotary model has no table and keeps ``gpt.init``'s parameters as they
+    are, under the same split of the key."""
     import jax
     from mingpt_distributed_tpu.models import gpt
 
     def make(key):
         k_model, k_pos = jax.random.split(key)
         params = gpt.init(k_model, gpt_cfg)
-        params["wpe"] = 0.02 * jax.random.normal(
-            k_pos, params["wpe"].shape, params["wpe"].dtype)
+        if "wpe" in params:
+            params["wpe"] = 0.02 * jax.random.normal(
+                k_pos, params["wpe"].shape, params["wpe"].dtype)
         return params
 
     return jax.jit(make)(jax.random.key(seed))
+
+
+def params_digest(params) -> str:
+    """A digest of every bit of a parameter tree, of any types: a leaf is
+    reduced on the device to the wrapping sum of its words, each times an
+    odd weight that grows with its place, and the leaves' sums are hashed on
+    the host under their names. Two trees with one digest hold the same
+    weights."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    def words(a):
+        if a.dtype == jnp.bool_ or jax.dtypes.itemsize_bits(a.dtype) < 8:
+            a = a.astype(jnp.float32)       # exact for all that is so small
+        bits = jax.lax.bitcast_convert_type(
+            a, jnp.dtype(f"uint{8 * a.dtype.itemsize}"))
+        if bits.dtype.itemsize == 8:        # both halves of a long word
+            bits = (bits >> 32).astype(jnp.uint32) * jnp.uint32(0x9E3779B1) \
+                + bits.astype(jnp.uint32)
+        # the word's place in row-major order, from one iota an axis: no
+        # reshape, so nothing of a leaf's size is ever laid out anew
+        place, stride = jnp.zeros((), jnp.uint32), 1
+        for axis in reversed(range(a.ndim)):
+            place = place + jnp.uint32(stride % (1 << 32)) * \
+                jax.lax.broadcasted_iota(jnp.uint32, a.shape, axis)
+            stride *= a.shape[axis]
+        return jnp.sum(bits.astype(jnp.uint32) * (2 * place + 1),
+                       dtype=jnp.uint32)
+
+    sums = jax.device_get(jax.jit(
+        lambda tree: jax.tree.map(words, tree))(params))
+    h = hashlib.sha256()
+    for path, value in jax.tree_util.tree_flatten_with_path(sums)[0]:
+        h.update(f"{jax.tree_util.keystr(path)}={int(value)};".encode())
+    return h.hexdigest()[:16]
 
 
 @dataclasses.dataclass
@@ -198,17 +238,24 @@ class Driver:
         play.watchdog_recompiles = server.watchdog.recompiles
         return play
 
-    def _counters(self) -> Dict[str, float]:
-        """The program's own counters (``ServingMetrics``), read as they
-        are; a window's value is the difference of two readings."""
-        m = self.server.metrics
+    def _counters(self) -> Dict[str, Optional[float]]:
+        """One reading of the program's own counters: every field of one
+        ``ServingMetrics.summary()`` that is a number, or None (a mean or a
+        rate the program has not formed yet), under the program's name for
+        it, so a counter a later PR adds reaches a reader in a new file with
+        no edit here. The counts are differenced over a window; a level, a
+        mean or a rate is read at one end (``benchmarks/README.md`` says
+        which is which). Beside them three the harness makes: ``lanes``
+        (lane-rounds of decode so far) and the queue and the occupied slots
+        at this moment."""
+        s = self.server.metrics.summary()
         return {
-            "steps": m.steps,
-            "lanes": (m.slot_utilization or 0.0) * m.steps * m.n_slots,
-            "prefill_tokens": m.prefill_tokens,
-            "prefill_padded_tokens": m.prefill_padded_tokens,
-            "queue_depth": len(self.server.queue),
-            "slots_occupied": self.server.slots.occupied,
+            **{k: v for k, v in s.items() if v is None or (
+                isinstance(v, (int, float)) and not isinstance(v, bool))},
+            "lanes": (s["slot_utilization"] or 0.0) * s["steps"]
+            * self.server.metrics.n_slots,
+            "queued_now": len(self.server.queue),
+            "slots_now": self.server.slots.occupied,
         }
 
 
@@ -282,9 +329,9 @@ class Play:
             "offered_req_s": len(attempted) / seconds,
             "generator_late_ms_p50": 1e3 * pct(late, 50),
             "generator_late_ms_p99": 1e3 * pct(late, 99),
-            "queue_open": c0["queue_depth"], "queue_close": c1["queue_depth"],
-            "slots_open": c0["slots_occupied"],
-            "slots_close": c1["slots_occupied"],
+            "queue_open": c0["queued_now"], "queue_close": c1["queued_now"],
+            "slots_open": c0["slots_now"],
+            "slots_close": c1["slots_now"],
             "rounds": self.rounds,
             # how well the bucket ladder fits the traffic: padded over real
             "prefill_pad_ratio": (
@@ -333,6 +380,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     play = driver.play(reqs, seconds, compiles=compiles, traced=traced)
     memory = [d.memory_stats() for d in devices]    # before the check's own
     summary = play.summary()
+    weights_digest = params_digest(driver.server.engine.params)
 
     # -- outside the window: the engine's own programs against the reference
     prompts = check.pick_prompts(reqs, driver.server.engine.buckets,
@@ -365,6 +413,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         "evidence": evidence, "verdict": verdict,
         "memory_stats": memory,
         "notes": {**summary, "traffic_digest": traffic.digest(reqs),
+                  "weights_digest": weights_digest,
                   "requests_generated": len(reqs),
                   "warm_s": t_origin - t_process},
     }

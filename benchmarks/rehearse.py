@@ -48,9 +48,11 @@ if ROOT not in sys.path:
 
 from benchmarks.harness import compiles, device, spec  # noqa: E402
 
-#: the tiny size: every key a cell's code reads, nothing published
-TINY_CONFIG = {"n_layer": 2, "n_head": 3, "n_embd": 96, "n_positions": 128,
-               "vocab_size": 384}
+#: the tiny size, stated once, in the program's own field names; a
+#: configuration whose other fields must shrink with it (KV heads, experts)
+#: says how under ``program.tiny`` in its file
+TINY_PROGRAM = {"n_layer": 2, "n_head": 3, "n_embd": 96, "block_size": 128,
+                "vocab_size": 384}
 TINY_TRAIN = {"global_batch": 8, "seq_len": 128, "stream_tokens": 1 << 15,
               "check_rows": 2, "trace_after_steps": 1, "trace_steps": 2}
 TINY_SERVE = {
@@ -64,13 +66,20 @@ TINY_SERVE = {
 
 
 def tiny(cell: spec.Cell, sizes=None, mix_too: bool = True) -> spec.Cell:
-    config = dict(cell.config, **(sizes or TINY_CONFIG))
-    program = dict(config["program"])
+    """The cell at a tiny size. The program's arguments are shrunk by the
+    program's names; every published key that ``program.key_map`` ties to a
+    field or property of the program's config is then set to what the tiny
+    program has there, whatever the key is called, so ``spec.gpt_config``'s
+    check of published against run holds as it does at full size."""
+    from mingpt_distributed_tpu.config import GPTConfig
+
+    program = cell.config["program"]
     gpt = {k: v for k, v in program["gpt_config"].items() if k != "model_type"}
-    gpt.update(n_layer=config["n_layer"], n_head=config["n_head"],
-               n_embd=config["n_embd"], vocab_size=config["vocab_size"],
-               block_size=config["n_positions"])
-    config["program"] = dict(program, gpt_config=gpt)
+    gpt.update({**TINY_PROGRAM, **program.get("tiny", {}), **(sizes or {})})
+    run = GPTConfig.make(**gpt)
+    config = dict(cell.config, program=dict(program, gpt_config=gpt),
+                  **{published: getattr(run, field)
+                     for published, field in program["key_map"].items()})
     if not mix_too:
         return dataclasses.replace(cell, config=config)
     mix = dict(cell.mix, **(TINY_TRAIN if cell.kind == "train" else TINY_SERVE))
@@ -129,8 +138,8 @@ def rehearse_cycle(cell: spec.Cell, blocks: int = 12) -> None:
     if not k:
         raise spec.SpecError(f"{cell.name}: no closed loop dealt in a cycle")
     cell = tiny(cell, mix_too=False, sizes=dict(
-        TINY_CONFIG, n_layer=1, n_head=2, n_embd=32,
-        n_positions=cell.config["n_positions"]))
+        n_layer=1, n_head=2, n_embd=32,
+        block_size=spec.gpt_config(cell, training=False).block_size))
     cell = dataclasses.replace(cell, mix=dict(cell.mix, ramp_s=0.0))
     driver = serve_cell.Driver(cell, seed=1, traced=False)
     reqs = traffic.requests(cell.mix, driver.gpt_cfg.vocab_size, 1, rate=None,
@@ -266,9 +275,6 @@ def compile_serve(cell: spec.Cell, topo_devices) -> None:
     pool = (cfg.n_layer, n_slots, cfg.block_size, cfg.kv_heads, cfg.head_dim)
     cache = {"k": sds(pool, jnp.dtype(cfg.dtype)),
              "v": sds(pool, jnp.dtype(cfg.dtype))}
-    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
-    keys = on_chip(jax.eval_shape(
-        lambda: jnp.stack([jax.random.key(0)] * n_slots)))
     prefill = jax.jit(functools.partial(engine_mod._prefill_impl, cfg=cfg),
                       donate_argnums=(1,))
     decode = jax.jit(functools.partial(engine_mod._decode_impl, cfg=cfg),
@@ -279,14 +285,15 @@ def compile_serve(cell: spec.Cell, topo_devices) -> None:
             params, cache, sds((int(bucket),), jnp.int32),
             sds((), jnp.int32), sds((), jnp.int32), sds((), jnp.int32),
             sds((), jnp.float32), sds((), jnp.int32), sds((), jnp.float32),
-            sds((), jnp.bool_), key).compile()
+            sds((), jnp.bool_), sds((), jnp.uint32)).compile()
         _report(f"{cell.name}: prefill bucket={bucket} n_slots={n_slots}",
                 compiled, time.perf_counter() - t0)
     t0 = time.perf_counter()
     vec = lambda dtype: sds((n_slots,), dtype)
     compiled = decode.lower(
         params, cache, vec(jnp.int32), vec(jnp.int32), vec(jnp.float32),
-        vec(jnp.int32), vec(jnp.float32), vec(jnp.bool_), keys).compile()
+        vec(jnp.int32), vec(jnp.float32), vec(jnp.bool_), vec(jnp.uint32),
+        vec(jnp.int32)).compile()
     _report(f"{cell.name}: decode n_slots={n_slots}", compiled,
             time.perf_counter() - t0)
 

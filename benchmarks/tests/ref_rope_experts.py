@@ -1,0 +1,146 @@
+"""A rotary, RMSNorm, routed-experts decoder in plain ``jax.numpy`` and
+float32: the reference of the fixture ``fixtures/rope-experts.json``, which
+stands for no published model. It is the proof that an architecture the
+program builds from ``GPTConfig`` enters a serving cell as files, and the
+worked example of a reference that is not GPT-2's (``benchmarks/README.md``,
+"Adding a configuration"). Every matmul runs under
+``jax.default_matmul_precision("highest")``.
+
+The equations (the block ``GPTConfig(rope, rmsnorm, swiglu, n_experts)``
+builds, written from ``models/gpt.py`` and ``ops/moe.py`` and sharing no
+code with them):
+
+  x = wte[tokens]                                 no position table
+  per layer:
+    h = rms(x) * ln1_g
+    q, k, v = h wq, h wk, h wv                    no biases; KV heads <= heads
+    q, k = rotate(q), rotate(k)                   split-half rotary, base theta
+    x = x + softmax(causal(q k^T / sqrt(hd))) v wo
+    h = rms(x) * ln2_g
+    p = softmax(h w_router)                       over all E experts, float32
+    the k largest p, renormalised to sum to 1 (k = 1: the raw p)
+    x = x + sum over the chosen e of
+            gate_e * (silu(h w_eg[e]) * (h w_e1[e])) w_e2[e]
+  x = rms(x) * lnf_g;  logits = x head
+
+Every chosen expert computes its token: there is no capacity and nothing
+drops, which the program matches only at ``moe_capacity_factor >= E / k``.
+Keys are returned as they are cached: rotated.
+
+The verdict. In float32 the program agrees with this file to 2e-5
+(``tests/test_arch.py``). With bf16 activations the first layer's keys and
+values, made before any expert, agree as GPT-2's do; from the second layer
+on a token whose k-th and (k+1)-th router probabilities lie closer than
+bf16's rounding of the residual stream moves them goes to another expert
+than float32 sends it to, its rows differ by tens of percent, and
+``check.py``'s dense law reads ``correct: false`` (on the chip at a
+64-expert model's widths: PERF.md, PR 25). The tolerances are the
+yardstick's and this file states none. The cure is a check that follows the
+program's routing (PERF.md, section 7).
+
+``weights``: wte (V, d), lnf_g (d,), head (d, V); blocks: ln1_g, ln2_g
+(L, d); wq (L, d, H hd); wk, wv (L, d, KV hd); wo (L, H hd, d); w_router
+(L, d, E); w_eg, w_e1 (L, E, d, f); w_e2 (L, E, f, d).
+
+``sizes`` holds the published keys ``num_attention_heads``,
+``num_key_value_heads``, ``num_experts_per_tok``, ``rms_norm_eps`` and
+``rope_theta``.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+
+def weights_from_program(params) -> dict:
+    """The program's parameter pytree (``models/gpt.py``) under the names
+    above. Renames only: the arrays are shared, nothing is copied or cast."""
+    blk = params["blocks"]
+    same = ("wq", "wk", "wv", "wo", "w_router", "w_eg", "w_e1", "w_e2")
+    return {
+        "wte": params["wte"], "head": params["head"],
+        "lnf_g": params["lnf_scale"],
+        "blocks": {"ln1_g": blk["ln1_scale"], "ln2_g": blk["ln2_scale"],
+                   **{k: blk[k] for k in same}},
+    }
+
+
+def _rms(x, g, eps):
+    return x / jnp.sqrt((x ** 2).mean(-1, keepdims=True) + eps) * g
+
+
+def _rotate(x, theta):
+    """(B, T, H, hd) turned by position: the pair (x[i], x[i + hd/2]) by the
+    angle t * theta^(-2i / hd)."""
+    t, hd = x.shape[1], x.shape[-1]
+    half = hd // 2
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * theta ** (
+        -jnp.arange(half, dtype=jnp.float32) / half)
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+
+
+def _experts(h, w, top_k):
+    """(B, T, d) -> (B, T, d): every expert on every token, weighted by its
+    gate, which is zero where the expert was not chosen."""
+    p = jax.nn.softmax(h @ w["w_router"], -1)                   # (B, T, E)
+    top, chosen = jax.lax.top_k(p, top_k)
+    if top_k > 1:
+        top = top / top.sum(-1, keepdims=True)
+    gates = (jax.nn.one_hot(chosen, p.shape[-1]) * top[..., None]).sum(-2)
+    inner = jax.nn.silu(jnp.einsum("btd,edf->btef", h, w["w_eg"])) \
+        * jnp.einsum("btd,edf->btef", h, w["w_e1"])
+    return jnp.einsum("btef,efd,bte->btd", inner, w["w_e2"], gates)
+
+
+def hidden(weights, tokens, sizes):
+    """tokens (B, T) int32 -> (final-RMSNorm hidden (B, T, d), keys (rotated)
+    and values of every layer, each (L, B, T, KV, hd))."""
+    n_head, n_kv = sizes["num_attention_heads"], sizes["num_key_value_heads"]
+    top_k, eps = sizes["num_experts_per_tok"], sizes["rms_norm_eps"]
+    theta = float(sizes["rope_theta"])
+    b, t = tokens.shape
+    x = weights["wte"][tokens]
+    d = x.shape[-1]
+    hd = d // n_head
+    causal = jnp.tril(jnp.ones((t, t), bool))
+
+    def block(x, w):
+        h = _rms(x, w["ln1_g"], eps)
+        q = _rotate((h @ w["wq"]).reshape(b, t, n_head, hd), theta)
+        k = _rotate((h @ w["wk"]).reshape(b, t, n_kv, hd), theta)
+        v = (h @ w["wv"]).reshape(b, t, n_kv, hd)
+        # a query head reads the KV head of its group
+        kq, vq = (jnp.repeat(a, n_head // n_kv, axis=2) for a in (k, v))
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kq) / math.sqrt(hd)
+        scores = jnp.where(causal, scores, -jnp.inf)
+        att = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), vq)
+        x = x + att.reshape(b, t, d) @ w["wo"]
+        x = x + _experts(_rms(x, w["ln2_g"], eps), w, top_k)
+        return x, (k, v)
+
+    with jax.default_matmul_precision("highest"):
+        x, (ks, vs) = jax.lax.scan(block, x.astype(jnp.float32),
+                                   weights["blocks"])
+        x = _rms(x, weights["lnf_g"], eps)
+    return x, ks, vs
+
+
+def logits(weights, x):
+    """Hidden states (..., d) -> float32 logits (..., V)."""
+    with jax.default_matmul_precision("highest"):
+        return x @ weights["head"]
+
+
+def loss(weights, tokens, targets, sizes):
+    """Mean cross-entropy over the positions whose target is not -1."""
+    x, _, _ = hidden(weights, tokens, sizes)
+    logp = jax.nn.log_softmax(logits(weights, x), -1)
+    valid = targets != -1
+    picked = jnp.take_along_axis(
+        logp, jnp.where(valid, targets, 0)[..., None], -1)[..., 0]
+    return -(picked * valid).sum() / valid.sum()

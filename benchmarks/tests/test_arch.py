@@ -1,0 +1,259 @@
+"""The harness takes any architecture ``GPTConfig`` builds, as files: the
+fixture ``fixtures/rope-experts.json`` (rotary, RMSNorm, routed SwiGLU
+experts, keys under other names than GPT-2's) with its reference
+``ref_rope_experts.py`` goes through ``Driver``, ``play`` and
+``check.serve_verdict`` with no edit to the harness; and the GPT-2 cells get
+from the generalised code what they got before it."""
+
+import dataclasses
+import json
+import os
+
+import jax
+import numpy as np
+import pytest
+
+from benchmarks import rehearse
+from benchmarks.harness import check, compiles, serve_cell, spec
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+SEED = 2_500_000_001        # past 32 signed bits, as the driver's seeds are
+
+
+def fixture_cell(**found) -> spec.Cell:
+    """The fixture under the decode mix: built here, as ``spec.load_cell``
+    builds a cell from ``BENCHMARK.json``, which never lists the fixture."""
+    with open(os.path.join(HERE, "fixtures", "rope-experts.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(spec.BENCH, "mixes", "serve-decode.json")) as f:
+        mix = json.load(f)
+    return spec.Cell(
+        name="rope-experts.serve-decode", chips=1, config=config, mix=mix,
+        found={"server": {"n_slots": 16}, "rate_req_s": 1.5, **found},
+        end_to_end=[], per_layer=[])
+
+
+def leaves_equal(a, b) -> bool:
+    return jax.tree.structure(a) == jax.tree.structure(b) and all(
+        np.array_equal(x, y) for x, y in
+        zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
+# -- the fixture's reference -------------------------------------------------
+
+@pytest.mark.parametrize("n_kv_head,top_k", [(4, 2), (2, 3), (4, 1)])
+def test_the_fixture_s_reference_is_the_program_s_block_in_float32(
+        n_kv_head, top_k):
+    """Two implementations of one set of equations, float32 on both sides,
+    capacity never binding: grouped KV heads, renormalised gates for k > 1
+    and the raw probability for k = 1."""
+    from mingpt_distributed_tpu.config import GPTConfig
+    from mingpt_distributed_tpu.models import gpt
+
+    reference = spec.load_reference({"reference": "tests/ref_rope_experts.py"})
+    cfg = GPTConfig.make(
+        n_layer=3, n_head=4, n_kv_head=n_kv_head, n_embd=64, vocab_size=211,
+        block_size=32, dtype="float32", embd_pdrop=0.0, resid_pdrop=0.0,
+        attn_pdrop=0.0, rope=True, rope_theta=500.0, rmsnorm=True,
+        swiglu=True, ffn_mult=0.5, n_experts=6, moe_top_k=top_k,
+        moe_capacity_factor=6.0 / top_k, moe_aux_weight=0.0)
+    sizes = {"num_attention_heads": 4, "num_key_value_heads": n_kv_head,
+             "num_experts_per_tok": top_k, "rms_norm_eps": 1e-5,
+             "rope_theta": 500.0}
+    params = gpt.init(jax.random.key(0), cfg)
+    assert "wpe" not in params
+    # the norms' scales off their trivial init, the router's logits apart
+    params = jax.tree.map(
+        lambda a: a + 0.01 * jax.random.normal(jax.random.key(2), a.shape),
+        params)
+    params["blocks"]["w_router"] = 20.0 * params["blocks"]["w_router"]
+    tokens = jax.random.randint(jax.random.key(3), (2, 32), 0, 211)
+    targets = jax.numpy.where(jax.numpy.arange(32) % 5 == 0, -1,
+                              jax.numpy.roll(tokens, -1, 1))
+    want_logits, want_loss = gpt.forward(params, tokens, cfg, targets=targets)
+
+    weights = reference.weights_from_program(params)
+    x, ks, vs = reference.hidden(weights, tokens, sizes)
+    np.testing.assert_allclose(reference.logits(weights, x), want_logits,
+                               atol=2e-5)
+    assert ks.shape == vs.shape == (3, 2, 32, n_kv_head, 16)
+    np.testing.assert_allclose(
+        reference.loss(weights, tokens, targets, sizes), want_loss, rtol=1e-5)
+
+
+# -- weights ------------------------------------------------------------------
+
+def test_gpt2_weights_are_the_earlier_recipe_s_bit_for_bit():
+    from mingpt_distributed_tpu.models import gpt
+
+    cfg = spec.gpt_config(rehearse.tiny(spec.load_cell(
+        "gpt2-124m.serve-decode")), training=False)
+
+    def earlier(key):       # serve_cell.init_params as PR 22 wrote it
+        k_model, k_pos = jax.random.split(key)
+        params = gpt.init(k_model, cfg)
+        params["wpe"] = 0.02 * jax.random.normal(
+            k_pos, params["wpe"].shape, params["wpe"].dtype)
+        return params
+
+    got = serve_cell.init_params(cfg, SEED)
+    assert leaves_equal(got, jax.jit(earlier)(jax.random.key(SEED)))
+    assert float(np.abs(got["wpe"]).max()) > 0.0    # drawn, not the zeros
+
+
+def test_rotary_weights_have_no_position_table_and_are_gpt_init_s():
+    from mingpt_distributed_tpu.models import gpt
+
+    cfg = spec.gpt_config(rehearse.tiny(fixture_cell()), training=False)
+    got = serve_cell.init_params(cfg, SEED)
+    assert "wpe" not in got
+    k_model, _ = jax.random.split(jax.random.key(SEED))
+    assert leaves_equal(got, jax.jit(lambda k: gpt.init(k, cfg))(k_model))
+
+
+def test_params_digest_sees_a_seed_and_a_single_word():
+    cfg = spec.gpt_config(rehearse.tiny(fixture_cell()), training=False)
+    a = serve_cell.init_params(cfg, SEED)
+    assert serve_cell.params_digest(a) == serve_cell.params_digest(
+        serve_cell.init_params(cfg, SEED))
+    assert serve_cell.params_digest(a) != serve_cell.params_digest(
+        serve_cell.init_params(cfg, SEED + 1))
+    # two words of one leaf swapped: the plain sum of the words stands
+    wte = np.array(a["wte"])
+    wte[0, 0], wte[0, 1] = wte[0, 1], wte[0, 0]
+    assert serve_cell.params_digest(a) != serve_cell.params_digest(
+        dict(a, wte=jax.numpy.asarray(wte)))
+
+
+def test_params_digest_takes_leaves_of_any_type():
+    jnp = jax.numpy
+    tree = {"q": jnp.arange(-8, 8, dtype=jnp.int8).reshape(4, 4),
+            "s": jnp.linspace(0, 1, 6, dtype=jnp.bfloat16),
+            "n": jnp.arange(16, dtype=jnp.int4),
+            "m": jnp.arange(5) % 2 == 0, "f": jnp.float8_e4m3fn(0.5)}
+    base = serve_cell.params_digest(tree)
+    assert len(base) == 16
+    for name, leaf in tree.items():
+        other = (~leaf if leaf.dtype == bool else
+                 (leaf.astype(jnp.float32) + 1).astype(leaf.dtype))
+        assert serve_cell.params_digest(dict(tree, **{name: other})) != base
+
+
+# -- rehearsal by the map -----------------------------------------------------
+
+@pytest.mark.parametrize("name", ["gpt2-124m.serve-decode",
+                                  "gpt2-xl.serve-prefill",
+                                  "gpt2-124m.train-1chip",
+                                  "gpt2-xl.train-fsdp4"])
+def test_tiny_gives_the_gpt2_cells_the_sizes_it_gave_them(name):
+    from mingpt_distributed_tpu.config import GPTConfig
+
+    cell = rehearse.tiny(spec.load_cell(name))
+    assert {k: cell.config[k] for k in (
+        "n_layer", "n_head", "n_embd", "n_positions", "vocab_size")} == {
+        "n_layer": 2, "n_head": 3, "n_embd": 96, "n_positions": 128,
+        "vocab_size": 384}
+    assert spec.gpt_config(cell, training=False) == GPTConfig.make(
+        n_layer=2, n_head=3, n_embd=96, vocab_size=384, block_size=128,
+        norm_eps=1e-5, dtype="bfloat16", attention="flash",
+        unroll_layers=True, tie_weights=False,
+        embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0)
+
+
+def test_tiny_shrinks_a_configuration_of_other_key_names_through_its_map():
+    cell = rehearse.tiny(fixture_cell())
+    assert {k: cell.config[k] for k in cell.config["program"]["key_map"]} == {
+        "num_hidden_layers": 2, "num_attention_heads": 3,
+        "num_key_value_heads": 3, "head_dim": 32, "hidden_size": 96,
+        "max_position_embeddings": 128, "vocab_size": 384,
+        "rms_norm_eps": 1e-5, "rope_theta": 10000.0,
+        "num_experts": 8, "num_experts_per_tok": 2}
+    cfg = spec.gpt_config(cell, training=False)
+    assert (cfg.n_layer, cfg.n_head, cfg.n_embd, cfg.block_size,
+            cfg.vocab_size) == (2, 3, 96, 128, 384)
+    assert cfg.rope and cfg.rmsnorm and cfg.swiglu
+    # sizes of a caller (the cycle rehearsal's) go on top, by program name
+    one = rehearse.tiny(fixture_cell(), sizes={"n_layer": 1, "n_head": 2,
+                                               "n_embd": 32}, mix_too=False)
+    assert (one.config["num_hidden_layers"], one.config["hidden_size"],
+            one.config["head_dim"]) == (1, 32, 16)
+    assert one.mix == fixture_cell().mix
+
+
+def test_a_key_map_target_may_be_a_property_and_is_held_like_a_field():
+    cell = fixture_cell()
+    cfg = spec.gpt_config(cell, training=False)     # head_dim, kv_heads
+    assert (cfg.head_dim, cfg.kv_heads) == (128, 16)
+    wrong = dataclasses.replace(cell, config=dict(cell.config, head_dim=64))
+    with pytest.raises(spec.SpecError, match="head_dim"):
+        spec.gpt_config(wrong, training=False)
+
+
+# -- the fixture through Driver, play and the check ---------------------------
+
+@pytest.fixture(scope="module")
+def fixture_run():
+    return serve_cell.run(
+        rehearse.tiny(fixture_cell()), seed=SEED, seconds=1.0, traced=False,
+        devices=jax.devices()[:1], t_process=0.0,
+        compiles=compiles.CompileCounter())
+
+
+def test_the_fixture_agrees_with_its_reference_through_the_whole_path(
+        fixture_run):
+    verdict = fixture_run["verdict"]
+    assert verdict["ok"], verdict
+    assert len(verdict["cases"]) == 3
+    for case in verdict["cases"]:
+        # the same error a layer, so that a failing verdict says where
+        assert len(case["k_rel_layers"]) == len(case["v_rel_layers"]) == 2
+        assert max(case["k_rel_layers"]) <= verdict["kv_rel_tol"]
+    notes = fixture_run["notes"]
+    assert len(notes["weights_digest"]) == 16 and notes["traffic_digest"]
+
+
+def test_play_carries_every_field_of_the_program_s_summary(fixture_run):
+    play = fixture_run["evidence"]["play"]
+    assert play.trace_open is None and play.trace_close is None   # untraced
+    opened, closed = play.open_counters, play.close_counters
+    assert {"steps", "tokens_generated", "prefill_tokens", "prefix_hits",
+            "spec_accepted", "requests_completed", "slot_utilization",
+            "lanes", "queued_now", "slots_now"} <= set(opened)
+    # one set of names at both ends: what the program has not formed yet is
+    # None, not missing; a dict of the summary's is no counter
+    assert set(opened) == set(closed)
+    assert opened["spec_accept_rate"] is None
+    assert "bucket_histogram" not in opened
+    assert all(v is None or (isinstance(v, (int, float))
+                             and not isinstance(v, bool))
+               for v in closed.values())
+    # the program's own count of rounds between the window's two ends
+    assert closed["steps"] - opened["steps"] > 0
+    assert closed["lanes"] == pytest.approx(
+        closed["slot_utilization"] * closed["steps"] * play.n_slots)
+
+
+@pytest.mark.parametrize("factor,ok", [(4.0, True), (1.0, False),
+                                       (0.25, False)])
+def test_the_verdict_fails_where_prefill_drops_routes(factor, ok):
+    """16 experts, 4 a token, at a width where the experts carry the residual
+    stream, in float32 so that no near-tie of the router is rounded another
+    way than the reference's: capacity never binds at E/k; just under it a
+    prefill drops a few routes, at a sixteenth of it most, and the dense law
+    of ``check.py`` fails both."""
+    cell = rehearse.tiny(fixture_cell(), sizes={
+        "n_layer": 3, "n_head": 4, "n_embd": 256, "n_experts": 16,
+        "moe_top_k": 4, "moe_capacity_factor": factor, "dtype": "float32"})
+    driver = serve_cell.Driver(cell, SEED, traced=False)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 384, size=n, dtype=np.int32) for n in (24, 40)]
+    verdict = check.serve_verdict(spec.load_reference(cell.config),
+                                  cell.config, driver.server, prompts, 4)
+    assert verdict["ok"] is ok, verdict
+    assert verdict["kv_rel_tol"] == pytest.approx(0.011 * (3 / 12) ** 0.3)
+    for case in verdict["cases"]:
+        # the first layer's rows are made before any expert: they agree
+        # either way, and the notes say that the error starts after the drop
+        assert case["k_rel_layers"][0] < 1e-5
+        if not ok:
+            assert case["k_rel_layers"][1] > verdict["kv_rel_tol"]

@@ -35,8 +35,11 @@ def test_cell_loads_and_its_metrics_fit_the_contract(name):
 def test_the_program_runs_the_published_sizes(name):
     cell = spec.load_cell(name)
     cfg = spec.gpt_config(cell, training=cell.kind == "train")
-    assert (cfg.n_layer, cfg.n_head, cfg.n_embd) == (
-        cell.config["n_layer"], cell.config["n_head"], cell.config["n_embd"])
+    key_map = cell.config["program"]["key_map"]
+    # depth, heads and width are mapped whatever the source calls them
+    assert {"n_layer", "n_head", "n_embd"} <= set(key_map.values())
+    for published, field in key_map.items():
+        assert getattr(cfg, field) == cell.config[published], published
     assert cfg.attn_pdrop == 0.0
     assert (cfg.resid_pdrop > 0) == (cell.kind == "train")
 
