@@ -18,6 +18,15 @@ loaded, so a reference reads its published keys under their own names):
       serving check holds the rows the engine's programs left in a slot to
       exactly these; a cache that is not ``{"k", "v"}`` of this layout (a
       latent, a state, a window ring) needs a ``benchmark`` PR here first.
+      Where the block routes each token to k of E experts (the program's
+      ``GPTConfig.n_experts`` is set), ``hidden`` owes two things more, and
+      a reference that lacks them is an error there, not a dense check: it
+      takes ``experts=`` (L, B, T, k) int32, the experts every token takes
+      in every layer (None: the router's own k best), and returns a fourth
+      array, the router's float32 logits (L, B, T, E). Nothing else: no
+      tolerance, no margin, no law. ``serve_verdict`` then has the reference
+      route as the program routed (``follow_routes``) before it holds every
+      layer to the dense law. A dense reference owes neither.
   ``logits(weights, x (..., d))`` -> float32 (..., V), any final softcap in.
   ``loss(weights, tokens, targets, sizes)`` -> mean cross-entropy over the
       targets that are not -1 (training cells).
@@ -33,8 +42,10 @@ configuration states (an int8 or fp8 cache or matmul) would fail.
 from __future__ import annotations
 
 import functools
+import inspect
+import itertools
 import math
-from typing import Dict, List, Sequence
+from typing import Callable, Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -62,13 +73,36 @@ TRAIN_FIRST_LOSS_TOL = 0.1
 #: law through both; an int8 or fp8 cache adds about 1% of its own and fails.
 #: The law is a dense block's, and it is the yardstick's: no reference sets
 #: a tolerance for itself. A block that makes a discrete choice (routed
-#: experts) sends the few tokens whose choice is a near-tie another way in
-#: bf16 than the reference does in float32; their rows then differ by tens
-#: of percent and the verdict is false from the first layer after such a
-#: block on (PERF.md, PR 25: what the chip read, and the cure, a check that
-#: follows the program's routing). The per-layer notes of a case say where.
+#: experts) is held to the same law once the reference has made the
+#: program's choices (``follow_routes``, below).
 SERVE_KV_REL_TOL_12_LAYERS = 1.1e-2
 SERVE_KV_DEPTH_POWER = 0.3
+#: Routed experts: how far from the reference's own choice the program's
+#: choice of experts may lie and still be followed. The router's logit of an
+#: expert is z = h . w, h the normed residual stream. Where the program's h
+#: is off by a relative error eps in no particular direction, the difference
+#: of two experts' logits moves by eps * sqrt(2) * s (one standard
+#: deviation), s being the scale of one logit, |h| |w| / sqrt(d): read here
+#: as the spread of a layer's logits about their mean over the experts. The
+#: law above lets a layer's input be off by ``kv_rel_tol``, and behind an
+#: expert layer the chip's rows are off by just that (0.72-0.90% a layer
+#: against 0.79%, every position alike: PERF.md, PR 26). A token is followed
+#: to another set of k experts only where every pair of logits the swap
+#: turns over lies within SERVE_ROUTE_MARGIN * kv_rel_tol * s:
+#: SERVE_ROUTE_MARGIN / sqrt(2) = 5.7 standard deviations of the rounding the
+#: law allows. Set from that arithmetic and from the chip: of 549 flips
+#: followed on the probe the widest lay 4.08 of these units apart, and a
+#: margin of 16 found the same 549 (PERF.md, PR 26). The margin fences which
+#: routes may be tried and forgives nothing: a program that routes outside
+#: it, drops a route, weighs a gate otherwise or caches in a lower precision
+#: matches no admissible set and fails by the dense law.
+SERVE_ROUTE_MARGIN = 8.0
+#: The sets of experts a token may try in one layer beside the reference's
+#: own, nearest first; each is one more forward of the reference for all
+#: tokens at once (40 ms on the chip at the probe's size). Sets beyond these
+#: are counted in the notes: a token has more only with three logits on one
+#: side of the boundary inside the margin and two on the other.
+SERVE_ROUTE_TRIES = 8
 #: Each token the engine emitted greedily must be the reference's best or
 #: within this of it, in the reference's own logits. With seeded weights the
 #: best two logits are often closer than bf16 resolves, so tokens are never
@@ -104,13 +138,116 @@ def train_verdict(eval_loss: float, ref_loss: float,
     return out
 
 
+def route_alternatives(logits: np.ndarray, top_k: int,
+                       margin: float) -> List[Tuple[float, np.ndarray]]:
+    """The other sets of ``top_k`` experts one token may have been sent to:
+    ``[(gap, experts), ...]``, smallest gap first. A set is admissible when
+    every expert it drops from the router's own k best lies within
+    ``margin`` (in logits) of every expert it takes in their place; its gap
+    is the largest such difference, the inversion the program must have
+    made."""
+    order = np.argsort(-logits, kind="stable")
+    own, rest = order[:top_k], order[top_k:]
+    if not len(rest):
+        return []
+    drop = [e for e in own[::-1] if logits[e] - logits[rest[0]] <= margin]
+    take = [e for e in rest if logits[own[-1]] - logits[e] <= margin]
+    found = []
+    for n in range(1, min(len(drop), len(take)) + 1):
+        for out in itertools.combinations(drop, n):
+            for into in itertools.combinations(take, n):
+                gap = float(max(logits[list(out)]) - min(logits[list(into)]))
+                if gap <= margin:
+                    kept = [e for e in own if e not in out]
+                    found.append((gap, np.asarray(kept + list(into),
+                                                  own.dtype)))
+    return sorted(found, key=lambda f: f[0])
+
+
+def follow_routes(forward: Callable, row_distance: Callable, n_layer: int,
+                  n_positions: int, top_k: int, n_prompt: int,
+                  emitted: Sequence[int], kv_tol: float):
+    """The table of experts (L, T, k) under which the reference goes the way
+    the program went, and the notes of how it was found.
+
+    ``forward(table)`` is one forward of the reference over the checked
+    sequence: ``(logits of the rows that emitted a token, ks, vs, router
+    logits (L, T, E))``. ``row_distance(ks, vs, layer)`` is every position's
+    squared relative distance between the reference's keys and values of
+    that layer and the rows the program cached.
+
+    Layer by layer, the layers below fixed. Row t of layer l+1's keys and
+    values is made of x_{l+1}(t) alone, and with everything before layer l
+    fixed that depends on token t's own experts at layer l alone: one
+    expert swapped moves the row by tens of percent, bf16 rounding by about
+    one. So each token whose logits leave a choice inside the margin takes,
+    of its admissible sets, the one whose layer-l+1 rows lie nearest the
+    program's; all such tokens try their n-th alternative in one forward.
+    Rows written by prefill and by decode are treated alike. The last
+    layer's experts feed nothing cached: there the rows that emitted a
+    token take the set under which that token's logit gap is least."""
+    n_rows = n_prompt + len(emitted) - 1          # rows the program cached
+    emitting = np.arange(n_prompt - 1, n_rows)
+    table = np.broadcast_to(np.arange(top_k, dtype=np.int32),
+                            (n_layer, n_positions, top_k)).copy()
+    notes: Dict[str, list] = {}
+    for layer in range(n_layer):
+        # the table's rows from this layer on are not yet the reference's
+        # choice, and this layer's logits do not depend on them
+        router = np.asarray(forward(table)[3][layer])
+        live = router[:n_rows]
+        spread = float(np.sqrt(np.mean(
+            (live - live.mean(-1, keepdims=True)) ** 2)))
+        margin = SERVE_ROUTE_MARGIN * kv_tol * spread
+        table[layer] = np.argsort(-router, axis=-1, kind="stable")[:, :top_k]
+        last = layer == n_layer - 1
+        # position -> the sets it may take, [(gap, experts), ...], its own
+        # first: only positions with a choice
+        choices, cut = {}, 0
+        for t in (emitting if last else range(n_rows)):
+            others = route_alternatives(router[t], top_k, margin)
+            if others:
+                choices[int(t)] = [(0.0, table[layer, t].copy()),
+                                   *others[:SERVE_ROUTE_TRIES]]
+                cut += max(0, len(others) - SERVE_ROUTE_TRIES)
+        scores = np.full((max(map(len, choices.values()), default=0),
+                          n_positions), np.inf)
+        for c in range(len(scores)):
+            trying = [t for t, sets in choices.items() if c < len(sets)]
+            trial = table.copy()
+            for t in trying:
+                trial[layer, t] = choices[t][c][1]
+            out = forward(trial)
+            if last:
+                rows = np.asarray(out[0])
+                score = np.full(n_positions, np.inf)
+                score[emitting] = rows.max(-1) - rows[
+                    np.arange(len(emitted)), list(emitted)]
+            else:
+                score = np.asarray(row_distance(out[1], out[2], layer + 1))
+            scores[c, trying] = score[trying]
+        best = {t: int(np.argmin(scores[:, t])) for t in choices}
+        for t, c in best.items():
+            table[layer, t] = choices[t][c][1]
+        gaps = [choices[t][c][0] for t, c in best.items() if c]
+        for key, value in (("route_margin_layers", margin),
+                           ("route_banded_layers", len(choices)),
+                           ("route_followed_layers", len(gaps)),
+                           ("route_gap_max_layers", max(gaps, default=0.0)),
+                           ("route_cut_layers", cut)):
+            notes.setdefault(key, []).append(value)
+    return table, notes
+
+
 def serve_verdict(reference, sizes: Dict, server, prompts: List[np.ndarray],
                   decode_steps: int) -> Dict:
     """Prefill each prompt into slot 0 and decode ``decode_steps`` tokens with
     the engine's own compiled programs, then hold the slot's cache rows and
     the emitted tokens to the reference's full forward over the same
-    sequence. The pool must be empty: the check takes a slot as a request
-    would and gives it back."""
+    sequence; where the reference brings the routed contract, to its
+    forward under the program's routes (``follow_routes``), whose notes then
+    stand in each case. The pool must be empty: the check takes a slot as a
+    request would and gives it back."""
     import jax
     import jax.numpy as jnp
 
@@ -118,6 +255,14 @@ def serve_verdict(reference, sizes: Dict, server, prompts: List[np.ndarray],
     cfg = eng.cfg
     if eng.pool.used_count:
         raise RuntimeError("the correctness check needs an empty pool")
+    routed = "experts" in inspect.signature(reference.hidden).parameters
+    if cfg.n_experts and not routed:
+        raise RuntimeError(
+            "the program routes experts (n_experts is set) and the "
+            "reference's hidden() takes no experts= table: it owes the "
+            "routed contract (harness/check.py)")
+    kv_tol = SERVE_KV_REL_TOL_12_LAYERS * (
+        cfg.n_layer / 12.0) ** SERVE_KV_DEPTH_POWER
     weights = reference.weights_from_program(eng.params)
     n_slots, parked = eng.n_slots, cfg.block_size - 1
     # greedy lanes: the seed is never used, every request's is 0
@@ -125,11 +270,27 @@ def serve_verdict(reference, sizes: Dict, server, prompts: List[np.ndarray],
     token_index = np.zeros(n_slots, np.int32)
 
     @jax.jit
-    def ref_forward(w, seq, n_prompt):
-        x, ks, vs = reference.hidden(w, seq[None], sizes)
+    def ref_forward(w, seq, n_prompt, experts=None):
+        """Dense: (logits, ks, vs). Routed: and the router's logits."""
+        routes = {} if experts is None else {"experts": experts[:, None]}
+        x, ks, vs, *router = reference.hidden(w, seq[None], sizes, **routes)
         rows = jax.lax.dynamic_slice_in_dim(
             x[0], n_prompt - 1, decode_steps + 1, axis=0)
-        return reference.logits(w, rows), ks[:, 0], vs[:, 0]
+        return (reference.logits(w, rows),
+                *(a[:, 0] for a in (ks, vs, *router)))
+
+    @jax.jit
+    def row_distance(cache, ref_k, ref_v, slot, layer):
+        total = 0.0
+        for name, ref in (("k", ref_k), ("v", ref_v)):
+            ref = jax.lax.dynamic_index_in_dim(ref, layer, keepdims=False)
+            got = jax.lax.dynamic_index_in_dim(
+                jax.lax.dynamic_index_in_dim(cache[name], layer,
+                                             keepdims=False),
+                slot, keepdims=False)[:ref.shape[0]]
+            total += jnp.sum((got.astype(jnp.float32) - ref) ** 2, (1, 2)) \
+                / jnp.sum(ref ** 2, (1, 2))
+        return total
 
     @jax.jit
     def kv_errors(cache, ref_k, ref_v, slot, n_rows):
@@ -181,7 +342,17 @@ def serve_verdict(reference, sizes: Dict, server, prompts: List[np.ndarray],
         seq = np.zeros(t_pad, np.int32)
         seq[:n] = prompt
         seq[n:n + decode_steps] = emitted[:-1]
-        ref_logits, ref_k, ref_v = ref_forward(weights, seq, np.int32(n))
+        routes = {}
+        if routed:
+            table, routes = follow_routes(
+                lambda experts: ref_forward(weights, seq, np.int32(n), experts),
+                lambda ks, vs, layer: row_distance(
+                    eng.pool.cache, ks, vs, np.int32(slot), np.int32(layer)),
+                cfg.n_layer, t_pad, cfg.moe_top_k, n, emitted, kv_tol)
+            ref_logits, ref_k, ref_v, _ = ref_forward(
+                weights, seq, np.int32(n), table)
+        else:
+            ref_logits, ref_k, ref_v = ref_forward(weights, seq, np.int32(n))
         errs = jax.device_get(kv_errors(
             eng.pool.cache, ref_k, ref_v, np.int32(slot),
             np.int32(n + decode_steps)))
@@ -195,9 +366,8 @@ def serve_verdict(reference, sizes: Dict, server, prompts: List[np.ndarray],
                for k, v in errs.items()},
             "max_logit_gap": max(gaps),
             "tokens_equal_argmax": sum(g == 0.0 for g in gaps),
+            **routes,
         })
-    kv_tol = SERVE_KV_REL_TOL_12_LAYERS * (
-        cfg.n_layer / 12.0) ** SERVE_KV_DEPTH_POWER
     ok = all(c["k_rel"] <= kv_tol and c["v_rel"] <= kv_tol
              and c["max_logit_gap"] <= SERVE_LOGIT_GAP_TOL for c in cases)
     return {"ok": bool(ok and cases), "kv_rel_tol": kv_tol, "cases": cases}

@@ -17,8 +17,11 @@ code with them):
     q, k = rotate(q), rotate(k)                   split-half rotary, base theta
     x = x + softmax(causal(q k^T / sqrt(hd))) v wo
     h = rms(x) * ln2_g
-    p = softmax(h w_router)                       over all E experts, float32
-    the k largest p, renormalised to sum to 1 (k = 1: the raw p)
+    z = h w_router                                E router logits, float32
+    p = softmax(z)                                over all E experts
+    the chosen k: those of the table the caller hands over, or, with no
+    table, the k largest p; their p renormalised to sum to 1 (k = 1, or
+    ``norm_topk_prob`` false: the raw p)
     x = x + sum over the chosen e of
             gate_e * (silu(h w_eg[e]) * (h w_e1[e])) w_e2[e]
   x = rms(x) * lnf_g;  logits = x head
@@ -27,24 +30,24 @@ Every chosen expert computes its token: there is no capacity and nothing
 drops, which the program matches only at ``moe_capacity_factor >= E / k``.
 Keys are returned as they are cached: rotated.
 
-The verdict. In float32 the program agrees with this file to 2e-5
-(``tests/test_arch.py``). With bf16 activations the first layer's keys and
-values, made before any expert, agree as GPT-2's do; from the second layer
-on a token whose k-th and (k+1)-th router probabilities lie closer than
-bf16's rounding of the residual stream moves them goes to another expert
-than float32 sends it to, its rows differ by tens of percent, and
-``check.py``'s dense law reads ``correct: false`` (on the chip at a
-64-expert model's widths: PERF.md, PR 25). The tolerances are the
-yardstick's and this file states none. The cure is a check that follows the
-program's routing (PERF.md, section 7).
+The routed contract (``harness/check.py``). ``hidden`` takes an optional
+table of experts ``(L, B, T, k)`` and also returns the router's logits
+``(L, B, T, E)``: with bf16 activations a token whose k-th and (k+1)-th
+logits lie closer than the rounding of the residual stream moves them goes
+to another expert in the program than float32 sends it to, and the check
+reads off the next layer's cached rows which way the program went and has
+this file go the same way. The tolerances and the margin inside which a
+route may be followed are the yardstick's; this file states none. In
+float32 the program agrees with this file to 2e-5 (``tests/test_arch.py``),
+with a table that holds its own choice as without one.
 
 ``weights``: wte (V, d), lnf_g (d,), head (d, V); blocks: ln1_g, ln2_g
 (L, d); wq (L, d, H hd); wk, wv (L, d, KV hd); wo (L, H hd, d); w_router
 (L, d, E); w_eg, w_e1 (L, E, d, f); w_e2 (L, E, f, d).
 
 ``sizes`` holds the published keys ``num_attention_heads``,
-``num_key_value_heads``, ``num_experts_per_tok``, ``rms_norm_eps`` and
-``rope_theta``.
+``num_key_value_heads``, ``num_experts_per_tok``, ``rms_norm_eps``,
+``rope_theta`` and, optionally, ``norm_topk_prob`` (absent: true).
 """
 
 from __future__ import annotations
@@ -84,32 +87,41 @@ def _rotate(x, theta):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
-def _experts(h, w, top_k):
-    """(B, T, d) -> (B, T, d): every expert on every token, weighted by its
-    gate, which is zero where the expert was not chosen."""
-    p = jax.nn.softmax(h @ w["w_router"], -1)                   # (B, T, E)
-    top, chosen = jax.lax.top_k(p, top_k)
-    if top_k > 1:
+def _experts(h, w, top_k, renormalise, chosen=None):
+    """(B, T, d) -> ((B, T, d), router logits (B, T, E)): every expert on
+    every token, weighted by its gate, which is zero where the expert was
+    not chosen. ``chosen`` (B, T, k): the experts to take; None: the k of
+    the largest probability."""
+    z = h @ w["w_router"]                                       # (B, T, E)
+    p = jax.nn.softmax(z, -1)
+    if chosen is None:
+        chosen = jax.lax.top_k(p, top_k)[1]
+    top = jnp.take_along_axis(p, chosen, -1)
+    if renormalise:
         top = top / top.sum(-1, keepdims=True)
     gates = (jax.nn.one_hot(chosen, p.shape[-1]) * top[..., None]).sum(-2)
     inner = jax.nn.silu(jnp.einsum("btd,edf->btef", h, w["w_eg"])) \
         * jnp.einsum("btd,edf->btef", h, w["w_e1"])
-    return jnp.einsum("btef,efd,bte->btd", inner, w["w_e2"], gates)
+    return jnp.einsum("btef,efd,bte->btd", inner, w["w_e2"], gates), z
 
 
-def hidden(weights, tokens, sizes):
+def hidden(weights, tokens, sizes, experts=None):
     """tokens (B, T) int32 -> (final-RMSNorm hidden (B, T, d), keys (rotated)
-    and values of every layer, each (L, B, T, KV, hd))."""
+    and values of every layer, each (L, B, T, KV, hd), the router's logits
+    (L, B, T, E)). ``experts`` (L, B, T, k) int32: the experts every token
+    takes in every layer; None: the router's own k best."""
     n_head, n_kv = sizes["num_attention_heads"], sizes["num_key_value_heads"]
     top_k, eps = sizes["num_experts_per_tok"], sizes["rms_norm_eps"]
     theta = float(sizes["rope_theta"])
+    renormalise = top_k > 1 and bool(sizes.get("norm_topk_prob", True))
     b, t = tokens.shape
     x = weights["wte"][tokens]
     d = x.shape[-1]
     hd = d // n_head
     causal = jnp.tril(jnp.ones((t, t), bool))
 
-    def block(x, w):
+    def block(x, layer):
+        w, chosen = layer
         h = _rms(x, w["ln1_g"], eps)
         q = _rotate((h @ w["wq"]).reshape(b, t, n_head, hd), theta)
         k = _rotate((h @ w["wk"]).reshape(b, t, n_kv, hd), theta)
@@ -120,14 +132,15 @@ def hidden(weights, tokens, sizes):
         scores = jnp.where(causal, scores, -jnp.inf)
         att = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), vq)
         x = x + att.reshape(b, t, d) @ w["wo"]
-        x = x + _experts(_rms(x, w["ln2_g"], eps), w, top_k)
-        return x, (k, v)
+        out, z = _experts(_rms(x, w["ln2_g"], eps), w, top_k, renormalise,
+                          chosen)
+        return x + out, (k, v, z)
 
     with jax.default_matmul_precision("highest"):
-        x, (ks, vs) = jax.lax.scan(block, x.astype(jnp.float32),
-                                   weights["blocks"])
+        x, (ks, vs, router) = jax.lax.scan(
+            block, x.astype(jnp.float32), (weights["blocks"], experts))
         x = _rms(x, weights["lnf_g"], eps)
-    return x, ks, vs
+    return x, ks, vs, router
 
 
 def logits(weights, x):
@@ -138,7 +151,7 @@ def logits(weights, x):
 
 def loss(weights, tokens, targets, sizes):
     """Mean cross-entropy over the positions whose target is not -1."""
-    x, _, _ = hidden(weights, tokens, sizes)
+    x = hidden(weights, tokens, sizes)[0]
     logp = jax.nn.log_softmax(logits(weights, x), -1)
     valid = targets != -1
     picked = jnp.take_along_axis(
