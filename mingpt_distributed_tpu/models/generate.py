@@ -49,28 +49,79 @@ def init_cache(cfg: GPTConfig, batch: int, dtype=None) -> Cache:
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def _lay_rows_over(old: jax.Array, rows: jax.Array, positions) -> jax.Array:
+    """A layer's cached ``(B, S, KV, hd)`` slice as it will read once each
+    lane's new row ``rows[b, 0]`` lies at ``positions[b]``. A select on the
+    way into the attention's reads (it fuses into them: nothing of the
+    slice's size is stored), so a decode step attends the rows it has not
+    written yet."""
+    at = jnp.arange(old.shape[1])[None, :] == positions[:, None]  # (B, S)
+    return jnp.where(at[:, :, None, None], rows, old)
+
+
+@jax.named_scope("kv_layout")
+def _write_lane_rows(cache: Cache, rows, positions) -> Cache:
+    """Every layer's new row of lane ``b`` into the full ``(L, B, S, KV,
+    hd)`` buffers at ``(:, b, positions[b])``: one ``dynamic_update_slice``
+    of ``(L, 1, 1, KV, hd)`` a lane and buffer, in place, whatever layout
+    the device keeps the buffers in. ``rows`` is the layers' list of
+    ``{"k", "v"}`` rows, ``(B, 1, KV, hd)`` each.
+
+    The lanes are a static python loop on purpose (compile rehearsals for
+    the TPU, PR 27): a ``fori_loop`` body is laid out before its caller,
+    with the row axes minor, and a scatter runs only in that layout, so
+    either made the program copy the whole buffer into that layout and
+    back, every call. A chain of slices in the entry computation takes
+    the layout the buffer arrives in."""
+    out = {}
+    for name in ("k", "v"):
+        buf = cache[name]
+        new = jnp.stack([r[name] for r in rows])  # (L, B, 1, KV, hd)
+        for lane in range(new.shape[1]):
+            buf = jax.lax.dynamic_update_slice(
+                buf, new[:, lane:lane + 1], (0, lane, positions[lane], 0, 0))
+        out[name] = buf
+    return out
+
+
 def _cached_block(
     x: jax.Array,            # (B, T, D) — T = prompt length or 1
     blk: gpt.Params,         # one layer's params (no leading L axis)
-    cache: Cache,            # FULL (L, B, S, KV, hd) buffers, updated here
+    cache: Cache,            # FULL (L, B, S, KV, hd) buffers
     layer: int,
-    offset: jax.Array,       # scalar: absolute position of x[:, 0]
+    offset: jax.Array,       # absolute position of x[:, 0]: scalar, or (B,)
     cfg: GPTConfig,
-) -> Tuple[jax.Array, Cache]:
-    """One pre-LN block; writes this call's (B, T, KV, hd) k/v into the
-    full cache at (layer, :, offset) and attends against the layer's
-    slice. Returns (y, cache).
+) -> Tuple[jax.Array, Cache, Cache]:
+    """One pre-LN block against the cache. Returns (y, cache, rows): the
+    block's own (B, T, KV, hd) k/v ``rows`` in the cache's dtype.
 
-    The update is a small dynamic_update_slice on the big buffer — XLA
-    aliases it in place through the unrolled layer chain and the decode
-    scan carry. The original layer ``lax.scan`` instead emitted every
-    layer's updated cache as stacked ys, rewriting the ENTIRE cache every
-    decode step — one-token decode scaled with cache size (~5.6 ms/token
-    at gpt2-124M b8, the r4/r5 decode mystery) instead of with the
-    one-slot update.
+    ``offset`` is one position for the whole batch (prefill, verify, solo
+    ``generate``: rows that advance together): the rows are written into
+    the full cache at (layer, :, offset) here and the block attends the
+    layer's slice. That update is a small dynamic_update_slice on the big
+    buffer — XLA aliases it in place through the unrolled layer chain and
+    the decode scan carry. The original layer ``lax.scan`` instead
+    emitted every layer's updated cache as stacked ys, rewriting the
+    ENTIRE cache every decode step — one-token decode scaled with cache
+    size (~5.6 ms/token at gpt2-124M b8, the r4/r5 decode mystery)
+    instead of with the one-slot update.
+
+    A ``(B,)`` offset, one a row, is the serving decode step: the batch is
+    the pool's slot axis, T is 1 and every lane stands at its own
+    position (the form is read from the offset's shape). The rotary
+    angles and the causal mask are then a lane's own; the block attends
+    the layer's slice with the new rows laid over it (``_lay_rows_over``:
+    the row a lane attends for its own token is the row as cached) and
+    returns the cache as it came: the caller writes all layers' rows
+    after the last (``_write_lane_rows``). An expert MLP routes each
+    lane alone, since lanes are other users' requests: a lane's routes
+    must not depend on which other lanes are live.
     """
     b, t, _ = x.shape
     nh, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
+    per_lane = jnp.ndim(offset) == 1
+    if per_lane and t != 1:
+        raise ValueError(f"a position a lane takes one token a lane, not {t}")
 
     h = gpt._norm(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
     q = L.dense(h, blk["wq"], blk.get("bq")).reshape(b, t, nh, hd)
@@ -78,23 +129,25 @@ def _cached_block(
     v = L.dense(h, blk["wv"], blk.get("bv")).reshape(b, t, kv, hd)
     if cfg.rope:
         cos, sin = attn_ops.rope_tables(
-            offset + jnp.arange(t), hd, cfg.rope_theta
+            jnp.asarray(offset)[..., None] + jnp.arange(t), hd, cfg.rope_theta
         )
         q = attn_ops.apply_rope(q, cos, sin)
         k = attn_ops.apply_rope(k, cos, sin)
 
-    big_k = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype)[None],
-        (layer, 0, offset, 0, 0))
-    big_v = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype)[None],
-        (layer, 0, offset, 0, 0))
-    cache = {"k": big_k, "v": big_v}
+    rows = {"k": k.astype(cache["k"].dtype), "v": v.astype(cache["v"].dtype)}
+    if per_lane:
+        big_k, big_v = (_lay_rows_over(cache[n][layer], rows[n], offset)
+                        for n in ("k", "v"))
+    else:
+        cache = {n: jax.lax.dynamic_update_slice(
+            cache[n], rows[n][None], (layer, 0, offset, 0, 0))
+            for n in ("k", "v")}
+        big_k, big_v = cache["k"][layer], cache["v"][layer]
     # attend against the whole cache; kv_offset makes query absolute
     # positions correct, and the causal mask kills both future tokens and
     # never-written (zero) slots beyond offset+t
     att = attn_ops.causal_attention(
-        q, big_k[layer], big_v[layer], kv_offset=offset,
+        q, big_k, big_v, kv_offset=offset,
         window=cfg.attention_window,
         logit_softcap=cfg.attn_logit_softcap,
     ).reshape(b, t, nh * hd)
@@ -105,24 +158,31 @@ def _cached_block(
     if cfg.n_experts:
         from mingpt_distributed_tpu.ops import moe
 
-        m, _ = moe.moe_mlp(
-            h2, blk["w_router"], blk["w_e1"], blk["w_e2"],
-            top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
-            w_gate=blk.get("w_eg"),
-        )
+        def experts(tokens):
+            return moe.moe_mlp(
+                tokens, blk["w_router"], blk["w_e1"], blk["w_e2"],
+                top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
+                w_gate=blk.get("w_eg"),
+            )[0]
+
+        if per_lane:
+            m = jax.vmap(lambda lane: experts(lane[None])[0])(h2)
+        else:
+            m = experts(h2)
     elif cfg.swiglu:
         m = L.mlp_swiglu(h2, blk["w_gate"], blk["w_up"], blk["w_down"])
     else:
         m = L.mlp_gelu(h2, blk["w_fc"], blk.get("b_fc"), blk["w_proj"],
                        blk.get("b_proj"))
-    return x + m, cache
+    return x + m, cache, rows
 
 
 def _forward_cached_hidden(
     params: gpt.Params, tokens: jax.Array, cache: Cache, offset, cfg: GPTConfig
 ) -> Tuple[jax.Array, Cache]:
-    """Forward (B, T) tokens at absolute position ``offset`` through all
-    layers, reading+writing the cache. Returns (final-norm hidden states
+    """Forward (B, T) tokens at absolute position ``offset`` (a scalar, or
+    a ``(B,)`` vector of one position a row: see ``_cached_block``) through
+    all layers, reading+writing the cache. Returns (final-norm hidden states
     (B, T, D), cache) — the LM head is applied separately (``_head_logits``)
     so callers that need logits at a *dynamic* position (the serving
     prefill reads position ``prompt_len - 1`` of a padded prompt) can slice
@@ -130,7 +190,9 @@ def _forward_cached_hidden(
 
     The layer loop is a static python loop (n_layer is static, decode
     bodies are small) so each layer's cache update stays a one-slot
-    in-place write — see _cached_block. Compile-time trade (ADVICE r5):
+    in-place write — see _cached_block; under a position a lane the
+    layers' rows are written together after the loop
+    (``_write_lane_rows``). Compile-time trade (ADVICE r5):
     unrolling puts every layer's body in the HLO, so prefill+decode program
     size and compile time grow roughly linearly with ``n_layer``. Fine at
     gpt2-124M (12 layers); a 48-layer gpt2-xl pays ~4x the compile of a
@@ -143,13 +205,17 @@ def _forward_cached_hidden(
     compute_dtype = jnp.dtype(cfg.dtype)
     x = params["wte"][tokens]
     if not cfg.rope:
-        pos = offset + jnp.arange(t)
+        pos = jnp.asarray(offset)[..., None] + jnp.arange(t)  # (T,) or (B, T)
         x = x + jnp.take(params["wpe"], pos, axis=0)
     x = x.astype(compute_dtype)
 
+    rows = []
     for layer in range(cfg.n_layer):
         blk = jax.tree.map(lambda a, _l=layer: a[_l], params["blocks"])
-        x, cache = _cached_block(x, blk, cache, layer, offset, cfg)
+        x, cache, new = _cached_block(x, blk, cache, layer, offset, cfg)
+        rows.append(new)
+    if jnp.ndim(offset) == 1:
+        cache = _write_lane_rows(cache, rows, offset)
     x = gpt._norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg)
     return x, cache
 

@@ -62,7 +62,10 @@ def causal_attention(
 
     ``kv_offset`` is the absolute position of q[0] relative to k[0] — 0 for
     training (S == T, self-attention), the cache length during incremental
-    decoding (so a single query attends to all cached keys).
+    decoding (so a single query attends to all cached keys). A ``(B,)``
+    offset gives every batch row its own (the serving decode step, whose
+    lanes stand at different positions): the mask then differs a row, and
+    the window and softcap keep their meaning.
     ``window`` enables sliding-window (banded) attention: each query sees
     only the last ``window`` positions, itself included (Mistral-style;
     ``None`` = full causal). ``logit_softcap`` applies Gemma-2-style
@@ -82,12 +85,13 @@ def causal_attention(
     logits = softcap(logits, logit_softcap)
 
     s = k.shape[1]
-    q_pos = jnp.arange(t)[:, None] + kv_offset  # absolute query positions
+    # absolute query positions: (T, 1), or (B, T, 1) under a (B,) offset
+    q_pos = jnp.arange(t)[:, None] + jnp.asarray(kv_offset)[..., None, None]
     k_pos = jnp.arange(s)[None, :]
     allowed = q_pos >= k_pos  # (T, S) boolean — the B6 fix
     if window is not None:
         allowed = allowed & (q_pos - k_pos < window)
-    logits = jnp.where(allowed[None, None], logits, NEG_INF)
+    logits = jnp.where(allowed[..., None, :, :], logits, NEG_INF)
 
     probs = jax.nn.softmax(logits, axis=-1)
     if not deterministic and attn_pdrop > 0.0:
@@ -106,22 +110,25 @@ def causal_attention(
 def rope_tables(
     positions: jax.Array, head_dim: int, theta: float = 10000.0
 ) -> Tuple[jax.Array, jax.Array]:
-    """cos/sin tables for rotary embeddings at the given absolute positions.
+    """cos/sin tables for rotary embeddings at the given absolute positions,
+    (P,) or a row of them a batch entry (B, P).
 
-    Returns (P, head_dim/2) float32 each, split-half (rotate-half) convention.
+    Returns (..., P, head_dim/2) float32 each, split-half (rotate-half)
+    convention.
     """
     half = head_dim // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]
+    angles = positions.astype(jnp.float32)[..., None] * freqs
     return jnp.cos(angles), jnp.sin(angles)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Rotate (B, T, H, hd) by per-position tables (T, hd/2)."""
+    """Rotate (B, T, H, hd) by per-position tables (T, hd/2), or by a
+    table a batch entry (B, T, hd/2)."""
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
-    cos = cos[None, :, None, :].astype(jnp.float32)
-    sin = sin[None, :, None, :].astype(jnp.float32)
+    cos = cos[..., None, :].astype(jnp.float32)  # the heads' axis
+    sin = sin[..., None, :].astype(jnp.float32)
     x1f, x2f = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate(
         [x1f * cos - x2f * sin, x2f * cos + x1f * sin], axis=-1

@@ -17,12 +17,19 @@ lifetime:
    a long prompt (``prefill_chunk``-token pieces between decode steps),
    and the tail after a prefix-cache hit.
 
-2. **decode-step** — one token for every slot at once: ``vmap`` over the
-   slot axis of the same ``_forward_cached`` the solo scan uses, each lane
-   carrying its own absolute position (per-slot ``kv_offset`` and RoPE /
-   learned-position index, per-slot one-row cache write — the vmapped
-   dynamic_update_slice lowers to a one-row-per-slot scatter, NOT a
-   whole-cache rewrite). Per-slot sampling params ride as traced arrays.
+2. **decode-step** — one token for every slot at once: the pool is a solo
+   cache whose batch is the slot axis, so the step is one ``(B=S, T=1)``
+   call of the same ``_forward_cached`` the solo scan uses, at a ``(S,)``
+   vector of absolute positions (per-slot ``kv_offset`` and RoPE /
+   learned-position index). Each layer attends its slice of the pool
+   with the lanes' new rows laid over it; after the last layer one
+   row-sized ``dynamic_update_slice`` a lane writes all layers' rows into
+   the donated pool where they lie (``generate._write_lane_rows``). The
+   pool is never transposed, copied or rebuilt inside the program: a
+   ``vmap`` over lanes of a batch of one would batch each lane's update
+   into a scatter with the slot axis in front, and the TPU compiler then
+   rewrites the whole pool four times a step. An expert MLP routes lane
+   by lane. Per-slot sampling params ride as traced arrays.
 
 3. **prefix extract / install** (only when the prefix store is enabled) —
    device-side row copies between a slot lane and a shared-prefix cache
@@ -299,23 +306,24 @@ def _decode_impl(
     """One token for every slot: tokens/positions (S,), sampling arrays
     (S,), request seeds (S,) uint32 and the index (S,) of the token each
     lane samples, from which the lanes' keys are derived here
-    (:func:`lane_keys`). Returns (next tokens (S,), updated pool cache)."""
+    (:func:`lane_keys`). Returns (next tokens (S,), updated pool cache).
+
+    The pool is a solo cache whose batch is the slot axis, so the step is
+    one ``(B=S, T=1)`` forward through the same cached-block chain solo
+    ``generate`` uses, at a ``(S,)`` vector of positions
+    (``generate._cached_block``): each layer attends its slice of the
+    pool under each lane's own causal mask with the lanes' new rows laid
+    over it, and all layers' rows are written into the donated pool after
+    the last, one row-sized update a lane. Nothing in the program has the
+    pool's size but the pool. Positions are clipped, so a free lane
+    (parked at ``block_size - 1``) writes into its own lane and no other.
+    A quantized pool is dequantized, stepped and requantized whole
+    (idempotent on the rows the step did not touch: serving/quant.py)."""
     safe_pos = jnp.clip(positions, 0, cfg.block_size - 1)
-
-    def one_slot(tok, cache_slot, pos):
-        # re-grow the batch axis the vmap stripped so the lane is exactly
-        # solo generate's (B=1, T=1) decode body
-        with jax.named_scope("kv_layout"):
-            cache_b = jax.tree.map(lambda a: a[:, None], cache_slot)
-        lane = _dequant_lane(cache_b, kv_quant, cfg)
-        logits, lane = gen._forward_cached(
-            params, tok[None, None], lane, pos, cfg)
-        cache_b = _requant_lane(lane, kv_quant)
-        with jax.named_scope("kv_layout"):
-            return logits[0], jax.tree.map(lambda a: a[:, 0], cache_b)
-
-    logits, cache = jax.vmap(one_slot, in_axes=(0, 1, 0), out_axes=(0, 1))(
-        tokens, cache, safe_pos)
+    logits, cache = gen._forward_cached(
+        params, tokens[:, None], _dequant_lane(cache, kv_quant, cfg),
+        safe_pos, cfg)
+    cache = _requant_lane(cache, kv_quant)
     nxt = _select_next_slots(logits, lane_keys(seeds, token_index),
                              temps, top_ks, top_ps, do_sample)
     return nxt, _pin_kv(cache, kv_sharding)
