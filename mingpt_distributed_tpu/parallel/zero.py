@@ -45,7 +45,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -261,34 +261,8 @@ def canonical_opt_shape(opt_state_shape: Any, plan: ZeroPlan) -> Any:
 
 
 # ---------------------------------------------------------------------------
-# Measurement helper (selftest / bench / dryrun)
+# Measurement helper (selftests / dryrun)
 # ---------------------------------------------------------------------------
-
-def opt_moment_bytes(params_shape: Any, plan: "Optional[ZeroPlan]" = None,
-                     ) -> int:
-    """Analytic per-device bytes of the Adam moments (mu + nu) from
-    shapes/dtypes alone — the zero_dp-aware HBM-ledger entry
-    (telemetry/attribution.py). With a plan, each leaf's moments live in
-    the update view sharded 1/dp over the dp axis (flat-mode pad slots
-    included: they are real allocated zeros); without one, moments are
-    replicated at full canonical size. dp-axis accounting only — any
-    fsdp/tp sharding of the base spec is a property of the mesh the
-    caller already divides by."""
-    total = 0
-    for path, leaf in jax.tree_util.tree_flatten_with_path(params_shape)[0]:
-        itemsize = np.dtype(leaf.dtype).itemsize
-        if plan is None or plan.dp <= 1:
-            elems = math.prod(leaf.shape) if leaf.shape else 1
-        else:
-            lp = plan.by_name.get(leaf_name(path))
-            if lp is None or lp.mode == NOOP:
-                elems = math.prod(leaf.shape) if leaf.shape else 1
-            else:
-                view = math.prod(lp.view_shape) if lp.view_shape else 1
-                elems = view // plan.dp
-        total += 2 * elems * itemsize  # mu + nu
-    return total
-
 
 def per_device_bytes(tree: Any) -> int:
     """Bytes of ``tree`` held on the busiest addressable device — the

@@ -712,58 +712,48 @@ class DecodeEngine:
             "prefix_save": self._extract_jit._cache_size(),
         }
 
-    # -- performance attribution (ISSUE 13) ----------------------------
-    def register_attrib(self, ledger, clock, family_prefix: str = "") -> None:
-        """Register every compiled program family of this engine with a
-        telemetry/attribution.py ProgramLedger: AOT-lower + compile each
-        family against its real call signature, recording compile time
-        (via the injected clock) and cost_analysis FLOPs/bytes. The AOT
-        path never touches the jit call caches, so ``compile_counts()``
-        and the recompile watchdog are unaffected; it does warm the
-        backend compilation cache, so a later ``warmup()`` retrace is
-        cheap. Family names mirror ``compile_counts()`` keys (prefixed
-        for a draft engine); prefill/prefix variants are per ladder
+    # -- compiled programs, as data ------------------------------------
+    def programs(self, family_prefix: str = ""):
+        """Yield ``(family, variant, jitted, args, kwargs)`` for every
+        compiled program of this engine, with the arguments of a real
+        call: what ``jitted.lower(*args, **kwargs)`` needs to build the
+        program the serving loop runs. Family names mirror
+        ``compile_counts()`` keys (prefixed for a draft engine) and key
+        ``audit_contracts``; prefill/prefix variants are per ladder
         bucket."""
         for b in self.buckets:
-            ledger.register_aot(
-                family_prefix + "prefill", self._prefill_jit,
-                (self.params, self.pool.cache, jnp.zeros(b, jnp.int32),
-                 np.int32(b), np.int32(0), np.int32(0),
-                 np.float32(1.0), np.int32(0), np.float32(1.0),
-                 np.bool_(False), np.uint32(0)),
-                clock, variant=f"b{b}")
+            yield (family_prefix + "prefill", f"b{b}", self._prefill_jit,
+                   (self.params, self.pool.cache, jnp.zeros(b, jnp.int32),
+                    np.int32(b), np.int32(0), np.int32(0),
+                    np.float32(1.0), np.int32(0), np.float32(1.0),
+                    np.bool_(False), np.uint32(0)), {})
         s = self.n_slots
-        ledger.register_aot(
-            family_prefix + "decode", self._decode_jit,
-            (self.params, self.pool.cache,
-             jnp.zeros(s, jnp.int32), jnp.zeros(s, jnp.int32),
-             jnp.ones(s, jnp.float32), jnp.zeros(s, jnp.int32),
-             jnp.ones(s, jnp.float32), jnp.zeros(s, bool),
-             jnp.zeros(s, jnp.uint32), jnp.zeros(s, jnp.int32)),
-            clock)
-        if self.prefix_store is not None:
-            for b in self.buckets:
-                if b > self.prefill_len - 1:
-                    continue
-                ledger.register_aot(
-                    family_prefix + "prefix_save", self._extract_jit,
-                    (self.pool.cache, np.int32(0)),
-                    clock, variant=f"b{b}", kwargs={"rows": b})
-                entry = {}
-                for name, arr in self.pool.cache.items():
-                    l, _, _, kv, last = arr.shape
-                    entry[name] = jax.ShapeDtypeStruct(
-                        (l, 1, b, kv, last), arr.dtype)
-                ledger.register_aot(
-                    family_prefix + "prefix_load", self._install_jit,
-                    (self.pool.cache, entry, np.int32(0)),
-                    clock, variant=f"b{b}")
+        yield (family_prefix + "decode", "", self._decode_jit,
+               (self.params, self.pool.cache,
+                jnp.zeros(s, jnp.int32), jnp.zeros(s, jnp.int32),
+                jnp.ones(s, jnp.float32), jnp.zeros(s, jnp.int32),
+                jnp.ones(s, jnp.float32), jnp.zeros(s, bool),
+                jnp.zeros(s, jnp.uint32), jnp.zeros(s, jnp.int32)), {})
+        if self.prefix_store is None:
+            return
+        for b in self.buckets:
+            if b > self.prefill_len - 1:
+                continue
+            yield (family_prefix + "prefix_save", f"b{b}", self._extract_jit,
+                   (self.pool.cache, np.int32(0)), {"rows": b})
+            entry = {}
+            for name, arr in self.pool.cache.items():
+                l, _, _, kv, last = arr.shape
+                entry[name] = jax.ShapeDtypeStruct(
+                    (l, 1, b, kv, last), arr.dtype)
+            yield (family_prefix + "prefix_load", f"b{b}", self._install_jit,
+                   (self.pool.cache, entry, np.int32(0)), {})
 
     # -- static audit contracts (ISSUE 15) -----------------------------
     def audit_contracts(self, family_prefix: str = "") -> Dict[str, dict]:
         """Per-family contracts for ``analysis/hlo_audit.py`` — plain
         dicts (serving never imports the analysis layer), keyed like
-        ``register_attrib`` families. Grammar (docs/static_analysis.md):
+        ``programs`` families. Grammar (docs/static_analysis.md):
 
         * ``allowed_collectives`` — collective op base names the lowered
           HLO may contain. Model-forwarding families at tp > 1 reduce

@@ -1198,23 +1198,6 @@ class Router:
              for rep in self.supervisor.replicas},
         )
 
-    def attrib_report(self, include_live: bool = False) -> Dict[str, Any]:
-        """Fleet attribution: one ``mingpt-attrib/1`` document per
-        replica whose server was built with ``attrib=True``, keyed by
-        replica name. Replicas without a ledger are skipped (a fleet may
-        mix instrumented and plain servers); raises only when NO replica
-        has attribution enabled."""
-        replicas = {
-            rep.name: rep.server.attrib_report(include_live=include_live)
-            for rep in self.supervisor.replicas
-            if rep.server.attrib is not None
-        }
-        if not replicas:
-            raise ValueError(
-                "no replica has attribution enabled — pass attrib=True "
-                "to the server factory")
-        return {"schema": "mingpt-attrib-fleet/1", "replicas": replicas}
-
     def summary(self) -> Dict[str, Any]:
         return {
             "replicas": {

@@ -5,8 +5,7 @@ Every JSON document that crosses the replica boundary — request or
 response, loopback or real HTTP — is an *envelope*: ``{"schema":
 "mingpt-rpc/1", "kind": <kind>, ...}`` with a per-kind required-field
 table enforced by :func:`validate_envelope`, the same strict-validator
-discipline as ``mingpt-trace/1`` / ``mingpt-flight/1`` /
-``mingpt-attrib/1``. Both transport implementations validate every
+discipline as ``mingpt-trace/1`` / ``mingpt-flight/1``. Both transport implementations validate every
 envelope in BOTH directions, so a drifting worker fails loudly at the
 boundary instead of corrupting router state, and the tamper battery in
 tests/test_procfleet.py pins each field.

@@ -185,7 +185,6 @@ class ServerProxy:
         self.on_token = None          # set by Router._wire_replica
         self.trace_recorder = None    # set by Router._wire_replica (unused:
         #                               the router owns spans and events)
-        self.attrib = None            # truthy when the worker has a ledger
         self._handles: Dict[str, Any] = {}
         self._recompiles_seen = 0
 
@@ -279,11 +278,6 @@ class ServerProxy:
             "/rpc/cancel", envelope("cancel", request_id=request_id))
         return bool(resp.get("cancelled"))
 
-    def attrib_report(self, include_live: bool = False) -> Dict[str, Any]:
-        # live (uncommitted) call spans never cross the boundary — the
-        # worker reports committed attribution only
-        return self.transport.fetch_json("/attrib")
-
     def metrics_page(self) -> str:
         return self.transport.fetch_text("/metrics")
 
@@ -304,12 +298,10 @@ class LoopbackBackend:
     kind = "loopback"
     pid = None
 
-    def __init__(self, worker, spill_dir: Optional[str] = None,
-                 attrib_enabled: bool = False):
+    def __init__(self, worker, spill_dir: Optional[str] = None):
         self.worker = worker
         self.transport = LoopbackTransport(worker)
         self.spill_dir = spill_dir
-        self.attrib_enabled = attrib_enabled
         self.wedged = False
         self._exit_code: Optional[int] = None
 
@@ -360,12 +352,11 @@ class ProcessBackend:
     kind = "process"
 
     def __init__(self, proc: subprocess.Popen, transport: SocketTransport,
-                 pid: int, spill_dir: str, attrib_enabled: bool = False):
+                 pid: int, spill_dir: str):
         self.proc = proc
         self.transport = transport
         self.pid = pid
         self.spill_dir = spill_dir
-        self.attrib_enabled = attrib_enabled
 
     def alive(self) -> bool:
         return self.proc.poll() is None
@@ -428,8 +419,7 @@ def loopback_backend_factory(params, cfg, spill_root: Optional[str] = None,
             # same on-disk evidence a real worker leaves at startup, so
             # a SIGKILL'd loopback replica still has a spill to collect
             flight.dump("spawn", replica=name, spawn=n)
-        return LoopbackBackend(worker, spill_dir=spill_dir,
-                               attrib_enabled=server.attrib is not None)
+        return LoopbackBackend(worker, spill_dir=spill_dir)
 
     return make
 
@@ -480,10 +470,8 @@ def process_backend_factory(spec_base: Dict[str, Any], spill_root: str,
         hello = validate_envelope(json.loads(line), kind="hello")
         transport = SocketTransport("127.0.0.1", hello["port"],
                                     timeout_s=rpc_timeout_s)
-        health = transport.call("/rpc/health")
         return ProcessBackend(proc, transport, pid=hello["pid"],
-                              spill_dir=spill_dir,
-                              attrib_enabled=bool(health.get("attrib")))
+                              spill_dir=spill_dir)
 
     return make
 
@@ -623,11 +611,8 @@ class ProcReplica(Replica):
                                          fault_hook=hook)
             self.last_spawn_path = "cold"
             self.adopted_name = None
-        proxy = ServerProxy(self.backend.transport, self.name,
-                            clock=self.clock)
-        if self.backend.attrib_enabled:
-            proxy.attrib = True
-        return proxy
+        return ServerProxy(self.backend.transport, self.name,
+                           clock=self.clock)
 
     def respawn(self) -> None:
         old = self.backend

@@ -80,13 +80,6 @@ class LoopbackTransport:
             raise TransportError(f"loopback GET {path} -> {status}")
         return payload.decode()
 
-    def fetch_json(self, path: str) -> Dict[str, Any]:
-        """Raw JSON (non-envelope) endpoints — /attrib."""
-        status, _ctype, payload = self._dispatch("GET", path, b"")
-        if status != 200:
-            raise TransportError(f"loopback GET {path} -> {status}")
-        return json.loads(payload.decode())
-
     def fetch_bytes(self, path: str) -> bytes:
         status, _ctype, payload = self._dispatch("GET", path, b"")
         if status != 200:
@@ -173,12 +166,6 @@ class SocketTransport:
         if status != 200:
             raise TransportError(f"GET {path} -> HTTP {status}")
         return payload.decode()
-
-    def fetch_json(self, path: str) -> Dict[str, Any]:
-        status, payload = self._roundtrip("GET", path, b"")
-        if status != 200:
-            raise TransportError(f"GET {path} -> HTTP {status}")
-        return json.loads(payload.decode())
 
     def fetch_bytes(self, path: str) -> bytes:
         status, payload = self._roundtrip("GET", path, b"")

@@ -20,7 +20,6 @@ Endpoints::
     GET  /rpc/migrate_out  -> size-framed KV/prefix blob (octet-stream)
     POST /rpc/migrate_in   size-framed blob -> migrate_in_result
     GET  /metrics          Prometheus text page (private registry)
-    GET  /attrib           mingpt-attrib/1 JSON (404 without a ledger)
 
 **Step-driven contract.** The worker never decodes on its own: each
 ``/rpc/step`` runs exactly one scheduling round and returns the round's
@@ -262,8 +261,7 @@ class ReplicaWorker:
                 draining=self.draining,
                 recompiles=self.server.watchdog.recompiles,
                 pid=os.getpid(),
-                itl_mean_s=m.itl_mean_s, itl_p99_s=m.itl_p99_s,
-                attrib=self.server.attrib is not None)
+                itl_mean_s=m.itl_mean_s, itl_p99_s=m.itl_p99_s)
         return (200, "application/json", _json_body(doc))
 
     def _metrics(self) -> Tuple[int, str, bytes]:
@@ -272,15 +270,6 @@ class ReplicaWorker:
             page = render_prometheus(self.server.metrics.registry)
         return (200, "text/plain; version=0.0.4; charset=utf-8",
                 page.encode())
-
-    def _attrib(self) -> Tuple[int, str, bytes]:
-        if self.server.attrib is None:
-            return _error(404, "no_attrib",
-                          "no attribution ledger configured")
-        with self._lock:
-            doc = self.server.attrib_report()
-        return (200, "application/json",
-                json.dumps(doc, sort_keys=True).encode())
 
     # -- migration ------------------------------------------------------
     def migrate_out_frames(self) -> List[Tuple[Dict[str, Any], bytes]]:
@@ -460,8 +449,6 @@ class ReplicaWorker:
                 return self._migrate_out()
             if method == "GET" and path == "/metrics":
                 return self._metrics()
-            if method == "GET" and path == "/attrib":
-                return self._attrib()
             return _error(404, "not_found",
                           f"unknown endpoint {method} {path}")
         except Exception as e:  # the boundary never leaks a traceback

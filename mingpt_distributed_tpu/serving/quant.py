@@ -164,7 +164,7 @@ def data_names(cache: Dict[str, jax.Array]) -> Tuple[str, ...]:
 def split_scales(
     cache: Dict[str, jax.Array],
 ) -> Tuple[Dict[str, jax.Array], Dict[str, jax.Array]]:
-    """(payload leaves, scale leaves) — the HBMLedger owner split."""
+    """(payload leaves, scale leaves) of a pool's cache dict."""
     data = {n: a for n, a in cache.items() if not n.endswith(SCALE_SUFFIX)}
     scales = {n: a for n, a in cache.items() if n.endswith(SCALE_SUFFIX)}
     return data, scales
@@ -207,9 +207,8 @@ def dequantize_lane(
 
 
 def scale_bytes(cfg, n_slots: int) -> int:
-    """Bytes the scale planes add for this geometry (both K and V) —
-    the ``kv_scales`` HBMLedger owner's capacity-planning analogue of
-    ``telemetry.kv_cache_bytes``."""
+    """Bytes the scale planes add for this geometry (both K and V),
+    for capacity planning."""
     elems = cfg.n_layer * n_slots * cfg.block_size * cfg.kv_heads
     return 2 * elems * jnp.dtype(SCALE_DTYPE).itemsize
 

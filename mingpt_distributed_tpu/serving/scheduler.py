@@ -88,7 +88,6 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Sequence
 import numpy as np
 
 from mingpt_distributed_tpu.config import GPTConfig
-from mingpt_distributed_tpu.serving import quant as quant_lib
 from mingpt_distributed_tpu.serving.admission import AdmissionPolicy, FifoPolicy
 from mingpt_distributed_tpu.serving.engine import DecodeEngine
 from mingpt_distributed_tpu.serving.metrics import ServingMetrics
@@ -100,15 +99,10 @@ from mingpt_distributed_tpu.serving.requests import (  # noqa: F401  (re-export)
 )
 from mingpt_distributed_tpu.serving.speculative import SpeculativeDecoder
 from mingpt_distributed_tpu.telemetry import (
-    HBMLedger,
     MetricsRegistry,
-    ProgramLedger,
     RecompileWatchdog,
     SpanTracer,
-    build_attrib_report,
     log_event,
-    per_device_tree_bytes,
-    tree_bytes,
 )
 from mingpt_distributed_tpu.telemetry.tracing import (
     TraceRecorder,
@@ -274,7 +268,6 @@ class InferenceServer:
         draft_cfg: Optional[GPTConfig] = None,
         spec_k: int = 0,
         admission_policy: Optional[AdmissionPolicy] = None,
-        attrib: bool = False,
         mesh=None,
         tp_axis: str = "tp",
         kv_dtype: Optional[str] = None,
@@ -379,23 +372,6 @@ class InferenceServer:
         # is preserved unless a policy is injected.
         self.admission_policy = (admission_policy if admission_policy
                                  is not None else FifoPolicy())
-        # performance attribution (ISSUE 13): a per-server program + HBM
-        # ledger registered into this server's metrics registry, so a
-        # respawned replica starts a fresh ledger and the fleet-merged
-        # scrape sees it under the replica's label. Registration is AOT
-        # (jit-cache-neutral — the armed watchdog never sees it) and all
-        # timing flows through self.clock, so attribution on a
-        # VirtualClock is byte-deterministic.
-        self.attrib: Optional[ProgramLedger] = None
-        self.hbm: Optional[HBMLedger] = None
-        if attrib:
-            areg = self.metrics.registry if registry is None else registry
-            self.attrib = ProgramLedger(registry=areg)
-            self.hbm = HBMLedger(registry=areg)
-            self.engine.register_attrib(self.attrib, self.clock)
-            if self.spec is not None:
-                self.spec.register_attrib(self.attrib, self.clock)
-            self._account_hbm()
         self.queue: Deque[RequestHandle] = deque()
         self.slots = SlotTable(n_slots, cfg.block_size)
         self._ids = itertools.count()
@@ -405,52 +381,6 @@ class InferenceServer:
                 self.spec.warmup()
             self.watchdog.arm()
 
-    # -- performance attribution (ISSUE 13) ----------------------------
-    def _account_hbm(self) -> None:
-        """Declare bytes-by-owner from shapes/dtypes: params, the KV
-        slot pool, the prefix store's current residency, and (with
-        speculation on) the draft model's params and mirrored pool.
-        Re-run before each report so LRU churn in the prefix store is
-        reflected. Each owner also carries its busiest-device residency
-        (per_device_bytes): total/tp for tp-sharded owners, == total on a
-        single device — the per-chip number that actually bounds slots on
-        a mesh (ISSUE 14)."""
-        if self.hbm is None:
-            return
-        eng = self.engine
-        self.hbm.account("params", tree_bytes(eng.params),
-                         per_device_bytes=per_device_tree_bytes(eng.params))
-        if eng.kv_quant is not None:
-            # quantized pool (ISSUE 18): payload bytes stay the kv_pool
-            # owner, the fp32 scale planes get their own first-class
-            # owner so a capacity plan can see exactly what the scales
-            # cost. fp32 pools take the other branch untouched — the
-            # fp32 attrib report is byte-identical to pre-quant builds.
-            data, scales = quant_lib.split_scales(eng.pool.cache)
-            self.hbm.account("kv_pool", tree_bytes(data),
-                             per_device_bytes=per_device_tree_bytes(data))
-            self.hbm.account("kv_scales", tree_bytes(scales),
-                             per_device_bytes=per_device_tree_bytes(scales))
-        else:
-            self.hbm.account("kv_pool", tree_bytes(eng.pool.cache),
-                             per_device_bytes=per_device_tree_bytes(
-                                 eng.pool.cache))
-        store = eng.prefix_store
-        store_bytes = 0 if store is None else store.used_bytes
-        # prefix entries carry the pool's head-sharding, so per-device
-        # residency divides by the pool's shard count (analytic — entries
-        # are many small arrays, summing shard shapes per entry says the
-        # same thing slower)
-        self.hbm.account("prefix_store", store_bytes,
-                         per_device_bytes=store_bytes // eng.kv_shard_count)
-        if self.spec is not None:
-            de = self.spec.draft.engine
-            self.hbm.account("draft_params", tree_bytes(de.params),
-                             per_device_bytes=per_device_tree_bytes(de.params))
-            self.hbm.account("draft_pool", tree_bytes(de.pool.cache),
-                             per_device_bytes=per_device_tree_bytes(
-                                 de.pool.cache))
-
     def observe_quant_logit_error(self, err: float) -> None:
         """Record a sampled quantization quality number (max |Δlogit| of
         a KV round trip, ``quant.max_abs_logit_error``) into the
@@ -458,16 +388,6 @@ class InferenceServer:
         servers or when no registry is wired in."""
         if self._quant_err_gauge is not None:
             self._quant_err_gauge.set(float(err))
-
-    def attrib_report(self, include_live: bool = False) -> Dict[str, Any]:
-        """The mingpt-attrib/1 report for this server (raises when the
-        server was built without ``attrib=True``)."""
-        if self.attrib is None:
-            raise ValueError(
-                "attribution not enabled — construct with attrib=True")
-        self._account_hbm()
-        return build_attrib_report(self.attrib, self.hbm,
-                                   include_live=include_live)
 
     # -- submission ----------------------------------------------------
     def submit(self, request: Request) -> RequestHandle:
@@ -671,9 +591,6 @@ class InferenceServer:
         with self._phase("serve.prefix_lookup", handle) as ph:
             hit = self.engine.try_load_prefix(slot, handle.prompt_used)
             ph.set(hit_rows=hit)
-        if self.attrib is not None and hit > 0:
-            self.attrib.observe_call("prefix_load", ph.dur_s,
-                                     variant=f"b{hit}")
         self.metrics.on_prefix_lookup(
             hit > 0, hit, enabled=self.engine.prefix_store is not None)
         handle.prefix_rows = hit
@@ -708,30 +625,18 @@ class InferenceServer:
             )
             ph.set(padded=padded)
         self.metrics.on_prefill_chunk(end - pos, padded, ph.dur_s)
-        if self.attrib is not None:
-            self.attrib.observe_call("prefill", ph.dur_s,
-                                     variant=f"b{padded}")
         handle.prefill_pos = end
         if not last:
             return
         handle.prefilling = False
         if self.engine.prefix_store is not None:
-            ts0 = self.clock()
-            rows = self.engine.save_prefix(slot, prompt)
-            if self.attrib is not None and rows > 0:
-                self.attrib.observe_call("prefix_save", self.clock() - ts0,
-                                         variant=f"b{rows}")
+            self.engine.save_prefix(slot, prompt)
         if self.spec is not None:
             # draft prime: a full prefill of the prompt, or — when
             # migration parked this prompt's draft rows on us — a
             # device-side row install plus at most a tail chunk
-            tp0 = self.clock()
             mode = self.spec.prime(slot, prompt, self.slots.seeds[slot])
             self.metrics.on_spec_prime(mode)
-            if self.attrib is not None:
-                b = self.spec.draft.engine.bucket_for(len(prompt))
-                self.attrib.observe_call("draft_prefill",
-                                         self.clock() - tp0, variant=f"b{b}")
         ok = self._emit(handle, tok)
         now = self.clock()
         self.metrics.on_prefill(
@@ -797,7 +702,6 @@ class InferenceServer:
                 plain = [s for s in active if s not in spec_slots]
                 burst: Dict[int, List[int]] = {}
                 if plain:
-                    tdp = self.clock()
                     pos = st.positions
                     if spec_slots:
                         # park speculating lanes: the verify program is
@@ -809,36 +713,23 @@ class InferenceServer:
                         st.tokens, pos, st.temps, st.top_ks,
                         st.top_ps, st.do_sample, st.seeds, index,
                     )
-                    if self.attrib is not None:
-                        self.attrib.observe_call("decode",
-                                                 self.clock() - tdp)
                     for s in plain:
                         burst[s] = [int(nxt[s])]
                 if spec_slots:
                     smask = np.zeros(st.n_slots, bool)
                     smask[spec_slots] = True
-                    tdr = self.clock()
                     proposals = self.spec.propose(
                         st.tokens, st.positions, smask, st.seeds, index)
-                    if self.attrib is not None:
-                        self.attrib.observe_call(
-                            "draft_decode", self.clock() - tdr,
-                            n=self.spec.k)
                     fill_mask = np.zeros(st.n_slots, bool)
                     fill_toks = np.zeros(st.n_slots, np.int32)
                     fill_pos = np.zeros(st.n_slots, np.int32)
                     for s in spec_slots:
                         rows = [int(st.tokens[s])] + \
                             [int(t) for t in proposals[s]]
-                        tv0 = self.clock()
                         g = self.spec.verify(
                             s, rows, int(st.positions[s]),
                             float(st.temps[s]), int(st.top_ks[s]),
                             float(st.top_ps[s]), st.seeds[s], index[s])
-                        if self.attrib is not None:
-                            self.attrib.observe_call(
-                                "verify", self.clock() - tv0,
-                                variant=f"k{self.spec.k}")
                         n_acc = self.spec.accept_len(proposals[s], g)
                         burst[s] = [int(t) for t in g[:n_acc]]
                         if n_acc == self.spec.k + 1:

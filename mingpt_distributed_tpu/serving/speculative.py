@@ -387,28 +387,24 @@ class SpeculativeDecoder:
             "draft_decode": draft["decode"],
         }
 
-    def register_attrib(self, ledger, clock,
-                        family_prefix: str = "") -> None:
-        """Attribution registration (ISSUE 13): the verify program plus
-        the draft engine's families under the ``draft_`` prefix —
-        matching the ``compile_counts()`` family names, AOT and
-        jit-cache-neutral exactly like ``DecodeEngine.register_attrib``.
-        ``family_prefix`` prefixes every family (graftaudit registers a
+    def programs(self, family_prefix: str = ""):
+        """The verify program plus the draft engine's programs under the
+        ``draft_`` prefix, as ``DecodeEngine.programs`` yields them —
+        matching the ``compile_counts()`` family names.
+        ``family_prefix`` prefixes every family (graftaudit audits a
         quantized decoder beside the fp32 one as ``q8_*``)."""
-        ledger.register_aot(
-            f"{family_prefix}verify", self._verify_jit,
-            (self.target.params, self.target.pool.cache,
-             jnp.zeros(self.rows, jnp.int32),
-             np.int32(0), np.int32(0),
-             np.float32(1.0), np.int32(0), np.float32(1.0),
-             np.uint32(0), np.int32(0)),
-            clock, variant=f"k{self.k}")
-        self.draft.engine.register_attrib(
-            ledger, clock, family_prefix=f"{family_prefix}draft_")
+        yield (f"{family_prefix}verify", f"k{self.k}", self._verify_jit,
+               (self.target.params, self.target.pool.cache,
+                jnp.zeros(self.rows, jnp.int32),
+                np.int32(0), np.int32(0),
+                np.float32(1.0), np.int32(0), np.float32(1.0),
+                np.uint32(0), np.int32(0)), {})
+        yield from self.draft.engine.programs(
+            family_prefix=f"{family_prefix}draft_")
 
     def audit_contracts(self, family_prefix: str = "") -> Dict[str, dict]:
-        """Audit contracts (ISSUE 15) for the families
-        ``register_attrib`` registers: verify is a model-forwarding
+        """Audit contracts (ISSUE 15) for the families ``programs``
+        yields: verify is a model-forwarding
         family on the target engine — same collectives/donation/sharding
         contract as the target's prefill — and the draft families are
         the draft engine's own contracts under the ``draft_`` prefix."""

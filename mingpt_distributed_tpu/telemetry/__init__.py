@@ -7,8 +7,7 @@ The whole stack reports through this package:
   :class:`MetricsRegistry`; ``RateWindow`` (the shared windowed-rate
   plumbing) lives here too.
 * ``peaks``     — the single roofline table (``PEAK_FLOPS`` /
-  ``PEAK_HBM_BYTES``) both ``training/metrics.py`` and ``bench.py``
-  consume.
+  ``PEAK_HBM_BYTES``) ``training/metrics.py`` consumes.
 * ``spans``     — monotonic-clock nested spans in a bounded ring with an
   optional JSONL sink, plus ``log_event`` (prefixed, attributable
   replacement for bare prints in multi-process paths).
@@ -37,19 +36,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-from mingpt_distributed_tpu.telemetry.attribution import (
-    ATTRIB_SCHEMA,
-    HBMLedger,
-    ProgramLedger,
-    build_attrib_report,
-    dump_attrib_report,
-    kv_cache_bytes,
-    per_device_tree_bytes,
-    render_attrib_report,
-    timed_aot_compile,
-    tree_bytes,
-    validate_attrib_report,
-)
 from mingpt_distributed_tpu.telemetry.export import (
     SCHEMA_VERSION,
     JsonlEventSink,
@@ -69,10 +55,8 @@ from mingpt_distributed_tpu.telemetry.flightrec import (
 from mingpt_distributed_tpu.telemetry.peaks import (
     PEAK_FLOPS,
     PEAK_HBM_BYTES,
-    PEAK_HBM_CAPACITY,
     peak_flops_per_chip,
     peak_hbm_bytes_per_chip,
-    peak_hbm_capacity_per_chip,
 )
 from mingpt_distributed_tpu.telemetry.registry import (
     LATENCY_BUCKETS_S,
@@ -113,7 +97,6 @@ from mingpt_distributed_tpu.telemetry.watchdog import (
 )
 
 __all__ = [
-    "ATTRIB_SCHEMA",
     "FLIGHT_SCHEMA",
     "SCHEMA_VERSION",
     "SLO_SCHEMA",
@@ -121,16 +104,13 @@ __all__ = [
     "LATENCY_BUCKETS_S",
     "PEAK_FLOPS",
     "PEAK_HBM_BYTES",
-    "PEAK_HBM_CAPACITY",
     "Counter",
     "FlightRecorder",
     "Gauge",
-    "HBMLedger",
     "Histogram",
     "JsonlEventSink",
     "MetricFamily",
     "MetricsRegistry",
-    "ProgramLedger",
     "RateWindow",
     "RecompileError",
     "RecompileWatchdog",
@@ -139,14 +119,11 @@ __all__ = [
     "TelemetryServer",
     "TraceContext",
     "TraceRecorder",
-    "build_attrib_report",
     "diff_slo_reports",
-    "dump_attrib_report",
     "evaluate_slos",
     "exact_quantile",
     "get_registry",
     "get_tracer",
-    "kv_cache_bytes",
     "load_flight_dir",
     "load_trace_jsonl",
     "log_event",
@@ -155,20 +132,14 @@ __all__ = [
     "parse_slo_spec",
     "peak_flops_per_chip",
     "peak_hbm_bytes_per_chip",
-    "peak_hbm_capacity_per_chip",
     "process_index",
     "register_build_info",
-    "render_attrib_report",
     "render_fleet_prometheus",
     "render_prometheus",
     "render_slo_diff",
     "render_slo_report",
-    "timed_aot_compile",
     "trace_baggage",
     "trace_sink",
-    "per_device_tree_bytes",
-    "tree_bytes",
-    "validate_attrib_report",
     "validate_trace_records",
 ]
 

@@ -435,19 +435,17 @@ class TelemetryServer:
     ephemeral port (exposed as ``.port``) — what the CI smoke uses so
     parallel runs never collide.
 
-    ``health_provider`` / ``flight_provider`` / ``attrib_provider`` /
-    ``metrics_provider`` are settable attributes (read per request, so
-    they can be wired after backend construction): ``health_provider``
-    returns a dict merged into the healthz document — serve.py wires
+    ``health_provider`` / ``flight_provider`` / ``metrics_provider``
+    are settable attributes (read per request, so they can be wired
+    after backend construction): ``health_provider`` returns a dict
+    merged into the healthz document — serve.py wires
     ``Router.health_report`` so /healthz carries per-replica breaker
     state and health-gate reasons (ISSUE 10) — ``flight_provider``
     returns a flight snapshot document (without one ``/debug/flight``
-    is 404), ``attrib_provider`` returns the mingpt-attrib/1 (or
-    fleet-wrapped) performance-attribution report served as JSON on
-    ``/attrib`` (404 without one — ISSUE 13), and ``metrics_provider``
-    overrides the ``/metrics`` body — the fleet router installs
-    ``render_fleet_prometheus`` over the per-replica registries here so
-    one scrape covers every replica under a ``replica`` label."""
+    is 404), and ``metrics_provider`` overrides the ``/metrics`` body —
+    the fleet router installs ``render_fleet_prometheus`` over the
+    per-replica registries here so one scrape covers every replica
+    under a ``replica`` label."""
 
     def __init__(
         self,
@@ -456,13 +454,11 @@ class TelemetryServer:
         host: str = "127.0.0.1",
         health_provider=None,
         flight_provider=None,
-        attrib_provider=None,
         metrics_provider=None,
     ):
         self.registry = registry
         self.health_provider = health_provider
         self.flight_provider = flight_provider
-        self.attrib_provider = attrib_provider
         self.metrics_provider = metrics_provider
         self._t0 = time.time()
         outer = self
@@ -476,18 +472,6 @@ class TelemetryServer:
                             if mp is None else mp())
                     body = page.encode()
                     ctype = "text/plain; version=0.0.4; charset=utf-8"
-                elif path == "/attrib":
-                    ap = outer.attrib_provider
-                    if ap is None:
-                        self.send_error(
-                            404, "no attribution ledger configured")
-                        return
-                    try:
-                        doc = ap()
-                    except Exception as e:
-                        doc = {"error": repr(e)}
-                    body = json.dumps(doc, sort_keys=True).encode()
-                    ctype = "application/json"
                 elif path == "/healthz":
                     doc = {
                         "status": "ok",

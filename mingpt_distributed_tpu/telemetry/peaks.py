@@ -1,8 +1,8 @@
 """Roofline peak tables — the single source of truth (ISSUE 5 satellite).
 
-``bench.py`` and ``training/metrics.py`` used to each consult a copy of
-these numbers; both now import from here, so a new chip generation is
-added in exactly one place. Public numbers throughout.
+``training/metrics.py`` reads these for ``train.py``'s MFU log line, so
+a new chip generation is added in exactly one place. Public numbers
+throughout.
 """
 
 from __future__ import annotations
@@ -12,10 +12,8 @@ from typing import Dict, Optional
 __all__ = [
     "PEAK_FLOPS",
     "PEAK_HBM_BYTES",
-    "PEAK_HBM_CAPACITY",
     "peak_flops_per_chip",
     "peak_hbm_bytes_per_chip",
-    "peak_hbm_capacity_per_chip",
 ]
 
 # Peak dense bf16 FLOP/s per chip, for MFU.
@@ -51,22 +49,6 @@ PEAK_HBM_BYTES: Dict[str, float] = {
 }
 
 
-# HBM capacity per chip (bytes) — the ceiling the attribution layer's
-# headroom gauge reports against (telemetry/attribution.py), distinct
-# from the PEAK_HBM_BYTES *bandwidth* table above.
-PEAK_HBM_CAPACITY: Dict[str, float] = {
-    "TPU v4": 32e9,
-    "TPU v5 lite": 16e9,  # v5e
-    "TPU v5e": 16e9,
-    "TPU v5p": 95e9,
-    "TPU v5": 95e9,  # v5p (bare "TPU v5" device_kind spelling)
-    "TPU v6 lite": 32e9,  # v6e (Trillium)
-    "TPU v6e": 32e9,
-    "TPU v7x": 192e9,
-    "TPU v7": 192e9,  # Ironwood
-}
-
-
 def _chip_lookup(table: Dict[str, float]) -> Optional[float]:
     """This process's chip in ``table`` — longest-prefix-wins by dict
     order (see the ordering note above). A non-TPU backend has no peak
@@ -95,7 +77,3 @@ def peak_flops_per_chip() -> Optional[float]:
 
 def peak_hbm_bytes_per_chip() -> Optional[float]:
     return _chip_lookup(PEAK_HBM_BYTES)
-
-
-def peak_hbm_capacity_per_chip() -> Optional[float]:
-    return _chip_lookup(PEAK_HBM_CAPACITY)

@@ -26,9 +26,8 @@ pins this), finishes in seconds, and is byte-identically replayable.
   ServingFaultInjector as an optional chaos axis) and the versioned
   ``mingpt-traffic/1`` report with SLO grades and knee location.
 
-CLI: ``traffic.py`` at the repo root; ``bench.py --traffic`` embeds the
-sweep summary in the BENCH record; ``run_tests.sh --selftest-traffic``
-gates it.
+CLI: ``traffic.py`` at the repo root; ``run_tests.sh`` gates it with
+``--selftest-traffic``.
 """
 
 from mingpt_distributed_tpu.trafficlab.arrivals import (

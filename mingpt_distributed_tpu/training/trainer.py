@@ -60,7 +60,6 @@ from mingpt_distributed_tpu.telemetry import (
     SpanTracer,
     TelemetryServer,
     log_event,
-    tree_bytes,
 )
 
 TrainState = Dict[str, Any]  # {"params", "opt_state", "step"}
@@ -497,11 +496,6 @@ class GPTTrainer:
             out_shardings=self.repl,
         )
 
-        # performance attribution (ISSUE 13): set by register_attrib()
-        self._attrib = None
-        self._attrib_clock = None
-        self._attrib_variant = ""
-
         self.metrics = MetricsLogger(
             gpt_config,
             jsonl_path=config.metrics_jsonl if self.is_writer else None,
@@ -535,27 +529,13 @@ class GPTTrainer:
             "step": jnp.asarray(0, dtype=jnp.int32),
         }
 
-    # -- performance attribution (ISSUE 13) ----------------------------
-    def register_attrib(self, ledger, clock, hbm=None) -> None:
-        """Register the compiled train step with a ProgramLedger.
-
-        AOT-lowers ``self._train_step`` against abstract state/batch
-        avals — donation binds at execution, not lowering, so no live
-        buffer is consumed and the backend executable cache makes the
-        first real dispatch a cache hit. Family ``train_step``, variant
-        ``zero`` (dp-sharded update, ISSUE 9) or ``dense``. Per-step
-        host-visible wall time then feeds ``observe_call`` from the
-        train loop through the SAME injected clock — deterministic under
-        a virtual clock, never a library ``time.*`` read.
-
-        With an :class:`HBMLedger` the resident training state is
-        accounted too: params at canonical size, optimizer moments at
-        the zero-plan's per-device extent (``opt_moment_bytes``).
-        """
-        self._attrib = ledger
-        self._attrib_clock = clock
-        self._attrib_variant = (
-            "zero" if self.zero_plan is not None else "dense")
+    # -- compiled programs, as data ------------------------------------
+    def programs(self):
+        """Yield ``(family, variant, jitted, args, kwargs)`` for the
+        compiled train step, against abstract state/batch avals —
+        donation binds at execution, not lowering, so lowering these
+        consumes no live buffer. Family ``train_step``, variant ``zero``
+        (dp-sharded update, ISSUE 9) or ``dense``."""
         abstract = lambda x: jax.ShapeDtypeStruct(
             jnp.shape(x), jnp.result_type(x))
         state_abs = jax.tree.map(abstract, self.state)
@@ -563,19 +543,13 @@ class GPTTrainer:
         tok = jax.ShapeDtypeStruct(
             (self.config.batch_size, block), jnp.int32)
         rng_abs = jax.eval_shape(lambda: self.base_rng)
-        ledger.register_aot(
-            "train_step", self._train_step,
-            (state_abs, (tok, tok), rng_abs),
-            clock, variant=self._attrib_variant)
-        if hbm is not None:
-            params_abs = state_abs["params"]
-            hbm.account("params", tree_bytes(params_abs))
-            hbm.account("opt_state", zero_lib.opt_moment_bytes(
-                params_abs, self.zero_plan))
+        yield ("train_step",
+               "zero" if self.zero_plan is not None else "dense",
+               self._train_step, (state_abs, (tok, tok), rng_abs), {})
 
     def audit_contracts(self) -> dict:
         """Audit contract (ISSUE 15) for the ``train_step`` family
-        ``register_attrib`` registers. On a one-device mesh the lowered
+        ``programs`` yields. On a one-device mesh the lowered
         step must contain no collectives at all; on a real mesh the data/
         tensor/zero parallel forms all appear (psum grads, zero's
         reduce-scatter + all-gather, megatron gathers), so every reduce-
@@ -722,8 +696,6 @@ class GPTTrainer:
                 batch = self._put_batch(xy)
                 # the span measures host-visible step time: dispatch of step
                 # N plus the wait on step N-1 (the two-in-flight cap below)
-                ta0 = (self._attrib_clock()
-                       if self._attrib is not None else 0.0)
                 with self.tracer.span("train.step", step=py_step + 1):
                     self.state, m = self._train_step(
                         self.state, batch, self.base_rng
@@ -731,12 +703,6 @@ class GPTTrainer:
                     if prev_metrics is not None:
                         jax.block_until_ready(prev_metrics)
                     prev_metrics = m
-                if self._attrib is not None:
-                    # host-visible step time (dispatch N + wait on N-1),
-                    # read on the injected attribution clock
-                    self._attrib.observe_call(
-                        "train_step", self._attrib_clock() - ta0,
-                        variant=self._attrib_variant)
                 py_step = step = py_step + 1
                 consumed += 1
                 # jax.profiler trace window (SURVEY §5.1: the reference has

@@ -153,8 +153,8 @@ def build_argparser() -> argparse.ArgumentParser:
                         "prefill + prefix store + speculation composed "
                         "— greedy token parity within tolerance vs the "
                         "fp32 server, identical compile_counts per "
-                        "dtype, zero post-warmup recompiles, HBMLedger "
-                        "kv_pool+kv_scales <= 0.27x the fp32 bytes, and "
+                        "dtype, zero post-warmup recompiles, pool payload "
+                        "+ scale planes <= 0.27x the fp32 pool bytes, and "
                         "a sampled max-abs-logit-error gauge; then "
                         "exits")
     p.add_argument("--warmup", action="store_true",
@@ -191,8 +191,8 @@ def build_argparser() -> argparse.ArgumentParser:
                         "tp=1 (incl. chunked prefill, prefix hits and "
                         "speculation), with identical compile_counts(), "
                         "zero watchdog recompiles, head-sharded prefix "
-                        "entries and per-device pool bytes = total/2 in "
-                        "the attrib report; then exits")
+                        "entries and per-device pool bytes = total/2; "
+                        "then exits")
     p.add_argument("--metrics-port", type=int, default=None,
                    help="serve Prometheus /metrics + /healthz on this port "
                         "(0 = ephemeral port, printed at start); default: "
@@ -321,20 +321,6 @@ def build_argparser() -> argparse.ArgumentParser:
                         "respects the bandwidth budget; post a tampered "
                         "control frame and verify the typed reject plus "
                         "auth counter; then exits")
-    p.add_argument("--selftest-attrib", action="store_true",
-                   help="ISSUE 13 gate: per-program attribution ledger "
-                        "(prefill/decode/verify/draft/train families with "
-                        "cost_analysis flops + compile times), HBM "
-                        "bytes-by-owner vs live pool bytes, byte-identical "
-                        "mingpt-attrib/1 reports on a virtual clock, "
-                        "perf_diff zero-regression, /attrib + fleet-merged "
-                        "/metrics scrape; then exits")
-    p.add_argument("--attrib-json", default=None, metavar="PATH",
-                   help="enable the performance-attribution ledger "
-                        "(ISSUE 13) and write the mingpt-attrib/1 report "
-                        "there at shutdown; renderable via "
-                        "tools/trace_summary.py and diffable via "
-                        "tools/perf_diff.py")
     p.add_argument("overrides", nargs="*")
     return p
 
@@ -852,13 +838,14 @@ def selftest_quant(args) -> int:
     prefix hits and speculative bursts); ``compile_counts()`` identical
     per dtype (the dtype rides the compile key, it never adds
     executables); zero post-warmup recompiles on both servers;
-    HBMLedger kv_pool+kv_scales <= 0.27x the fp32 kv_pool bytes; the
+    pool payload + scale planes <= 0.27x the fp32 pool's bytes; the
     ``mingpt_serve_kv_dtype`` build-info gauge and a sampled
     ``mingpt_serve_quant_logit_err_max``; and that ``fp8`` resolves."""
     import jax
 
     from mingpt_distributed_tpu.config import GPTConfig
     from mingpt_distributed_tpu.models import gpt
+    from mingpt_distributed_tpu.parallel.zero import per_device_bytes
     from mingpt_distributed_tpu.serving import InferenceServer, Request
     from mingpt_distributed_tpu.serving import quant as quant_lib
     from mingpt_distributed_tpu.telemetry import (
@@ -880,7 +867,7 @@ def selftest_quant(args) -> int:
     def run_once(kv_dtype):
         reg = MetricsRegistry()
         srv = InferenceServer(
-            params, cfg, n_slots=2, registry=reg, attrib=True,
+            params, cfg, n_slots=2, registry=reg,
             prefill_buckets=(8, 48), prefill_chunk=6,
             prefix_cache_mb=0.5, warmup=True,
             draft_params=params, draft_cfg=cfg, spec_k=2,
@@ -931,16 +918,17 @@ def selftest_quant(args) -> int:
         print("selftest-quant FAIL: no speculative rounds on int8")
         rc = 1
 
-    # the hard bytes gate: payload + scale planes vs the fp32 pool
-    pd32 = srv32.attrib_report()["hbm"]["per_device_bytes"]
-    pd8 = srv8.attrib_report()["hbm"]["per_device_bytes"]
-    kv8 = pd8.get("kv_pool", 0) + pd8.get("kv_scales", 0)
-    ratio = kv8 / pd32["kv_pool"]
-    if "kv_scales" not in pd8 or pd8["kv_scales"] <= 0:
-        print("selftest-quant FAIL: no kv_scales HBM owner on int8")
+    # the hard bytes gate: payload + scale planes vs the fp32 pool, as
+    # the busiest device holds them
+    pool32, pool8 = srv32.engine.pool.cache, srv8.engine.pool.cache
+    bytes32, bytes8 = per_device_bytes(pool32), per_device_bytes(pool8)
+    ratio = bytes8 / bytes32
+    if per_device_bytes(quant_lib.split_scales(pool8)[1]) <= 0:
+        print("selftest-quant FAIL: no scale planes in the int8 pool")
         rc = 1
-    if "kv_scales" in pd32:
-        print("selftest-quant FAIL: fp32 report grew a kv_scales owner")
+    if sorted(pool32) != ["k", "v"]:
+        print(f"selftest-quant FAIL: fp32 pool grew leaves beyond k/v: "
+              f"{sorted(pool32)}")
         rc = 1
     if ratio > 0.27:
         print(f"selftest-quant FAIL: kv_pool+kv_scales ratio {ratio:.4f} "
@@ -975,8 +963,8 @@ def selftest_quant(args) -> int:
         print(f"selftest-quant FAIL: fp8 resolved to {q!r}")
         rc = 1
 
-    print(f"selftest-quant bytes: int8 kv_pool+kv_scales={kv8} "
-          f"fp32 kv_pool={pd32['kv_pool']} ratio={ratio:.4f}")
+    print(f"selftest-quant bytes: int8 payload+scales={bytes8} "
+          f"fp32 pool={bytes32} ratio={ratio:.4f}")
     print(f"selftest-quant err={err:.6f} counts={c8}")
     print("selftest-quant", "PASSED" if rc == 0 else "FAILED")
     return rc
@@ -1289,359 +1277,6 @@ def _chaos_scrape(tserver, has_flight: bool = False) -> int:
     return rc
 
 
-def selftest_attrib(args) -> int:
-    """The ISSUE 13 acceptance gate, CPU-only and fully deterministic.
-
-    * Every lifetime-compiled program family — prefill buckets, decode,
-      spec verify, draft prefill/decode, the train step — appears in the
-      ``mingpt-attrib/1`` report with nonzero cost_analysis FLOPs and a
-      recorded compile time, and the report strict-validates.
-    * The HBM ledger's serving-pool owners match the live device bytes
-      of those pools within 1% (they are computed from shapes/dtypes,
-      so in practice exactly).
-    * Two identical runs on the deterministic clock produce
-      byte-identical report dumps, and tools/perf_diff.py on that pair
-      reports zero regressions.
-    * ``/attrib`` serves the report and the fleet-merged ``/metrics``
-      page strict-parses with per-replica ``mingpt_attrib_*`` samples.
-    """
-    import importlib.util
-    import tempfile
-
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    from mingpt_distributed_tpu import telemetry
-    from mingpt_distributed_tpu.config import GPTConfig
-    from mingpt_distributed_tpu.models import gpt
-    from mingpt_distributed_tpu.serving import (
-        InferenceServer,
-        ReplicaSupervisor,
-        Request,
-        Router,
-        VirtualClock,
-        default_server_factory,
-    )
-    from mingpt_distributed_tpu.training.trainer import make_train_step
-
-    cfg = GPTConfig.make(
-        n_layer=2, n_head=2, n_embd=32, vocab_size=96, block_size=48,
-        embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32",
-    )
-    params = gpt.init(jax.random.key(0), cfg)
-    canned = ["O God, O God!", "Once more unto", "All the world's"]
-    if args.prefix_cache_mb > 0:
-        canned += ["Once more unto the breach", "Once more unto the wall!"]
-    prompts = [[ord(c) % cfg.vocab_size for c in s] for s in canned]
-    max_new = 8
-    spec_k = args.spec_k if args.spec_k > 0 else 2
-
-    class TickingClock:
-        """Deterministic injected clock: a fixed quantum per read, so
-        two identical runs observe identical timestamps (and therefore
-        identical compile_s / device_s) regardless of wall time."""
-
-        def __init__(self):
-            self.t = 0.0
-
-        def __call__(self) -> float:
-            self.t += 1e-4
-            return self.t
-
-    def run_once():
-        """One instrumented serving run on a PRIVATE registry (the
-        byte-identity pair must not share mutable state), plus the
-        compiled train step registered through the same ledger."""
-        clock = TickingClock()
-        srv = InferenceServer(
-            params, cfg, n_slots=2, clock=clock, attrib=True,
-            draft_params=params, draft_cfg=cfg, spec_k=spec_k,
-            **_server_kwargs(args))
-        srv.generate_batch(
-            [Request(prompt=p, max_new_tokens=max_new) for p in prompts])
-        opt = optax.adamw(1e-3)
-        state_abs = jax.eval_shape(lambda: {
-            "params": params,
-            "opt_state": opt.init(params),
-            "step": jnp.asarray(0, jnp.int32),
-        })
-        tok = jax.ShapeDtypeStruct((2, 16), jnp.int32)
-        rng_abs = jax.eval_shape(lambda: jax.random.key(0))
-        srv.attrib.register_aot(
-            "train_step", jax.jit(make_train_step(cfg, opt)),
-            (state_abs, (tok, tok), rng_abs), clock, variant="dense")
-        return srv, srv.attrib_report()
-
-    rc = 0
-    if args.metrics_port is None:
-        args.metrics_port = 0  # the scrape assertions are part of the gate
-    reg, tserver = _start_telemetry(args)
-
-    srv_a, report_a = run_once()
-    try:
-        telemetry.validate_attrib_report(report_a)
-    except ValueError as e:
-        print(f"selftest-attrib FAIL: report does not validate: {e}")
-        return 1
-
-    rows = {(r["family"], r["variant"]): r for r in report_a["programs"]}
-    families = {fam for fam, _ in rows}
-    expected = {"prefill", "decode", "verify", "draft_prefill",
-                "draft_decode", "train_step"}
-    if args.prefix_cache_mb > 0:
-        expected |= {"prefix_load", "prefix_save"}
-    missing = expected - families
-    if missing:
-        print(f"selftest-attrib FAIL: families missing from report: "
-              f"{sorted(missing)} (got {sorted(families)})")
-        rc = 1
-    for (fam, variant), row in sorted(rows.items()):
-        if fam in expected and not row["flops"]:
-            print(f"selftest-attrib FAIL: {fam}:{variant} has no "
-                  f"cost_analysis flops ({row['flops']!r})")
-            rc = 1
-        if fam in expected and row["compile_s"] <= 0:
-            print(f"selftest-attrib FAIL: {fam}:{variant} recorded no "
-                  f"compile time")
-            rc = 1
-    # invocation sampling: with speculation on, every decode round goes
-    # through verify + draft_decode (the plain decode program compiles
-    # but stays cold — its calls counter correctly reads 0)
-    for fam in ("prefill", "verify", "draft_decode"):
-        called = sum(r["calls"] for (f, _), r in rows.items() if f == fam)
-        if fam in families and called < 1:
-            print(f"selftest-attrib FAIL: no invocations sampled for "
-                  f"{fam}")
-            rc = 1
-
-    # HBM ledger vs the actual serving pools: analytic bytes-by-owner
-    # must match live device bytes within 1% (shapes/dtypes => exact)
-    owners = report_a["hbm"]["owners"]
-    pools = {
-        "kv_pool": srv_a.engine.pool.cache,
-        "draft_pool": srv_a.spec.draft.engine.pool.cache,
-    }
-    for owner, pool in pools.items():
-        live = sum(int(a.nbytes) for a in jax.tree.leaves(pool))
-        got = owners.get(owner, 0)
-        if abs(got - live) > 0.01 * live:
-            print(f"selftest-attrib FAIL: hbm owner {owner} accounts "
-                  f"{got} bytes but the pool holds {live}")
-            rc = 1
-    if owners.get("params", 0) <= 0:
-        print("selftest-attrib FAIL: params not accounted in hbm ledger")
-        rc = 1
-    # per-device accounting (ISSUE 14): unsharded owners report their
-    # full bytes per device; with >= 2 devices a tp=2 server's ledger
-    # must match what the runtime actually holds per device
-    # (jax.live_arrays(), bucketed by shard device)
-    per_dev = report_a["hbm"]["per_device_bytes"]
-    for owner in pools:
-        if per_dev.get(owner) != owners.get(owner):
-            print(f"selftest-attrib FAIL: unsharded owner {owner} "
-                  f"per-device {per_dev.get(owner)} != total "
-                  f"{owners.get(owner)}")
-            rc = 1
-    if len(jax.devices()) >= 2:
-        from mingpt_distributed_tpu.parallel.mesh import (
-            MeshConfig,
-            make_mesh,
-        )
-
-        mesh = make_mesh(MeshConfig(tp=2), devices=jax.devices()[:2])
-        srv_sh = InferenceServer(params, cfg, n_slots=2, attrib=True,
-                                 mesh=mesh, **_server_kwargs(args))
-        sh_report = srv_sh.attrib_report()
-        sh_owner = sh_report["hbm"]["owners"]["kv_pool"]
-        sh_pd = sh_report["hbm"]["per_device_bytes"]["kv_pool"]
-        if sh_pd * 2 != sh_owner:
-            print(f"selftest-attrib FAIL: sharded kv_pool per-device "
-                  f"{sh_pd} != total {sh_owner} / 2")
-            rc = 1
-        pool_ids = {id(a) for a in jax.tree.leaves(srv_sh.engine.pool.cache)}
-        live_per_dev = {}
-        for arr in jax.live_arrays():
-            if id(arr) in pool_ids:
-                for shard in arr.addressable_shards:
-                    live_per_dev[shard.device] = (
-                        live_per_dev.get(shard.device, 0)
-                        + int(shard.data.nbytes))
-        if sorted(live_per_dev.values()) != [sh_pd, sh_pd]:
-            print(f"selftest-attrib FAIL: ledger says {sh_pd} pool bytes "
-                  f"per device but live_arrays holds "
-                  f"{sorted(live_per_dev.values())}")
-            rc = 1
-    audit = srv_a.hbm.audit()
-    if audit["live_bytes"] < owners.get("kv_pool", 0):
-        print(f"selftest-attrib FAIL: live_arrays audit below the pool "
-              f"bytes: {audit}")
-        rc = 1
-    if srv_a.watchdog.recompiles:
-        print(f"selftest-attrib FAIL: attribution registration tripped "
-              f"the watchdog ({srv_a.watchdog.recompiles} recompiles)")
-        rc = 1
-
-    # byte-identical reports on the deterministic clock, and perf_diff
-    # over the pair must find zero regressions
-    _, report_b = run_once()
-    dump_a = telemetry.dump_attrib_report(report_a)
-    dump_b = telemetry.dump_attrib_report(report_b)
-    if dump_a != dump_b:
-        print("selftest-attrib FAIL: two identical runs produced "
-              "different report bytes")
-        rc = 1
-    if args.attrib_json:
-        with open(args.attrib_json, "w") as f:
-            f.write(dump_a + "\n")
-        print(f"[serve] attribution report written to {args.attrib_json}",
-              file=sys.stderr)
-    tools_dir = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "tools")
-    pd_spec = importlib.util.spec_from_file_location(
-        "perf_diff", os.path.join(tools_dir, "perf_diff.py"))
-    perf_diff = importlib.util.module_from_spec(pd_spec)
-    pd_spec.loader.exec_module(perf_diff)
-    with tempfile.TemporaryDirectory() as tmp:
-        pa, pb = os.path.join(tmp, "a.json"), os.path.join(tmp, "b.json")
-        for path, dump in ((pa, dump_a), (pb, dump_b)):
-            with open(path, "w") as f:
-                f.write(dump + "\n")
-        pd_rc = perf_diff.main([pa, pb])
-    if pd_rc != 0:
-        print(f"selftest-attrib FAIL: perf_diff found regressions "
-              f"between identical runs (rc={pd_rc})")
-        rc = 1
-
-    # /attrib endpoint: the single-server report over HTTP
-    if tserver is not None:
-        tserver.attrib_provider = lambda: srv_a.attrib_report()
-        rc |= _attrib_scrape_single(tserver, expected)
-
-    # fleet: 2 instrumented replicas, merged scrape + fleet report
-    supervisor = ReplicaSupervisor(
-        default_server_factory(params, cfg, n_slots=2, attrib=True,
-                               **_server_kwargs(args)),
-        n_replicas=2,
-        clock=VirtualClock(tick_s=0.001),
-        registry=reg,
-    )
-    router = Router(supervisor)
-    handles = [router.submit(Request(prompt=p, max_new_tokens=max_new))
-               for p in prompts]
-    router.run_until_drained(max_steps=5000)
-    if any(h.finish_reason != "length" for h in handles):
-        print("selftest-attrib FAIL: fleet requests did not complete")
-        rc = 1
-    fleet_doc = router.attrib_report()
-    if fleet_doc.get("schema") != "mingpt-attrib-fleet/1" or \
-            set(fleet_doc.get("replicas", {})) != {"replica0", "replica1"}:
-        print(f"selftest-attrib FAIL: fleet attrib report malformed: "
-              f"{sorted(fleet_doc.get('replicas', {}))}")
-        rc = 1
-    for name, doc in fleet_doc.get("replicas", {}).items():
-        try:
-            telemetry.validate_attrib_report(doc)
-        except ValueError as e:
-            print(f"selftest-attrib FAIL: fleet replica {name} report "
-                  f"invalid: {e}")
-            rc = 1
-    if tserver is not None:
-        tserver.attrib_provider = router.attrib_report
-        tserver.metrics_provider = router.fleet_metrics_page
-        rc |= _attrib_scrape_fleet(tserver)
-        tserver.close()
-
-    print(f"selftest-attrib report: {len(rows)} program rows, "
-          f"families {sorted(families)}")
-    print("selftest-attrib hbm:", json.dumps(owners))
-    print("selftest-attrib", "PASSED" if rc == 0 else "FAILED")
-    return rc
-
-
-def _attrib_scrape_single(tserver, expected) -> int:
-    """GET /attrib and re-assert the family set on the HTTP copy — the
-    endpoint must serve the same strict-valid document the in-process
-    report carries."""
-    import urllib.request
-
-    from mingpt_distributed_tpu import telemetry
-
-    rc = 0
-    with urllib.request.urlopen(tserver.url("/attrib"), timeout=10) as resp:
-        doc = json.loads(resp.read().decode())
-    try:
-        telemetry.validate_attrib_report(doc)
-    except ValueError as e:
-        print(f"selftest-attrib FAIL: /attrib document invalid: {e}")
-        return 1
-    got = {r["family"] for r in doc["programs"]}
-    if not expected <= got:
-        print(f"selftest-attrib FAIL: /attrib lacks families "
-              f"{sorted(expected - got)}")
-        rc = 1
-    bad = [r for r in doc["programs"]
-           if r["family"] in expected and not r["flops"]]
-    if bad:
-        print(f"selftest-attrib FAIL: /attrib families without flops: "
-              f"{[(r['family'], r['variant']) for r in bad]}")
-        rc = 1
-    print(f"selftest-attrib /attrib: {len(doc['programs'])} rows, "
-          f"{len(got)} families")
-    return rc
-
-
-def _attrib_scrape_fleet(tserver) -> int:
-    """The fleet-merged /metrics page must strict-parse (ONE TYPE line
-    per family) and carry per-replica mingpt_attrib_* samples under the
-    replica label; /attrib must serve the per-replica report union."""
-    import urllib.request
-
-    from mingpt_distributed_tpu.telemetry import parse_prometheus
-
-    rc = 0
-    with urllib.request.urlopen(tserver.url("/metrics"), timeout=10) as resp:
-        text = resp.read().decode()
-    try:
-        parsed = parse_prometheus(text)
-    except ValueError as e:
-        print(f"selftest-attrib FAIL: fleet-merged /metrics is not valid "
-              f"exposition text: {e}")
-        return 1
-    for name, kind in (("mingpt_attrib_flops", "gauge"),
-                       ("mingpt_attrib_calls_total", "counter"),
-                       ("mingpt_attrib_hbm_bytes", "gauge"),
-                       ("mingpt_fleet_replica_up", "gauge")):
-        if parsed["types"].get(name) != kind:
-            print(f"selftest-attrib FAIL: merged page lacks {kind} "
-                  f"{name} (got {parsed['types'].get(name)})")
-            rc = 1
-    per_replica = {}
-    for n, labels, v in parsed["samples"]:
-        if n == "mingpt_attrib_flops":
-            if "replica" not in labels:
-                print(f"selftest-attrib FAIL: unlabelled attrib sample "
-                      f"on the merged page: {labels}")
-                rc = 1
-                continue
-            if labels.get("family") == "decode" and v > 0:
-                per_replica[labels["replica"]] = v
-    if set(per_replica) != {"replica0", "replica1"}:
-        print(f"selftest-attrib FAIL: merged page missing per-replica "
-              f"decode flops (got {sorted(per_replica)})")
-        rc = 1
-    with urllib.request.urlopen(tserver.url("/attrib"), timeout=10) as resp:
-        doc = json.loads(resp.read().decode())
-    if set(doc.get("replicas", {})) != {"replica0", "replica1"}:
-        print(f"selftest-attrib FAIL: /attrib fleet document lacks "
-              f"replicas: {sorted(doc.get('replicas', {}))}")
-        rc = 1
-    print(f"selftest-attrib fleet scrape: {len(parsed['samples'])} "
-          f"samples, decode flops per replica "
-          f"{ {k: per_replica[k] for k in sorted(per_replica)} }")
-    return rc
-
-
 def selftest_sharded(args) -> int:
     """The ISSUE 14 acceptance gate, CPU-only via forced host devices.
 
@@ -1651,14 +1286,14 @@ def selftest_sharded(args) -> int:
     head-parallel and the megatron param split reassembles exactly),
     with identical ``compile_counts()`` (the mesh rides the compile key,
     it never adds executables), zero post-warmup recompiles, prefix-hit
-    parity, head-sharded prefix entries, and ``per_device_bytes = total
-    / 2`` for the sharded pools in the attribution report."""
+    parity, head-sharded prefix entries, and per-device pool bytes =
+    total / 2 on the mesh."""
     import jax
 
-    from mingpt_distributed_tpu import telemetry
     from mingpt_distributed_tpu.config import GPTConfig
     from mingpt_distributed_tpu.models import gpt
     from mingpt_distributed_tpu.parallel.mesh import MeshConfig, make_mesh
+    from mingpt_distributed_tpu.parallel.zero import per_device_bytes
     from mingpt_distributed_tpu.serving import InferenceServer, Request
 
     if len(jax.devices()) < 2:
@@ -1678,7 +1313,7 @@ def selftest_sharded(args) -> int:
     max_new = 10
 
     def run_once(mesh):
-        srv = InferenceServer(params, cfg, n_slots=2, attrib=True,
+        srv = InferenceServer(params, cfg, n_slots=2,
                               mesh=mesh, **_server_kwargs(args))
         handles = srv.generate_batch(
             [Request(prompt=p, max_new_tokens=max_new) for p in prompts])
@@ -1735,30 +1370,24 @@ def selftest_sharded(args) -> int:
                           f"{arr.shape} -> {shard}")
                     rc = 1
 
-    # attribution: the sharded pools' per-device residency is total/2,
-    # and the report still strict-validates with the new block
-    report = srv2.attrib_report()
-    try:
-        telemetry.validate_attrib_report(report)
-    except ValueError as e:
-        print(f"selftest-sharded FAIL: attrib report invalid: {e}")
-        return 1
-    owners = report["hbm"]["owners"]
-    per_dev = report["hbm"]["per_device_bytes"]
-    for owner in ("kv_pool",):
-        if per_dev.get(owner, -1) * 2 != owners.get(owner, 0):
-            print(f"selftest-sharded FAIL: {owner} per-device bytes "
-                  f"{per_dev.get(owner)} != total {owners.get(owner)} / 2")
-            rc = 1
-    base_owners = srv1.attrib_report()["hbm"]["owners"]
-    if owners.get("kv_pool") != base_owners.get("kv_pool"):
+    # the sharded pool's per-device residency is total/2, read from the
+    # shards the devices hold, and sharding leaves the total unchanged
+    pool1, pool2 = srv1.engine.pool.cache, srv2.engine.pool.cache
+    total1 = sum(a.nbytes for a in pool1.values())
+    total2 = sum(a.nbytes for a in pool2.values())
+    per_dev = per_device_bytes(pool2)
+    if per_dev * 2 != total2:
+        print(f"selftest-sharded FAIL: pool per-device bytes {per_dev} "
+              f"!= total {total2} / 2")
+        rc = 1
+    if total2 != total1:
         print(f"selftest-sharded FAIL: sharding changed the pool's total "
-              f"bytes: tp1={base_owners.get('kv_pool')} "
-              f"tp2={owners.get('kv_pool')}")
+              f"bytes: tp1={total1} tp2={total2}")
         rc = 1
 
     print(f"selftest-sharded compile_counts: {c2}")
-    print(f"selftest-sharded hbm: total={owners} per_device={per_dev}")
+    print(f"selftest-sharded pool bytes: total={total2} "
+          f"per_device={per_dev}")
     print("selftest-sharded", "PASSED" if rc == 0 else "FAILED")
     return rc
 
@@ -2635,8 +2264,6 @@ def main(argv=None) -> int:
         return selftest_crosshost(args)
     if args.selftest_sharded:
         return selftest_sharded(args)
-    if args.selftest_attrib:
-        return selftest_attrib(args)
     if args.selftest_chaos:
         return selftest_chaos(args)
     if args.selftest_spec:
@@ -2748,7 +2375,6 @@ def main(argv=None) -> int:
                 "server": {"n_slots": args.slots,
                            "max_queue": args.queue_limit,
                            "default_deadline_s": args.deadline_s,
-                           "attrib": bool(args.attrib_json),
                            **_server_kwargs(args)},
                 "serving_faults": args.chaos_spec,
             }
@@ -2775,8 +2401,6 @@ def main(argv=None) -> int:
                 # fleet scrape over RPC: worker /metrics pages merged
                 # under the replica label
                 tserver.metrics_provider = router.fleet_metrics_page
-                if args.attrib_json:
-                    tserver.attrib_provider = router.attrib_report
             return router
         if args.replicas > 1 or autoscale:
             from mingpt_distributed_tpu.serving import (
@@ -2794,7 +2418,6 @@ def main(argv=None) -> int:
                     params, gpt_cfg, n_slots=args.slots,
                     max_queue=args.queue_limit,
                     default_deadline_s=args.deadline_s,
-                    attrib=bool(args.attrib_json),
                     **spec_kw,
                     **mesh_kw,
                     **_server_kwargs(args)),
@@ -2810,10 +2433,7 @@ def main(argv=None) -> int:
             if tserver is not None:
                 tserver.health_provider = router.health_report
                 # fleet-wide observability (ISSUE 13): union scrape page
-                # + per-replica attribution reports
                 tserver.metrics_provider = router.fleet_metrics_page
-                if args.attrib_json:
-                    tserver.attrib_provider = router.attrib_report
             return router
         server = InferenceServer(params, gpt_cfg, n_slots=args.slots,
                                  on_token=stream_cb,
@@ -2823,12 +2443,9 @@ def main(argv=None) -> int:
                                  default_deadline_s=args.deadline_s,
                                  registry=reg,
                                  trace_recorder=recorder,
-                                 attrib=bool(args.attrib_json),
                                  **spec_kw,
                                  **mesh_kw,
                                  **_server_kwargs(args))
-        if tserver is not None and args.attrib_json:
-            tserver.attrib_provider = lambda: server.attrib_report()
         if flight is not None:
             server.watchdog.on_recompile = (
                 lambda grown: flight.dump("watchdog_recompile",
@@ -2848,16 +2465,6 @@ def main(argv=None) -> int:
         if guard.stop_requested and flight is not None:
             flight.dump("sigterm_drain")
         _slo_report(args, recorder)
-        if args.attrib_json and hasattr(backend, "attrib_report"):
-            from mingpt_distributed_tpu.telemetry import dump_attrib_report
-
-            doc = backend.attrib_report()
-            with open(args.attrib_json, "w") as f:
-                f.write(json.dumps(doc, sort_keys=True, indent=2)
-                        if "replicas" in doc else dump_attrib_report(doc))
-                f.write("\n")
-            print(f"[serve] attribution report written to "
-                  f"{args.attrib_json}", file=sys.stderr)
         if recorder is not None:
             recorder.close()
         if args.metrics_json:

@@ -1,21 +1,19 @@
-"""GL007/GL008 fixtures — wall-clock and naming temptations in
-attribution-shaped code.
+"""GL007/GL008 fixtures — wall-clock and naming temptations in code
+that times compiled programs and registers gauges for them.
 
-The attribution ledger's guarantee (ISSUE 13) is that two serving runs
-on the same VirtualClock dump byte-identical ``mingpt-attrib/1``
-reports — which holds only while every compile/device timestamp is
-read from the injected clock, never the wall. These are the shapes
-that would quietly break it, plus the ledger's gauge-family naming
-contract.
+A report built on a VirtualClock is byte-identical across two runs only
+while every compile/device timestamp is read from the injected clock,
+never the wall. These are the shapes that would quietly break that,
+plus the gauge-family naming contract.
 
 Positives: timing an AOT compile with ``time.perf_counter()``;
 sampling a device interval through an imported ``perf_counter``
-alias; an off-convention ledger gauge name.
+alias; an off-convention gauge name.
 Suppressed: one wall-clock headroom probe and one bad name, inline
 disable.
 Negatives: the injected-clock compile timer, a ``wall_ts`` report
-stamp, an injectable clock default, and the ledger's real
-``mingpt_attrib_*`` registrations.
+stamp, an injectable clock default, and conforming ``mingpt_attrib_*``
+registrations.
 """
 import time
 from time import perf_counter
@@ -40,8 +38,8 @@ def timed_compile_bad(jit_fn, args):
     return compiled, time.perf_counter() - t0  # expect: GL007
 
 
-def observe_call_bad(ledger, family, started):
-    ledger.observe_call(family, perf_counter() - started)  # expect: GL007
+def observe_bad(ledger, family, started):
+    ledger.observe(family, perf_counter() - started)  # expect: GL007
 
 
 def hbm_probe_wall_suppressed():
@@ -65,7 +63,7 @@ def make_ledger_clock(clock=time.perf_counter):  # clean: injectable ref
 
 
 FLOPS = REG.gauge("mingpt_attrib_flops", labels=("family", "variant"))
-CALLS = REG.counter("mingpt_attrib_calls_total")  # clean: real family
+CALLS = REG.counter("mingpt_attrib_calls_total")  # clean: conforming
 HBM = REG.gauge("mingpt_attrib_hbm_bytes", labels=("owner",))
 BAD_NAME = REG.gauge("attrib_mfu")  # expect: GL008
 BAD_SUPPRESSED = REG.gauge("hbm_bytes")  # graftlint: disable=GL008

@@ -202,8 +202,7 @@ def test_peak_lookup_raises_for_unknown_tpu_and_is_none_on_cpu(monkeypatch):
     monkeypatch.setattr(jax, "devices", _fake_devices("tpu", "TPU v5 lite"))
     assert peaks.peak_flops_per_chip() == 197e12
     monkeypatch.setattr(jax, "devices", _fake_devices("tpu", "TPU v99"))
-    for lookup in (peaks.peak_flops_per_chip, peaks.peak_hbm_bytes_per_chip,
-                   peaks.peak_hbm_capacity_per_chip):
+    for lookup in (peaks.peak_flops_per_chip, peaks.peak_hbm_bytes_per_chip):
         with pytest.raises(LookupError, match="TPU v99"):
             lookup()
 

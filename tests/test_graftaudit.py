@@ -1,7 +1,6 @@
-"""graftaudit tests (ISSUE 15): HLO parsing on synthetic text, the four
+"""graftaudit tests (ISSUE 15): HLO parsing on synthetic text, the
 checks against real lowered programs, contract coverage of the tiny
-engine's full family set, budget exact-matching, report validation +
-byte-determinism, and tools/perf_diff.py's budgets-diff mode.
+engine's full family set, and report validation + byte-determinism.
 
 The run_tests.sh gate runs the full CLI sweeps (tp=1 and forced-2-device
 tp=2, byte-identical double run); these tests pin the pieces those
@@ -10,8 +9,6 @@ instead of "the gate went red".
 """
 
 import json
-import os
-import sys
 import textwrap
 
 import jax
@@ -20,26 +17,18 @@ import pytest
 
 from mingpt_distributed_tpu.analysis.hlo_audit import (
     AUDIT_SCHEMA,
-    BUDGETS_SCHEMA,
-    AuditLedger,
     ProgramArtifact,
     audit_programs,
     build_audit_report,
-    build_budget_section,
-    check_budgets,
     collective_inventory,
     donated_alias_count,
     dump_audit_report,
+    lower_programs,
     validate_audit_report,
 )
 from mingpt_distributed_tpu.config import GPTConfig
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.serving.engine import DecodeEngine
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO, "tools"))
-
-import perf_diff  # noqa: E402
 
 
 # ---------------------------------------------------------------------
@@ -93,7 +82,7 @@ def test_host_transfer_always_flagged():
     assert len(inv) == 1
     assert inv[0]["host_transfer"]
     # a host transfer is a finding no matter what the contract allows
-    art = ProgramArtifact("decode", "", hlo, [], 1.0, 1.0)
+    art = ProgramArtifact("decode", "", hlo, [])
     findings = audit_programs(
         {("decode", ""): art},
         {"decode": {"allowed_collectives": ("send",), "donated": 0}})
@@ -111,7 +100,7 @@ def test_donated_alias_count_synthetic():
 
 
 def test_undeclared_collective_is_finding():
-    art = ProgramArtifact("decode", "", SYNTH_HLO, [], 1.0, 1.0)
+    art = ProgramArtifact("decode", "", SYNTH_HLO, [])
     contract = {"allowed_collectives": ("all-gather", "all-reduce"),
                 "donated": 2}
     findings = audit_programs({("decode", ""): art}, {"decode": contract})
@@ -122,7 +111,7 @@ def test_undeclared_collective_is_finding():
 def test_pool_sized_collective_is_finding():
     # all ops declared, but the all-gather result (256 elems) reaches
     # the pool-buffer size => moving the pool, not an activation
-    art = ProgramArtifact("decode", "", SYNTH_HLO, [], 1.0, 1.0)
+    art = ProgramArtifact("decode", "", SYNTH_HLO, [])
     contract = {"allowed_collectives":
                 ("all-gather", "all-reduce", "collective-permute"),
                 "donated": 2, "pool_leaf_elems": 256}
@@ -132,7 +121,7 @@ def test_pool_sized_collective_is_finding():
 
 
 def test_missing_contract_is_finding():
-    art = ProgramArtifact("mystery", "b8", "HloModule m\n", [], 1.0, 1.0)
+    art = ProgramArtifact("mystery", "b8", "HloModule m\n", [])
     findings = audit_programs({("mystery", "b8"): art}, {})
     assert [(f.family, f.check) for f in findings] == [("mystery",
                                                         "contract")]
@@ -145,10 +134,7 @@ def test_missing_contract_is_finding():
 
 
 def _artifact_from_jit(fn, args, family="fam"):
-    compiled = fn.lower(*args).compile()
-    return ProgramArtifact(
-        family, "", compiled.as_text(), compiled.output_shardings,
-        1.0, 1.0)
+    return lower_programs([(family, "", fn, args, {})])[(family, "")]
 
 
 def test_donation_verified_in_lowered_hlo():
@@ -192,34 +178,32 @@ def engine():
                         prefix_cache_mb=0.5)
 
 
-def _register(engine):
-    ledger = AuditLedger()
-    engine.register_attrib(ledger, lambda: 0.0)
-    return ledger
-
-
 def test_every_engine_family_has_a_contract(engine):
-    """Audit-coverage gate (satellite): a family registered in the
-    attribution ledger without a contract fails the SUITE, not just the
-    CLI — so a new jit program cannot land unaudited."""
-    ledger = _register(engine)
+    """Audit-coverage gate (satellite): a family the engine's
+    ``programs()`` lists without a contract fails the SUITE, not just
+    the CLI — so a new jit program cannot land unaudited."""
+    artifacts = lower_programs(engine.programs())
     contracts = engine.audit_contracts()
-    families = {family for (family, _) in ledger.artifacts}
-    assert families  # the seam actually registered programs
+    families = {family for (family, _) in artifacts}
+    assert families == {"prefill", "decode", "prefix_save", "prefix_load"}
     assert families <= set(contracts), (
         f"families without an audit contract: "
         f"{sorted(families - set(contracts))}")
-    assert not [f for f in audit_programs(ledger.artifacts, contracts)
+    assert not [f for f in audit_programs(artifacts, contracts)
                 if f.check == "contract"]
 
 
 def test_tiny_engine_audits_clean(engine):
-    ledger = _register(engine)
-    findings = audit_programs(ledger.artifacts, engine.audit_contracts())
+    before = engine.compile_counts()
+    artifacts = lower_programs(engine.programs())
+    # ahead-of-time lowering never enters the jit call caches: an audit
+    # next to an armed recompile watchdog cannot trip it
+    assert engine.compile_counts() == before
+    findings = audit_programs(artifacts, engine.audit_contracts())
     assert findings == [], [f.render() for f in findings]
     # single-device sweep: zero collectives anywhere, donation as
     # contracted (2 cache leaves for prefill/decode/load, 0 for save)
-    for (family, variant), art in ledger.artifacts.items():
+    for (family, variant), art in artifacts.items():
         assert collective_inventory(art.hlo_text) == [], (family, variant)
         want = engine.audit_contracts()[family]["donated"]
         assert donated_alias_count(art.hlo_text) == want, (family, variant)
@@ -227,17 +211,17 @@ def test_tiny_engine_audits_clean(engine):
 
 def test_audit_report_byte_identical_across_runs(engine):
     """The envelope holds only properties of the lowered programs —
-    rebuilding from a fresh registration serializes byte-identically
+    rebuilding from a fresh lowering serializes byte-identically
     (the run_tests.sh tp=2 gate cmp's two full CLI runs; this pins the
     same property in-process)."""
-    sweep = {"tp": 1, "devices": 1, "budgets_file": "unused"}
+    sweep = {"tp": 1, "devices": 1}
 
     def one():
-        ledger = _register(engine)
+        artifacts = lower_programs(engine.programs())
         contracts = engine.audit_contracts()
-        findings = audit_programs(ledger.artifacts, contracts)
+        findings = audit_programs(artifacts, contracts)
         return dump_audit_report(build_audit_report(
-            sweep, ledger.artifacts, contracts, findings))
+            sweep, artifacts, contracts, findings))
 
     a, b = one(), one()
     assert a == b
@@ -248,10 +232,10 @@ def test_audit_report_byte_identical_across_runs(engine):
 
 
 def test_validate_audit_report_rejects_tampering(engine):
-    ledger = _register(engine)
     contracts = engine.audit_contracts()
     report = build_audit_report({"tp": 1, "devices": 1},
-                                ledger.artifacts, contracts, [])
+                                lower_programs(engine.programs()),
+                                contracts, [])
     validate_audit_report(report)
     bad = json.loads(dump_audit_report(report))
     bad["summary"]["programs"] += 1
@@ -263,108 +247,3 @@ def test_validate_audit_report_rejects_tampering(engine):
         validate_audit_report(bad2)
     with pytest.raises(ValueError, match="schema"):
         validate_audit_report({"schema": "nope/1"})
-
-
-# ---------------------------------------------------------------------
-# cost budgets: exact match, missing, stale
-# ---------------------------------------------------------------------
-
-
-def _art(family, variant="", flops=100.0, byts=200.0):
-    return ProgramArtifact(family, variant, "HloModule m\n", [],
-                           flops, byts)
-
-
-def test_budget_exact_match_and_drift():
-    arts = {("decode", ""): _art("decode")}
-    budgets = {"decode": {"flops": 100.0, "bytes_accessed": 200.0}}
-    assert check_budgets(arts, budgets) == []
-    # ANY drift is a finding — budgets are exact, not toleranced
-    budgets["decode"]["bytes_accessed"] = 200.0000001
-    findings = check_budgets(arts, budgets)
-    assert [f.check for f in findings] == ["budget"]
-    assert "--update-budgets" in findings[0].message
-
-
-def test_budget_missing_and_stale_entries():
-    arts = {("decode", ""): _art("decode"),
-            ("prefill", "b8"): _art("prefill", "b8")}
-    budgets = {"decode": {"flops": 100.0, "bytes_accessed": 200.0},
-               "retired:b4": {"flops": 1.0, "bytes_accessed": 1.0}}
-    findings = check_budgets(arts, budgets)
-    msgs = {f.family: f.message for f in findings}
-    assert "no committed budget" in msgs["prefill"]
-    assert "stale entry" in msgs["retired"]
-    # no budgets section at all: every program is a missing-budget
-    # finding (the gate fails until --update-budgets is run + committed)
-    assert len(check_budgets(arts, None)) == 2
-
-
-def test_budget_section_roundtrip():
-    arts = {("prefill", "b8"): _art("prefill", "b8", 7.0, 9.0),
-            ("decode", ""): _art("decode", "", 3.0, 4.0)}
-    section = build_budget_section(arts)
-    assert section == {"prefill:b8": {"flops": 7.0, "bytes_accessed": 9.0},
-                       "decode": {"flops": 3.0, "bytes_accessed": 4.0}}
-    assert check_budgets(arts, section) == []
-
-
-def test_committed_budgets_file_is_valid():
-    """The file the run_tests.sh gate audits against: right schema, both
-    sweeps present, decode + train_step recorded where expected."""
-    with open(os.path.join(REPO, "program_budgets.json")) as f:
-        doc = json.load(f)
-    assert doc["schema"] == BUDGETS_SCHEMA
-    assert set(doc["sweeps"]) == {"tp1", "tp2"}
-    for sweep, progs in doc["sweeps"].items():
-        assert "decode" in progs
-        for key, metrics in progs.items():
-            assert set(metrics) == {"flops", "bytes_accessed"}, (sweep, key)
-    assert "train_step:dense" in doc["sweeps"]["tp1"]  # tp=1-only family
-    assert "train_step:dense" not in doc["sweeps"]["tp2"]
-
-
-# ---------------------------------------------------------------------
-# perf_diff budgets mode
-# ---------------------------------------------------------------------
-
-
-def _budget_doc():
-    return {
-        "schema": BUDGETS_SCHEMA,
-        "sweeps": {
-            "tp1": {"decode": {"flops": 100.0, "bytes_accessed": 200.0}},
-            "tp2": {"decode": {"flops": 50.0, "bytes_accessed": 90.0}},
-        },
-    }
-
-
-def test_perf_diff_classifies_budgets():
-    assert perf_diff.classify("x.json", _budget_doc()) == "budgets"
-
-
-def test_perf_diff_budgets_same_and_regressed():
-    a, b = _budget_doc(), _budget_doc()
-    diff = perf_diff.diff_budget_reports(a, b)
-    assert diff["regressions"] == 0
-    assert all(r["verdict"] == "same" for r in diff["metrics"])
-
-    b["sweeps"]["tp2"]["decode"]["bytes_accessed"] = 180.0  # worse
-    b["sweeps"]["tp1"]["decode"]["flops"] = 80.0            # improvement
-    diff = perf_diff.diff_budget_reports(a, b)
-    verdicts = {r["metric"]: r["verdict"] for r in diff["metrics"]}
-    assert verdicts["tp2.decode.bytes_accessed"] == "regressed"
-    assert verdicts["tp1.decode.flops"] == "improved"
-    assert diff["regressions"] == 1
-
-    # a family on one side only is n/a — coverage event, not perf
-    b["sweeps"]["tp2"]["prefill:b8"] = {"flops": 1.0,
-                                        "bytes_accessed": 1.0}
-    diff = perf_diff.diff_budget_reports(a, b)
-    assert {r["verdict"] for r in diff["metrics"]
-            if r["metric"].startswith("tp2.prefill")} == {"n/a"}
-
-
-def test_perf_diff_budgets_rejects_wrong_schema():
-    with pytest.raises(ValueError, match=BUDGETS_SCHEMA):
-        perf_diff.diff_budget_reports({"schema": "nope"}, _budget_doc())

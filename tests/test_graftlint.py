@@ -437,31 +437,6 @@ def test_control_package_clean_under_clock_and_name_rules():
     assert res.findings == []
 
 
-def test_attribution_module_clean_under_clock_and_name_rules():
-    """ISSUE 13: the attribution ledger's byte-identical-report
-    guarantee (two VirtualClock serving runs must dump the same
-    mingpt-attrib/1 bytes) holds only because every compile/device
-    timestamp reaches telemetry/attribution.py through an injected
-    clock — the module itself never reads the wall. It is in GL007
-    scope (Config.clock_paths) and must stay clean outright — no
-    suppressions, no baseline entries. Its mingpt_attrib_* gauge
-    families must also pass the GL008 naming convention unsuppressed.
-    The wall-clock shapes that would break report determinism are
-    pinned by the gl007_gl008_attribution.py fixture."""
-    path = os.path.join(
-        REPO, "mingpt_distributed_tpu", "telemetry", "attribution.py")
-    cfg = Engine(select=["GL007"], root=REPO).config
-    rel = os.path.relpath(path, REPO)
-    assert cfg.clock_in_scope(rel), f"{rel} fell out of GL007 scope"
-    res = Engine(select=["GL007"], root=REPO).run([path])
-    assert not res.parse_errors
-    assert res.findings == []  # not even suppressed or baselined ones
-
-    res = Engine(select=["GL008", "GL009"], root=REPO).run([path])
-    assert not res.parse_errors
-    assert res.findings == []
-
-
 def test_list_rules(capsys):
     assert lint_main(["--list-rules"]) == 0
     out = capsys.readouterr().out

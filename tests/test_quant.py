@@ -17,7 +17,7 @@ The load-bearing guarantees:
   ``{"k", "v"}`` cache, no scale leaves, no quant descriptor;
 * the int8+scales pool at head_dim=64 fits the <= 0.27x fp32 budget
   the acceptance gate (serve.py --selftest-quant) enforces on the
-  HBMLedger.
+  pool's per-device bytes.
 """
 
 import dataclasses
@@ -31,15 +31,16 @@ from mingpt_distributed_tpu.config import GPTConfig, MeshConfig
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
+from mingpt_distributed_tpu.parallel.zero import per_device_bytes
 from mingpt_distributed_tpu.serving import InferenceServer, Request
 from mingpt_distributed_tpu.serving import quant as quant_lib
 from mingpt_distributed_tpu.serving.engine import DecodeEngine
-from mingpt_distributed_tpu.telemetry import (
-    per_device_tree_bytes,
-    tree_bytes,
-)
 
 INT8 = quant_lib.resolve_kv_dtype("int8")
+
+
+def tree_bytes(tree):
+    return sum(leaf.nbytes for leaf in jax.tree.leaves(tree))
 
 
 @pytest.fixture(scope="module")
@@ -201,7 +202,7 @@ def test_tp2_scale_planes_head_sharded(cfg_params, tp2_mesh):
         shard = arr.sharding.shard_shape(arr.shape)
         assert shard[3] * 2 == arr.shape[3], (
             f"{name} not head-sharded: {arr.shape} -> {shard}")
-    assert per_device_tree_bytes(eng.pool.cache) * 2 \
+    assert per_device_bytes(eng.pool.cache) * 2 \
         == tree_bytes(eng.pool.cache)
 
 
@@ -281,7 +282,7 @@ def test_int8_pool_fits_quarter_budget_at_hd64():
     """The acceptance-gate arithmetic without running a model: at
     head_dim=64 (the selftest-quant geometry) int8 payload + fp32 scale
     planes come to (hd+4)/(4*hd) = 0.2656x the fp32 pool bytes —
-    under the 0.27 ceiling the HBMLedger gate enforces."""
+    under the 0.27 ceiling the selftest-quant gate enforces."""
     cfg = GPTConfig.make(
         n_layer=2, n_head=4, n_embd=256, vocab_size=96, block_size=48,
         embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32",

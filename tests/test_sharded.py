@@ -14,8 +14,8 @@ The load-bearing guarantees:
 * a fleet of sharded replicas survives a mid-decode crash with zero
   duplicate tokens — ownership (fleet) and placement (mesh) never
   interact;
-* ``per_device_tree_bytes`` and the ``HBMLedger`` per-device column
-  account sharded pools exactly.
+* the bytes each device holds of a sharded pool, read from its shards,
+  are exactly total/tp — on a bare engine and on a whole server.
 """
 
 import dataclasses
@@ -29,6 +29,7 @@ from mingpt_distributed_tpu.config import GPTConfig, MeshConfig
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
+from mingpt_distributed_tpu.parallel.zero import per_device_bytes
 from mingpt_distributed_tpu.serving import (
     InferenceServer,
     Request,
@@ -38,11 +39,6 @@ from mingpt_distributed_tpu.serving import (
     default_server_factory,
 )
 from mingpt_distributed_tpu.serving.engine import DecodeEngine
-from mingpt_distributed_tpu.telemetry import (
-    HBMLedger,
-    per_device_tree_bytes,
-    tree_bytes,
-)
 from mingpt_distributed_tpu.training.faults import ServingFaultInjector
 
 
@@ -53,6 +49,10 @@ def cfg_params():
         embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32",
     )
     return cfg, gpt.init(jax.random.key(0), cfg)
+
+
+def tree_bytes(tree):
+    return sum(leaf.nbytes for leaf in jax.tree.leaves(tree))
 
 
 @pytest.fixture(scope="module")
@@ -83,7 +83,7 @@ def test_tp2_engine_shards_pool_halving_per_device_bytes(
     shape = eng.pool.cache["k"].shape
     shard = eng.pool.sharding.shard_shape(shape)
     assert shard == shape[:3] + (shape[3] // 2,) + shape[4:]
-    assert per_device_tree_bytes(eng.pool.cache) * 2 \
+    assert per_device_bytes(eng.pool.cache) * 2 \
         == tree_bytes(eng.pool.cache)
     # an unsharded engine from the same ingredients is the 1x baseline
     solo = DecodeEngine(params, cfg, n_slots=2)
@@ -238,32 +238,36 @@ def test_fleet_crash_retry_on_sharded_replicas(cfg_params, tp2_mesh):
 # ---------------------------------------------------------------------------
 
 
-def test_per_device_tree_bytes_counts_shards(tp2_mesh):
-    plain = np.zeros((4, 8), np.float32)  # no sharding: full size
-    assert per_device_tree_bytes({"a": plain}) == plain.nbytes
+def test_per_device_bytes_counts_shards(tp2_mesh):
     single = jnp.zeros((4, 8), jnp.float32)  # single device: full size
-    assert per_device_tree_bytes({"a": single}) == single.nbytes
+    assert per_device_bytes({"a": single}) == single.nbytes
     spec = jax.sharding.NamedSharding(
         tp2_mesh, jax.sharding.PartitionSpec("tp"))
     split = jax.device_put(jnp.zeros((4, 8), jnp.float32), spec)
-    assert per_device_tree_bytes({"a": split}) == split.nbytes // 2
-    # mixed trees sum leafwise
-    assert per_device_tree_bytes({"a": split, "b": plain}) \
-        == split.nbytes // 2 + plain.nbytes
-    assert tree_bytes({"a": split, "b": plain}) \
-        == split.nbytes + plain.nbytes
+    assert per_device_bytes({"a": split}) == split.nbytes // 2
+    # mixed trees sum leafwise on the busiest device (device 0 holds
+    # half of the split leaf and the whole single-device one)
+    assert per_device_bytes({"a": split, "b": single}) \
+        == split.nbytes // 2 + single.nbytes
+    assert tree_bytes({"a": split, "b": single}) \
+        == split.nbytes + single.nbytes
 
 
-def test_hbm_ledger_per_device_column():
-    hbm = HBMLedger(capacity_bytes=None)
-    hbm.account("params", 100)  # default: single-device truth
-    hbm.account("kv_pool", 80, per_device_bytes=40)
-    assert hbm.owners() == {"kv_pool": 80, "params": 100}
-    assert hbm.per_device() == {"kv_pool": 40, "params": 100}
-    # re-accounting is declarative, both columns follow
-    hbm.account("kv_pool", 80, per_device_bytes=20)
-    assert hbm.per_device()["kv_pool"] == 20
-    with pytest.raises(ValueError):
-        hbm.account("kv_pool", 80, per_device_bytes=81)  # > total
-    with pytest.raises(ValueError):
-        hbm.account("kv_pool", 80, per_device_bytes=-1)
+def test_tp2_server_pools_hold_total_over_tp_per_device(
+        cfg_params, tp2_mesh):
+    """A whole tp=2 server — target pool and the speculation draft's
+    mirrored pool — holds total/2 of each pool on a device, and sharding
+    leaves the totals where a tp=1 server has them."""
+    cfg, params = cfg_params
+    kw = dict(n_slots=2, draft_params=params, draft_cfg=cfg, spec_k=2)
+    solo = InferenceServer(params, cfg, **kw)
+    srv = InferenceServer(params, cfg, mesh=tp2_mesh, **kw)
+    for pick in (lambda s: s.engine.pool.cache,
+                 lambda s: s.spec.draft.engine.pool.cache):
+        pool = pick(srv)
+        for leaf in pool.values():
+            assert [sh.data.nbytes for sh in leaf.addressable_shards] \
+                == [leaf.nbytes // 2] * 2
+        assert per_device_bytes(pool) * 2 == tree_bytes(pool)
+        assert tree_bytes(pool) == tree_bytes(pick(solo))
+        assert per_device_bytes(pick(solo)) == tree_bytes(pick(solo))

@@ -596,7 +596,7 @@ def test_peak_tables_share_keys_and_prefix_order():
 
 
 def test_training_metrics_reexports_peaks():
-    # bench.py and pre-existing imports keep working after the dedupe
+    # pre-existing imports keep working after the dedupe
     from mingpt_distributed_tpu.training import metrics as tm
 
     assert tm.PEAK_FLOPS is PEAK_FLOPS

@@ -3,30 +3,22 @@
 (ISSUE 15 tentpole; checks live in ``analysis/hlo_audit.py``).
 
 Usage: python tools/graftaudit.py [--tp {1,2}] [--json]
-           [--budgets program_budgets.json] [--no-budgets]
-           [--update-budgets]
 
 Builds the canonical tiny serving + speculation stack (the
 ``serve.py --selftest-sharded`` config) — and, on the tp=1 sweep, the
-tiny trainer — then AOT-lowers every program family through the
-attribution ``register_attrib`` seams into an
-:class:`~mingpt_distributed_tpu.analysis.hlo_audit.AuditLedger` and
+tiny trainer — then AOT-lowers every program family their
+``programs()`` enumerate
+(:func:`~mingpt_distributed_tpu.analysis.hlo_audit.lower_programs`) and
 checks the lowered artifacts against the families' declared contracts:
-collectives inventory, donation aliasing, output-sharding drift and
-exact ``cost_analysis`` budgets. Nothing is ever executed on the model
-(params are initialised, programs are only lowered + compiled).
+collectives inventory, donation aliasing and output-sharding drift.
+Nothing is ever executed on the model (params are initialised,
+programs are only lowered + compiled).
 
 Sweeps: ``--tp 1`` is the single-device audit (every family must lower
 with zero collectives); ``--tp 2`` runs the same serving stack across a
 forced-2-device mesh (``XLA_FLAGS=--xla_force_host_platform_device_count=2``
 on CPU) and proves the tp contracts: reduce-family ops only, no
 gathered KV pool, donation aliasing intact, normalized sharding specs.
-
-Budgets: ``program_budgets.json`` commits the exact flops /
-bytes-accessed per program per sweep. Drift is a finding;
-``--update-budgets`` re-records the current sweep's section (bless an
-intentional program change, then commit the file).
-``tools/perf_diff.py old.json new.json`` renders a budgets diff.
 
 Exit codes mirror graftlint: 0 clean, 1 findings, 2 usage/build error.
 The ``--json`` envelope (``graftaudit/1``) is byte-identical across
@@ -37,7 +29,6 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import json
 import os
 import sys
 import tempfile
@@ -45,7 +36,7 @@ import tempfile
 
 def _repo_import():
     """Running this file directly puts tools/ on sys.path; make the
-    repo root importable like perf_diff does."""
+    repo root importable."""
     try:
         import mingpt_distributed_tpu  # noqa: F401
     except ImportError:
@@ -131,24 +122,6 @@ def _build_trainer(tmpdir: str):
         mesh=mesh)
 
 
-def _load_budgets(path: str):
-    """The committed budgets doc, or a fresh skeleton when the file
-    does not exist yet. Raises ValueError on a wrong-schema file."""
-    from mingpt_distributed_tpu.analysis.hlo_audit import BUDGETS_SCHEMA
-
-    if not os.path.exists(path):
-        return {"schema": BUDGETS_SCHEMA, "sweeps": {}}
-    with open(path) as f:
-        doc = json.load(f)
-    if doc.get("schema") != BUDGETS_SCHEMA:
-        raise ValueError(
-            f"{path}: not a {BUDGETS_SCHEMA} document "
-            f"(schema={doc.get('schema')!r})")
-    if not isinstance(doc.get("sweeps"), dict):
-        raise ValueError(f"{path}: sweeps must be an object")
-    return doc
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="graftaudit", description=__doc__,
@@ -159,26 +132,15 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true",
                     help="emit the graftaudit/1 envelope instead of the "
                          "human rendering")
-    ap.add_argument("--budgets", default="program_budgets.json",
-                    metavar="FILE",
-                    help="committed cost-budget baseline "
-                         "(default: %(default)s)")
-    ap.add_argument("--no-budgets", action="store_true",
-                    help="skip the cost-budget check")
-    ap.add_argument("--update-budgets", action="store_true",
-                    help="re-record this sweep's budgets in FILE "
-                         "(bless an intentional program change)")
     args = ap.parse_args(argv)
 
     _repo_import()
     from mingpt_distributed_tpu.analysis.hlo_audit import (
-        AuditLedger,
         audit_exit_code,
         audit_programs,
         build_audit_report,
-        build_budget_section,
-        check_budgets,
         dump_audit_report,
+        lower_programs,
         render_audit_human,
         validate_audit_report,
     )
@@ -192,17 +154,16 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
 
-    # Build + registration chatter (log_event, sharding telemetry) goes
-    # to stderr so --json stdout stays a single parseable document.
-    ledger = AuditLedger()
-    clock = lambda: 0.0  # noqa: E731 — no timing may enter the report
+    # Build chatter (log_event, sharding telemetry) goes to stderr so
+    # --json stdout stays a single parseable document.
     with contextlib.redirect_stdout(sys.stderr), \
             tempfile.TemporaryDirectory() as tmpdir:
         engine, spec, q8_engine, q8_spec = _build_serving(args.tp)
-        engine.register_attrib(ledger, clock)
-        spec.register_attrib(ledger, clock)
-        q8_engine.register_attrib(ledger, clock, family_prefix="q8_")
-        q8_spec.register_attrib(ledger, clock, family_prefix="q8_")
+        programs = [
+            *engine.programs(), *spec.programs(),
+            *q8_engine.programs(family_prefix="q8_"),
+            *q8_spec.programs(family_prefix="q8_"),
+        ]
         contracts = {
             **engine.audit_contracts(), **spec.audit_contracts(),
             **q8_engine.audit_contracts(family_prefix="q8_"),
@@ -210,34 +171,14 @@ def main(argv=None) -> int:
         }
         if args.tp == 1:
             trainer = _build_trainer(tmpdir)
-            trainer.register_attrib(ledger, clock)
+            programs += trainer.programs()
             contracts.update(trainer.audit_contracts())
+        artifacts = lower_programs(programs)
 
-    findings = audit_programs(ledger.artifacts, contracts)
-    sweep_key = f"tp{args.tp}"
-    try:
-        budgets_doc = _load_budgets(args.budgets)
-    except (OSError, ValueError) as e:
-        print(f"graftaudit: {e}", file=sys.stderr)
-        return 2
-    if args.update_budgets:
-        budgets_doc["sweeps"][sweep_key] = build_budget_section(
-            ledger.artifacts)
-        with open(args.budgets, "w") as f:
-            json.dump(budgets_doc, f, sort_keys=True, indent=2)
-            f.write("\n")
-        print(f"graftaudit: recorded {sweep_key} budgets for "
-              f"{len(ledger.artifacts)} programs in {args.budgets}",
-              file=sys.stderr)
-    if not args.no_budgets:
-        findings = sorted(
-            findings + check_budgets(
-                ledger.artifacts, budgets_doc["sweeps"].get(sweep_key)),
-            key=lambda x: x.sort_key)
-
+    findings = audit_programs(artifacts, contracts)
     report = build_audit_report(
-        {"tp": args.tp, "devices": args.tp, "budgets_file": args.budgets},
-        ledger.artifacts, contracts, findings)
+        {"tp": args.tp, "devices": args.tp},
+        artifacts, contracts, findings)
     validate_audit_report(report)
     print(dump_audit_report(report) if args.json
           else render_audit_human(report))

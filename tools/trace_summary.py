@@ -2,7 +2,7 @@
 """Summarise a jax.profiler trace: top ops by total duration, per lane.
 
 Input: a profile directory written by ``jax.profiler.trace`` (e.g. from
-``python bench.py --profile DIR``) — it contains
+``trainer_config.profile_dir``) — it contains
 ``plugins/profile/<run>/<host>.trace.json.gz`` in Chrome trace-event
 format, which this tool aggregates without needing TensorBoard: for each
 process/thread lane, complete events ("ph": "X") are summed by name.
@@ -11,7 +11,6 @@ Usage: python tools/trace_summary.py DIR [--top N]
        python tools/trace_summary.py SPANS.jsonl [--top N]
        python tools/trace_summary.py TRACE.jsonl [--slo [SPEC]]
        python tools/trace_summary.py CONTROL.jsonl [--top N]
-       python tools/trace_summary.py ATTRIB.json
        python tools/trace_summary.py --compare A.json B.json
 
 A ``.jsonl`` file argument is treated as a telemetry span stream instead
@@ -35,13 +34,6 @@ autoscaled cell, ISSUE 20) is an SLO-autoscaler decision log: rendered
 as the per-actuator action table (ups/downs per lever), the actuation
 timeline in virtual time, and the grouped reason mix — what the
 controller saw (values elided) and how often, holds included.
-
-A ``.json`` file argument carrying the ``mingpt-attrib/1`` schema
-(written by ``serve.py --attrib-json``, ISSUE 13) is a performance
-attribution report: it is strict-validated and rendered as the
-per-program-family table — compiled FLOPs / bytes accessed from
-``cost_analysis()``, compile wall time, invocation counts, sampled
-device seconds and MFU where roofline peaks are known.
 
 ``--compare A.json B.json`` (ISSUE 12) takes two ``mingpt-slo/1``
 reports (written by ``serve.py --slo-json``) and prints a per-objective
@@ -356,9 +348,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("profile_dir", nargs="?", default=None,
                     help="profiler output dir, a telemetry span .jsonl, "
-                         "a mingpt-trace/1 request-trace .jsonl, a "
-                         "mingpt-control/1 autoscaler decision .jsonl, "
-                         "or a mingpt-attrib/1 attribution report .json "
+                         "a mingpt-trace/1 request-trace .jsonl, or a "
+                         "mingpt-control/1 autoscaler decision .jsonl "
                          "(omitted with --compare)")
     ap.add_argument("--top", type=int, default=12)
     ap.add_argument("--compare", nargs=2, default=None,
@@ -393,32 +384,6 @@ def main(argv=None) -> int:
         return 0
     if args.profile_dir is None:
         ap.error("profile_dir is required unless --compare is given")
-    if (os.path.isfile(args.profile_dir)
-            and args.profile_dir.endswith(".json")):
-        # third input kind (ISSUE 13): a mingpt-attrib/1 performance
-        # attribution report — strict-validate, then render the
-        # per-family flops / bytes / compile-time table
-        tel = _telemetry()
-        try:
-            with open(args.profile_dir) as f:
-                doc = json.load(f)
-        except (OSError, ValueError) as e:
-            print(f"cannot read {args.profile_dir}: {e}", file=sys.stderr)
-            return 1
-        schema = doc.get("schema") if isinstance(doc, dict) else None
-        if schema != tel.ATTRIB_SCHEMA:
-            print(f"{args.profile_dir}: expected a {tel.ATTRIB_SCHEMA} "
-                  f"report, got schema={schema!r} (for mingpt-slo/1 "
-                  f"reports use --compare)", file=sys.stderr)
-            return 1
-        try:
-            tel.validate_attrib_report(doc)
-        except ValueError as e:
-            print(f"invalid {tel.ATTRIB_SCHEMA} report: {e}",
-                  file=sys.stderr)
-            return 1
-        print(tel.render_attrib_report(doc))
-        return 0
     span_input = (os.path.isfile(args.profile_dir)
                   and args.profile_dir.endswith(".jsonl"))
     if span_input and sniff_jsonl_schema(args.profile_dir) == TRACE_SCHEMA:
