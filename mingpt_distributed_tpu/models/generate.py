@@ -231,6 +231,55 @@ def _head_logits(params: gpt.Params, x: jax.Array, cfg: GPTConfig) -> jax.Array:
     return attn_ops.softcap(logits, cfg.final_logit_softcap)
 
 
+#: leaves the cached forward reads ONLY through ``.astype(<compute dtype>)``:
+#: the matmul weights and biases of ``L.dense`` / ``moe.moe_mlp`` and an
+#: untied ``head``. Casting one of these first gives the bits casting it at
+#: its use gives. Every other leaf is used in float32 somewhere (``wte`` and
+#: ``wpe`` are summed before the cast, also when ``wte`` is the tied head;
+#: the norms and ``w_router`` compute in float32) and must stay as it is.
+_CAST_ONLY_BLOCK_LEAVES = frozenset({
+    "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo",
+    "w_fc", "b_fc", "w_proj", "b_proj",
+    "w_gate", "w_up", "w_down",
+    "w_e1", "w_e2", "w_eg",
+})
+
+
+def cast_once_params(
+    params: gpt.Params, cfg: GPTConfig
+) -> Tuple[gpt.Params, int]:
+    """The tree a caller that runs the cached forward many times should
+    hand it: every leaf of ``_CAST_ONLY_BLOCK_LEAVES`` (and ``head``) stored
+    in ``cfg.dtype``, every other leaf the array that came in. Returns (tree,
+    leaves cast). ``w.astype(x.dtype)`` on such a leaf is then no operation,
+    so a jitted program that takes the tree as an argument (and so cannot
+    hoist the cast itself) converts no weight, and computes bit for bit what
+    it computes on ``params`` (tests/test_cast_once.py holds every
+    architecture to that: a leaf added to the forward is added here only if
+    it passes). Where no leaf needs a cast (a float32 model) the tree
+    returned IS ``params``."""
+    dtype = jnp.dtype(cfg.dtype)
+    picked = {"blocks": {n: a for n, a in params["blocks"].items()
+                         if n in _CAST_ONLY_BLOCK_LEAVES and a.dtype != dtype}}
+    if "head" in params and params["head"].dtype != dtype:
+        picked["head"] = params["head"]
+    n_cast = len(jax.tree.leaves(picked))
+    if not n_cast:
+        return params, 0
+    # a leaf placed on purpose (an engine's mesh) keeps that sharding, spelled
+    # as it was; any other stays uncommitted, as the leaf it came from is: a
+    # committed argument would commit the programs' outputs, and a jit call
+    # keys on that
+    cast = jax.jit(
+        lambda t: jax.tree.map(lambda a: a.astype(dtype), t),
+        out_shardings=jax.tree.map(
+            lambda a: a.sharding if getattr(a, "committed", False) else None,
+            picked),
+    )(picked)
+    blocks = {**params["blocks"], **cast.pop("blocks")}
+    return {**params, **cast, "blocks": blocks}, n_cast
+
+
 def _forward_cached(
     params: gpt.Params, tokens: jax.Array, cache: Cache, offset, cfg: GPTConfig
 ) -> Tuple[jax.Array, Cache]:

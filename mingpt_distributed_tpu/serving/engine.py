@@ -54,6 +54,15 @@ Chunked prefill is exactly row-equivalent to one whole-prompt forward:
 attention, MLP and norms are row-wise, and a chunk's queries see the same
 keys at the same absolute positions the one-shot forward would.
 
+Weights: parameters arrive in float32 and ``cfg.dtype`` is the
+activations'; every matmul takes its weight as ``w.astype(x.dtype)``. The
+weights are arguments of the programs, so XLA cannot hoist that cast out
+of them: at 1.6B it was 13 ms at the head of every program (PR 29). The
+engine therefore hands its programs ``program_params``, in which
+``generate.cast_once_params`` has made that cast once, at construction,
+for exactly the leaves whose only use it is: same bits, no ``convert`` of
+a weight in any program. ``params`` stays the tree that was handed in.
+
 Tensor-parallel sharding (ISSUE 14): the engine optionally runs across a
 ``jax.sharding.Mesh``. Params shard by ``parallel/mesh.py``'s megatron
 rules (column/row-split matmuls over the tp axis); the KV pool and every
@@ -412,7 +421,11 @@ class DecodeEngine:
                 mesh, cache_shape, kv_pool_spec(tp_axis), name="kv_cache")
         else:
             self.kv_sharding = None
+        # what was handed in (placed, under a mesh), and what the programs
+        # read (module docstring, "Weights"): one tree on a float32 engine
         self.params = params
+        self.program_params, self.n_cast_leaves = gen.cast_once_params(
+            params, cfg)
         self.prefill_len = int(prefill_len or cfg.block_size)
         if not (1 <= self.prefill_len <= cfg.block_size):
             raise ValueError(
@@ -471,6 +484,11 @@ class DecodeEngine:
         return self.pool.shard_count
 
     @property
+    def program_param_bytes(self) -> int:
+        """Bytes of the tree the programs read."""
+        return sum(a.nbytes for a in jax.tree.leaves(self.program_params))
+
+    @property
     def chunk_size(self) -> int:
         """Max tokens one prefill call processes (= prefill_len when
         chunking is off)."""
@@ -513,7 +531,7 @@ class DecodeEngine:
         padded = np.zeros(bucket, np.int32)
         padded[:n] = np.asarray(chunk_ids, np.int32)
         tok, cache = self._prefill_jit(
-            self.params, self.pool.cache, padded,
+            self.program_params, self.pool.cache, padded,
             np.int32(n), np.int32(offset), np.int32(slot),
             np.float32(temperature),
             np.int32(0 if top_k is None else top_k),
@@ -687,7 +705,7 @@ class DecodeEngine:
             if token_index is None:
                 token_index = np.zeros(len(tokens), np.int32)
             nxt, cache = self._decode_jit(
-                self.params, self.pool.cache,
+                self.program_params, self.pool.cache,
                 np.asarray(tokens, np.int32),
                 np.asarray(positions, np.int32),
                 np.asarray(temps, np.float32),
@@ -723,13 +741,14 @@ class DecodeEngine:
         bucket."""
         for b in self.buckets:
             yield (family_prefix + "prefill", f"b{b}", self._prefill_jit,
-                   (self.params, self.pool.cache, jnp.zeros(b, jnp.int32),
+                   (self.program_params, self.pool.cache,
+                    jnp.zeros(b, jnp.int32),
                     np.int32(b), np.int32(0), np.int32(0),
                     np.float32(1.0), np.int32(0), np.float32(1.0),
                     np.bool_(False), np.uint32(0)), {})
         s = self.n_slots
         yield (family_prefix + "decode", "", self._decode_jit,
-               (self.params, self.pool.cache,
+               (self.program_params, self.pool.cache,
                 jnp.zeros(s, jnp.int32), jnp.zeros(s, jnp.int32),
                 jnp.ones(s, jnp.float32), jnp.zeros(s, jnp.int32),
                 jnp.ones(s, jnp.float32), jnp.zeros(s, bool),

@@ -183,6 +183,14 @@ class ServingMetrics:
         self._hit_rate = r.gauge(
             "mingpt_serve_prefix_hit_rate",
             help="prefix-cache hits / lookups so far")
+        # set once, by the server that owns the engine (engine_built)
+        self._program_weight_bytes = r.gauge(
+            "mingpt_serve_program_weight_bytes",
+            help="bytes of the parameter tree the compiled programs read")
+        self._program_weights_cast = r.gauge(
+            "mingpt_serve_program_weights_cast",
+            help="leaves of that tree cast to the compute dtype at "
+                 "construction (0: the programs read the tree handed in)")
         self._util_sum = 0.0
         self._prefill_rate = RateWindow()
         self._prefill_tokens_per_sec: Optional[float] = None
@@ -454,8 +462,16 @@ class ServingMetrics:
                 f"spec_accept {self.spec_accepted}/{self.spec_proposed}")
         return " | ".join(parts)
 
+    def engine_built(self, program_weight_bytes: int,
+                     program_weights_cast: int) -> None:
+        """What the engine's programs read, known once it is built."""
+        self._program_weight_bytes.set(program_weight_bytes)
+        self._program_weights_cast.set(program_weights_cast)
+
     def summary(self) -> Dict[str, Any]:
         return {
+            "program_weight_bytes": int(self._program_weight_bytes.value),
+            "program_weights_cast": int(self._program_weights_cast.value),
             "requests_submitted": self.requests_submitted,
             "requests_completed": self.requests_completed,
             "requests_rejected": self.requests_rejected,

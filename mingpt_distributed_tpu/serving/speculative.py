@@ -323,7 +323,7 @@ class SpeculativeDecoder:
                 f"{self.target.cfg.block_size} cache window (the scheduler "
                 "gates eligibility on window headroom)")
         nxt, cache = self._verify_jit(
-            self.target.params, self.target.pool.cache,
+            self.target.program_params, self.target.pool.cache,
             np.asarray(row_tokens, np.int32),
             np.int32(offset), np.int32(slot),
             np.float32(temperature),
@@ -394,7 +394,7 @@ class SpeculativeDecoder:
         ``family_prefix`` prefixes every family (graftaudit audits a
         quantized decoder beside the fp32 one as ``q8_*``)."""
         yield (f"{family_prefix}verify", f"k{self.k}", self._verify_jit,
-               (self.target.params, self.target.pool.cache,
+               (self.target.program_params, self.target.pool.cache,
                 jnp.zeros(self.rows, jnp.int32),
                 np.int32(0), np.int32(0),
                 np.float32(1.0), np.int32(0), np.float32(1.0),

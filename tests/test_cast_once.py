@@ -1,0 +1,257 @@
+"""The serving engine casts its weights to the compute dtype once (PR 29).
+
+``DecodeEngine`` hands its programs ``program_params``: the tree it was
+given, but for the leaves the cached forward reads only through
+``.astype(<compute dtype>)``, which ``generate.cast_once_params`` stores in
+``cfg.dtype`` at construction. What must hold, on the CPU at tiny sizes:
+
+* the arithmetic is unchanged, bit for bit: the engine's own prefill and
+  decode programs give the same tokens (greedy and sampling lanes) and the
+  same cache on the tree handed in and on the tree they are given, for
+  every architecture the tests build;
+* a float32 engine holds one tree: ``program_params`` IS ``params``;
+* ``engine.params`` stays the tree that was handed in (the benchmark
+  digests it and hands it to its float32 reference);
+* under a mesh every leaf of ``program_params`` keeps the sharding of the
+  leaf it was cast from;
+* the compiled decode program converts no weight, and ``ServingMetrics``
+  says what the programs read.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mingpt_distributed_tpu.config import GPTConfig, MeshConfig
+from mingpt_distributed_tpu.models import generate as gen
+from mingpt_distributed_tpu.models import gpt
+from mingpt_distributed_tpu.parallel import mesh as mesh_lib
+from mingpt_distributed_tpu.serving import InferenceServer
+from mingpt_distributed_tpu.serving.engine import DecodeEngine
+
+BLOCK = 32
+SLOTS = 3
+STEPS = 8
+BASE = dict(n_layer=2, n_head=4, n_embd=32, vocab_size=64, block_size=BLOCK,
+            embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0)
+LLAMA = dict(rope=True, swiglu=True, rmsnorm=True, n_kv_head=2)
+ARCHS = {
+    "gpt2-untied": dict(tie_weights=False),
+    "gpt2-tied": dict(tie_weights=True),
+    "rope-swiglu-rmsnorm": dict(LLAMA, tie_weights=False),
+    # benchmarks/tests/fixtures/rope-experts.json, cut as its "tiny" is
+    "rope-experts": dict(rope=True, swiglu=True, rmsnorm=True, ffn_mult=0.5,
+                         n_experts=8, moe_top_k=2, moe_capacity_factor=4.0,
+                         tie_weights=False),
+}
+
+
+def model(arch, dtype):
+    """Config and parameters with every leaf away from its initial value:
+    biases, norm scales and ``wpe`` start as zeros and ones, where a cast
+    too many would not show."""
+    cfg = GPTConfig.make(**BASE, **ARCHS[arch], dtype=dtype)
+    params = gpt.init(jax.random.key(11), cfg)
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.key(12), len(leaves))
+    leaves = [a + 0.05 * jax.random.normal(k, a.shape, a.dtype)
+              for a, k in zip(leaves, keys)]
+    return cfg, jax.tree.unflatten(tree, leaves)
+
+
+def leaves_by_path(tree):
+    return {jax.tree_util.keystr(p): a
+            for p, a in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+def leaf_name(path):
+    """``"['blocks']['wq']"`` -> ``"wq"``."""
+    return path.rsplit("'", 2)[-2]
+
+
+def bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint16) if a.dtype == jnp.bfloat16 else a
+
+
+def serve(engine, tree):
+    """A prefill a lane and STEPS decode steps through the engine's own
+    programs on ``tree``, from an empty pool: lane 0 greedy, lane 1 sampling
+    under top-k, lane 2 under top-p. Returns (every token, the pool)."""
+    cache = jax.tree.map(jnp.zeros_like, engine.pool.cache)
+    if engine.kv_sharding is not None:
+        cache = jax.device_put(cache, engine.kv_sharding)
+    temps = np.array([1.0, 0.8, 1.3], np.float32)
+    top_ks = np.array([0, 5, 0], np.int32)
+    top_ps = np.array([1.0, 1.0, 0.9], np.float32)
+    sample = np.array([False, True, True])
+    seeds = np.array([3, 2_147_483_659, 77], np.uint32)
+    lengths = [5, 9, 16]
+    rng = np.random.default_rng(5)
+    toks = []
+    cur = np.zeros(SLOTS, np.int32)
+    for slot, n in enumerate(lengths):
+        padded = np.zeros(16, np.int32)
+        padded[:n] = rng.integers(1, BASE["vocab_size"], size=n)
+        tok, cache = engine._prefill_jit(
+            tree, cache, padded, np.int32(n), np.int32(0), np.int32(slot),
+            temps[slot], top_ks[slot], top_ps[slot], sample[slot],
+            seeds[slot])
+        cur[slot] = int(tok)
+    toks.append(cur.copy())
+    pos = np.array(lengths, np.int32)
+    for step in range(STEPS):
+        nxt, cache = engine._decode_jit(
+            tree, cache, cur, pos, temps, top_ks, top_ps, sample, seeds,
+            np.full(SLOTS, step + 1, np.int32))
+        cur = np.asarray(nxt)
+        toks.append(cur.copy())
+        pos = pos + 1
+    return np.stack(toks), {n: bits(a) for n, a in cache.items()}
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("arch", sorted(ARCHS))
+def test_programs_read_a_tree_cast_once(arch, dtype, tp):
+    cfg, params = model(arch, dtype)
+    mesh = None
+    if tp > 1:
+        mesh = mesh_lib.make_mesh(MeshConfig(tp=tp),
+                                  devices=jax.devices()[:tp])
+    engine = DecodeEngine(params, cfg, n_slots=SLOTS, prefill_len=16,
+                          prefill_buckets=[16], mesh=mesh)
+    handed, held = leaves_by_path(params), leaves_by_path(engine.params)
+    read = leaves_by_path(engine.program_params)
+    assert handed.keys() == held.keys() == read.keys()
+
+    # engine.params: the tree handed in (placed by the engine under a mesh)
+    for path, leaf in handed.items():
+        if mesh is None:
+            assert held[path] is leaf, path
+        else:
+            assert held[path].dtype == leaf.dtype == jnp.float32
+            np.testing.assert_array_equal(held[path], leaf)
+    if mesh is not None:
+        want = leaves_by_path(mesh_lib.param_shardings(mesh, params))
+        for path in held:
+            assert held[path].sharding == want[path], path
+            assert read[path].sharding == held[path].sharding, path
+
+    # nor is a leaf committed that was not: a committed argument commits the
+    # programs' outputs, and the next call with them is another jit entry
+    # (the recompile watchdog counts it)
+    for path in held:
+        assert read[path].committed == held[path].committed == (
+            mesh is not None), path
+
+    cast = {p for p in read if read[p] is not held[p]}
+    assert engine.n_cast_leaves == len(cast)
+    if dtype == "float32":
+        assert engine.program_params is engine.params
+        assert not cast
+        return
+
+    for path in cast:
+        assert leaf_name(path) in gen._CAST_ONLY_BLOCK_LEAVES | {"head"}
+        assert read[path].dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            bits(read[path]), bits(held[path].astype(jnp.bfloat16)))
+    kept = {leaf_name(p) for p in read if p not in cast}
+    assert {"wte", "lnf_scale", "ln1_scale", "ln2_scale"} <= kept
+    assert kept.isdisjoint(gen._CAST_ONLY_BLOCK_LEAVES | {"head"})
+    assert ("['head']" in cast) == (not cfg.tie_weights)
+
+    toks_handed, pool_handed = serve(engine, engine.params)
+    toks_read, pool_read = serve(engine, engine.program_params)
+    np.testing.assert_array_equal(toks_handed, toks_read)
+    # sampling lanes do sample: they leave the greedy lane's choices
+    assert len({tuple(toks_read[:, lane]) for lane in range(SLOTS)}) == SLOTS
+    for name in pool_handed:
+        assert pool_read[name].any()
+        np.testing.assert_array_equal(pool_handed[name], pool_read[name])
+
+
+def weight_converts(text, params):
+    """``convert`` instructions of compiled HLO ``text`` that take float32
+    to bfloat16 and whose operand has the shape of a leaf of ``params`` that
+    ``cast_once_params`` casts: the whole leaf, or one layer of it."""
+    shapes = {params["head"].shape} if "head" in params else set()
+    for name, a in params["blocks"].items():
+        if name in gen._CAST_ONLY_BLOCK_LEAVES:
+            shapes |= {a.shape, a.shape[1:], (1,) + a.shape[1:]}
+    # the text names an operand without its shape: take it from the line
+    # that defines the operand
+    defined = {m.group(1): (m.group(2), m.group(3)) for m in re.finditer(
+        r"%([\w.\-]+) = (\w+)\[([\d,]*)\]", text)}
+    found = []
+    for m in re.finditer(r"= bf16\[[\d,]*\]\S* convert\(%([\w.\-]+)\)", text):
+        dtype, dims = defined[m.group(1)]
+        if dtype == "f32" and tuple(
+                int(d) for d in dims.split(",") if d) in shapes:
+            found.append(m.group(0))
+    return found
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip to compile for. The CPU backend
+    computes bfloat16 in float32, so its compiled text is full of converts
+    on either tree and says nothing; the TPU's compiler is installed here."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # such a compile would be written to the persistent cache and can never
+    # be read back without a chip: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("arch", ["gpt2-untied", "rope-experts"])
+def test_decode_program_converts_no_weight(arch, one_chip):
+    cfg, params = model(arch, "bfloat16")
+    engine = DecodeEngine(params, cfg, n_slots=SLOTS)
+    # what the engine yields for the audit is what the serving loop runs
+    (_, _, jitted, args, kwargs), = [
+        p for p in engine.programs() if p[0] == "decode"]
+    assert jitted is engine._decode_jit and args[0] is engine.program_params
+
+    def compiled_text(tree):
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            (tree,) + args[1:])
+        return jitted.lower(*shapes, **kwargs).compile().as_text()
+
+    assert weight_converts(compiled_text(engine.params), params)
+    assert not weight_converts(compiled_text(engine.program_params), params)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_metrics_say_what_the_programs_read(dtype):
+    cfg, params = model("gpt2-untied", dtype)
+    server = InferenceServer(params, cfg, n_slots=2, warmup=False)
+    got = server.metrics.summary()
+    n_params = gpt.param_count(params)
+    if dtype == "float32":
+        assert got["program_weights_cast"] == 0
+        assert got["program_weight_bytes"] == 4 * n_params
+        return
+    # 6 matmul weights and their 6 biases a block (stacked: one leaf each)
+    # and the head
+    assert got["program_weights_cast"] == 13
+    cast = sum(a.size for n, a in params["blocks"].items()
+               if n in gen._CAST_ONLY_BLOCK_LEAVES) + params["head"].size
+    assert got["program_weight_bytes"] == 4 * n_params - 2 * cast
