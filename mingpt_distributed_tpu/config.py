@@ -74,6 +74,19 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
                           rope=True, swiglu=True, rmsnorm=True, tie_weights=False,
                           vocab_size=32000, block_size=8192, ffn_mult=3.5,
                           rope_theta=1000000.0, n_experts=8, moe_top_k=2),
+    # kakaocorp/kanana-2-30b-a3b-instruct-2601 (model_type deepseek_v3): a
+    # latent (MLA) cache with no query latent, one dense SwiGLU layer, then
+    # 128 sigmoid-routed experts of 768 (6 a token, nothing dropped) beside
+    # two shared experts. Published and served in bfloat16.
+    "kanana-2-30b-a3b-instruct-2601": dict(
+        n_layer=48, n_head=32, n_embd=2048, vocab_size=128256,
+        block_size=32768, rope=True, rope_theta=1000000.0,
+        rope_interleave=True, swiglu=True, rmsnorm=True, norm_eps=1e-6,
+        tie_weights=False, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, n_dense_layers=1, ffn_dim=6144,
+        n_experts=128, moe_top_k=6, moe_ffn_dim=768, n_shared_experts=2,
+        moe_scoring="sigmoid", moe_route_scale=2.448,
+        param_dtype="bfloat16"),
 }
 
 
@@ -134,8 +147,13 @@ class GPTConfig:
     # loss, and generation alike). None disables.
     attn_logit_softcap: Optional[float] = None
     final_logit_softcap: Optional[float] = None
-    # Compute dtype for activations; params are kept in float32.
+    # Compute dtype for activations.
     dtype: str = "bfloat16"
+    # Dtype ``gpt.init`` makes the parameters in. float32 is the training
+    # master copy (the serving engine then keeps a cast copy of the matmul
+    # leaves, generate.cast_once_params); a model published and served in
+    # bfloat16 is made in bfloat16 and lives on the device once.
+    param_dtype: str = "float32"
     # Rematerialise each block in backward (jax.checkpoint) to trade FLOPs
     # for HBM.
     remat: bool = False
@@ -161,7 +179,21 @@ class GPTConfig:
     rmsnorm: bool = False
     n_kv_head: Optional[int] = None  # grouped-query attention; None = n_head
     ffn_mult: float = 4.0  # MLP expansion factor (reference hardcodes 4x)
+    # The MLP width as a number where a model publishes one that is no
+    # multiple of the width (``dense_width``); None = ffn_mult * n_embd.
+    ffn_dim: Optional[int] = None
     norm_eps: float = 1e-5  # LayerNorm/RMSNorm epsilon
+    # Rotate adjacent pairs (2i, 2i+1) instead of the halves (i, i + hd/2).
+    rope_interleave: bool = False
+    # Multi-head latent attention (DeepSeek-V2/V3, no query latent): > 0
+    # replaces wk/wv with a down-projection to one ``kv_lora_rank`` latent
+    # and one shared rotary key of ``qk_rope_head_dim`` a token (all that
+    # is cached) and an up-projection to per-head keys (``qk_nope_head_dim``)
+    # and values (``v_head_dim``). Needs rope and rmsnorm.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # Mixture-of-experts (ops/moe.py): 0 = dense MLP (reference semantics);
     # E > 0 replaces every block's MLP with E GELU experts, top-k routed,
     # expert axis sharded over the mesh's `ep` axis.
@@ -169,6 +201,22 @@ class GPTConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01  # load-balancing loss weight
+    # An expert's width (``expert_width``); None = the dense MLP's.
+    moe_ffn_dim: Optional[int] = None
+    # Which route (ops/moe.py). "softmax": GShard dispatch by capacity,
+    # gates renormalised for k > 1. "sigmoid": the DeepSeek-V3 route:
+    # sigmoid scores, the k best of score + a bias leaf, gates the chosen
+    # scores (normalised to sum to 1 under ``moe_norm_topk``) times
+    # ``moe_route_scale``, nothing dropped whatever the load, no aux loss.
+    moe_scoring: str = "softmax"
+    moe_norm_topk: bool = True
+    moe_route_scale: float = 1.0
+    # SwiGLU experts every token takes beside its routed ones, as one MLP
+    # of n_shared_experts * expert_width (sigmoid route only).
+    n_shared_experts: int = 0
+    # Leading layers that keep a dense MLP in an expert model: a stack of
+    # their own (params["dense_blocks"]) before the expert stack.
+    n_dense_layers: int = 0
     # Cross-entropy head chunking: >1 splits the LM-head matmul + softmax
     # into this many sequence chunks under jax.checkpoint, so the (B, T, V)
     # fp32 logits tensor — the dominant activation at GPT-2 vocab sizes —
@@ -278,10 +326,80 @@ class GPTConfig:
                 raise ConfigError(
                     f"moe_top_k={self.moe_top_k} outside [1, {self.n_experts}]"
                 )
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ConfigError(f"unknown moe_scoring {self.moe_scoring!r}")
+        if self.moe_scoring == "sigmoid" and not (self.n_experts and self.swiglu):
+            raise ConfigError(
+                "moe_scoring='sigmoid' is the dropless route of SwiGLU "
+                "experts: it needs n_experts > 0 and swiglu")
+        if self.moe_scoring == "softmax" and (
+                self.n_shared_experts or self.moe_route_scale != 1.0
+                or not self.moe_norm_topk):
+            raise ConfigError(
+                "shared experts, a gate scale and un-renormalised gates are "
+                "built for moe_scoring='sigmoid' only: the capacity route "
+                "(ops/moe.py) renormalises softmax gates and adds nothing")
+        if not 0 <= self.n_dense_layers <= self.n_layer:
+            raise ConfigError(
+                f"n_dense_layers={self.n_dense_layers} outside "
+                f"[0, {self.n_layer}]")
+        if self.n_dense_layers and not self.n_experts:
+            raise ConfigError(
+                "n_dense_layers names the dense layers that lead an expert "
+                "model: without n_experts every layer is dense already")
+        if self.kv_lora_rank:
+            if min(self.qk_nope_head_dim, self.qk_rope_head_dim,
+                   self.v_head_dim) < 1 or self.qk_rope_head_dim % 2:
+                raise ConfigError(
+                    "latent attention needs qk_nope_head_dim, v_head_dim and "
+                    "an even qk_rope_head_dim")
+            if not (self.rope and self.rmsnorm):
+                raise ConfigError(
+                    "latent attention rotates its shared key and norms its "
+                    "latent: it needs rope and rmsnorm")
+            if self.attention != "einsum" or self.n_kv_head is not None \
+                    or self.attention_window or self.attn_logit_softcap:
+                raise ConfigError(
+                    "latent attention is built for attention='einsum' with "
+                    "no window, softcap or n_kv_head: the flash, ring and "
+                    "ulysses paths take one head size for keys and values")
+        elif self.qk_nope_head_dim or self.qk_rope_head_dim or self.v_head_dim:
+            raise ConfigError(
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim belong "
+                "to latent attention: set kv_lora_rank")
+        if self.param_dtype not in ("float32", "bfloat16"):
+            raise ConfigError(
+                f"param_dtype {self.param_dtype!r}: float32 or bfloat16")
 
     @property
     def head_dim(self) -> int:
         return self.n_embd // self.n_head
+
+    @property
+    def qk_head_dim(self) -> int:
+        """A query's size against a key: nope + rope under latent
+        attention, else ``head_dim``."""
+        if self.kv_lora_rank:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        """The dimensions the rotary embedding turns: the shared rope key's
+        under latent attention, else a whole head's."""
+        return self.qk_rope_head_dim or self.head_dim
+
+    @property
+    def dense_width(self) -> int:
+        """Inner width of a dense MLP."""
+        return self.ffn_dim if self.ffn_dim is not None \
+            else int(self.ffn_mult * self.n_embd)
+
+    @property
+    def expert_width(self) -> int:
+        """Inner width of one routed expert."""
+        return self.moe_ffn_dim if self.moe_ffn_dim is not None \
+            else self.dense_width
 
     @property
     def kv_heads(self) -> int:

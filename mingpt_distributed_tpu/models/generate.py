@@ -40,13 +40,47 @@ from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.ops import layers as L
 
-Cache = Dict[str, jax.Array]  # {"k","v"}: (n_layer, B, block_size, KV, hd)
+# {"k", "v"}: (n_layer, B, block_size, heads, size), heads and size each
+# leaf's own (``cache_leaf_shapes``). A pool that counts a routed model's
+# rows carries a third leaf, MOE_ROWS, which is no cache of anything.
+Cache = Dict[str, jax.Array]
+
+#: the leaf of a serving pool's cache tree in which the cached forward
+#: counts an expert model's routed rows: (expert layers, E + 1) int32, the
+#: rows each expert computed and, last, the routes asked for
+#: (ops/moe.grouped_swiglu). It rides in the donated tree so that the
+#: programs add to it in place and nothing is fetched in a round.
+MOE_ROWS = "moe_rows"
+
+
+def cache_leaf_shapes(cfg: GPTConfig, batch: int) -> Dict[str, Tuple[int, ...]]:
+    """The shape of each leaf of a ``batch``-lane cache: the one
+    description ``init_cache``, the serving pool, its shardings and its
+    audits read. Per-head rows: keys and values alike, ``kv_heads`` of
+    ``head_dim``. Latent attention caches two different things a token,
+    each shared by all heads: ``"k"`` the rotated rope key and ``"v"`` the
+    normed latent (the values the absorbed attention averages, and the
+    first part of every key, so stored once)."""
+    rows = (cfg.n_layer, batch, cfg.block_size)
+    if cfg.kv_lora_rank:
+        return {"k": rows + (1, cfg.qk_rope_head_dim),
+                "v": rows + (1, cfg.kv_lora_rank)}
+    return {n: rows + (cfg.kv_heads, cfg.head_dim) for n in ("k", "v")}
 
 
 def init_cache(cfg: GPTConfig, batch: int, dtype=None) -> Cache:
-    shape = (cfg.n_layer, batch, cfg.block_size, cfg.kv_heads, cfg.head_dim)
     dtype = dtype or jnp.dtype(cfg.dtype)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return {n: jnp.zeros(shape, dtype)
+            for n, shape in cache_leaf_shapes(cfg, batch).items()}
+
+
+def init_moe_rows(cfg: GPTConfig) -> Optional[jax.Array]:
+    """A zeroed MOE_ROWS leaf, or None where the model counts nothing (only
+    the dropless route does)."""
+    if not (cfg.n_experts and cfg.moe_scoring == "sigmoid"):
+        return None
+    return jnp.zeros((cfg.n_layer - cfg.n_dense_layers, cfg.n_experts + 1),
+                     jnp.int32)
 
 
 def _lay_rows_over(old: jax.Array, rows: jax.Array, positions) -> jax.Array:
@@ -73,7 +107,7 @@ def _write_lane_rows(cache: Cache, rows, positions) -> Cache:
     either made the program copy the whole buffer into that layout and
     back, every call. A chain of slices in the entry computation takes
     the layout the buffer arrives in."""
-    out = {}
+    out = dict(cache)
     for name in ("k", "v"):
         buf = cache[name]
         new = jnp.stack([r[name] for r in rows])  # (L, B, 1, KV, hd)
@@ -87,13 +121,17 @@ def _write_lane_rows(cache: Cache, rows, positions) -> Cache:
 def _cached_block(
     x: jax.Array,            # (B, T, D) — T = prompt length or 1
     blk: gpt.Params,         # one layer's params (no leading L axis)
-    cache: Cache,            # FULL (L, B, S, KV, hd) buffers
+    cache: Cache,            # FULL (L, B, S, heads, size) buffers
     layer: int,
     offset: jax.Array,       # absolute position of x[:, 0]: scalar, or (B,)
     cfg: GPTConfig,
-) -> Tuple[jax.Array, Cache, Cache]:
-    """One pre-LN block against the cache. Returns (y, cache, rows): the
-    block's own (B, T, KV, hd) k/v ``rows`` in the cache's dtype.
+    valid: Optional[jax.Array] = None,  # (B, T) bool: tokens worth counting
+    expert_layer: Optional[int] = None,  # blk's EXPERT_LEAVES are the stack's
+) -> Tuple[jax.Array, Cache, Cache, Optional[jax.Array]]:
+    """One pre-LN block against the cache. Returns (y, cache, rows,
+    counts): the block's own (B, T, heads, size) k/v ``rows`` in the
+    cache's dtype, and a dropless expert layer's counts of the ``valid``
+    tokens' routed rows (ops/moe.grouped_swiglu; None for any other MLP).
 
     ``offset`` is one position for the whole batch (prefill, verify, solo
     ``generate``: rows that advance together): the rows are written into
@@ -113,9 +151,17 @@ def _cached_block(
     the layer's slice with the new rows laid over it (``_lay_rows_over``:
     the row a lane attends for its own token is the row as cached) and
     returns the cache as it came: the caller writes all layers' rows
-    after the last (``_write_lane_rows``). An expert MLP routes each
-    lane alone, since lanes are other users' requests: a lane's routes
-    must not depend on which other lanes are live.
+    after the last (``_write_lane_rows``). A capacity-routed expert MLP
+    routes each lane alone, since lanes are other users' requests: a
+    lane's routes must not depend on which other lanes are live. The
+    dropless route has that property by itself and takes the lanes' rows
+    together.
+
+    Latent attention (``cfg.kv_lora_rank``) caches a token's rotated rope
+    key and normed latent and attends them absorbed: the queries go
+    through W_UK to the latent's size, the heads average latents, and the
+    averages go through W_UV. Per-head keys and values of the cache are
+    never built, in prefill or in decode.
     """
     b, t, _ = x.shape
     nh, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
@@ -124,38 +170,57 @@ def _cached_block(
         raise ValueError(f"a position a lane takes one token a lane, not {t}")
 
     h = gpt._norm(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
-    q = L.dense(h, blk["wq"], blk.get("bq")).reshape(b, t, nh, hd)
-    k = L.dense(h, blk["wk"], blk.get("bk")).reshape(b, t, kv, hd)
-    v = L.dense(h, blk["wv"], blk.get("bv")).reshape(b, t, kv, hd)
     if cfg.rope:
-        cos, sin = attn_ops.rope_tables(
-            jnp.asarray(offset)[..., None] + jnp.arange(t), hd, cfg.rope_theta
-        )
-        q = attn_ops.apply_rope(q, cos, sin)
-        k = attn_ops.apply_rope(k, cos, sin)
+        rope = attn_ops.rope_tables(
+            jnp.asarray(offset)[..., None] + jnp.arange(t),
+            cfg.rope_dim, cfg.rope_theta)
+    if cfg.kv_lora_rank:
+        # what is cached: "v" the normed latent, "k" the rotated rope key
+        nope = cfg.qk_nope_head_dim
+        q_nope, q_pe, v, k = gpt.latent_parts(h, blk, cfg, rope)
+        w_kv_b = blk["w_kv_b"].astype(x.dtype).reshape(
+            cfg.kv_lora_rank, nh, nope + cfg.v_head_dim)
+        q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, w_kv_b[..., :nope])
+    else:
+        q = L.dense(h, blk["wq"], blk.get("bq")).reshape(b, t, nh, hd)
+        k = L.dense(h, blk["wk"], blk.get("bk")).reshape(b, t, kv, hd)
+        v = L.dense(h, blk["wv"], blk.get("bv")).reshape(b, t, kv, hd)
+        if cfg.rope:
+            q = attn_ops.apply_rope(q, *rope, cfg.rope_interleave)
+            k = attn_ops.apply_rope(k, *rope, cfg.rope_interleave)
 
     rows = {"k": k.astype(cache["k"].dtype), "v": v.astype(cache["v"].dtype)}
     if per_lane:
         big_k, big_v = (_lay_rows_over(cache[n][layer], rows[n], offset)
                         for n in ("k", "v"))
     else:
-        cache = {n: jax.lax.dynamic_update_slice(
+        cache = {**cache, **{n: jax.lax.dynamic_update_slice(
             cache[n], rows[n][None], (layer, 0, offset, 0, 0))
-            for n in ("k", "v")}
+            for n in ("k", "v")}}
         big_k, big_v = cache["k"][layer], cache["v"][layer]
     # attend against the whole cache; kv_offset makes query absolute
     # positions correct, and the causal mask kills both future tokens and
     # never-written (zero) slots beyond offset+t
-    att = attn_ops.causal_attention(
-        q, big_k, big_v, kv_offset=offset,
-        window=cfg.attention_window,
-        logit_softcap=cfg.attn_logit_softcap,
-    ).reshape(b, t, nh * hd)
+    if cfg.kv_lora_rank:
+        att = attn_ops.latent_attention(
+            q_lat, q_pe, big_v, big_k, kv_offset=offset,
+            scale=cfg.qk_head_dim ** -0.5)
+        att = jnp.einsum("bthr,rhv->bthv", att, w_kv_b[..., nope:]).reshape(
+            b, t, nh * cfg.v_head_dim)
+    else:
+        att = attn_ops.causal_attention(
+            q, big_k, big_v, kv_offset=offset,
+            window=cfg.attention_window,
+            logit_softcap=cfg.attn_logit_softcap,
+        ).reshape(b, t, nh * hd)
     att = L.dense(att, blk["wo"], blk.get("bo"))
     x = x + att
 
     h2 = gpt._norm(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
-    if cfg.n_experts:
+    counts = None
+    if "w_router" in blk and cfg.moe_scoring == "sigmoid":
+        m, counts = gpt.routed_and_shared(h2, blk, cfg, valid, expert_layer)
+    elif "w_router" in blk:
         from mingpt_distributed_tpu.ops import moe
 
         def experts(tokens):
@@ -174,11 +239,12 @@ def _cached_block(
     else:
         m = L.mlp_gelu(h2, blk["w_fc"], blk.get("b_fc"), blk["w_proj"],
                        blk.get("b_proj"))
-    return x + m, cache, rows
+    return x + m, cache, rows, counts
 
 
 def _forward_cached_hidden(
-    params: gpt.Params, tokens: jax.Array, cache: Cache, offset, cfg: GPTConfig
+    params: gpt.Params, tokens: jax.Array, cache: Cache, offset, cfg: GPTConfig,
+    valid: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Cache]:
     """Forward (B, T) tokens at absolute position ``offset`` (a scalar, or
     a ``(B,)`` vector of one position a row: see ``_cached_block``) through
@@ -187,6 +253,11 @@ def _forward_cached_hidden(
     so callers that need logits at a *dynamic* position (the serving
     prefill reads position ``prompt_len - 1`` of a padded prompt) can slice
     the hidden states before paying the head matmul.
+
+    A cache that carries MOE_ROWS (a serving pool's) gets the expert
+    layers' counts of the ``valid`` (B, T) tokens' routed rows added to it
+    (None: every token counts; a prefill's padding and a parked decode
+    lane are computed and not counted).
 
     The layer loop is a static python loop (n_layer is static, decode
     bodies are small) so each layer's cache update stays a one-slot
@@ -209,13 +280,25 @@ def _forward_cached_hidden(
         x = x + jnp.take(params["wpe"], pos, axis=0)
     x = x.astype(compute_dtype)
 
-    rows = []
+    rows, counts = [], []
+    n_dense = cfg.n_dense_layers
+    # a dropless layer's expert leaves stay the stack's: sliced out, they
+    # would be copied whole into the route's loop
+    whole = gpt.EXPERT_LEAVES if cfg.moe_scoring == "sigmoid" else ()
     for layer in range(cfg.n_layer):
-        blk = jax.tree.map(lambda a, _l=layer: a[_l], params["blocks"])
-        x, cache, new = _cached_block(x, blk, cache, layer, offset, cfg)
+        stack, at = (params["dense_blocks"], layer) if layer < n_dense \
+            else (params["blocks"], layer - n_dense)
+        blk = {n: a if n in whole else a[at] for n, a in stack.items()}
+        x, cache, new, routed = _cached_block(
+            x, blk, cache, layer, offset, cfg, valid,
+            expert_layer=at if whole else None)
         rows.append(new)
+        if routed is not None:
+            counts.append(routed)
     if jnp.ndim(offset) == 1:
         cache = _write_lane_rows(cache, rows, offset)
+    if MOE_ROWS in cache and counts:
+        cache = {**cache, MOE_ROWS: cache[MOE_ROWS] + jnp.stack(counts)}
     x = gpt._norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg)
     return x, cache
 
@@ -242,6 +325,7 @@ _CAST_ONLY_BLOCK_LEAVES = frozenset({
     "w_fc", "b_fc", "w_proj", "b_proj",
     "w_gate", "w_up", "w_down",
     "w_e1", "w_e2", "w_eg",
+    "w_kv_a", "w_kv_b", "w_sg", "w_su", "w_sd",
 })
 
 
@@ -256,11 +340,14 @@ def cast_once_params(
     hoist the cast itself) converts no weight, and computes bit for bit what
     it computes on ``params`` (tests/test_cast_once.py holds every
     architecture to that: a leaf added to the forward is added here only if
-    it passes). Where no leaf needs a cast (a float32 model) the tree
-    returned IS ``params``."""
+    it passes). Where no leaf needs a cast (a float32 model, or parameters
+    made in the compute dtype: ``cfg.param_dtype``) the tree returned IS
+    ``params``, and the device holds the weights once."""
     dtype = jnp.dtype(cfg.dtype)
-    picked = {"blocks": {n: a for n, a in params["blocks"].items()
-                         if n in _CAST_ONLY_BLOCK_LEAVES and a.dtype != dtype}}
+    stacks = [s for s in ("dense_blocks", "blocks") if s in params]
+    picked = {s: {n: a for n, a in params[s].items()
+                  if n in _CAST_ONLY_BLOCK_LEAVES and a.dtype != dtype}
+              for s in stacks}
     if "head" in params and params["head"].dtype != dtype:
         picked["head"] = params["head"]
     n_cast = len(jax.tree.leaves(picked))
@@ -276,18 +363,19 @@ def cast_once_params(
             lambda a: a.sharding if getattr(a, "committed", False) else None,
             picked),
     )(picked)
-    blocks = {**params["blocks"], **cast.pop("blocks")}
-    return {**params, **cast, "blocks": blocks}, n_cast
+    cast.update({s: {**params[s], **cast[s]} for s in stacks})
+    return {**params, **cast}, n_cast
 
 
 def _forward_cached(
-    params: gpt.Params, tokens: jax.Array, cache: Cache, offset, cfg: GPTConfig
+    params: gpt.Params, tokens: jax.Array, cache: Cache, offset, cfg: GPTConfig,
+    valid: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Cache]:
     """Forward (B, T) tokens at position ``offset`` through all layers.
     Returns (last-position logits (B, V), cache). Thin composition of
     ``_forward_cached_hidden`` + ``_head_logits`` — the serving engine
     (serving/engine.py) shares the same two pieces."""
-    x, cache = _forward_cached_hidden(params, tokens, cache, offset, cfg)
+    x, cache = _forward_cached_hidden(params, tokens, cache, offset, cfg, valid)
     logits = _head_logits(params, x[:, -1:], cfg)[:, 0]
     return logits, cache
 

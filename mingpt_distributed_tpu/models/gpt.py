@@ -10,7 +10,7 @@ model bugs (B3-B6, B16: broken asserts, pos-embedding indexed by token value,
 MLP activation after both linears, non-masking float causal mask) fixed by
 construction, and the mechanism re-thought for XLA:
 
-* the model is data — a pytree of float32 arrays — and ``forward`` is a pure
+* the model is data — a pytree of arrays (float32, or ``cfg.param_dtype``) — and ``forward`` is a pure
   function, so sharding enters from *outside* via NamedSharding on the pytree
   (preserving the reference's parallelism-unaware-model layering, SURVEY §1-L2);
 * per-layer parameters are stacked along a leading layer axis and the block
@@ -46,76 +46,118 @@ Params = Dict[str, Any]
 
 
 def init(key: jax.Array, cfg: GPTConfig) -> Params:
-    """Materialise the parameter pytree.
+    """Materialise the parameter pytree, in ``cfg.param_dtype``.
 
     Init scheme is the reference's (model.py:298-307, 252-256): weights
     N(0, 0.02), biases 0, LayerNorm identity, positional embedding zeros
     (model.py:209-214), residual-path projections N(0, 0.02/sqrt(2L)).
     Runs fine under jit with out_shardings so huge models can be born sharded.
+
+    ``params["blocks"]`` stacks the layers of one kind along a leading
+    axis. An expert model with ``n_dense_layers`` leading dense layers has
+    two kinds: those lie in ``params["dense_blocks"]``, a stack of their
+    own before the expert stack.
     """
     cfg.validate()
-    d, nl, nh = cfg.n_embd, cfg.n_layer, cfg.n_head
-    hd, kv = cfg.head_dim, cfg.kv_heads
-    ffn = int(cfg.ffn_mult * d)
-    use_bias = not (cfg.swiglu or cfg.rmsnorm)  # GPT-2 mode has biases everywhere
+    d = cfg.n_embd
+    dtype = jnp.dtype(cfg.param_dtype)
 
     keys = iter(jax.random.split(key, 32))
     std = 0.02
-    resid_std = 0.02 / math.sqrt(2 * nl)
+    resid_std = 0.02 / math.sqrt(2 * cfg.n_layer)
 
     def normal(k, shape, s=std):
-        return jax.random.normal(k, shape, dtype=jnp.float32) * s
+        # drawn in float32 whatever the leaf's dtype: a bfloat16 leaf is
+        # the float32 draw rounded, not another stream
+        return (jax.random.normal(k, shape, dtype=jnp.float32) * s).astype(dtype)
 
-    blocks: Params = {
-        "ln1_scale": jnp.ones((nl, d)),
-        "ln2_scale": jnp.ones((nl, d)),
-        "wq": normal(next(keys), (nl, d, nh * hd)),
-        "wk": normal(next(keys), (nl, d, kv * hd)),
-        "wv": normal(next(keys), (nl, d, kv * hd)),
-        "wo": normal(next(keys), (nl, nh * hd, d), resid_std),
-    }
-    if not cfg.rmsnorm:
-        blocks["ln1_bias"] = jnp.zeros((nl, d))
-        blocks["ln2_bias"] = jnp.zeros((nl, d))
-    if use_bias:
-        blocks.update(
-            bq=jnp.zeros((nl, nh * hd)),
-            bk=jnp.zeros((nl, kv * hd)),
-            bv=jnp.zeros((nl, kv * hd)),
-            bo=jnp.zeros((nl, d)),
-        )
-    if cfg.n_experts:
-        e = cfg.n_experts
-        blocks.update(
-            w_router=normal(next(keys), (nl, d, e)),
-            w_e1=normal(next(keys), (nl, e, d, ffn)),
-            w_e2=normal(next(keys), (nl, e, ffn, d), resid_std),
-        )
-        if cfg.swiglu:  # Mixtral-style SwiGLU experts
-            blocks["w_eg"] = normal(next(keys), (nl, e, d, ffn))
-    elif cfg.swiglu:
-        blocks.update(
-            w_gate=normal(next(keys), (nl, d, ffn)),
-            w_up=normal(next(keys), (nl, d, ffn)),
-            w_down=normal(next(keys), (nl, ffn, d), resid_std),
-        )
-    else:
-        blocks.update(
-            w_fc=normal(next(keys), (nl, d, ffn)),
-            w_proj=normal(next(keys), (nl, ffn, d), resid_std),
-        )
+    ones = lambda shape: jnp.ones(shape, dtype)
+    zeros = lambda shape: jnp.zeros(shape, dtype)
+
+    def stack(nl: int, experts: bool) -> Params:
+        """``nl`` layers of one kind, stacked: the model's attention and a
+        dense MLP or, under ``experts``, the routed one."""
+        nh = cfg.n_head
+        hd, kv = cfg.head_dim, cfg.kv_heads
+        ffn = cfg.expert_width if experts else cfg.dense_width
+        use_bias = not (cfg.swiglu or cfg.rmsnorm)  # GPT-2 mode has biases everywhere
+
+        blocks: Params = {"ln1_scale": ones((nl, d)), "ln2_scale": ones((nl, d))}
+        if cfg.kv_lora_rank:
+            r, rope_d = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+            blocks.update(
+                wq=normal(next(keys), (nl, d, nh * cfg.qk_head_dim)),
+                # the latent and the shared rotary key, side by side
+                w_kv_a=normal(next(keys), (nl, d, r + rope_d)),
+                kv_norm_scale=ones((nl, r)),
+                # per head [k_nope | v], as the published kv_b_proj lays them
+                w_kv_b=normal(next(keys), (
+                    nl, r, nh * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+                wo=normal(next(keys), (nl, nh * cfg.v_head_dim, d), resid_std),
+            )
+        else:
+            blocks.update(
+                wq=normal(next(keys), (nl, d, nh * hd)),
+                wk=normal(next(keys), (nl, d, kv * hd)),
+                wv=normal(next(keys), (nl, d, kv * hd)),
+                wo=normal(next(keys), (nl, nh * hd, d), resid_std),
+            )
+        if not cfg.rmsnorm:
+            blocks["ln1_bias"] = zeros((nl, d))
+            blocks["ln2_bias"] = zeros((nl, d))
         if use_bias:
-            blocks.update(b_fc=jnp.zeros((nl, ffn)), b_proj=jnp.zeros((nl, d)))
+            blocks.update(
+                bq=zeros((nl, nh * hd)),
+                bk=zeros((nl, kv * hd)),
+                bv=zeros((nl, kv * hd)),
+                bo=zeros((nl, d)),
+            )
+        if experts:
+            e = cfg.n_experts
+            blocks["w_router"] = normal(next(keys), (nl, d, e))
+            if cfg.moe_scoring == "sigmoid":
+                # e_score_correction_bias: drawn, so that the choice it moves
+                # is exercised (a trained model's balances the experts' load)
+                blocks["e_bias"] = normal(next(keys), (nl, e))
+            blocks.update(
+                w_e1=normal(next(keys), (nl, e, d, ffn)),
+                w_e2=normal(next(keys), (nl, e, ffn, d), resid_std),
+            )
+            if cfg.swiglu:  # Mixtral-style SwiGLU experts
+                blocks["w_eg"] = normal(next(keys), (nl, e, d, ffn))
+            if cfg.n_shared_experts:
+                shared = cfg.n_shared_experts * ffn
+                blocks.update(
+                    w_sg=normal(next(keys), (nl, d, shared)),
+                    w_su=normal(next(keys), (nl, d, shared)),
+                    w_sd=normal(next(keys), (nl, shared, d), resid_std),
+                )
+        elif cfg.swiglu:
+            blocks.update(
+                w_gate=normal(next(keys), (nl, d, ffn)),
+                w_up=normal(next(keys), (nl, d, ffn)),
+                w_down=normal(next(keys), (nl, ffn, d), resid_std),
+            )
+        else:
+            blocks.update(
+                w_fc=normal(next(keys), (nl, d, ffn)),
+                w_proj=normal(next(keys), (nl, ffn, d), resid_std),
+            )
+            if use_bias:
+                blocks.update(b_fc=zeros((nl, ffn)), b_proj=zeros((nl, d)))
+        return blocks
 
-    params: Params = {
-        "wte": normal(next(keys), (cfg.vocab_size, d)),
-        "blocks": blocks,
-        "lnf_scale": jnp.ones((d,)),
-    }
+    params: Params = {}
+    if cfg.n_dense_layers:
+        params["dense_blocks"] = stack(cfg.n_dense_layers, experts=False)
+    params["blocks"] = stack(cfg.n_layer - cfg.n_dense_layers,
+                             experts=bool(cfg.n_experts))
+    params["wte"] = normal(next(keys), (cfg.vocab_size, d))
+    params["lnf_scale"] = ones((d,))
     if not cfg.rope:
-        params["wpe"] = jnp.zeros((cfg.block_size, d))
+        params["wpe"] = zeros((cfg.block_size, d))
     if not cfg.rmsnorm:
-        params["lnf_bias"] = jnp.zeros((d,))
+        params["lnf_bias"] = zeros((d,))
     if not cfg.tie_weights:
         params["head"] = normal(next(keys), (d, cfg.vocab_size))
     return params
@@ -235,6 +277,66 @@ def _norm(x, scale, bias, cfg: GPTConfig):
     return L.layer_norm(x, scale, bias, eps=cfg.norm_eps)
 
 
+def latent_parts(h, blk: Params, cfg: GPTConfig, rope):
+    """What latent attention makes of (B, T, D) normed activations before
+    it attends, in either form: the queries' nope part (B, T, H, nope) and
+    rotated rope part (B, T, H, e), and the two things a token caches, each
+    shared by all heads: the normed latent (B, T, 1, r) and the rotated
+    rope key (B, T, 1, e)."""
+    b, t, _ = h.shape
+    r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    cos, sin = rope
+    q = L.dense(h, blk["wq"]).reshape(b, t, cfg.n_head, cfg.qk_head_dim)
+    q_pe = attn_ops.apply_rope(q[..., nope:], cos, sin, cfg.rope_interleave)
+    kv_a = L.dense(h, blk["w_kv_a"])
+    latent = L.rms_norm(kv_a[..., None, :r], blk["kv_norm_scale"],
+                        eps=cfg.norm_eps)
+    k_pe = attn_ops.apply_rope(kv_a[..., None, r:], cos, sin,
+                               cfg.rope_interleave)
+    return q[..., :nope], q_pe, latent, k_pe
+
+
+def latent_qkv(h, blk: Params, cfg: GPTConfig, rope):
+    """Latent attention as published, not absorbed: (B, T, D) normed
+    activations -> per-head q, k (B, T, H, nope + rope) and v (B, T, H,
+    v_head_dim). The latent is taken up through ``w_kv_b`` to every head's
+    own key part and value; the one rotated rope key is shared by all
+    heads. The cached forward never builds these (it attends the latents:
+    generate._cached_block); this is the form the uncached forward and
+    training take, and the one the cached form is held to."""
+    b, t, _ = h.shape
+    nh, nope = cfg.n_head, cfg.qk_nope_head_dim
+    q_nope, q_pe, latent, k_pe = latent_parts(h, blk, cfg, rope)
+    kv_b = L.dense(latent[:, :, 0], blk["w_kv_b"]).reshape(
+        b, t, nh, nope + cfg.v_head_dim)
+    k = jnp.concatenate([kv_b[..., :nope], jnp.broadcast_to(
+        k_pe, (b, t, nh, cfg.qk_rope_head_dim))], -1)
+    return jnp.concatenate([q_nope, q_pe], -1), k, kv_b[..., nope:]
+
+
+#: the routed experts' leaves: the cached forward hands ``routed_and_shared``
+#: the stack's, unsliced, with the layer's index (ops/moe.grouped_swiglu)
+EXPERT_LEAVES = ("w_eg", "w_e1", "w_e2")
+
+
+def routed_and_shared(h2, blk: Params, cfg: GPTConfig, valid=None,
+                      layer=None):
+    """The MLP of a sigmoid-routed expert layer: each token's k routed
+    experts (ops/moe.moe_dropless) plus the shared expert every token
+    takes. Returns (out, the route's counts). With ``layer``, the
+    EXPERT_LEAVES of ``blk`` are the whole stack's."""
+    from mingpt_distributed_tpu.ops import moe
+
+    m, counts = moe.moe_dropless(
+        h2, blk["w_router"], blk["e_bias"], blk["w_eg"], blk["w_e1"],
+        blk["w_e2"], top_k=cfg.moe_top_k, norm_topk=cfg.moe_norm_topk,
+        route_scale=cfg.moe_route_scale, valid=valid, layer=layer)
+    if "w_sg" in blk:
+        with jax.named_scope("moe_shared"):
+            m = m + L.mlp_swiglu(h2, blk["w_sg"], blk["w_su"], blk["w_sd"])
+    return m, counts
+
+
 def _block(
     x: jax.Array,
     blk: Params,
@@ -277,13 +379,17 @@ def _block(
 
     with jax.named_scope("attn"):
         h = _norm(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
-        q = L.dense(h, blk["wq"], blk.get("bq")).reshape(b, t, nh, hd)
-        k = L.dense(h, blk["wk"], blk.get("bk")).reshape(b, t, kv, hd)
-        v = L.dense(h, blk["wv"], blk.get("bv")).reshape(b, t, kv, hd)
-        if rope is not None:
-            cos, sin = rope
-            q = attn_ops.apply_rope(q, cos, sin)
-            k = attn_ops.apply_rope(k, cos, sin)
+        if cfg.kv_lora_rank:
+            q, k, v = latent_qkv(h, blk, cfg, rope)
+            hd = cfg.v_head_dim
+        else:
+            q = L.dense(h, blk["wq"], blk.get("bq")).reshape(b, t, nh, hd)
+            k = L.dense(h, blk["wk"], blk.get("bk")).reshape(b, t, kv, hd)
+            v = L.dense(h, blk["wv"], blk.get("bv")).reshape(b, t, kv, hd)
+            if rope is not None:
+                cos, sin = rope
+                q = attn_ops.apply_rope(q, cos, sin, cfg.rope_interleave)
+                k = attn_ops.apply_rope(k, cos, sin, cfg.rope_interleave)
         # window/softcap compose with every attention impl, including the
         # manual-sp attn_fn override inside pipeline stages
         attn_kw = {}
@@ -310,7 +416,9 @@ def _block(
     with jax.named_scope("mlp"):
         h2 = _norm(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
         aux = jnp.zeros((), jnp.float32)
-        if cfg.n_experts:
+        if "w_router" in blk and cfg.moe_scoring == "sigmoid":
+            m, _ = routed_and_shared(h2, blk, cfg)
+        elif "w_router" in blk:
             from mingpt_distributed_tpu.ops import moe
 
             m, aux = moe.moe_mlp(
@@ -377,15 +485,17 @@ def forward(
 
     rope = None
     if cfg.rope:
-        rope = attn_ops.rope_tables(jnp.arange(t), cfg.head_dim, cfg.rope_theta)
+        rope = attn_ops.rope_tables(jnp.arange(t), cfg.rope_dim, cfg.rope_theta)
 
     nl = cfg.n_layer
+    n_dense = cfg.n_dense_layers
     if deterministic:
         def body(carry, blk):
             xc, aux = carry
             y, a = _block(xc, blk, cfg, rope, None, True, mesh)
             return (y, aux + a), None
         xs = params["blocks"]
+        xs_dense = params.get("dense_blocks")
     else:
         layer_keys = jax.random.split(rng, nl)
         def body(carry, scanned):
@@ -393,10 +503,27 @@ def forward(
             xc, aux = carry
             y, a = _block(xc, blk, cfg, rope, key, False, mesh)
             return (y, aux + a), None
-        xs = (params["blocks"], layer_keys)
+        xs = (params["blocks"], layer_keys[n_dense:])
+        xs_dense = (params.get("dense_blocks"), layer_keys[:n_dense])
 
     step = jax.checkpoint(body) if cfg.remat else body
 
+    def run_stack(carry, xs, n):
+        """``n`` stacked layers of one kind over the carry."""
+        if cfg.unroll_layers:
+            # statically unrolled layer loop: same body (incl. remat
+            # wrapping), but per-layer params/keys are static slices: no
+            # scan carry, no dynamic-update-slice stacking of saved
+            # activations (see config.unroll_layers)
+            for i in range(n):
+                carry, _ = step(carry, jax.tree.map(lambda a: a[i], xs))
+            return carry
+        return jax.lax.scan(step, carry, xs, unroll=cfg.scan_unroll)[0]
+
+    if mesh is not None and mesh.shape.get("pp", 1) > 1 and n_dense:
+        raise NotImplementedError(
+            "pipeline stages split one stack of like layers: a model with "
+            "leading dense layers (n_dense_layers) has two")
     if mesh is not None and mesh.shape.get("pp", 1) > 1:
         # pipeline stages over the pp axis (parallel/pipeline.py): the same
         # scanned block, applied to each stage's layer shard per microbatch.
@@ -433,7 +560,7 @@ def forward(
         # per *layer* inside the scan (ZeRO-3-style JIT gather: one layer's
         # params live at a time; remat re-gathers in backward).
         tp_n = mesh.shape.get("tp", 1)
-        ffn_dim = int(cfg.ffn_mult * cfg.n_embd)
+        ffn_dim = cfg.dense_width
         tp_manual = (
             tp_n > 1
             and not cfg.n_experts
@@ -552,21 +679,11 @@ def forward(
             xs_specs=xs_specs,
             schedule=cfg.pp_schedule,
         )
-    elif cfg.unroll_layers:
-        # statically unrolled layer loop: same body (incl. remat wrapping),
-        # but per-layer params/keys are static slices — no scan carry, no
-        # dynamic-update-slice stacking of saved activations (see
-        # config.unroll_layers)
-        carry = (x, jnp.zeros((), jnp.float32))
-        for i in range(nl):
-            xi = jax.tree.map(lambda a: a[i], xs)
-            carry, _ = step(carry, xi)
-        x, moe_aux = carry
     else:
-        (x, moe_aux), _ = jax.lax.scan(
-            step, (x, jnp.zeros((), jnp.float32)), xs,
-            unroll=cfg.scan_unroll,
-        )
+        carry = (x, jnp.zeros((), jnp.float32))
+        if n_dense:
+            carry = run_stack(carry, xs_dense, n_dense)
+        x, moe_aux = run_stack(carry, xs, nl - n_dense)
 
     x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg)
     w_head = params["wte"].T if cfg.tie_weights else params["head"]

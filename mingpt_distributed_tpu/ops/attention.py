@@ -122,15 +122,99 @@ def rope_tables(
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
+               interleave: bool = False) -> jax.Array:
     """Rotate (B, T, H, hd) by per-position tables (T, hd/2), or by a
-    table a batch entry (B, T, hd/2)."""
+    table a batch entry (B, T, hd/2). Pair i is the halves' ``(x[i],
+    x[i + hd/2])`` or, under ``interleave`` (DeepSeek's ``rope_interleave``),
+    the neighbours ``(x[2i], x[2i + 1])``; either way the rotated pair goes
+    back where it came from."""
     half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
+    if interleave:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+    else:
+        x1, x2 = x[..., :half], x[..., half:]
     cos = cos[..., None, :].astype(jnp.float32)  # the heads' axis
     sin = sin[..., None, :].astype(jnp.float32)
     x1f, x2f = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    out = jnp.concatenate(
-        [x1f * cos - x2f * sin, x2f * cos + x1f * sin], axis=-1
-    )
+    r1, r2 = x1f * cos - x2f * sin, x2f * cos + x1f * sin
+    if interleave:
+        out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
+    else:
+        out = jnp.concatenate([r1, r2], axis=-1)
     return out.astype(x.dtype)
+
+
+#: cached rows a step of ``latent_attention``'s loop attends
+LATENT_KV_BLOCK = 1024
+
+
+@jax.named_scope("latent_attn")
+def latent_attention(
+    q_lat: jax.Array,   # (B, T, H, r): queries absorbed through W_UK
+    q_pe: jax.Array,    # (B, T, H, e): rotated rope part of the queries
+    latent: jax.Array,  # (B, S, 1, r): the cached normed latents
+    k_pe: jax.Array,    # (B, S, 1, e): the cached rotated shared rope key
+    *,
+    kv_offset: int | jax.Array,
+    scale: float,
+) -> jax.Array:
+    """Causal attention against a latent (MLA) cache in its absorbed form:
+    a key is ``[latent ; rope key]``, shared by all heads, and the values
+    are the latents themselves, so the cache is never expanded to per-head
+    keys and values. Returns the heads' averaged latents (B, T, H, r); the
+    caller takes them through W_UV. ``kv_offset`` as in
+    ``causal_attention``: a scalar, or a position a row (B,).
+
+    One token a row (T == 1, the decode step) attends its whole slice in
+    one pass. A chunk of tokens (prefill) walks the slice in blocks of
+    ``LATENT_KV_BLOCK`` rows under a running softmax and stops at the last
+    block any query can see, so a prompt pays for the rows it has, not
+    for the window, and the (H, T, S) scores never exist whole."""
+    b, t, h, r = q_lat.shape
+    s, out_dtype = latent.shape[1], q_lat.dtype
+    q_pos = jnp.arange(t)[:, None] + jnp.asarray(kv_offset)[..., None, None]
+    # one key and one value a row for all heads: the heads are rows of the
+    # matmuls' left side, (B, T*H, .) against (B, S, .), scores (B, T, H, S)
+    q_lat = q_lat.reshape(b, t * h, r)
+    q_pe = q_pe.reshape(b, t * h, -1)
+
+    def scores(lat_blk, pe_blk, k_pos):
+        z = jnp.einsum("bqr,bsr->bqs", q_lat, lat_blk[:, :, 0],
+                       preferred_element_type=jnp.float32)
+        z = z + jnp.einsum("bqe,bse->bqs", q_pe, pe_blk[:, :, 0],
+                           preferred_element_type=jnp.float32)
+        allowed = q_pos >= k_pos[None, :]          # (T, S') or (B, T, S')
+        return jnp.where(allowed[..., None, :],
+                         z.reshape(b, t, h, -1) * scale, NEG_INF)
+
+    def weigh(p, lat_blk):
+        return jnp.einsum(
+            "bqs,bsr->bqr", p.astype(latent.dtype).reshape(b, t * h, -1),
+            lat_blk[:, :, 0], preferred_element_type=jnp.float32,
+        ).reshape(b, t, h, r)
+
+    if t == 1 or s <= LATENT_KV_BLOCK or s % LATENT_KV_BLOCK:
+        p = jax.nn.softmax(scores(latent, k_pe, jnp.arange(s)), axis=-1)
+        return weigh(p, latent).astype(out_dtype)
+
+    blk = LATENT_KV_BLOCK
+
+    def step(i, carry):
+        m, l, acc = carry
+        lat_blk = jax.lax.dynamic_slice_in_dim(latent, i * blk, blk, axis=1)
+        pe_blk = jax.lax.dynamic_slice_in_dim(k_pe, i * blk, blk, axis=1)
+        z = scores(lat_blk, pe_blk, i * blk + jnp.arange(blk))
+        m_new = jnp.maximum(m, z.max(-1))               # (B, T, H)
+        p = jnp.exp(z - m_new[..., None])
+        fix = jnp.exp(m - m_new)
+        return (m_new, l * fix + p.sum(-1),
+                acc * fix[..., None] + weigh(p, lat_blk))
+
+    # the last row any query of the chunk sees is its own last position
+    n_blocks = jnp.minimum((jnp.max(q_pos) + blk) // blk, s // blk)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, step, (
+        jnp.full((b, t, h), NEG_INF, jnp.float32),
+        jnp.zeros((b, t, h), jnp.float32),
+        jnp.zeros((b, t, h, r), jnp.float32)))
+    return (acc / l[..., None]).astype(out_dtype)

@@ -78,5 +78,12 @@ def mlp_gelu(
 def mlp_swiglu(
     x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array
 ) -> jax.Array:
-    """SwiGLU MLP (Llama retrofit): down(silu(gate(x)) * up(x))."""
-    return dense(jax.nn.silu(dense(x, w_gate)) * dense(x, w_up), w_down)
+    """SwiGLU MLP (Llama retrofit): down(silu(gate(x)) * up(x)). The gate
+    and up products stay in the matmuls' float32 accumulators through the
+    activation and are rounded to x's dtype once, as the product the down
+    matmul takes (three roundings fewer a layer than rounding each: a fifth
+    of a bf16 block's error against a float32 reference, PERF.md PR 30)."""
+    gate = jnp.dot(x, w_gate.astype(x.dtype),
+                   preferred_element_type=jnp.float32)
+    up = jnp.dot(x, w_up.astype(x.dtype), preferred_element_type=jnp.float32)
+    return dense((jax.nn.silu(gate) * up).astype(x.dtype), w_down)

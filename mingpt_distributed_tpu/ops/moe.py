@@ -1,4 +1,16 @@
-"""Mixture-of-experts MLP with capacity-based dispatch (GShard/Switch style).
+"""Mixture-of-experts MLPs. Two routes, chosen by ``GPTConfig.moe_scoring``:
+
+* ``moe_mlp`` ("softmax"): capacity-based dispatch, GShard/Switch style,
+  described below. The Mixtral-style presets, the benchmark's fixture
+  ``rope-experts`` and every training path take it, as does the ``ep``
+  exchange; tokens over an expert's capacity are dropped.
+* ``moe_dropless`` ("sigmoid"): the DeepSeek-V3 route (``noaux_tc`` without
+  a group limit), which kanana-2-30b-a3b takes: sigmoid scores, the k best
+  of score + bias, the chosen scores normalised and scaled as gates, every
+  route computed whatever the load, a shared expert added by the caller.
+  See the function.
+
+The capacity route:
 
 Beyond-parity capability (SURVEY §2.2: the reference has a dense MLP only,
 model.py:179-184; EP/MoE marked absent). TPU-native design: dispatch and
@@ -183,3 +195,138 @@ def moe_mlp(
     p = jnp.mean(probs.reshape(s, e), axis=0)  # mean router prob per expert
     aux = e * jnp.sum(f * p)
     return out.reshape(b, t, d), aux
+
+
+# ---------------------------------------------------------------------------
+# The dropless route
+# ---------------------------------------------------------------------------
+
+
+def _row_block(rows: int, e: int) -> int:
+    """Rows of one step of the grouped matmul: the power of two nearest
+    above an expert's mean load, within [8, 256]. Every expert's rows are
+    padded up to a multiple of it, so it trades padding (small loads) for
+    steps (large ones)."""
+    mean = max(1, -(-rows // e))
+    return min(256, max(8, 1 << (mean - 1).bit_length()))
+
+
+def sigmoid_routes(h, w_router, bias, *, top_k: int, norm_topk: bool,
+                   route_scale: float):
+    """(N, D) tokens -> (chosen experts (N, k) int32, gates (N, k) float32,
+    selection scores (N, E) float32). Scores are ``sigmoid(h w_router)`` in
+    float32; the k chosen are the largest of score + bias; the gates are
+    the chosen experts' *scores* (the bias moves the choice and never a
+    gate), over their sum under ``norm_topk``, times ``route_scale``."""
+    z = h.astype(jnp.float32) @ w_router.astype(jnp.float32)
+    scores = jax.nn.sigmoid(z)
+    select = scores + bias.astype(jnp.float32)
+    chosen = jax.lax.top_k(select, top_k)[1]
+    gates = jnp.take_along_axis(scores, chosen, axis=-1)
+    if norm_topk:
+        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), gates * route_scale, select
+
+
+@jax.named_scope("moe_experts")
+def grouped_swiglu(x, chosen, w_gate, w_up, w_down, valid=None, layer=None):
+    """Every token's chosen experts on it: (N, D) tokens and (N, k) experts
+    -> ((N, k, D) float32 expert outputs, (E + 1,) int32 counts). The
+    weights are one layer's (E, D, F) / (E, F, D) or, with ``layer``, the
+    whole stack's (L, E, ...) of which that layer is taken: the scan below
+    then reads its one expert a step straight out of the stacked leaf. (A
+    layer sliced out first is an operand of the loop, and the TPU compiler
+    copies it there whole: 1.1 GB a layer at 128 experts of 2,048 x 768.)
+
+    Nothing is dropped and no expert computes a token that did not choose
+    it: the N*k routes are laid out grouped by expert, each group padded
+    to whole blocks of ``_row_block`` rows (at most E - 1 blocks of
+    padding in all, whatever the load: the shapes are static), and a scan
+    takes one block a step through its one expert's three matrices. A
+    route's result depends on its own token alone, so which other tokens
+    share the call changes nothing for it.
+
+    ``counts[:E]`` are the rows each expert's blocks computed for tokens
+    of ``valid`` (N,) (None: all), counted from the layout the blocks
+    read; ``counts[E]`` is the routes those tokens asked for. The two
+    agree exactly when nothing was dropped."""
+    n, d = x.shape
+    k = chosen.shape[1]
+    e, first = w_gate.shape[-3], 0
+    if layer is not None:
+        first = layer * e
+        w_gate, w_up, w_down = (w.reshape((-1,) + w.shape[2:])
+                                for w in (w_gate, w_up, w_down))
+    rows = n * k
+    bm = _row_block(rows, e)
+    n_blocks = -(-rows // bm) + e - 1
+
+    flat = chosen.reshape(rows)
+    onehot = jax.nn.one_hot(flat, e, dtype=jnp.int32)            # (R, E)
+    sizes = onehot.sum(0)                                         # (E,)
+    rank = jnp.take_along_axis(
+        jnp.cumsum(onehot, axis=0) - onehot, flat[:, None], axis=1)[:, 0]
+    blocks_of = -(-sizes // bm)                                   # (E,)
+    first_block = jnp.cumsum(blocks_of) - blocks_of
+    dest = first_block[flat] * bm + rank                          # (R,)
+    # the block's expert; blocks past the last group are padding of the
+    # last expert, and hold no row
+    block_expert = jnp.minimum(jnp.searchsorted(
+        jnp.cumsum(blocks_of), jnp.arange(n_blocks), side="right"), e - 1)
+    # which route lies at each row of the layout (rows: padding)
+    source = jnp.full((n_blocks * bm,), rows, jnp.int32).at[dest].set(
+        jnp.arange(rows, dtype=jnp.int32))
+    real = source < rows
+    token = jnp.where(real, source // k, 0)
+    laid = jnp.where(real[:, None], x[token], 0).reshape(n_blocks, bm, d)
+
+    def block(_, item):
+        rows_in, ex = item
+        ex = first + ex
+        gate = jnp.dot(rows_in, w_gate[ex].astype(x.dtype),
+                       preferred_element_type=jnp.float32)
+        up = jnp.dot(rows_in, w_up[ex].astype(x.dtype),
+                     preferred_element_type=jnp.float32)
+        inner = (jax.nn.silu(gate) * up).astype(x.dtype)
+        return None, jnp.dot(inner, w_down[ex].astype(x.dtype),
+                             preferred_element_type=jnp.float32)
+
+    _, out = jax.lax.scan(block, None, (laid, block_expert))
+    out = out.reshape(n_blocks * bm, d)[dest].reshape(n, k, d)
+
+    live = real if valid is None else real & valid[token]
+    computed = (jax.nn.one_hot(block_expert, e, dtype=jnp.int32)
+                * live.reshape(n_blocks, bm).sum(1)[:, None]).sum(0)
+    asked = k * (n if valid is None else valid.sum())
+    return out, jnp.concatenate(
+        [computed, jnp.asarray(asked, jnp.int32)[None]])
+
+
+def moe_dropless(
+    x: jax.Array,         # (B, T, D) post-norm activations
+    w_router: jax.Array,  # (D, E)
+    bias: jax.Array,      # (E,) e_score_correction_bias
+    w_gate: jax.Array,    # (E, D, F)
+    w_up: jax.Array,      # (E, D, F)
+    w_down: jax.Array,    # (E, F, D)
+    *,
+    top_k: int,
+    norm_topk: bool = True,
+    route_scale: float = 1.0,
+    valid: jax.Array = None,   # (B, T) bool: the tokens the counts count
+    layer: int = None,         # the expert weights are the stack's (L, E, ..)
+) -> Tuple[jax.Array, jax.Array]:
+    """The routed part of a DeepSeek-V3 expert layer: ``sum_i g_i
+    expert_i(x)`` over each token's k experts. Returns (out (B, T, D),
+    counts (E + 1,) int32: ``grouped_swiglu``, which also says what
+    ``layer`` is for)."""
+    b, t, d = x.shape
+    tokens = x.reshape(b * t, d)
+    chosen, gates, _ = sigmoid_routes(
+        tokens, w_router, bias, top_k=top_k, norm_topk=norm_topk,
+        route_scale=route_scale)
+    out, counts = grouped_swiglu(
+        tokens, chosen, w_gate, w_up, w_down,
+        None if valid is None else valid.reshape(b * t), layer)
+    out = jnp.einsum("nkd,nk->nd", out, gates)
+    return out.astype(x.dtype).reshape(b, t, d), counts

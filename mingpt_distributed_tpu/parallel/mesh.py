@@ -186,6 +186,17 @@ PARAM_RULES: dict[str, P] = {
     "w_e1": P("pp", "ep", "fsdp", "tp"),
     "w_e2": P("pp", "ep", "tp", "fsdp"),
     "w_eg": P("pp", "ep", "fsdp", "tp"),
+    "e_bias": P("pp"),
+    # the shared expert every token takes: a dense SwiGLU MLP
+    "w_sg": P("pp", "fsdp", "tp"),
+    "w_su": P("pp", "fsdp", "tp"),
+    "w_sd": P("pp", "tp", "fsdp"),
+    # latent attention: the down-projection's outputs (one latent and one
+    # rope key a token) belong to no head and stay whole; the
+    # up-projection's columns are the heads'
+    "w_kv_a": P("pp", "fsdp"),
+    "kv_norm_scale": P("pp"),
+    "w_kv_b": P("pp", "fsdp", "tp"),
 }
 
 

@@ -87,7 +87,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mingpt_distributed_tpu.config import GPTConfig
+from mingpt_distributed_tpu.config import ConfigError, GPTConfig
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.serving import quant as quant_lib
@@ -128,6 +128,21 @@ def kv_pool_spec(tp_axis: str = "tp"):
     return jax.sharding.PartitionSpec(None, None, None, tp_axis)
 
 
+def _kv_leaves(cache):
+    """Names of the leaves that hold cached rows, sorted: every leaf but
+    the routed-rows counter (``generate.MOE_ROWS``), which rides in a
+    routed model's pool and is no (L, S, block, heads, size) buffer."""
+    return sorted(n for n in cache if n != gen.MOE_ROWS)
+
+
+def _with_counter(kv, cache):
+    """``kv`` (leaves of cached rows) with the counter of ``cache``, if it
+    has one: what a program hands back for a cache it was handed."""
+    if gen.MOE_ROWS in cache:
+        return {**kv, gen.MOE_ROWS: cache[gen.MOE_ROWS]}
+    return kv
+
+
 def _pin_kv(cache, kv_sharding):
     """``with_sharding_constraint`` over a cache (or prefix entry)
     pytree — ``{"k","v"}``, plus the ``*_scale`` planes of a quantized
@@ -139,10 +154,10 @@ def _pin_kv(cache, kv_sharding):
     (single-device engine) is the identity."""
     if kv_sharding is None:
         return cache
-    return {
+    return _with_counter({
         name: jax.lax.with_sharding_constraint(cache[name], kv_sharding)
-        for name in sorted(cache)
-    }
+        for name in _kv_leaves(cache)
+    }, cache)
 
 
 def bucket_ladder(
@@ -215,7 +230,7 @@ def _slot_lane(cache, slot):
     """The (L, 1, S, KV, hd) cache lane of one slot (scale planes, when
     present, slice the same way with their collapsed trailing axis)."""
     out = {}
-    for name in sorted(cache):
+    for name in _kv_leaves(cache):
         l, _, s, kv, last = cache[name].shape
         out[name] = jax.lax.dynamic_slice(
             cache[name], (0, slot, 0, 0, 0), (l, 1, s, kv, last))
@@ -227,7 +242,7 @@ def _install_lane(cache, lane, slot):
     return {
         name: jax.lax.dynamic_update_slice(
             cache[name], lane[name], (0, slot, 0, 0, 0))
-        for name in sorted(cache)
+        for name in _kv_leaves(cache)
     }
 
 
@@ -280,6 +295,22 @@ def request_seeds(seeds) -> np.ndarray:
     return np.asarray(seeds).astype(np.uint32, copy=False)
 
 
+def _forward_slot_lane(params, cache, tokens, offset, slot, *, cfg,
+                       kv_quant, valid=None):
+    """Forward ``tokens`` (T,) at absolute position ``offset`` against one
+    slot's lane (dequantized for the forward and requantized whole after,
+    on a quantized pool) and write the lane back. Returns (hidden states
+    (1, T, D), the pool's cache). A routed model's counter rides through
+    the forward beside the lane."""
+    lane = _with_counter(
+        _dequant_lane(_slot_lane(cache, slot), kv_quant, cfg), cache)
+    x, lane = gen._forward_cached_hidden(
+        params, tokens[None], lane, offset, cfg, valid)
+    cache = _with_counter(cache, lane)
+    lane = _requant_lane(lane, kv_quant)
+    return x, _with_counter(_install_lane(cache, lane, slot), cache)
+
+
 def _prefill_impl(
     params, cache, chunk, length, offset, slot,
     temp, top_k, top_p, do_sample, seed,
@@ -295,16 +326,18 @@ def _prefill_impl(
     engine (``kv_quant``) dequantizes the lane before the forward and
     requantizes the whole lane after — both inside this traced program,
     so the dtype rides the compile key and no collective is added."""
-    lane = _dequant_lane(_slot_lane(cache, slot), kv_quant, cfg)
-    x, lane = gen._forward_cached_hidden(params, chunk[None], lane, offset, cfg)
-    lane = _requant_lane(lane, kv_quant)
+    # a routed model's counter counts the chunk's real tokens, not its
+    # padding
+    x, cache = _forward_slot_lane(
+        params, cache, chunk, offset, slot, cfg=cfg, kv_quant=kv_quant,
+        valid=(jnp.arange(chunk.shape[0]) < length)[None])
     h_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
     logits = gen._head_logits(params, h_last, cfg)[:, 0]  # (1, V)
     tok = _select_next_slots(
         logits, lane_keys(seed[None]), temp[None], top_k[None], top_p[None],
         do_sample[None],
     )[0]
-    return tok, _pin_kv(_install_lane(cache, lane, slot), kv_sharding)
+    return tok, _pin_kv(cache, kv_sharding)
 
 
 def _decode_impl(
@@ -329,10 +362,13 @@ def _decode_impl(
     A quantized pool is dequantized, stepped and requantized whole
     (idempotent on the rows the step did not touch: serving/quant.py)."""
     safe_pos = jnp.clip(positions, 0, cfg.block_size - 1)
-    logits, cache = gen._forward_cached(
-        params, tokens[:, None], _dequant_lane(cache, kv_quant, cfg),
-        safe_pos, cfg)
-    cache = _requant_lane(cache, kv_quant)
+    # a lane parked at the window's last row is no request: computed like
+    # any lane, and left out of a routed model's counts
+    logits, stepped = gen._forward_cached(
+        params, tokens[:, None],
+        _with_counter(_dequant_lane(cache, kv_quant, cfg), cache),
+        safe_pos, cfg, valid=(positions < cfg.block_size - 1)[:, None])
+    cache = _with_counter(_requant_lane(stepped, kv_quant), stepped)
     nxt = _select_next_slots(logits, lane_keys(seeds, token_index),
                              temps, top_ks, top_ps, do_sample)
     return nxt, _pin_kv(cache, kv_sharding)
@@ -345,7 +381,7 @@ def _extract_prefix_impl(cache, slot, *, rows: int, kv_sharding=None):
     pool's head-sharding (same spec, smaller row count), so storing a
     prefix never gathers K/V to one chip."""
     out = {}
-    for name in sorted(cache):
+    for name in _kv_leaves(cache):
         l, _, _, kv, last = cache[name].shape
         out[name] = jax.lax.dynamic_slice(
             cache[name], (0, slot, 0, 0, 0), (l, 1, rows, kv, last))
@@ -359,12 +395,12 @@ def _install_prefix_impl(cache, entry, slot, *, kv_sharding=None):
     and pool carry the same head-sharding, so a hit is a chip-local row
     copy. For the fp32 ``{"k","v"}`` entry this flattens to the identical
     two-leaf program as before the quantization layer existed."""
-    return _pin_kv({
+    return _pin_kv(_with_counter({
         name: jax.lax.dynamic_update_slice(
             cache[name], entry[name].astype(cache[name].dtype),
             (0, slot, 0, 0, 0))
-        for name in sorted(cache)
-    }, kv_sharding)
+        for name in _kv_leaves(cache)
+    }, cache), kv_sharding)
 
 
 class DecodeEngine:
@@ -408,6 +444,14 @@ class DecodeEngine:
             raise ValueError(
                 "cache_dtype and kv_dtype are mutually exclusive — a "
                 "quantized pool's storage dtype comes from kv_dtype")
+        tp = 1 if mesh is None else int(mesh.shape.get(tp_axis, 1))
+        if cfg.kv_lora_rank and (self.kv_quant is not None or tp > 1):
+            raise ConfigError(
+                "a latent (MLA) cache is served unquantized on one chip's "
+                "engine: its one latent a token has no heads to split over "
+                f"tp={tp}, and kv_dtype={self.kv_dtype!r} would scale the "
+                "rope key and the latent, which differ in size and range, "
+                "by one rule made for per-head rows")
         if mesh is not None:
             # One placement decision, made once: params follow the megatron
             # column/row rules, the pool shards heads over the tp axis (or
@@ -415,10 +459,9 @@ class DecodeEngine:
             # by shard_by_rule's telemetry, never an error).
             params = jax.device_put(
                 params, mesh_lib.param_shardings(mesh, params))
-            cache_shape = (cfg.n_layer, n_slots, cfg.block_size,
-                           cfg.kv_heads, cfg.head_dim)
             self.kv_sharding = mesh_lib.shard_by_rule(
-                mesh, cache_shape, kv_pool_spec(tp_axis), name="kv_cache")
+                mesh, gen.cache_leaf_shapes(cfg, n_slots)["k"],
+                kv_pool_spec(tp_axis), name="kv_cache")
         else:
             self.kv_sharding = None
         # what was handed in (placed, under a mesh), and what the programs
@@ -487,6 +530,20 @@ class DecodeEngine:
     def program_param_bytes(self) -> int:
         """Bytes of the tree the programs read."""
         return sum(a.nbytes for a in jax.tree.leaves(self.program_params))
+
+    @property
+    def kv_bytes_per_row(self) -> int:
+        """Bytes one cached token costs in the pool, all layers and leaves
+        (a quantized pool's scale planes too)."""
+        return sum(a.nbytes // (a.shape[1] * a.shape[2])
+                   for n, a in self.pool.cache.items() if n != gen.MOE_ROWS)
+
+    def moe_rows(self) -> Optional[np.ndarray]:
+        """The routed-rows counter as it stands, fetched from the device:
+        (expert layers, E + 1), or None where the model counts none. The
+        one transfer the counter ever costs; no round makes it."""
+        counter = self.pool.cache.get(gen.MOE_ROWS)
+        return None if counter is None else np.asarray(jax.device_get(counter))
 
     @property
     def chunk_size(self) -> int:
@@ -680,6 +737,10 @@ class DecodeEngine:
                         self.pool.cache, np.int32(0), rows=b)
                     self.pool.cache = self._install_jit(
                         self.pool.cache, lane, np.int32(0))
+        if gen.MOE_ROWS in self.pool.cache:
+            # the warm-up's prompts (one token repeated) are no traffic:
+            # the routed rows are counted from here on
+            self.pool.cache[gen.MOE_ROWS] = gen.init_moe_rows(self.cfg)
 
     def decode_step(
         self,
@@ -761,7 +822,8 @@ class DecodeEngine:
             yield (family_prefix + "prefix_save", f"b{b}", self._extract_jit,
                    (self.pool.cache, np.int32(0)), {"rows": b})
             entry = {}
-            for name, arr in self.pool.cache.items():
+            for name in _kv_leaves(self.pool.cache):
+                arr = self.pool.cache[name]
                 l, _, _, kv, last = arr.shape
                 entry[name] = jax.ShapeDtypeStruct(
                     (l, 1, b, kv, last), arr.dtype)

@@ -1,8 +1,16 @@
 """Slot-based KV-cache pool + shared-prefix KV store.
 
-One fixed ``(n_layer, n_slots, block_size, kv_heads, head_dim)`` pair of
-K/V buffers — ``models/generate.init_cache`` with the batch axis
-reinterpreted as a *slot* axis. Each slot holds one in-flight request's
+One fixed pair of ``(n_layer, n_slots, block_size, heads, size)`` buffers —
+``models/generate.init_cache`` with the batch axis reinterpreted as a
+*slot* axis. ``heads`` and ``size`` are each leaf's own
+(``generate.cache_leaf_shapes``): per-head rows keep ``kv_heads`` keys and
+values of ``head_dim``; a latent (MLA) model keeps one rotated rope key
+(``"k"``) and one normed latent (``"v"``) a token, which differ in size.
+Everything here and in the engine's programs works leaf by leaf and asks
+no leaf for another's shape. A model that counts its routed rows
+(``generate.MOE_ROWS``) carries that counter in the same donated tree; it
+is no buffer of rows and the row programs pass it through.
+Each slot holds one in-flight request's
 cache; a request is admitted by prefilling its prompt into a free slot
 and retired by returning the slot to the free list. Stale K/V from a
 previous tenant never leaks into attention because masking is positional
@@ -40,7 +48,8 @@ from collections import OrderedDict
 from typing import List, Optional, Tuple
 
 from mingpt_distributed_tpu.config import GPTConfig
-from mingpt_distributed_tpu.models.generate import Cache, init_cache
+from mingpt_distributed_tpu.models.generate import (
+    MOE_ROWS, Cache, init_cache, init_moe_rows)
 from mingpt_distributed_tpu.serving import quant as quant_lib
 
 
@@ -77,6 +86,9 @@ class SlotKVPool:
             # on sharding equality — an unnormalized spec here would make
             # the first serving call on a warmed bucket look novel
             sharding = cache["k"].sharding
+        moe_rows = init_moe_rows(cfg)
+        if moe_rows is not None:
+            cache[MOE_ROWS] = moe_rows
         self.sharding = sharding
         self.cache = cache
         self._free: List[int] = list(range(n_slots))  # kept sorted
@@ -95,13 +107,17 @@ class SlotKVPool:
     def audit_facts(self) -> dict:
         """Static facts graftaudit checks pool-touching programs against
         (plain dict so serving never imports the analysis layer):
-        ``cache_leaf_elems`` is the element count of one K/V buffer — any
-        collective whose result is at least that large is moving the pool
-        itself, not a per-token activation; ``cache_sharding`` is the
+        ``cache_leaf_shapes`` is each row buffer's shape, by name;
+        ``cache_leaf_elems`` the element count of the smallest of them —
+        any collective whose result is at least that large is moving the
+        pool itself, not a per-token activation; ``cache_sharding`` is the
         runtime-normalized NamedSharding every compiled program must
         return the cache under (None on a single device)."""
+        shapes = {n: tuple(a.shape) for n, a in self.cache.items()
+                  if n != MOE_ROWS}
         return {
-            "cache_leaf_elems": math.prod(tuple(self.cache["k"].shape)),
+            "cache_leaf_shapes": shapes,
+            "cache_leaf_elems": min(map(math.prod, shapes.values())),
             "cache_sharding": self.sharding,
             "shard_count": self.shard_count,
         }
@@ -138,7 +154,7 @@ class PrefixKVStore:
     tokens themselves, so a hit can never alias two different prefixes);
     values are device-array lane dicts (``{"k", "v"}``, plus
     ``{"k_scale", "v_scale"}`` planes when the pool is quantized) of
-    shape (L, 1, P, KV, hd) with P = len(key). ``capacity_bytes`` bounds
+    shape (L, 1, P, heads, size), each leaf's own, with P = len(key). ``capacity_bytes`` bounds
     the sum of entry sizes across every leaf — a quantized store fits
     ~4x the prefixes in the same budget, which is the ISSUE 18 point;
     inserting past it evicts least-recently-used entries first. An entry

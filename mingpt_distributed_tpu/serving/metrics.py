@@ -191,6 +191,11 @@ class ServingMetrics:
             "mingpt_serve_program_weights_cast",
             help="leaves of that tree cast to the compute dtype at "
                  "construction (0: the programs read the tree handed in)")
+        self._kv_bytes_per_row = r.gauge(
+            "mingpt_serve_kv_bytes_per_row",
+            help="bytes one cached token costs in the pool, all layers")
+        # a routed model's device-side counter, read only by summary()
+        self._moe_rows_source: Optional[Callable[[], Any]] = None
         self._util_sum = 0.0
         self._prefill_rate = RateWindow()
         self._prefill_tokens_per_sec: Optional[float] = None
@@ -463,15 +468,44 @@ class ServingMetrics:
         return " | ".join(parts)
 
     def engine_built(self, program_weight_bytes: int,
-                     program_weights_cast: int) -> None:
-        """What the engine's programs read, known once it is built."""
+                     program_weights_cast: int, kv_bytes_per_row: int = 0,
+                     moe_rows_source: Optional[Callable[[], Any]] = None,
+                     ) -> None:
+        """What the engine's programs read and what a cached token costs,
+        known once it is built. ``moe_rows_source`` fetches a routed
+        model's (expert layers, E + 1) counter of routed rows from the
+        device (``DecodeEngine.moe_rows``); only ``summary()`` calls it."""
         self._program_weight_bytes.set(program_weight_bytes)
         self._program_weights_cast.set(program_weights_cast)
+        self._kv_bytes_per_row.set(kv_bytes_per_row)
+        self._moe_rows_source = moe_rows_source
+
+    def _moe_summary(self) -> Dict[str, Any]:
+        """The routed-rows counter since the server was built: rows the
+        experts computed, routes asked for and not computed (0: the route
+        drops nothing, and this is where it would show), and the busiest
+        expert's rows over the mean, the worst layer's. None where the
+        model routes nothing this way."""
+        rows = self._moe_rows_source() if self._moe_rows_source else None
+        if rows is None:
+            return {"moe_routed_rows": None, "moe_dropped_rows": None,
+                    "moe_load_max_over_mean": None}
+        computed, asked = rows[:, :-1], rows[:, -1]
+        mean = computed.mean(axis=1)
+        return {
+            "moe_routed_rows": int(computed.sum()),
+            "moe_dropped_rows": int(asked.sum() - computed.sum()),
+            "moe_load_max_over_mean": float(
+                (computed.max(axis=1) / mean)[mean > 0].max())
+            if (mean > 0).any() else None,
+        }
 
     def summary(self) -> Dict[str, Any]:
         return {
             "program_weight_bytes": int(self._program_weight_bytes.value),
             "program_weights_cast": int(self._program_weights_cast.value),
+            "kv_bytes_per_row": int(self._kv_bytes_per_row.value),
+            **self._moe_summary(),
             "requests_submitted": self.requests_submitted,
             "requests_completed": self.requests_completed,
             "requests_rejected": self.requests_rejected,

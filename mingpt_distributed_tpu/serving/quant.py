@@ -173,13 +173,12 @@ def split_scales(
 def init_quant_cache(cfg, n_slots: int, q: KVQuant) -> Dict[str, jax.Array]:
     """The quantized analogue of ``generate.init_cache``: zeroed payload
     buffers in ``q.qdtype`` plus zeroed fp32 scale planes."""
-    shape = (cfg.n_layer, n_slots, cfg.block_size, cfg.kv_heads,
-             cfg.head_dim)
-    sshape = shape[:-1] + (1,)
+    from mingpt_distributed_tpu.models.generate import cache_leaf_shapes
+
     out: Dict[str, jax.Array] = {}
-    for n in DATA_NAMES:
+    for n, shape in cache_leaf_shapes(cfg, n_slots).items():
         out[n] = jnp.zeros(shape, q.qdtype)
-        out[n + SCALE_SUFFIX] = jnp.zeros(sshape, SCALE_DTYPE)
+        out[n + SCALE_SUFFIX] = jnp.zeros(shape[:-1] + (1,), SCALE_DTYPE)
     return out
 
 

@@ -315,7 +315,8 @@ class InferenceServer:
         self.metrics = metrics or ServingMetrics(
             n_slots, log_every=log_every, registry=registry)
         self.metrics.engine_built(
-            self.engine.program_param_bytes, self.engine.n_cast_leaves)
+            self.engine.program_param_bytes, self.engine.n_cast_leaves,
+            self.engine.kv_bytes_per_row, self.engine.moe_rows)
         # post-warmup recompile watchdog over the compiled program families
         # (the merged server-level counts, so draft/verify traces are
         # watched too; armed after warmup(); checked every round)

@@ -47,12 +47,9 @@ from ..config import GPTConfig
 from ..models import generate as gen
 from .engine import (
     DecodeEngine,
-    _dequant_lane,
-    _install_lane,
+    _forward_slot_lane,
     _pin_kv,
-    _requant_lane,
     _select_next_slots,
-    _slot_lane,
     bind_static,
     lane_keys,
     request_seeds,
@@ -77,9 +74,8 @@ def _verify_impl(
     pool dequantizes the lane before the forward and requantizes the
     whole lane on the way back in, same as the prefill/decode bodies."""
     rows = tokens.shape[0]
-    lane = _dequant_lane(_slot_lane(cache, slot), kv_quant, cfg)
-    x, lane = gen._forward_cached_hidden(params, tokens[None], lane, offset, cfg)
-    lane = _requant_lane(lane, kv_quant)
+    x, cache = _forward_slot_lane(
+        params, cache, tokens, offset, slot, cfg=cfg, kv_quant=kv_quant)
     logits = gen._head_logits(params, x, cfg)[0]  # (rows, V) fp32
     keys = jax.random.split(
         lane_keys(seed[None], token_index[None])[0], rows)
@@ -90,7 +86,7 @@ def _verify_impl(
         jnp.full((rows,), top_p, jnp.float32),
         jnp.zeros((rows,), bool),
     )
-    return nxt, _pin_kv(_install_lane(cache, lane, slot), kv_sharding)
+    return nxt, _pin_kv(cache, kv_sharding)
 
 
 class DraftEngine:

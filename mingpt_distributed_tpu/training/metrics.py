@@ -40,7 +40,7 @@ def flops_per_token(cfg: GPTConfig, seq_len: Optional[int] = None) -> float:
     (the 6ND model with the quadratic-attention correction)."""
     t = seq_len or cfg.block_size
     d, l, v = cfg.n_embd, cfg.n_layer, cfg.vocab_size
-    ffn = int(cfg.ffn_mult * d)
+    ffn = cfg.dense_width
     kv = cfg.kv_heads * cfg.head_dim
     per_layer = d * (d + 2 * kv) + d * d  # qkv + out proj
     per_layer += (3 if cfg.swiglu else 2) * d * ffn

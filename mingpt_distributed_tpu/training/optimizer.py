@@ -36,14 +36,17 @@ from mingpt_distributed_tpu.utils.pytree import leaf_name
 #   no-decay: every bias, every norm scale/bias, token + positional embeddings
 _DECAY_NAMES = frozenset(
     {"wq", "wk", "wv", "wo", "w_fc", "w_proj", "w_gate", "w_up", "w_down",
-     "head", "w_router", "w_e1", "w_e2", "w_eg"}  # MoE router/experts are matmuls
+     "head", "w_router", "w_e1", "w_e2", "w_eg",  # MoE router/experts are matmuls
+     "w_sg", "w_su", "w_sd",  # the shared expert
+     "w_kv_a", "w_kv_b"}  # latent attention's down- and up-projections
 )
 _NO_DECAY_NAMES = frozenset(
     {
         "wte", "wpe",  # embeddings (reference: Embedding + pos_embedding no-decay)
         "bq", "bk", "bv", "bo", "b_fc", "b_proj",  # biases
         "ln1_scale", "ln1_bias", "ln2_scale", "ln2_bias",
-        "lnf_scale", "lnf_bias",
+        "lnf_scale", "lnf_bias", "kv_norm_scale",
+        "e_bias",  # the router's choice bias: a bias
     }
 )
 

@@ -26,7 +26,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from mingpt_distributed_tpu.config import GPTConfig, MeshConfig
+from mingpt_distributed_tpu.config import ConfigError, GPTConfig, MeshConfig
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
@@ -47,6 +47,15 @@ ARCHS = {
     "rope-experts": dict(rope=True, swiglu=True, rmsnorm=True, ffn_mult=0.5,
                          n_experts=8, moe_top_k=2, moe_capacity_factor=4.0,
                          tie_weights=False),
+    # benchmarks/configs/kanana-2-30b-a3b.json, cut as its "tiny" is: a
+    # latent cache, a dense layer before dropless experts and a shared one
+    "latent-experts": dict(rope=True, rope_interleave=True, swiglu=True,
+                           rmsnorm=True, tie_weights=False, kv_lora_rank=16,
+                           qk_nope_head_dim=8, qk_rope_head_dim=4,
+                           v_head_dim=8, n_dense_layers=1, ffn_dim=48,
+                           n_experts=8, moe_top_k=2, moe_ffn_dim=16,
+                           n_shared_experts=2, moe_scoring="sigmoid",
+                           moe_route_scale=2.448),
 }
 
 
@@ -123,6 +132,10 @@ def test_programs_read_a_tree_cast_once(arch, dtype, tp):
     if tp > 1:
         mesh = mesh_lib.make_mesh(MeshConfig(tp=tp),
                                   devices=jax.devices()[:tp])
+        if cfg.kv_lora_rank:
+            with pytest.raises(ConfigError, match="latent"):
+                DecodeEngine(params, cfg, n_slots=SLOTS, mesh=mesh)
+            return
     engine = DecodeEngine(params, cfg, n_slots=SLOTS, prefill_len=16,
                           prefill_buckets=[16], mesh=mesh)
     handed, held = leaves_by_path(params), leaves_by_path(engine.params)
@@ -181,9 +194,10 @@ def weight_converts(text, params):
     to bfloat16 and whose operand has the shape of a leaf of ``params`` that
     ``cast_once_params`` casts: the whole leaf, or one layer of it."""
     shapes = {params["head"].shape} if "head" in params else set()
-    for name, a in params["blocks"].items():
-        if name in gen._CAST_ONLY_BLOCK_LEAVES:
-            shapes |= {a.shape, a.shape[1:], (1,) + a.shape[1:]}
+    for stack in ("blocks", "dense_blocks"):
+        for name, a in params.get(stack, {}).items():
+            if name in gen._CAST_ONLY_BLOCK_LEAVES:
+                shapes |= {a.shape, a.shape[1:], (1,) + a.shape[1:]}
     # the text names an operand without its shape: take it from the line
     # that defines the operand
     defined = {m.group(1): (m.group(2), m.group(3)) for m in re.finditer(
@@ -219,7 +233,8 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("arch", ["gpt2-untied", "rope-experts"])
+@pytest.mark.parametrize("arch", ["gpt2-untied", "rope-experts",
+                                  "latent-experts"])
 def test_decode_program_converts_no_weight(arch, one_chip):
     cfg, params = model(arch, "bfloat16")
     engine = DecodeEngine(params, cfg, n_slots=SLOTS)

@@ -1,0 +1,178 @@
+"""The benchmark's cell ``kanana-2-30b-a3b.serve-long-decode`` at a tiny size
+on the CPU, through the path the driver runs: ``rehearse.tiny`` +
+``serve_cell.Driver`` + ``check.serve_verdict`` with the configuration's own
+reference, in bfloat16 with the routes followed (as
+``benchmarks/tests/test_arch.py`` does for the fixture), and the new
+per-layer readers on the run's evidence."""
+
+import dataclasses
+import json
+import types
+
+import jax
+import numpy as np
+import pytest
+
+from benchmarks import rehearse
+from benchmarks.harness import check, compiles, serve_cell, spec
+
+CELL = "kanana-2-30b-a3b.serve-long-decode"
+SEED = 2_500_000_001        # past 32 signed bits, as the driver's seeds are
+NEW_READERS = ("moe.load_max_over_mean", "moe.dropped_rows",
+               "moe.rows_per_expert_round", "kv.bytes_per_live_token")
+
+
+def tiny_cell(**sizes) -> spec.Cell:
+    return rehearse.tiny(spec.load_cell(CELL), sizes=sizes or None)
+
+
+def test_the_configuration_holds_the_published_widths():
+    cell = spec.load_cell(CELL)
+    config = cell.config
+    assert config["reduced"] == ["num_hidden_layers", "max_position_embeddings"]
+    assert (config["hidden_size"], config["kv_lora_rank"],
+            config["intermediate_size"], config["moe_intermediate_size"],
+            config["n_routed_experts"], config["num_experts_per_tok"],
+            config["vocab_size"]) == (2048, 512, 6144, 768, 128, 6, 128256)
+    cfg = spec.gpt_config(cell, training=False)
+    assert cfg.param_dtype == cfg.dtype == "bfloat16"
+    assert spec.server_options(cell) == {
+        "prefill_len": 4096, "prefill_buckets": [1024, 2048, 4096],
+        "n_slots": cell.found["server"]["n_slots"]}
+    wrong = dataclasses.replace(cell, config=dict(config, kv_lora_rank=256))
+    with pytest.raises(spec.SpecError, match="kv_lora_rank"):
+        spec.gpt_config(wrong, training=False)
+
+
+def test_tiny_shrinks_every_size_the_reference_reads():
+    cell = tiny_cell()
+    cfg = spec.gpt_config(cell, training=False)
+    assert (cfg.n_layer, cfg.n_dense_layers, cfg.kv_lora_rank,
+            cfg.qk_head_dim, cfg.dense_width, cfg.expert_width,
+            cfg.n_experts, cfg.moe_top_k) == (2, 1, 32, 24, 192, 48, 8, 2)
+    config = cell.config
+    assert (config["kv_lora_rank"], config["qk_rope_head_dim"],
+            config["head_dim"], config["n_routed_experts"],
+            config["intermediate_size"]) == (32, 8, 8, 8, 192)
+
+
+@pytest.fixture(scope="module")
+def cell_run():
+    return serve_cell.run(
+        tiny_cell(n_layer=3), seed=SEED, seconds=1.0, traced=False,
+        devices=jax.devices()[:1], t_process=0.0,
+        compiles=compiles.CompileCounter())
+
+
+def test_the_cell_agrees_with_its_reference_through_the_whole_path(cell_run):
+    """bfloat16 weights and activations, three layers (the dense one and two
+    that route), the engine's own prefill and decode programs: every layer's
+    rotated rope keys and normed latents inside the dense law once the
+    reference has followed the program's routes, no program compiled in the
+    window."""
+    verdict = cell_run["verdict"]
+    assert verdict["ok"], verdict
+    assert verdict["compiled_in_window"] == 0
+    assert len(verdict["cases"]) == 3
+    for case in verdict["cases"]:
+        assert len(case["k_rel_layers"]) == len(case["v_rel_layers"]) == 3
+        assert max(case["k_rel_layers"] + case["v_rel_layers"]) \
+            <= verdict["kv_rel_tol"]
+        assert case["route_banded_layers"][0] == 0      # the dense layer
+    assert cell_run["failed"] == 0 and cell_run["attempted"] > 0
+
+
+def test_the_counters_reach_the_readers(cell_run):
+    play = cell_run["evidence"]["play"]
+    closed = play.close_counters
+    assert closed["moe_dropped_rows"] == 0
+    assert closed["moe_routed_rows"] > play.open_counters["moe_routed_rows"]
+    assert closed["kv_bytes_per_row"] == 3 * (8 + 32) * 2
+    assert closed["program_weights_cast"] == 0
+    # untraced: the readers find nothing and say so
+    for name in NEW_READERS:
+        assert spec.load_reader(name).read(cell_run["evidence"]) is None
+    # a traced window's two readings
+    play.trace_open, play.trace_close = play.open_counters, closed
+    play.trace_rounds, play.trace_live_rows = 10, 400
+    values = {name: spec.load_reader(name).read(cell_run["evidence"])
+              for name in NEW_READERS}
+    assert values["moe.dropped_rows"] == 0
+    assert values["moe.load_max_over_mean"] >= 1.0
+    assert values["moe.rows_per_expert_round"] > 0
+    assert values["kv.bytes_per_live_token"] == pytest.approx(
+        240 * play.n_slots * play.block_size / 40)
+
+
+def test_the_new_readers_return_none_for_a_dense_cell():
+    """On a cell of the parent's (or the parent itself, whose summary lacks
+    the fields) there is nothing to read, and no reader raises."""
+    cell = rehearse.tiny(spec.load_cell("gpt2-124m.serve-decode"))
+    driver = serve_cell.Driver(cell, SEED, traced=False)
+    reading = driver._counters()
+    play = serve_cell.Play(n_slots=4, block_size=128, trace_rounds=3,
+                           trace_live_rows=30, trace_open=reading,
+                           trace_close=reading)
+    evidence = {"play": play, "cell": cell}
+    for name in NEW_READERS[:3]:
+        assert spec.load_reader(name).read(evidence) is None
+    stripped = {k: v for k, v in reading.items()
+                if not k.startswith(("moe_", "kv_bytes"))}
+    play.trace_open = play.trace_close = stripped
+    for name in NEW_READERS:
+        assert spec.load_reader(name).read(evidence) is None
+
+
+def test_a_lower_precision_fails_the_verdict():
+    """The nearest precision below the one the configuration states: the
+    reference's own cached rows rounded to 8-bit floats before they are
+    compared (what an fp8 pool would hold) lie outside the law."""
+    cell = tiny_cell(n_layer=3)
+    reference = spec.load_reference(cell.config)
+    driver = serve_cell.Driver(cell, SEED, traced=False)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 384, size=n, dtype=np.int32) for n in (24, 40)]
+    good = check.serve_verdict(reference, cell.config, driver.server,
+                               prompts, 4)
+    assert good["ok"], good
+    pool = driver.server.engine.pool
+    fp8 = jax.numpy.float8_e4m3fn
+
+    class Rounding:
+        """The engine, but every program's rows pass through 8 bits."""
+        def __init__(self, engine):
+            self._engine = engine
+
+        def __getattr__(self, name):
+            return getattr(self._engine, name)
+
+        def _round(self):
+            pool.cache = {
+                n: a if a.ndim != 5 else a.astype(fp8).astype(a.dtype)
+                for n, a in pool.cache.items()}
+
+        def prefill_chunk_call(self, *args):
+            out = self._engine.prefill_chunk_call(*args)
+            self._round()
+            return out
+
+        def decode_step(self, *args):
+            out = self._engine.decode_step(*args)
+            self._round()
+            return out
+
+    bad = check.serve_verdict(
+        reference, cell.config,
+        types.SimpleNamespace(engine=Rounding(driver.server.engine)),
+        prompts, 4)
+    assert bad["ok"] is False
+    worst = max(max(c["k_rel"], c["v_rel"]) for c in bad["cases"])
+    assert worst > 2 * bad["kv_rel_tol"]
+
+
+def test_rehearse_runs_the_cell_and_prints_its_routes(capsys):
+    rehearse.rehearse_run(spec.load_cell(CELL))
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["agrees_with_reference"] is True and line["failed"] == 0
+    assert line["compiled_in_window"] == 0
+    assert {line["readers"][name] for name in NEW_READERS} == {"read"}

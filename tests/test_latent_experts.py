@@ -1,0 +1,536 @@
+"""kanana-2-30b-a3b's architecture (DeepSeek-V3's: a latent cache, a dense
+layer before sigmoid-routed dropless experts with a shared one) at a tiny
+size on the CPU, seeded weights: the program against the plain reference
+``benchmarks/references/deepseek_v3.py`` (logits, loss, cached rows), the
+absorbed cached forward against the published form, the route's contract,
+and the latent pool through the serving paths that move rows about."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.harness import spec
+from mingpt_distributed_tpu.config import ConfigError, GPTConfig, MeshConfig
+from mingpt_distributed_tpu.models import generate as gen
+from mingpt_distributed_tpu.models import gpt
+from mingpt_distributed_tpu.ops import attention as attn_ops
+from mingpt_distributed_tpu.ops import moe
+from mingpt_distributed_tpu.parallel import mesh as mesh_lib
+from mingpt_distributed_tpu.serving import InferenceServer, Request
+from mingpt_distributed_tpu.serving.engine import DecodeEngine
+
+VOCAB, BLOCK = 211, 64
+TINY = dict(
+    n_layer=3, n_head=4, n_embd=64, vocab_size=VOCAB, block_size=BLOCK,
+    embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, attention="einsum",
+    rope=True, rope_theta=500.0, rope_interleave=True, rmsnorm=True,
+    swiglu=True, norm_eps=1e-6, tie_weights=False,
+    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    n_dense_layers=1, ffn_dim=96, n_experts=8, moe_top_k=3, moe_ffn_dim=24,
+    n_shared_experts=2, moe_scoring="sigmoid", moe_route_scale=2.448)
+#: the published keys the reference reads, as the tiny program has them
+SIZES = dict(
+    num_attention_heads=4, kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, rope_theta=500.0,
+    rope_interleave=True, rms_norm_eps=1e-6, first_k_dense_replace=1,
+    num_experts_per_tok=3, norm_topk_prob=True, routed_scaling_factor=2.448,
+    scoring_func="sigmoid", q_lora_rank=None, n_group=1, topk_group=1)
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return spec.load_reference({"reference": "references/deepseek_v3.py"})
+
+
+def model(dtype="float32", param_dtype="float32", **over):
+    """Config and parameters with the norms' scales and the bias off their
+    initial values, where a factor left out would not show."""
+    cfg = GPTConfig.make(**{**TINY, "dtype": dtype, "param_dtype": param_dtype,
+                            **over})
+    params = gpt.init(jax.random.key(0), cfg)
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.key(2), len(leaves))
+    leaves = [(a + 0.01 * jax.random.normal(k, a.shape)).astype(a.dtype)
+              for a, k in zip(leaves, keys)]
+    return cfg, jax.tree.unflatten(tree, leaves)
+
+
+def tokens_of(n, batch=2, seed=3):
+    return jax.random.randint(jax.random.key(seed), (batch, n), 0, VOCAB)
+
+
+# -- the reference and the uncached forward ----------------------------------
+
+@pytest.mark.parametrize("told", [False, True],
+                         ids=["own-choice", "told-its-own-choice"])
+def test_the_reference_is_the_program_s_forward_in_float32(reference, told):
+    """Two implementations of one set of equations, float32 on both sides:
+    logits to 2e-5 and the loss to 1e-5 relative (sums in another order:
+    the reference's experts run masked in blocks, the program's grouped).
+    The routed contract: what the layer takes its k best of comes back
+    (score + bias for an expert layer, a row with no near-tie for the dense
+    one), and a table that holds the reference's own choice changes
+    nothing."""
+    cfg, params = model()
+    tokens = tokens_of(40)
+    targets = jnp.where(jnp.arange(40) % 5 == 0, -1, jnp.roll(tokens, -1, 1))
+    want_logits, want_loss = gpt.forward(params, tokens, cfg, targets=targets)
+    weights = reference.weights_from_program(params)
+    x, ks, vs, router = reference.hidden(weights, tokens, SIZES)
+    assert router.shape == (3, 2, 40, 8) and router.dtype == np.float32
+    dense_row = np.asarray(router[0, 0, 0])
+    assert dense_row.tolist() == [1, 1, 1, -1, -1, -1, -1, -1]
+    if told:
+        own = np.argsort(-np.asarray(router), -1, kind="stable")[..., :3]
+        x, ks, vs, again = reference.hidden(
+            weights, tokens, SIZES, experts=own[..., ::-1].astype(np.int32))
+        np.testing.assert_allclose(again, router, atol=1e-6)
+    np.testing.assert_allclose(reference.logits(weights, x), want_logits,
+                               atol=2e-5)
+    assert ks.shape == (3, 2, 40, 1, 8) and vs.shape == (3, 2, 40, 1, 32)
+    np.testing.assert_allclose(
+        reference.loss(weights, tokens, targets, SIZES), want_loss, rtol=1e-5)
+
+
+def test_the_reference_refuses_what_it_does_not_write(reference):
+    cfg, params = model()
+    weights = reference.weights_from_program(params)
+    for key, value in (("q_lora_rank", 16), ("n_group", 2),
+                       ("scoring_func", "softmax")):
+        with pytest.raises(ValueError):
+            reference.hidden(weights, tokens_of(8), {**SIZES, key: value})
+
+
+@pytest.mark.parametrize("what", ["dense_blocks.w_down", "blocks.w_sd",
+                                  "blocks.w_e2", "blocks.e_bias"])
+def test_every_part_of_the_mlp_is_in(what):
+    """Zeroing the dense first layer's MLP, the shared expert, the routed
+    experts or the bias (which moves the choice) changes the output."""
+    cfg, params = model()
+    tokens = tokens_of(24)
+    base, _ = gpt.forward(params, tokens, cfg)
+    stack, leaf = what.split(".")
+    without = {**params, stack: {**params[stack], leaf: jnp.zeros_like(
+        params[stack][leaf])}}
+    got, _ = gpt.forward(without, tokens, cfg)
+    assert float(jnp.abs(got - base).max()) > 1e-4
+
+
+# -- rope --------------------------------------------------------------------
+
+def test_interleaved_rope_turns_neighbours_and_half_split_does_not():
+    x = jax.random.normal(jax.random.key(0), (2, 5, 3, 8))
+    cos, sin = attn_ops.rope_tables(jnp.arange(5), 8, 500.0)
+    got = np.asarray(attn_ops.apply_rope(x, cos, sin, interleave=True))
+    pairs = np.asarray(x).reshape(2, 5, 3, 4, 2)
+    turned = (pairs[..., 0] + 1j * pairs[..., 1]) * np.asarray(
+        cos + 1j * sin)[None, :, None, :]
+    want = np.stack([turned.real, turned.imag], -1).reshape(2, 5, 3, 8)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    half = np.asarray(attn_ops.apply_rope(x, cos, sin))
+    assert np.abs(half - want).max() > 0.1
+    # position 0 is the identity either way
+    np.testing.assert_allclose(got[:, 0], np.asarray(x)[:, 0], atol=1e-7)
+
+
+def test_a_half_split_rope_disagrees_with_the_reference(reference):
+    cfg, params = model(rope_interleave=False)
+    tokens = tokens_of(24)
+    logits, _ = gpt.forward(params, tokens, cfg)
+    weights = reference.weights_from_program(params)
+    ref = reference.logits(weights, reference.hidden(weights, tokens, SIZES)[0])
+    assert float(jnp.abs(ref - logits).max()) > 1e-3
+    same = reference.logits(weights, reference.hidden(
+        weights, tokens, {**SIZES, "rope_interleave": False})[0])
+    np.testing.assert_allclose(same, logits, atol=2e-5)
+
+
+# -- the cached forward: absorbed, against the published form -----------------
+
+def cached_logits(cfg, params, seq, n_prompt):
+    """Prefill ``seq[:n_prompt]`` then decode the rest a token a step at a
+    position a lane: the logits after every step, and the cache."""
+    cache = gen.init_cache(cfg, 2)
+    step = jax.jit(lambda c, t, o: gen._forward_cached(params, t, c, o, cfg))
+    logits, cache = step(cache, seq[:, :n_prompt], 0)
+    out = [logits]
+    for t in range(n_prompt, seq.shape[1]):
+        logits, cache = step(cache, seq[:, t:t + 1], jnp.full((2,), t))
+        out.append(logits)
+    return jnp.stack(out, 1), cache
+
+
+def test_the_absorbed_cached_forward_equals_the_published_form(reference):
+    """Prefill and decode attend the cached latents absorbed (queries through
+    W_UK, outputs through W_UV); ``gpt.forward`` and the reference take the
+    latent up to per-head keys and values. Float32 both: logits to 1e-4
+    (another order of the same sums), the cached rows (the rotated rope key
+    and the normed latent, nothing else) to 1e-5."""
+    cfg, params = model()
+    seq = tokens_of(30)
+    got, cache = cached_logits(cfg, params, seq, 20)
+    want, _ = gpt.forward(params, seq, cfg)
+    np.testing.assert_allclose(got, want[:, 19:], atol=1e-4)
+    weights = reference.weights_from_program(params)
+    x, ks, vs, _ = reference.hidden(weights, seq, SIZES)
+    np.testing.assert_allclose(reference.logits(weights, x)[:, 19:], got,
+                               atol=1e-4)
+    assert cache["k"].shape == (3, 2, BLOCK, 1, 8)
+    assert cache["v"].shape == (3, 2, BLOCK, 1, 32)
+    np.testing.assert_allclose(cache["k"][:, :, :30], ks, atol=1e-5)
+    np.testing.assert_allclose(cache["v"][:, :, :30], vs, atol=1e-5)
+
+
+def test_a_long_prefill_walks_the_slice_in_blocks(monkeypatch):
+    """Past ``LATENT_KV_BLOCK`` rows a chunk attends block by block under a
+    running softmax and stops at its own last row: the same numbers."""
+    cfg, params = model()
+    seq = tokens_of(40)
+    want, _ = cached_logits(cfg, params, seq, 36)
+    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", 16)
+    got, _ = cached_logits(cfg, params, seq, 36)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_the_engine_s_programs_agree_with_the_reference(reference):
+    """Prefill then decode through ``DecodeEngine``'s own programs against
+    the reference's full forward over the same sequence, float32: each
+    emitted token's logit within 1e-4 of the reference's best (logits, not
+    tokens: with seeded weights the best two are often a rounding apart),
+    the rows the programs left in the slot to 1e-5 relative."""
+    cfg, params = model()
+    eng = DecodeEngine(params, cfg, n_slots=3, prefill_len=32,
+                       prefill_buckets=[16, 32])
+    prompt = np.asarray(tokens_of(21, batch=1, seed=5)[0])
+    tok, _ = eng.prefill_chunk_call(1, prompt.tolist(), 0, 1.0, None, None,
+                                    False, 0)
+    seq = prompt.tolist() + [tok]
+    for _ in range(5):
+        t, p = np.zeros(3, np.int32), np.full(3, BLOCK - 1, np.int32)
+        t[1], p[1] = seq[-1], len(seq) - 1
+        nxt = eng.decode_step(t, p, np.ones(3, np.float32),
+                              np.zeros(3, np.int32), np.ones(3, np.float32),
+                              np.zeros(3, bool), np.zeros(3, np.uint32))
+        seq.append(int(nxt[1]))
+    weights = reference.weights_from_program(eng.params)
+    x, ks, vs, _ = reference.hidden(weights, np.asarray([seq[:-1]]), SIZES)
+    ref = np.asarray(reference.logits(weights, x[0, 20:]))
+    assert (ref.max(-1) - ref[np.arange(6), seq[21:]]).max() <= 1e-4
+    for name, rows in (("k", ks), ("v", vs)):
+        got = np.asarray(eng.pool.cache[name][:, 1, :26])
+        want = np.asarray(rows[:, 0])
+        assert np.sqrt(((got - want) ** 2).sum() / (want ** 2).sum()) < 1e-5
+    # 26 real tokens took 3 routes in each of 2 expert layers; the parked
+    # lanes and the prefill's padding were computed and not counted
+    counter = eng.moe_rows()
+    assert counter.shape == (2, 9)
+    assert counter[:, -1].tolist() == [78, 78]
+    assert counter[:, :-1].sum(1).tolist() == [78, 78]
+
+
+@pytest.mark.parametrize("what,ok", [("as-served", True),
+                                     ("a-float32-reference-of-another-rope",
+                                      False)])
+def test_in_bfloat16_the_engine_holds_the_check_s_law(reference, what, ok):
+    """bfloat16 activations and parameters, as kanana is served: one copy of
+    the weights (``cast_once_params`` returns the tree it was given), and
+    ``check.serve_verdict``'s own law with the routes followed: relative row
+    error under 1.1% x (layers / 12)^0.3, logit gap under 0.05
+    (``harness/check.py`` has the reasons). A reference whose rope is
+    half-split fails it."""
+    import types
+
+    from benchmarks.harness import check
+
+    cfg, params = model("bfloat16", "bfloat16")
+    eng = DecodeEngine(params, cfg, n_slots=3, prefill_len=32,
+                       prefill_buckets=[16, 32])
+    assert eng.program_params is eng.params is params
+    assert eng.n_cast_leaves == 0
+    assert eng.program_param_bytes == 2 * gpt.param_count(params)
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, VOCAB, size=n, dtype=np.int32) for n in (12, 27)]
+    sizes = SIZES if ok else {**SIZES, "rope_interleave": False}
+    verdict = check.serve_verdict(
+        reference, sizes, types.SimpleNamespace(engine=eng), prompts, 4)
+    assert verdict["ok"] is ok, verdict
+    for case in verdict["cases"]:
+        assert len(case["route_banded_layers"]) == 3
+        assert case["route_banded_layers"][0] == 0      # the dense layer
+        if ok:
+            assert max(case["k_rel_layers"] + case["v_rel_layers"]) \
+                <= verdict["kv_rel_tol"]
+
+
+# -- the route ----------------------------------------------------------------
+
+def routed(n=48, e=8, d=32, f=16, seed=0, skew=0.0):
+    keys = jax.random.split(jax.random.key(seed), 6)
+    x = jax.random.normal(keys[0], (n, d))
+    w_router = jax.random.normal(keys[1], (d, e))
+    # skew: every token's scores lean to the first experts
+    bias = jnp.zeros((e,)).at[:3].set(skew)
+    w = [0.1 * jax.random.normal(k, s) for k, s in zip(
+        keys[2:5], ((e, d, f), (e, d, f), (e, f, d)))]
+    return x, w_router, bias, w
+
+
+def dense_experts(x, chosen, gates, w):
+    """Every chosen expert on its token, the plain way."""
+    gate, up, down = w
+    inner = jax.nn.silu(jnp.einsum("nd,edf->nef", x, gate)) \
+        * jnp.einsum("nd,edf->nef", x, up)
+    every = jnp.einsum("nef,efd->ned", inner, down)
+    picked = jnp.take_along_axis(every, chosen[..., None], axis=1)
+    return jnp.einsum("nkd,nk->nd", picked, gates)
+
+
+def test_gates_are_the_scores_normalised_and_scaled():
+    x, w_router, _, _ = routed()
+    bias = jnp.linspace(-0.5, 0.5, 8)
+    chosen, gates, select = moe.sigmoid_routes(
+        x, w_router, bias, top_k=3, norm_topk=True, route_scale=2.448)
+    np.testing.assert_allclose(gates.sum(-1), 2.448, rtol=1e-5)
+    scores = jax.nn.sigmoid(x @ w_router)
+    np.testing.assert_allclose(select, scores + bias, atol=1e-6)
+    # the bias moves the choice...
+    plain, plain_gates, _ = moe.sigmoid_routes(
+        x, w_router, jnp.zeros(8), top_k=3, norm_topk=True, route_scale=2.448)
+    assert (np.sort(chosen, -1) != np.sort(plain, -1)).any()
+    # ...and never a gate: a gate is its expert's score over the chosen sum
+    picked = jnp.take_along_axis(scores, chosen, -1)
+    np.testing.assert_allclose(
+        gates, 2.448 * picked / picked.sum(-1, keepdims=True), rtol=1e-5)
+    _, raw, _ = moe.sigmoid_routes(x, w_router, bias, top_k=3,
+                                   norm_topk=False, route_scale=1.0)
+    np.testing.assert_allclose(raw, picked, rtol=1e-6)
+
+
+@pytest.mark.parametrize("skew", [0.0, 4.0], ids=["level", "three-hot"])
+def test_the_dropless_route_computes_every_row_where_capacity_drops(skew):
+    """With every token leaning to three experts of eight, the capacity
+    route at factor 1 drops most rows; the dropless route computes all of
+    them, its counter says so, and the result is the plain sum."""
+    x, w_router, bias, w = routed(skew=skew)
+    chosen, gates, _ = moe.sigmoid_routes(
+        x, w_router, bias, top_k=3, norm_topk=True, route_scale=1.0)
+    out, counts = jax.jit(moe.grouped_swiglu)(x, chosen, w[1], w[0], w[2])
+    want = dense_experts(x, chosen, gates, (w[1], w[0], w[2]))
+    np.testing.assert_allclose(jnp.einsum("nkd,nk->nd", out, gates), want,
+                               atol=1e-5)
+    assert int(counts[-1]) == 48 * 3 == int(counts[:-1].sum())
+    np.testing.assert_array_equal(
+        counts[:-1], np.bincount(np.asarray(chosen).ravel(), minlength=8))
+    if skew:
+        assert int(counts[:3].sum()) == 48 * 3      # all on three experts
+        # the same load through the capacity route: rows are dropped
+        full = moe.moe_mlp(x[None], w_router + skew * jnp.eye(32, 8)[:, :8],
+                           w[0], w[2], top_k=3, capacity_factor=8 / 3,
+                           w_gate=w[1])[0]
+        tight = moe.moe_mlp(x[None], w_router + skew * jnp.eye(32, 8)[:, :8],
+                            w[0], w[2], top_k=3, capacity_factor=1.0,
+                            w_gate=w[1])[0]
+        assert float(jnp.abs(full - tight).max()) > 1e-3
+
+
+def test_the_counter_counts_the_valid_tokens_only():
+    x, w_router, bias, w = routed()
+    chosen, _, _ = moe.sigmoid_routes(x, w_router, bias, top_k=3,
+                                      norm_topk=True, route_scale=1.0)
+    valid = jnp.arange(48) < 10
+    _, counts = moe.grouped_swiglu(x, chosen, w[1], w[0], w[2], valid)
+    assert int(counts[-1]) == 30 == int(counts[:-1].sum())
+    np.testing.assert_array_equal(
+        counts[:-1], np.bincount(np.asarray(chosen[:10]).ravel(), minlength=8))
+
+
+def test_a_lane_s_output_does_not_depend_on_which_lanes_are_live():
+    """The decode step routes the lanes' rows together; a lane alone, or
+    beside other tokens, takes the same experts and gets the same output
+    (to the last bits: a matmul's row may be summed in another order beside
+    other rows, nothing more)."""
+    x, w_router, bias, w = routed(n=6, seed=4)
+    run = lambda rows: moe.moe_dropless(
+        rows[:, None], w_router, bias, w[1], w[0], w[2], top_k=3,
+        route_scale=2.448)[0][:, 0]
+    routes = lambda rows: moe.sigmoid_routes(
+        rows, w_router, bias, top_k=3, norm_topk=True, route_scale=2.448)[:2]
+    together = run(x)
+    for lane in range(6):
+        np.testing.assert_allclose(run(x[lane:lane + 1])[0], together[lane],
+                                   rtol=1e-5, atol=1e-7)
+    others = x.at[1:].set(jax.random.normal(jax.random.key(9), (5, 32)))
+    np.testing.assert_allclose(run(others)[0], together[0], rtol=1e-5,
+                               atol=1e-7)
+    for a, b in zip(routes(others), routes(x)):
+        np.testing.assert_array_equal(a[0], b[0])
+
+
+# -- configuration ------------------------------------------------------------
+
+@pytest.mark.parametrize("change,match", [
+    (dict(attention="flash"), "einsum"),
+    (dict(rmsnorm=False), "rope and rmsnorm"),
+    (dict(qk_rope_head_dim=7), "even"),
+    (dict(kv_lora_rank=0), "set kv_lora_rank"),
+    (dict(swiglu=False), "swiglu"),
+    (dict(moe_scoring="softmax"), "sigmoid"),
+    (dict(moe_scoring="tanh"), "unknown moe_scoring"),
+    (dict(n_dense_layers=4), "n_dense_layers"),
+    (dict(n_experts=0, moe_scoring="softmax", n_shared_experts=0,
+          moe_route_scale=1.0), "lead an expert model"),
+    (dict(param_dtype="float16"), "param_dtype"),
+])
+def test_combinations_that_are_not_built_are_refused_with_a_sentence(
+        change, match):
+    with pytest.raises(ConfigError, match=match):
+        GPTConfig.make(**{**TINY, **change})
+
+
+@pytest.mark.parametrize("how", ["int8-pool", "tp-over-the-latent"])
+def test_the_engine_refuses_what_the_latent_pool_is_not_built_for(how):
+    cfg, params = model()
+    kwargs = {"kv_dtype": "int8"} if how == "int8-pool" else {
+        "mesh": mesh_lib.make_mesh(MeshConfig(tp=2), devices=jax.devices()[:2])}
+    with pytest.raises(ConfigError, match="latent"):
+        DecodeEngine(params, cfg, n_slots=2, **kwargs)
+
+
+def test_the_preset_is_the_published_model():
+    cfg = GPTConfig.make(model_type="kanana-2-30b-a3b-instruct-2601")
+    assert (cfg.n_layer, cfg.n_head, cfg.n_embd, cfg.vocab_size,
+            cfg.block_size) == (48, 32, 2048, 128256, 32768)
+    assert (cfg.kv_lora_rank, cfg.qk_head_dim, cfg.v_head_dim,
+            cfg.dense_width, cfg.expert_width) == (512, 192, 128, 6144, 768)
+    assert cfg.param_dtype == "bfloat16" and cfg.moe_scoring == "sigmoid"
+    # the benchmark's configuration is the preset but for its two cuts
+    program = spec.load_cell(
+        "kanana-2-30b-a3b.serve-long-decode").config["program"]["gpt_config"]
+    cut = GPTConfig.make(**program)
+    assert dataclasses.replace(
+        cfg, model_type=None, n_layer=6, block_size=8192) == cut
+    shapes = gen.cache_leaf_shapes(cut, 64)
+    assert shapes == {"k": (6, 64, 8192, 1, 64), "v": (6, 64, 8192, 1, 512)}
+
+
+def test_parameters_are_made_in_param_dtype_and_a_mesh_still_builds():
+    cfg, _ = model("bfloat16", "bfloat16")
+    params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
+    assert {a.dtype for a in jax.tree.leaves(params)} == {jnp.dtype("bfloat16")}
+    assert params["dense_blocks"]["w_gate"].shape == (1, 64, 96)
+    assert params["blocks"]["w_e1"].shape == (2, 8, 64, 24)
+    assert params["blocks"]["w_sg"].shape == (2, 64, 48)
+    mesh = mesh_lib.make_mesh(MeshConfig(dp=2, fsdp=2, tp=2),
+                              devices=jax.devices()[:8])
+    shardings = mesh_lib.param_shardings(mesh, params)
+    assert jax.tree.structure(shardings) == jax.tree.structure(params)
+    # a bfloat16 draw is the float32 draw rounded, not another stream
+    f32 = gpt.init(jax.random.key(0), dataclasses.replace(
+        cfg, param_dtype="float32"))
+    bf16 = gpt.init(jax.random.key(0), cfg)
+    np.testing.assert_array_equal(
+        np.asarray(f32["blocks"]["w_kv_b"].astype(jnp.bfloat16), np.float32),
+        np.asarray(bf16["blocks"]["w_kv_b"], np.float32))
+
+
+def test_the_training_path_takes_the_new_leaves():
+    """Every leaf has a decay rule (the bias and the latent's norm scale are
+    not decayed) and the loss has a finite gradient in every leaf; the
+    router's comes through the gates (the bias, which only moves a choice,
+    has none, as published)."""
+    from mingpt_distributed_tpu.training import optimizer
+
+    cfg, params = model()
+    mask = optimizer.decay_mask(params)
+    assert mask["blocks"]["w_kv_b"] and mask["blocks"]["w_sd"]
+    assert not mask["blocks"]["e_bias"]
+    assert not mask["dense_blocks"]["kv_norm_scale"]
+    tokens = tokens_of(24)
+    grads = jax.grad(lambda p: gpt.forward(
+        p, tokens, cfg, targets=jnp.roll(tokens, -1, 1))[1])(params)
+    for path, g in jax.tree_util.tree_leaves_with_path(grads):
+        assert bool(jnp.isfinite(g).all()), path
+    assert float(jnp.abs(grads["blocks"]["w_router"]).max()) > 0
+    assert float(jnp.abs(grads["dense_blocks"]["w_kv_a"]).max()) > 0
+    assert float(jnp.abs(grads["blocks"]["e_bias"]).max()) == 0
+
+
+# -- the latent pool through the serving paths --------------------------------
+
+def serve(cfg, params, prompts, **kwargs):
+    server = InferenceServer(params, cfg, n_slots=2, prefill_len=32,
+                             prefill_buckets=[8, 16, 32], **kwargs)
+    handles = server.generate_batch([
+        Request(prompt=p, max_new_tokens=6, do_sample=False) for p in prompts])
+    return server, [h.tokens for h in handles]
+
+
+@pytest.fixture(scope="module")
+def served():
+    cfg, params = model()
+    rng = np.random.default_rng(0)
+    shared = rng.integers(0, VOCAB, size=17).tolist()
+    prompts = [shared + rng.integers(0, VOCAB, size=n).tolist()
+               for n in (3, 5, 4, 6, 2)]
+    server, tokens = serve(cfg, params, prompts)
+    return cfg, params, prompts, server, tokens
+
+
+def test_slots_are_reused_and_the_tokens_are_solo_generate_s(served):
+    """Five requests through two slots: a slot's next tenant attends none
+    of the last one's latents, and every greedy stream is ``generate``'s."""
+    cfg, params, prompts, server, tokens = served
+    for prompt, got in zip(prompts, tokens):
+        solo = gen.generate(params, cfg, np.asarray([prompt]), 6)
+        assert got == np.asarray(solo)[0, len(prompt):].tolist()
+    summary = server.metrics.summary()
+    assert summary["kv_bytes_per_row"] == 3 * (8 + 32) * 4
+    assert summary["moe_dropped_rows"] == 0
+    assert summary["moe_routed_rows"] > 0
+    assert summary["moe_load_max_over_mean"] >= 1.0
+    assert summary["program_weights_cast"] == 0
+    facts = server.engine.pool.audit_facts()
+    assert facts["cache_leaf_shapes"] == {"k": (3, 2, BLOCK, 1, 8),
+                                          "v": (3, 2, BLOCK, 1, 32)}
+    assert facts["cache_leaf_elems"] == 3 * 2 * BLOCK * 8
+
+
+def test_the_prefix_store_copies_latent_rows(served):
+    cfg, params, prompts, _, want = served
+    server, got = serve(cfg, params, prompts, prefix_cache_mb=1.0)
+    assert got == want
+    summary = server.metrics.summary()
+    assert summary["prefix_hits"] >= 3 and summary["prefix_rows_reused"] >= 48
+    (_, entry), *_ = server.engine.prefix_store.entries()
+    assert sorted(entry) == ["k", "v"]
+    assert entry["k"].shape[3:] == (1, 8) and entry["v"].shape[3:] == (1, 32)
+
+
+def test_speculative_verify_scores_against_the_latent_pool(served):
+    """A draft of another architecture proposes, the latent target verifies:
+    the tokens are the plain server's."""
+    cfg, params, prompts, _, want = served
+    draft_cfg = GPTConfig.make(
+        n_layer=1, n_head=2, n_embd=32, vocab_size=VOCAB, block_size=BLOCK,
+        dtype="float32", embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0)
+    draft = gpt.init(jax.random.key(7), draft_cfg)
+    server, got = serve(cfg, params, prompts, draft_params=draft,
+                        draft_cfg=draft_cfg, spec_k=2)
+    assert got == want
+    assert server.metrics.summary()["spec_rounds"] > 0
+    assert server.metrics.summary()["moe_dropped_rows"] == 0
+
+
+def test_a_dense_model_s_summary_has_the_fields_and_no_counter():
+    cfg = GPTConfig.make(n_layer=1, n_head=2, n_embd=32, vocab_size=VOCAB,
+                         block_size=BLOCK, dtype="float32", embd_pdrop=0.0,
+                         resid_pdrop=0.0, attn_pdrop=0.0)
+    server = InferenceServer(gpt.init(jax.random.key(0), cfg), cfg, n_slots=2)
+    summary = server.metrics.summary()
+    assert summary["kv_bytes_per_row"] == 1 * 2 * 32 * 4
+    assert summary["moe_routed_rows"] is None
+    assert summary["moe_dropped_rows"] is None
+    assert sorted(server.engine.pool.cache) == ["k", "v"]
