@@ -50,6 +50,18 @@ per-slot arrays instead of static python scalars — which is what keeps one
 compiled program serving mixed greedy/sampled tenants. For greedy lanes
 the filters cannot move the argmax, so a greedy request's tokens match
 solo ``generate()`` exactly (tests/test_serving.py asserts token identity).
+What a round computes is chosen inside the program, by ``lax.cond`` on its
+own inputs (``_select_next_slots``): every lane's logits are divided by
+its temperature; if no lane samples, one argmax and nothing else; if some
+do, the S x V draws as well; and only if one of those samples under top-k
+or top-p (``sampler_orders``) is the vocabulary ordered, once, for both
+filters (top-k masks by value, so the order of the masked logits is the
+masked order). A lane without a request asks for nothing: the scheduler
+resets a slot's sampling parameters when it frees it
+(``SlotTable.release``). Tokens are those of the sampler that sorted twice
+every round, bit for bit (tests/test_sampler_order.py keeps that sampler
+as its reference), and the scheduler counts the rounds that sorted
+(``sampler_sorted_rounds``).
 Chunked prefill is exactly row-equivalent to one whole-prompt forward:
 attention, MLP and norms are row-wise, and a chunk's queries see the same
 keys at the same absolute positions the one-shot forward would.
@@ -190,6 +202,14 @@ def bucket_ladder(
     return tuple(sorted(vals))
 
 
+def sampler_orders(do_sample, top_ks, top_ps):
+    """Whether a round has to order the vocabulary: some lane samples
+    under a filter (``top_k`` on, or ``top_p`` below 1). NumPy or ``jnp``
+    vectors alike: the programs branch on it (:func:`_select_next_slots`)
+    and the scheduler counts it, on the host's copy of the same vectors."""
+    return (do_sample & ((top_ks > 0) | (top_ps < 1.0))).any()
+
+
 @jax.named_scope("sample")
 def _select_next_slots(
     logits: jax.Array,      # (S, V) fp32
@@ -201,28 +221,51 @@ def _select_next_slots(
 ) -> jax.Array:
     """generate._select_next with per-slot traced params. Filter order and
     edge semantics (top token always survives top-p; top_k clamped to V)
-    match the solo sampler exactly."""
+    match the solo sampler exactly.
+
+    The round does what its lanes need and no more, chosen by two
+    conditionals on the program's own inputs: no lane samples, one argmax;
+    some do, the draws too; one of them under a filter
+    (:func:`sampler_orders`), the vocabulary's one sort and the filters
+    for all lanes. A lane's token is the same whichever branch ran (a
+    filter cannot move an argmax, and a disabled filter removes nothing)."""
     v = logits.shape[-1]
     logits = logits / jnp.maximum(temps, 1e-8)[:, None]
-    # top-k with per-slot k: threshold at the k-th largest value; k=V is a
-    # no-op, so "disabled" rides as k_eff = V
-    k_eff = jnp.where(top_ks > 0, jnp.minimum(top_ks, v), v)
-    desc = jnp.sort(logits, axis=-1)[:, ::-1]
-    kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None], axis=-1)
-    logits = jnp.where(logits < kth, -jnp.inf, logits)
-    # nucleus: smallest prefix of the (re-sorted, post-top-k) distribution
-    # whose preceding cumulative mass is < top_p; top token unconditional
-    desc2 = jnp.sort(logits, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(desc2, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (cum - probs) < top_ps[:, None]
-    keep = keep.at[:, 0].set(True)
-    kth2 = jnp.min(jnp.where(keep, desc2, jnp.inf), axis=-1, keepdims=True)
-    nucleus_on = (top_ps < 1.0)[:, None]
-    logits = jnp.where(nucleus_on & (logits < kth2), -jnp.inf, logits)
-    sampled = jax.vmap(lambda l, k: jax.random.categorical(k, l))(logits, keys)
-    greedy = jnp.argmax(logits, axis=-1)
-    return jnp.where(do_sample, sampled, greedy).astype(jnp.int32)
+
+    def filtered(logits):
+        # top-k with per-slot k: threshold at the k-th largest value; k=V
+        # is a no-op, so "disabled" rides as k_eff = V
+        k_eff = jnp.where(top_ks > 0, jnp.minimum(top_ks, v), v)
+        # descending without a reversed copy: negation is exact. Values
+        # alone are sorted, so a stable sort would order nothing more, and
+        # on the TPU it carries an index operand that doubles its time
+        desc = -jnp.sort(-logits, axis=-1, stable=False)
+        kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None], axis=-1)
+        logits = jnp.where(logits < kth, -jnp.inf, logits)
+        # nucleus: smallest prefix of the post-top-k distribution whose
+        # preceding cumulative mass is < top_p; top token unconditional.
+        # top-k masks by value, so the masked logits in descending order
+        # are the descending order masked: no second sort
+        desc = jnp.where(desc < kth, -jnp.inf, desc)
+        probs = jax.nn.softmax(desc, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        keep = (cum - probs) < top_ps[:, None]
+        keep = keep.at[:, 0].set(True)
+        kth2 = jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1, keepdims=True)
+        nucleus_on = (top_ps < 1.0)[:, None]
+        return jnp.where(nucleus_on & (logits < kth2), -jnp.inf, logits)
+
+    def sample(logits):
+        logits = jax.lax.cond(
+            sampler_orders(do_sample, top_ks, top_ps),
+            filtered, lambda l: l, logits)
+        sampled = jax.vmap(
+            lambda l, k: jax.random.categorical(k, l))(logits, keys)
+        return jnp.where(do_sample, sampled, jnp.argmax(logits, axis=-1))
+
+    return jax.lax.cond(
+        do_sample.any(), sample,
+        lambda l: jnp.argmax(l, axis=-1), logits).astype(jnp.int32)
 
 
 @jax.named_scope("kv_layout")

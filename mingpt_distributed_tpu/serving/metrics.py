@@ -80,6 +80,12 @@ class ServingMetrics:
             self._rejected.labels(reason=_reason).inc(0)
         self._steps = r.counter(
             "mingpt_serve_steps_total", help="scheduler rounds executed")
+        self._sampler_sorted = r.counter(
+            "mingpt_serve_sampler_sorted_rounds_total",
+            help="decode rounds whose program ordered the vocabulary: a "
+                 "lane sampled under top-k or top-p "
+                 "(engine.sampler_orders); 0 under greedy or "
+                 "plain-temperature traffic")
         # prefill accounting (ISSUE 3): real prompt tokens forwarded, the
         # padded bucket fit (how well the ladder matches the traffic), and
         # wall time inside prefill calls — the decode-stall budget
@@ -236,6 +242,10 @@ class ServingMetrics:
         return int(self._steps.value)
 
     @property
+    def sampler_sorted_rounds(self) -> int:
+        return int(self._sampler_sorted.value)
+
+    @property
     def prefill_chunks(self) -> int:
         return int(self._prefill_chunks.value)
 
@@ -344,6 +354,11 @@ class ServingMetrics:
         self._requests.labels(outcome="completed").inc()
         if n_generated > 1:
             self._itl.observe(gen_span_s / (n_generated - 1))
+
+    def on_sampler_sorted(self) -> None:
+        """A decode round whose program ordered the vocabulary (the
+        scheduler calls it, beside its ``decode_step``)."""
+        self._sampler_sorted.inc()
 
     def on_step(
         self, queue_depth: int, slots_active: int, lanes_used: Optional[int] = None
@@ -528,6 +543,7 @@ class ServingMetrics:
             "prefix_rows_reused": self.prefix_rows_reused,
             "tokens_generated": self.tokens_generated,
             "steps": self.steps,
+            "sampler_sorted_rounds": self.sampler_sorted_rounds,
             "queue_depth": self.queue_depth,
             "slots_active": self.slots_active,
             "slot_utilization": self.slot_utilization,

@@ -89,7 +89,7 @@ import numpy as np
 
 from mingpt_distributed_tpu.config import GPTConfig
 from mingpt_distributed_tpu.serving.admission import AdmissionPolicy, FifoPolicy
-from mingpt_distributed_tpu.serving.engine import DecodeEngine
+from mingpt_distributed_tpu.serving.engine import DecodeEngine, sampler_orders
 from mingpt_distributed_tpu.serving.metrics import ServingMetrics
 from mingpt_distributed_tpu.serving.requests import (  # noqa: F401  (re-export)
     QueueFullError,
@@ -202,9 +202,14 @@ class SlotTable:
         self.seeds[slot] = seed & 0xFFFFFFFF
 
     def release(self, slot: int) -> None:
+        """Back to a free lane's state: parked, and asking the sampler for
+        nothing (a tenant's ``do_sample`` left behind would keep the
+        decode program sorting for nobody: engine.sampler_orders)."""
         self.handles[slot] = None
         self.seeds[slot] = 0
         self.positions[slot] = self.parked
+        self.temps[slot], self.top_ks[slot], self.top_ps[slot] = 1.0, 0, 1.0
+        self.do_sample[slot] = False
 
     def start_decode(self, slot: int, token: int, position: int,
                      req: Request) -> None:
@@ -716,6 +721,9 @@ class InferenceServer:
                         st.tokens, pos, st.temps, st.top_ks,
                         st.top_ps, st.do_sample, st.seeds, index,
                     )
+                    # the program's own predicate, on the vectors it got
+                    if sampler_orders(st.do_sample, st.top_ks, st.top_ps):
+                        self.metrics.on_sampler_sorted()
                     for s in plain:
                         burst[s] = [int(nxt[s])]
                 if spec_slots:
