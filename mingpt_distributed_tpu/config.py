@@ -87,7 +87,39 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
         n_experts=128, moe_top_k=6, moe_ffn_dim=768, n_shared_experts=2,
         moe_scoring="sigmoid", moe_route_scale=2.448,
         param_dtype="bfloat16"),
+    # openbmb/MiniCPM-SALA (9B): 24 lightning linear-attention layers (a
+    # decaying 128x128 state a head, rope) interleaved with 8 InfLLM-v2
+    # block-sparse softmax layers (32 query heads over 2 KV heads, no rope),
+    # qk-norm, gated outputs, MiniCPM's three scalings. Published in bfloat16.
+    "minicpm-sala": dict(
+        n_layer=32, n_head=32, n_embd=4096, n_kv_head=2, vocab_size=73448,
+        block_size=524288, rope=True, rope_theta=10000.0, swiglu=True,
+        rmsnorm=True, norm_eps=1e-6, tie_weights=False, ffn_dim=16384,
+        mixer_types=tuple(
+            "minicpm4" if i in (0, 9, 16, 17, 22, 29, 30, 31)
+            else "lightning-attn" for i in range(32)),
+        lightning_heads=32, lightning_head_dim=128, qk_norm=True,
+        output_gate=True, scale_emb=12.0, scale_depth=1.4,
+        dim_model_base=256, param_dtype="bfloat16"),
+    # the same stack at a size the CPU tests run: one period of four layers
+    # that ends on a sparse one, a selection that selects within 128 rows
+    "minicpm-sala-tiny": dict(
+        n_layer=4, n_head=4, n_embd=64, n_kv_head=2, vocab_size=96,
+        block_size=128, rope=True, swiglu=True, rmsnorm=True, norm_eps=1e-6,
+        tie_weights=False, ffn_dim=128,
+        mixer_types=("lightning-attn", "minicpm4", "lightning-attn",
+                     "minicpm4"),
+        lightning_heads=4, lightning_head_dim=16, qk_norm=True,
+        output_gate=True, scale_emb=12.0, scale_depth=1.4,
+        scale_depth_layers=32, dim_model_base=16,
+        sparse_kernel_size=8, sparse_kernel_stride=4, sparse_block_size=16,
+        sparse_topk=4, sparse_window=16, sparse_init_blocks=1,
+        sparse_dense_len=48, dtype="float32"),
 }
+
+#: the two mixers a hybrid stack (``GPTConfig.mixer_types``) is made of,
+#: under their published names
+LIGHTNING, SPARSE = "lightning-attn", "minicpm4"
 
 
 class ConfigError(ValueError):
@@ -217,6 +249,42 @@ class GPTConfig:
     # Leading layers that keep a dense MLP in an expert model: a stack of
     # their own (params["dense_blocks"]) before the expert stack.
     n_dense_layers: int = 0
+    # A hybrid stack (MiniCPM-SALA): one mixer a layer, under its published
+    # name. LIGHTNING is linear attention with a decaying (head_dim,
+    # head_dim) float32 state a head and no rows (ops/lightning.py); SPARSE
+    # is InfLLM-v2 block-sparse softmax attention over ``n_head`` query and
+    # ``n_kv_head`` KV heads of ``head_dim`` (ops/sparse_attention.py).
+    # None: every layer is the stack's one attention. Needs rope (no
+    # position table), rmsnorm and swiglu; the lightning layers rotate their
+    # queries and keys, the sparse ones do not.
+    mixer_types: Optional[Tuple[str, ...]] = None
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    # RMS-norm every head's queries and keys, with a learned weight a mixer.
+    qk_norm: bool = False
+    # The mixer's output times sigmoid(W_g u) before the output projection.
+    output_gate: bool = False
+    # MiniCPM's scalings: the embeddings times ``scale_emb``; every residual
+    # branch times ``scale_depth / sqrt(scale_depth_layers or n_layer)`` (a
+    # cut in depth keeps the published depth here); the head's input divided
+    # by ``n_embd / dim_model_base``. 1, 0 and 0 switch each off.
+    scale_emb: float = 1.0
+    scale_depth: float = 0.0
+    scale_depth_layers: Optional[int] = None
+    dim_model_base: int = 0
+    # The sparse mixer's selection: keys mean-pooled over windows of
+    # ``sparse_kernel_size`` every ``sparse_kernel_stride``; a query below
+    # ``sparse_dense_len`` attends every row, one at or above it the rows of
+    # ``sparse_topk`` blocks of ``sparse_block_size``: the first
+    # ``sparse_init_blocks``, those that cover its last ``sparse_window``
+    # positions, and the best-scoring others.
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_window: int = 2048
+    sparse_init_blocks: int = 1
+    sparse_dense_len: int = 8192
     # Cross-entropy head chunking: >1 splits the LM-head matmul + softmax
     # into this many sequence chunks under jax.checkpoint, so the (B, T, V)
     # fp32 logits tensor — the dominant activation at GPT-2 vocab sizes —
@@ -243,6 +311,8 @@ class GPTConfig:
         kwargs = dict(kwargs)
         if "n_embed" in kwargs:  # normalise the reference's stray spelling
             kwargs.setdefault("n_embd", kwargs.pop("n_embed"))
+        if kwargs.get("mixer_types") is not None:  # YAML and JSON give lists
+            kwargs["mixer_types"] = tuple(kwargs["mixer_types"])
         cfg = cls(**_reject_unknown(cls, kwargs))
         return cfg.resolved()
 
@@ -370,6 +440,72 @@ class GPTConfig:
         if self.param_dtype not in ("float32", "bfloat16"):
             raise ConfigError(
                 f"param_dtype {self.param_dtype!r}: float32 or bfloat16")
+        if self.scale_depth < 0 or self.scale_emb <= 0 \
+                or self.dim_model_base < 0:
+            raise ConfigError(
+                "scale_emb must be > 0, scale_depth and dim_model_base >= 0")
+        if self.mixer_types is not None:
+            self._validate_hybrid()
+        elif self.lightning_heads or self.lightning_head_dim \
+                or self.qk_norm or self.output_gate:
+            raise ConfigError(
+                "lightning_heads, lightning_head_dim, qk_norm and "
+                "output_gate belong to a hybrid stack: set mixer_types")
+
+    def _validate_hybrid(self) -> None:
+        """A hybrid stack's own rules, and what it does not compose with,
+        a sentence each."""
+        kinds = set(self.mixer_types)
+        if len(self.mixer_types) != self.n_layer or not kinds <= {
+                LIGHTNING, SPARSE}:
+            raise ConfigError(
+                f"mixer_types names one of {LIGHTNING!r}, {SPARSE!r} for "
+                f"each of the {self.n_layer} layers, got {self.mixer_types}")
+        if SPARSE not in kinds:
+            raise ConfigError(
+                "a hybrid stack needs a sparse layer: the serving pool, its "
+                "audits and the benchmark's check read rows, and a stack of "
+                "linear layers alone keeps none")
+        if not (self.rope and self.rmsnorm and self.swiglu):
+            raise ConfigError(
+                "a hybrid stack has no position table, RMS-norms and a "
+                "SwiGLU MLP: it needs rope, rmsnorm and swiglu")
+        if LIGHTNING in kinds and (
+                self.lightning_heads < 1 or self.lightning_head_dim < 2
+                or self.lightning_head_dim % 2):
+            raise ConfigError(
+                "a lightning layer needs lightning_heads and an even "
+                "lightning_head_dim (it rotates its queries and keys)")
+        if self.attention != "einsum":
+            raise ConfigError(
+                f"a hybrid stack is built for attention='einsum': the "
+                f"{self.attention!r} path takes one softmax attention for "
+                "every layer and knows no state and no block selection")
+        if self.attention_window or self.attn_logit_softcap:
+            raise ConfigError(
+                "a hybrid stack takes no attention_window and no "
+                "attn_logit_softcap: the sparse mixer has its own window "
+                "inside its selection and the linear one has no scores")
+        if self.kv_lora_rank or self.n_experts:
+            raise ConfigError(
+                "a hybrid stack keeps per-head rows and a dense MLP: "
+                "latent attention and experts are not written for it")
+        if self.pp_microbatches:
+            raise ConfigError(
+                "a hybrid stack is not pipelined (pp_microbatches): the "
+                "pipeline splits one stack of like layers")
+        k, s, b = (self.sparse_kernel_size, self.sparse_kernel_stride,
+                   self.sparse_block_size)
+        if SPARSE in kinds and (
+                min(k, s, b, self.sparse_topk, self.sparse_window) < 1
+                or self.sparse_init_blocks < 0 or k % s or b % s
+                or self.block_size % b or self.sparse_dense_len < 0):
+            raise ConfigError(
+                "the sparse mixer pools whole strides into kernels and "
+                "whole strides into blocks: sparse_kernel_size and "
+                "sparse_block_size must be multiples of "
+                "sparse_kernel_stride, block_size a multiple of "
+                "sparse_block_size, and every size positive")
 
     @property
     def head_dim(self) -> int:
@@ -404,6 +540,57 @@ class GPTConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_head if self.n_kv_head is not None else self.n_head
+
+    @property
+    def mixer_names(self) -> Optional[list]:
+        """``mixer_types`` as a configuration file spells it (a list)."""
+        return None if self.mixer_types is None else list(self.mixer_types)
+
+    def mixer_layers(self, kind: str) -> Tuple[int, ...]:
+        """The layers of a hybrid stack that take mixer ``kind``, in order:
+        a layer's place in this tuple is its place in its kind's stack of
+        parameters and in its kind's leaves of the cache."""
+        return tuple(i for i, m in enumerate(self.mixer_types or ())
+                     if m == kind)
+
+    def mixer_heads(self, kind: str) -> Tuple[int, int, int]:
+        """(query heads, key and value heads, head size) of mixer ``kind``:
+        a linear layer has a key head a query head."""
+        if kind == LIGHTNING:
+            return (self.lightning_heads, self.lightning_heads,
+                    self.lightning_head_dim)
+        return self.n_head, self.kv_heads, self.head_dim
+
+    @property
+    def residual_scale(self) -> float:
+        """What every residual branch is multiplied by (``scale_depth``)."""
+        if not self.scale_depth:
+            return 1.0
+        return self.scale_depth / (
+            self.scale_depth_layers or self.n_layer) ** 0.5
+
+    @property
+    def head_divisor(self) -> float:
+        """What the head's input is divided by (``dim_model_base``)."""
+        return self.n_embd / self.dim_model_base if self.dim_model_base \
+            else 1.0
+
+    @property
+    def sparse_config(self) -> dict:
+        """The selection's sizes under the family's published names."""
+        return {"kernel_size": self.sparse_kernel_size,
+                "kernel_stride": self.sparse_kernel_stride,
+                "block_size": self.sparse_block_size,
+                "topk": self.sparse_topk,
+                "window_size": self.sparse_window,
+                "init_blocks": self.sparse_init_blocks,
+                "dense_len": self.sparse_dense_len}
+
+    @property
+    def sparse_pooled_len(self) -> int:
+        """Pooled keys a sparse layer keeps for ``block_size`` positions:
+        one a stride (the last ``kernel/stride - 1`` never complete)."""
+        return self.block_size // self.sparse_kernel_stride
 
 
 @dataclass

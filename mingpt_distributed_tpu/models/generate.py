@@ -35,10 +35,11 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from mingpt_distributed_tpu.config import GPTConfig
+from mingpt_distributed_tpu.config import LIGHTNING, SPARSE, GPTConfig
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.ops import layers as L
+from mingpt_distributed_tpu.ops import sparse_attention as sparse_ops
 
 # {"k", "v"}: (n_layer, B, block_size, heads, size), heads and size each
 # leaf's own (``cache_leaf_shapes``). A pool that counts a routed model's
@@ -52,6 +53,22 @@ Cache = Dict[str, jax.Array]
 #: programs add to it in place and nothing is fetched in a round.
 MOE_ROWS = "moe_rows"
 
+#: a hybrid stack's leaves beside the sparse layers' ``"k"``, ``"v"`` rows
+#: ((sparse layers, B, block_size, 1, KV * hd): the heads side by side).
+#: POOLED: the sparse layers' pooled keys, (sparse layers, B, block_size /
+#: stride, 1, KV * hd), rows of a coarser grid. STATE: the lightning layers'
+#: state, (lightning layers, B, H, hd, hd) float32: no position axis, so no
+#: mask hides a stale one: a sequence's first chunk starts it from zero.
+POOLED = "pooled_k"
+STATE = "state"
+#: and the counter of the rows the sparse layers' decode steps attended:
+#: (2,) float32, [rows attended, rows at or before the query], summed over
+#: sparse layers and counted lanes, each the mean over a layer's KV heads.
+#: It rides in a serving pool's donated tree as MOE_ROWS does.
+SPARSE_ROWS = "sparse_rows"
+#: leaves of a cache tree that count and hold nothing of a request
+COUNTERS = (MOE_ROWS, SPARSE_ROWS)
+
 
 def cache_leaf_shapes(cfg: GPTConfig, batch: int) -> Dict[str, Tuple[int, ...]]:
     """The shape of each leaf of a ``batch``-lane cache: the one
@@ -61,6 +78,21 @@ def cache_leaf_shapes(cfg: GPTConfig, batch: int) -> Dict[str, Tuple[int, ...]]:
     each shared by all heads: ``"k"`` the rotated rope key and ``"v"`` the
     normed latent (the values the absorbed attention averages, and the
     first part of every key, so stored once)."""
+    if cfg.mixer_types is not None:
+        # rows for the sparse layers alone; the lightning layers keep a
+        # state a head and nothing a position
+        sparse, lin = (len(cfg.mixer_layers(m)) for m in (SPARSE, LIGHTNING))
+        # a row holds its KV heads side by side: a (2, 128) pair is tiled
+        # so that the MXU cannot read it (ops/sparse_attention.py)
+        width = cfg.kv_heads * cfg.head_dim
+        rows = (sparse, batch, cfg.block_size, 1, width)
+        shapes = {"k": rows, "v": rows} if sparse else {}
+        if sparse:
+            shapes[POOLED] = (sparse, batch, cfg.sparse_pooled_len, 1, width)
+        if lin:
+            shapes[STATE] = (lin, batch, cfg.lightning_heads,
+                             cfg.lightning_head_dim, cfg.lightning_head_dim)
+        return shapes
     rows = (cfg.n_layer, batch, cfg.block_size)
     if cfg.kv_lora_rank:
         return {"k": rows + (1, cfg.qk_rope_head_dim),
@@ -70,8 +102,16 @@ def cache_leaf_shapes(cfg: GPTConfig, batch: int) -> Dict[str, Tuple[int, ...]]:
 
 def init_cache(cfg: GPTConfig, batch: int, dtype=None) -> Cache:
     dtype = dtype or jnp.dtype(cfg.dtype)
-    return {n: jnp.zeros(shape, dtype)
+    # a state is float32 whatever the rows are kept in
+    return {n: jnp.zeros(shape, jnp.float32 if n == STATE else dtype)
             for n, shape in cache_leaf_shapes(cfg, batch).items()}
+
+
+def init_sparse_rows(cfg: GPTConfig) -> Optional[jax.Array]:
+    """A zeroed SPARSE_ROWS leaf, or None where no layer selects."""
+    if SPARSE not in (cfg.mixer_types or ()):
+        return None
+    return jnp.zeros((2,), jnp.float32)
 
 
 def init_moe_rows(cfg: GPTConfig) -> Optional[jax.Array]:
@@ -99,7 +139,9 @@ def _write_lane_rows(cache: Cache, rows, positions) -> Cache:
     hd)`` buffers at ``(:, b, positions[b])``: one ``dynamic_update_slice``
     of ``(L, 1, 1, KV, hd)`` a lane and buffer, in place, whatever layout
     the device keeps the buffers in. ``rows`` is the layers' list of
-    ``{"k", "v"}`` rows, ``(B, 1, KV, hd)`` each.
+    ``{"k", "v"}`` rows, ``(B, 1, KV, hd)`` each (a hybrid stack's sparse
+    layers bring POOLED rows too, which lie on a coarser grid:
+    ``positions`` is then a dict of (B,) indices by leaf).
 
     The lanes are a static python loop on purpose (compile rehearsals for
     the TPU, PR 27): a ``fori_loop`` body is laid out before its caller,
@@ -108,12 +150,13 @@ def _write_lane_rows(cache: Cache, rows, positions) -> Cache:
     back, every call. A chain of slices in the entry computation takes
     the layout the buffer arrives in."""
     out = dict(cache)
-    for name in ("k", "v"):
+    for name in rows[0]:
         buf = cache[name]
         new = jnp.stack([r[name] for r in rows])  # (L, B, 1, KV, hd)
+        at = positions[name] if isinstance(positions, dict) else positions
         for lane in range(new.shape[1]):
             buf = jax.lax.dynamic_update_slice(
-                buf, new[:, lane:lane + 1], (0, lane, positions[lane], 0, 0))
+                buf, new[:, lane:lane + 1], (0, lane, at[lane], 0, 0))
         out[name] = buf
     return out
 
@@ -242,6 +285,143 @@ def _cached_block(
     return x + m, cache, rows, counts
 
 
+def _cached_hybrid_block(
+    x: jax.Array,            # (B, T, D)
+    blk: gpt.Params,         # one layer's params
+    cache: Cache,            # the FULL buffers of ``cache_leaf_shapes``
+    kind: str,               # the layer's mixer
+    at: int,                 # its place among that mixer's layers
+    offset: jax.Array,       # scalar, or (B,): see ``_cached_block``
+    cfg: GPTConfig,
+    valid: Optional[jax.Array] = None,  # (B, T) bool: real tokens
+):
+    """One layer of a hybrid stack against the cache, in either form of
+    ``_cached_block``. Returns (y, cache, rows, counted): ``rows`` a sparse
+    layer's new ``"k"``, ``"v"`` and POOLED rows for the caller to write
+    under a position a lane (None else, and for a lightning layer);
+    ``counted`` (2,) a sparse decode step's [rows attended, rows at or
+    before the query] over the ``valid`` lanes (None else).
+
+    A lightning layer reads its state at ``cache[STATE][at]`` and writes
+    the new one there, in place. A sequence's first chunk (``offset`` 0)
+    starts from zero whatever the slot held: a stale state, unlike a stale
+    row, would be read. Tokens that are not ``valid`` (a bucket's padding,
+    a parked lane, a lane still prefilling) leave the state as it was.
+
+    A sparse layer writes rows as any layer does, and keeps its pooled keys
+    beside them: a chunk re-pools its lane's rows whole (entries over rows
+    not yet written are not yet visible), a decode step re-pools the one
+    window that ends at or last before each lane's position, laid over the
+    cached ones before the selection reads them. The decode step reads the
+    cached rows as they lie and attends its own new row beside them
+    (``sparse_ops.sparse_attend_step``): the rows are not laid over a
+    slice of the pool's size as ``_cached_block`` lays them.
+    """
+    b, t, _ = x.shape
+    per_lane = jnp.ndim(offset) == 1
+    positions = jnp.asarray(offset)[..., None] + jnp.arange(t)  # (T,) | (B,T)
+    u = L.rms_norm(x, blk["ln1_scale"], eps=cfg.norm_eps)
+    rows = counted = None
+    if kind == LIGHTNING:
+        state = cache[STATE][at]
+        if not per_lane:
+            state = jnp.where(jnp.asarray(offset) == 0, 0.0, state)
+        mixed, state = gpt.lightning_mixer(
+            u, blk, cfg, positions, state, valid, step=per_lane)
+        cache = {**cache, STATE: cache[STATE].at[at].set(state)}
+    else:
+        sizes = sparse_ops.SparseSizes.of(cfg)
+        q, k, v = gpt.sparse_rows(
+            *gpt.mixer_qkv(u, blk, cfg, SPARSE, positions))
+        new = {"k": k.astype(cache["k"].dtype), "v": v.astype(cache["v"].dtype)}
+        q_pos = jnp.broadcast_to(positions, (b, t))
+        if per_lane:
+            old_k, old_v = cache["k"][at], cache["v"][at]
+            index = sparse_ops.last_pooled_index(offset, sizes)
+            new[POOLED] = sparse_ops.pooled_key_at(
+                cache["k"], at, new["k"], offset, index, sizes)
+            pooled = _lay_rows_over(cache[POOLED][at], new[POOLED], index)
+            chosen = sparse_ops.select_blocks(q, pooled, q_pos, sizes,
+                                              cfg.kv_heads)
+            mixed, attended = sparse_ops.sparse_attend_step(
+                q, old_k, old_v, new["k"], new["v"], chosen, q_pos, sizes)
+            live = jnp.ones((b, 1), bool) if valid is None else valid
+            counted = jnp.stack([
+                jnp.sum(jnp.where(live, attended, 0.0)),
+                jnp.sum(jnp.where(live, q_pos + 1.0, 0.0))])
+            rows = new
+        else:
+            cache = {**cache, **{n: jax.lax.dynamic_update_slice(
+                cache[n], new[n][None], (at, 0, offset, 0, 0))
+                for n in ("k", "v")}}
+            big_k, big_v = cache["k"][at], cache["v"][at]
+            pooled = sparse_ops.pooled_keys(big_k, sizes)
+            cache = {**cache, POOLED: cache[POOLED].at[at].set(pooled)}
+            mixed = sparse_ops.sparse_attention_chunked(
+                q, big_k, big_v, pooled, q_pos, sizes, cfg.kv_heads)
+        mixed = gpt.mixer_out(mixed, u, blk, cfg, SPARSE)
+    return gpt.hybrid_mlp(x, mixed, blk, cfg), cache, rows, counted
+
+
+#: positions of a long chunk a hybrid layer takes at one time: a 32k prompt's
+#: MLP and decay matrices whole would be gigabytes each
+HYBRID_SEGMENT = 4096
+
+
+def _hybrid_layer_in_segments(x, params, cache, layer, offset, cfg, valid):
+    """Layer ``layer`` of a hybrid stack against the cache
+    (``_cached_hybrid_block``). A chunk longer than HYBRID_SEGMENT goes a
+    segment at a time in order, the cache carried: each segment is a chunk
+    at its own offset, which is what chunked prefill is, so the result is
+    the whole chunk's. The layer's parameters are sliced out of their stack
+    inside the loop's body: sliced outside, each would be copied whole
+    into it."""
+    b, t, d = x.shape
+    seg = HYBRID_SEGMENT
+    kind, blk, at = gpt.hybrid_layer_params(params, cfg, layer)
+    if jnp.ndim(offset) == 1 or t <= seg or t % seg:
+        return _cached_hybrid_block(x, blk, cache, kind, at, offset, cfg,
+                                    valid)
+    if valid is None:
+        valid = jnp.ones((b, t), bool)
+    in_segments = lambda a: jnp.moveaxis(
+        a.reshape(b, t // seg, seg, *a.shape[2:]), 1, 0)
+
+    def one(cache, item):
+        x_s, valid_s, start = item
+        blk = gpt.hybrid_layer_params(params, cfg, layer)[1]
+        y, cache, _, _ = _cached_hybrid_block(
+            x_s, blk, cache, kind, at, offset + start, cfg, valid_s)
+        return cache, y
+
+    cache, y = jax.lax.scan(one, cache, (
+        in_segments(x), in_segments(valid), jnp.arange(0, t, seg)))
+    return jnp.moveaxis(y, 0, 1).reshape(b, t, d), cache, None, None
+
+
+def _forward_cached_hybrid(params, x, cache: Cache, offset, cfg: GPTConfig,
+                           valid) -> Tuple[jax.Array, Cache]:
+    """The layers of a hybrid stack over embedded ``x``, reading and
+    writing the cache: ``_forward_cached_hidden``'s loop for a stack whose
+    layers differ in kind."""
+    rows, counted = [], []
+    for layer in range(cfg.n_layer):
+        x, cache, new, count = _hybrid_layer_in_segments(
+            x, params, cache, layer, offset, cfg, valid)
+        if new is not None:
+            rows.append(new)
+        if count is not None:
+            counted.append(count)
+    if rows:
+        sizes = sparse_ops.SparseSizes.of(cfg)
+        cache = _write_lane_rows(cache, rows, {
+            "k": offset, "v": offset,
+            POOLED: sparse_ops.last_pooled_index(offset, sizes)})
+    if SPARSE_ROWS in cache and counted:
+        cache = {**cache, SPARSE_ROWS: cache[SPARSE_ROWS] + sum(counted)}
+    return x, cache
+
+
 def _forward_cached_hidden(
     params: gpt.Params, tokens: jax.Array, cache: Cache, offset, cfg: GPTConfig,
     valid: Optional[jax.Array] = None,
@@ -278,7 +458,13 @@ def _forward_cached_hidden(
     if not cfg.rope:
         pos = jnp.asarray(offset)[..., None] + jnp.arange(t)  # (T,) or (B, T)
         x = x + jnp.take(params["wpe"], pos, axis=0)
+    if cfg.scale_emb != 1.0:
+        x = x * cfg.scale_emb
     x = x.astype(compute_dtype)
+
+    if cfg.mixer_types is not None:
+        x, cache = _forward_cached_hybrid(params, x, cache, offset, cfg, valid)
+        return gpt._norm(x, params["lnf_scale"], None, cfg), cache
 
     rows, counts = [], []
     n_dense = cfg.n_dense_layers
@@ -307,6 +493,8 @@ def _head_logits(params: gpt.Params, x: jax.Array, cfg: GPTConfig) -> jax.Array:
     """LM head over (B, t, D) hidden states -> (B, t, V) fp32 logits
     (with the Gemma-2 final softcap when configured)."""
     w_head = params["wte"].T if cfg.tie_weights else params["head"]
+    if cfg.dim_model_base:
+        x = (x / cfg.head_divisor).astype(x.dtype)
     logits = jnp.einsum(
         "btd,dv->btv", x, w_head.astype(x.dtype),
         preferred_element_type=jnp.float32,
@@ -325,7 +513,7 @@ _CAST_ONLY_BLOCK_LEAVES = frozenset({
     "w_fc", "b_fc", "w_proj", "b_proj",
     "w_gate", "w_up", "w_down",
     "w_e1", "w_e2", "w_eg",
-    "w_kv_a", "w_kv_b", "w_sg", "w_su", "w_sd",
+    "w_kv_a", "w_kv_b", "w_sg", "w_su", "w_sd", "w_og",
 })
 
 
@@ -344,7 +532,8 @@ def cast_once_params(
     made in the compute dtype: ``cfg.param_dtype``) the tree returned IS
     ``params``, and the device holds the weights once."""
     dtype = jnp.dtype(cfg.dtype)
-    stacks = [s for s in ("dense_blocks", "blocks") if s in params]
+    stacks = [s for s in ("dense_blocks", "blocks",
+                          *gpt.MIXER_STACKS.values()) if s in params]
     picked = {s: {n: a for n, a in params[s].items()
                   if n in _CAST_ONLY_BLOCK_LEAVES and a.dtype != dtype}
               for s in stacks}

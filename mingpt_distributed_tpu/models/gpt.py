@@ -26,18 +26,25 @@ the same skeleton.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from mingpt_distributed_tpu.config import GPTConfig
+from mingpt_distributed_tpu.config import LIGHTNING, SPARSE, GPTConfig
 from mingpt_distributed_tpu.ops import attention as attn_ops
+from mingpt_distributed_tpu.ops import lightning as lightning_ops
+from mingpt_distributed_tpu.ops import sparse_attention as sparse_ops
 from mingpt_distributed_tpu.ops import layers as L
 from mingpt_distributed_tpu.parallel.mesh import BATCH_AXES
 
 Params = Dict[str, Any]
+
+#: where a hybrid stack's parameters lie: each mixer's layers stacked along
+#: a leading axis under its own name, in the order ``cfg.mixer_layers`` gives
+MIXER_STACKS = {LIGHTNING: "lightning_blocks", SPARSE: "sparse_blocks"}
 
 
 # ---------------------------------------------------------------------------
@@ -147,11 +154,43 @@ def init(key: jax.Array, cfg: GPTConfig) -> Params:
                 blocks.update(b_fc=zeros((nl, ffn)), b_proj=zeros((nl, d)))
         return blocks
 
+    def mixer_stack(kind: str) -> Params:
+        """The layers of a hybrid stack that take mixer ``kind``, stacked:
+        the mixer's projections, qk-norm weights and output gate, and the
+        SwiGLU MLP every layer has. Norm weights are 1, also the lightning
+        mixer's output norm; the gate is drawn, or it would gate by one
+        half whatever its input."""
+        nl = len(cfg.mixer_layers(kind))
+        nh, kv, hd = cfg.mixer_heads(kind)
+        blocks: Params = {
+            "ln1_scale": ones((nl, d)), "ln2_scale": ones((nl, d)),
+            "wq": normal(next(keys), (nl, d, nh * hd)),
+            "wk": normal(next(keys), (nl, d, kv * hd)),
+            "wv": normal(next(keys), (nl, d, kv * hd)),
+            "wo": normal(next(keys), (nl, nh * hd, d), resid_std),
+            "w_gate": normal(next(keys), (nl, d, cfg.dense_width)),
+            "w_up": normal(next(keys), (nl, d, cfg.dense_width)),
+            "w_down": normal(next(keys), (nl, cfg.dense_width, d), resid_std),
+        }
+        if cfg.qk_norm:
+            blocks.update(q_norm_scale=ones((nl, hd)),
+                          k_norm_scale=ones((nl, hd)))
+        if cfg.output_gate:
+            blocks["w_og"] = normal(next(keys), (nl, d, nh * hd))
+        if kind == LIGHTNING:
+            blocks["o_norm_scale"] = ones((nl, nh * hd))
+        return blocks
+
     params: Params = {}
-    if cfg.n_dense_layers:
-        params["dense_blocks"] = stack(cfg.n_dense_layers, experts=False)
-    params["blocks"] = stack(cfg.n_layer - cfg.n_dense_layers,
-                             experts=bool(cfg.n_experts))
+    if cfg.mixer_types is not None:
+        for kind in (LIGHTNING, SPARSE):
+            if cfg.mixer_layers(kind):
+                params[MIXER_STACKS[kind]] = mixer_stack(kind)
+    else:
+        if cfg.n_dense_layers:
+            params["dense_blocks"] = stack(cfg.n_dense_layers, experts=False)
+        params["blocks"] = stack(cfg.n_layer - cfg.n_dense_layers,
+                                 experts=bool(cfg.n_experts))
     params["wte"] = normal(next(keys), (cfg.vocab_size, d))
     params["lnf_scale"] = ones((d,))
     if not cfg.rope:
@@ -337,6 +376,110 @@ def routed_and_shared(h2, blk: Params, cfg: GPTConfig, valid=None,
     return m, counts
 
 
+def hybrid_layer_params(params: Params, cfg: GPTConfig, layer: int):
+    """(the layer's mixer, its parameters sliced out of its mixer's stack,
+    its place among that mixer's layers)."""
+    kind = cfg.mixer_types[layer]
+    at = cfg.mixer_layers(kind).index(layer)
+    return kind, {n: a[at] for n, a in params[MIXER_STACKS[kind]].items()}, at
+
+
+def mixer_qkv(u, blk: Params, cfg: GPTConfig, kind: str, positions):
+    """What a hybrid layer's mixer makes of (B, T, D) normed activations
+    before it mixes: per-head q (B, T, H, hd) and k, v (B, T, KV, hd),
+    queries and keys RMS-normed per head (``qk_norm``) and, in a lightning
+    layer, rotated to ``positions`` ((T,) or (B, T)). A sparse layer's keys
+    are not rotated: normed is how it caches them."""
+    b, t, _ = u.shape
+    nh, kv, hd = cfg.mixer_heads(kind)
+    q = L.dense(u, blk["wq"]).reshape(b, t, nh, hd)
+    k = L.dense(u, blk["wk"]).reshape(b, t, kv, hd)
+    v = L.dense(u, blk["wv"]).reshape(b, t, kv, hd)
+    if cfg.qk_norm:
+        q = L.rms_norm(q, blk["q_norm_scale"], eps=cfg.norm_eps)
+        k = L.rms_norm(k, blk["k_norm_scale"], eps=cfg.norm_eps)
+    if kind == LIGHTNING:
+        cos, sin = attn_ops.rope_tables(positions, hd, cfg.rope_theta)
+        q = attn_ops.apply_rope(q, cos, sin)
+        k = attn_ops.apply_rope(k, cos, sin)
+    return q, k, v
+
+
+def sparse_rows(q, k, v):
+    """A sparse layer's per-head q, k, v as ``ops/sparse_attention`` takes
+    them and the cache keeps them: keys and values with their KV heads side
+    by side, (B, T, 1, KV * hd), and the queries spread to that width."""
+    b, t, kv, hd = k.shape
+    return (sparse_ops.spread_queries(q, kv), k.reshape(b, t, 1, kv * hd),
+            v.reshape(b, t, 1, kv * hd))
+
+
+def mixer_out(mixed, u, blk: Params, cfg: GPTConfig, kind: str):
+    """A mixer's (B, T, H, hd) output to the residual stream's width: the
+    lightning mixer's output norm, the gate ``sigmoid(W_g u)``, then W_o."""
+    b, t = mixed.shape[:2]
+    mixed = mixed.reshape(b, t, -1).astype(u.dtype)
+    if kind == LIGHTNING:
+        mixed = L.rms_norm(mixed, blk["o_norm_scale"], eps=cfg.norm_eps)
+    if cfg.output_gate:
+        gate = jnp.dot(u, blk["w_og"].astype(u.dtype),
+                       preferred_element_type=jnp.float32)
+        mixed = (jax.nn.sigmoid(gate) * mixed).astype(u.dtype)
+    return L.dense(mixed, blk["wo"])
+
+
+def lightning_mixer(u, blk: Params, cfg: GPTConfig, positions, state,
+                    valid=None, step: bool = False):
+    """A lightning layer's mixer over (B, T, D) normed activations from
+    ``state`` (B, H, hd, hd) float32: (the mixer's output (B, T, D), the
+    state after the last valid token). ``step``: one position a lane, the
+    recurrence as written; else the chunked scan."""
+    q, k, v = mixer_qkv(u, blk, cfg, LIGHTNING, positions)
+    run = lightning_ops.lightning_step if step else lightning_ops.lightning_scan
+    mixed, state = run(q, k, v, state, lightning_ops.slopes(cfg.lightning_heads),
+                       cfg.lightning_head_dim ** -0.5, valid)
+    return mixer_out(mixed, u, blk, cfg, LIGHTNING), state
+
+
+def zero_lightning_state(cfg: GPTConfig, batch: int) -> jax.Array:
+    return jnp.zeros((batch, cfg.lightning_heads, cfg.lightning_head_dim,
+                      cfg.lightning_head_dim), jnp.float32)
+
+
+def hybrid_mlp(x, mixed, blk: Params, cfg: GPTConfig):
+    """The rest of a hybrid layer: the mixer's branch and the SwiGLU MLP's
+    onto the residual stream, each times ``cfg.residual_scale``."""
+    scale = cfg.residual_scale
+    x = x + (scale * mixed).astype(x.dtype)
+    h2 = L.rms_norm(x, blk["ln2_scale"], eps=cfg.norm_eps)
+    m = L.mlp_swiglu(h2, blk["w_gate"], blk["w_up"], blk["w_down"])
+    return x + (scale * m).astype(x.dtype)
+
+
+def _hybrid_block(x, blk: Params, cfg: GPTConfig, kind: str) -> jax.Array:
+    """One layer of a hybrid stack over a whole sequence from its start,
+    nothing cached: the form training and the uncached forward take, and
+    the one the cached forms are held to."""
+    b, t, _ = x.shape
+    u = L.rms_norm(x, blk["ln1_scale"], eps=cfg.norm_eps)
+    positions = jnp.arange(t)
+    if kind == LIGHTNING:
+        mixed, _ = lightning_mixer(u, blk, cfg, positions,
+                                   zero_lightning_state(cfg, b))
+    else:
+        sizes = sparse_ops.SparseSizes.of(cfg)
+        q, k, v = sparse_rows(*mixer_qkv(u, blk, cfg, SPARSE, positions))
+        pad = ((0, 0), (0, -t % sizes.block), (0, 0), (0, 0))
+        k, v = jnp.pad(k, pad), jnp.pad(v, pad)   # whole blocks of rows
+        q_pos = jnp.broadcast_to(positions, (b, t))
+        chosen = sparse_ops.select_blocks(
+            q, sparse_ops.pooled_keys(k, sizes), q_pos, sizes, cfg.kv_heads)
+        mixed = mixer_out(
+            sparse_ops.sparse_attend(q, k, v, chosen, q_pos, sizes)[0],
+            u, blk, cfg, SPARSE)
+    return hybrid_mlp(x, mixed, blk, cfg)
+
+
 def _block(
     x: jax.Array,
     blk: Params,
@@ -481,7 +624,25 @@ def forward(
     else:
         rng, emb_key = jax.random.split(rng)
     x = L.dropout(x, cfg.embd_pdrop, emb_key, deterministic)
+    if cfg.scale_emb != 1.0:
+        x = x * cfg.scale_emb
     x = x.astype(compute_dtype)
+
+    if cfg.mixer_types is not None:
+        if mesh is not None and mesh.shape.get("pp", 1) > 1:
+            raise NotImplementedError(
+                "pipeline stages split one stack of like layers: a hybrid "
+                "stack (mixer_types) has one kind a layer")
+        if not deterministic and (cfg.resid_pdrop or cfg.attn_pdrop):
+            raise NotImplementedError(
+                "a hybrid stack's layers are written without dropout: set "
+                "resid_pdrop and attn_pdrop to 0")
+        for layer in range(cfg.n_layer):
+            kind, blk, _ = hybrid_layer_params(params, cfg, layer)
+            step = functools.partial(_hybrid_block, cfg=cfg, kind=kind)
+            x = (jax.checkpoint(step) if cfg.remat else step)(x, blk)
+        return _head_and_loss(params, x, cfg, targets, return_logits,
+                              jnp.zeros((), jnp.float32))
 
     rope = None
     if cfg.rope:
@@ -684,8 +845,17 @@ def forward(
         if n_dense:
             carry = run_stack(carry, xs_dense, n_dense)
         x, moe_aux = run_stack(carry, xs, nl - n_dense)
+    return _head_and_loss(params, x, cfg, targets, return_logits, moe_aux)
 
+
+def _head_and_loss(params: Params, x, cfg: GPTConfig, targets,
+                   return_logits: bool, moe_aux):
+    """The final norm, the LM head and the loss of ``forward``: (logits or
+    None, loss or None)."""
+    t, nl = x.shape[1], cfg.n_layer
     x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg)
+    if cfg.dim_model_base:
+        x = (x / cfg.head_divisor).astype(x.dtype)
     w_head = params["wte"].T if cfg.tie_weights else params["head"]
     # snap the chunk count to the largest divisor of T <= loss_chunks, so an
     # awkward block_size degrades to fewer/larger chunks, not silently to

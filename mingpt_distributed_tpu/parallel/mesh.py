@@ -197,6 +197,13 @@ PARAM_RULES: dict[str, P] = {
     "w_kv_a": P("pp", "fsdp"),
     "kv_norm_scale": P("pp"),
     "w_kv_b": P("pp", "fsdp", "tp"),
+    # a hybrid stack's mixers (models/gpt.py MIXER_STACKS): the output
+    # gate's columns are the heads', as wq's; the per-head qk-norm weights
+    # and the linear mixer's output norm stay whole
+    "w_og": P("pp", "fsdp", "tp"),
+    "q_norm_scale": P("pp"),
+    "k_norm_scale": P("pp"),
+    "o_norm_scale": P("pp"),
 }
 
 

@@ -141,18 +141,19 @@ def kv_pool_spec(tp_axis: str = "tp"):
 
 
 def _kv_leaves(cache):
-    """Names of the leaves that hold cached rows, sorted: every leaf but
-    the routed-rows counter (``generate.MOE_ROWS``), which rides in a
-    routed model's pool and is no (L, S, block, heads, size) buffer."""
-    return sorted(n for n in cache if n != gen.MOE_ROWS)
+    """Names of the leaves that hold something of a slot, sorted: cached
+    rows and, in a hybrid stack's pool, pooled keys and the linear layers'
+    state, each with the slot as its second axis. Every leaf but the
+    counters (``generate.COUNTERS``), which ride in a pool and are no (L, S,
+    ...) buffer."""
+    return sorted(n for n in cache if n not in gen.COUNTERS)
 
 
 def _with_counter(kv, cache):
-    """``kv`` (leaves of cached rows) with the counter of ``cache``, if it
-    has one: what a program hands back for a cache it was handed."""
-    if gen.MOE_ROWS in cache:
-        return {**kv, gen.MOE_ROWS: cache[gen.MOE_ROWS]}
-    return kv
+    """``kv`` (leaves of a slot's or a pool's rows and state) with the
+    counters of ``cache``, where it has any: what a program hands back for
+    a cache it was handed."""
+    return {**kv, **{n: cache[n] for n in gen.COUNTERS if n in cache}}
 
 
 def _pin_kv(cache, kv_sharding):
@@ -495,6 +496,17 @@ class DecodeEngine:
                 f"tp={tp}, and kv_dtype={self.kv_dtype!r} would scale the "
                 "rope key and the latent, which differ in size and range, "
                 "by one rule made for per-head rows")
+        if cfg.mixer_types is not None and (
+                self.kv_quant is not None or mesh is not None
+                or prefix_cache_mb > 0):
+            raise ConfigError(
+                "a hybrid stack (mixer_types) is served on one device with "
+                "an unquantized pool and no prefix store: no rule splits a "
+                "state's heads or the block selection over a mesh, "
+                f"kv_dtype={self.kv_dtype!r} has no scale for a state or a "
+                "pooled key, and a stored prefix would have to carry the "
+                "state at its last row, which rows copied up to a bucket "
+                "do not")
         if mesh is not None:
             # One placement decision, made once: params follow the megatron
             # column/row rules, the pool shards heads over the tp axis (or
@@ -525,6 +537,14 @@ class DecodeEngine:
                     f"prefill_chunk {prefill_chunk} outside [1, "
                     f"{self.prefill_len}]"
                 )
+        if cfg.mixer_types is not None and prefill_chunk is not None \
+                and self.prefill_len + prefill_chunk > cfg.block_size:
+            raise ConfigError(
+                "a hybrid stack's state takes every token once: a last "
+                "chunk shifted back to stay inside the window would "
+                f"re-prefill its overlap, so prefill_len {self.prefill_len}"
+                f" + prefill_chunk {prefill_chunk} must not pass block_size "
+                f"{cfg.block_size}")
         self.prefill_chunk = prefill_chunk
         self.buckets = bucket_ladder(
             self.prefill_len, prefill_buckets, prefill_chunk)
@@ -576,17 +596,36 @@ class DecodeEngine:
 
     @property
     def kv_bytes_per_row(self) -> int:
-        """Bytes one cached token costs in the pool, all layers and leaves
-        (a quantized pool's scale planes too)."""
-        return sum(a.nbytes // (a.shape[1] * a.shape[2])
-                   for n, a in self.pool.cache.items() if n != gen.MOE_ROWS)
+        """Bytes one cached token costs in the pool, all layers and row
+        leaves (a quantized pool's scale planes and a hybrid stack's pooled
+        keys too, each over the positions a slot holds; its state, which
+        costs a slot the same whatever it holds, is
+        ``state_bytes_per_slot``)."""
+        return sum(a.nbytes // (a.shape[1] * self.cfg.block_size)
+                   for n, a in self.pool.cache.items()
+                   if n not in gen.COUNTERS and n != gen.STATE)
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of state a slot holds beside its rows (a hybrid stack's
+        linear layers); 0 where every layer keeps rows."""
+        state = self.pool.cache.get(gen.STATE)
+        return 0 if state is None else state.nbytes // state.shape[1]
+
+    def _counter(self, name: str) -> Optional[np.ndarray]:
+        counter = self.pool.cache.get(name)
+        return None if counter is None else np.asarray(jax.device_get(counter))
 
     def moe_rows(self) -> Optional[np.ndarray]:
         """The routed-rows counter as it stands, fetched from the device:
         (expert layers, E + 1), or None where the model counts none. The
         one transfer the counter ever costs; no round makes it."""
-        counter = self.pool.cache.get(gen.MOE_ROWS)
-        return None if counter is None else np.asarray(jax.device_get(counter))
+        return self._counter(gen.MOE_ROWS)
+
+    def sparse_rows(self) -> Optional[np.ndarray]:
+        """``generate.SPARSE_ROWS`` as it stands, fetched likewise: (2,)
+        [rows attended, rows at or before the query], or None."""
+        return self._counter(gen.SPARSE_ROWS)
 
     @property
     def chunk_size(self) -> int:
@@ -693,6 +732,10 @@ class DecodeEngine:
         short of the prompt (a hit on the peer must leave >= 1 tail token
         to prefill). Collapses to ``quantized_prefix_len`` for a slot
         that finished prefilling. 0 = nothing shippable."""
+        if self.cfg.mixer_types is not None:
+            # a state cannot be cut at a bucket, and pooled keys lie on
+            # another grid than rows: the peer prefills anew
+            return 0
         cap = min(frontier, prompt_len - 1)
         best = 0
         for b in self.buckets:
@@ -717,6 +760,10 @@ class DecodeEngine:
         row-copy program family ``save_prefix`` uses. ``rows`` must sit
         on the bucket ladder so this never grows the bounded prefix-copy
         family past one trace per bucket."""
+        if self.cfg.mixer_types is not None:
+            raise ValueError(
+                "a slot of a hybrid stack is rows and a state: its leading "
+                "rows alone are no request (migratable_rows is 0)")
         if rows not in self.buckets:
             raise ValueError(
                 f"extract rows {rows} not on the bucket ladder "
@@ -780,10 +827,12 @@ class DecodeEngine:
                         self.pool.cache, np.int32(0), rows=b)
                     self.pool.cache = self._install_jit(
                         self.pool.cache, lane, np.int32(0))
-        if gen.MOE_ROWS in self.pool.cache:
-            # the warm-up's prompts (one token repeated) are no traffic:
-            # the routed rows are counted from here on
-            self.pool.cache[gen.MOE_ROWS] = gen.init_moe_rows(self.cfg)
+        # the warm-up's prompts (one token repeated) are no traffic: the
+        # device's counters count from here on
+        for name, fresh in ((gen.MOE_ROWS, gen.init_moe_rows),
+                            (gen.SPARSE_ROWS, gen.init_sparse_rows)):
+            if name in self.pool.cache:
+                self.pool.cache[name] = fresh(self.cfg)
 
     def decode_step(
         self,

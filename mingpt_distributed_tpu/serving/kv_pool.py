@@ -7,9 +7,17 @@ One fixed pair of ``(n_layer, n_slots, block_size, heads, size)`` buffers —
 values of ``head_dim``; a latent (MLA) model keeps one rotated rope key
 (``"k"``) and one normed latent (``"v"``) a token, which differ in size.
 Everything here and in the engine's programs works leaf by leaf and asks
-no leaf for another's shape. A model that counts its routed rows
-(``generate.MOE_ROWS``) carries that counter in the same donated tree; it
-is no buffer of rows and the row programs pass it through.
+no leaf for another's shape. A hybrid stack (``GPTConfig.mixer_types``)
+keeps two kinds of thing a slot: rows of its sparse layers alone (``"k"``,
+``"v"`` and their pooled keys, ``generate.POOLED``) and a float32 state of
+its linear layers (``generate.STATE``: ``(layers, n_slots, heads, size,
+size)``, no position axis). No mask hides a stale state as position hides a
+stale row, so a slot's state is started from zero by the program that
+prefills a sequence's first chunk (``generate._cached_hybrid_block``):
+allocate and free stay host-side and clear nothing. A model that counts on
+the device (``generate.COUNTERS``: routed rows, the sparse layers' attended
+rows) carries the counter in the same donated tree; it is no buffer of rows
+and the row programs pass it through.
 Each slot holds one in-flight request's
 cache; a request is admitted by prefilling its prompt into a free slot
 and retired by returning the slot to the free list. Stale K/V from a
@@ -49,7 +57,8 @@ from typing import List, Optional, Tuple
 
 from mingpt_distributed_tpu.config import GPTConfig
 from mingpt_distributed_tpu.models.generate import (
-    MOE_ROWS, Cache, init_cache, init_moe_rows)
+    COUNTERS, MOE_ROWS, SPARSE_ROWS, STATE, Cache, init_cache, init_moe_rows,
+    init_sparse_rows)
 from mingpt_distributed_tpu.serving import quant as quant_lib
 
 
@@ -86,9 +95,10 @@ class SlotKVPool:
             # on sharding equality — an unnormalized spec here would make
             # the first serving call on a warmed bucket look novel
             sharding = cache["k"].sharding
-        moe_rows = init_moe_rows(cfg)
-        if moe_rows is not None:
-            cache[MOE_ROWS] = moe_rows
+        for name, counter in ((MOE_ROWS, init_moe_rows(cfg)),
+                              (SPARSE_ROWS, init_sparse_rows(cfg))):
+            if counter is not None:
+                cache[name] = counter
         self.sharding = sharding
         self.cache = cache
         self._free: List[int] = list(range(n_slots))  # kept sorted
@@ -107,16 +117,21 @@ class SlotKVPool:
     def audit_facts(self) -> dict:
         """Static facts graftaudit checks pool-touching programs against
         (plain dict so serving never imports the analysis layer):
-        ``cache_leaf_shapes`` is each row buffer's shape, by name;
-        ``cache_leaf_elems`` the element count of the smallest of them —
+        ``cache_leaf_shapes`` is each row buffer's shape, by name (a
+        hybrid stack's pooled keys are rows of a coarser grid);
+        ``state_leaf_shapes`` the leaves that hold a state a slot and have
+        no position axis (a hybrid stack's linear layers), by name;
+        ``cache_leaf_elems`` the element count of the smallest row buffer —
         any collective whose result is at least that large is moving the
         pool itself, not a per-token activation; ``cache_sharding`` is the
         runtime-normalized NamedSharding every compiled program must
         return the cache under (None on a single device)."""
         shapes = {n: tuple(a.shape) for n, a in self.cache.items()
-                  if n != MOE_ROWS}
+                  if n not in COUNTERS and n != STATE}
         return {
             "cache_leaf_shapes": shapes,
+            "state_leaf_shapes": {n: tuple(a.shape)
+                                  for n, a in self.cache.items() if n == STATE},
             "cache_leaf_elems": min(map(math.prod, shapes.values())),
             "cache_sharding": self.sharding,
             "shard_count": self.shard_count,

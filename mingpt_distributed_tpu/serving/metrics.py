@@ -200,8 +200,12 @@ class ServingMetrics:
         self._kv_bytes_per_row = r.gauge(
             "mingpt_serve_kv_bytes_per_row",
             help="bytes one cached token costs in the pool, all layers")
-        # a routed model's device-side counter, read only by summary()
+        self._state_bytes_per_slot = r.gauge(
+            "mingpt_serve_state_bytes_per_slot",
+            help="bytes of recurrent state a slot holds beside its rows")
+        # the device-side counters, read only by summary()
         self._moe_rows_source: Optional[Callable[[], Any]] = None
+        self._sparse_rows_source: Optional[Callable[[], Any]] = None
         self._util_sum = 0.0
         self._prefill_rate = RateWindow()
         self._prefill_tokens_per_sec: Optional[float] = None
@@ -485,15 +489,33 @@ class ServingMetrics:
     def engine_built(self, program_weight_bytes: int,
                      program_weights_cast: int, kv_bytes_per_row: int = 0,
                      moe_rows_source: Optional[Callable[[], Any]] = None,
+                     state_bytes_per_slot: int = 0,
+                     sparse_rows_source: Optional[Callable[[], Any]] = None,
                      ) -> None:
-        """What the engine's programs read and what a cached token costs,
-        known once it is built. ``moe_rows_source`` fetches a routed
-        model's (expert layers, E + 1) counter of routed rows from the
-        device (``DecodeEngine.moe_rows``); only ``summary()`` calls it."""
+        """What the engine's programs read and what a cached token and a
+        slot's state cost, known once it is built. ``moe_rows_source``
+        fetches a routed model's (expert layers, E + 1) counter of routed
+        rows from the device (``DecodeEngine.moe_rows``) and
+        ``sparse_rows_source`` a hybrid stack's (2,) counter of the rows its
+        sparse layers' decode steps attended (``DecodeEngine.sparse_rows``);
+        only ``summary()`` calls them."""
         self._program_weight_bytes.set(program_weight_bytes)
         self._program_weights_cast.set(program_weights_cast)
         self._kv_bytes_per_row.set(kv_bytes_per_row)
+        self._state_bytes_per_slot.set(state_bytes_per_slot)
         self._moe_rows_source = moe_rows_source
+        self._sparse_rows_source = sparse_rows_source
+
+    def _sparse_summary(self) -> Dict[str, Any]:
+        """The sparse layers' decode steps since the server was built: the
+        rows they attended and the rows at or before their queries, summed
+        over sparse layers and live lanes (a layer's KV heads averaged).
+        None where no layer selects."""
+        rows = self._sparse_rows_source() if self._sparse_rows_source else None
+        if rows is None:
+            return {"sparse_rows_attended": None, "sparse_rows_live": None}
+        return {"sparse_rows_attended": float(rows[0]),
+                "sparse_rows_live": float(rows[1])}
 
     def _moe_summary(self) -> Dict[str, Any]:
         """The routed-rows counter since the server was built: rows the
@@ -520,7 +542,9 @@ class ServingMetrics:
             "program_weight_bytes": int(self._program_weight_bytes.value),
             "program_weights_cast": int(self._program_weights_cast.value),
             "kv_bytes_per_row": int(self._kv_bytes_per_row.value),
+            "state_bytes_per_slot": int(self._state_bytes_per_slot.value),
             **self._moe_summary(),
+            **self._sparse_summary(),
             "requests_submitted": self.requests_submitted,
             "requests_completed": self.requests_completed,
             "requests_rejected": self.requests_rejected,

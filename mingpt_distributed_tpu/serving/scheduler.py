@@ -87,7 +87,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from mingpt_distributed_tpu.config import GPTConfig
+from mingpt_distributed_tpu.config import ConfigError, GPTConfig
 from mingpt_distributed_tpu.serving.admission import AdmissionPolicy, FifoPolicy
 from mingpt_distributed_tpu.serving.engine import DecodeEngine, sampler_orders
 from mingpt_distributed_tpu.serving.metrics import ServingMetrics
@@ -301,6 +301,11 @@ class InferenceServer:
         if draft_params is not None:
             if draft_cfg is None:
                 raise ValueError("draft_params given without draft_cfg")
+            if cfg.mixer_types is not None or draft_cfg.mixer_types is not None:
+                raise ConfigError(
+                    "speculation rolls rejected tokens back by position, and "
+                    "a hybrid stack's state has none: neither the target "
+                    "nor the draft may set mixer_types")
             if spec_k < 1:
                 raise ValueError(
                     "draft model given but spec_k < 1: pass spec_k >= 1 "
@@ -321,7 +326,8 @@ class InferenceServer:
             n_slots, log_every=log_every, registry=registry)
         self.metrics.engine_built(
             self.engine.program_param_bytes, self.engine.n_cast_leaves,
-            self.engine.kv_bytes_per_row, self.engine.moe_rows)
+            self.engine.kv_bytes_per_row, self.engine.moe_rows,
+            self.engine.state_bytes_per_slot, self.engine.sparse_rows)
         # post-warmup recompile watchdog over the compiled program families
         # (the merged server-level counts, so draft/verify traces are
         # watched too; armed after warmup(); checked every round)

@@ -38,7 +38,8 @@ _DECAY_NAMES = frozenset(
     {"wq", "wk", "wv", "wo", "w_fc", "w_proj", "w_gate", "w_up", "w_down",
      "head", "w_router", "w_e1", "w_e2", "w_eg",  # MoE router/experts are matmuls
      "w_sg", "w_su", "w_sd",  # the shared expert
-     "w_kv_a", "w_kv_b"}  # latent attention's down- and up-projections
+     "w_kv_a", "w_kv_b",  # latent attention's down- and up-projections
+     "w_og"}  # a hybrid stack's output gate
 )
 _NO_DECAY_NAMES = frozenset(
     {
@@ -46,6 +47,7 @@ _NO_DECAY_NAMES = frozenset(
         "bq", "bk", "bv", "bo", "b_fc", "b_proj",  # biases
         "ln1_scale", "ln1_bias", "ln2_scale", "ln2_bias",
         "lnf_scale", "lnf_bias", "kv_norm_scale",
+        "q_norm_scale", "k_norm_scale", "o_norm_scale",  # a hybrid stack's
         "e_bias",  # the router's choice bias: a bias
     }
 )

@@ -56,6 +56,16 @@ ARCHS = {
                            n_experts=8, moe_top_k=2, moe_ffn_dim=16,
                            n_shared_experts=2, moe_scoring="sigmoid",
                            moe_route_scale=2.448),
+    # benchmarks/configs/minicpm-sala.json, cut further than its "tiny": a
+    # linear layer's state under a sparse layer's rows, gated and scaled
+    "hybrid": dict(LLAMA, tie_weights=False,
+                   mixer_types=("lightning-attn", "minicpm4"),
+                   lightning_heads=4, lightning_head_dim=8, qk_norm=True,
+                   output_gate=True, scale_emb=12.0, scale_depth=1.4,
+                   scale_depth_layers=32, dim_model_base=8,
+                   sparse_kernel_size=4, sparse_kernel_stride=2,
+                   sparse_block_size=8, sparse_topk=2, sparse_window=8,
+                   sparse_dense_len=16),
 }
 
 
@@ -132,8 +142,10 @@ def test_programs_read_a_tree_cast_once(arch, dtype, tp):
     if tp > 1:
         mesh = mesh_lib.make_mesh(MeshConfig(tp=tp),
                                   devices=jax.devices()[:tp])
-        if cfg.kv_lora_rank:
-            with pytest.raises(ConfigError, match="latent"):
+        refused = "latent" if cfg.kv_lora_rank else \
+            "hybrid stack" if cfg.mixer_types else None
+        if refused:
+            with pytest.raises(ConfigError, match=refused):
                 DecodeEngine(params, cfg, n_slots=SLOTS, mesh=mesh)
             return
     engine = DecodeEngine(params, cfg, n_slots=SLOTS, prefill_len=16,

@@ -1,0 +1,529 @@
+"""MiniCPM-SALA on the normal path, at a tiny size on the CPU: a hybrid stack
+(``GPTConfig.mixer_types``: lightning linear attention beside InfLLM-v2
+block-sparse attention) against the plain reference
+``benchmarks/references/minicpm_sala.py``, through ``gpt.forward``, the
+cached forward, ``DecodeEngine`` and ``InferenceServer``, and the benchmark's
+cell through the path the driver runs."""
+
+import dataclasses
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import rehearse
+from benchmarks.harness import check, compiles, serve_cell, spec
+from mingpt_distributed_tpu.config import (
+    LIGHTNING, MODEL_PRESETS, SPARSE, ConfigError, GPTConfig)
+from mingpt_distributed_tpu.models import generate as gen
+from mingpt_distributed_tpu.models import gpt
+from mingpt_distributed_tpu.ops import lightning as lightning_ops
+from mingpt_distributed_tpu.ops import sparse_attention as sparse_ops
+from mingpt_distributed_tpu.serving import InferenceServer, Request
+from mingpt_distributed_tpu.serving.engine import DecodeEngine
+
+CELL = "minicpm-sala.serve-long-context"
+TINY = "minicpm-sala-tiny"
+SEED = 2_500_000_001        # past 32 signed bits, as the driver's seeds are
+
+
+def tiny_cfg(**over) -> GPTConfig:
+    return GPTConfig.make(**{**MODEL_PRESETS[TINY], **over})
+
+
+def sizes_of(cfg: GPTConfig) -> dict:
+    """What the reference reads of a configuration file, from the program's
+    config: the cell's own ``key_map``, applied as ``rehearse.tiny`` does."""
+    key_map = spec.load_cell(CELL).config["program"]["key_map"]
+    return {published: getattr(cfg, field)
+            for published, field in key_map.items()}
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return spec.load_reference(spec.load_cell(CELL).config)
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = tiny_cfg()
+    return cfg, gpt.init(jax.random.key(3), cfg)
+
+
+def tokens_of(cfg, batch, t, seed=1):
+    return jax.random.randint(jax.random.key(seed), (batch, t), 0,
+                              cfg.vocab_size)
+
+
+# -- the program against the reference, float32 ------------------------------
+
+def test_the_full_forward_is_the_reference_s(reference, model):
+    cfg, params = model
+    toks = tokens_of(cfg, 2, 112)
+    logits, loss = gpt.forward(params, toks, cfg, targets=toks)
+    w = reference.weights_from_program(params)
+    x, ks, vs = reference.hidden(w, toks, sizes_of(cfg))
+    ref_logits = reference.logits(w, x)
+    assert ks.shape == vs.shape == (2, 2, 112, 1, cfg.kv_heads * cfg.head_dim)
+    np.testing.assert_allclose(logits, ref_logits, atol=2e-6)
+    np.testing.assert_allclose(
+        loss, reference.loss(w, toks, toks, sizes_of(cfg)), atol=1e-5)
+
+
+@pytest.mark.parametrize("n_prompt, steps", [(40, 24), (96, 16)])
+def test_prefill_then_decode_through_the_cache_is_the_full_forward(
+        reference, model, n_prompt, steps):
+    """Logits, the sparse layers' rows and the linear layers' state after
+    every decode step, against the reference's forward over the whole
+    sequence. From 96 the decode steps select (``dense_len`` 48 of the tiny
+    preset); from 40 they cross it."""
+    cfg, params = model
+    n = n_prompt + steps   # whole blocks: the reference pads no recurrence
+    toks = tokens_of(cfg, 2, n)
+    w = reference.weights_from_program(params)
+    x, ks, vs = reference.hidden(w, toks, sizes_of(cfg))
+    ref_logits = reference.logits(w, x)
+    cache = gen.init_cache(cfg, 2)
+    assert cache[gen.STATE].dtype == jnp.float32
+    logits, cache = gen._forward_cached(params, toks[:, :n_prompt], cache, 0,
+                                        cfg)
+    np.testing.assert_allclose(logits, ref_logits[:, n_prompt - 1], atol=2e-6)
+    for i in range(n_prompt, n):
+        logits, cache = gen._forward_cached(
+            params, toks[:, i:i + 1], cache, jnp.full((2,), i), cfg)
+        np.testing.assert_allclose(logits, ref_logits[:, i], atol=2e-6)
+    np.testing.assert_allclose(cache["k"][:, :, :n], ks, atol=1e-5)
+    np.testing.assert_allclose(cache["v"][:, :, :n], vs, atol=1e-5)
+    np.testing.assert_allclose(
+        cache[gen.STATE], reference.states(w, toks, sizes_of(cfg)),
+        rtol=1e-4, atol=1e-4)
+
+
+def test_solo_generate_takes_the_hybrid_stack(model):
+    cfg, params = model
+    prompt = tokens_of(cfg, 1, 20)
+    out = gen.generate(params, cfg, prompt, 30)
+    # greedy: each new token is the full forward's argmax at its position
+    logits, _ = gpt.forward(params, out[:, :-1], cfg)
+    np.testing.assert_array_equal(out[0, 20:], logits[0, 19:].argmax(-1))
+
+
+# -- the ops ------------------------------------------------------------------
+
+def test_the_chunked_scan_is_the_recurrence_and_skips_what_is_not_valid():
+    b, t, h, d = 2, 300, 3, 8
+    keys = jax.random.split(jax.random.key(0), 4)
+    q, k, v = (jax.random.normal(kk, (b, t, h, d)) for kk in keys[:3])
+    state = jax.random.normal(keys[3], (b, h, d, d))
+    slope = lightning_ops.slopes(h)
+    valid = jnp.arange(t)[None] < jnp.asarray([300, 170])[:, None]
+    out, end = lightning_ops.lightning_scan(q, k, v, state, slope, 0.5, valid)
+    s = state
+    for i in range(t):
+        o, s = lightning_ops.lightning_step(
+            q[:, i:i + 1], k[:, i:i + 1], v[:, i:i + 1], s, slope, 0.5,
+            valid[:, i:i + 1])
+        live = np.asarray(valid[:, i])
+        np.testing.assert_allclose(out[live, i], o[live, 0], atol=2e-4)
+    np.testing.assert_allclose(end, s, atol=2e-4)
+    # the padded lane's state is the state after its 170 real tokens
+    _, short = lightning_ops.lightning_scan(
+        q[1:, :170], k[1:, :170], v[1:, :170], state[1:], slope, 0.5)
+    np.testing.assert_allclose(end[1:], short, atol=2e-4)
+
+
+def test_the_selection_forces_the_first_block_and_the_window():
+    """Past ``dense_len`` a query attends ``topk`` blocks: block 0, the
+    blocks over its last ``window`` positions, and the best others; below
+    it, every block that has started."""
+    sizes = sparse_ops.SparseSizes(kernel=8, stride=4, block=16, topk=4,
+                                   window=16, init_blocks=1, dense_len=48)
+    b, s, h, kv, d = 1, 128, 4, 2, 16
+    kq, kk = jax.random.split(jax.random.key(5))
+    q = sparse_ops.spread_queries(jax.random.normal(kq, (b, s, h, d)), kv)
+    k = jax.random.normal(kk, (b, s, 1, kv * d))
+    pos = jnp.arange(s)[None]
+    chosen = np.asarray(sparse_ops.select_blocks(
+        q, sparse_ops.pooled_keys(k, sizes), pos, sizes, kv))
+    for t in (0, 20, 47):
+        assert chosen[0, t].sum(-1).tolist() == [t // 16 + 1] * kv
+    for t in (48, 79, 100, 127):
+        row = chosen[0, t]
+        assert row.sum(-1).tolist() == [4, 4]
+        assert row[:, 0].all() and row[:, t // 16].all()
+        assert row[:, (t - 15) // 16].all()
+        assert not row[:, t // 16 + 1:].any()
+    # the two KV heads choose for themselves
+    assert (chosen[0, 127, 0] != chosen[0, 127, 1]).any()
+    # and the chosen rows are all a query attends
+    v = jax.random.normal(kk, (b, s, 1, kv * d))
+    _, attended = sparse_ops.sparse_attend(q, k, v, jnp.asarray(chosen), pos,
+                                           sizes)
+    assert float(attended[0, 40]) == 41.0
+    assert float(attended[0, 127]) == 16 * 3 + 127 % 16 + 1
+
+
+def test_the_chunked_sparse_prefill_is_the_one_pass(monkeypatch):
+    sizes = sparse_ops.SparseSizes(kernel=8, stride=4, block=16, topk=4,
+                                   window=16, init_blocks=1, dense_len=48)
+    b, t, s, h, kv, d = 1, 64, 128, 4, 2, 16
+    keys = jax.random.split(jax.random.key(6), 3)
+    q = sparse_ops.spread_queries(jax.random.normal(keys[0], (b, t, h, d)),
+                                  kv)
+    k = jax.random.normal(keys[1], (b, s, 1, kv * d))
+    v = jax.random.normal(keys[2], (b, s, 1, kv * d))
+    pos = 32 + jnp.arange(t)[None]              # a chunk at offset 32
+    pooled = sparse_ops.pooled_keys(k, sizes)
+    whole = sparse_ops.sparse_attend(
+        q, k, v, sparse_ops.select_blocks(q, pooled, pos, sizes, kv), pos,
+        sizes)[0]
+    monkeypatch.setattr(sparse_ops, "QUERY_CHUNK", 16)
+    monkeypatch.setattr(sparse_ops, "KEY_CHUNK", 32)
+    chunked = sparse_ops.sparse_attention_chunked(q, k, v, pooled, pos, sizes,
+                                                  kv)
+    np.testing.assert_allclose(chunked, whole, atol=2e-6)
+
+
+# -- the engine and the server ------------------------------------------------
+
+def decode_alone(eng, slot, token, position):
+    s = eng.n_slots
+    tokens = np.zeros(s, np.int32)
+    positions = np.full(s, eng.cfg.block_size - 1, np.int32)
+    tokens[slot], positions[slot] = token, position
+    return int(eng.decode_step(
+        tokens, positions, np.ones(s, np.float32), np.zeros(s, np.int32),
+        np.ones(s, np.float32), np.zeros(s, bool), np.zeros(s, np.uint32))[
+            slot])
+
+
+def served_tokens(eng, slot, prompt, steps):
+    """Prefill ``prompt`` chunk by chunk as the scheduler does, then decode
+    ``steps`` tokens greedily; the other lanes parked."""
+    pos, tok = 0, None
+    while pos < len(prompt):
+        take = min(len(prompt) - pos, eng.chunk_size)
+        tok, _ = eng.prefill_chunk_call(
+            slot, prompt[pos:pos + take], pos, 1.0, None, None, False, 0)
+        pos += take
+    out = [tok]
+    for i in range(steps):
+        out.append(decode_alone(eng, slot, out[-1], len(prompt) + i))
+    return out
+
+
+def test_chunked_prefill_carries_the_state_and_slots_start_from_zero(model):
+    """Whole-prompt and chunked prefill give the same tokens, rows and
+    state; a lane parked between another's chunks keeps its state; a slot
+    that held another request starts a new one from a zero state."""
+    cfg, params = model
+    prompt = tokens_of(cfg, 1, 90, seed=7)[0].tolist()
+    other = tokens_of(cfg, 1, 50, seed=8)[0].tolist()
+    whole = DecodeEngine(params, cfg, n_slots=2, prefill_len=96,
+                         prefill_buckets=[32, 64, 96])
+    chunked = DecodeEngine(params, cfg, n_slots=2, prefill_len=96,
+                           prefill_chunk=32)
+    # slot 0 first holds another request: its state and rows are stale
+    assert whole.pool.allocate() == 0 and chunked.pool.allocate() == 0
+    served_tokens(whole, 0, other, 6)
+    served_tokens(chunked, 0, other, 6)
+    assert float(jnp.abs(whole.pool.cache[gen.STATE][:, 0]).max()) > 0
+    a = served_tokens(whole, 0, prompt, 12)
+    b = served_tokens(chunked, 0, prompt, 12)
+    assert a == b
+    for name in ("k", "v", gen.STATE):
+        lane_a, lane_b = (e.pool.cache[name][:, 0] for e in (whole, chunked))
+        if name != gen.STATE:
+            lane_a, lane_b = lane_a[:, :102], lane_b[:, :102]
+        np.testing.assert_allclose(lane_a, lane_b, rtol=1e-4, atol=1e-5)
+    # and they are solo generate's, which starts from an empty cache
+    solo = gen.generate(params, cfg, jnp.asarray([prompt]), 13)[0, 90:]
+    assert a == solo.tolist()
+    # a lane that is parked while others decode keeps its state bit for bit
+    before = np.asarray(whole.pool.cache[gen.STATE][:, 0])
+    assert whole.pool.allocate() == 1
+    served_tokens(whole, 1, other, 4)
+    np.testing.assert_array_equal(whole.pool.cache[gen.STATE][:, 0], before)
+
+
+def test_the_server_serves_mixed_lengths_and_counts_the_selection(model):
+    cfg, params = model
+    server = InferenceServer(params, cfg, n_slots=3, prefill_len=96,
+                             prefill_chunk=32, warmup=True)
+    prompts = [tokens_of(cfg, 1, n, seed=n)[0].tolist()
+               for n in (70, 12, 95, 40)]
+    handles = [server.submit(Request(prompt=p, max_new_tokens=20,
+                                     do_sample=False)) for p in prompts]
+    while server.step():
+        pass
+    for p, h in zip(prompts, handles):
+        solo = gen.generate(params, cfg, jnp.asarray([p]), 20)[0, len(p):]
+        assert h.tokens == solo.tolist()
+    s = server.metrics.summary()
+    eng = server.engine
+    assert s["state_bytes_per_slot"] == eng.state_bytes_per_slot \
+        == 2 * 4 * 16 * 16 * 4
+    # rows: two sparse layers of k and v (2 heads of 16, float32) and a
+    # pooled key every 4 positions
+    assert s["kv_bytes_per_row"] == eng.kv_bytes_per_row \
+        == 2 * 2 * 2 * 16 * 4 + 2 * 2 * 16 * 4 // 4
+    assert 0 < s["sparse_rows_attended"] < s["sparse_rows_live"]
+    assert server.compile_counts()["decode"] == 1
+    facts = eng.pool.audit_facts()
+    assert set(facts["cache_leaf_shapes"]) == {"k", "v", gen.POOLED}
+    assert facts["state_leaf_shapes"] == {gen.STATE: (2, 3, 4, 16, 16)}
+    assert facts["cache_leaf_elems"] == 2 * 3 * 32 * 1 * 32
+    assert eng.audit_contracts()["decode"]["donated"] == 5
+    assert eng.migratable_rows(90, 90) == 0
+
+
+# -- precision: what the check's law lets through and what it does not -------
+
+def bf16_model():
+    cfg = tiny_cfg(dtype="bfloat16", param_dtype="bfloat16")
+    return cfg, gpt.init(jax.random.key(3), cfg)
+
+
+def test_in_bfloat16_the_engine_holds_the_check_s_law(reference):
+    """bfloat16 weights and activations over a float32 state pass the dense
+    law of ``harness/check.py`` (0.79% at this depth) by the rows of the
+    sparse layers above the linear ones and by the logit gap: three
+    prompts, the longest past ``dense_len``, 24 decode steps each."""
+    cfg, params = bf16_model()
+    server = InferenceServer(params, cfg, n_slots=2, prefill_len=96,
+                             prefill_buckets=[32, 64, 96], warmup=True)
+    rng = np.random.default_rng(SEED)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (30, 60, 90)]
+    verdict = check.serve_verdict(reference, sizes_of(cfg), server, prompts,
+                                  24)
+    assert verdict["ok"], json.dumps(verdict)[:2000]
+    assert [c["bucket"] for c in verdict["cases"]] == [32, 64, 96]
+
+
+def to_bfloat16(state):
+    return state.astype(jnp.bfloat16).astype(state.dtype)
+
+
+def to_int8_steps(state):
+    """255 levels over +-max of each head's state."""
+    top = jnp.abs(state).max((-1, -2), keepdims=True) + 1e-30
+    return jnp.round(state / top * 127.0) / 127.0 * top
+
+
+#: The engine's state after a 32-token prefill and 64 decode steps in
+#: bfloat16, against the reference's float32 S_t, as relative Frobenius error
+#: a layer. Kept in float32 between steps it is the rounding of the keys and
+#: values that went into it, which averages out: 0.32-0.47% over seeds.
+#: Rounded to bfloat16 after every step the roundings add up with the steps:
+#: 0.96-1.7%; in 255 steps a head, 4-24%. The rows above a linear layer do
+#: not show this at seeded weights (the scaled embedding dominates the
+#: residual stream: the check's k_rel moves from 0.417% to 0.424%), so the
+#: state is held here directly and in the benchmark by its bytes
+#: (``kv.state_bytes_per_slot``).
+STATE_REL_TOL = 7e-3
+
+
+@pytest.mark.parametrize("round_state, inside", [
+    (None, True), (to_bfloat16, False), (to_int8_steps, False)],
+    ids=["float32 state", "bfloat16 state", "int8 state"])
+def test_the_state_is_float32_and_fewer_bits_would_show(reference,
+                                                         round_state, inside):
+    cfg, params = bf16_model()
+    eng = DecodeEngine(params, cfg, n_slots=2, prefill_len=32,
+                       prefill_buckets=[32])
+    slot = eng.pool.allocate()
+    prompt = np.random.default_rng(1).integers(0, cfg.vocab_size, 32).tolist()
+    tok, _ = eng.prefill_chunk_call(slot, prompt, 0, 1.0, None, None, False, 0)
+    fed = []
+    for i in range(64):
+        if round_state is not None:     # as a pool that kept it so would
+            eng.pool.cache[gen.STATE] = round_state(eng.pool.cache[gen.STATE])
+        fed.append(tok)
+        tok = decode_alone(eng, slot, tok, 32 + i)
+    want = reference.states(reference.weights_from_program(params),
+                            jnp.asarray([prompt + fed]), sizes_of(cfg))[:, 0]
+    got = eng.pool.cache[gen.STATE][:, slot]
+    rel = jnp.sqrt(((got - want) ** 2).sum((1, 2, 3))
+                   / (want ** 2).sum((1, 2, 3)))
+    assert bool((rel <= STATE_REL_TOL).all()) is inside, rel
+
+
+# -- what is refused ----------------------------------------------------------
+
+@pytest.mark.parametrize("over, sentence", [
+    (dict(attention="flash"), "built for attention='einsum'"),
+    (dict(attention="ring"), "built for attention='einsum'"),
+    (dict(attention="ulysses"), "built for attention='einsum'"),
+    (dict(attention_window=64), "no attention_window"),
+    (dict(attn_logit_softcap=30.0), "no attention_window"),
+    (dict(pp_microbatches=2), "not pipelined"),
+    (dict(n_experts=4), "latent attention and experts are not written"),
+    (dict(rope=False), "needs rope, rmsnorm and swiglu"),
+    (dict(mixer_types=("lightning-attn",) * 4), "needs a sparse layer"),
+    (dict(mixer_types=("minicpm4",) * 3), "for each of the 4 layers"),
+    (dict(mixer_types=("minicpm4", "mamba", "minicpm4", "minicpm4")),
+     "for each of the 4 layers"),
+    (dict(lightning_head_dim=15), "an even lightning_head_dim"),
+    (dict(sparse_block_size=24), "multiples of sparse_kernel_stride"),
+    (dict(mixer_types=None), "belong to a hybrid stack"),
+])
+def test_combinations_that_are_not_built_are_refused_with_a_sentence(
+        over, sentence):
+    with pytest.raises(ConfigError, match=sentence):
+        tiny_cfg(**over)
+
+
+@pytest.mark.parametrize("how, sentence", [
+    (dict(kv_dtype="int8"), "no scale for a state"),
+    (dict(prefix_cache_mb=1.0), "no prefix store"),
+    (dict(mesh="tp2"), "served on one device"),
+    (dict(prefill_len=96, prefill_chunk=64), "takes every token once"),
+])
+def test_the_engine_refuses_what_a_state_is_not_built_for(model, how,
+                                                          sentence):
+    cfg, params = model
+    if how.get("mesh"):
+        how = dict(mesh=jax.sharding.Mesh(
+            np.asarray(jax.devices()[:2]).reshape(2), ("tp",)))
+    with pytest.raises(ConfigError, match=sentence):
+        DecodeEngine(params, cfg, n_slots=2, **how)
+
+
+def test_speculation_over_a_state_is_refused(model):
+    cfg, params = model
+    with pytest.raises(ConfigError, match="rolls rejected tokens back"):
+        InferenceServer(params, cfg, n_slots=2, draft_params=params,
+                        draft_cfg=cfg, spec_k=2)
+    eng = DecodeEngine(params, cfg, n_slots=2)
+    with pytest.raises(ValueError, match="rows and a state"):
+        eng.extract_slot_rows(0, eng.buckets[0])
+
+
+def test_a_pipeline_mesh_and_dropout_are_refused_by_the_forward(model):
+    cfg, params = model
+    toks = tokens_of(cfg, 1, 16)
+    with pytest.raises(NotImplementedError, match="without dropout"):
+        gpt.forward(params, toks, dataclasses.replace(cfg, resid_pdrop=0.1),
+                    rng=jax.random.key(0), deterministic=False)
+
+
+# -- the preset, the configuration file and the cell ---------------------------
+
+def test_the_preset_is_the_published_model():
+    cfg = GPTConfig.make(model_type="minicpm-sala")
+    assert (cfg.n_layer, cfg.n_embd, cfg.n_head, cfg.kv_heads, cfg.head_dim,
+            cfg.dense_width, cfg.vocab_size, cfg.block_size) == (
+        32, 4096, 32, 2, 128, 16384, 73448, 524288)
+    assert cfg.mixer_layers(SPARSE) == (0, 9, 16, 17, 22, 29, 30, 31)
+    assert len(cfg.mixer_layers(LIGHTNING)) == 24
+    assert cfg.residual_scale == pytest.approx(1.4 / 32 ** 0.5)
+    assert cfg.head_divisor == 16.0 and cfg.scale_emb == 12.0
+    shapes = gen.cache_leaf_shapes(dataclasses.replace(cfg, block_size=32768),
+                                   16)
+    assert shapes == {"k": (8, 16, 32768, 1, 256), "v": (8, 16, 32768, 1, 256),
+                      gen.POOLED: (8, 16, 2048, 1, 256),
+                      gen.STATE: (24, 16, 32, 128, 128)}
+    count = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(
+        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))))
+    assert 9.4e9 < count < 9.6e9            # "9B"
+
+
+def test_the_configuration_file_holds_the_published_widths():
+    cell = spec.load_cell(CELL)
+    config = cell.config
+    assert config["reduced"] == ["num_hidden_layers", "mixer_types",
+                                 "max_position_embeddings"]
+    assert (config["hidden_size"], config["intermediate_size"],
+            config["num_attention_heads"], config["num_key_value_heads"],
+            config["head_dim"], config["lightning_nh"],
+            config["lightning_head_dim"], config["vocab_size"],
+            config["scale_emb"], config["scale_depth"],
+            config["dim_model_base"]) == (
+        4096, 16384, 32, 2, 128, 32, 128, 73448, 12, 1.4, 256)
+    published = MODEL_PRESETS["minicpm-sala"]["mixer_types"]
+    assert tuple(config["mixer_types"]) == published[7:23]
+    cfg = spec.gpt_config(cell, training=False)
+    assert cfg.param_dtype == cfg.dtype == "bfloat16"
+    assert cfg.residual_scale == pytest.approx(1.4 / 32 ** 0.5)
+    assert len(cfg.mixer_layers(SPARSE)) == 4
+    assert cfg.mixer_types[-1] == SPARSE
+    assert spec.server_options(cell) == {
+        "prefill_len": 32768, "prefill_buckets": [8192, 16384, 32768],
+        "n_slots": cell.found["server"]["n_slots"]}
+    for key in ("sparse_config", "slopes", "topk", "dense_len", "max-pool",
+                "mup_denominator", "weights"):
+        assert key in config["assumed"]
+    wrong = dataclasses.replace(cell, config=dict(config, lightning_nh=16))
+    with pytest.raises(spec.SpecError, match="lightning_nh"):
+        spec.gpt_config(wrong, training=False)
+    wrong = dataclasses.replace(cell, config=dict(
+        config, sparse_config=dict(config["sparse_config"], topk=32)))
+    with pytest.raises(spec.SpecError, match="sparse_config"):
+        spec.gpt_config(wrong, training=False)
+
+
+def test_the_slot_is_the_size_the_configuration_states():
+    cfg = spec.gpt_config(spec.load_cell(CELL), training=False)
+    shapes = gen.cache_leaf_shapes(cfg, 1)
+    size = {n: int(np.prod(s)) * (4 if n == gen.STATE else 2)
+            for n, s in shapes.items()}
+    assert size["k"] + size["v"] == 4 * 32768 * 1024
+    assert size[gen.POOLED] == 4 * 2048 * 512
+    assert size[gen.STATE] == 25_165_824
+
+
+@pytest.fixture(scope="module")
+def cell_run():
+    return serve_cell.run(
+        rehearse.tiny(spec.load_cell(CELL)), seed=SEED, seconds=1.0,
+        traced=False, devices=jax.devices()[:1], t_process=0.0,
+        compiles=compiles.CompileCounter())
+
+
+def test_the_cell_agrees_with_its_reference_through_the_whole_path(cell_run):
+    """bfloat16, the engine's own programs, ``serve_cell.Driver`` and
+    ``check.serve_verdict`` as the driver runs them: the sparse layers' rows
+    inside the dense law, no program compiled in the window."""
+    verdict = cell_run["verdict"]
+    assert verdict["ok"], verdict
+    assert verdict["compiled_in_window"] == 0
+    assert len(verdict["cases"]) == 3
+    for case in verdict["cases"]:
+        assert len(case["k_rel_layers"]) == len(case["v_rel_layers"]) == 2
+    assert cell_run["failed"] == 0 and cell_run["attempted"] > 0
+
+
+@pytest.mark.parametrize("reader", ["kv.state_bytes_per_slot",
+                                    "sparse.attended_row_share"])
+def test_the_new_readers_read_the_program_s_counters(reader):
+    play = serve_cell.Play(n_slots=4, block_size=128)
+    play.trace_open = {"state_bytes_per_slot": 8192,
+                       "sparse_rows_attended": 100.0,
+                       "sparse_rows_live": 200.0}
+    play.trace_close = {"state_bytes_per_slot": 8192,
+                        "sparse_rows_attended": 400.0,
+                        "sparse_rows_live": 1400.0}
+    read = spec.load_reader(reader).read
+    want = {"kv.state_bytes_per_slot": 8192.0,
+            "sparse.attended_row_share": 25.0}[reader]
+    assert read({"play": play}) == want
+    # a program that has no such gauge or counter (the parent's): nothing
+    play.trace_open, play.trace_close = {"steps": 1}, {"steps": 9}
+    assert read({"play": play}) is None
+    assert read({"play": None}) is None
+
+
+def test_the_manifest_lists_the_cell_where_it_reports():
+    cell = spec.load_cell(CELL)
+    assert [m["name"] for m in cell.end_to_end] == ["itl_p50_ms", "setup_s"]
+    names = {m["name"] for m in cell.per_layer}
+    assert {"kv.state_bytes_per_slot", "sparse.attended_row_share",
+            "kv.bytes_per_live_token", "kv.live_row_share",
+            "engine.decode_step_ms_p50", "sched.host_ms_per_round"} <= names
+    # a traced window at 0.28 requests a second can hold no prefill at all
+    assert "engine.prefill_ms_per_ktok" not in names
+    assert "sched.queue_wait_ms_p50" not in names
+    assert not any(n.startswith("moe.") for n in names)
