@@ -125,10 +125,12 @@ def init_moe_rows(cfg: GPTConfig) -> Optional[jax.Array]:
 
 def _lay_rows_over(old: jax.Array, rows: jax.Array, positions) -> jax.Array:
     """A layer's cached ``(B, S, KV, hd)`` slice as it will read once each
-    lane's new row ``rows[b, 0]`` lies at ``positions[b]``. A select on the
-    way into the attention's reads (it fuses into them: nothing of the
-    slice's size is stored), so a decode step attends the rows it has not
-    written yet."""
+    lane's new row ``rows[b, 0]`` lies at ``positions[b]``: a select of
+    the slice's size. For a slice a step reads whole and small (a hybrid
+    stack's pooled keys); over a layer's rows it bound the attention's
+    read, which therefore takes the cache as it lies and the new rows
+    beside it (``attn_ops.causal_attend_step``, ``latent_attend_step``,
+    ``sparse_ops.sparse_attend_step``)."""
     at = jnp.arange(old.shape[1])[None, :] == positions[:, None]  # (B, S)
     return jnp.where(at[:, :, None, None], rows, old)
 
@@ -170,6 +172,7 @@ def _cached_block(
     cfg: GPTConfig,
     valid: Optional[jax.Array] = None,  # (B, T) bool: tokens worth counting
     expert_layer: Optional[int] = None,  # blk's EXPERT_LEAVES are the stack's
+    frontier: Optional[jax.Array] = None,  # furthest (B,) offset that counts
 ) -> Tuple[jax.Array, Cache, Cache, Optional[jax.Array]]:
     """One pre-LN block against the cache. Returns (y, cache, rows,
     counts): the block's own (B, T, heads, size) k/v ``rows`` in the
@@ -190,10 +193,14 @@ def _cached_block(
     A ``(B,)`` offset, one a row, is the serving decode step: the batch is
     the pool's slot axis, T is 1 and every lane stands at its own
     position (the form is read from the offset's shape). The rotary
-    angles and the causal mask are then a lane's own; the block attends
-    the layer's slice with the new rows laid over it (``_lay_rows_over``:
-    the row a lane attends for its own token is the row as cached) and
-    returns the cache as it came: the caller writes all layers' rows
+    angles and the causal mask are then a lane's own; the block reads the
+    cache as it lies: a lane attends the rows before its position out of
+    the cache and its own new row beside them, under one softmax in two
+    parts (``attn_ops.causal_attend_step``, ``latent_attend_step``; the
+    row a lane attends for its own token is the row as cached), a slice
+    of more than one block only as far as ``frontier``, the furthest
+    position of a lane whose output counts (None: the furthest of all).
+    It returns the cache as it came: the caller writes all layers' rows
     after the last (``_write_lane_rows``). A capacity-routed expert MLP
     routes each lane alone, since lanes are other users' requests: a
     lane's routes must not depend on which other lanes are live. The
@@ -233,10 +240,7 @@ def _cached_block(
             k = attn_ops.apply_rope(k, *rope, cfg.rope_interleave)
 
     rows = {"k": k.astype(cache["k"].dtype), "v": v.astype(cache["v"].dtype)}
-    if per_lane:
-        big_k, big_v = (_lay_rows_over(cache[n][layer], rows[n], offset)
-                        for n in ("k", "v"))
-    else:
+    if not per_lane:
         cache = {**cache, **{n: jax.lax.dynamic_update_slice(
             cache[n], rows[n][None], (layer, 0, offset, 0, 0))
             for n in ("k", "v")}}
@@ -245,11 +249,22 @@ def _cached_block(
     # positions correct, and the causal mask kills both future tokens and
     # never-written (zero) slots beyond offset+t
     if cfg.kv_lora_rank:
-        att = attn_ops.latent_attention(
-            q_lat, q_pe, big_v, big_k, kv_offset=offset,
-            scale=cfg.qk_head_dim ** -0.5)
+        scale = cfg.qk_head_dim ** -0.5
+        if per_lane:
+            att = attn_ops.latent_attend_step(
+                q_lat, q_pe, cache["v"], cache["k"], layer, rows["v"],
+                rows["k"], offset, frontier=frontier, scale=scale)
+        else:
+            att = attn_ops.latent_attention(
+                q_lat, q_pe, big_v, big_k, kv_offset=offset, scale=scale)
         att = jnp.einsum("bthr,rhv->bthv", att, w_kv_b[..., nope:]).reshape(
             b, t, nh * cfg.v_head_dim)
+    elif per_lane:
+        att = attn_ops.causal_attend_step(
+            q, cache["k"], cache["v"], layer, rows["k"], rows["v"], offset,
+            frontier=frontier, window=cfg.attention_window,
+            logit_softcap=cfg.attn_logit_softcap,
+        ).reshape(b, t, nh * hd)
     else:
         att = attn_ops.causal_attention(
             q, big_k, big_v, kv_offset=offset,
@@ -314,8 +329,8 @@ def _cached_hybrid_block(
     window that ends at or last before each lane's position, laid over the
     cached ones before the selection reads them. The decode step reads the
     cached rows as they lie and attends its own new row beside them
-    (``sparse_ops.sparse_attend_step``): the rows are not laid over a
-    slice of the pool's size as ``_cached_block`` lays them.
+    (``sparse_ops.sparse_attend_step``), as ``_cached_block``'s step
+    does: no rows are laid over a slice of the pool's size.
     """
     b, t, _ = x.shape
     per_lane = jnp.ndim(offset) == 1
@@ -424,7 +439,7 @@ def _forward_cached_hybrid(params, x, cache: Cache, offset, cfg: GPTConfig,
 
 def _forward_cached_hidden(
     params: gpt.Params, tokens: jax.Array, cache: Cache, offset, cfg: GPTConfig,
-    valid: Optional[jax.Array] = None,
+    valid: Optional[jax.Array] = None, frontier: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Cache]:
     """Forward (B, T) tokens at absolute position ``offset`` (a scalar, or
     a ``(B,)`` vector of one position a row: see ``_cached_block``) through
@@ -437,7 +452,9 @@ def _forward_cached_hidden(
     A cache that carries MOE_ROWS (a serving pool's) gets the expert
     layers' counts of the ``valid`` (B, T) tokens' routed rows added to it
     (None: every token counts; a prefill's padding and a parked decode
-    lane are computed and not counted).
+    lane are computed and not counted). ``frontier`` bounds what a step
+    under a position a lane reads of a long slice (``_cached_block``; a
+    hybrid stack's sparse layers read every row and take no notice).
 
     The layer loop is a static python loop (n_layer is static, decode
     bodies are small) so each layer's cache update stays a one-slot
@@ -477,7 +494,7 @@ def _forward_cached_hidden(
         blk = {n: a if n in whole else a[at] for n, a in stack.items()}
         x, cache, new, routed = _cached_block(
             x, blk, cache, layer, offset, cfg, valid,
-            expert_layer=at if whole else None)
+            expert_layer=at if whole else None, frontier=frontier)
         rows.append(new)
         if routed is not None:
             counts.append(routed)
@@ -558,13 +575,14 @@ def cast_once_params(
 
 def _forward_cached(
     params: gpt.Params, tokens: jax.Array, cache: Cache, offset, cfg: GPTConfig,
-    valid: Optional[jax.Array] = None,
+    valid: Optional[jax.Array] = None, frontier: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Cache]:
     """Forward (B, T) tokens at position ``offset`` through all layers.
     Returns (last-position logits (B, V), cache). Thin composition of
     ``_forward_cached_hidden`` + ``_head_logits`` — the serving engine
     (serving/engine.py) shares the same two pieces."""
-    x, cache = _forward_cached_hidden(params, tokens, cache, offset, cfg, valid)
+    x, cache = _forward_cached_hidden(
+        params, tokens, cache, offset, cfg, valid, frontier)
     logits = _head_logits(params, x[:, -1:], cfg)[:, 0]
     return logits, cache
 

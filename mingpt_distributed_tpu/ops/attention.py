@@ -145,8 +145,190 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
     return out.astype(x.dtype)
 
 
-#: cached rows a step of ``latent_attention``'s loop attends
+#: cached rows a step of a walk over a slice attends: ``latent_attention``'s
+#: prefill loop, and a decode step's walk to its lanes' frontier
 LATENT_KV_BLOCK = 1024
+
+
+def _walked(s: int) -> bool:
+    """Whether an ``s``-row slice is attended a block at a time: more than
+    one block, and a whole number of them."""
+    return s > LATENT_KV_BLOCK and s % LATENT_KV_BLOCK == 0
+
+
+def step_rows_read(s: int, frontier):
+    """Rows of an ``s``-row slice a decode step reads
+    (:func:`causal_attend_step`, :func:`latent_attend_step`) when no lane
+    that counts stands past ``frontier``: whole blocks of LATENT_KV_BLOCK
+    up to it. A slice of one block or less (or of no whole number of them)
+    is read whole, in one pass. The one rule for the program and for
+    whoever counts what it read: NumPy or ``jnp`` alike."""
+    if not _walked(s):
+        return s
+    blk = LATENT_KV_BLOCK
+    return (frontier + blk - 1) // blk * blk
+
+
+def _attend_step(take, score, weigh, own, own_value, s: int, frontier,
+                 reread: bool = False):
+    """One softmax in two parts for a decode step: a query's cached rows,
+    read as they lie, and its own new row beside them, which starts the
+    running maximum and sum (its score is finite, so a masked row's
+    ``exp(NEG_INF - m)`` is 0 exactly and a block of masked rows changes
+    nothing). ``take(start, size)`` cuts rows ``[start, start + size)``
+    out of the whole cache buffers (inside the loop's body: a layer's
+    slice taken before the loop would be copied whole into it);
+    ``score(rows, k_pos)`` gives their (..., size) float32 scores, NEG_INF
+    where masked; ``weigh(p, rows)`` the (..., d) float32 sum of their
+    values under ``p``. ``own`` (...,) float32 and ``own_value`` (..., d)
+    float32 (broadcastable) are the new row's. ``reread``: ``score`` and
+    ``weigh`` read the same rows (a latent is key and value), so a walk's
+    block is made a buffer of its own: small enough for the chip's
+    compiler to keep in fast memory, and the cache's rows then leave HBM
+    once a step and not twice (PERF.md, PR 33: 6.0 -> 4.5 ms).
+    Returns (..., d) float32."""
+    if not _walked(s):
+        rows = take(0, s)
+        z = score(rows, jnp.arange(s))
+        m = jnp.maximum(z.max(-1), own)
+        p, p_own = jnp.exp(z - m[..., None]), jnp.exp(own - m)
+        acc = weigh(p, rows) + p_own[..., None] * own_value
+        return acc / (p.sum(-1) + p_own)[..., None]
+
+    blk = LATENT_KV_BLOCK
+
+    def step(i, carry):
+        m, l, acc = carry
+        rows = take(i * blk, blk)
+        if reread:
+            rows = jax.lax.optimization_barrier(rows)
+        z = score(rows, i * blk + jnp.arange(blk))
+        m_new = jnp.maximum(m, z.max(-1))
+        p, fix = jnp.exp(z - m_new[..., None]), jnp.exp(m - m_new)
+        return (m_new, l * fix + p.sum(-1),
+                acc * fix[..., None] + weigh(p, rows))
+
+    _, l, acc = jax.lax.fori_loop(
+        0, step_rows_read(s, frontier) // blk, step,
+        (own, jnp.ones_like(own),
+         jnp.broadcast_to(own_value, own.shape + own_value.shape[-1:])))
+    return acc / l[..., None]
+
+
+def _layer_rows(buf: jax.Array, layer: int, start, size: int) -> jax.Array:
+    """Rows ``[start, start + size)`` of layer ``layer`` out of a whole
+    ``(L, B, S, heads, size)`` cache buffer: (B, size, heads, size)."""
+    return jax.lax.dynamic_slice(
+        buf, (layer, 0, start, 0, 0), (1, buf.shape[1], size) + buf.shape[3:]
+    )[0]
+
+
+@jax.named_scope("cached_attn")
+def causal_attend_step(
+    q: jax.Array,         # (B, 1, H, hd): one query a lane
+    k_cache: jax.Array,   # (L, B, S, KV, hd): whole, the new rows not yet
+    v_cache: jax.Array,   #   written
+    layer: int,
+    k_new: jax.Array,     # (B, 1, KV, hd): each lane's own new row
+    v_new: jax.Array,
+    positions: jax.Array,  # (B,): where each lane's query stands
+    *,
+    frontier=None,
+    window: Optional[int] = None,
+    logit_softcap: Optional[float] = None,
+) -> jax.Array:
+    """:func:`causal_attention` for the serving decode step, the cache read
+    as it lies: lane ``b`` attends rows ``[0, positions[b])`` of its slice
+    of layer ``layer`` out of the cache, and its own row from ``k_new``,
+    ``v_new``, under one softmax in two parts (:func:`_attend_step`).
+    Nothing of the slice's size is selected, copied or written. ``window``
+    and ``logit_softcap`` as in ``causal_attention`` (the own row is always
+    inside the window). The slice is read as far as
+    ``step_rows_read(S, frontier)`` rows (``frontier``: the furthest
+    position of a lane whose output counts; None, the furthest of all).
+    Returns (B, 1, H, hd) in q's dtype."""
+    b, _, h, hd = q.shape
+    s, kv = k_cache.shape[2], k_cache.shape[3]
+    if frontier is None:
+        frontier = jnp.max(positions)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, dtype=jnp.float32))
+    # grouped queries beside their KV head: the cache is never repeated
+    qg = q.reshape(b, kv, h // kv, hd)
+
+    def take(start, size):
+        return (_layer_rows(k_cache, layer, start, size),
+                _layer_rows(v_cache, layer, start, size))
+
+    def score(rows, k_pos):
+        z = softcap(jnp.einsum(
+            "bkgd,bskd->bkgs", qg, rows[0],
+            preferred_element_type=jnp.float32) * scale, logit_softcap)
+        behind = positions[:, None] - k_pos[None, :]      # (B, S')
+        allowed = behind > 0
+        if window is not None:
+            allowed = allowed & (behind < window)
+        return jnp.where(allowed[:, None, None, :], z, NEG_INF)
+
+    def weigh(p, rows):
+        return jnp.einsum("bkgs,bskd->bkgd", p.astype(rows[1].dtype), rows[1],
+                          preferred_element_type=jnp.float32)
+
+    own = softcap(jnp.einsum(
+        "bkgd,bkd->bkg", qg, k_new[:, 0],
+        preferred_element_type=jnp.float32) * scale, logit_softcap)
+    out = _attend_step(take, score, weigh, own,
+                       v_new[:, 0, :, None].astype(jnp.float32), s, frontier)
+    return out.reshape(b, 1, h, hd).astype(q.dtype)
+
+
+@jax.named_scope("latent_attn")
+def latent_attend_step(
+    q_lat: jax.Array,         # (B, 1, H, r): queries absorbed through W_UK
+    q_pe: jax.Array,          # (B, 1, H, e): their rotated rope part
+    latent_cache: jax.Array,  # (L, B, S, 1, r): whole, the new rows not yet
+    pe_cache: jax.Array,      # (L, B, S, 1, e)   written
+    layer: int,
+    latent_new: jax.Array,    # (B, 1, 1, r): each lane's own new latent
+    pe_new: jax.Array,        # (B, 1, 1, e): and rotated rope key
+    positions: jax.Array,     # (B,)
+    *,
+    frontier=None,
+    scale: float,
+) -> jax.Array:
+    """:func:`latent_attention` for the serving decode step, the cache read
+    as it lies, as :func:`causal_attend_step` reads a per-head one: the own
+    row's score is ``q_lat . latent_new + q_pe . pe_new``, its value the
+    new latent. Returns the heads' averaged latents (B, 1, H, r)."""
+    s = latent_cache.shape[2]
+    if frontier is None:
+        frontier = jnp.max(positions)
+    q_lat, q_pe = q_lat[:, 0], q_pe[:, 0]                 # (B, H, .)
+
+    def take(start, size):
+        return (_layer_rows(latent_cache, layer, start, size)[:, :, 0],
+                _layer_rows(pe_cache, layer, start, size)[:, :, 0])
+
+    def score(rows, k_pos):
+        z = jnp.einsum("bhr,bsr->bhs", q_lat, rows[0],
+                       preferred_element_type=jnp.float32)
+        z = z + jnp.einsum("bhe,bse->bhs", q_pe, rows[1],
+                           preferred_element_type=jnp.float32)
+        allowed = k_pos[None, :] < positions[:, None]     # (B, S')
+        return jnp.where(allowed[:, None, :], z * scale, NEG_INF)
+
+    def weigh(p, rows):
+        return jnp.einsum("bhs,bsr->bhr", p.astype(rows[0].dtype), rows[0],
+                          preferred_element_type=jnp.float32)
+
+    new, pe = latent_new[:, 0, 0], pe_new[:, 0, 0]        # (B, r), (B, e)
+    own = (jnp.einsum("bhr,br->bh", q_lat, new,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("bhe,be->bh", q_pe, pe,
+                        preferred_element_type=jnp.float32)) * scale
+    out = _attend_step(take, score, weigh, own,
+                       new[:, None].astype(jnp.float32), s, frontier,
+                       reread=True)
+    return out[:, None].astype(q_lat.dtype)
 
 
 @jax.named_scope("latent_attn")
@@ -194,7 +376,7 @@ def latent_attention(
             lat_blk[:, :, 0], preferred_element_type=jnp.float32,
         ).reshape(b, t, h, r)
 
-    if t == 1 or s <= LATENT_KV_BLOCK or s % LATENT_KV_BLOCK:
+    if t == 1 or not _walked(s):
         p = jax.nn.softmax(scores(latent, k_pe, jnp.arange(s)), axis=-1)
         return weigh(p, latent).astype(out_dtype)
 

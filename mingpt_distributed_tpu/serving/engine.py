@@ -21,10 +21,12 @@ lifetime:
    cache whose batch is the slot axis, so the step is one ``(B=S, T=1)``
    call of the same ``_forward_cached`` the solo scan uses, at a ``(S,)``
    vector of absolute positions (per-slot ``kv_offset`` and RoPE /
-   learned-position index). Each layer attends its slice of the pool
-   with the lanes' new rows laid over it; after the last layer one
-   row-sized ``dynamic_update_slice`` a lane writes all layers' rows into
-   the donated pool where they lie (``generate._write_lane_rows``). The
+   learned-position index). Each layer reads its slice of the pool as
+   it lies, each lane's new row attended beside it under one softmax, and
+   a slice longer than one block only as far as the furthest live lane
+   stands (``decode_rows_read``); after the last layer one row-sized
+   ``dynamic_update_slice`` a lane writes all layers' rows into the
+   donated pool where they lie (``generate._write_lane_rows``). The
    pool is never transposed, copied or rebuilt inside the program: a
    ``vmap`` over lanes of a batch of one would batch each lane's update
    into a scatter with the slot axis in front, and the TPU compiler then
@@ -101,6 +103,7 @@ import numpy as np
 
 from mingpt_distributed_tpu.config import ConfigError, GPTConfig
 from mingpt_distributed_tpu.models import generate as gen
+from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.serving import quant as quant_lib
 from mingpt_distributed_tpu.serving.kv_pool import PrefixKVStore, SlotKVPool
@@ -209,6 +212,27 @@ def sampler_orders(do_sample, top_ks, top_ps):
     vectors alike: the programs branch on it (:func:`_select_next_slots`)
     and the scheduler counts it, on the host's copy of the same vectors."""
     return (do_sample & ((top_ks > 0) | (top_ps < 1.0))).any()
+
+
+def decode_frontier(positions, live):
+    """The furthest position of a lane the decode step is run for: how far
+    the step has to read a slot. ``live`` and not the positions says which
+    lanes those are (a free lane is parked at the window's last row, and
+    so is a request's last step)."""
+    return (positions * live).max()
+
+
+def decode_rows_read(positions, live, cfg: GPTConfig):
+    """Rows of every slot's slice a decode step reads, a layer: whole
+    blocks up to :func:`decode_frontier` where the slice is walked
+    (``attn_ops.step_rows_read``), else the whole slice (one block or
+    less; a hybrid stack's sparse layers). NumPy or ``jnp`` vectors alike:
+    the decode program walks as far as this says and the scheduler counts
+    it, on the host's copy of the same vectors."""
+    if cfg.mixer_types is not None:
+        return cfg.block_size
+    return attn_ops.step_rows_read(
+        cfg.block_size, decode_frontier(positions, live))
 
 
 @jax.named_scope("sample")
@@ -386,22 +410,28 @@ def _prefill_impl(
 
 def _decode_impl(
     params, cache, tokens, positions, temps, top_ks, top_ps, do_sample,
-    seeds, token_index=None,
+    seeds, token_index=None, live=None,
     *, cfg: GPTConfig, kv_sharding=None, kv_quant=None,
 ):
     """One token for every slot: tokens/positions (S,), sampling arrays
-    (S,), request seeds (S,) uint32 and the index (S,) of the token each
+    (S,), request seeds (S,) uint32, the index (S,) of the token each
     lane samples, from which the lanes' keys are derived here
-    (:func:`lane_keys`). Returns (next tokens (S,), updated pool cache).
+    (:func:`lane_keys`), and ``live`` (S,) bool, the lanes the step is run
+    for (None: all). Returns (next tokens (S,), updated pool cache).
 
     The pool is a solo cache whose batch is the slot axis, so the step is
     one ``(B=S, T=1)`` forward through the same cached-block chain solo
     ``generate`` uses, at a ``(S,)`` vector of positions
-    (``generate._cached_block``): each layer attends its slice of the
-    pool under each lane's own causal mask with the lanes' new rows laid
-    over it, and all layers' rows are written into the donated pool after
-    the last, one row-sized update a lane. Nothing in the program has the
-    pool's size but the pool. Positions are clipped, so a free lane
+    (``generate._cached_block``): each layer reads its slice of the pool
+    as it lies, under each lane's own causal mask, with the lane's new
+    row attended beside it, and all layers' rows are written into the
+    donated pool after the last, one row-sized update a lane. Nothing in
+    the program has the pool's size but the pool, and nothing a slice's. A
+    slice of more than one block is read only as far as the furthest
+    ``live`` lane stands (:func:`decode_rows_read`): a lane that is not
+    live attends what that leaves it, and its token is the caller's to
+    discard; a live lane attends every row its mask allows. Positions are
+    clipped, so a free lane
     (parked at ``block_size - 1``) writes into its own lane and no other.
     A quantized pool is dequantized, stepped and requantized whole
     (idempotent on the rows the step did not touch: serving/quant.py)."""
@@ -411,7 +441,9 @@ def _decode_impl(
     logits, stepped = gen._forward_cached(
         params, tokens[:, None],
         _with_counter(_dequant_lane(cache, kv_quant, cfg), cache),
-        safe_pos, cfg, valid=(positions < cfg.block_size - 1)[:, None])
+        safe_pos, cfg, valid=(positions < cfg.block_size - 1)[:, None],
+        frontier=decode_frontier(
+            safe_pos, True if live is None else live))
     cache = _with_counter(_requant_lane(stepped, kv_quant), stepped)
     nxt = _select_next_slots(logits, lane_keys(seeds, token_index),
                              temps, top_ks, top_ps, do_sample)
@@ -819,6 +851,7 @@ class DecodeEngine:
             np.ones(s, np.float32), np.zeros(s, np.int32),
             np.ones(s, np.float32), np.zeros(s, bool),
             np.zeros(s, np.uint32), np.zeros(s, np.int32),
+            np.zeros(s, bool),
         )
         if self.prefix_store is not None:
             for b in self.buckets:
@@ -844,12 +877,17 @@ class DecodeEngine:
         do_sample: np.ndarray,
         seeds,
         token_index: Optional[np.ndarray] = None,
+        live: Optional[np.ndarray] = None,
     ) -> np.ndarray:
         """Advance every slot one token; caller masks inactive lanes.
         Lane ``s`` samples under ``fold_in(key(seeds[s]), token_index[s])``,
         derived inside the program: ``seeds`` are the (S,) request seeds
         (:func:`request_seeds`), ``token_index`` how many tokens each
-        request has emitted (None: 0 a lane). The host vectors go to the
+        request has emitted (None: 0 a lane). ``live`` (S,) bool names the
+        lanes whose tokens the caller will use (None: all of them): the
+        pool is read as it lies, and a long slot only as far as the
+        furthest live lane stands (:func:`decode_rows_read`); it is always
+        an argument of the one program. The host vectors go to the
         one jit call as they are; no other program is dispatched. Two
         spans split the host's part: ``serve.decode_launch`` is the
         staging of the arguments and the jit call up to its return (the
@@ -857,6 +895,8 @@ class DecodeEngine:
         with self.tracer.span("serve.decode_launch"):
             if token_index is None:
                 token_index = np.zeros(len(tokens), np.int32)
+            if live is None:
+                live = np.ones(len(tokens), bool)
             nxt, cache = self._decode_jit(
                 self.program_params, self.pool.cache,
                 np.asarray(tokens, np.int32),
@@ -866,6 +906,7 @@ class DecodeEngine:
                 np.asarray(top_ps, np.float32),
                 np.asarray(do_sample, bool),
                 request_seeds(seeds), np.asarray(token_index, np.int32),
+                np.asarray(live, bool),
             )
             self.pool.cache = cache
         with self.tracer.span("serve.decode_sync"):
@@ -905,7 +946,8 @@ class DecodeEngine:
                 jnp.zeros(s, jnp.int32), jnp.zeros(s, jnp.int32),
                 jnp.ones(s, jnp.float32), jnp.zeros(s, jnp.int32),
                 jnp.ones(s, jnp.float32), jnp.zeros(s, bool),
-                jnp.zeros(s, jnp.uint32), jnp.zeros(s, jnp.int32)), {})
+                jnp.zeros(s, jnp.uint32), jnp.zeros(s, jnp.int32),
+                jnp.ones(s, bool)), {})
         if self.prefix_store is None:
             return
         for b in self.buckets:

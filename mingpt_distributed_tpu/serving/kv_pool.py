@@ -26,6 +26,13 @@ and every writer fills a row with real data before the first query that
 could see it (the stale-row invariant, serving/engine.py). The buffers
 themselves never change shape or owner-visible identity, which is what
 lets the decode program stay compiled once for the server's lifetime.
+The decode step reads the buffers as they lie: a layer's slice is never
+selected over, copied or converted on its way into the attention (each
+lane's new row is attended beside the cached ones and written after the
+last layer), and a slot longer than one block of rows is read only as far
+as the furthest live lane stands (``engine.decode_rows_read``;
+``ServingMetrics`` counts ``decode_rows_read`` against
+``decode_rows_reserved``).
 
 Allocation is deterministic (lowest free index first) so a given arrival
 order always produces the same slot placement — the scheduler tests rely

@@ -86,6 +86,15 @@ class ServingMetrics:
                  "lane sampled under top-k or top-p "
                  "(engine.sampler_orders); 0 under greedy or "
                  "plain-temperature traffic")
+        self._decode_rows_read = r.counter(
+            "mingpt_serve_decode_rows_read_total",
+            help="rows of the pool's slots the decode steps read, a layer: "
+                 "every slot as far as the furthest live lane stands "
+                 "(engine.decode_rows_read)")
+        self._decode_rows_reserved = r.counter(
+            "mingpt_serve_decode_rows_reserved_total",
+            help="rows the slots of those decode steps reserve "
+                 "(n_slots x block_size a step)")
         # prefill accounting (ISSUE 3): real prompt tokens forwarded, the
         # padded bucket fit (how well the ladder matches the traffic), and
         # wall time inside prefill calls — the decode-stall budget
@@ -250,6 +259,14 @@ class ServingMetrics:
         return int(self._sampler_sorted.value)
 
     @property
+    def decode_rows_read(self) -> int:
+        return int(self._decode_rows_read.value)
+
+    @property
+    def decode_rows_reserved(self) -> int:
+        return int(self._decode_rows_reserved.value)
+
+    @property
     def prefill_chunks(self) -> int:
         return int(self._prefill_chunks.value)
 
@@ -363,6 +380,13 @@ class ServingMetrics:
         """A decode round whose program ordered the vocabulary (the
         scheduler calls it, beside its ``decode_step``)."""
         self._sampler_sorted.inc()
+
+    def on_decode_rows(self, read: int, reserved: int) -> None:
+        """A decode step's rows, a layer, over all slots: those it read
+        and those the slots reserve (the scheduler calls it, beside its
+        ``decode_step``, with the program's own rule)."""
+        self._decode_rows_read.inc(read)
+        self._decode_rows_reserved.inc(reserved)
 
     def on_step(
         self, queue_depth: int, slots_active: int, lanes_used: Optional[int] = None
@@ -568,6 +592,8 @@ class ServingMetrics:
             "tokens_generated": self.tokens_generated,
             "steps": self.steps,
             "sampler_sorted_rounds": self.sampler_sorted_rounds,
+            "decode_rows_read": self.decode_rows_read,
+            "decode_rows_reserved": self.decode_rows_reserved,
             "queue_depth": self.queue_depth,
             "slots_active": self.slots_active,
             "slot_utilization": self.slot_utilization,

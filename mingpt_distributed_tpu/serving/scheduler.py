@@ -89,7 +89,11 @@ import numpy as np
 
 from mingpt_distributed_tpu.config import ConfigError, GPTConfig
 from mingpt_distributed_tpu.serving.admission import AdmissionPolicy, FifoPolicy
-from mingpt_distributed_tpu.serving.engine import DecodeEngine, sampler_orders
+from mingpt_distributed_tpu.serving.engine import (
+    DecodeEngine,
+    decode_rows_read,
+    sampler_orders,
+)
 from mingpt_distributed_tpu.serving.metrics import ServingMetrics
 from mingpt_distributed_tpu.serving.requests import (  # noqa: F401  (re-export)
     QueueFullError,
@@ -716,20 +720,25 @@ class InferenceServer:
                 plain = [s for s in active if s not in spec_slots]
                 burst: Dict[int, List[int]] = {}
                 if plain:
+                    # the lanes this step is run for: the program reads a
+                    # long slot only as far as the furthest of them stands
+                    live = np.zeros(st.n_slots, bool)
+                    live[plain] = True
                     pos = st.positions
                     if spec_slots:
                         # park speculating lanes: the verify program is
                         # their row-writer this round
-                        pmask = np.zeros(st.n_slots, bool)
-                        pmask[plain] = True
-                        pos = np.where(pmask, st.positions, st.parked)
+                        pos = np.where(live, st.positions, st.parked)
                     nxt = self.engine.decode_step(
                         st.tokens, pos, st.temps, st.top_ks,
-                        st.top_ps, st.do_sample, st.seeds, index,
+                        st.top_ps, st.do_sample, st.seeds, index, live,
                     )
-                    # the program's own predicate, on the vectors it got
+                    # the program's own rules, on the vectors it got
                     if sampler_orders(st.do_sample, st.top_ks, st.top_ps):
                         self.metrics.on_sampler_sorted()
+                    read = int(decode_rows_read(pos, live, self.cfg))
+                    self.metrics.on_decode_rows(
+                        st.n_slots * read, st.n_slots * self.cfg.block_size)
                     for s in plain:
                         burst[s] = [int(nxt[s])]
                 if spec_slots:

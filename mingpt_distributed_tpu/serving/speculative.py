@@ -289,7 +289,7 @@ class SpeculativeDecoder:
         for j in range(self.k):
             nxt = self.draft.engine.decode_step(
                 toks, pos, ones_f, zeros_i, ones_f, greedy, seeds,
-                token_index)
+                token_index, spec_mask)
             out[:, j] = nxt
             toks = np.where(spec_mask, nxt, 0).astype(np.int32)
             pos = np.where(spec_mask, pos + 1, self._parked).astype(np.int32)
@@ -360,7 +360,8 @@ class SpeculativeDecoder:
         pos = np.where(fill_mask, positions, self._parked).astype(np.int32)
         self.draft.engine.decode_step(
             toks, pos, np.ones(s, np.float32), np.zeros(s, np.int32),
-            np.ones(s, np.float32), np.zeros(s, bool), seeds, token_index)
+            np.ones(s, np.float32), np.zeros(s, bool), seeds, token_index,
+            fill_mask)
 
     # -- warmup / accounting -------------------------------------------
     def warmup(self) -> None:

@@ -29,6 +29,7 @@ from jax.sharding import SingleDeviceSharding
 from mingpt_distributed_tpu.config import ConfigError, GPTConfig, MeshConfig
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
+from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.serving import InferenceServer
 from mingpt_distributed_tpu.serving.engine import DecodeEngine
@@ -264,6 +265,71 @@ def test_decode_program_converts_no_weight(arch, one_chip):
 
     assert weight_converts(compiled_text(engine.params), params)
     assert not weight_converts(compiled_text(engine.program_params), params)
+
+
+def slice_sized(text, lanes, rows, ops=("select", "copy", "convert")):
+    """Instructions of compiled HLO ``text`` of the kinds ``ops`` whose
+    result holds a layer's slice of the pool, ``(.., lanes, rows, heads,
+    size)`` or, one head dropped, ``(lanes, rows, size)``, fused or not."""
+    return re.findall(
+        rf"= \w+\[(?:\d+,)*{lanes},{rows},\d+(?:,\d+)?\]\S* "
+        rf"(?:{'|'.join(ops)})\(.*", text)
+
+
+#: sizes at which the chip's compiler gives the attention's products to the
+#: MXU, as it does at a cell's (at the tiny ones above it spells them out
+#: elementwise, and what is then fused around them says nothing)
+WIDE = dict(n_layer=2, n_head=8, n_embd=256, vocab_size=64, block_size=512,
+            embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="bfloat16",
+            tie_weights=False)
+WIDE_FORMS = {
+    # eight query heads beside one KV head: a matmul a lane, as the latent
+    # form's is (one query head a KV head is a matrix-vector product, which
+    # the compiler spells out elementwise at these few lanes)
+    "per-head": dict(n_kv_head=1),
+    "latent": dict(rope=True, rope_interleave=True, swiglu=True, rmsnorm=True,
+                   kv_lora_rank=128, qk_nope_head_dim=64,
+                   qk_rope_head_dim=64, v_head_dim=64),
+}
+
+
+@pytest.mark.parametrize("form,block", [
+    ("per-head", 1024), ("per-head", 128), ("latent", 1024), ("latent", 128)],
+    ids=["per-head-one-pass", "per-head-walked", "latent-one-pass",
+         "latent-walked"])
+def test_decode_program_reads_the_cache_as_it_lies(form, block, one_chip,
+                                                   monkeypatch):
+    """The decode program compiled for the chip selects, copies and converts
+    nothing of a slice's size (PR 33): each layer reads the pool's buffers
+    where they lie, in one pass or block by block, and attends the lanes'
+    new rows beside them. Laying the rows over the slice first, as the step
+    did before, is the control: the chip's compiler keeps that select."""
+    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", block)
+    lanes = 8
+    cfg = GPTConfig.make(**WIDE, **WIDE_FORMS[form])
+    params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
+    engine = DecodeEngine(
+        jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params), cfg,
+        n_slots=lanes)
+    (_, _, jitted, args, kwargs), = [
+        p for p in engine.programs() if p[0] == "decode"]
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    text = jitted.lower(*on_chip(args), **kwargs).compile().as_text()
+    assert ("while" in text) == (block < cfg.block_size)
+    assert not slice_sized(text, lanes, cfg.block_size)
+
+    def laid_over(cache, rows, positions, q):
+        k = gen._lay_rows_over(cache["k"][0], rows, positions)
+        return jnp.einsum("bhd,bskd->bhs", q, k)
+    shape = engine.pool.cache["k"].shape
+    control = jax.jit(laid_over).lower(*on_chip((
+        engine.pool.cache, jnp.zeros((lanes, 1) + shape[3:], jnp.bfloat16),
+        jnp.zeros(lanes, jnp.int32),
+        jnp.zeros((lanes, 4, shape[-1]), jnp.bfloat16))))
+    assert slice_sized(control.compile().as_text(), lanes, cfg.block_size,
+                       ops=("select",))
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])

@@ -104,6 +104,39 @@ def test_the_counters_reach_the_readers(cell_run):
         240 * play.n_slots * play.block_size / 40)
 
 
+def test_the_read_row_share_reader_reads_the_scheduler_s_counters(cell_run):
+    """``kv.read_row_share`` (PR 33): rows the decode steps of the traced
+    window read over rows their slots reserve, from two readings of
+    ``summary()``; nothing where the program has no such counters (the
+    parent's) or the window held no decode step."""
+    read = spec.load_reader("kv.read_row_share").read
+    play = serve_cell.Play(n_slots=64, block_size=8192)
+    play.trace_open = {"decode_rows_read": 64 * 5120 * 10,
+                       "decode_rows_reserved": 64 * 8192 * 10}
+    play.trace_close = {"decode_rows_read": 64 * (5120 * 10 + 4096 * 30),
+                        "decode_rows_reserved": 64 * 8192 * 40}
+    assert read({"play": play}) == 50.0
+    play.trace_close = dict(play.trace_open)
+    assert read({"play": play}) is None           # no decode step
+    play.trace_open, play.trace_close = {"steps": 1}, {"steps": 9}
+    assert read({"play": play}) is None           # the parent's summary
+    assert read({"play": None}) is None
+    # the rehearsal's slots are one block: every step reads them whole
+    run = cell_run["evidence"]["play"]
+    opened, closed = run.open_counters, run.close_counters
+    untraced = dataclasses.replace(run, trace_open=None, trace_close=None)
+    assert read({"play": untraced}) is None
+    assert closed["decode_rows_reserved"] > opened["decode_rows_reserved"]
+    assert closed["decode_rows_read"] == closed["decode_rows_reserved"]
+    traced = dataclasses.replace(run, trace_open=opened, trace_close=closed)
+    assert read({"play": traced}) == 100.0
+    cell = spec.load_cell(CELL)
+    assert "kv.read_row_share" in {m["name"] for m in cell.per_layer}
+    for other in ("gpt2-124m.serve-decode", "minicpm-sala.serve-long-context"):
+        assert "kv.read_row_share" not in {
+            m["name"] for m in spec.load_cell(other).per_layer}
+
+
 def test_the_new_readers_return_none_for_a_dense_cell():
     """On a cell of the parent's (or the parent itself, whose summary lacks
     the fields) there is nothing to read, and no reader raises."""

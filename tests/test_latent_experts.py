@@ -6,6 +6,7 @@ absorbed cached forward against the published form, the route's contract,
 and the latent pool through the serving paths that move rows about."""
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -193,6 +194,115 @@ def test_a_long_prefill_walks_the_slice_in_blocks(monkeypatch):
     monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", 16)
     got, _ = cached_logits(cfg, params, seq, 36)
     np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("walk", [False, True], ids=["one-pass", "walked"])
+def test_the_two_part_step_is_the_laid_over_step(walk, monkeypatch):
+    """``latent_attend_step`` reads the cache as it lies and attends each
+    lane's own new row beside it; what it replaced laid the new rows over
+    the slice (``_lay_rows_over``) and attended that in one pass. The same
+    sums in another order: float32 rounding apart."""
+    if walk:
+        monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", 8)
+    keys = jax.random.split(jax.random.key(6), 6)
+    lanes, rows, heads, r, e = 3, 32, 4, 16, 4
+    q_lat = jax.random.normal(keys[0], (lanes, 1, heads, r))
+    q_pe = jax.random.normal(keys[1], (lanes, 1, heads, e))
+    latents = jax.random.normal(keys[2], (2, lanes, rows, 1, r))
+    pes = jax.random.normal(keys[3], (2, lanes, rows, 1, e))
+    new = jax.random.normal(keys[4], (lanes, 1, 1, r))
+    new_pe = jax.random.normal(keys[5], (lanes, 1, 1, e))
+    positions = jnp.array([0, 13, rows - 1])
+    got = attn_ops.latent_attend_step(
+        q_lat, q_pe, latents, pes, 1, new, new_pe, positions, scale=0.2)
+    want = attn_ops.latent_attention(
+        q_lat, q_pe, gen._lay_rows_over(latents[1], new, positions),
+        gen._lay_rows_over(pes[1], new_pe, positions), kv_offset=positions,
+        scale=0.2)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    # a frontier short of a lane's position cuts that lane alone
+    if walk:
+        short = attn_ops.latent_attend_step(
+            q_lat, q_pe, latents, pes, 1, new, new_pe, positions,
+            frontier=13, scale=0.2)
+        np.testing.assert_array_equal(short[:2], got[:2])
+        assert not np.array_equal(short[2], got[2])
+
+
+def slice_sized_selects(text, lanes, rows):
+    """``select`` instructions of a lowered (StableHLO) or compiled (HLO)
+    program whose result is a layer's slice of the pool, ``(lanes, rows, 1,
+    size)``."""
+    return re.findall(
+        rf"= \w+\[{lanes},{rows},1,\d+\]\S* select\(.*"
+        rf"|stablehlo\.select.*tensor<{lanes}x{rows}x1x\d+x\w+>$", text,
+        re.M)
+
+
+def test_the_decode_program_selects_nothing_of_a_slice_s_size(monkeypatch):
+    """The engine's decode program for a latent pool, lowered and compiled
+    for this backend: no ``select`` of a ``(lanes, rows, 1, size)`` operand
+    (the laid-over form had one a leaf and layer, and on the chip it bound
+    the read: PERF.md, PRs 30 and 33). The old form is the control."""
+    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", 16)
+    cfg, params = model()
+    eng = DecodeEngine(params, cfg, n_slots=5, prefill_len=32)
+    (_, _, jitted, args, kwargs), = [
+        p for p in eng.programs() if p[0] == "decode"]
+    lowered = jitted.lower(*args, **kwargs)
+    for text in (lowered.as_text(), lowered.compile().as_text()):
+        assert "while" in text                    # the walk is in it
+        assert not slice_sized_selects(text, 5, BLOCK)
+
+    def laid_over(cache, rows, positions):
+        return gen._lay_rows_over(cache["v"][1], rows, positions).sum(1)
+    control = jax.jit(laid_over).lower(
+        eng.pool.cache, jnp.zeros((5, 1, 1, 32)), jnp.zeros(5, jnp.int32))
+    for text in (control.as_text(), control.compile().as_text()):
+        assert slice_sized_selects(text, 5, BLOCK)
+
+
+@pytest.mark.parametrize("furthest", [15, 16, 17],
+                         ids=["below-an-edge", "on-an-edge", "above-an-edge"])
+def test_the_engine_s_step_under_a_live_mask_is_its_step_under_all_true(
+        furthest, monkeypatch):
+    """Through ``DecodeEngine.decode_step`` on the whole tiny model (a
+    dense layer, routed layers): the live lanes' tokens equal and their
+    written rows equal to 1e-6 (the routed layers' grouped matmuls see the
+    dead lanes' other rows beside them). A live lane at the window's last
+    row beside parked lanes reads its whole slot."""
+    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", 16)
+    cfg, params = model()
+    eng = DecodeEngine(params, cfg, n_slots=4, prefill_len=32)
+    keys = jax.random.split(jax.random.key(8), 2)
+    pool = {"k": jax.random.normal(keys[0], eng.pool.cache["k"].shape),
+            "v": jax.random.normal(keys[1], eng.pool.cache["v"].shape)}
+
+    def step(positions, live):
+        eng.pool.cache = {**eng.pool.cache, **jax.tree.map(jnp.array, pool)}
+        s = eng.n_slots
+        tokens = eng.decode_step(
+            np.array([3, 9, 27, 41], np.int32), positions,
+            np.ones(s, np.float32), np.zeros(s, np.int32),
+            np.ones(s, np.float32), np.zeros(s, bool),
+            np.zeros(s, np.uint32), None, live)
+        return tokens, {n: np.asarray(eng.pool.cache[n]) for n in ("k", "v")}
+
+    for positions, live in (
+            ([furthest, 5, BLOCK - 1, 50], [True, True, False, False]),
+            ([BLOCK - 1, 5, BLOCK - 1, BLOCK - 1],
+             [True, True, False, False])):
+        positions, live = np.array(positions, np.int32), np.array(live)
+        got, got_rows = step(positions, live)
+        want, want_rows = step(positions, None)
+        np.testing.assert_array_equal(got[live], want[live])
+        for name in ("k", "v"):
+            for lane in np.flatnonzero(live):
+                np.testing.assert_allclose(
+                    got_rows[name][:, lane, positions[lane]],
+                    want_rows[name][:, lane, positions[lane]], rtol=1e-6,
+                    atol=1e-7)
+    assert eng.compile_counts()["decode"] == 1
 
 
 def test_the_engine_s_programs_agree_with_the_reference(reference):

@@ -21,6 +21,8 @@ import pytest
 from mingpt_distributed_tpu.config import GPTConfig
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
+from mingpt_distributed_tpu.ops import attention as attn_ops
+from mingpt_distributed_tpu.serving import engine as engine_mod
 from mingpt_distributed_tpu.serving import (
     InferenceServer,
     QueueFullError,
@@ -1067,3 +1069,225 @@ def test_spec_constructor_validation(cfg_params):
     with pytest.raises(ValueError):  # draft window can't cover target's
         InferenceServer(params, cfg, draft_params=params, draft_cfg=small,
                         spec_k=2)
+
+
+# ---------------------------------------------------------------------------
+# the decode step reads the cache as it lies, as far as the live lanes stand
+# (PR 33)
+# ---------------------------------------------------------------------------
+
+STEP_BLOCK, STEP_ROWS = 16, 64
+STEP_FORMS = {
+    "per-head": dict(n_kv_head=2),
+    "latent": dict(rope=True, rope_interleave=True, rmsnorm=True, swiglu=True,
+                   tie_weights=False, kv_lora_rank=16, qk_nope_head_dim=8,
+                   qk_rope_head_dim=4, v_head_dim=8),
+}
+
+
+def step_model(form, **over):
+    cfg = GPTConfig.make(
+        n_layer=2, n_head=4, n_embd=32, vocab_size=50, block_size=STEP_ROWS,
+        embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32",
+        **{**STEP_FORMS[form], **over})
+    return cfg, gpt.init(jax.random.key(1), cfg)
+
+
+def stale_pool(cfg, lanes, seed=2):
+    """A pool whose every row holds something: what a mask or a bound must
+    keep out is there to be read."""
+    shapes = gen.cache_leaf_shapes(cfg, lanes)
+    keys = jax.random.split(jax.random.key(seed), len(shapes))
+    return {n: jax.random.normal(k, shapes[n])
+            for (n, k) in zip(sorted(shapes), keys)}
+
+
+def lanes_step(cfg, params, cache, tokens, positions, live):
+    """One decode step of all lanes as ``_decode_impl`` runs it, the logits
+    kept: (logits (S, V), the cache with the lanes' rows written)."""
+    positions, live = jnp.asarray(positions), jnp.asarray(live)
+
+    @jax.jit
+    def run(cache, tokens, positions, live):
+        return gen._forward_cached(
+            params, tokens[:, None], cache, positions, cfg,
+            frontier=engine_mod.decode_frontier(positions, live))
+    return run(cache, jnp.asarray(tokens), positions, live)
+
+
+@pytest.mark.parametrize("furthest", [STEP_BLOCK - 1, STEP_BLOCK,
+                                      STEP_BLOCK + 1, 2 * STEP_BLOCK + 5],
+                         ids=["below-an-edge", "on-an-edge", "above-an-edge",
+                              "two-blocks-on"])
+@pytest.mark.parametrize("form", sorted(STEP_FORMS))
+def test_a_step_under_a_live_mask_is_the_step_under_all_true(
+        form, furthest, monkeypatch):
+    """The walk stops at the furthest live lane; rows past it are masked
+    for every live lane and add exactly 0 under a block loop: logits and
+    written rows of the live lanes are the full walk's, bit for bit. A
+    lane that is not live (parked, or standing further on) is read short
+    and is nobody's."""
+    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", STEP_BLOCK)
+    cfg, params = step_model(form)
+    cache = stale_pool(cfg, 4)
+    tokens = np.array([3, 9, 27, 41], np.int32)
+    positions = np.array([furthest, 5, STEP_ROWS - 1, 50], np.int32)
+    live = np.array([True, True, False, False])
+    want_rows = -(-furthest // STEP_BLOCK) * STEP_BLOCK
+    assert engine_mod.decode_rows_read(positions, live, cfg) == want_rows
+    assert engine_mod.decode_rows_read(
+        positions, np.ones(4, bool), cfg) == STEP_ROWS
+    got, got_cache = lanes_step(cfg, params, cache, tokens, positions, live)
+    want, want_cache = lanes_step(cfg, params, cache, tokens, positions,
+                                  np.ones(4, bool))
+    np.testing.assert_array_equal(got[:2], want[:2])
+    for name in ("k", "v"):
+        for lane in (0, 1):
+            np.testing.assert_array_equal(
+                got_cache[name][:, lane], want_cache[name][:, lane])
+    if want_rows < 48:
+        # the lane at 50 was cut short: the bound is real
+        assert not np.array_equal(got[3], want[3])
+
+
+@pytest.mark.parametrize("form", sorted(STEP_FORMS))
+def test_a_live_lane_at_the_last_row_attends_all_its_rows(form, monkeypatch):
+    """A request's last step stands where free lanes are parked: liveness
+    is the mask's to say, not the position's. Beside parked lanes it reads
+    the whole slot, and what it computes is what it computes alone in a
+    pool of one block."""
+    cfg, params = step_model(form)
+    cache = stale_pool(cfg, 3)
+    tokens = np.array([7, 0, 0], np.int32)
+    positions = np.full(3, STEP_ROWS - 1, np.int32)
+    live = np.array([True, False, False])
+    one_pass, _ = lanes_step(cfg, params, cache, tokens, positions, live)
+    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", STEP_BLOCK)
+    assert engine_mod.decode_rows_read(positions, live, cfg) == STEP_ROWS
+    assert engine_mod.decode_rows_read(
+        positions, np.zeros(3, bool), cfg) == 0
+    walked, _ = lanes_step(cfg, params, cache, tokens, positions, live)
+    np.testing.assert_allclose(walked[0], one_pass[0], rtol=2e-5, atol=2e-6)
+    # a stale row inside the mask does move the lane: all 63 are attended
+    moved = {n: a.at[:, 0, STEP_ROWS - 2].add(1.0) for n, a in cache.items()}
+    other, _ = lanes_step(cfg, params, moved, tokens, positions, live)
+    assert np.abs(np.asarray(other[0] - walked[0])).max() > 1e-4
+
+
+@pytest.mark.parametrize("form", sorted(STEP_FORMS))
+def test_a_lane_that_is_not_live_changes_no_live_lane(form, monkeypatch):
+    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", STEP_BLOCK)
+    cfg, params = step_model(form)
+    cache = stale_pool(cfg, 3)
+    live = np.array([True, True, False])
+    base, _ = lanes_step(cfg, params, cache, [3, 9, 0],
+                         [20, 6, STEP_ROWS - 1], live)
+    for token, position in ((0, 45), (31, 2), (31, STEP_ROWS - 1)):
+        got, _ = lanes_step(cfg, params, cache, [3, 9, token],
+                            [20, 6, position], live)
+        np.testing.assert_array_equal(got[:2], base[:2])
+
+
+@pytest.mark.parametrize("case", [
+    dict(), dict(window=5), dict(logit_softcap=3.0),
+    dict(window=7, logit_softcap=2.0)],
+    ids=["plain", "window", "softcap", "window-and-softcap"])
+@pytest.mark.parametrize("kv_heads", [4, 2], ids=["mha", "gqa"])
+@pytest.mark.parametrize("walk", [False, True], ids=["one-pass", "walked"])
+def test_the_two_part_step_is_the_laid_over_step(case, kv_heads, walk,
+                                                 monkeypatch):
+    """``causal_attend_step`` against what it replaced: the new rows laid
+    over the slice (``_lay_rows_over``) and ``causal_attention`` under a
+    position a lane. Float32 rounding apart: the same sums in another
+    order."""
+    if walk:
+        monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", 8)
+    keys = jax.random.split(jax.random.key(4), 5)
+    lanes, rows, heads, size = 3, 32, 4, 8
+    q = jax.random.normal(keys[0], (lanes, 1, heads, size))
+    k_cache, v_cache = (jax.random.normal(k, (2, lanes, rows, kv_heads, size))
+                        for k in keys[1:3])
+    k_new, v_new = (jax.random.normal(k, (lanes, 1, kv_heads, size))
+                    for k in keys[3:5])
+    positions = jnp.array([0, 13, rows - 1])
+    got = attn_ops.causal_attend_step(
+        q, k_cache, v_cache, 1, k_new, v_new, positions, **case)
+    want = attn_ops.causal_attention(
+        q, gen._lay_rows_over(k_cache[1], k_new, positions),
+        gen._lay_rows_over(v_cache[1], v_new, positions),
+        kv_offset=positions, **case)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+def walked_server(form, monkeypatch, **kwargs):
+    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", STEP_BLOCK)
+    cfg, params = step_model(form)
+    return cfg, params, InferenceServer(
+        params, cfg, prefill_buckets=(8, 32), **kwargs)
+
+
+@pytest.mark.parametrize("form", sorted(STEP_FORMS))
+def test_one_decode_program_whatever_the_frontier(form, monkeypatch):
+    """``live`` is always an argument of the one decode program: rounds
+    that stop at different blocks, the warm-up's round with no lane live
+    and a caller that names no mask all run the same executable, and the
+    tokens are solo ``generate``'s."""
+    cfg, params, server = walked_server(
+        form, monkeypatch, n_slots=3, warmup=True, recompile_fail=True)
+    before = server.compile_counts()
+    assert before["decode"] == 1
+    prompts = [list(range(1, 4)), list(range(5, 25)), list(range(9, 42))]
+    budgets = [12, 6, 3]    # the longest leaves first, then the next
+    handles = [server.submit(Request(prompt=p, max_new_tokens=n))
+               for p, n in zip(prompts, budgets)]
+    seen = set()
+    while server.step():
+        st = server.slots
+        active = st.decoding_slots()
+        if active:
+            seen.add(int(engine_mod.decode_rows_read(
+                st.positions, np.isin(np.arange(3), active), cfg)))
+    assert seen == {STEP_BLOCK, 2 * STEP_BLOCK, 3 * STEP_BLOCK}
+    for h, p, n in zip(handles, prompts, budgets):
+        assert h.tokens == solo_greedy(params, cfg, p, n)
+    s = server.engine.n_slots
+    server.engine.decode_step(
+        np.zeros(s, np.int32), np.full(s, STEP_ROWS - 1, np.int32),
+        np.ones(s, np.float32), np.zeros(s, np.int32),
+        np.ones(s, np.float32), np.zeros(s, bool), np.zeros(s, np.uint32))
+    assert server.compile_counts() == before
+    fam = server.metrics.registry.counter(
+        "mingpt_recompiles_total", labels=("family",))
+    assert sum(child.value for _, child in fam.children()) == 0
+
+
+def test_the_decode_rows_counters_count_what_the_program_reads(monkeypatch):
+    """``decode_rows_read`` over ``decode_rows_reserved``: equal where a
+    slot is one block (every step reads it whole), a known fraction where
+    the walk stops: one request that never leaves the first of four
+    blocks reads a quarter."""
+    cfg, params = step_model("per-head")
+    whole = InferenceServer(params, cfg, n_slots=2)
+    whole.generate_batch([Request(prompt=[1, 2, 3], max_new_tokens=5)])
+    got = whole.summary()
+    assert got["decode_rows_read"] == got["decode_rows_reserved"] \
+        == 4 * 2 * STEP_ROWS
+    _, _, server = walked_server("per-head", monkeypatch, n_slots=2,
+                                 warmup=True)
+    assert server.summary()["decode_rows_reserved"] == 0  # the warm-up's
+    server.generate_batch([Request(prompt=[1, 2, 3], max_new_tokens=5)])
+    got = server.summary()
+    assert got["decode_rows_reserved"] == 4 * 2 * STEP_ROWS
+    assert got["decode_rows_read"] * 4 == got["decode_rows_reserved"]
+    # a second request that stands in the third block: three quarters
+    server.generate_batch([Request(prompt=list(range(1, 36)),
+                                   max_new_tokens=3)])
+    after = server.summary()
+    assert after["decode_rows_read"] - got["decode_rows_read"] \
+        == 2 * 2 * 3 * STEP_BLOCK
+    from mingpt_distributed_tpu.telemetry.export import render_prometheus
+    text = render_prometheus(server.metrics.registry)
+    assert f"mingpt_serve_decode_rows_read_total {after['decode_rows_read']}" \
+        in text
+    assert "mingpt_serve_decode_rows_reserved_total " \
+        f"{after['decode_rows_reserved']}" in text
