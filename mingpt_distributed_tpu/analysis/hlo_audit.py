@@ -56,6 +56,7 @@ from mingpt_distributed_tpu.analysis.core import (
     EXIT_CLEAN,
     EXIT_FINDINGS,
 )
+from mingpt_distributed_tpu.telemetry.programs import compile_programs
 
 __all__ = [
     "AUDIT_SCHEMA",
@@ -131,8 +132,7 @@ def lower_programs(
     the jit call cache, so the owner's ``compile_counts()`` and an armed
     recompile watchdog are untouched by an audit."""
     artifacts: Dict[Tuple[str, str], ProgramArtifact] = {}
-    for family, variant, jitted, args, kwargs in programs:
-        compiled = jitted.lower(*args, **kwargs).compile()
+    for family, variant, compiled in compile_programs(programs):
         artifacts[(family, variant)] = ProgramArtifact(
             family=family,
             variant=variant,

@@ -107,6 +107,7 @@ from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.serving import quant as quant_lib
 from mingpt_distributed_tpu.serving.kv_pool import PrefixKVStore, SlotKVPool
+from mingpt_distributed_tpu.telemetry import programs as program_lib
 from mingpt_distributed_tpu.telemetry.spans import SpanTracer
 
 #: smallest default bucket — prompts below this pay one 64-token forward,
@@ -929,40 +930,46 @@ class DecodeEngine:
         """Yield ``(family, variant, jitted, args, kwargs)`` for every
         compiled program of this engine, with the arguments of a real
         call: what ``jitted.lower(*args, **kwargs)`` needs to build the
-        program the serving loop runs. Family names mirror
-        ``compile_counts()`` keys (prefixed for a draft engine) and key
-        ``audit_contracts``; prefill/prefix variants are per ladder
-        bucket."""
+        program the serving loop runs. The weights and the pool go as
+        abstract values with the live arrays' shapes, dtypes and shardings,
+        taken now: the pool is donated every round, and a caller may lower
+        later (``telemetry/programs.py``); the small vectors are NumPy's, as
+        the serving loop hands them over, so enumerating dispatches nothing.
+        Family names mirror ``compile_counts()`` keys (prefixed for a draft
+        engine) and key ``audit_contracts``; prefill/prefix variants are per
+        ladder bucket."""
+        params = program_lib.abstract(self.program_params)
+        cache = program_lib.abstract(self.pool.cache)
         for b in self.buckets:
             yield (family_prefix + "prefill", f"b{b}", self._prefill_jit,
-                   (self.program_params, self.pool.cache,
-                    jnp.zeros(b, jnp.int32),
+                   (params, cache,
+                    np.zeros(b, np.int32),
                     np.int32(b), np.int32(0), np.int32(0),
                     np.float32(1.0), np.int32(0), np.float32(1.0),
                     np.bool_(False), np.uint32(0)), {})
         s = self.n_slots
         yield (family_prefix + "decode", "", self._decode_jit,
-               (self.program_params, self.pool.cache,
-                jnp.zeros(s, jnp.int32), jnp.zeros(s, jnp.int32),
-                jnp.ones(s, jnp.float32), jnp.zeros(s, jnp.int32),
-                jnp.ones(s, jnp.float32), jnp.zeros(s, bool),
-                jnp.zeros(s, jnp.uint32), jnp.zeros(s, jnp.int32),
-                jnp.ones(s, bool)), {})
+               (params, cache,
+                np.zeros(s, np.int32), np.zeros(s, np.int32),
+                np.ones(s, np.float32), np.zeros(s, np.int32),
+                np.ones(s, np.float32), np.zeros(s, bool),
+                np.zeros(s, np.uint32), np.zeros(s, np.int32),
+                np.ones(s, bool)), {})
         if self.prefix_store is None:
             return
         for b in self.buckets:
             if b > self.prefill_len - 1:
                 continue
             yield (family_prefix + "prefix_save", f"b{b}", self._extract_jit,
-                   (self.pool.cache, np.int32(0)), {"rows": b})
+                   (cache, np.int32(0)), {"rows": b})
             entry = {}
-            for name in _kv_leaves(self.pool.cache):
-                arr = self.pool.cache[name]
+            for name in _kv_leaves(cache):
+                arr = cache[name]
                 l, _, _, kv, last = arr.shape
                 entry[name] = jax.ShapeDtypeStruct(
                     (l, 1, b, kv, last), arr.dtype)
             yield (family_prefix + "prefix_load", f"b{b}", self._install_jit,
-                   (self.pool.cache, entry, np.int32(0)), {})
+                   (cache, entry, np.int32(0)), {})
 
     # -- static audit contracts (ISSUE 15) -----------------------------
     def audit_contracts(self, family_prefix: str = "") -> Dict[str, dict]:

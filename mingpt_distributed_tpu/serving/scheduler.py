@@ -108,6 +108,7 @@ from mingpt_distributed_tpu.telemetry import (
     SpanTracer,
     log_event,
 )
+from mingpt_distributed_tpu.telemetry.programs import program_records
 from mingpt_distributed_tpu.telemetry.tracing import (
     TraceRecorder,
     trace_baggage,
@@ -398,6 +399,11 @@ class InferenceServer:
             if self.spec is not None:
                 self.spec.warmup()
             self.watchdog.arm()
+        # which named scope each instruction of each program came from: made
+        # when somebody first reads the tracer, never with it disabled
+        self.tracer.pin("program", lambda: program_records(
+            [*self.engine.programs(),
+             *(self.spec.programs() if self.spec is not None else ())]))
 
     def observe_quant_logit_error(self, err: float) -> None:
         """Record a sampled quantization quality number (max |Δlogit| of

@@ -45,6 +45,7 @@ import numpy as np
 
 from ..config import GPTConfig
 from ..models import generate as gen
+from ..telemetry import programs as program_lib
 from .engine import (
     DecodeEngine,
     _forward_slot_lane,
@@ -391,8 +392,9 @@ class SpeculativeDecoder:
         ``family_prefix`` prefixes every family (graftaudit audits a
         quantized decoder beside the fp32 one as ``q8_*``)."""
         yield (f"{family_prefix}verify", f"k{self.k}", self._verify_jit,
-               (self.target.program_params, self.target.pool.cache,
-                jnp.zeros(self.rows, jnp.int32),
+               (program_lib.abstract(self.target.program_params),
+                program_lib.abstract(self.target.pool.cache),
+                np.zeros(self.rows, np.int32),
                 np.int32(0), np.int32(0),
                 np.float32(1.0), np.int32(0), np.float32(1.0),
                 np.uint32(0), np.int32(0)), {})

@@ -11,6 +11,10 @@ The whole stack reports through this package:
 * ``spans``     — monotonic-clock nested spans in a bounded ring with an
   optional JSONL sink, plus ``log_event`` (prefixed, attributable
   replacement for bare prints in multi-process paths).
+* ``programs``  — the ``program`` record (ISSUE 34): which named scope
+  each instruction of a compiled program came from, filed by the
+  program's owner as a pinned record of its tracer, so any device
+  profile sums by scope.
 * ``export``    — Prometheus text exposition + strict parser, the
   versioned JSONL event schema, and the stdlib ``/metrics`` +
   ``/healthz`` HTTP server.

@@ -21,7 +21,9 @@ Record layout (also the JSONL ``kind: "span"`` payload):
 "parent", <attrs...>}``. ``id`` counts up per tracer; ``parent`` is the
 ``id`` of the span open around this one on the same thread, or None: self
 time is a span's duration minus its children's. Point events
-(``tracer.event``) carry ``{"name", "ts", "depth", <attrs...>}``.
+(``tracer.event``) carry ``{"name", "ts", "depth", <attrs...>}``. A pinned
+record (``tracer.pin``) is its owner's: the ``program`` record of a compiled
+program is laid out in ``telemetry/programs.py``.
 
 Overhead discipline: a disabled tracer returns one shared no-op context
 manager (no allocation per call, no annotation), and an enabled span costs
@@ -39,7 +41,7 @@ import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from mingpt_distributed_tpu.telemetry.export import JsonlEventSink
 
@@ -147,6 +149,8 @@ class SpanTracer:
         self.sink = sink
         self.emitted = 0  # total ever recorded; ring keeps the newest
         self._ring: deque = deque(maxlen=capacity)
+        self._pinned: List[Dict[str, Any]] = []
+        self._pins: List[tuple] = []    # (kind, make) not yet made
         self._ids = itertools.count(1)
         self._tls = threading.local()
 
@@ -189,29 +193,58 @@ class SpanTracer:
         rec.update(attrs)
         self._record("event", rec)
 
+    def pin(self, kind: str,
+            make: Callable[[], List[Dict[str, Any]]]) -> None:
+        """Records the ring's eviction never drops, made only when somebody
+        reads: ``make`` returns them, and is called once, by the first
+        ``records()`` or, where a JSONL sink is or gets attached, then. It
+        is how the owner of a compiled program files its ``program`` record
+        (``telemetry/programs.py``) at no cost to a run nobody profiles. A
+        disabled tracer ignores the call."""
+        if not self.enabled:
+            return
+        self._pins.append((kind, make))
+        if self.sink is not None:
+            self._make_pinned()
+
+    def _make_pinned(self) -> None:
+        while self._pins:
+            kind, make = self._pins.pop(0)
+            for rec in make():
+                rec["kind"] = kind
+                self._pinned.append(rec)
+                self._to_sink(rec)
+
+    def _to_sink(self, rec: Dict[str, Any]) -> None:
+        if self.sink is not None:
+            payload = dict(rec)
+            self.sink.write(payload.pop("kind"), payload)
+
     def _record(self, kind: str, rec: Dict[str, Any]) -> None:
         rec["kind"] = kind
         self._ring.append(rec)
         self.emitted += 1
-        if self.sink is not None:
-            payload = dict(rec)
-            payload.pop("kind")
-            self.sink.write(kind, payload)
+        self._to_sink(rec)
 
     @property
     def dropped(self) -> int:
         return self.emitted - len(self._ring)
 
     def records(self) -> List[Dict[str, Any]]:
-        """Snapshot of the ring, oldest first."""
-        return list(self._ring)
+        """The pinned records, then a snapshot of the ring, oldest first."""
+        self._make_pinned()
+        return self._pinned + list(self._ring)
 
     def attach_jsonl(self, path: str) -> None:
         """Start streaming spans/events to a JSONL file (idempotent for
-        the same tracer: replaces any previous sink)."""
+        the same tracer: replaces any previous sink). The pinned records
+        lead the file."""
         if self.sink is not None:
             self.sink.close()
         self.sink = JsonlEventSink(path)
+        for rec in self._pinned:
+            self._to_sink(rec)
+        self._make_pinned()
 
     def close(self) -> None:
         if self.sink is not None:

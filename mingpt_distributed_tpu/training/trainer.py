@@ -61,6 +61,7 @@ from mingpt_distributed_tpu.telemetry import (
     TelemetryServer,
     log_event,
 )
+from mingpt_distributed_tpu.telemetry import programs as program_lib
 
 TrainState = Dict[str, Any]  # {"params", "opt_state", "step"}
 
@@ -509,6 +510,10 @@ class GPTTrainer:
         if self.is_writer:
             log_event(gpt.model_size_report(self.state["params"], gpt_config),
                       tracer=self.tracer)
+        # which named scope each instruction of the step came from: made
+        # when somebody first reads the tracer (or with ``spans_jsonl``, now)
+        self.tracer.pin("program", lambda: program_lib.program_records(
+            self.programs()))
 
     # ------------------------------------------------------------------
     def _fresh_state(self, rng) -> TrainState:
@@ -534,11 +539,12 @@ class GPTTrainer:
         """Yield ``(family, variant, jitted, args, kwargs)`` for the
         compiled train step, against abstract state/batch avals —
         donation binds at execution, not lowering, so lowering these
-        consumes no live buffer. Family ``train_step``, variant ``zero``
-        (dp-sharded update, ISSUE 9) or ``dense``."""
-        abstract = lambda x: jax.ShapeDtypeStruct(
-            jnp.shape(x), jnp.result_type(x))
-        state_abs = jax.tree.map(abstract, self.state)
+        consumes no live buffer. The state's avals carry the live arrays'
+        shardings; the jit's own ``in_shardings`` already fix the layout,
+        so the text is the program that runs either way. Family
+        ``train_step``, variant ``zero`` (dp-sharded update, ISSUE 9) or
+        ``dense``."""
+        state_abs = program_lib.abstract(self.state)
         block = self.train_iter.view.block_size
         tok = jax.ShapeDtypeStruct(
             (self.config.batch_size, block), jnp.int32)
