@@ -47,8 +47,9 @@ from mingpt_distributed_tpu.ops import sparse_attention as sparse_ops
 Cache = Dict[str, jax.Array]
 
 #: the leaf of a serving pool's cache tree in which the cached forward
-#: counts an expert model's routed rows: (expert layers, E + 1) int32, the
-#: rows each expert computed and, last, the routes asked for
+#: counts an expert model's routed rows: (expert layers, E + 3) int32, the
+#: rows each expert computed and then the routes asked for, the blocks the
+#: experts' loop ran and the blocks its layout has
 #: (ops/moe.grouped_swiglu). It rides in the donated tree so that the
 #: programs add to it in place and nothing is fetched in a round.
 MOE_ROWS = "moe_rows"
@@ -119,7 +120,7 @@ def init_moe_rows(cfg: GPTConfig) -> Optional[jax.Array]:
     the dropless route does)."""
     if not (cfg.n_experts and cfg.moe_scoring == "sigmoid"):
         return None
-    return jnp.zeros((cfg.n_layer - cfg.n_dense_layers, cfg.n_experts + 1),
+    return jnp.zeros((cfg.n_layer - cfg.n_dense_layers, cfg.n_experts + 3),
                      jnp.int32)
 
 
@@ -170,14 +171,15 @@ def _cached_block(
     layer: int,
     offset: jax.Array,       # absolute position of x[:, 0]: scalar, or (B,)
     cfg: GPTConfig,
-    valid: Optional[jax.Array] = None,  # (B, T) bool: tokens worth counting
+    valid: Optional[jax.Array] = None,  # (B, T) bool: tokens of a request
     expert_layer: Optional[int] = None,  # blk's EXPERT_LEAVES are the stack's
     frontier: Optional[jax.Array] = None,  # furthest (B,) offset that counts
 ) -> Tuple[jax.Array, Cache, Cache, Optional[jax.Array]]:
     """One pre-LN block against the cache. Returns (y, cache, rows,
     counts): the block's own (B, T, heads, size) k/v ``rows`` in the
     cache's dtype, and a dropless expert layer's counts of the ``valid``
-    tokens' routed rows (ops/moe.grouped_swiglu; None for any other MLP).
+    tokens' routed rows (ops/moe.grouped_swiglu, which routes no other
+    token; None for any other MLP).
 
     ``offset`` is one position for the whole batch (prefill, verify, solo
     ``generate``: rows that advance together): the rows are written into
@@ -451,10 +453,11 @@ def _forward_cached_hidden(
 
     A cache that carries MOE_ROWS (a serving pool's) gets the expert
     layers' counts of the ``valid`` (B, T) tokens' routed rows added to it
-    (None: every token counts; a prefill's padding and a parked decode
-    lane are computed and not counted). ``frontier`` bounds what a step
-    under a position a lane reads of a long slice (``_cached_block``; a
-    hybrid stack's sparse layers read every row and take no notice).
+    (None: every token is routed and counts; a prefill's padding and a
+    decode lane without a request take no routed expert, and what they
+    leave in the cache is nobody's to read). ``frontier`` bounds what a
+    step under a position a lane reads of a long slice (``_cached_block``;
+    a hybrid stack's sparse layers read every row and take no notice).
 
     The layer loop is a static python loop (n_layer is static, decode
     bodies are small) so each layer's cache update stays a one-slot

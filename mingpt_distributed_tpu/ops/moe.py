@@ -230,26 +230,38 @@ def sigmoid_routes(h, w_router, bias, *, top_k: int, norm_topk: bool,
 
 @jax.named_scope("moe_experts")
 def grouped_swiglu(x, chosen, w_gate, w_up, w_down, valid=None, layer=None):
-    """Every token's chosen experts on it: (N, D) tokens and (N, k) experts
-    -> ((N, k, D) float32 expert outputs, (E + 1,) int32 counts). The
-    weights are one layer's (E, D, F) / (E, F, D) or, with ``layer``, the
-    whole stack's (L, E, ...) of which that layer is taken: the scan below
-    then reads its one expert a step straight out of the stacked leaf. (A
-    layer sliced out first is an operand of the loop, and the TPU compiler
-    copies it there whole: 1.1 GB a layer at 128 experts of 2,048 x 768.)
+    """The chosen experts on every ``valid`` token: (N, D) tokens and (N, k)
+    experts -> ((N, k, D) float32 expert outputs, (E + 3,) int32 counts).
+    The weights are one layer's (E, D, F) / (E, F, D) or, with ``layer``,
+    the whole stack's (L, E, ...) of which that layer is taken: the loop
+    below then reads its one expert a step straight out of the stacked
+    leaf. (A layer sliced out first is an operand of the loop, and the TPU
+    compiler copies it there whole: 1.1 GB a layer at 128 experts of
+    2,048 x 768.)
 
     Nothing is dropped and no expert computes a token that did not choose
-    it: the N*k routes are laid out grouped by expert, each group padded
-    to whole blocks of ``_row_block`` rows (at most E - 1 blocks of
-    padding in all, whatever the load: the shapes are static), and a scan
-    takes one block a step through its one expert's three matrices. A
-    route's result depends on its own token alone, so which other tokens
-    share the call changes nothing for it.
+    it: the routes of the tokens of ``valid`` (N,) (None: all) are laid out
+    grouped by expert, each group padded to whole blocks of ``_row_block``
+    rows. The layout's shape is static, the worst case (at most E - 1
+    blocks of padding, whatever the load); the blocks that hold a row are
+    its first ``sum_e ceil(size_e / bm)``, and the loop takes those and no
+    other through their expert's three matrices: a block that holds no row
+    runs no dot and reads no weight. With ``layer`` (the cached forward:
+    inference only) the loop's trip count is that number. Without, the
+    call may be differentiated (``gpt.forward`` trains through it) and a
+    loop of unknown length cannot be: the trip count is the layout's, and
+    a ``lax.cond`` a step passes an empty block by (on the chip 2.5-3.5 us
+    at 8 rows a block and 15 at 128, where a block's read is 16: PERF.md,
+    PR 35). A route's result depends on its own token alone, so which
+    other tokens share the call changes nothing for it. A token that is not
+    ``valid`` (a lane without a request, a bucket's padding) has no route
+    laid: its outputs are zeros.
 
-    ``counts[:E]`` are the rows each expert's blocks computed for tokens
-    of ``valid`` (N,) (None: all), counted from the layout the blocks
-    read; ``counts[E]`` is the routes those tokens asked for. The two
-    agree exactly when nothing was dropped."""
+    ``counts[:E]`` are the rows each expert's blocks computed, counted from
+    the layout the blocks that ran read; ``counts[E]`` is the routes the
+    ``valid`` tokens asked for: the two agree exactly when nothing was
+    dropped. ``counts[E + 1]`` is the blocks the loop took through an
+    expert and ``counts[E + 2]`` the blocks the layout has."""
     n, d = x.shape
     k = chosen.shape[1]
     e, first = w_gate.shape[-3], 0
@@ -262,44 +274,55 @@ def grouped_swiglu(x, chosen, w_gate, w_up, w_down, valid=None, layer=None):
     n_blocks = -(-rows // bm) + e - 1
 
     flat = chosen.reshape(rows)
-    onehot = jax.nn.one_hot(flat, e, dtype=jnp.int32)            # (R, E)
+    asked = jnp.ones((rows,), bool) if valid is None else jnp.repeat(valid, k)
+    onehot = jax.nn.one_hot(flat, e, dtype=jnp.int32) * asked[:, None]  # (R, E)
     sizes = onehot.sum(0)                                         # (E,)
     rank = jnp.take_along_axis(
         jnp.cumsum(onehot, axis=0) - onehot, flat[:, None], axis=1)[:, 0]
     blocks_of = -(-sizes // bm)                                   # (E,)
-    first_block = jnp.cumsum(blocks_of) - blocks_of
-    dest = first_block[flat] * bm + rank                          # (R,)
-    # the block's expert; blocks past the last group are padding of the
-    # last expert, and hold no row
+    last_block = jnp.cumsum(blocks_of)
+    # a route nobody asked for lies past the layout: nothing is written
+    # there and what is read there is zeros
+    dest = jnp.where(asked, (last_block - blocks_of)[flat] * bm + rank,
+                     n_blocks * bm)                               # (R,)
+    # the block's expert; the blocks from ``last_block[-1]`` on hold no row
     block_expert = jnp.minimum(jnp.searchsorted(
-        jnp.cumsum(blocks_of), jnp.arange(n_blocks), side="right"), e - 1)
+        last_block, jnp.arange(n_blocks), side="right"), e - 1)
     # which route lies at each row of the layout (rows: padding)
     source = jnp.full((n_blocks * bm,), rows, jnp.int32).at[dest].set(
-        jnp.arange(rows, dtype=jnp.int32))
+        jnp.arange(rows, dtype=jnp.int32), mode="drop")
     real = source < rows
     token = jnp.where(real, source // k, 0)
     laid = jnp.where(real[:, None], x[token], 0).reshape(n_blocks, bm, d)
 
-    def block(_, item):
-        rows_in, ex = item
-        ex = first + ex
+    def block(i, out):
+        # block i through its expert's three matrices, written where it lies
+        rows_in, ex = laid[i], first + block_expert[i]
         gate = jnp.dot(rows_in, w_gate[ex].astype(x.dtype),
                        preferred_element_type=jnp.float32)
         up = jnp.dot(rows_in, w_up[ex].astype(x.dtype),
                      preferred_element_type=jnp.float32)
         inner = (jax.nn.silu(gate) * up).astype(x.dtype)
-        return None, jnp.dot(inner, w_down[ex].astype(x.dtype),
-                             preferred_element_type=jnp.float32)
+        return jax.lax.dynamic_update_index_in_dim(out, jnp.dot(
+            inner, w_down[ex].astype(x.dtype),
+            preferred_element_type=jnp.float32), i, 0)
 
-    _, out = jax.lax.scan(block, None, (laid, block_expert))
-    out = out.reshape(n_blocks * bm, d)[dest].reshape(n, k, d)
+    ran = last_block[-1]
+    out = jnp.zeros((n_blocks, bm, d), jnp.float32)
+    if layer is None:
+        # every block a step, those that hold no row passed by
+        out = jax.lax.fori_loop(0, n_blocks, lambda i, out: jax.lax.cond(
+            i < ran, block, lambda i, out: out, i, out), out)
+    else:
+        out = jax.lax.fori_loop(0, ran, block, out)
+    out = out.reshape(n_blocks * bm, d).at[dest].get(
+        mode="fill", fill_value=0).reshape(n, k, d)
 
-    live = real if valid is None else real & valid[token]
+    in_run = real.reshape(n_blocks, bm).sum(1) * (jnp.arange(n_blocks) < ran)
     computed = (jax.nn.one_hot(block_expert, e, dtype=jnp.int32)
-                * live.reshape(n_blocks, bm).sum(1)[:, None]).sum(0)
-    asked = k * (n if valid is None else valid.sum())
-    return out, jnp.concatenate(
-        [computed, jnp.asarray(asked, jnp.int32)[None]])
+                * in_run[:, None]).sum(0)
+    return out, jnp.concatenate([computed, jnp.stack([
+        asked.sum().astype(jnp.int32), ran, jnp.int32(n_blocks)])])
 
 
 def moe_dropless(
@@ -313,13 +336,13 @@ def moe_dropless(
     top_k: int,
     norm_topk: bool = True,
     route_scale: float = 1.0,
-    valid: jax.Array = None,   # (B, T) bool: the tokens the counts count
+    valid: jax.Array = None,   # (B, T) bool: the tokens that are routed
     layer: int = None,         # the expert weights are the stack's (L, E, ..)
 ) -> Tuple[jax.Array, jax.Array]:
     """The routed part of a DeepSeek-V3 expert layer: ``sum_i g_i
-    expert_i(x)`` over each token's k experts. Returns (out (B, T, D),
-    counts (E + 1,) int32: ``grouped_swiglu``, which also says what
-    ``layer`` is for)."""
+    expert_i(x)`` over each ``valid`` token's k experts (zeros for any
+    other). Returns (out (B, T, D), counts (E + 3,) int32:
+    ``grouped_swiglu``, which also says what ``layer`` is for)."""
     b, t, d = x.shape
     tokens = x.reshape(b * t, d)
     chosen, gates, _ = sigmoid_routes(

@@ -430,19 +430,22 @@ def _decode_impl(
     the program has the pool's size but the pool, and nothing a slice's. A
     slice of more than one block is read only as far as the furthest
     ``live`` lane stands (:func:`decode_rows_read`): a lane that is not
-    live attends what that leaves it, and its token is the caller's to
-    discard; a live lane attends every row its mask allows. Positions are
-    clipped, so a free lane
-    (parked at ``block_size - 1``) writes into its own lane and no other.
+    live attends what that leaves it, a routed model lays none of its
+    routes and counts none (``moe.grouped_swiglu``: it takes the shared
+    expert alone), a hybrid stack leaves its state as it was, and its
+    token is the caller's to discard; a live lane attends every row its
+    mask allows and takes every expert it chose. Positions are clipped, so
+    a free lane (parked at ``block_size - 1``) writes into its own lane and
+    no other.
     A quantized pool is dequantized, stepped and requantized whole
     (idempotent on the rows the step did not touch: serving/quant.py)."""
     safe_pos = jnp.clip(positions, 0, cfg.block_size - 1)
-    # a lane parked at the window's last row is no request: computed like
-    # any lane, and left out of a routed model's counts
+    # ``live`` and not the position says which lanes hold a request (a
+    # request's last step stands where a free lane is parked)
     logits, stepped = gen._forward_cached(
         params, tokens[:, None],
         _with_counter(_dequant_lane(cache, kv_quant, cfg), cache),
-        safe_pos, cfg, valid=(positions < cfg.block_size - 1)[:, None],
+        safe_pos, cfg, valid=None if live is None else live[:, None],
         frontier=decode_frontier(
             safe_pos, True if live is None else live))
     cache = _with_counter(_requant_lane(stepped, kv_quant), stepped)
@@ -651,7 +654,7 @@ class DecodeEngine:
 
     def moe_rows(self) -> Optional[np.ndarray]:
         """The routed-rows counter as it stands, fetched from the device:
-        (expert layers, E + 1), or None where the model counts none. The
+        (expert layers, E + 3), or None where the model counts none. The
         one transfer the counter ever costs; no round makes it."""
         return self._counter(gen.MOE_ROWS)
 
@@ -885,19 +888,24 @@ class DecodeEngine:
         derived inside the program: ``seeds`` are the (S,) request seeds
         (:func:`request_seeds`), ``token_index`` how many tokens each
         request has emitted (None: 0 a lane). ``live`` (S,) bool names the
-        lanes whose tokens the caller will use (None: all of them): the
-        pool is read as it lies, and a long slot only as far as the
-        furthest live lane stands (:func:`decode_rows_read`); it is always
-        an argument of the one program. The host vectors go to the
-        one jit call as they are; no other program is dispatched. Two
-        spans split the host's part: ``serve.decode_launch`` is the
-        staging of the arguments and the jit call up to its return (the
-        enqueue), ``serve.decode_sync`` the wait for the tokens."""
+        lanes whose tokens the caller will use (None: those not parked at
+        the window's last row, where the scheduler keeps a lane without a
+        request; a request's last step stands there too, so a caller that
+        runs one says so): the pool is read as it lies, and a long slot
+        only as far as the furthest live lane stands
+        (:func:`decode_rows_read`); a routed model's experts run for the
+        live lanes' routes alone, and a lane that is not live keeps its
+        recurrent state; it is always an argument of the one program. The
+        host vectors go to the one jit call as they are; no other program
+        is dispatched. Two spans split the host's part:
+        ``serve.decode_launch`` is the staging of the arguments and the jit
+        call up to its return (the enqueue), ``serve.decode_sync`` the wait
+        for the tokens."""
         with self.tracer.span("serve.decode_launch"):
             if token_index is None:
                 token_index = np.zeros(len(tokens), np.int32)
             if live is None:
-                live = np.ones(len(tokens), bool)
+                live = np.asarray(positions) < self.cfg.block_size - 1
             nxt, cache = self._decode_jit(
                 self.program_params, self.pool.cache,
                 np.asarray(tokens, np.int32),

@@ -518,7 +518,7 @@ class ServingMetrics:
                      ) -> None:
         """What the engine's programs read and what a cached token and a
         slot's state cost, known once it is built. ``moe_rows_source``
-        fetches a routed model's (expert layers, E + 1) counter of routed
+        fetches a routed model's (expert layers, E + 3) counter of routed
         rows from the device (``DecodeEngine.moe_rows``) and
         ``sparse_rows_source`` a hybrid stack's (2,) counter of the rows its
         sparse layers' decode steps attended (``DecodeEngine.sparse_rows``);
@@ -542,23 +542,30 @@ class ServingMetrics:
                 "sparse_rows_live": float(rows[1])}
 
     def _moe_summary(self) -> Dict[str, Any]:
-        """The routed-rows counter since the server was built: rows the
-        experts computed, routes asked for and not computed (0: the route
-        drops nothing, and this is where it would show), and the busiest
-        expert's rows over the mean, the worst layer's. None where the
+        """The routed-rows counter since the server was built
+        (``generate.MOE_ROWS``, all expert layers): rows the experts
+        computed, routes asked for and not computed (0: the route drops
+        nothing, and this is where it would show), the busiest expert's
+        rows over the mean, the worst layer's, and the blocks the experts'
+        loop took through an expert beside the blocks its layouts had (only
+        the blocks that hold a request's route are run). None where the
         model routes nothing this way."""
         rows = self._moe_rows_source() if self._moe_rows_source else None
         if rows is None:
-            return {"moe_routed_rows": None, "moe_dropped_rows": None,
-                    "moe_load_max_over_mean": None}
-        computed, asked = rows[:, :-1], rows[:, -1]
+            return dict.fromkeys((
+                "moe_routed_rows", "moe_dropped_rows",
+                "moe_load_max_over_mean", "moe_blocks_run",
+                "moe_blocks_laid"))
+        computed, (asked, ran, laid) = rows[:, :-3], rows[:, -3:].sum(0)
         mean = computed.mean(axis=1)
         return {
             "moe_routed_rows": int(computed.sum()),
-            "moe_dropped_rows": int(asked.sum() - computed.sum()),
+            "moe_dropped_rows": int(asked - computed.sum()),
             "moe_load_max_over_mean": float(
                 (computed.max(axis=1) / mean)[mean > 0].max())
             if (mean > 0).any() else None,
+            "moe_blocks_run": int(ran),
+            "moe_blocks_laid": int(laid),
         }
 
     def summary(self) -> Dict[str, Any]:

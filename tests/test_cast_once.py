@@ -334,6 +334,58 @@ def test_decode_program_reads_the_cache_as_it_lies(form, block, one_chip,
                        ops=("select",))
 
 
+def test_the_experts_loop_takes_the_stacked_leaves_as_they_lie(one_chip):
+    """A routed model's decode and prefill programs compiled for the chip
+    (PR 35): the stacked expert leaves are operands of the experts' loop,
+    whose trip count is the blocks that hold a row, and the chip's
+    compiler hands them over by reference: nothing of a layer's experts'
+    size or the stack's is copied, sliced or fused into a new buffer. A
+    layer sliced out of the stack before the loop is the control: the
+    chip's compiler copies it whole (PR 30: 1.1 GB a layer at kanana's
+    sizes)."""
+    from mingpt_distributed_tpu.ops import moe
+
+    cfg = GPTConfig.make(**{
+        **WIDE, **WIDE_FORMS["latent"], "n_layer": 3, "n_dense_layers": 1,
+        "ffn_dim": 512, "n_experts": 12, "moe_top_k": 2, "moe_ffn_dim": 128,
+        "n_shared_experts": 1, "moe_scoring": "sigmoid",
+        "moe_route_scale": 2.448, "param_dtype": "bfloat16"})
+    params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
+    engine = DecodeEngine(
+        jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params), cfg,
+        n_slots=8)
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    widths = {(cfg.n_embd, cfg.expert_width), (cfg.expert_width, cfg.n_embd)}
+
+    def expert_sized(text):
+        """Instructions that make a buffer shaped as one layer's (E, D, F)
+        experts or the two-layer stack's: anything but the leaf itself seen
+        under another shape."""
+        made = [(m.group(0), [int(n) for n in m.group(1).split(",")])
+                for m in re.finditer(
+                    r"= \w+\[([\d,]+)\]\S* "
+                    r"(?!parameter|bitcast|get-tuple-element)[\w-]+\(.*", text)]
+        return [line for line, dims in made if tuple(dims[-2:]) in widths
+                and np.prod(dims[:-2]) in (cfg.n_experts, 2 * cfg.n_experts)]
+
+    for family in ("decode", "prefill"):
+        _, _, jitted, args, kwargs = [
+            p for p in engine.programs() if p[0] == family][-1]
+        text = jitted.lower(*on_chip(args), **kwargs).compile().as_text()
+        assert "moe_experts/while/body" in text     # the loop is in it
+        assert not expert_sized(text)
+
+    def sliced_first(x, chosen, blocks):
+        return moe.grouped_swiglu(x, chosen, *(
+            blocks[n][1] for n in ("w_eg", "w_e1", "w_e2")))[0]
+    control = jax.jit(sliced_first).lower(*on_chip((
+        jnp.zeros((8, cfg.n_embd), jnp.bfloat16), jnp.zeros((8, 2), jnp.int32),
+        params["blocks"])))
+    assert expert_sized(control.compile().as_text())
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_metrics_say_what_the_programs_read(dtype):
     cfg, params = model("gpt2-untied", dtype)

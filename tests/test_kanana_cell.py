@@ -137,6 +137,43 @@ def test_the_read_row_share_reader_reads_the_scheduler_s_counters(cell_run):
             m["name"] for m in spec.load_cell(other).per_layer}
 
 
+def test_the_blocks_run_share_reader_reads_the_experts_counters(cell_run):
+    """``moe.blocks_run_share`` (PR 35): blocks the experts' loops of the
+    traced window took through an expert over the blocks their layouts
+    had, from two readings of ``summary()``; nothing where the program has
+    no such counters (the parent's, a dense model's ``None``) or the window
+    laid nothing out."""
+    read = spec.load_reader("moe.blocks_run_share").read
+    play = serve_cell.Play(n_slots=64, block_size=8192)
+    play.trace_open = {"moe_blocks_run": 5 * 90 * 10,
+                       "moe_blocks_laid": 5 * 175 * 10}
+    play.trace_close = {"moe_blocks_run": 5 * (90 * 10 + 70 * 30),
+                        "moe_blocks_laid": 5 * 175 * 40}
+    assert read({"play": play}) == 40.0
+    play.trace_close = dict(play.trace_open)
+    assert read({"play": play}) is None           # no step in the window
+    play.trace_open, play.trace_close = {"steps": 1}, {"steps": 9}
+    assert read({"play": play}) is None           # the parent's summary
+    play.trace_open = play.trace_close = {"moe_blocks_run": None,
+                                          "moe_blocks_laid": None}
+    assert read({"play": play}) is None           # a dense model's
+    assert read({"play": None}) is None
+    # the rehearsal's run: free lanes and padded buckets lay nothing out
+    run = cell_run["evidence"]["play"]
+    opened, closed = run.open_counters, run.close_counters
+    untraced = dataclasses.replace(run, trace_open=None, trace_close=None)
+    assert read({"play": untraced}) is None
+    assert closed["moe_blocks_laid"] > opened["moe_blocks_laid"]
+    traced = dataclasses.replace(run, trace_open=opened, trace_close=closed)
+    assert 0.0 < read({"play": traced}) < 100.0
+    cell = spec.load_cell(CELL)
+    assert "moe.blocks_run_share" in {m["name"] for m in cell.per_layer}
+    for other in ("gpt2-124m.serve-decode", "gpt2-xl.serve-prefill",
+                  "minicpm-sala.serve-long-context"):
+        assert "moe.blocks_run_share" not in {
+            m["name"] for m in spec.load_cell(other).per_layer}
+
+
 def test_the_new_readers_return_none_for_a_dense_cell():
     """On a cell of the parent's (or the parent itself, whose summary lacks
     the fields) there is nothing to read, and no reader raises."""

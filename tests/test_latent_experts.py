@@ -262,6 +262,43 @@ def test_the_decode_program_selects_nothing_of_a_slice_s_size(monkeypatch):
         assert slice_sized_selects(text, 5, BLOCK)
 
 
+def expert_sized(text, cfg, layers):
+    """Instructions of compiled HLO ``text`` that make a buffer shaped as
+    one layer's (E, D, F) experts or the ``layers``-deep stack's: anything
+    but the leaf itself seen under another shape."""
+    widths = {(cfg.n_embd, cfg.expert_width), (cfg.expert_width, cfg.n_embd)}
+    made = [(m.group(0), [int(n) for n in m.group(1).split(",")])
+            for m in re.finditer(
+                r"= \w+\[([\d,]+)\]\S* "
+                r"(?!parameter|bitcast|get-tuple-element)[\w-]+\(.*", text)]
+    return [line for line, dims in made if tuple(dims[-2:]) in widths
+            and np.prod(dims[:-2]) in (cfg.n_experts, layers * cfg.n_experts)]
+
+
+def test_the_decode_program_copies_nothing_of_an_expert_stack_s_size():
+    """The engine's decode and prefill programs compiled for this backend:
+    the stacked expert leaves reach the experts' loop as they lie: nothing
+    of a layer's experts' size, or the stack's, is copied, sliced or
+    converted on the way (sliced out before the loop, a layer's experts are
+    copied whole: on the chip 1.1 GB a layer, PERF.md, PR 30). That form is
+    the control."""
+    cfg, params = model()
+    eng = DecodeEngine(params, cfg, n_slots=5, prefill_len=32)
+    for family in ("decode", "prefill"):
+        _, _, jitted, args, kwargs = [
+            p for p in eng.programs() if p[0] == family][-1]
+        text = jitted.lower(*args, **kwargs).compile().as_text()
+        assert "moe_experts/while" in text          # the loop is in it
+        assert not expert_sized(text, cfg, 2)
+
+    def sliced_first(x, chosen, blocks):
+        return moe.grouped_swiglu(x, chosen, *(
+            blocks[n][1] for n in ("w_eg", "w_e1", "w_e2")))[0]
+    control = jax.jit(sliced_first).lower(
+        jnp.zeros((5, 64)), jnp.zeros((5, 3), jnp.int32), params["blocks"])
+    assert expert_sized(control.compile().as_text(), cfg, 2)
+
+
 @pytest.mark.parametrize("furthest", [15, 16, 17],
                          ids=["below-an-edge", "on-an-edge", "above-an-edge"])
 def test_the_engine_s_step_under_a_live_mask_is_its_step_under_all_true(
@@ -294,7 +331,7 @@ def test_the_engine_s_step_under_a_live_mask_is_its_step_under_all_true(
              [True, True, False, False])):
         positions, live = np.array(positions, np.int32), np.array(live)
         got, got_rows = step(positions, live)
-        want, want_rows = step(positions, None)
+        want, want_rows = step(positions, np.ones(4, bool))
         np.testing.assert_array_equal(got[live], want[live])
         for name in ("k", "v"):
             for lane in np.flatnonzero(live):
@@ -333,12 +370,14 @@ def test_the_engine_s_programs_agree_with_the_reference(reference):
         got = np.asarray(eng.pool.cache[name][:, 1, :26])
         want = np.asarray(rows[:, 0])
         assert np.sqrt(((got - want) ** 2).sum() / (want ** 2).sum()) < 1e-5
-    # 26 real tokens took 3 routes in each of 2 expert layers; the parked
-    # lanes and the prefill's padding were computed and not counted
+    # 26 real tokens took 3 routes in each of 2 expert layers; the lanes
+    # without a request and the prefill's padding were neither routed nor
+    # counted, and the loops ran fewer blocks than their layouts have
     counter = eng.moe_rows()
-    assert counter.shape == (2, 9)
-    assert counter[:, -1].tolist() == [78, 78]
-    assert counter[:, :-1].sum(1).tolist() == [78, 78]
+    assert counter.shape == (2, 11)
+    assert counter[:, 8].tolist() == [78, 78]
+    assert counter[:, :8].sum(1).tolist() == [78, 78]
+    assert (counter[:, 9] < counter[:, 10]).all() and (counter[:, 9] > 0).all()
 
 
 @pytest.mark.parametrize("what,ok", [("as-served", True),
@@ -431,9 +470,9 @@ def test_the_dropless_route_computes_every_row_where_capacity_drops(skew):
     want = dense_experts(x, chosen, gates, (w[1], w[0], w[2]))
     np.testing.assert_allclose(jnp.einsum("nkd,nk->nd", out, gates), want,
                                atol=1e-5)
-    assert int(counts[-1]) == 48 * 3 == int(counts[:-1].sum())
+    assert int(counts[8]) == 48 * 3 == int(counts[:8].sum())
     np.testing.assert_array_equal(
-        counts[:-1], np.bincount(np.asarray(chosen).ravel(), minlength=8))
+        counts[:8], np.bincount(np.asarray(chosen).ravel(), minlength=8))
     if skew:
         assert int(counts[:3].sum()) == 48 * 3      # all on three experts
         # the same load through the capacity route: rows are dropped
@@ -452,9 +491,74 @@ def test_the_counter_counts_the_valid_tokens_only():
                                       norm_topk=True, route_scale=1.0)
     valid = jnp.arange(48) < 10
     _, counts = moe.grouped_swiglu(x, chosen, w[1], w[0], w[2], valid)
-    assert int(counts[-1]) == 30 == int(counts[:-1].sum())
+    assert int(counts[8]) == 30 == int(counts[:8].sum())
     np.testing.assert_array_equal(
-        counts[:-1], np.bincount(np.asarray(chosen[:10]).ravel(), minlength=8))
+        counts[:8], np.bincount(np.asarray(chosen[:10]).ravel(), minlength=8))
+
+
+def route_by_route(x, chosen, w, bm):
+    """Each route's row through its expert's three matrices, alone in a
+    block of ``bm`` rows of zeros (a matmul of another height may sum a
+    row in another order)."""
+    gate, up, down = (np.asarray(a) for a in w)
+    out = np.zeros(chosen.shape + x.shape[1:], np.float32)
+    for t, j in np.ndindex(*chosen.shape):
+        ex = int(chosen[t, j])
+        rows = jnp.zeros((bm,) + x.shape[1:]).at[0].set(x[t])
+        inner = jax.nn.silu(jnp.dot(rows, gate[ex])) * jnp.dot(rows, up[ex])
+        out[t, j] = jnp.dot(inner, down[ex])[0]
+    return out
+
+
+@pytest.mark.parametrize("leaves", ["a-layer-s", "the-stack-s"])
+@pytest.mark.parametrize("load,held", [
+    ("even", "some"), ("one-expert", "some"), ("skewed", "some"),
+    ("skewed", "none"), ("skewed", "unsaid"), ("even", "unsaid")])
+def test_only_the_blocks_that_hold_a_valid_token_s_route_are_run(
+        load, held, leaves):
+    """The routes of ``valid`` tokens alone are laid out, and the loop takes
+    through an expert only the blocks that hold one: a valid token's
+    outputs are, exactly, its rows through its experts' matrices whatever
+    else the call holds; any other token's are zeros; the counts say what
+    ran (``valid=None``: every token is a request's). Both loops: over a
+    layer's own leaves (a conditional a step: the form that is
+    differentiated) and over the stack's with the layer's index (as many
+    steps as blocks hold a row: the cached forward's)."""
+    n, k, e = 24, 2, 8
+    x, w_router, bias, w = routed(n=n, skew=4.0)
+    w = (w[1], w[0], w[2])
+    stacked = leaves == "the-stack-s"
+    given = tuple(jnp.stack([-a, a]) for a in w) + (1,) if stacked else w
+    chosen = {
+        "even": (k * jnp.arange(n)[:, None] + jnp.arange(k)) % e,
+        "one-expert": jnp.full((n, k), 3),
+        "skewed": moe.sigmoid_routes(x, w_router, bias, top_k=k,
+                                     norm_topk=True, route_scale=1.0)[0],
+    }[load].astype(jnp.int32)
+    valid = {"some": (jnp.arange(n) % 3 != 1) & (jnp.arange(n) < 20),
+             "none": jnp.zeros(n, bool), "unsaid": None}[held]
+    out, counts = jax.jit(
+        lambda x, chosen, wg, wu, wd, valid, layer=None: moe.grouped_swiglu(
+            x, chosen, wg, wu, wd, valid, layer), static_argnums=6)(
+        x, chosen, *given[:3], valid, *given[3:])
+    out, counts = np.asarray(out), np.asarray(counts)
+    assert out.shape == (n, k, 32) and counts.shape == (e + 3,)
+    assert np.isfinite(out).all()
+
+    asked = np.ones(n, bool) if valid is None else np.asarray(valid)
+    bm = moe._row_block(n * k, e)
+    np.testing.assert_array_equal(out[asked],
+                                  route_by_route(x, chosen, w, bm)[asked])
+    assert not out[~asked].any()
+    sizes = np.bincount(np.asarray(chosen)[asked].ravel(), minlength=e)
+    np.testing.assert_array_equal(counts[:e], sizes)
+    assert counts[e] == k * asked.sum() == sizes.sum()
+    assert counts[e + 1] == (-(-sizes // bm)).sum()
+    assert counts[e + 2] == -(-n * k // bm) + e - 1
+    if held == "none":
+        assert counts[e + 1] == 0
+    elif load == "one-expert":
+        assert counts[e + 1] == -(-k * asked.sum() // bm) > 1
 
 
 def test_a_lane_s_output_does_not_depend_on_which_lanes_are_live():
@@ -601,11 +705,32 @@ def test_slots_are_reused_and_the_tokens_are_solo_generate_s(served):
     assert summary["moe_dropped_rows"] == 0
     assert summary["moe_routed_rows"] > 0
     assert summary["moe_load_max_over_mean"] >= 1.0
+    assert 0 < summary["moe_blocks_run"] < summary["moe_blocks_laid"]
     assert summary["program_weights_cast"] == 0
     facts = server.engine.pool.audit_facts()
     assert facts["cache_leaf_shapes"] == {"k": (3, 2, BLOCK, 1, 8),
                                           "v": (3, 2, BLOCK, 1, 32)}
     assert facts["cache_leaf_elems"] == 3 * 2 * BLOCK * 8
+
+
+def test_a_request_s_last_step_at_the_window_s_last_row_is_routed():
+    """A request that fills the window takes its last step where a lane
+    without a request is parked; ``live`` and not the position says which
+    it is, so its last token is ``generate``'s (its routes laid out like
+    any other step's), beside a free lane and with every lane busy."""
+    cfg, params = model()
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, VOCAB, size=n).tolist() for n in (30, 29)]
+    new = [BLOCK - len(p) + 1 for p in prompts]
+    want = [np.asarray(gen.generate(params, cfg, np.asarray([p]), n))[
+        0, len(p):].tolist() for p, n in zip(prompts, new)]
+    for busy in (1, 2):
+        server = InferenceServer(params, cfg, n_slots=2, prefill_len=32)
+        handles = server.generate_batch([
+            Request(prompt=p, max_new_tokens=n, do_sample=False)
+            for p, n in zip(prompts[:busy], new)])
+        assert [h.tokens for h in handles] == want[:busy]
+        assert server.metrics.summary()["moe_dropped_rows"] == 0
 
 
 def test_the_prefix_store_copies_latent_rows(served):
@@ -643,4 +768,5 @@ def test_a_dense_model_s_summary_has_the_fields_and_no_counter():
     assert summary["kv_bytes_per_row"] == 1 * 2 * 32 * 4
     assert summary["moe_routed_rows"] is None
     assert summary["moe_dropped_rows"] is None
+    assert summary["moe_blocks_run"] is summary["moe_blocks_laid"] is None
     assert sorted(server.engine.pool.cache) == ["k", "v"]
