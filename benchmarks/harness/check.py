@@ -8,16 +8,34 @@ nothing here knows an architecture's parameter names.
 What a reference owes the check (``sizes`` is the configuration file as
 loaded, so a reference reads its published keys under their own names):
 
-  ``hidden(weights, tokens (B, T) int32, sizes)`` -> ``(x, ks, vs)``: ``x``
-      (B, T, d) the hidden states after the final norm, float32; ``ks`` and
-      ``vs`` (L, B, T, KV, hd) every layer's keys and values *as the
-      architecture caches them*: after any norm of the projected keys and
-      after any rotation by position (a rotary model's keys are cached
-      rotated, so the reference returns them rotated), with the KV heads
-      the architecture keeps (not repeated up to the query heads). The
-      serving check holds the rows the engine's programs left in a slot to
-      exactly these; a cache that is not ``{"k", "v"}`` of this layout (a
-      latent, a state, a window ring) needs a ``benchmark`` PR here first.
+  ``hidden(weights, tokens (B, T) int32, sizes, act_dtype=None)`` -> ``(x,
+      ks, vs)``: ``x`` (B, T, d) the hidden states after the final norm,
+      float32; ``ks`` and ``vs`` (L, B, T, KV, hd) every cached layer's keys
+      and values *as the architecture caches them*: after any norm of the
+      projected keys and after any rotation by position (a rotary model's
+      keys are cached rotated, so the reference returns them rotated), with
+      the KV heads the architecture keeps (not repeated up to the query
+      heads). The serving check holds the rows the engine's programs left
+      in a slot's ``"k"`` and ``"v"`` leaves to exactly these, a row against
+      a row: the reference's ``(KV, hd)`` is reshaped to the pool's row
+      shape where the sizes agree (a pool that keeps a row's heads side by
+      side, ``(1, KV x hd)``, is the same numbers). A latent's two parts
+      (PR 30) and a hybrid stack's sparse rows beside a state (PR 32) come
+      as ``ks``, ``vs`` too; what the pool holds under other names (a
+      recurrent state, pooled keys) is held by the rows above it and by the
+      logits.
+      ``act_dtype`` is **the twin**: with a dtype the same plain code rounds
+      to it where the published model holds that type (the embedding's
+      output, every matmul's output, every residual sum, every norm's
+      output) and keeps float32 inside what the configuration's
+      ``departures`` say the program keeps there (norms, softmax, gates, a
+      recurrent state, a router's scores). At ``None`` nothing is rounded.
+      The reference writes the twin and the check reads it: how far the
+      twin's rows lie from the unrounded run's is the error the stated
+      precision must make in this architecture at this depth with these
+      weights, and the program is held to ``SERVE_TWIN_FACTOR`` times it,
+      a layer and (times the cell's ``twin_ratio``) over the stack. A
+      reference rounds with ``references/rounding.py`` and nothing else.
       Where the block routes each token to k of E experts (the program's
       ``GPTConfig.n_experts`` is set), ``hidden`` owes two things more, and
       a reference that lacks them is an error there, not a dense check: it
@@ -25,8 +43,9 @@ loaded, so a reference reads its published keys under their own names):
       in every layer (None: the router's own k best), and returns a fourth
       array, the router's float32 logits (L, B, T, E). Nothing else: no
       tolerance, no margin, no law. ``serve_verdict`` then has the reference
-      route as the program routed (``follow_routes``) before it holds every
-      layer to the dense law. A dense reference owes neither.
+      route as the program routed (``follow_routes``) before it holds the
+      rows to the twin, which takes the same table. A dense reference owes
+      neither.
   ``logits(weights, x (..., d))`` -> float32 (..., V), any final softcap in.
   ``loss(weights, tokens, targets, sizes)`` -> mean cross-entropy over the
       targets that are not -1 (training cells).
@@ -34,8 +53,8 @@ loaded, so a reference reads its published keys under their own names):
       the program's arrays, nothing copied or cast.
 
 Every tolerance stands beside its check with the reason for it. They are
-set from what bf16 arithmetic must give and what the chip measured
-(PERF.md, Findings), tight enough that a lower precision than the
+set from what the stated precision must give (the twin) and what the chip
+measured (PERF.md, Findings), tight enough that a lower precision than the
 configuration states (an int8 or fp8 cache or matmul) would fail.
 """
 
@@ -66,17 +85,53 @@ TRAIN_FIRST_LOSS_TOL = 0.1
 # -- serving ----------------------------------------------------------------
 #: Keys and values the engine's own prefill and decode programs left in a
 #: slot, against the reference's, as relative Frobenius error per tensor over
-#: all layers and positions. The cache is bf16 over bf16 matmuls and the
-#: rounding of the residual stream adds up with depth: measured 0.84-0.89% at
-#: 12 layers and 1.32-1.34% at 48, the same to a few percent for every prompt
-#: and seed (my chip runs, PR 22). The tolerance is a quarter above a power
-#: law through both; an int8 or fp8 cache adds about 1% of its own and fails.
-#: The law is a dense block's, and it is the yardstick's: no reference sets
-#: a tolerance for itself. A block that makes a discrete choice (routed
-#: experts) is held to the same law once the reference has made the
-#: program's choices (``follow_routes``, below).
-SERVE_KV_REL_TOL_12_LAYERS = 1.1e-2
-SERVE_KV_DEPTH_POWER = 0.3
+#: the live positions: **in every cached layer**, and over all of them. The
+#: tolerance is this factor times the twin's own error over the same rows
+#: (module docstring): what the stated precision must give in this
+#: architecture, at this depth, under these weights, so no line here reads a
+#: depth. (Until PR 36 it was a power law in ``n_layer`` through two GPT-2
+#: depths, which a stack with four norms a layer, or one that runs its
+#: layers several times, outruns while computing exactly what its
+#: configuration states.)
+#: A layer is what a pool in fewer bits fails by: rounding adds up with
+#: depth, a row's own rounding does not, so it stands out in the first
+#: layer, whose twin is the smallest. On the chip (PR 36; PERF.md has the
+#: tables) the program reads 0.88 of the first layer's twin in the GPT-2
+#: cells, 1.00 in kanana-2-30b-a3b, 0.84 in minicpm-sala, and at most 1.00 of
+#: any layer's in any cell: the factor is a quarter above that. gpt2-xl's
+#: rows through int8 with a scale a row (0.6% a row, the finest 8 bits can
+#: do) passed the whole stack (0.87 of its limit) and the first quarter of it
+#: (0.96), which this rule's first draft held, and fail the first layer on
+#: all 6 prompts read (0.705% against 0.472: 1.49 times); rows through
+#: 8-bit floats read twice the last layer's limit in kanana and more in
+#: every layer before it.
+#: Over the whole stack the program is held to its cell's ``twin_ratio``
+#: (``cells/<cell>.json``, found once on the chip like its rate) of the
+#: twin, times the same factor. The program is not the twin: its matmuls sum
+#: in another order, its attention is a kernel or a two-part softmax, and
+#: inside a fusion it keeps float32 where the twin rounds after each
+#: operation, so past the first layer it reads *under* the twin, by a share
+#: that is the architecture's: the largest whole-stack program over twin,
+#: k or v, of the builder's runs (gpt2-124m 0.926-0.988, gpt2-xl
+#: 0.990-0.997, kanana 0.864-0.896 on 96 prompts, minicpm 0.745-0.751),
+#: rounded up to two places. Lower-precision arithmetic in the trunk adds up
+#: with depth and shows there. A cell that states none is held to 1, and
+#: none may state more: the twin rounds wherever the program may, so no
+#: cell buys room above the factor, and a reference that rounds more than
+#: it should gets its cell a smaller ratio, not a wider limit.
+SERVE_TWIN_FACTOR = 1.25
+#: A twin that reads above this makes the verdict blind: seeded weights
+#: under which every branch has unit size amplify rounding (10% at 192
+#: blocks, sized on the CPU: ISSUE 36), and a lower precision's 1% is then
+#: lost in it. That is an error that names the configuration's ``assumed``
+#: weights, never a pass. Twice the twin of the deepest cell, gpt2-xl's 48
+#: layers (1.330-1.356% on the chip, PR 36); the largest twin of a cell is
+#: minicpm-sala's 1.234%.
+SERVE_TWIN_CEILING = 0.027
+#: Where the stated precision is float32 the twin is the reference and
+#: reads 0: two float32 programs still sum in two orders (measured on the
+#: CPU: under 1e-5 at every tiny size of the tests).
+SERVE_KV_REL_FLOOR = 1e-4
 #: Routed experts: how far from the reference's own choice the program's
 #: choice of experts may lie and still be followed. The router's logit of an
 #: expert is z = h . w, h the normed residual stream. Where the program's h
@@ -84,18 +139,19 @@ SERVE_KV_DEPTH_POWER = 0.3
 #: of two experts' logits moves by eps * sqrt(2) * s (one standard
 #: deviation), s being the scale of one logit, |h| |w| / sqrt(d): read here
 #: as the spread of a layer's logits about their mean over the experts. The
-#: law above lets a layer's input be off by ``kv_rel_tol``, and behind an
-#: expert layer the chip's rows are off by just that (0.72-0.90% a layer
-#: against 0.79%, every position alike: PERF.md, PR 26). A token is followed
-#: to another set of k experts only where every pair of logits the swap
-#: turns over lies within SERVE_ROUTE_MARGIN * kv_rel_tol * s:
+#: twin's tolerance lets a layer's input be off by ``kv_tol`` (the whole
+#: stack's, under the reference's own routes: the followed ones do not exist
+#: yet), and behind an expert layer the chip's rows are off by just that
+#: (0.72-0.90% a layer, every position alike: PERF.md, PR 26). A token is
+#: followed to another set of k experts only where every pair of logits the
+#: swap turns over lies within SERVE_ROUTE_MARGIN * kv_tol * s:
 #: SERVE_ROUTE_MARGIN / sqrt(2) = 5.7 standard deviations of the rounding the
-#: law allows. Set from that arithmetic and from the chip: of 549 flips
+#: tolerance allows. Set from that arithmetic and from the chip: of 549 flips
 #: followed on the probe the widest lay 4.08 of these units apart, and a
 #: margin of 16 found the same 549 (PERF.md, PR 26). The margin fences which
 #: routes may be tried and forgives nothing: a program that routes outside
 #: it, drops a route, weighs a gate otherwise or caches in a lower precision
-#: matches no admissible set and fails by the dense law.
+#: matches no admissible set and fails by the twin's tolerance.
 SERVE_ROUTE_MARGIN = 8.0
 #: The sets of experts a token may try in one layer beside the reference's
 #: own, nearest first; each is one more forward of the reference for all
@@ -135,6 +191,26 @@ def train_verdict(eval_loss: float, ref_loss: float,
         and abs(eval_loss - ref_loss) <= TRAIN_EVAL_LOSS_TOL
         and abs(losses[0] - ref_loss) <= TRAIN_FIRST_LOSS_TOL
         and losses[-1] < losses[0])
+    out["compared"] = {
+        "eval_minus_reference": [abs(eval_loss - ref_loss),
+                                 TRAIN_EVAL_LOSS_TOL],
+        "first_minus_reference": [abs(losses[0] - ref_loss),
+                                  TRAIN_FIRST_LOSS_TOL],
+        "last_minus_first": [losses[-1] - losses[0], 0.0]}
+    return out
+
+
+def compared(verdict: Dict) -> Dict:
+    """Every number a verdict compared, beside its limit, under short plain
+    names: ``{name: [number, limit]}``; a serving case's under ``<case>.``.
+    What ``run.py`` prints last, so that a run that is not correct says by
+    which number."""
+    out = dict(verdict.get("compared", {}))
+    for i, case in enumerate(verdict.get("cases", [])):
+        out.update({f"{i}.{name}": pair
+                    for name, pair in case["compared"].items()})
+    if "compiled_in_window" in verdict:
+        out["compiled_in_window"] = [verdict["compiled_in_window"], 0]
     return out
 
 
@@ -239,83 +315,199 @@ def follow_routes(forward: Callable, row_distance: Callable, n_layer: int,
     return table, notes
 
 
+def _as_rows(ref, got):
+    """The reference's rows ``(..., KV, hd)`` in the pool's row shape: the
+    same numbers where the pool keeps a row's heads side by side."""
+    if ref.shape[-2:] == got.shape[-2:]:
+        return ref
+    if math.prod(ref.shape[-2:]) != math.prod(got.shape[-2:]):
+        raise RuntimeError(
+            f"the pool's rows {got.shape[-2:]} are not the reference's "
+            f"{ref.shape[-2:]} in another shape")
+    return ref.reshape(*ref.shape[:-2], *got.shape[-2:])
+
+
+def row_errors(got: Dict, ref_k, ref_v, n_rows) -> Dict:
+    """``got["k"]``, ``got["v"]`` (L, T' >= T, row) against the reference's
+    (L, T, row) over the first ``n_rows`` positions: the relative Frobenius
+    error of each over the whole stack, a layer, and a layer's median over
+    the positions of a position's own error."""
+    import jax.numpy as jnp
+
+    t = ref_k.shape[1]
+    live = (jnp.arange(t) < n_rows)[None, :, None, None]
+    out = {}
+    for name, ref in (("k", ref_k), ("v", ref_v)):
+        rows = got[name][:, :t]
+        ref = _as_rows(ref, rows)
+        diff = jnp.where(live, rows.astype(jnp.float32) - ref, 0.0)
+        ref = jnp.where(live, ref, 0.0)
+        num = jnp.sum(diff ** 2, axis=(1, 2, 3))
+        den = jnp.sum(ref ** 2, axis=(1, 2, 3))
+        out[name + "_rel"] = jnp.sqrt(num.sum() / den.sum())
+        out[name + "_max_abs"] = jnp.max(jnp.abs(diff))
+        # the same error a layer: what a row kept in fewer bits fails by
+        out[name + "_rel_layers"] = jnp.sqrt(num / den)
+        # and a layer's median over the live positions of a position's
+        # own error: the bulk of the tokens, whatever a few of them do
+        by_position = jnp.sqrt(jnp.sum(diff ** 2, axis=(2, 3))
+                               / jnp.sum(ref ** 2, axis=(2, 3)))
+        out[name + "_rel_p50_layers"] = jnp.nanmedian(
+            jnp.where(live[:, :, 0, 0], by_position, jnp.nan), axis=1)
+    return out
+
+
+def pool_errors(cache, ref_k, ref_v, slot, n_rows) -> Dict:
+    """``row_errors`` of one slot of the pool's ``"k"`` and ``"v"``."""
+    import jax
+
+    return row_errors(
+        {name: jax.lax.dynamic_index_in_dim(cache[name], slot, axis=1,
+                                            keepdims=False)
+         for name in ("k", "v")}, ref_k, ref_v, n_rows)
+
+
+def row_distance(cache, ref_k, ref_v, slot, layer):
+    """Every position's squared relative distance between one layer's rows
+    of a slot and the reference's, keys and values added."""
+    import jax
+    import jax.numpy as jnp
+
+    total = 0.0
+    for name, ref in (("k", ref_k), ("v", ref_v)):
+        ref = jax.lax.dynamic_index_in_dim(ref, layer, keepdims=False)
+        got = jax.lax.dynamic_index_in_dim(
+            jax.lax.dynamic_index_in_dim(cache[name], layer, keepdims=False),
+            slot, keepdims=False)[:ref.shape[0]]
+        ref = _as_rows(ref, got)
+        total += jnp.sum((got.astype(jnp.float32) - ref) ** 2, (1, 2)) \
+            / jnp.sum(ref ** 2, (1, 2))
+    return total
+
+
+def twin_tolerance(twin_rel, twin_ratio: float = 1.0) -> float:
+    """What the program's error may be where the twin's is ``twin_rel`` and
+    the cell's program reads ``twin_ratio`` of its twin."""
+    return max(SERVE_TWIN_FACTOR * twin_ratio * float(twin_rel),
+               SERVE_KV_REL_FLOOR)
+
+
+def _listed(errors: Dict, prefix: str = "") -> Dict:
+    """Fetched arrays as plain numbers and lists, for the notes."""
+    return {prefix + k: float(v) if v.ndim == 0 else [float(e) for e in v]
+            for k, v in errors.items()}
+
+
+def held_to_twin(errs: Dict, twin: Dict, twin_ratio: float = 1.0) -> Dict:
+    """One case's numbers beside their limits: ``{name: [the program's
+    error, the tolerance]}`` for keys and values, over the whole stack
+    (``k_rel``, held to the cell's ``twin_ratio`` of the twin) and in the
+    layer that stands nearest its own twin's tolerance or furthest past it
+    (``k_rel_layer``; ``k_worst_layer`` says which). ``kv_ratio`` is the
+    program over the twin across the whole stack, the larger of keys and
+    values: what a cell's ``twin_ratio`` records; ``kv_ratio_layers`` the
+    largest of any layer."""
+    out = {"compared": {}, "kv_ratio": 0.0, "kv_ratio_layers": 0.0}
+    for name in ("k_rel", "v_rel"):
+        out["compared"][name] = [
+            float(errs[name]), twin_tolerance(twin[name], twin_ratio)]
+        layers = [(float(e), float(t)) for e, t in
+                  zip(errs[name + "_layers"], twin[name + "_layers"])]
+        worst = max(range(len(layers)), key=lambda i: layers[i][0]
+                    / twin_tolerance(layers[i][1]))
+        out["compared"][name + "_layer"] = [
+            layers[worst][0], twin_tolerance(layers[worst][1])]
+        out[name[0] + "_worst_layer"] = worst
+        if float(twin[name]) > 0.0:
+            out["kv_ratio"] = max(out["kv_ratio"],
+                                  float(errs[name]) / float(twin[name]))
+        out["kv_ratio_layers"] = max(
+            out["kv_ratio_layers"], *(e / t for e, t in layers if t > 0.0), 0.0)
+    return out
+
+
 def serve_verdict(reference, sizes: Dict, server, prompts: List[np.ndarray],
-                  decode_steps: int) -> Dict:
+                  decode_steps: int, twin_ratio: float = 1.0) -> Dict:
     """Prefill each prompt into slot 0 and decode ``decode_steps`` tokens with
     the engine's own compiled programs, then hold the slot's cache rows and
     the emitted tokens to the reference's full forward over the same
     sequence; where the reference brings the routed contract, to its
     forward under the program's routes (``follow_routes``), whose notes then
-    stand in each case. The pool must be empty: the check takes a slot as a
-    request would and gives it back."""
+    stand in each case. The rows' tolerance is the twin's: the reference
+    once more over the same sequence (a routed one under its own routes:
+    rounding does not read which experts a few tokens took, and the margin
+    that fences the following needs the tolerance first), rounded to the
+    program's compute dtype, which the configuration states, times the
+    cell's ``twin_ratio``. The pool must be empty: the check takes a slot as
+    a request would and gives it back."""
     import jax
     import jax.numpy as jnp
+
+    if not 0.0 < twin_ratio <= 1.0:
+        raise ValueError(
+            f"twin_ratio {twin_ratio}: the share of its twin's error a "
+            "cell's program reads lies in (0, 1]; the twin rounds wherever "
+            "the program may, so no cell buys room above SERVE_TWIN_FACTOR")
 
     eng = server.engine
     cfg = eng.cfg
     if eng.pool.used_count:
         raise RuntimeError("the correctness check needs an empty pool")
-    routed = "experts" in inspect.signature(reference.hidden).parameters
+    parameters = inspect.signature(reference.hidden).parameters
+    routed = "experts" in parameters
     if cfg.n_experts and not routed:
         raise RuntimeError(
             "the program routes experts (n_experts is set) and the "
             "reference's hidden() takes no experts= table: it owes the "
             "routed contract (harness/check.py)")
-    kv_tol = SERVE_KV_REL_TOL_12_LAYERS * (
-        cfg.n_layer / 12.0) ** SERVE_KV_DEPTH_POWER
+    if "act_dtype" not in parameters:
+        raise RuntimeError(
+            "the reference's hidden() takes no act_dtype=: it owes the twin "
+            "the rows' tolerance is read from (harness/check.py)")
+    act_dtype = jnp.dtype(cfg.dtype)
     weights = reference.weights_from_program(eng.params)
     n_slots, parked = eng.n_slots, cfg.block_size - 1
     # greedy lanes: the seed is never used, every request's is 0
     seeds = np.zeros(n_slots, np.uint32)
     token_index = np.zeros(n_slots, np.int32)
 
+    def hidden(w, seq, experts, **twin):
+        routes = {} if experts is None else {"experts": experts[:, None]}
+        return reference.hidden(w, seq[None], sizes, **routes, **twin)
+
     @jax.jit
     def ref_forward(w, seq, n_prompt, experts=None):
         """Dense: (logits, ks, vs). Routed: and the router's logits."""
-        routes = {} if experts is None else {"experts": experts[:, None]}
-        x, ks, vs, *router = reference.hidden(w, seq[None], sizes, **routes)
+        x, ks, vs, *router = hidden(w, seq, experts)
         rows = jax.lax.dynamic_slice_in_dim(
             x[0], n_prompt - 1, decode_steps + 1, axis=0)
         return (reference.logits(w, rows),
                 *(a[:, 0] for a in (ks, vs, *router)))
 
     @jax.jit
-    def row_distance(cache, ref_k, ref_v, slot, layer):
-        total = 0.0
-        for name, ref in (("k", ref_k), ("v", ref_v)):
-            ref = jax.lax.dynamic_index_in_dim(ref, layer, keepdims=False)
-            got = jax.lax.dynamic_index_in_dim(
-                jax.lax.dynamic_index_in_dim(cache[name], layer,
-                                             keepdims=False),
-                slot, keepdims=False)[:ref.shape[0]]
-            total += jnp.sum((got.astype(jnp.float32) - ref) ** 2, (1, 2)) \
-                / jnp.sum(ref ** 2, (1, 2))
-        return total
+    def twin_errors(w, seq, ref_k, ref_v, n_rows, experts=None):
+        """The twin's rows against the unrounded run's: ``row_errors``."""
+        _, ks, vs, *_ = hidden(w, seq, experts, act_dtype=act_dtype)
+        return row_errors({"k": ks[:, 0], "v": vs[:, 0]}, ref_k, ref_v,
+                          n_rows)
 
-    @jax.jit
-    def kv_errors(cache, ref_k, ref_v, slot, n_rows):
-        t = ref_k.shape[1]
-        live = (jnp.arange(t) < n_rows)[None, :, None, None]
-        out = {}
-        for name, ref in (("k", ref_k), ("v", ref_v)):
-            got = jax.lax.dynamic_index_in_dim(
-                cache[name], slot, axis=1, keepdims=False)[:, :t]
-            diff = jnp.where(live, got.astype(jnp.float32) - ref, 0.0)
-            ref = jnp.where(live, ref, 0.0)
-            out[name + "_rel"] = jnp.sqrt(jnp.sum(diff ** 2)
-                                          / jnp.sum(ref ** 2))
-            out[name + "_max_abs"] = jnp.max(jnp.abs(diff))
-            # the same error a layer: a verdict that fails says where
-            out[name + "_rel_layers"] = jnp.sqrt(
-                jnp.sum(diff ** 2, axis=(1, 2, 3))
-                / jnp.sum(ref ** 2, axis=(1, 2, 3)))
-            # and a layer's median over the live positions of a position's
-            # own error: the bulk of the tokens, whatever a few of them do
-            by_position = jnp.sqrt(jnp.sum(diff ** 2, axis=(2, 3))
-                                   / jnp.sum(ref ** 2, axis=(2, 3)))
-            out[name + "_rel_p50_layers"] = jnp.nanmedian(
-                jnp.where(live[:, :, 0, 0], by_position, jnp.nan), axis=1)
-        return out
+    def read_twin(seq, ref_k, ref_v, n_rows, table=None) -> Dict:
+        twin = jax.device_get(twin_errors(weights, seq, ref_k, ref_v,
+                                          np.int32(n_rows), table))
+        worst = float(max(twin["k_rel"], twin["v_rel"]))
+        if not worst <= SERVE_TWIN_CEILING:
+            raise RuntimeError(
+                f"the reference rounded to {act_dtype.name} lies {worst:.4f} "
+                f"from itself unrounded, over SERVE_TWIN_CEILING = "
+                f"{SERVE_TWIN_CEILING}: these weights amplify rounding, and "
+                "no verdict under them could see a lower precision. The "
+                "configuration's assumed.weights have to change (smaller "
+                "norm weights on a deep or looped stack: "
+                "benchmarks/README.md)")
+        return twin
+
+    errors_of_slot = jax.jit(pool_errors)
+    distance_of_layer = jax.jit(row_distance)
 
     cases = []
     for prompt in prompts:
@@ -342,35 +534,52 @@ def serve_verdict(reference, sizes: Dict, server, prompts: List[np.ndarray],
         seq = np.zeros(t_pad, np.int32)
         seq[:n] = prompt
         seq[n:n + decode_steps] = emitted[:-1]
+        n_rows = n + decode_steps
+        ref_logits, ref_k, ref_v, *router = ref_forward(
+            weights, seq, np.int32(n))
         routes = {}
         if routed:
+            # both runs under the reference's own routes: the twin must take
+            # the table, or it would round its way to routes of its own
+            own = np.argsort(-np.asarray(router[0]), axis=-1,
+                             kind="stable")[..., :cfg.moe_top_k]
+            twin = read_twin(seq, ref_k, ref_v, n_rows, own.astype(np.int32))
             table, routes = follow_routes(
                 lambda experts: ref_forward(weights, seq, np.int32(n), experts),
-                lambda ks, vs, layer: row_distance(
+                lambda ks, vs, layer: distance_of_layer(
                     eng.pool.cache, ks, vs, np.int32(slot), np.int32(layer)),
-                cfg.n_layer, t_pad, cfg.moe_top_k, n, emitted, kv_tol)
+                cfg.n_layer, t_pad, cfg.moe_top_k, n, emitted,
+                twin_tolerance(max(twin["k_rel"], twin["v_rel"]), twin_ratio))
             ref_logits, ref_k, ref_v, _ = ref_forward(
                 weights, seq, np.int32(n), table)
         else:
-            ref_logits, ref_k, ref_v = ref_forward(weights, seq, np.int32(n))
-        errs = jax.device_get(kv_errors(
-            eng.pool.cache, ref_k, ref_v, np.int32(slot),
-            np.int32(n + decode_steps)))
+            twin = read_twin(seq, ref_k, ref_v, n_rows)
+        errs = jax.device_get(errors_of_slot(
+            eng.pool.cache, ref_k, ref_v, np.int32(slot), np.int32(n_rows)))
         ref_logits = np.asarray(ref_logits)
         gaps = [float(ref_logits[i].max() - ref_logits[i, t])
                 for i, t in enumerate(emitted)]
         eng.pool.free(slot)
+        held = held_to_twin(errs, twin, twin_ratio)
+        held["compared"]["logit_gap"] = [max(gaps), SERVE_LOGIT_GAP_TOL]
         cases.append({
             "prompt_len": n, "bucket": bucket,
-            **{k: float(v) if v.ndim == 0 else [float(e) for e in v]
-               for k, v in errs.items()},
+            **_listed(errs),
+            # the twin's own error: a case that fails says which side moved
+            **_listed({k: v for k, v in twin.items()
+                       if k.endswith(("_rel", "_rel_layers"))}, "twin_"),
+            **held,
             "max_logit_gap": max(gaps),
             "tokens_equal_argmax": sum(g == 0.0 for g in gaps),
             **routes,
         })
-    ok = all(c["k_rel"] <= kv_tol and c["v_rel"] <= kv_tol
-             and c["max_logit_gap"] <= SERVE_LOGIT_GAP_TOL for c in cases)
-    return {"ok": bool(ok and cases), "kv_rel_tol": kv_tol, "cases": cases}
+    ok = all(value <= limit for c in cases
+             for value, limit in c["compared"].values())
+    # the widest tolerance any part of any case's rows was held to
+    widest = max((limit for c in cases for name, (_, limit)
+                  in c["compared"].items() if name != "logit_gap"),
+                 default=0.0)
+    return {"ok": bool(ok and cases), "kv_rel_tol": widest, "cases": cases}
 
 
 def pick_prompts(reqs, buckets: Sequence[int], n: int) -> List[np.ndarray]:

@@ -18,6 +18,9 @@ stuck. A request that is still streaming then is neither failed nor
 finished; what is left in the server is cancelled before the check.
 (Waiting for every request to finish would cost a whole request's life after
 every window: 70 s for 512 tokens at the gaps PR 22 measured.)
+A traced run starts the profiler ``trace_after_s`` into the window and stops
+it ``trace_s`` later; each holds this thread for seconds, and the loop's clock
+stands still meanwhile (``play``), so the traced rounds are the window's own.
 
 A closed-loop mix whose lengths are dealt in a cycle (``traffic``: ``order``,
 ``cycle_length``) comes in blocks of that length. The window then opens in the
@@ -45,8 +48,11 @@ from benchmarks.harness import check, spec, trace, traffic
 
 
 def init_params(gpt_cfg, seed: int):
-    """Seeded weights on the device in one jitted call, float32 as the
-    program serves them. Where the architecture has a learned position
+    """Seeded weights on the device in one jitted call, in the type the
+    configuration states for its parameters (``gpt.init`` draws them in
+    ``cfg.param_dtype``: float32 for the GPT-2 cells, which the engine casts
+    once for its programs; bfloat16, in one copy, for kanana and minicpm).
+    Where the architecture has a learned position
     table the program zero-initialises it; here it is drawn like the other
     embeddings, or no check could see a position that is looked up wrongly.
     A rotary model has no table and keeps ``gpt.init``'s parameters as they
@@ -180,16 +186,34 @@ class Driver:
                 play.close_counters = self._counters()
                 if compiles is not None:
                     play.compiled_in_window = compiles.close_window()
+            # The profiler holds this thread for seconds when it starts and
+            # when it stops (12.9-18.8 s in all on the chip, PR 36). The loop's
+            # clock stands still meanwhile: the traffic's origin and the
+            # window's end move by what each took, so no arrivals pile up
+            # behind the instrument and the traced rounds see the house the
+            # untraced window sees. Requests in flight see one long gap, in
+            # a run whose end-to-end numbers nobody reads.
             if tracing is None and now - origin >= t_trace[0]:
                 tracing = trace.capture(trace_dir)
                 tracing.__enter__()
                 traced_now = True
                 play.trace_open = self._counters()
-            if traced_now and now - origin >= t_trace[1]:
+                held = time.perf_counter() - now
+            elif traced_now and now - origin >= t_trace[1]:
                 tracing.__exit__(None, None, None)
                 traced_now = False
                 play.trace_close = self._counters()
-                now = time.perf_counter()
+                held = time.perf_counter() - now
+            else:
+                held = 0.0
+            if held:
+                origin, now = origin + held, now + held
+                if play.close_counters is None:     # the window's end moves,
+                    play.w1 += held
+                    if play.open_counters is None:  # and an unopened one whole
+                        play.w0 += held
+                    else:
+                        play.profiler_held_s += held
             if play.close_counters is not None and not traced_now:
                 attempted = [s for s in sent if play.w0 <= s.t_ref < play.w1]
                 if all(s.handle.finished for s in attempted) \
@@ -279,12 +303,13 @@ class Play:
     trace_close: Optional[Dict] = None
     compiled_in_window: int = 0
     watchdog_recompiles: int = 0
+    profiler_held_s: float = 0.0    # a traced run: the clock stood, in [w0, w1)
 
     def summary(self) -> Dict:
         """The window's numbers, from the benchmark's own stamps."""
         pct = lambda a, q: float(np.percentile(a, q)) if len(a) else float("nan")
         w0, w1 = self.w0, self.w1
-        seconds = w1 - w0
+        seconds = w1 - w0 - self.profiler_held_s
         attempted = [s for s in self.sent if w0 <= s.t_ref < w1]
         ok = lambda s: s.handle.finish_reason in ("length", "eos")
 
@@ -332,7 +357,7 @@ class Play:
             "queue_open": c0["queued_now"], "queue_close": c1["queued_now"],
             "slots_open": c0["slots_now"],
             "slots_close": c1["slots_now"],
-            "rounds": self.rounds,
+            "rounds": self.rounds, "profiler_held_s": self.profiler_held_s,
             # how well the bucket ladder fits the traffic: padded over real
             "prefill_pad_ratio": (
                 (c1["prefill_padded_tokens"] - c0["prefill_padded_tokens"])
@@ -389,8 +414,10 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         verdict = {"ok": False, "cases": [],
                    "why": "the server did not drain: no empty pool to check in"}
     else:
-        verdict = check.serve_verdict(reference, cell.config, driver.server,
-                                      prompts, int(mix["check_decode_steps"]))
+        verdict = check.serve_verdict(
+            reference, cell.config, driver.server, prompts,
+            int(mix["check_decode_steps"]),
+            twin_ratio=float(found.get("twin_ratio", 1.0)))
     recompiled = play.compiled_in_window + play.watchdog_recompiles
     verdict["compiled_in_window"] = recompiled
     verdict["ok"] = verdict["ok"] and recompiled == 0

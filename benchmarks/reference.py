@@ -23,6 +23,18 @@ leading layer axis (that is how the program keeps them, so no copy is made):
           w_proj (L, 4d, d); b_proj (L, d)
 
 ``sizes`` holds the published keys ``n_head`` and ``layer_norm_epsilon``.
+
+The twin (``harness/check.py``). ``hidden(..., act_dtype=jnp.bfloat16)`` is
+the same code with every value rounded to that type where a model served in
+it holds that type: the matmuls' weights, the embedding's output, every
+matmul's output (its bias added), the GELU's, every residual sum, every
+LayerNorm's output. LayerNorm and softmax stay float32 inside (the
+configurations' ``departures``), and every sum of a matmul is still float32
+at ``highest``: a bf16 matmul's products are exact and its sums float32, so
+the twin errs as bf16 arithmetic must and no more. At ``None`` nothing is
+rounded: the function of before, bit for bit. The check measures how far
+the twin's cached rows lie from the unrounded run's and holds the program
+to a multiple of that; this file states no tolerance.
 """
 
 from __future__ import annotations
@@ -31,6 +43,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from benchmarks.references.rounding import rounder
 
 
 def weights_from_program(params) -> dict:
@@ -60,33 +74,36 @@ def _gelu_new(x):
         math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
 
 
-def hidden(weights, tokens, sizes):
+def hidden(weights, tokens, sizes, act_dtype=None):
     """tokens (B, T) int32 -> (final-LayerNorm hidden (B, T, d), keys and
-    values of every layer, each (L, B, T, H, hd))."""
+    values of every layer, each (L, B, T, H, hd)). ``act_dtype``: the twin
+    (module docstring); None: float32 throughout."""
     n_head, eps = sizes["n_head"], sizes["layer_norm_epsilon"]
+    r = rounder(act_dtype)
     b, t = tokens.shape
-    x = weights["wte"][tokens] + weights["wpe"][:t]
+    x = r(r(weights["wte"][tokens]) + r(weights["wpe"][:t]))
     d = x.shape[-1]
     hd = d // n_head
     causal = jnp.tril(jnp.ones((t, t), bool))
 
     def block(x, w):
-        h = _layer_norm(x, w["ln1_g"], w["ln1_b"], eps)
-        q = (h @ w["wq"] + w["bq"]).reshape(b, t, n_head, hd)
-        k = (h @ w["wk"] + w["bk"]).reshape(b, t, n_head, hd)
-        v = (h @ w["wv"] + w["bv"]).reshape(b, t, n_head, hd)
+        h = r(_layer_norm(x, w["ln1_g"], w["ln1_b"], eps))
+        q = r(h @ r(w["wq"]) + w["bq"]).reshape(b, t, n_head, hd)
+        k = r(h @ r(w["wk"]) + w["bk"]).reshape(b, t, n_head, hd)
+        v = r(h @ r(w["wv"]) + w["bv"]).reshape(b, t, n_head, hd)
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
         scores = jnp.where(causal, scores, -jnp.inf)
-        att = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
-        x = x + att.reshape(b, t, d) @ w["wo"] + w["bo"]
-        h = _layer_norm(x, w["ln2_g"], w["ln2_b"], eps)
-        x = x + _gelu_new(h @ w["w_fc"] + w["b_fc"]) @ w["w_proj"] + w["b_proj"]
+        att = r(jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v))
+        x = r(x + r(att.reshape(b, t, d) @ r(w["wo"])) + w["bo"])
+        h = r(_layer_norm(x, w["ln2_g"], w["ln2_b"], eps))
+        inner = r(_gelu_new(r(h @ r(w["w_fc"]) + w["b_fc"])))
+        x = r(x + r(inner @ r(w["w_proj"])) + w["b_proj"])
         return x, (k, v)
 
     with jax.default_matmul_precision("highest"):
         x, (ks, vs) = jax.lax.scan(block, x.astype(jnp.float32),
                                    weights["blocks"])
-        x = _layer_norm(x, weights["lnf_g"], weights["lnf_b"], eps)
+        x = r(_layer_norm(x, weights["lnf_g"], weights["lnf_b"], eps))
     return x, ks, vs
 
 

@@ -77,6 +77,17 @@ order.
 ``routed_scaling_factor``, ``scoring_func`` and, where present,
 ``q_lora_rank``, ``n_group``, ``topk_group``.
 
+The twin (``harness/check.py``). ``hidden(..., act_dtype=jnp.bfloat16)`` is
+the same code with every value rounded to that type where the published
+model holds that type: the embedding's output, every matmul's output, the
+rotated keys and queries, the SwiGLU's inner product, every residual sum,
+every RMSNorm's output. The norms, the softmax, the router's scores, the
+choice and the gates stay float32 inside, as the configuration's
+``departures`` say the program keeps them, and every sum of a matmul is
+still float32 at ``highest``. At ``None`` nothing is rounded: the function
+of before, bit for bit. The twin takes the same table of experts as the
+unrounded run, so the two differ by rounding alone and never by a route.
+
 Departures from the published architecture: none intended.
 """
 
@@ -86,6 +97,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from benchmarks.references.rounding import rounder
 
 #: experts whose float32 weights exist at one time
 EXPERT_BLOCK = 8
@@ -141,8 +154,9 @@ def _rotate(x, theta, interleave):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
-def _swiglu(h, gate, up, down):
-    return (jax.nn.silu(h @ _f32(gate)) * (h @ _f32(up))) @ _f32(down)
+def _swiglu(h, gate, up, down, r):
+    return r(r(jax.nn.silu(r(h @ _f32(gate))) * r(h @ _f32(up)))
+             @ _f32(down))
 
 
 def _attend(q, k, v):
@@ -161,7 +175,7 @@ def _attend(q, k, v):
     return jnp.concatenate(out, axis=1)
 
 
-def _routed(h, w, stack, at, sizes, chosen):
+def _routed(h, w, stack, at, sizes, chosen, r):
     """(B, T, d) -> (the routed experts' sum (B, T, d), s + bias (B, T, E)).
     ``w`` is the layer's router and bias; the experts are read a block at a
     time out of ``stack``, all expert layers' (L, E, ...), at layer ``at``."""
@@ -192,29 +206,32 @@ def _routed(h, w, stack, at, sizes, chosen):
         i, gate = item
         eg, eu, ed = (_f32(blocks[k][at * (e // n) + i])
                       for k in ("eg", "eu", "ed"))
-        inner = jax.nn.silu(jnp.einsum("btd,edf->btef", h, eg)) \
-            * jnp.einsum("btd,edf->btef", h, eu)
-        return total + jnp.einsum("btef,efd,bte->btd", inner, ed, gate), None
+        inner = r(jax.nn.silu(r(jnp.einsum("btd,edf->btef", h, eg)))
+                  * r(jnp.einsum("btd,edf->btef", h, eu)))
+        return total + r(jnp.einsum("btef,efd,bte->btd", inner, ed, gate)), \
+            None
 
     total, _ = jax.lax.scan(some_experts, jnp.zeros_like(h),
                             (jnp.arange(e // n), gate_blocks))
-    return total, select
+    return r(total), select
 
 
-def hidden(weights, tokens, sizes, experts=None):
+def hidden(weights, tokens, sizes, experts=None, act_dtype=None):
     """tokens (B, T) int32 -> (final-RMSNorm hidden (B, T, d); the rotated
     shared rope keys (L, B, T, 1, rope) and the normed latents (L, B, T, 1,
     kv_lora_rank) of every layer; what every layer takes its k best of (L,
     B, T, E)). ``experts`` (L, B, T, k) int32: the experts every token takes
     in every layer (a dense layer's row is ignored); None: the k best of
-    s + bias."""
+    s + bias. ``act_dtype``: the twin (module docstring); None: float32
+    throughout."""
     if sizes.get("q_lora_rank") is not None:
         raise ValueError("a query latent (q_lora_rank) is not written here")
-    n_head, r = sizes["num_attention_heads"], sizes["kv_lora_rank"]
+    n_head, rank = sizes["num_attention_heads"], sizes["kv_lora_rank"]
     nope, rope = sizes["qk_nope_head_dim"], sizes["qk_rope_head_dim"]
     v_dim, eps = sizes["v_head_dim"], sizes["rms_norm_eps"]
     theta, interleave = float(sizes["rope_theta"]), sizes["rope_interleave"]
     n_dense, top_k = sizes["first_k_dense_replace"], sizes["num_experts_per_tok"]
+    r = rounder(act_dtype)
     b, t = tokens.shape
     n_experts = weights["moe"]["router"].shape[-1]
     n_layer = n_dense + weights["moe"]["router"].shape[0]
@@ -224,7 +241,7 @@ def hidden(weights, tokens, sizes, experts=None):
 
     ks, vs, router = [], [], []
     with jax.default_matmul_precision("highest"):
-        x = _f32(weights["embed"][tokens])
+        x = r(_f32(weights["embed"][tokens]))
         for layer in range(n_layer):
             stack, at = (weights["dense"], layer) if layer < n_dense \
                 else (weights["moe"], layer - n_dense)
@@ -232,33 +249,35 @@ def hidden(weights, tokens, sizes, experts=None):
             # and are read a block at a time (_routed)
             w = {k: a[at] for k, a in stack.items()
                  if k not in ("eg", "eu", "ed")}
-            h = _rms(x, w["input_norm"], eps)
-            q = (h @ _f32(w["q_proj"])).reshape(b, t, n_head, nope + rope)
-            kv_a = h @ _f32(w["kv_a_proj"])
-            c = _rms(kv_a[..., :r], w["kv_a_norm"], eps)
-            k_pe = _rotate(kv_a[..., None, r:], theta, interleave)
-            q_pe = _rotate(q[..., nope:], theta, interleave)
-            kv_b = (c @ _f32(w["kv_b_proj"])).reshape(b, t, n_head, nope + v_dim)
+            h = r(_rms(x, w["input_norm"], eps))
+            q = r(h @ _f32(w["q_proj"])).reshape(b, t, n_head, nope + rope)
+            kv_a = r(h @ _f32(w["kv_a_proj"]))
+            c = r(_rms(kv_a[..., :rank], w["kv_a_norm"], eps))
+            k_pe = r(_rotate(kv_a[..., None, rank:], theta, interleave))
+            q_pe = r(_rotate(q[..., nope:], theta, interleave))
+            kv_b = r(c @ _f32(w["kv_b_proj"])).reshape(
+                b, t, n_head, nope + v_dim)
             k = jnp.concatenate([
                 kv_b[..., :nope], jnp.broadcast_to(k_pe, (b, t, n_head, rope))
             ], -1)
-            att = _attend(jnp.concatenate([q[..., :nope], q_pe], -1), k,
-                          kv_b[..., nope:])
-            x = x + att.reshape(b, t, n_head * v_dim) @ _f32(w["o_proj"])
-            h = _rms(x, w["post_norm"], eps)
+            att = r(_attend(jnp.concatenate([q[..., :nope], q_pe], -1), k,
+                            kv_b[..., nope:]))
+            x = r(x + r(att.reshape(b, t, n_head * v_dim) @ _f32(w["o_proj"])))
+            h = r(_rms(x, w["post_norm"], eps))
             if layer < n_dense:
-                x = x + _swiglu(h, w["gate_proj"], w["up_proj"], w["down_proj"])
+                x = r(x + _swiglu(h, w["gate_proj"], w["up_proj"],
+                                  w["down_proj"], r))
                 router.append(no_choice)
             else:
                 out, select = _routed(
                     h, w, stack, at, sizes,
-                    None if experts is None else experts[layer])
-                x = x + out + _swiglu(h, w["shared_gate"], w["shared_up"],
-                                      w["shared_down"])
+                    None if experts is None else experts[layer], r)
+                x = r(x + out + _swiglu(h, w["shared_gate"], w["shared_up"],
+                                        w["shared_down"], r))
                 router.append(select)
             ks.append(k_pe)
             vs.append(c[..., None, :])
-        x = _rms(x, weights["norm"], eps)
+        x = r(_rms(x, weights["norm"], eps))
     return x, jnp.stack(ks), jnp.stack(vs), jnp.stack(router)
 
 

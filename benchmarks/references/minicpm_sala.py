@@ -66,6 +66,16 @@ carries S from block to block, and a sparse layer first makes all keys and
 values (two KV heads: small), then attends ``QUERY_BLOCK`` queries at a
 time against all rows.
 
+The twin (``harness/check.py``). ``hidden(..., act_dtype=jnp.bfloat16)`` is
+the same code with every value rounded to that type where the published
+model holds that type: the embedding's output, every matmul's output, the
+normed and rotated queries and keys, a linear layer's read-out ``o_t``, the
+gated product, the SwiGLU's inner product, every residual sum, every
+RMSNorm's output. The norms, the softmax, the selection's scores and choice,
+the gates' sigmoid, the state ``S`` and its decay stay float32 inside, as the
+configuration's ``departures`` say the program keeps them. At ``None``
+nothing is rounded: the function of before, bit for bit.
+
 ``sizes`` holds the published keys ``mixer_types``, ``num_attention_heads``,
 ``num_key_value_heads``, ``lightning_nh``, ``lightning_head_dim``,
 ``rope_theta``, ``rms_norm_eps``, ``scale_emb``, ``scale_depth``,
@@ -81,6 +91,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from benchmarks.references.rounding import rounder
 
 #: positions whose projections and MLP exist at one time
 BLOCK = 512
@@ -154,13 +166,13 @@ def _whole(blocks):
     return jnp.moveaxis(blocks, 0, 1).reshape(b, n * size, *blocks.shape[3:])
 
 
-def _mlp_onto(h, w, s, eps):
-    v = _rms(h, w["post_norm"], eps)
-    inner = jax.nn.silu(v @ _f32(w["gate"])) * (v @ _f32(w["up"]))
-    return h + s * (inner @ _f32(w["down"]))
+def _mlp_onto(h, w, s, eps, r):
+    v = r(_rms(h, w["post_norm"], eps))
+    inner = r(r(jax.nn.silu(r(v @ _f32(w["gate"])))) * r(v @ _f32(w["up"])))
+    return r(h + s * r(inner @ _f32(w["down"])))
 
 
-def _lightning_layer(x, w, sizes, s):
+def _lightning_layer(x, w, sizes, s, r):
     """(B, T, d) -> ((B, T, d), S after the last position (B, H, hd, hd))."""
     nh, hd = sizes["lightning_nh"], sizes["lightning_head_dim"]
     eps, theta = sizes["rms_norm_eps"], float(sizes["rope_theta"])
@@ -171,12 +183,12 @@ def _lightning_layer(x, w, sizes, s):
     def a_block(state, item):
         x_b, start = item
         size = x_b.shape[1]
-        u = _rms(x_b, w["input_norm"], eps)
+        u = r(_rms(x_b, w["input_norm"], eps))
         positions = start + jnp.arange(size)
-        q, k, v = ((u @ _f32(w[p])).reshape(b, size, nh, hd)
+        q, k, v = (r(u @ _f32(w[p])).reshape(b, size, nh, hd)
                    for p in ("q_proj", "k_proj", "v_proj"))
-        q = _rotate(_rms(q, w["q_norm"], eps), positions, theta)
-        k = _rotate(_rms(k, w["k_norm"], eps), positions, theta)
+        q = r(_rotate(r(_rms(q, w["q_norm"], eps)), positions, theta))
+        k = r(_rotate(r(_rms(k, w["k_norm"], eps)), positions, theta))
 
         def a_position(st, qkv):
             q_t, k_t, v_t = qkv                         # (B, H, hd) each
@@ -187,10 +199,10 @@ def _lightning_layer(x, w, sizes, s):
         state, o = jax.lax.scan(
             a_position, state,
             tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v)), unroll=4)
-        o = jnp.moveaxis(o, 0, 1).reshape(b, size, nh * hd)
-        m = (jax.nn.sigmoid(u @ _f32(w["gate_proj"]))
-             * _rms(o, w["o_norm"], eps)) @ _f32(w["o_proj"])
-        return state, _mlp_onto(x_b + s * m, w, s, eps)
+        o = r(jnp.moveaxis(o, 0, 1).reshape(b, size, nh * hd))
+        m = r(r(jax.nn.sigmoid(r(u @ _f32(w["gate_proj"])))
+                * r(_rms(o, w["o_norm"], eps))) @ _f32(w["o_proj"]))
+        return state, _mlp_onto(r(x_b + s * m), w, s, eps, r)
 
     size = min(BLOCK, x.shape[1])
     starts = jnp.arange(0, x.shape[1], size)
@@ -245,7 +257,7 @@ def _chosen_blocks(q, pooled, positions, sc):
     return chosen & starts_by_t[None, :, None]
 
 
-def _sparse_layer(x, w, sizes, s):
+def _sparse_layer(x, w, sizes, s, r):
     """(B, T, d) -> ((B, T, d), keys (B, T, KV, hd), values)."""
     nh, kv = sizes["num_attention_heads"], sizes["num_key_value_heads"]
     eps, sc = sizes["rms_norm_eps"], sizes["sparse_config"]
@@ -257,10 +269,10 @@ def _sparse_layer(x, w, sizes, s):
         raise ValueError(f"{t} positions are no whole blocks of {size}")
 
     def keys_values(x_b):
-        u = _rms(x_b, w["input_norm"], eps)
-        k = _rms((u @ _f32(w["k_proj"])).reshape(b, -1, kv, hd),
-                 w["k_norm"], eps)
-        return k, (u @ _f32(w["v_proj"])).reshape(b, -1, kv, hd)
+        u = r(_rms(x_b, w["input_norm"], eps))
+        k = r(_rms(r(u @ _f32(w["k_proj"])).reshape(b, -1, kv, hd),
+                   w["k_norm"], eps))
+        return k, r(u @ _f32(w["v_proj"])).reshape(b, -1, kv, hd)
 
     big = min(BLOCK, t)
     k, v = (_whole(a) for a in jax.lax.map(keys_values, _in_blocks(x, big)))
@@ -275,10 +287,10 @@ def _sparse_layer(x, w, sizes, s):
     def some_queries(item):
         x_q, start = item
         n_q = x_q.shape[1]
-        u = _rms(x_q, w["input_norm"], eps)
+        u = r(_rms(x_q, w["input_norm"], eps))
         positions = start + jnp.arange(n_q)
-        q = _rms((u @ _f32(w["q_proj"])).reshape(b, n_q, nh, hd),
-                 w["q_norm"], eps)
+        q = r(_rms(r(u @ _f32(w["q_proj"])).reshape(b, n_q, nh, hd),
+                   w["q_norm"], eps))
         chosen = _chosen_blocks(q, pooled, positions, sc)   # (B, Q, KV, Nb)
         rows = jnp.arange(t)
         allowed = chosen[..., rows // size] \
@@ -287,10 +299,10 @@ def _sparse_layer(x, w, sizes, s):
                             q.reshape(b, n_q, kv, nh // kv, hd), k) \
             / math.sqrt(hd)
         scores = jnp.where(allowed[:, :, :, None], scores, -jnp.inf)
-        o = jnp.einsum("bqkgs,bskd->bqkgd", jax.nn.softmax(scores, -1), v)
-        m = (jax.nn.sigmoid(u @ _f32(w["gate_proj"]))
-             * o.reshape(b, n_q, nh * hd)) @ _f32(w["o_proj"])
-        return _mlp_onto(x_q + s * m, w, s, eps)
+        o = r(jnp.einsum("bqkgs,bskd->bqkgd", jax.nn.softmax(scores, -1), v))
+        m = r(r(jax.nn.sigmoid(r(u @ _f32(w["gate_proj"])))
+                * o.reshape(b, n_q, nh * hd)) @ _f32(w["o_proj"]))
+        return _mlp_onto(r(x_q + s * m), w, s, eps, r)
 
     small = math.gcd(QUERY_BLOCK, t)
     out = jax.lax.map(some_queries,
@@ -298,9 +310,10 @@ def _sparse_layer(x, w, sizes, s):
     return _whole(out), k, v
 
 
-def _layers(weights, tokens, sizes):
+def _layers(weights, tokens, sizes, act_dtype=None):
     """-> (x as the head takes it, ks, vs, states)."""
     sc = sizes["sparse_config"]
+    r = rounder(act_dtype)
     s = sizes["scale_depth"] / math.sqrt(
         sizes.get("scale_depth_num_hidden_layers")
         or len(sizes["mixer_types"]))
@@ -311,7 +324,7 @@ def _layers(weights, tokens, sizes):
     tokens = jnp.pad(tokens, ((0, 0), (0, -t % unit)))
     ks, vs, states, seen = [], [], [], {LIGHTNING: 0, SPARSE: 0}
     with jax.default_matmul_precision("highest"):
-        x = sizes["scale_emb"] * _f32(weights["embed"][tokens])
+        x = r(sizes["scale_emb"] * _f32(weights["embed"][tokens]))
         for kind in sizes["mixer_types"]:
             # a layer's leaves are read out of the stack where they are
             # used, inside the loops over blocks: sliced out here, each
@@ -319,26 +332,27 @@ def _layers(weights, tokens, sizes):
             w = _Layer(weights[kind], seen[kind])
             seen[kind] += 1
             if kind == LIGHTNING:
-                x, state = _lightning_layer(x, w, sizes, s)
+                x, state = _lightning_layer(x, w, sizes, s, r)
                 states.append(state)
             elif kind == SPARSE:
-                x, k, v = _sparse_layer(x, w, sizes, s)
+                x, k, v = _sparse_layer(x, w, sizes, s, r)
                 # as cached: a row's KV heads side by side
                 ks.append(k[:, :t].reshape(k.shape[0], t, 1, -1))
                 vs.append(v[:, :t].reshape(v.shape[0], t, 1, -1))
             else:
                 raise ValueError(f"no mixer {kind!r} is written here")
-        x = _rms(x[:, :t], weights["norm"], sizes["rms_norm_eps"]) \
+        x = r(_rms(x[:, :t], weights["norm"], sizes["rms_norm_eps"])) \
             / (sizes["hidden_size"] / sizes["dim_model_base"])
     return x, ks, vs, states
 
 
-def hidden(weights, tokens, sizes):
+def hidden(weights, tokens, sizes, act_dtype=None):
     """tokens (B, T) int32 -> (the hidden states as the head takes them (B,
     T, d): after the final RMSNorm and the division by ``hidden_size /
     dim_model_base``; the normed, unrotated keys and the values of the
-    sparse layers, (L_sparse, B, T, 1, KV * hd) each)."""
-    x, ks, vs, _ = _layers(weights, tokens, sizes)
+    sparse layers, (L_sparse, B, T, 1, KV * hd) each). ``act_dtype``: the
+    twin (module docstring); None: float32 throughout."""
+    x, ks, vs, _ = _layers(weights, tokens, sizes, act_dtype)
     return x, jnp.stack(ks), jnp.stack(vs)
 
 

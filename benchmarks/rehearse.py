@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import functools
 import json
 import os
 import sys
@@ -92,6 +91,9 @@ def tiny(cell: spec.Cell, sizes=None, mix_too: bool = True) -> spec.Cell:
     found = dict(cell.found)
     if cell.kind == "serve":
         found.update(server={"n_slots": 4}, rate_req_s=6.0, warm_inflight=2)
+        # found at the published size on the chip: a tiny program on the
+        # CPU is held to its twin itself
+        found.pop("twin_ratio", None)
     return dataclasses.replace(cell, config=config, mix=mix, found=found)
 
 
@@ -257,45 +259,35 @@ def compile_train(cell: spec.Cell, topo_devices) -> None:
 
 
 def compile_serve(cell: spec.Cell, topo_devices) -> None:
+    """Every program of the engine the cell's server builds, as
+    ``DecodeEngine.programs()`` yields them: the jitted function and the
+    arguments of a real call, whatever the pool's leaves and the programs'
+    vectors are (a latent pool, a state beside rows, the next vector a PR
+    adds). The engine is built here on the CPU over weights of zeros and
+    nothing of it runs: only shapes, dtypes and the static configuration
+    reach the compiler."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
     from mingpt_distributed_tpu.models import gpt
-    from mingpt_distributed_tpu.serving import engine as engine_mod
+    from mingpt_distributed_tpu.serving import InferenceServer
 
     options = spec.server_options(cell)
     cfg = spec.gpt_config(cell, training=False)
     one = SingleDeviceSharding(topo_devices[0])
-    on_chip = lambda tree: jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
-    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-    params = on_chip(jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
-    n_slots = int(options["n_slots"])
-    pool = (cfg.n_layer, n_slots, cfg.block_size, cfg.kv_heads, cfg.head_dim)
-    cache = {"k": sds(pool, jnp.dtype(cfg.dtype)),
-             "v": sds(pool, jnp.dtype(cfg.dtype))}
-    prefill = jax.jit(functools.partial(engine_mod._prefill_impl, cfg=cfg),
-                      donate_argnums=(1,))
-    decode = jax.jit(functools.partial(engine_mod._decode_impl, cfg=cfg),
-                     donate_argnums=(1,))
-    for bucket in options["prefill_buckets"]:
+    on_chip = lambda x: jax.ShapeDtypeStruct(
+        jnp.shape(x), jnp.result_type(x), sharding=one)
+    params = jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype),
+        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
+    engine = InferenceServer(params, cfg, warmup=False, **options).engine
+    for family, variant, jitted, args, kwargs in engine.programs():
         t0 = time.perf_counter()
-        compiled = prefill.lower(
-            params, cache, sds((int(bucket),), jnp.int32),
-            sds((), jnp.int32), sds((), jnp.int32), sds((), jnp.int32),
-            sds((), jnp.float32), sds((), jnp.int32), sds((), jnp.float32),
-            sds((), jnp.bool_), sds((), jnp.uint32)).compile()
-        _report(f"{cell.name}: prefill bucket={bucket} n_slots={n_slots}",
+        compiled = jitted.lower(*jax.tree.map(on_chip, args),
+                                **kwargs).compile()
+        _report(f"{cell.name}: {family} {variant} n_slots={engine.n_slots}",
                 compiled, time.perf_counter() - t0)
-    t0 = time.perf_counter()
-    vec = lambda dtype: sds((n_slots,), dtype)
-    compiled = decode.lower(
-        params, cache, vec(jnp.int32), vec(jnp.int32), vec(jnp.float32),
-        vec(jnp.int32), vec(jnp.float32), vec(jnp.bool_), vec(jnp.uint32),
-        vec(jnp.int32)).compile()
-    _report(f"{cell.name}: decode n_slots={n_slots}", compiled,
-            time.perf_counter() - t0)
 
 
 def rehearse_compile(cell: spec.Cell) -> None:

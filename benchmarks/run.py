@@ -9,8 +9,10 @@ files under ``benchmarks/`` found by name (``benchmarks/README.md``).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (with ``--trace 0`` the cell's
-end-to-end metrics, with ``--trace 1`` its per-layer metrics), ``device``
-and, traced, ``breakdown``. ``device`` holds, beside ``memory_peak_bytes``, the
+end-to-end metrics, with ``--trace 1`` its per-layer metrics), ``device``,
+traced, ``breakdown``, and last ``compared``: each number the verdict
+compared beside its limit (``harness/check.compared``), which are also the
+last lines of standard error. ``device`` holds, beside ``memory_peak_bytes``, the
 runtime's memory statistics it is made of (``harness/device.memory``). Lines
 before it are notes for a reader; nothing parses them. Without a TPU of a kind the table of peaks knows, or with fewer
 chips than the cell asks for, the exit code is 2 and no result is printed:
@@ -80,6 +82,7 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), required=True)
     args = ap.parse_args(argv)
 
+    from benchmarks.harness import check
     from benchmarks.harness import compiles as compiles_lib
     from benchmarks.harness import device, serve_cell, spec, train_cell
 
@@ -122,9 +125,15 @@ def main(argv=None) -> int:
             m["name"]: {"value": float(values[m["name"]]), "unit": m["unit"]}
             for m in cell.end_to_end}
     line["device"] = dev
+    # each number the verdict compared beside its limit: last in the line
+    # and last on standard error
+    line["compared"] = check.compared(result["verdict"])
     note("end_to_end", dict(result["end_to_end"], setup_s=result["setup_s"],
                             total_s=time.perf_counter() - T_PROCESS))
     print(json.dumps(line), flush=True)
+    for name, (value, limit) in line["compared"].items():
+        print(f"compared {name}: {value!r} limit {limit!r}", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

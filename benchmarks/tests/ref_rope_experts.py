@@ -41,6 +41,12 @@ route may be followed are the yardstick's; this file states none. In
 float32 the program agrees with this file to 2e-5 (``tests/test_arch.py``),
 with a table that holds its own choice as without one.
 
+The twin: ``hidden(..., act_dtype=jnp.bfloat16)`` rounds to that type the
+matmuls' weights, the embedding's output, every matmul's output, the rotated
+queries and keys, the SwiGLU's inner product, every residual sum and every
+norm's output; the norms, the softmax, the router and the gates stay
+float32 inside. At ``None`` it is the function of before, bit for bit.
+
 ``weights``: wte (V, d), lnf_g (d,), head (d, V); blocks: ln1_g, ln2_g
 (L, d); wq (L, d, H hd); wk, wv (L, d, KV hd); wo (L, H hd, d); w_router
 (L, d, E); w_eg, w_e1 (L, E, d, f); w_e2 (L, E, f, d).
@@ -56,6 +62,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from benchmarks.references.rounding import rounder
 
 
 def weights_from_program(params) -> dict:
@@ -87,7 +95,7 @@ def _rotate(x, theta):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
-def _experts(h, w, top_k, renormalise, chosen=None):
+def _experts(h, w, top_k, renormalise, r, chosen=None):
     """(B, T, d) -> ((B, T, d), router logits (B, T, E)): every expert on
     every token, weighted by its gate, which is zero where the expert was
     not chosen. ``chosen`` (B, T, k): the experts to take; None: the k of
@@ -100,46 +108,49 @@ def _experts(h, w, top_k, renormalise, chosen=None):
     if renormalise:
         top = top / top.sum(-1, keepdims=True)
     gates = (jax.nn.one_hot(chosen, p.shape[-1]) * top[..., None]).sum(-2)
-    inner = jax.nn.silu(jnp.einsum("btd,edf->btef", h, w["w_eg"])) \
-        * jnp.einsum("btd,edf->btef", h, w["w_e1"])
-    return jnp.einsum("btef,efd,bte->btd", inner, w["w_e2"], gates), z
+    inner = r(jax.nn.silu(r(jnp.einsum("btd,edf->btef", h, r(w["w_eg"]))))
+              * r(jnp.einsum("btd,edf->btef", h, r(w["w_e1"]))))
+    return r(jnp.einsum("btef,efd,bte->btd", inner, r(w["w_e2"]), gates)), z
 
 
-def hidden(weights, tokens, sizes, experts=None):
+def hidden(weights, tokens, sizes, experts=None, act_dtype=None):
     """tokens (B, T) int32 -> (final-RMSNorm hidden (B, T, d), keys (rotated)
     and values of every layer, each (L, B, T, KV, hd), the router's logits
     (L, B, T, E)). ``experts`` (L, B, T, k) int32: the experts every token
-    takes in every layer; None: the router's own k best."""
+    takes in every layer; None: the router's own k best. ``act_dtype``: the
+    twin (module docstring); None: float32 throughout."""
     n_head, n_kv = sizes["num_attention_heads"], sizes["num_key_value_heads"]
     top_k, eps = sizes["num_experts_per_tok"], sizes["rms_norm_eps"]
     theta = float(sizes["rope_theta"])
     renormalise = top_k > 1 and bool(sizes.get("norm_topk_prob", True))
+    r = rounder(act_dtype)
     b, t = tokens.shape
-    x = weights["wte"][tokens]
+    x = r(weights["wte"][tokens])
     d = x.shape[-1]
     hd = d // n_head
     causal = jnp.tril(jnp.ones((t, t), bool))
 
     def block(x, layer):
         w, chosen = layer
-        h = _rms(x, w["ln1_g"], eps)
-        q = _rotate((h @ w["wq"]).reshape(b, t, n_head, hd), theta)
-        k = _rotate((h @ w["wk"]).reshape(b, t, n_kv, hd), theta)
-        v = (h @ w["wv"]).reshape(b, t, n_kv, hd)
+        h = r(_rms(x, w["ln1_g"], eps))
+        q = r(_rotate(r(h @ r(w["wq"])).reshape(b, t, n_head, hd), theta))
+        k = r(_rotate(r(h @ r(w["wk"])).reshape(b, t, n_kv, hd), theta))
+        v = r(h @ r(w["wv"])).reshape(b, t, n_kv, hd)
         # a query head reads the KV head of its group
         kq, vq = (jnp.repeat(a, n_head // n_kv, axis=2) for a in (k, v))
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, kq) / math.sqrt(hd)
         scores = jnp.where(causal, scores, -jnp.inf)
-        att = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), vq)
-        x = x + att.reshape(b, t, d) @ w["wo"]
-        out, z = _experts(_rms(x, w["ln2_g"], eps), w, top_k, renormalise,
-                          chosen)
-        return x + out, (k, v, z)
+        att = r(jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1),
+                           vq))
+        x = r(x + r(att.reshape(b, t, d) @ r(w["wo"])))
+        out, z = _experts(r(_rms(x, w["ln2_g"], eps)), w, top_k, renormalise,
+                          r, chosen)
+        return r(x + out), (k, v, z)
 
     with jax.default_matmul_precision("highest"):
         x, (ks, vs, router) = jax.lax.scan(
             block, x.astype(jnp.float32), (weights["blocks"], experts))
-        x = _rms(x, weights["lnf_g"], eps)
+        x = r(_rms(x, weights["lnf_g"], eps))
     return x, ks, vs, router
 
 

@@ -6,6 +6,7 @@ experts, keys under other names than GPT-2's) with its reference
 from the generalised code what they got before it."""
 
 import dataclasses
+import inspect
 import json
 import os
 import types
@@ -262,8 +263,29 @@ def routed_verdict(reference=None, config=None, found=None, **sizes):
     verdict = check.serve_verdict(
         reference or spec.load_reference(cell.config), cell.config,
         driver.server, prompts, 4)
-    assert verdict["kv_rel_tol"] == pytest.approx(0.011 * (3 / 12) ** 0.3)
+    assert_held_to_the_twin(verdict)
     return verdict
+
+
+def assert_held_to_the_twin(verdict, twin_ratio=1.0):
+    """Every tolerance of a verdict is the factor times the twin's own
+    error over the same rows, in the layer that stands worst and (times the
+    cell's ratio) over the whole stack, and nothing else."""
+    tol = lambda twin, ratio=1.0: max(
+        check.SERVE_TWIN_FACTOR * ratio * twin, check.SERVE_KV_REL_FLOOR)
+    for case in verdict["cases"]:
+        for name in ("k_rel", "v_rel"):
+            assert case["compared"][name] == [
+                case[name], tol(case["twin_" + name], twin_ratio)]
+            layers = [(e, tol(t)) for e, t in zip(
+                case[name + "_layers"], case["twin_" + name + "_layers"])]
+            worst = case[name[0] + "_worst_layer"]
+            assert case["compared"][name + "_layer"] == list(layers[worst])
+            assert all(e / t <= layers[worst][0] / layers[worst][1]
+                       for e, t in layers)
+    assert verdict["kv_rel_tol"] == max(
+        limit for c in verdict["cases"]
+        for n, (_, limit) in c["compared"].items() if n != "logit_gap")
 
 
 def another(reference, **functions):
@@ -398,11 +420,19 @@ def test_a_dense_reference_takes_the_parent_s_path(monkeypatch, name):
     verdict = check.serve_verdict(reference, cell.config, driver.server,
                                   [prompt], 3)
     assert verdict["ok"] and set(verdict) == {"ok", "kv_rel_tol", "cases"}
+    assert_held_to_the_twin(verdict)
     (case,) = verdict["cases"]
     assert set(case) == {
         "prompt_len", "bucket", "max_logit_gap", "tokens_equal_argmax",
+        "compared", "kv_ratio", "kv_ratio_layers", "k_worst_layer",
+        "v_worst_layer",
         *(f"{a}_{b}" for a in "kv" for b in (
-            "rel", "max_abs", "rel_layers", "rel_p50_layers"))}
+            "rel", "max_abs", "rel_layers", "rel_p50_layers")),
+        *(f"twin_{a}_{b}" for a in "kv" for b in ("rel", "rel_layers"))}
+    assert len(case["twin_k_rel_layers"]) == 2
+    assert set(case["compared"]) == {
+        "logit_gap", *(f"{a}_rel{q}" for a in "kv" for q in ("", "_layer"))}
+    assert 0.5 < case["kv_ratio"] < check.SERVE_TWIN_FACTOR
     # slot 0 was taken and given back; its 23 rows are still in the pool.
     # The tokens fed back are not in the verdict: greedy, from the reference
     seq = list(prompt)
@@ -430,3 +460,202 @@ def test_rehearse_runs_a_routed_cell_in_bf16_and_prints_its_routes(capsys):
     for case in line["check"]["cases"]:
         assert len(case["route_banded_layers"]) == 2
         assert len(case["route_followed_layers"]) == 2
+
+
+# -- the tolerance is the twin's ----------------------------------------------
+
+def dense_verdict(n_layer, monkeypatch=None, **patched):
+    for name, value in patched.items():
+        monkeypatch.setattr(check, name, value)
+    cell = rehearse.tiny(spec.load_cell("gpt2-124m.serve-decode"),
+                         sizes={"n_layer": n_layer})
+    driver = serve_cell.Driver(cell, SEED, traced=False)
+    prompt = np.random.default_rng(1).integers(0, 384, size=40, dtype=np.int32)
+    return check.serve_verdict(spec.load_reference(cell.config), cell.config,
+                               driver.server, [prompt], 3)
+
+
+def test_the_tolerance_follows_the_twin_with_depth_and_reads_no_depth():
+    """Rounding adds up with depth, the twin reads it, and the tolerance is
+    the twin's: a deeper stack gets more room from the reference's own
+    arithmetic, a layer of it what that layer needs."""
+    shallow, deep = dense_verdict(2), dense_verdict(8)
+    assert shallow["ok"] and deep["ok"], (shallow, deep)
+    assert_held_to_the_twin(shallow)
+    assert_held_to_the_twin(deep)
+    a, b = shallow["cases"][0], deep["cases"][0]
+    assert b["twin_k_rel"] > 1.2 * a["twin_k_rel"]
+    assert b["compared"]["k_rel"][1] > 1.2 * a["compared"]["k_rel"][1]
+    # the first layer of the deep stack is held tighter than its last, and
+    # than the whole: where a row in fewer bits stands out
+    assert b["twin_k_rel_layers"][0] < 0.7 * b["twin_k_rel_layers"][-1]
+    assert b["twin_k_rel_layers"][0] < b["twin_k_rel"]
+    tolerance_lines = [line for line in inspect.getsource(check).splitlines()
+                       if "n_layer" in line and "tol" in line.lower()
+                       and not line.lstrip().startswith("#")]
+    assert tolerance_lines == []
+    assert not hasattr(check, "SERVE_KV_REL_TOL_12_LAYERS")
+    assert not hasattr(check, "SERVE_KV_DEPTH_POWER")
+
+
+def test_a_twin_over_the_ceiling_is_an_error_not_a_pass(monkeypatch):
+    with pytest.raises(RuntimeError, match="assumed.weights"):
+        dense_verdict(2, monkeypatch, SERVE_TWIN_CEILING=1e-4)
+
+
+def test_a_reference_without_a_twin_is_an_error():
+    cell = rehearse.tiny(spec.load_cell("gpt2-124m.serve-decode"))
+    reference = spec.load_reference(cell.config)
+    twinless = another(reference, hidden=lambda weights, tokens, sizes:
+                       reference.hidden(weights, tokens, sizes))
+    driver = serve_cell.Driver(cell, SEED, traced=False)
+    with pytest.raises(RuntimeError, match="act_dtype"):
+        check.serve_verdict(twinless, cell.config, driver.server,
+                            [np.arange(20, dtype=np.int32)], 3)
+
+
+def layers_case(errs, twins, twin_ratio=1.0):
+    """``held_to_twin`` on one tensor's layers (keys and values alike); the
+    whole stack as ``row_errors`` forms it, every layer's rows the same
+    size."""
+    whole = lambda v: float(np.sqrt(np.mean(np.square(v))))
+    errs, twins = np.asarray(errs, np.float32), np.asarray(twins, np.float32)
+    side = lambda v: {f"{a}_rel{b}": x for a in "kv"
+                      for b, x in (("", np.float32(whole(v))), ("_layers", v))}
+    held = check.held_to_twin(side(errs), side(twins), twin_ratio)
+    return held, all(e <= limit for e, limit in held["compared"].values())
+
+
+@pytest.mark.parametrize("errs,twins,ratio,fails,worst", [
+    # the program reads its twin's error in every layer
+    ([.0033, .0060, .0080, .0100], [.0038, .0063, .0081, .0100], 1.0, None, 3),
+    # rows in eight bits with a scale a row add 0.6% in quadrature: the whole
+    # stack stays inside its tolerance, and the first layer stands out
+    ([.00685, .0087, .01008, .01166, .01297, .01432],
+     [.0038, .0063, .0081, .0100, .0115, .0130], 1.0, "k_rel_layer", 0),
+    # a cell whose program reads 0.75 of its twin is held to that over the
+    # whole stack; a layer is held to the twin itself
+    ([.0050, .0091, .0096, .0120], [.0060, .0121, .0128, .0162], 0.76, None, 0),
+    ([.0050, .0091, .0096, .0120], [.0060, .0121, .0128, .0162], 0.55,
+     "k_rel", 0),
+    # one broken layer among sound ones, deep in the stack
+    ([.0033, .0060, .0130, .0100], [.0038, .0063, .0081, .0100], 1.0,
+     "k_rel_layer", 2),
+    # float32 on both sides: the twin reads nothing and the floor holds
+    ([2e-6, 3e-6], [0.0, 0.0], 1.0, None, 1),
+    ([2e-6, 3e-4], [0.0, 0.0], 1.0, "k_rel_layer", 1),
+], ids=["sound", "int8-rows", "a-cell-s-ratio", "under-a-cell-s-ratio",
+        "one-layer", "float32", "float32-broken"])
+def test_every_layer_is_held_to_its_twin_and_the_stack_to_the_cell_s_ratio(
+        errs, twins, ratio, fails, worst):
+    held, _ = layers_case(errs, twins, ratio)
+    outside = {n for n, (e, limit) in held["compared"].items() if e > limit}
+    if fails is None:
+        assert outside == set(), held
+    elif fails == "k_rel":          # the whole stack alone: every layer holds
+        assert outside == {"k_rel", "v_rel"}, held
+    else:
+        assert {"k_rel_layer", "v_rel_layer"} <= outside, held
+    assert held["k_worst_layer"] == held["v_worst_layer"] == worst
+    tol = max(check.SERVE_TWIN_FACTOR * twins[worst], check.SERVE_KV_REL_FLOOR)
+    assert held["compared"]["k_rel_layer"] == pytest.approx([errs[worst], tol])
+    if len(errs) == 6:
+        # what the whole stack alone would have let through
+        assert "k_rel" not in outside
+
+
+@pytest.mark.parametrize("ratio", [0.0, 1.2, -0.5])
+def test_no_cell_states_a_ratio_above_its_twin(ratio):
+    cell = rehearse.tiny(spec.load_cell("gpt2-124m.serve-decode"))
+    driver = serve_cell.Driver(cell, SEED, traced=False)
+    with pytest.raises(ValueError, match="twin_ratio"):
+        check.serve_verdict(spec.load_reference(cell.config), cell.config,
+                            driver.server, [np.arange(20, dtype=np.int32)], 3,
+                            twin_ratio=ratio)
+
+
+def test_a_cell_s_ratio_reaches_the_verdict_and_tightens_it():
+    """``found.twin_ratio`` of the cell's file is what ``serve_cell.run``
+    hands the verdict: a tiny dense stack reads near its twin, so a stated
+    ratio of a half fails it where the cell's own passes."""
+    cell = rehearse.tiny(spec.load_cell("gpt2-124m.serve-decode"))
+    run = lambda c: serve_cell.run(
+        c, seed=SEED, seconds=0.5, traced=False, devices=jax.devices()[:1],
+        t_process=0.0, compiles=compiles.CompileCounter())["verdict"]
+    stated = cell.found.get("twin_ratio", 1.0)
+    sound = run(cell)
+    assert sound["ok"], sound
+    assert_held_to_the_twin(sound, stated)
+    half = run(dataclasses.replace(
+        cell, found={**cell.found, "twin_ratio": 0.5 * stated}))
+    assert half["ok"] is False
+    assert_held_to_the_twin(half, 0.5 * stated)
+
+
+def test_the_profiler_s_hold_is_not_the_open_loop_s_time(monkeypatch):
+    """A traced run's profiler holds the thread when it starts and when it
+    stops. The traffic's clock stands still meanwhile: nothing queues up
+    behind the instrument, and the window is as long in running time."""
+    import contextlib
+    import time
+
+    from benchmarks.harness import trace, traffic
+
+    @contextlib.contextmanager
+    def slow_capture(directory):
+        time.sleep(0.4)
+        try:
+            yield
+        finally:
+            time.sleep(0.4)
+
+    monkeypatch.setattr(trace, "capture", slow_capture)
+    cell = rehearse.tiny(fixture_cell())
+    driver = serve_cell.Driver(cell, SEED, traced=True)
+    reqs = traffic.requests(
+        cell.mix, driver.gpt_cfg.vocab_size, SEED,
+        rate=cell.found["rate_req_s"], horizon_s=6.0, warm_inflight=2)
+    plays = {traced: driver.play(reqs, 2.5, traced=traced).summary()
+             for traced in (False, True)}
+    plain, held = plays[False], plays[True]
+    assert plain["profiler_held_s"] == 0.0
+    assert 0.8 <= held["profiler_held_s"] < 1.2
+    # the same requests fall in the window, none of them late by the hold
+    assert held["attempted"] == plain["attempted"]
+    assert held["generator_late_ms_p99"] < 200.0
+    assert held["offered_req_s"] == pytest.approx(plain["offered_req_s"])
+
+
+@pytest.mark.parametrize("side_by_side", [False, True],
+                         ids=["a-row-by-head", "a-row-s-heads-side-by-side"])
+def test_both_row_layouts_of_a_pool_read_the_same_numbers(side_by_side):
+    """The reference hands rows ``(T, KV, hd)``; a pool may keep them so or
+    ``(T, 1, KV x hd)`` (``ROADMAP.md`` Speed 1): one comparison."""
+    rng = np.random.default_rng(0)
+    layers, slots, rows, kv, hd = 5, 3, 24, 4, 8
+    ref = {n: rng.normal(size=(layers, 16, kv, hd)).astype(np.float32)
+           for n in "kv"}
+    pool = {n: rng.normal(size=(layers, slots, rows, kv, hd)) * 0.01
+            for n in "kv"}
+    for n in "kv":
+        pool[n][:, 1, :16] += ref[n]
+    pool = {n: a.astype(jax.numpy.bfloat16) for n, a in pool.items()}
+    flat = {n: a.reshape(layers, slots, rows, 1, kv * hd)
+            for n, a in pool.items()}
+    want = check.pool_errors(pool, ref["k"], ref["v"], 1, 12)
+    got = check.pool_errors(flat if side_by_side else pool,
+                            ref["k"], ref["v"], 1, 12)
+    assert set(got) == set(want) and len(got["k_rel_layers"]) == layers
+    for name in want:
+        np.testing.assert_allclose(got[name], want[name], rtol=1e-5)
+    np.testing.assert_allclose(
+        check.row_distance(flat if side_by_side else pool, ref["k"], ref["v"],
+                           1, 2),
+        check.row_distance(pool, ref["k"], ref["v"], 1, 2), rtol=1e-5)
+    # by hand, from the per-head layout
+    diff = np.asarray(pool["k"][:, 1, :12], np.float32) - ref["k"][:, :12]
+    assert float(got["k_rel"]) == pytest.approx(
+        np.sqrt((diff ** 2).sum() / (ref["k"][:, :12] ** 2).sum()), rel=1e-4)
+    wrong = {n: a[..., :-1] for n, a in flat.items()}
+    with pytest.raises(RuntimeError, match="another shape"):
+        check.pool_errors(wrong, ref["k"], ref["v"], 1, 12)
