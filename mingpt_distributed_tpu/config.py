@@ -285,6 +285,32 @@ class GPTConfig:
     sparse_window: int = 2048
     sparse_init_blocks: int = 1
     sparse_dense_len: int = 8192
+    # A looped stack (Ouro): the ``n_layer`` layers run ``n_passes`` times
+    # over one set of weights. The final norm closes every pass and its
+    # output is what the next pass starts from; pass t of layer l caches and
+    # attends its own keys and values (cache plane ``t * n_layer + l``:
+    # ``cache_planes`` planes over ``n_layer`` layers of weights).
+    n_passes: int = 1
+    # An RMSNorm after each sublayer as well as before it: x + N2(Attn(N1 x)),
+    # then a + N4(MLP(N3 a)) (leaves ``ln1_post_scale``, ``ln2_post_scale``).
+    post_norms: bool = False
+    # One Linear(n_embd, 1) read on every pass's normed output: g_t =
+    # sigmoid(w . h_t + b), and p_t = g_t * prod_{j<t}(1 - g_j) the mass
+    # that exits at pass t (the last pass takes what is left). A token
+    # leaves at the first pass whose cumulative p reaches
+    # ``exit_threshold``; at 1, the only value built, every token runs every
+    # pass and the gate moves no logit (the serving programs count it:
+    # generate.LOOP_PASSES).
+    exit_gate: bool = False
+    exit_threshold: float = 1.0
+    # The dtype the residual stream is carried in between sublayers (and
+    # from pass to pass); None = ``dtype``. "float32": a sublayer's input is
+    # normed in float32 and rounded to ``dtype`` where it enters a matmul,
+    # its output joins the stream in float32, and the head reads the final
+    # norm rounded to ``dtype``. Rounding the stream itself at each of 384
+    # sums is what a bfloat16 stack of 48 layers run four times cannot
+    # afford (PERF.md, PR 37).
+    residual_dtype: Optional[str] = None
     # Cross-entropy head chunking: >1 splits the LM-head matmul + softmax
     # into this many sequence chunks under jax.checkpoint, so the (B, T, V)
     # fp32 logits tensor — the dominant activation at GPT-2 vocab sizes —
@@ -444,6 +470,7 @@ class GPTConfig:
                 or self.dim_model_base < 0:
             raise ConfigError(
                 "scale_emb must be > 0, scale_depth and dim_model_base >= 0")
+        self._validate_looped()
         if self.mixer_types is not None:
             self._validate_hybrid()
         elif self.lightning_heads or self.lightning_head_dim \
@@ -451,6 +478,78 @@ class GPTConfig:
             raise ConfigError(
                 "lightning_heads, lightning_head_dim, qk_norm and "
                 "output_gate belong to a hybrid stack: set mixer_types")
+
+    def _validate_looped(self) -> None:
+        """A looped stack's own rules, and what it is not built with, a
+        sentence each."""
+        if self.n_passes < 1:
+            raise ConfigError(f"n_passes must be >= 1, got {self.n_passes}")
+        if self.exit_threshold != 1.0:
+            raise ConfigError(
+                f"exit_threshold={self.exit_threshold}: only 1 is built, at "
+                "which every token runs every pass. Under it a token leaves "
+                "at the first pass whose cumulative exit mass reaches the "
+                "threshold, and which keys and values that lane then owes "
+                "the later passes of later tokens is in no published config: "
+                "a guessed mechanism under a real model's name is worse "
+                "than none")
+        if (self.post_norms or self.exit_gate) and not self.rmsnorm:
+            raise ConfigError(
+                "post_norms and exit_gate are written for an RMS-normed "
+                "stack: the norm after a sublayer has a scale and no bias, "
+                "and the gate reads the final RMSNorm's output")
+        if self.residual_dtype not in (None, "float32"):
+            raise ConfigError(
+                f"residual_dtype {self.residual_dtype!r}: None (the compute "
+                "dtype) or 'float32'")
+        if (self.post_norms or self.exit_gate or self.residual_dtype) \
+                and self.mixer_types is not None:
+            raise ConfigError(
+                "post_norms, exit_gate and residual_dtype are not written "
+                "for a hybrid stack (mixer_types), whose layers have their "
+                "own block")
+        if self.n_passes == 1:
+            return
+        if not self.rmsnorm:
+            raise ConfigError(
+                "a looped stack (n_passes > 1) carries its final RMSNorm's "
+                "output from pass to pass: it needs rmsnorm")
+        if self.mixer_types is not None:
+            raise ConfigError(
+                "a looped stack (n_passes > 1) is not written for a hybrid "
+                "stack (mixer_types): a recurrent state a pass and layer, "
+                "and which pass's state a later pass reads, is no published "
+                "model's")
+        if self.n_experts or self.kv_lora_rank:
+            raise ConfigError(
+                "a looped stack (n_passes > 1) is built over per-head rows "
+                "and a dense MLP: the routed rows' counter has a row a layer "
+                "of weights and the latent cache's absorbed attention is "
+                "untested over passes x layers planes")
+        if self.pp_microbatches:
+            raise ConfigError(
+                "a looped stack (n_passes > 1) is not pipelined "
+                "(pp_microbatches): a stage would have to hand its output "
+                "back to the first stage n_passes - 1 times, a schedule "
+                "parallel/pipeline.py does not have")
+
+    @property
+    def closes_passes(self) -> bool:
+        """Whether the final norm closes every pass inside the stack (a
+        looped stack carries it to the next pass, an exit gate reads it), so
+        that the head takes the last pass's output as it comes."""
+        return self.n_passes > 1 or self.exit_gate
+
+    @property
+    def stream_dtype(self) -> str:
+        """The dtype the residual stream is carried in."""
+        return self.residual_dtype or self.dtype
+
+    @property
+    def cache_planes(self) -> int:
+        """Planes of keys and values a token caches: one a pass and layer
+        of a looped stack, ``n_layer`` where the layers run once."""
+        return self.n_passes * self.n_layer
 
     def _validate_hybrid(self) -> None:
         """A hybrid stack's own rules, and what it does not compose with,

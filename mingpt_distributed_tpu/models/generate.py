@@ -41,9 +41,11 @@ from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.ops import layers as L
 from mingpt_distributed_tpu.ops import sparse_attention as sparse_ops
 
-# {"k", "v"}: (n_layer, B, block_size, heads, size), heads and size each
-# leaf's own (``cache_leaf_shapes``). A pool that counts a routed model's
-# rows carries a third leaf, MOE_ROWS, which is no cache of anything.
+# {"k", "v"}: (planes, B, block_size, heads, size), heads and size each
+# leaf's own (``cache_leaf_shapes``); a plane a layer, or a pass and layer
+# of a looped stack (``GPTConfig.cache_planes``). A pool that counts a
+# routed model's rows carries a third leaf, MOE_ROWS, which is no cache of
+# anything.
 Cache = Dict[str, jax.Array]
 
 #: the leaf of a serving pool's cache tree in which the cached forward
@@ -67,8 +69,15 @@ STATE = "state"
 #: sparse layers and counted lanes, each the mean over a layer's KV heads.
 #: It rides in a serving pool's donated tree as MOE_ROWS does.
 SPARSE_ROWS = "sparse_rows"
+#: and the counter of a looped stack's passes (``GPTConfig.n_passes``,
+#: ``exit_gate``): (2 + n_passes,) float32, [token-passes run, tokens, the
+#: exit mass ``p_t`` of each pass summed over those tokens], over the
+#: ``valid`` tokens of every prefill and decode program. Token-passes over
+#: tokens is ``n_passes`` at the one exit threshold that is built: each pass
+#: adds its own tokens, so a pass left out would show.
+LOOP_PASSES = "loop_passes"
 #: leaves of a cache tree that count and hold nothing of a request
-COUNTERS = (MOE_ROWS, SPARSE_ROWS)
+COUNTERS = (MOE_ROWS, SPARSE_ROWS, LOOP_PASSES)
 
 
 def cache_leaf_shapes(cfg: GPTConfig, batch: int) -> Dict[str, Tuple[int, ...]]:
@@ -78,7 +87,9 @@ def cache_leaf_shapes(cfg: GPTConfig, batch: int) -> Dict[str, Tuple[int, ...]]:
     ``head_dim``. Latent attention caches two different things a token,
     each shared by all heads: ``"k"`` the rotated rope key and ``"v"`` the
     normed latent (the values the absorbed attention averages, and the
-    first part of every key, so stored once)."""
+    first part of every key, so stored once). A looped stack has a plane a
+    pass and layer (``cfg.cache_planes``): pass t of layer l keeps its own
+    keys and values at plane ``t * n_layer + l``."""
     if cfg.mixer_types is not None:
         # rows for the sparse layers alone; the lightning layers keep a
         # state a head and nothing a position
@@ -94,7 +105,7 @@ def cache_leaf_shapes(cfg: GPTConfig, batch: int) -> Dict[str, Tuple[int, ...]]:
             shapes[STATE] = (lin, batch, cfg.lightning_heads,
                              cfg.lightning_head_dim, cfg.lightning_head_dim)
         return shapes
-    rows = (cfg.n_layer, batch, cfg.block_size)
+    rows = (cfg.cache_planes, batch, cfg.block_size)
     if cfg.kv_lora_rank:
         return {"k": rows + (1, cfg.qk_rope_head_dim),
                 "v": rows + (1, cfg.kv_lora_rank)}
@@ -113,6 +124,14 @@ def init_sparse_rows(cfg: GPTConfig) -> Optional[jax.Array]:
     if SPARSE not in (cfg.mixer_types or ()):
         return None
     return jnp.zeros((2,), jnp.float32)
+
+
+def init_loop_passes(cfg: GPTConfig) -> Optional[jax.Array]:
+    """A zeroed LOOP_PASSES leaf, or None where the layers run once and no
+    gate is read."""
+    if cfg.n_passes == 1 and not cfg.exit_gate:
+        return None
+    return jnp.zeros((2 + cfg.n_passes,), jnp.float32)
 
 
 def init_moe_rows(cfg: GPTConfig) -> Optional[jax.Array]:
@@ -138,10 +157,10 @@ def _lay_rows_over(old: jax.Array, rows: jax.Array, positions) -> jax.Array:
 
 @jax.named_scope("kv_layout")
 def _write_lane_rows(cache: Cache, rows, positions) -> Cache:
-    """Every layer's new row of lane ``b`` into the full ``(L, B, S, KV,
+    """Every plane's new row of lane ``b`` into the full ``(L, B, S, KV,
     hd)`` buffers at ``(:, b, positions[b])``: one ``dynamic_update_slice``
     of ``(L, 1, 1, KV, hd)`` a lane and buffer, in place, whatever layout
-    the device keeps the buffers in. ``rows`` is the layers' list of
+    the device keeps the buffers in. ``rows`` is the planes' list of
     ``{"k", "v"}`` rows, ``(B, 1, KV, hd)`` each (a hybrid stack's sparse
     layers bring POOLED rows too, which lie on a coarser grid:
     ``positions`` is then a dict of (B,) indices by leaf).
@@ -167,8 +186,8 @@ def _write_lane_rows(cache: Cache, rows, positions) -> Cache:
 def _cached_block(
     x: jax.Array,            # (B, T, D) — T = prompt length or 1
     blk: gpt.Params,         # one layer's params (no leading L axis)
-    cache: Cache,            # FULL (L, B, S, heads, size) buffers
-    layer: int,
+    cache: Cache,            # FULL (planes, B, S, heads, size) buffers
+    plane: int,              # the block's plane of the cache
     offset: jax.Array,       # absolute position of x[:, 0]: scalar, or (B,)
     cfg: GPTConfig,
     valid: Optional[jax.Array] = None,  # (B, T) bool: tokens of a request
@@ -181,16 +200,18 @@ def _cached_block(
     tokens' routed rows (ops/moe.grouped_swiglu, which routes no other
     token; None for any other MLP).
 
+    ``blk`` is the layer's weights and ``plane`` where its keys and values
+    lie in the cache: the layer's own number where the layers run once, and
+    ``pass * n_layer + layer`` in a looped stack, where one layer's weights
+    serve several planes.
+
     ``offset`` is one position for the whole batch (prefill, verify, solo
     ``generate``: rows that advance together): the rows are written into
-    the full cache at (layer, :, offset) here and the block attends the
-    layer's slice. That update is a small dynamic_update_slice on the big
-    buffer — XLA aliases it in place through the unrolled layer chain and
-    the decode scan carry. The original layer ``lax.scan`` instead
-    emitted every layer's updated cache as stacked ys, rewriting the
-    ENTIRE cache every decode step — one-token decode scaled with cache
-    size (~5.6 ms/token at gpt2-124M b8, the r4/r5 decode mystery)
-    instead of with the one-slot update.
+    the full cache at (plane, :, offset) here and the block attends the
+    plane's slice. That update is a small dynamic_update_slice on the big
+    buffer, which XLA aliases in place through the unrolled layer chain
+    and the decode scan's carry: a decode step costs the rows it writes,
+    not the cache's size.
 
     A ``(B,)`` offset, one a row, is the serving decode step: the batch is
     the pool's slot axis, T is 1 and every lane stands at its own
@@ -202,7 +223,7 @@ def _cached_block(
     row a lane attends for its own token is the row as cached), a slice
     of more than one block only as far as ``frontier``, the furthest
     position of a lane whose output counts (None: the furthest of all).
-    It returns the cache as it came: the caller writes all layers' rows
+    It returns the cache as it came: the caller writes all planes' rows
     after the last (``_write_lane_rows``). A capacity-routed expert MLP
     routes each lane alone, since lanes are other users' requests: a
     lane's routes must not depend on which other lanes are live. The
@@ -214,6 +235,9 @@ def _cached_block(
     through W_UK to the latent's size, the heads average latents, and the
     averages go through W_UV. Per-head keys and values of the cache are
     never built, in prefill or in decode.
+
+    ``cfg.post_norms``: each sublayer's output is RMS-normed before it
+    joins the residual stream, as in ``gpt._block``.
     """
     b, t, _ = x.shape
     nh, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
@@ -221,7 +245,7 @@ def _cached_block(
     if per_lane and t != 1:
         raise ValueError(f"a position a lane takes one token a lane, not {t}")
 
-    h = gpt._norm(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
+    h = gpt.sublayer_input(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
     if cfg.rope:
         rope = attn_ops.rope_tables(
             jnp.asarray(offset)[..., None] + jnp.arange(t),
@@ -244,9 +268,9 @@ def _cached_block(
     rows = {"k": k.astype(cache["k"].dtype), "v": v.astype(cache["v"].dtype)}
     if not per_lane:
         cache = {**cache, **{n: jax.lax.dynamic_update_slice(
-            cache[n], rows[n][None], (layer, 0, offset, 0, 0))
+            cache[n], rows[n][None], (plane, 0, offset, 0, 0))
             for n in ("k", "v")}}
-        big_k, big_v = cache["k"][layer], cache["v"][layer]
+        big_k, big_v = cache["k"][plane], cache["v"][plane]
     # attend against the whole cache; kv_offset makes query absolute
     # positions correct, and the causal mask kills both future tokens and
     # never-written (zero) slots beyond offset+t
@@ -254,7 +278,7 @@ def _cached_block(
         scale = cfg.qk_head_dim ** -0.5
         if per_lane:
             att = attn_ops.latent_attend_step(
-                q_lat, q_pe, cache["v"], cache["k"], layer, rows["v"],
+                q_lat, q_pe, cache["v"], cache["k"], plane, rows["v"],
                 rows["k"], offset, frontier=frontier, scale=scale)
         else:
             att = attn_ops.latent_attention(
@@ -263,7 +287,7 @@ def _cached_block(
             b, t, nh * cfg.v_head_dim)
     elif per_lane:
         att = attn_ops.causal_attend_step(
-            q, cache["k"], cache["v"], layer, rows["k"], rows["v"], offset,
+            q, cache["k"], cache["v"], plane, rows["k"], rows["v"], offset,
             frontier=frontier, window=cfg.attention_window,
             logit_softcap=cfg.attn_logit_softcap,
         ).reshape(b, t, nh * hd)
@@ -274,9 +298,11 @@ def _cached_block(
             logit_softcap=cfg.attn_logit_softcap,
         ).reshape(b, t, nh * hd)
     att = L.dense(att, blk["wo"], blk.get("bo"))
+    if cfg.post_norms:
+        att = L.rms_norm(att, blk["ln1_post_scale"], eps=cfg.norm_eps)
     x = x + att
 
-    h2 = gpt._norm(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
+    h2 = gpt.sublayer_input(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
     counts = None
     if "w_router" in blk and cfg.moe_scoring == "sigmoid":
         m, counts = gpt.routed_and_shared(h2, blk, cfg, valid, expert_layer)
@@ -299,6 +325,8 @@ def _cached_block(
     else:
         m = L.mlp_gelu(h2, blk["w_fc"], blk.get("b_fc"), blk["w_proj"],
                        blk.get("b_proj"))
+    if cfg.post_norms:
+        m = L.rms_norm(m, blk["ln2_post_scale"], eps=cfg.norm_eps)
     return x + m, cache, rows, counts
 
 
@@ -459,53 +487,80 @@ def _forward_cached_hidden(
     step under a position a lane reads of a long slice (``_cached_block``;
     a hybrid stack's sparse layers read every row and take no notice).
 
-    The layer loop is a static python loop (n_layer is static, decode
-    bodies are small) so each layer's cache update stays a one-slot
-    in-place write — see _cached_block; under a position a lane the
-    layers' rows are written together after the loop
-    (``_write_lane_rows``). Compile-time trade (ADVICE r5):
-    unrolling puts every layer's body in the HLO, so prefill+decode program
-    size and compile time grow roughly linearly with ``n_layer``. Fine at
-    gpt2-124M (12 layers); a 48-layer gpt2-xl pays ~4x the compile of a
-    scanned loop. If decode compile time ever binds for very deep configs,
-    gate this on ``n_layer`` and fall back to a lax.scan over layers —
-    accepting that the scan re-emits the whole cache per step (the r4/r5
-    ~5.6 ms/token decode regression this unrolled loop exists to kill).
+    The layers are a static python loop: every layer's body is in the
+    program, with its weights sliced out of their stack at a static index
+    and its plane of the cache a static one, so each cache update is a
+    row-sized write in place (``_cached_block``); under a position a lane
+    the planes' rows are written together after the loop
+    (``_write_lane_rows``). Program size and compile time grow with
+    ``n_layer`` (48 bodies of gpt2-xl: 200 s cold on the chip).
+
+    A looped stack (``cfg.n_passes`` > 1) runs that chain of layers once a
+    pass, the passes written out one after another like the layers (192
+    bodies for 48 layers run four times: the chip prefers it to a loop
+    over the passes with the plane a traced index, by 45.0 against 52.0 ms
+    a decode step, at twice the compile time; PERF.md, PR 37). The
+    weights are the same in every pass, the final norm closes each and its
+    output is what the next starts from, and pass t of layer l reads and
+    writes plane ``t * n_layer + l``. The exit gate reads every pass's
+    output; at the one threshold that is built its mass moves no logit and
+    is counted (LOOP_PASSES, over the ``valid`` tokens, each pass adding
+    the tokens it ran).
     """
     b, t = tokens.shape
-    compute_dtype = jnp.dtype(cfg.dtype)
     x = params["wte"][tokens]
     if not cfg.rope:
         pos = jnp.asarray(offset)[..., None] + jnp.arange(t)  # (T,) or (B, T)
         x = x + jnp.take(params["wpe"], pos, axis=0)
     if cfg.scale_emb != 1.0:
         x = x * cfg.scale_emb
-    x = x.astype(compute_dtype)
+    x = x.astype(cfg.stream_dtype)
 
     if cfg.mixer_types is not None:
         x, cache = _forward_cached_hybrid(params, x, cache, offset, cfg, valid)
         return gpt._norm(x, params["lnf_scale"], None, cfg), cache
 
-    rows, counts = [], []
     n_dense = cfg.n_dense_layers
     # a dropless layer's expert leaves stay the stack's: sliced out, they
     # would be copied whole into the route's loop
     whole = gpt.EXPERT_LEAVES if cfg.moe_scoring == "sigmoid" else ()
-    for layer in range(cfg.n_layer):
-        stack, at = (params["dense_blocks"], layer) if layer < n_dense \
-            else (params["blocks"], layer - n_dense)
-        blk = {n: a if n in whole else a[at] for n, a in stack.items()}
-        x, cache, new, routed = _cached_block(
-            x, blk, cache, layer, offset, cfg, valid,
-            expert_layer=at if whole else None, frontier=frontier)
-        rows.append(new)
-        if routed is not None:
-            counts.append(routed)
+    # the tokens a pass counts (LOOP_PASSES), where the cache counts any
+    counting = LOOP_PASSES in cache
+    if counting:
+        counted = jnp.ones((b, t), bool) if valid is None else valid
+        n_counted = jnp.sum(counted, dtype=jnp.float32)
+        ran = jnp.zeros((), jnp.float32)
+    rows, counts, gates = [], [], []
+    for index in range(cfg.n_passes):       # the same weights in every pass
+        for layer in range(cfg.n_layer):
+            stack, at = (params["dense_blocks"], layer) if layer < n_dense \
+                else (params["blocks"], layer - n_dense)
+            blk = {n: a if n in whole else a[at] for n, a in stack.items()}
+            x, cache, new, routed = _cached_block(
+                x, blk, cache, index * cfg.n_layer + layer, offset, cfg, valid,
+                expert_layer=at if whole else None, frontier=frontier)
+            rows.append(new)
+            if routed is not None:
+                counts.append(routed)
+        if cfg.closes_passes:   # the last pass's is the norm this returns
+            x = gpt._norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg)
+        if cfg.exit_gate:
+            gates.append(gpt.exit_gate_logits(params, x))
+        if counting:
+            ran = ran + n_counted
     if jnp.ndim(offset) == 1:
         cache = _write_lane_rows(cache, rows, offset)
     if MOE_ROWS in cache and counts:
         cache = {**cache, MOE_ROWS: cache[MOE_ROWS] + jnp.stack(counts)}
-    x = gpt._norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg)
+    if counting:
+        with jax.named_scope("exit_gate"):
+            mass = jnp.sum(jnp.where(
+                counted, gpt.exit_mass(jnp.stack(gates)), 0.0), axis=(1, 2)) \
+                if gates else jnp.zeros((cfg.n_passes,), jnp.float32)
+            cache = {**cache, LOOP_PASSES: cache[LOOP_PASSES]
+                     + jnp.concatenate([jnp.stack([ran, n_counted]), mass])}
+    if not cfg.closes_passes:
+        x = gpt._norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg)
     return x, cache
 
 
@@ -513,6 +568,8 @@ def _head_logits(params: gpt.Params, x: jax.Array, cfg: GPTConfig) -> jax.Array:
     """LM head over (B, t, D) hidden states -> (B, t, V) fp32 logits
     (with the Gemma-2 final softcap when configured)."""
     w_head = params["wte"].T if cfg.tie_weights else params["head"]
+    if cfg.residual_dtype:
+        x = x.astype(cfg.dtype)     # the head's matmul runs in the compute dtype
     if cfg.dim_model_base:
         x = (x / cfg.head_divisor).astype(x.dtype)
     logits = jnp.einsum(

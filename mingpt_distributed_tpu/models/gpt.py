@@ -90,6 +90,15 @@ def init(key: jax.Array, cfg: GPTConfig) -> Params:
         use_bias = not (cfg.swiglu or cfg.rmsnorm)  # GPT-2 mode has biases everywhere
 
         blocks: Params = {"ln1_scale": ones((nl, d)), "ln2_scale": ones((nl, d))}
+        if cfg.post_norms:
+            # drawn in [0.05, 0.2], not 1: seeded branches of unit size make
+            # a stack of four norms a layer chaotic, and one that runs its
+            # layers several times the more (benchmarks/README.md, "What
+            # assumed.weights owes a deep or looped stack"); a trained
+            # model's are learned
+            for name in ("ln1_post_scale", "ln2_post_scale"):
+                blocks[name] = jax.random.uniform(
+                    next(keys), (nl, d), jnp.float32, 0.05, 0.2).astype(dtype)
         if cfg.kv_lora_rank:
             r, rope_d = cfg.kv_lora_rank, cfg.qk_rope_head_dim
             blocks.update(
@@ -199,6 +208,11 @@ def init(key: jax.Array, cfg: GPTConfig) -> Params:
         params["lnf_bias"] = zeros((d,))
     if not cfg.tie_weights:
         params["head"] = normal(next(keys), (d, cfg.vocab_size))
+    if cfg.exit_gate:
+        # the weight and the bias drawn: at zero the gate would read one
+        # half whatever its input, and a gate left out would not show
+        params["exit_gate_w"] = normal(next(keys), (d,))
+        params["exit_gate_b"] = normal(next(keys), ())
     return params
 
 
@@ -314,6 +328,15 @@ def _norm(x, scale, bias, cfg: GPTConfig):
     if cfg.rmsnorm:
         return L.rms_norm(x, scale, eps=cfg.norm_eps)
     return L.layer_norm(x, scale, bias, eps=cfg.norm_eps)
+
+
+def sublayer_input(x, scale, bias, cfg: GPTConfig):
+    """A sublayer's normed input in the compute dtype: the norm of the
+    residual stream, rounded to ``cfg.dtype`` where the stream is carried in
+    another (``cfg.residual_dtype``), so that every matmul runs in the
+    compute dtype; its output then joins the stream by promotion."""
+    h = _norm(x, scale, bias, cfg)
+    return h.astype(cfg.dtype) if cfg.residual_dtype else h
 
 
 def latent_parts(h, blk: Params, cfg: GPTConfig, rope):
@@ -521,7 +544,7 @@ def _block(
         k_attn = k_resid1 = k_resid2 = None
 
     with jax.named_scope("attn"):
-        h = _norm(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
+        h = sublayer_input(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
         if cfg.kv_lora_rank:
             q, k, v = latent_qkv(h, blk, cfg, rope)
             hd = cfg.v_head_dim
@@ -553,11 +576,13 @@ def _block(
                 att = att + blk["bo"].astype(att.dtype)
         else:
             att = L.dense(att, blk["wo"], blk.get("bo"))
+        if cfg.post_norms:
+            att = L.rms_norm(att, blk["ln1_post_scale"], eps=cfg.norm_eps)
         att = L.dropout(att, cfg.resid_pdrop, k_resid1, deterministic)
         x = x + att
 
     with jax.named_scope("mlp"):
-        h2 = _norm(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
+        h2 = sublayer_input(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
         aux = jnp.zeros((), jnp.float32)
         if "w_router" in blk and cfg.moe_scoring == "sigmoid":
             m, _ = routed_and_shared(h2, blk, cfg)
@@ -583,8 +608,33 @@ def _block(
                     m = m + blk["b_proj"].astype(m.dtype)
             else:
                 m = L.mlp_gelu(h2, blk["w_fc"], blk.get("b_fc"), blk["w_proj"], blk.get("b_proj"))
+        if cfg.post_norms:
+            m = L.rms_norm(m, blk["ln2_post_scale"], eps=cfg.norm_eps)
         m = L.dropout(m, cfg.resid_pdrop, k_resid2, deterministic)
         return x + m, aux
+
+
+@jax.named_scope("exit_gate")
+def exit_gate_logits(params: Params, h: jax.Array) -> jax.Array:
+    """The exit gate's logits over a pass's normed output: (..., D) ->
+    (...,) float32, ``w . h + b`` summed in float32 (the gate is one of the
+    things a bfloat16 model keeps there)."""
+    return jnp.einsum(
+        "...d,d->...", h.astype(jnp.float32),
+        params["exit_gate_w"].astype(jnp.float32),
+    ) + params["exit_gate_b"].astype(jnp.float32)
+
+
+@jax.named_scope("exit_gate")
+def exit_mass(gate_logits: jax.Array) -> jax.Array:
+    """What exits at each pass: ``gate_logits`` (R, ...) float32, a pass
+    first -> ``p`` (R, ...), ``p_t = g_t * prod_{j<t}(1 - g_j)`` with ``g =
+    sigmoid(logits)``, the last pass taking what is left, so that the passes
+    sum to 1."""
+    g = jax.nn.sigmoid(gate_logits)
+    stay = jnp.cumprod(1.0 - g, axis=0)                  # prod_{j<=t}
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+    return jnp.concatenate([(g * before)[:-1], before[-1:]])
 
 
 def forward(
@@ -597,6 +647,7 @@ def forward(
     deterministic: bool = True,
     mesh=None,  # required only for attention="ring" (see _attention_dispatch)
     return_logits: bool = True,
+    return_gates: bool = False,
 ) -> Tuple[Optional[jax.Array], Optional[jax.Array]]:
     """Full forward pass -> (logits (B, T, V) float32, loss or None).
 
@@ -606,14 +657,28 @@ def forward(
     returns ``(None, loss)`` and — when ``cfg.loss_chunks`` applies — never
     materialises the (B, T, V) logits at all: the LM head + softmax run per
     sequence chunk under jax.checkpoint (see chunked_cross_entropy).
+
+    A looped stack (``cfg.n_passes`` > 1) runs its layers that many times
+    over the one set of weights; the final norm closes every pass, and its
+    output is what the next pass starts from and what the exit gate and the
+    head read. ``return_gates`` adds a third result, the exit gate's
+    float32 logits a pass (n_passes, B, T) (``cfg.exit_gate``); at the one
+    threshold that is built they move no logit.
     """
     b, t = tokens.shape
     if t > cfg.block_size:  # static shape — checked at trace time (B3 intent)
         raise ValueError(f"sequence length {t} > block_size {cfg.block_size}")
     if not deterministic and rng is None:
         raise ValueError("training-mode forward needs rng for dropout")
+    if cfg.n_passes > 1 and (targets is not None or not deterministic):
+        raise NotImplementedError(
+            "a looped stack (n_passes > 1) is served, not trained: its "
+            "family's objective is an expected loss over the exits with an "
+            "entropy term, which no published config states, and the last "
+            "pass's cross-entropy under its name would be a guess")
+    if return_gates and not cfg.exit_gate:
+        raise ValueError("return_gates needs cfg.exit_gate")
 
-    compute_dtype = jnp.dtype(cfg.dtype)
     x = params["wte"][tokens]  # (B, T, D) fp32 gather
     if not cfg.rope:
         # slice by *position*, add (the B4 fix: reference indexed pos table
@@ -626,7 +691,7 @@ def forward(
     x = L.dropout(x, cfg.embd_pdrop, emb_key, deterministic)
     if cfg.scale_emb != 1.0:
         x = x * cfg.scale_emb
-    x = x.astype(compute_dtype)
+    x = x.astype(cfg.stream_dtype)
 
     if cfg.mixer_types is not None:
         if mesh is not None and mesh.shape.get("pp", 1) > 1:
@@ -685,6 +750,11 @@ def forward(
         raise NotImplementedError(
             "pipeline stages split one stack of like layers: a model with "
             "leading dense layers (n_dense_layers) has two")
+    if mesh is not None and mesh.shape.get("pp", 1) > 1 and cfg.closes_passes:
+        raise NotImplementedError(
+            "pipeline stages run their layers once: a looped stack "
+            "(n_passes > 1) or an exit gate is not written for them")
+    gates = []
     if mesh is not None and mesh.shape.get("pp", 1) > 1:
         # pipeline stages over the pp axis (parallel/pipeline.py): the same
         # scanned block, applied to each stage's layer shard per microbatch.
@@ -841,19 +911,31 @@ def forward(
             schedule=cfg.pp_schedule,
         )
     else:
-        carry = (x, jnp.zeros((), jnp.float32))
-        if n_dense:
-            carry = run_stack(carry, xs_dense, n_dense)
-        x, moe_aux = run_stack(carry, xs, nl - n_dense)
-    return _head_and_loss(params, x, cfg, targets, return_logits, moe_aux)
+        moe_aux = jnp.zeros((), jnp.float32)
+        for _ in range(cfg.n_passes):       # the same weights in every pass
+            carry = (x, moe_aux)
+            if n_dense:
+                carry = run_stack(carry, xs_dense, n_dense)
+            x, moe_aux = run_stack(carry, xs, nl - n_dense)
+            if cfg.closes_passes:
+                x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg)
+            if cfg.exit_gate:
+                gates.append(exit_gate_logits(params, x))
+    out = _head_and_loss(params, x, cfg, targets, return_logits, moe_aux,
+                         normed=cfg.closes_passes)
+    return (*out, jnp.stack(gates)) if return_gates else out
 
 
 def _head_and_loss(params: Params, x, cfg: GPTConfig, targets,
-                   return_logits: bool, moe_aux):
-    """The final norm, the LM head and the loss of ``forward``: (logits or
-    None, loss or None)."""
+                   return_logits: bool, moe_aux, normed: bool = False):
+    """The final norm (but for ``normed`` hidden states, which a looped
+    stack's last pass hands over), the LM head and the loss of ``forward``:
+    (logits or None, loss or None)."""
     t, nl = x.shape[1], cfg.n_layer
-    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg)
+    if not normed:
+        x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg)
+    if cfg.residual_dtype:
+        x = x.astype(cfg.dtype)     # the head's matmul runs in the compute dtype
     if cfg.dim_model_base:
         x = (x / cfg.head_divisor).astype(x.dtype)
     w_head = params["wte"].T if cfg.tie_weights else params["head"]

@@ -204,6 +204,12 @@ PARAM_RULES: dict[str, P] = {
     "q_norm_scale": P("pp"),
     "k_norm_scale": P("pp"),
     "o_norm_scale": P("pp"),
+    # the norms after a sublayer (GPTConfig.post_norms) and the exit gate
+    # (GPTConfig.exit_gate): small and whole, as every norm is
+    "ln1_post_scale": P("pp"),
+    "ln2_post_scale": P("pp"),
+    "exit_gate_w": P(),
+    "exit_gate_b": P(),
 }
 
 

@@ -632,7 +632,8 @@ class DecodeEngine:
 
     @property
     def kv_bytes_per_row(self) -> int:
-        """Bytes one cached token costs in the pool, all layers and row
+        """Bytes one cached token costs in the pool, all planes (a layer's,
+        or a pass and layer's of a looped stack) and row
         leaves (a quantized pool's scale planes and a hybrid stack's pooled
         keys too, each over the positions a slot holds; its state, which
         costs a slot the same whatever it holds, is
@@ -662,6 +663,12 @@ class DecodeEngine:
         """``generate.SPARSE_ROWS`` as it stands, fetched likewise: (2,)
         [rows attended, rows at or before the query], or None."""
         return self._counter(gen.SPARSE_ROWS)
+
+    def loop_passes(self) -> Optional[np.ndarray]:
+        """``generate.LOOP_PASSES`` as it stands, fetched likewise: (2 +
+        n_passes,) [token-passes run, tokens, each pass's exit mass], or
+        None where the layers run once and no gate is read."""
+        return self._counter(gen.LOOP_PASSES)
 
     @property
     def chunk_size(self) -> int:
@@ -867,9 +874,13 @@ class DecodeEngine:
         # the warm-up's prompts (one token repeated) are no traffic: the
         # device's counters count from here on
         for name, fresh in ((gen.MOE_ROWS, gen.init_moe_rows),
-                            (gen.SPARSE_ROWS, gen.init_sparse_rows)):
-            if name in self.pool.cache:
-                self.pool.cache[name] = fresh(self.cfg)
+                            (gen.SPARSE_ROWS, gen.init_sparse_rows),
+                            (gen.LOOP_PASSES, gen.init_loop_passes)):
+            old = self.pool.cache.get(name)
+            if old is not None:
+                zeroed = fresh(self.cfg)
+                self.pool.cache[name] = jax.device_put(
+                    zeroed, old.sharding) if old.committed else zeroed
 
     def decode_step(
         self,

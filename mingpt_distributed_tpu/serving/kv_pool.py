@@ -1,8 +1,9 @@
 """Slot-based KV-cache pool + shared-prefix KV store.
 
-One fixed pair of ``(n_layer, n_slots, block_size, heads, size)`` buffers —
+One fixed pair of ``(planes, n_slots, block_size, heads, size)`` buffers —
 ``models/generate.init_cache`` with the batch axis reinterpreted as a
-*slot* axis. ``heads`` and ``size`` are each leaf's own
+*slot* axis; a plane a layer, or a pass and layer of a looped stack
+(``GPTConfig.cache_planes``). ``heads`` and ``size`` are each leaf's own
 (``generate.cache_leaf_shapes``): per-head rows keep ``kv_heads`` keys and
 values of ``head_dim``; a latent (MLA) model keeps one rotated rope key
 (``"k"``) and one normed latent (``"v"``) a token, which differ in size.
@@ -16,8 +17,8 @@ stale row, so a slot's state is started from zero by the program that
 prefills a sequence's first chunk (``generate._cached_hybrid_block``):
 allocate and free stay host-side and clear nothing. A model that counts on
 the device (``generate.COUNTERS``: routed rows, the sparse layers' attended
-rows) carries the counter in the same donated tree; it is no buffer of rows
-and the row programs pass it through.
+rows, a looped stack's passes) carries the counter in the same donated tree;
+it is no buffer of rows and the row programs pass it through.
 Each slot holds one in-flight request's
 cache; a request is admitted by prefilling its prompt into a free slot
 and retired by returning the slot to the free list. Stale K/V from a
@@ -62,10 +63,12 @@ import math
 from collections import OrderedDict
 from typing import List, Optional, Tuple
 
+import jax
+
 from mingpt_distributed_tpu.config import GPTConfig
 from mingpt_distributed_tpu.models.generate import (
-    COUNTERS, MOE_ROWS, SPARSE_ROWS, STATE, Cache, init_cache, init_moe_rows,
-    init_sparse_rows)
+    COUNTERS, LOOP_PASSES, MOE_ROWS, SPARSE_ROWS, STATE, Cache, init_cache,
+    init_loop_passes, init_moe_rows, init_sparse_rows)
 from mingpt_distributed_tpu.serving import quant as quant_lib
 
 
@@ -92,8 +95,6 @@ class SlotKVPool:
             # head-sharding spec below applies to them unchanged
             cache = quant_lib.init_quant_cache(cfg, n_slots, quant)
         if sharding is not None:
-            import jax
-
             cache = jax.device_put(
                 cache, {name: sharding for name in cache})
             # adopt the runtime's normalized sharding (trailing-None
@@ -103,8 +104,15 @@ class SlotKVPool:
             # the first serving call on a warmed bucket look novel
             sharding = cache["k"].sharding
         for name, counter in ((MOE_ROWS, init_moe_rows(cfg)),
-                              (SPARSE_ROWS, init_sparse_rows(cfg))):
+                              (SPARSE_ROWS, init_sparse_rows(cfg)),
+                              (LOOP_PASSES, init_loop_passes(cfg))):
             if counter is not None:
+                if sharding is not None:
+                    # whole on every device of the mesh, and committed as
+                    # the programs hand it back: an uncommitted leaf would
+                    # be another jit entry on the first call after it
+                    counter = jax.device_put(counter, jax.sharding.NamedSharding(
+                        sharding.mesh, jax.sharding.PartitionSpec()))
                 cache[name] = counter
         self.sharding = sharding
         self.cache = cache

@@ -215,6 +215,7 @@ class ServingMetrics:
         # the device-side counters, read only by summary()
         self._moe_rows_source: Optional[Callable[[], Any]] = None
         self._sparse_rows_source: Optional[Callable[[], Any]] = None
+        self._loop_passes_source: Optional[Callable[[], Any]] = None
         self._util_sum = 0.0
         self._prefill_rate = RateWindow()
         self._prefill_tokens_per_sec: Optional[float] = None
@@ -515,13 +516,16 @@ class ServingMetrics:
                      moe_rows_source: Optional[Callable[[], Any]] = None,
                      state_bytes_per_slot: int = 0,
                      sparse_rows_source: Optional[Callable[[], Any]] = None,
+                     loop_passes_source: Optional[Callable[[], Any]] = None,
                      ) -> None:
         """What the engine's programs read and what a cached token and a
         slot's state cost, known once it is built. ``moe_rows_source``
         fetches a routed model's (expert layers, E + 3) counter of routed
         rows from the device (``DecodeEngine.moe_rows``) and
         ``sparse_rows_source`` a hybrid stack's (2,) counter of the rows its
-        sparse layers' decode steps attended (``DecodeEngine.sparse_rows``);
+        sparse layers' decode steps attended (``DecodeEngine.sparse_rows``),
+        ``loop_passes_source`` a looped stack's (2 + n_passes,) counter of
+        its passes (``DecodeEngine.loop_passes``);
         only ``summary()`` calls them."""
         self._program_weight_bytes.set(program_weight_bytes)
         self._program_weights_cast.set(program_weights_cast)
@@ -529,6 +533,24 @@ class ServingMetrics:
         self._state_bytes_per_slot.set(state_bytes_per_slot)
         self._moe_rows_source = moe_rows_source
         self._sparse_rows_source = sparse_rows_source
+        self._loop_passes_source = loop_passes_source
+
+    def _loop_summary(self) -> Dict[str, Any]:
+        """A looped stack's passes since the server was built
+        (``generate.LOOP_PASSES``), over the tokens of requests in every
+        prefill and decode program: token-passes run, tokens counted (their
+        quotient is the pass count at the one exit threshold that is built)
+        and, a pass, the mean exit mass ``p_t`` a token (a list, which sums
+        to 1 where the stack has a gate). None where the layers run once
+        and no gate is read."""
+        got = self._loop_passes_source() if self._loop_passes_source else None
+        if got is None:
+            return dict.fromkeys((
+                "loop_token_passes", "loop_tokens", "loop_exit_mass"))
+        return {"loop_token_passes": float(got[0]),
+                "loop_tokens": float(got[1]),
+                "loop_exit_mass": [float(m) / max(float(got[1]), 1.0)
+                                   for m in got[2:]]}
 
     def _sparse_summary(self) -> Dict[str, Any]:
         """The sparse layers' decode steps since the server was built: the
@@ -576,6 +598,7 @@ class ServingMetrics:
             "state_bytes_per_slot": int(self._state_bytes_per_slot.value),
             **self._moe_summary(),
             **self._sparse_summary(),
+            **self._loop_summary(),
             "requests_submitted": self.requests_submitted,
             "requests_completed": self.requests_completed,
             "requests_rejected": self.requests_rejected,

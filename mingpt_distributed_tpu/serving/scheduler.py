@@ -311,6 +311,12 @@ class InferenceServer:
                     "speculation rolls rejected tokens back by position, and "
                     "a hybrid stack's state has none: neither the target "
                     "nor the draft may set mixer_types")
+            if cfg.n_passes > 1 or draft_cfg.n_passes > 1:
+                raise ConfigError(
+                    "speculation (spec_k) is not built for a looped stack "
+                    "(n_passes > 1): the verify program's rows of passes x "
+                    "layers planes and their roll-back are untested, for "
+                    "the target and for the draft")
             if spec_k < 1:
                 raise ValueError(
                     "draft model given but spec_k < 1: pass spec_k >= 1 "
@@ -332,7 +338,8 @@ class InferenceServer:
         self.metrics.engine_built(
             self.engine.program_param_bytes, self.engine.n_cast_leaves,
             self.engine.kv_bytes_per_row, self.engine.moe_rows,
-            self.engine.state_bytes_per_slot, self.engine.sparse_rows)
+            self.engine.state_bytes_per_slot, self.engine.sparse_rows,
+            self.engine.loop_passes)
         # post-warmup recompile watchdog over the compiled program families
         # (the merged server-level counts, so draft/verify traces are
         # watched too; armed after warmup(); checked every round)

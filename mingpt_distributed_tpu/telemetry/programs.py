@@ -37,7 +37,7 @@ __all__ = ["SCOPES", "abstract", "compile_programs", "program_records",
 SCOPES = (
     "attn", "mlp", "ce", "optimizer", "sample", "kv_layout", "cached_attn",
     "latent_attn", "moe_experts", "moe_shared", "lightning_scan",
-    "lightning_step", "sparse_select", "sparse_attend",
+    "lightning_step", "sparse_select", "sparse_attend", "exit_gate",
 )
 
 Program = Tuple[str, str, Any, tuple, dict]

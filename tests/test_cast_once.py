@@ -67,6 +67,11 @@ ARCHS = {
                    sparse_kernel_size=4, sparse_kernel_stride=2,
                    sparse_block_size=8, sparse_topk=2, sparse_window=8,
                    sparse_dense_len=16),
+    # benchmarks/configs/ouro-2.6b.json, cut further than its "tiny": the
+    # layers run three times, a norm after each sublayer, an exit gate
+    "looped": dict(rope=True, swiglu=True, rmsnorm=True, tie_weights=False,
+                   ffn_dim=48, n_passes=3, post_norms=True, exit_gate=True,
+                   residual_dtype="float32"),
 }
 
 
@@ -104,7 +109,9 @@ def serve(engine, tree):
     under top-k, lane 2 under top-p. Returns (every token, the pool)."""
     cache = jax.tree.map(jnp.zeros_like, engine.pool.cache)
     if engine.kv_sharding is not None:
-        cache = jax.device_put(cache, engine.kv_sharding)
+        # the rows' leaves; a counter stays whole on every device
+        cache = {n: jax.device_put(a, engine.kv_sharding) if a.ndim == 5
+                 else a for n, a in cache.items()}
     temps = np.array([1.0, 0.8, 1.3], np.float32)
     top_ks = np.array([0, 5, 0], np.int32)
     top_ps = np.array([1.0, 1.0, 0.9], np.float32)
@@ -189,6 +196,9 @@ def test_programs_read_a_tree_cast_once(arch, dtype, tp):
             bits(read[path]), bits(held[path].astype(jnp.bfloat16)))
     kept = {leaf_name(p) for p in read if p not in cast}
     assert {"wte", "lnf_scale", "ln1_scale", "ln2_scale"} <= kept
+    if cfg.post_norms:
+        assert {"ln1_post_scale", "ln2_post_scale", "exit_gate_w",
+                "exit_gate_b"} <= kept
     assert kept.isdisjoint(gen._CAST_ONLY_BLOCK_LEAVES | {"head"})
     assert ("['head']" in cast) == (not cfg.tie_weights)
 
@@ -200,6 +210,10 @@ def test_programs_read_a_tree_cast_once(arch, dtype, tp):
     for name in pool_handed:
         assert pool_read[name].any()
         np.testing.assert_array_equal(pool_handed[name], pool_read[name])
+    if cfg.n_passes > 1:
+        assert pool_read["k"].shape[0] == cfg.n_passes * cfg.n_layer
+        # every plane was written: a pass that shared another's would not
+        assert all(plane.any() for plane in pool_read["k"])
 
 
 def weight_converts(text, params):
