@@ -11,7 +11,9 @@ under jit would recompile per step (SURVEY §3.3 flags this as the idiom not
 to translate). Here decoding is two compiled programs:
 
   1. **prefill** — one batched forward over the prompt that also writes every
-     layer's K/V into a preallocated ``(L, B, block_size, KV, hd)`` cache;
+     layer's K/V into a preallocated ``(L, B, block_size, heads, size)``
+     cache (``cache_leaf_shapes``: a position's heads side by side where
+     they are narrower than a lane tile and together fill whole ones);
   2. **decode** — a single ``lax.scan`` over ``max_new_tokens`` steps, each
      step one-token attention against the cache (static shapes throughout,
      cache updated in place via dynamic_update_slice).
@@ -79,15 +81,35 @@ LOOP_PASSES = "loop_passes"
 #: leaves of a cache tree that count and hold nothing of a request
 COUNTERS = (MOE_ROWS, SPARSE_ROWS, LOOP_PASSES)
 
+#: lanes of the device's tile: the minor axis of a buffer is laid out in
+#: pieces of this many elements
+LANE_TILE = 128
+
 
 def cache_leaf_shapes(cfg: GPTConfig, batch: int) -> Dict[str, Tuple[int, ...]]:
     """The shape of each leaf of a ``batch``-lane cache: the one
     description ``init_cache``, the serving pool, its shardings and its
     audits read. Per-head rows: keys and values alike, ``kv_heads`` of
-    ``head_dim``. Latent attention caches two different things a token,
-    each shared by all heads: ``"k"`` the rotated rope key and ``"v"`` the
-    normed latent (the values the absorbed attention averages, and the
-    first part of every key, so stored once). A looped stack has a plane a
+    ``head_dim``, kept one of two ways by the widths alone (no flag, no
+    model's name). As a rule a head has an axis entry of its own,
+    ``(planes, B, S, kv_heads, head_dim)``. Where a head fills no lane
+    tile of its own (``head_dim`` under LANE_TILE) the device keeps such a
+    buffer with positions minor, a lane's row scattered over ``kv_heads x
+    head_dim / 16`` tiles a plane (PERF.md, PR 39: 4.65 of an 8.43 ms
+    decode step at GPT-2 124M was the rows' writes); if a position's heads
+    together are whole tiles (``kv_heads x head_dim`` a multiple of
+    LANE_TILE) they lie side by side instead, ``(planes, B, S, 1, kv_heads
+    x head_dim)``, the numbers and their order unchanged, and a row is
+    ``width / 128`` tiles a plane. A width that is no whole number of
+    tiles (GPT-2 XL's 25 x 64 = 1,600) stays per-head: the device keeps
+    that leaf positions minor too rather than pad a row, so nothing is won
+    and the attention pays (PERF.md, PR 39). ``row_heads`` says how many
+    heads a row holds; the decode step reads such rows whole
+    (``attn_ops.causal_attend_step``), a chunk views its lane's as heads
+    (``attn_ops.as_heads``). Latent attention caches two different things
+    a token, each shared by all heads: ``"k"`` the rotated rope key and
+    ``"v"`` the normed latent (the values the absorbed attention averages,
+    and the first part of every key, so stored once). A looped stack has a plane a
     pass and layer (``cfg.cache_planes``): pass t of layer l keeps its own
     keys and values at plane ``t * n_layer + l``."""
     if cfg.mixer_types is not None:
@@ -109,7 +131,34 @@ def cache_leaf_shapes(cfg: GPTConfig, batch: int) -> Dict[str, Tuple[int, ...]]:
     if cfg.kv_lora_rank:
         return {"k": rows + (1, cfg.qk_rope_head_dim),
                 "v": rows + (1, cfg.kv_lora_rank)}
-    return {n: rows + (cfg.kv_heads, cfg.head_dim) for n in ("k", "v")}
+    width = cfg.kv_heads * cfg.head_dim
+    side_by_side = cfg.head_dim < LANE_TILE and width % LANE_TILE == 0
+    row = (1, width) if side_by_side else (cfg.kv_heads, cfg.head_dim)
+    return {n: rows + row for n in ("k", "v")}
+
+
+def row_heads(cfg: GPTConfig) -> int:
+    """KV heads that lie side by side in one row of the ``"k"``, ``"v"``
+    leaves (``cache_leaf_shapes``): ``kv_heads`` where a row is a
+    position's heads, 1 where a head has an axis entry of its own or the
+    row is no head's (a latent). What splits a row into heads for whoever
+    needs them apart: a quantized pool's scales, a mesh's shards."""
+    if cfg.kv_lora_rank:
+        return 1
+    return cache_leaf_shapes(cfg, 1)["k"][-1] // cfg.head_dim
+
+
+def row_tiles(cfg: GPTConfig) -> int:
+    """Lane tiles one position's row of the ``"k"`` leaf touches over all
+    planes, which is what a lane's write of a decode step touches: a row
+    whose last axis holds it whole is ``ceil(width / LANE_TILE)`` tiles a
+    plane; a per-head leaf of narrow heads is kept with positions minor, a
+    head's numbers 16 to a tile's sublanes, so ``kv_heads x head_dim / 16``
+    a plane (the arithmetic of PERF.md, PR 39, for any per-head leaf)."""
+    planes, _, _, heads, width = cache_leaf_shapes(cfg, 1)["k"]
+    if heads == 1:
+        return planes * -(-width // LANE_TILE)
+    return planes * heads * width // 16
 
 
 def init_cache(cfg: GPTConfig, batch: int, dtype=None) -> Cache:
@@ -144,7 +193,7 @@ def init_moe_rows(cfg: GPTConfig) -> Optional[jax.Array]:
 
 
 def _lay_rows_over(old: jax.Array, rows: jax.Array, positions) -> jax.Array:
-    """A layer's cached ``(B, S, KV, hd)`` slice as it will read once each
+    """A layer's cached ``(B, S, heads, size)`` slice as it will read once each
     lane's new row ``rows[b, 0]`` lies at ``positions[b]``: a select of
     the slice's size. For a slice a step reads whole and small (a hybrid
     stack's pooled keys); over a layer's rows it bound the attention's
@@ -157,11 +206,14 @@ def _lay_rows_over(old: jax.Array, rows: jax.Array, positions) -> jax.Array:
 
 @jax.named_scope("kv_layout")
 def _write_lane_rows(cache: Cache, rows, positions) -> Cache:
-    """Every plane's new row of lane ``b`` into the full ``(L, B, S, KV,
-    hd)`` buffers at ``(:, b, positions[b])``: one ``dynamic_update_slice``
-    of ``(L, 1, 1, KV, hd)`` a lane and buffer, in place, whatever layout
-    the device keeps the buffers in. ``rows`` is the planes' list of
-    ``{"k", "v"}`` rows, ``(B, 1, KV, hd)`` each (a hybrid stack's sparse
+    """Every plane's new row of lane ``b`` into the full ``(L, B, S, heads,
+    size)`` buffers at ``(:, b, positions[b])``: one
+    ``dynamic_update_slice`` of ``(L, 1, 1, heads, size)`` a lane and
+    buffer, in place, whatever layout the device keeps the buffers in
+    (``heads`` and ``size`` are the leaf's own, ``cache_leaf_shapes``: a
+    row of heads side by side is ``L x ceil(size / 128)`` tiles, a per-head
+    one ``L x heads x size / 16``). ``rows`` is the planes' list of
+    ``{"k", "v"}`` rows, ``(B, 1, heads, size)`` each (a hybrid stack's sparse
     layers bring POOLED rows too, which lie on a coarser grid:
     ``positions`` is then a dict of (B,) indices by leaf).
 
@@ -174,7 +226,7 @@ def _write_lane_rows(cache: Cache, rows, positions) -> Cache:
     out = dict(cache)
     for name in rows[0]:
         buf = cache[name]
-        new = jnp.stack([r[name] for r in rows])  # (L, B, 1, KV, hd)
+        new = jnp.stack([r[name] for r in rows])  # (L, B, 1, heads, size)
         at = positions[name] if isinstance(positions, dict) else positions
         for lane in range(new.shape[1]):
             buf = jax.lax.dynamic_update_slice(
@@ -196,7 +248,12 @@ def _cached_block(
 ) -> Tuple[jax.Array, Cache, Cache, Optional[jax.Array]]:
     """One pre-LN block against the cache. Returns (y, cache, rows,
     counts): the block's own (B, T, heads, size) k/v ``rows`` in the
-    cache's dtype, and a dropless expert layer's counts of the ``valid``
+    cache's dtype and row shape (``cache_leaf_shapes``: a position's
+    ``(kv_heads, head_dim)`` as computed, or the same numbers side by
+    side, ``(1, kv_heads x head_dim)``; the queries stay ``(B, T, H,
+    hd)``, a chunk's attention views its lane's rows as heads and the
+    decode step reads them whole), and a dropless expert layer's counts
+    of the ``valid``
     tokens' routed rows (ops/moe.grouped_swiglu, which routes no other
     token; None for any other MLP).
 
@@ -265,7 +322,10 @@ def _cached_block(
             q = attn_ops.apply_rope(q, *rope, cfg.rope_interleave)
             k = attn_ops.apply_rope(k, *rope, cfg.rope_interleave)
 
-    rows = {"k": k.astype(cache["k"].dtype), "v": v.astype(cache["v"].dtype)}
+    # in the cache's row shape: a latent's parts have it, a per-head row
+    # takes it here (heads side by side where the leaf keeps them so)
+    rows = {n: a.astype(cache[n].dtype).reshape(b, t, *cache[n].shape[3:])
+            for n, a in (("k", k), ("v", v))}
     if not per_lane:
         cache = {**cache, **{n: jax.lax.dynamic_update_slice(
             cache[n], rows[n][None], (plane, 0, offset, 0, 0))
@@ -293,8 +353,8 @@ def _cached_block(
         ).reshape(b, t, nh * hd)
     else:
         att = attn_ops.causal_attention(
-            q, big_k, big_v, kv_offset=offset,
-            window=cfg.attention_window,
+            q, attn_ops.as_heads(big_k, hd), attn_ops.as_heads(big_v, hd),
+            kv_offset=offset, window=cfg.attention_window,
             logit_softcap=cfg.attn_logit_softcap,
         ).reshape(b, t, nh * hd)
     att = L.dense(att, blk["wo"], blk.get("bo"))

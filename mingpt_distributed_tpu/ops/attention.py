@@ -19,6 +19,7 @@ are tested for parity against it.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -44,6 +45,36 @@ def repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
     return jnp.broadcast_to(k[:, :, :, None, :], (b, s, kv, n_rep, hd)).reshape(
         b, s, kv * n_rep, hd
     )
+
+
+def as_heads(rows: jax.Array, head_dim: int) -> jax.Array:
+    """Rows ``(..., heads, size)`` as the attention reads them, ``(..., KV,
+    head_dim)``: what came in where a head has an axis entry of its own,
+    and the same numbers where a cache keeps a position's heads side by
+    side, ``(..., 1, KV x head_dim)`` (``generate.cache_leaf_shapes``). A
+    view taken where the rows are read, on rows already cut out of the
+    cache: never of a buffer."""
+    if rows.shape[-1] == head_dim:
+        return rows
+    return rows.reshape(*rows.shape[:-2], -1, head_dim)
+
+
+def spread_queries(q: jax.Array, kv: int) -> jax.Array:
+    """(B, T, H, hd) -> (B, T, H, KV * hd): each query head's values at its
+    KV head's place in a row, zeros at the others'."""
+    b, t, h, hd = q.shape
+    own = jnp.eye(kv, dtype=q.dtype)                        # (KV, KV)
+    wide = q.reshape(b, t, kv, h // kv, 1, hd) * own[:, None, :, None]
+    return wide.reshape(b, t, h, kv * hd)
+
+
+def own_part(out: jax.Array, kv: int) -> jax.Array:
+    """(B, T, H, KV * hd) -> (B, T, H, hd): of what a query head averaged
+    over whole rows, its own KV head's part."""
+    b, t, h, e = out.shape
+    parts = out.reshape(b, t, kv, h // kv, kv, e // kv)
+    return jnp.stack([parts[:, :, k, :, k] for k in range(kv)],
+                     2).reshape(b, t, h, e // kv)
 
 
 def causal_attention(
@@ -226,11 +257,11 @@ def _layer_rows(buf: jax.Array, layer: int, start, size: int) -> jax.Array:
 @jax.named_scope("cached_attn")
 def causal_attend_step(
     q: jax.Array,         # (B, 1, H, hd): one query a lane
-    k_cache: jax.Array,   # (L, B, S, KV, hd): whole, the new rows not yet
-    v_cache: jax.Array,   #   written
+    k_cache: jax.Array,   # (L, B, S, KV, hd) or (L, B, S, 1, KV x hd): whole,
+    v_cache: jax.Array,   #   as it lies, the new rows not yet written
     layer: int,
-    k_new: jax.Array,     # (B, 1, KV, hd): each lane's own new row
-    v_new: jax.Array,
+    k_new: jax.Array,     # (B, 1, ...): each lane's own new row, shaped as
+    v_new: jax.Array,     #   the cache's rows
     positions: jax.Array,  # (B,): where each lane's query stands
     *,
     frontier=None,
@@ -246,38 +277,68 @@ def causal_attend_step(
     inside the window). The slice is read as far as
     ``step_rows_read(S, frontier)`` rows (``frontier``: the furthest
     position of a lane whose output counts; None, the furthest of all).
+
+    A cache that keeps each head an axis entry of its own is scored a KV
+    head at a time, the grouped queries beside their head. A cache that
+    keeps a position's heads side by side (``generate.cache_leaf_shapes``:
+    heads narrower than a lane tile, whole tiles together) is read whole
+    rows at a time, as a
+    hybrid stack's sparse rows are (``ops/sparse_attention.py``): the
+    queries go to the rows' width (:func:`spread_queries`), one matmul
+    scores every head against the rows where they lie, and each head keeps
+    its own part of what it averaged (:func:`own_part`): the per-head sums
+    with as many zero products again as there are other heads. Viewing the
+    rows as ``(KV, hd)`` instead made the chip's compiler lay every slice
+    out again, positions minor, before it scored it (compile rehearsal,
+    PR 39: a copy and a float32 convert of a layer's slice, each leaf).
     Returns (B, 1, H, hd) in q's dtype."""
     b, _, h, hd = q.shape
-    s, kv = k_cache.shape[2], k_cache.shape[3]
+    s, kv = k_cache.shape[2], math.prod(k_cache.shape[3:]) // hd
+    side_by_side = k_cache.shape[3] != kv
     if frontier is None:
         frontier = jnp.max(positions)
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, dtype=jnp.float32))
-    # grouped queries beside their KV head: the cache is never repeated
-    qg = q.reshape(b, kv, h // kv, hd)
+    if side_by_side:
+        # the queries to the rows' width: (B, H, KV x hd)
+        qg = spread_queries(q, kv)[:, 0]
+        by_q, by_rows, by_new, scored = "bhw", "bsw", "bw", "bh"
+    else:
+        # grouped queries beside their KV head: the cache is never repeated
+        qg = q.reshape(b, kv, h // kv, hd)
+        by_q, by_rows, by_new, scored = "bkgd", "bskd", "bkd", "bkg"
 
     def take(start, size):
-        return (_layer_rows(k_cache, layer, start, size),
+        rows = (_layer_rows(k_cache, layer, start, size),
                 _layer_rows(v_cache, layer, start, size))
+        return tuple(r[:, :, 0] for r in rows) if side_by_side else rows
 
     def score(rows, k_pos):
         z = softcap(jnp.einsum(
-            "bkgd,bskd->bkgs", qg, rows[0],
+            f"{by_q},{by_rows}->{scored}s", qg, rows[0],
             preferred_element_type=jnp.float32) * scale, logit_softcap)
         behind = positions[:, None] - k_pos[None, :]      # (B, S')
         allowed = behind > 0
         if window is not None:
             allowed = allowed & (behind < window)
-        return jnp.where(allowed[:, None, None, :], z, NEG_INF)
+        allowed = allowed[:, None, :] if side_by_side \
+            else allowed[:, None, None, :]
+        return jnp.where(allowed, z, NEG_INF)
 
     def weigh(p, rows):
-        return jnp.einsum("bkgs,bskd->bkgd", p.astype(rows[1].dtype), rows[1],
+        return jnp.einsum(f"{scored}s,{by_rows}->{by_q}",
+                          p.astype(rows[1].dtype), rows[1],
                           preferred_element_type=jnp.float32)
 
     own = softcap(jnp.einsum(
-        "bkgd,bkd->bkg", qg, k_new[:, 0],
+        f"{by_q},{by_new}->{scored}", qg,
+        k_new[:, 0, 0] if side_by_side else k_new[:, 0],
         preferred_element_type=jnp.float32) * scale, logit_softcap)
-    out = _attend_step(take, score, weigh, own,
-                       v_new[:, 0, :, None].astype(jnp.float32), s, frontier)
+    # the own row's value beside the scores' axes: (B, 1, W), (B, KV, 1, hd)
+    v_own = v_new[:, 0] if side_by_side else v_new[:, 0, :, None]
+    out = _attend_step(take, score, weigh, own, v_own.astype(jnp.float32),
+                       s, frontier)
+    if side_by_side:
+        return own_part(out[:, None], kv).astype(q.dtype)
     return out.reshape(b, 1, h, hd).astype(q.dtype)
 
 

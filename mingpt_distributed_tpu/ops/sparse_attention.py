@@ -44,7 +44,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from mingpt_distributed_tpu.ops.attention import NEG_INF
+from mingpt_distributed_tpu.ops.attention import (  # noqa: F401
+    NEG_INF, own_part, spread_queries)
 
 #: queries scored against the pooled keys, and keys attended, at one time
 QUERY_CHUNK = 512
@@ -112,24 +113,6 @@ def pooled_key_at(k_pool: jax.Array, layer: int, new_row: jax.Array,
                         new_row, windows)
     return windows.astype(jnp.float32).mean(1, keepdims=True).astype(
         k_pool.dtype)
-
-
-def spread_queries(q: jax.Array, kv: int) -> jax.Array:
-    """(B, T, H, hd) -> (B, T, H, KV * hd): each query head's values at its
-    KV head's place in a row, zeros at the others'."""
-    b, t, h, hd = q.shape
-    own = jnp.eye(kv, dtype=q.dtype)                        # (KV, KV)
-    wide = q.reshape(b, t, kv, h // kv, 1, hd) * own[:, None, :, None]
-    return wide.reshape(b, t, h, kv * hd)
-
-
-def own_part(out: jax.Array, kv: int) -> jax.Array:
-    """(B, T, H, KV * hd) -> (B, T, H, hd): of what a query head averaged
-    over whole rows, its own KV head's part."""
-    b, t, h, e = out.shape
-    parts = out.reshape(b, t, kv, h // kv, kv, e // kv)
-    return jnp.stack([parts[:, :, k, :, k] for k in range(kv)],
-                     2).reshape(b, t, h, e // kv)
 
 
 @jax.named_scope("sparse_select")

@@ -5,7 +5,7 @@ The substrate is models/generate.py's compiled prefill/decode split: a
 static-shape, slot-addressable KV cache updated in place. This package adds
 what a server needs on top of it:
 
-* ``SlotKVPool`` (kv_pool.py) — a fixed (L, S_slots, block_size, KV, hd)
+* ``SlotKVPool`` (kv_pool.py) — a fixed (L, S_slots, block_size, heads, size)
   cache where each slot holds one in-flight request, with a deterministic
   host-side allocate/free free-list; ``PrefixKVStore`` is the byte-bounded
   LRU of shared-prefix KV entries behind prefix reuse.

@@ -128,10 +128,15 @@ def bind_static(fn, **static):
     return bound
 
 
-def kv_pool_spec(tp_axis: str = "tp"):
-    """PartitionSpec of the (L, S, block, KV, hd) pool cache — and of the
-    (L, 1, P, KV, hd) prefix entries it exchanges rows with: KV heads
-    shard over the tensor axis, every other dimension replicates. Heads
+def kv_pool_spec(cfg: GPTConfig, tp_axis: str = "tp"):
+    """PartitionSpec of a pool's ``"k"``, ``"v"`` leaves (``generate.
+    cache_leaf_shapes``) and of the prefix entries they exchange rows
+    with: the axis that holds the KV heads shards over the tensor axis,
+    every other dimension replicates. That is axis 3 of a per-head leaf
+    ``(L, S, block, KV, hd)``, and the last of a leaf that keeps a
+    position's heads side by side, ``(L, S, block, 1, KV x hd)``: equal
+    parts of such a row are whole heads where ``kv_heads % tp == 0``
+    (the engine holds the rule to the head count, not the width). Heads
     are the right axis because attention is independent per head, so a
     head-sharded cache is read and written only by the chip that owns it
     (no collective touches K/V); slots must stay whole per device (the
@@ -141,7 +146,10 @@ def kv_pool_spec(tp_axis: str = "tp"):
     cache keys compare shardings by equality — an unnormalized spec on
     the warmup cache would make the first serving call on a warmed
     bucket compile a second (identical) executable."""
-    return jax.sharding.PartitionSpec(None, None, None, tp_axis)
+    spec = (None, None, None, tp_axis)
+    if gen.row_heads(cfg) > 1:
+        spec = (None,) + spec
+    return jax.sharding.PartitionSpec(*spec)
 
 
 def _kv_leaves(cache):
@@ -164,7 +172,7 @@ def _pin_kv(cache, kv_sharding):
     """``with_sharding_constraint`` over a cache (or prefix entry)
     pytree — ``{"k","v"}``, plus the ``*_scale`` planes of a quantized
     pool, which carry the same head-sharding spec (their sharded axis is
-    kv_heads; the collapsed head_dim axis is unsharded either way).
+    kv_heads, where the data's is: serving/quant.py).
     ``kv_sharding`` reaches every program as a partial-bound constant —
     trace-time static, exactly like ``cfg`` — which is how the mesh
     participates in the compile key without adding executables. ``None``
@@ -296,8 +304,10 @@ def _select_next_slots(
 
 @jax.named_scope("kv_layout")
 def _slot_lane(cache, slot):
-    """The (L, 1, S, KV, hd) cache lane of one slot (scale planes, when
-    present, slice the same way with their collapsed trailing axis)."""
+    """The (L, 1, S, heads, size) cache lane of one slot, ``heads`` and
+    ``size`` each leaf's own (``generate.cache_leaf_shapes``: per-head
+    rows, heads side by side, a latent's parts; scale planes, when
+    present, slice the same way)."""
     out = {}
     for name in _kv_leaves(cache):
         l, _, s, kv, last = cache[name].shape
@@ -324,14 +334,16 @@ def _dequant_lane(lane, kv_quant, cfg):
     return quant_lib.dequantize_lane(lane, jnp.dtype(cfg.dtype))
 
 
-def _requant_lane(lane, kv_quant):
+def _requant_lane(lane, kv_quant, cfg):
     """The write-back half: requantize a forwarded lane before it
-    re-enters the pool. Power-of-two scales make this exactly idempotent
-    on rows the forward did not touch (serving/quant.py), which is what
-    keeps greedy decode deterministic and migrated rows bit-stable."""
+    re-enters the pool, a scale a row and head (``generate.row_heads``
+    of them side by side in a row). Power-of-two scales make this exactly
+    idempotent on rows the forward did not touch (serving/quant.py), which
+    is what keeps greedy decode deterministic and migrated rows
+    bit-stable."""
     if kv_quant is None:
         return lane
-    return quant_lib.quantize_lane(lane, kv_quant)
+    return quant_lib.quantize_lane(lane, kv_quant, gen.row_heads(cfg))
 
 
 def lane_keys(seeds: jax.Array, token_index: Optional[jax.Array] = None):
@@ -376,7 +388,7 @@ def _forward_slot_lane(params, cache, tokens, offset, slot, *, cfg,
     x, lane = gen._forward_cached_hidden(
         params, tokens[None], lane, offset, cfg, valid)
     cache = _with_counter(cache, lane)
-    lane = _requant_lane(lane, kv_quant)
+    lane = _requant_lane(lane, kv_quant, cfg)
     return x, _with_counter(_install_lane(cache, lane, slot), cache)
 
 
@@ -448,7 +460,7 @@ def _decode_impl(
         safe_pos, cfg, valid=None if live is None else live[:, None],
         frontier=decode_frontier(
             safe_pos, True if live is None else live))
-    cache = _with_counter(_requant_lane(stepped, kv_quant), stepped)
+    cache = _with_counter(_requant_lane(stepped, kv_quant, cfg), stepped)
     nxt = _select_next_slots(logits, lane_keys(seeds, token_index),
                              temps, top_ks, top_ps, do_sample)
     return nxt, _pin_kv(cache, kv_sharding)
@@ -469,7 +481,7 @@ def _extract_prefix_impl(cache, slot, *, rows: int, kv_sharding=None):
 
 
 def _install_prefix_impl(cache, entry, slot, *, kv_sharding=None):
-    """Write a stored (L, 1, P, KV, hd) prefix entry (a lane dict: K/V
+    """Write a stored (L, 1, P, heads, size) prefix entry (a lane dict: K/V
     payloads plus scale planes on a quantized pool) into rows [0, P) of a
     slot lane — a device-side dynamic_update_slice, no recompute. Entry
     and pool carry the same head-sharding, so a hit is a chip-local row
@@ -547,12 +559,17 @@ class DecodeEngine:
             # One placement decision, made once: params follow the megatron
             # column/row rules, the pool shards heads over the tp axis (or
             # downgrades to replication when kv_heads % tp != 0 — counted
-            # by shard_by_rule's telemetry, never an error).
+            # by shard_by_rule's telemetry, never an error). The rule is
+            # held to the heads an axis holds: of a row of heads side by
+            # side that is the row's head count, not its width.
             params = jax.device_put(
                 params, mesh_lib.param_shardings(mesh, params))
+            shape, heads = (gen.cache_leaf_shapes(cfg, n_slots)["k"],
+                            gen.row_heads(cfg))
+            if heads > 1:
+                shape = shape[:-1] + (heads,)
             self.kv_sharding = mesh_lib.shard_by_rule(
-                mesh, gen.cache_leaf_shapes(cfg, n_slots)["k"],
-                kv_pool_spec(tp_axis), name="kv_cache")
+                mesh, shape, kv_pool_spec(cfg, tp_axis), name="kv_cache")
         else:
             self.kv_sharding = None
         # what was handed in (placed, under a mesh), and what the programs
@@ -797,7 +814,7 @@ class DecodeEngine:
 
     def extract_slot_rows(self, slot: int, rows: int) -> Dict[str, jax.Array]:
         """Pull ``rows`` leading K/V rows out of ``slot`` as a pinned
-        (L, 1, rows, KV, hd) entry dict (payloads + scale planes on a
+        (L, 1, rows, heads, size) entry dict (payloads + scale planes on a
         quantized engine — a migrated quantized entry ships ~4x fewer
         bytes) — the extract half of live migration, through the SAME
         row-copy program family ``save_prefix`` uses. ``rows`` must sit
@@ -815,7 +832,7 @@ class DecodeEngine:
         return self._extract_jit(self.pool.cache, np.int32(slot), rows=rows)
 
     def install_slot_rows(self, slot: int, entry: Dict[str, jax.Array]) -> int:
-        """Copy an extracted (L, 1, rows, KV, hd) entry dict straight
+        """Copy an extracted (L, 1, rows, heads, size) entry dict straight
         into ``slot``'s leading cache rows — the install half of live
         migration for engines that have no prefix store (the draft
         engine): same compiled row-copy program ``try_load_prefix`` uses.

@@ -5,7 +5,14 @@ One fixed pair of ``(planes, n_slots, block_size, heads, size)`` buffers —
 *slot* axis; a plane a layer, or a pass and layer of a looped stack
 (``GPTConfig.cache_planes``). ``heads`` and ``size`` are each leaf's own
 (``generate.cache_leaf_shapes``): per-head rows keep ``kv_heads`` keys and
-values of ``head_dim``; a latent (MLA) model keeps one rotated rope key
+values of ``head_dim``, as a rule a head an axis entry of its own; where a
+head fills no lane tile (``head_dim`` under 128) and a position's heads
+together fill whole ones (GPT-2 124M's 12 x 64 = 768), they lie side by
+side, ``(1, kv_heads x head_dim)``: the same numbers in the same order,
+and a lane's row write touches ``width / 128`` tiles a plane where the
+per-head buffer, which the device keeps with positions minor, makes it
+``kv_heads x head_dim / 16`` (``row_width``, ``row_tiles``); a latent
+(MLA) model keeps one rotated rope key
 (``"k"``) and one normed latent (``"v"``) a token, which differ in size.
 Everything here and in the engine's programs works leaf by leaf and asks
 no leaf for another's shape. A hybrid stack (``GPTConfig.mixer_types``)
@@ -40,7 +47,9 @@ order always produces the same slot placement — the scheduler tests rely
 on replayability.
 
 Tensor-parallel serving (ISSUE 14): the pool optionally carries a
-``NamedSharding`` that splits the KV-heads axis over the mesh's tp axis,
+``NamedSharding`` that splits the axis holding the KV heads (the last, of
+a row of heads side by side: ``engine.kv_pool_spec``) over the mesh's tp
+axis,
 so each device holds ``total / tp`` cache bytes. The sharding is decided
 once at construction (it is part of the engine's program identity, see
 serving/engine.py) and never changes — the buffers keep the same global
@@ -50,8 +59,9 @@ request) stays a host concept; placement (which chip holds which heads)
 is the sharding's concern — the two never interact.
 
 ``PrefixKVStore`` is the byte-bounded LRU behind shared-prefix reuse
-(the system-prompt case): entries are device-resident ``(L, 1, P, KV,
-hd)`` K/V row blocks keyed by the exact token tuple they encode, with P
+(the system-prompt case): entries are device-resident ``(L, 1, P, heads,
+size)`` K/V row blocks (the pool's own row shape) keyed by the exact
+token tuple they encode, with P
 quantized to the engine's bucket ladder so the copy programs stay a
 bounded compile family. A request whose prompt extends a stored entry
 copies its rows instead of recomputing them and prefills only the tail.
@@ -68,7 +78,7 @@ import jax
 from mingpt_distributed_tpu.config import GPTConfig
 from mingpt_distributed_tpu.models.generate import (
     COUNTERS, LOOP_PASSES, MOE_ROWS, SPARSE_ROWS, STATE, Cache, init_cache,
-    init_loop_passes, init_moe_rows, init_sparse_rows)
+    init_loop_passes, init_moe_rows, init_sparse_rows, row_tiles)
 from mingpt_distributed_tpu.serving import quant as quant_lib
 
 
@@ -129,6 +139,20 @@ class SlotKVPool:
         shard = self.sharding.shard_shape(shape)
         return math.prod(shape) // math.prod(shard)
 
+    @property
+    def row_width(self) -> int:
+        """The last axis of the ``"k"`` leaf: a head's size where each head
+        has an axis entry of its own, all of a position's heads where they
+        lie side by side (``generate.cache_leaf_shapes``); 0 where no leaf
+        holds rows."""
+        return self.cache["k"].shape[-1] if "k" in self.cache else 0
+
+    @property
+    def row_tiles(self) -> int:
+        """Lane tiles one lane's row write touches in the ``"k"`` leaf, all
+        planes (``generate.row_tiles``)."""
+        return row_tiles(self.cfg) if "k" in self.cache else 0
+
     def audit_facts(self) -> dict:
         """Static facts graftaudit checks pool-touching programs against
         (plain dict so serving never imports the analysis layer):
@@ -150,6 +174,8 @@ class SlotKVPool:
             "cache_leaf_elems": min(map(math.prod, shapes.values())),
             "cache_sharding": self.sharding,
             "shard_count": self.shard_count,
+            "row_width": self.row_width,
+            "row_tiles": self.row_tiles,
         }
 
     @property

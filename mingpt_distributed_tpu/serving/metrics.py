@@ -212,6 +212,14 @@ class ServingMetrics:
         self._state_bytes_per_slot = r.gauge(
             "mingpt_serve_state_bytes_per_slot",
             help="bytes of recurrent state a slot holds beside its rows")
+        self._kv_row_width = r.gauge(
+            "mingpt_serve_kv_row_width",
+            help="last axis of the pool's k leaf: one head's size, or a "
+                 "position's heads side by side")
+        self._kv_row_tiles = r.gauge(
+            "mingpt_serve_kv_row_tiles",
+            help="lane tiles a lane's row write touches in the k leaf, "
+                 "all planes")
         # the device-side counters, read only by summary()
         self._moe_rows_source: Optional[Callable[[], Any]] = None
         self._sparse_rows_source: Optional[Callable[[], Any]] = None
@@ -517,9 +525,12 @@ class ServingMetrics:
                      state_bytes_per_slot: int = 0,
                      sparse_rows_source: Optional[Callable[[], Any]] = None,
                      loop_passes_source: Optional[Callable[[], Any]] = None,
+                     kv_row_width: int = 0, kv_row_tiles: int = 0,
                      ) -> None:
         """What the engine's programs read and what a cached token and a
-        slot's state cost, known once it is built. ``moe_rows_source``
+        slot's state cost, known once it is built; ``kv_row_width`` and
+        ``kv_row_tiles`` say which way the pool keeps a row
+        (``SlotKVPool.row_width``, ``row_tiles``). ``moe_rows_source``
         fetches a routed model's (expert layers, E + 3) counter of routed
         rows from the device (``DecodeEngine.moe_rows``) and
         ``sparse_rows_source`` a hybrid stack's (2,) counter of the rows its
@@ -531,6 +542,8 @@ class ServingMetrics:
         self._program_weights_cast.set(program_weights_cast)
         self._kv_bytes_per_row.set(kv_bytes_per_row)
         self._state_bytes_per_slot.set(state_bytes_per_slot)
+        self._kv_row_width.set(kv_row_width)
+        self._kv_row_tiles.set(kv_row_tiles)
         self._moe_rows_source = moe_rows_source
         self._sparse_rows_source = sparse_rows_source
         self._loop_passes_source = loop_passes_source
@@ -596,6 +609,8 @@ class ServingMetrics:
             "program_weights_cast": int(self._program_weights_cast.value),
             "kv_bytes_per_row": int(self._kv_bytes_per_row.value),
             "state_bytes_per_slot": int(self._state_bytes_per_slot.value),
+            "kv_row_width": int(self._kv_row_width.value),
+            "kv_row_tiles": int(self._kv_row_tiles.value),
             **self._moe_summary(),
             **self._sparse_summary(),
             **self._loop_summary(),

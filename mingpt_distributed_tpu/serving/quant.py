@@ -26,10 +26,14 @@ docs/architecture.md "Quantized KV cache").
 
 Layout: a quantized cache/lane/entry is the plain ``{"k", "v"}`` dict
 grown to ``{"k", "v", "k_scale", "v_scale"}``. Scale planes are
-``float32`` with the data's shape except ``head_dim -> 1``
-(one scale per (layer, slot, row, kv_head)), so every rank-5 slicing
-program and the ``kv_pool_spec`` head-sharding apply to them unchanged
-— under tp the scale planes shard over kv_heads exactly like the data.
+``float32``, one scale per (layer, slot, row, kv_head) whichever way
+the pool keeps a row (``generate.cache_leaf_shapes``): beside per-head
+data ``(..., KV, hd)`` the data's shape except ``head_dim -> 1``; beside
+a row of heads side by side, ``(..., 1, KV x hd)``, a scale a head of the
+row's ``(KV, hd)`` view, ``(..., 1, KV)``. Either way every rank-5
+slicing program and the ``kv_pool_spec`` head-sharding apply to them
+unchanged — under tp the scale planes shard over kv_heads exactly like
+the data, on the same axis.
 
 The same primitive quantizes weight leaves per output channel
 (``quantize_weight``); the serving weights path itself is the next rung
@@ -110,28 +114,40 @@ def _pow2_scale(amax: jax.Array, qmax: float) -> jax.Array:
     return jnp.where(amax > 0, jnp.exp2(exp), jnp.float32(0.0))
 
 
-def quantize(x: jax.Array, q: KVQuant) -> Tuple[jax.Array, jax.Array]:
-    """Symmetric per-channel quantize over the last axis.
+def _in_parts(x: jax.Array, parts: int) -> jax.Array:
+    """``(..., W)`` as ``(..., parts, W / parts)``: a row's heads apart."""
+    return x.reshape(*x.shape[:-1], parts, x.shape[-1] // parts)
+
+
+def quantize(x: jax.Array, q: KVQuant,
+             heads: int = 1) -> Tuple[jax.Array, jax.Array]:
+    """Symmetric per-channel quantize over the last axis, or over each of
+    ``heads`` equal parts of it (a row that holds that many heads side by
+    side: a scale a head, as if each had an axis entry of its own).
 
     Returns ``(payload, scale)`` with ``payload.shape == x.shape`` in
-    ``q.qdtype`` and ``scale.shape == x.shape[:-1] + (1,)`` in fp32.
+    ``q.qdtype`` and ``scale.shape == x.shape[:-1] + (heads,)`` in fp32.
     """
-    amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
+    parts = _in_parts(x, heads)
+    amax = jnp.max(jnp.abs(parts), axis=-1, keepdims=True)
     scale = _pow2_scale(amax, q.qmax)
     safe = jnp.where(scale > 0, scale, jnp.float32(1.0))
-    y = x.astype(SCALE_DTYPE) / safe
+    y = parts.astype(SCALE_DTYPE) / safe
     if q.qdtype == jnp.int8:
         payload = jnp.round(jnp.clip(y, -q.qmax, q.qmax)).astype(jnp.int8)
     else:
         payload = y.astype(q.qdtype)
-    return payload, scale
+    return payload.reshape(x.shape), scale[..., 0]
 
 
 def dequantize(payload: jax.Array, scale: jax.Array, dtype=None) -> jax.Array:
-    """payload * scale in ``dtype`` (default fp32). Zero-scale channels
-    hold zero payloads, so the product needs no guard."""
+    """payload * scale in ``dtype`` (default fp32), the scale's last axis
+    saying how many equal parts of the payload's have a scale each
+    (:func:`quantize`). Zero-scale channels hold zero payloads, so the
+    product needs no guard."""
     dtype = SCALE_DTYPE if dtype is None else dtype
-    return (payload.astype(SCALE_DTYPE) * scale).astype(dtype)
+    parts = _in_parts(payload, scale.shape[-1]).astype(SCALE_DTYPE)
+    return (parts * scale[..., None]).reshape(payload.shape).astype(dtype)
 
 
 def quantize_weight(w: jax.Array, q: KVQuant) -> Tuple[jax.Array, jax.Array]:
@@ -173,22 +189,25 @@ def split_scales(
 def init_quant_cache(cfg, n_slots: int, q: KVQuant) -> Dict[str, jax.Array]:
     """The quantized analogue of ``generate.init_cache``: zeroed payload
     buffers in ``q.qdtype`` plus zeroed fp32 scale planes."""
-    from mingpt_distributed_tpu.models.generate import cache_leaf_shapes
+    from mingpt_distributed_tpu.models.generate import (
+        cache_leaf_shapes, row_heads)
 
     out: Dict[str, jax.Array] = {}
     for n, shape in cache_leaf_shapes(cfg, n_slots).items():
         out[n] = jnp.zeros(shape, q.qdtype)
-        out[n + SCALE_SUFFIX] = jnp.zeros(shape[:-1] + (1,), SCALE_DTYPE)
+        out[n + SCALE_SUFFIX] = jnp.zeros(
+            shape[:-1] + (row_heads(cfg),), SCALE_DTYPE)
     return out
 
 
 def quantize_lane(
-    lane: Dict[str, jax.Array], q: KVQuant,
+    lane: Dict[str, jax.Array], q: KVQuant, heads: int = 1,
 ) -> Dict[str, jax.Array]:
-    """fp32 ``{"k", "v"}`` lane -> quantized lane with scale planes."""
+    """fp32 ``{"k", "v"}`` lane -> quantized lane with scale planes;
+    ``heads`` the heads a row holds side by side (``generate.row_heads``)."""
     out: Dict[str, jax.Array] = {}
     for n in DATA_NAMES:
-        payload, scale = quantize(lane[n], q)
+        payload, scale = quantize(lane[n], q, heads)
         out[n] = payload
         out[n + SCALE_SUFFIX] = scale
     return out
@@ -234,7 +253,7 @@ def max_abs_logit_error(params, cfg, tokens, q: KVQuant) -> float:
     length = ids.shape[1]
     cache = gen.init_cache(cfg, 1)
     _, cache = gen._forward_cached_hidden(params, ids, cache, 0, cfg)
-    rt = dequantize_lane(quantize_lane(cache, q))
+    rt = dequantize_lane(quantize_lane(cache, q, gen.row_heads(cfg)))
     rt = {n: rt[n].astype(cache[n].dtype) for n in DATA_NAMES}
     # re-run only the last token against each cache: rows 0..length-2
     # are read (exact vs round-tripped), the rewritten last row is fp32

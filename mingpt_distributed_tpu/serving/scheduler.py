@@ -339,7 +339,8 @@ class InferenceServer:
             self.engine.program_param_bytes, self.engine.n_cast_leaves,
             self.engine.kv_bytes_per_row, self.engine.moe_rows,
             self.engine.state_bytes_per_slot, self.engine.sparse_rows,
-            self.engine.loop_passes)
+            self.engine.loop_passes, self.engine.pool.row_width,
+            self.engine.pool.row_tiles)
         # post-warmup recompile watchdog over the compiled program families
         # (the merged server-level counts, so draft/verify traces are
         # watched too; armed after warmup(); checked every round)

@@ -303,6 +303,9 @@ WIDE_FORMS = {
     # form's is (one query head a KV head is a matrix-vector product, which
     # the compiler spells out elementwise at these few lanes)
     "per-head": dict(n_kv_head=1),
+    # eight KV heads of 32, a row of 256: heads side by side (PR 39), read
+    # whole against queries spread to the row's width
+    "side-by-side": dict(),
     "latent": dict(rope=True, rope_interleave=True, swiglu=True, rmsnorm=True,
                    kv_lora_rank=128, qk_nope_head_dim=64,
                    qk_rope_head_dim=64, v_head_dim=64),
@@ -310,9 +313,10 @@ WIDE_FORMS = {
 
 
 @pytest.mark.parametrize("form,block", [
-    ("per-head", 1024), ("per-head", 128), ("latent", 1024), ("latent", 128)],
+    ("per-head", 1024), ("per-head", 128), ("latent", 1024), ("latent", 128),
+    ("side-by-side", 1024), ("side-by-side", 128)],
     ids=["per-head-one-pass", "per-head-walked", "latent-one-pass",
-         "latent-walked"])
+         "latent-walked", "side-by-side-one-pass", "side-by-side-walked"])
 def test_decode_program_reads_the_cache_as_it_lies(form, block, one_chip,
                                                    monkeypatch):
     """The decode program compiled for the chip selects, copies and converts
