@@ -31,7 +31,12 @@ lifetime:
    ``vmap`` over lanes of a batch of one would batch each lane's update
    into a scatter with the slot axis in front, and the TPU compiler then
    rewrites the whole pool four times a step. An expert MLP routes lane
-   by lane. Per-slot sampling params ride as traced arrays.
+   by lane. Per-slot sampling params ride as traced arrays. A lane's
+   input token is the host's, or the step before's output where it lies
+   on the device (``prev_tokens`` under the mask ``from_prev``, one
+   ``select`` at the program's head): the scheduler launches a step
+   before it has seen the last one's tokens (``launch_decode``,
+   ``sync_decode``; ``decode_step`` is the two back to back).
 
 3. **prefix extract / install** (only when the prefix store is enabled) —
    device-side row copies between a slot lane and a shared-prefix cache
@@ -423,14 +428,18 @@ def _prefill_impl(
 
 def _decode_impl(
     params, cache, tokens, positions, temps, top_ks, top_ps, do_sample,
-    seeds, token_index=None, live=None,
+    seeds, token_index=None, live=None, prev_tokens=None, from_prev=None,
     *, cfg: GPTConfig, kv_sharding=None, kv_quant=None,
 ):
     """One token for every slot: tokens/positions (S,), sampling arrays
     (S,), request seeds (S,) uint32, the index (S,) of the token each
     lane samples, from which the lanes' keys are derived here
     (:func:`lane_keys`), and ``live`` (S,) bool, the lanes the step is run
-    for (None: all). Returns (next tokens (S,), updated pool cache).
+    for (None: all). ``prev_tokens`` (S,) are the tokens the step before
+    this one returned, still on the device, and ``from_prev`` (S,) bool the
+    lanes that feed theirs: a lane launched before the host has seen its
+    last token takes it from there, every other lane from ``tokens`` (None:
+    all from ``tokens``). Returns (next tokens (S,), updated pool cache).
 
     The pool is a solo cache whose batch is the slot axis, so the step is
     one ``(B=S, T=1)`` forward through the same cached-block chain solo
@@ -451,6 +460,8 @@ def _decode_impl(
     no other.
     A quantized pool is dequantized, stepped and requantized whole
     (idempotent on the rows the step did not touch: serving/quant.py)."""
+    if prev_tokens is not None:
+        tokens = jax.lax.select(from_prev, prev_tokens, tokens)
     safe_pos = jnp.clip(positions, 0, cfg.block_size - 1)
     # ``live`` and not the position says which lanes hold a request (a
     # request's last step stands where a free lane is parked)
@@ -463,7 +474,16 @@ def _decode_impl(
     cache = _with_counter(_requant_lane(stepped, kv_quant, cfg), stepped)
     nxt = _select_next_slots(logits, lane_keys(seeds, token_index),
                              temps, top_ks, top_ps, do_sample)
+    if kv_sharding is not None:
+        # whole on every chip, and said so: the next step takes these as
+        # they are, under the sharding ``DecodeEngine._idle_tokens`` has
+        nxt = jax.lax.with_sharding_constraint(
+            nxt, _replicated(kv_sharding.mesh))
     return nxt, _pin_kv(cache, kv_sharding)
+
+
+def _replicated(mesh) -> jax.sharding.NamedSharding:
+    return jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
 
 
 def _extract_prefix_impl(cache, slot, *, rows: int, kv_sharding=None):
@@ -493,6 +513,18 @@ def _install_prefix_impl(cache, entry, slot, *, kv_sharding=None):
             (0, slot, 0, 0, 0))
         for name in _kv_leaves(cache)
     }, cache), kv_sharding)
+
+
+class DecodeLaunch:
+    """A decode step the device has been handed and the host has not waited
+    for: ``tokens`` is the step's (S,) result where it lies, on the device.
+    ``DecodeEngine.launch_decode`` makes one, ``sync_decode`` fetches it, and
+    the launch that follows may take its tokens as they are (``prev``)."""
+
+    __slots__ = ("tokens",)
+
+    def __init__(self, tokens: jax.Array):
+        self.tokens = tokens
 
 
 class DecodeEngine:
@@ -632,6 +664,24 @@ class DecodeEngine:
         self._install_jit = jax.jit(
             bind_static(_install_prefix_impl, kv_sharding=kv),
             donate_argnums=(0,))
+        self._idle_tokens = self._step_like_zeros()
+
+    def _step_like_zeros(self) -> jax.Array:
+        """(S,) zeros that the jit call cannot tell from a decode step's own
+        tokens: what a launch with no step before it hands the program as
+        ``prev_tokens``, so that it and a launch that runs ahead are one
+        entry of the jit's cache (a committed array and an uncommitted one
+        are two). A step's tokens are committed where any argument is, which
+        the vectors never are: under a mesh whole on every chip
+        (``_decode_impl`` says so), else where the weights or the pool
+        lie."""
+        zeros = np.zeros(self.n_slots, np.int32)
+        if self.kv_sharding is not None:
+            return jax.device_put(zeros, _replicated(self.kv_sharding.mesh))
+        for leaf in jax.tree.leaves((self.program_params, self.pool.cache)):
+            if getattr(leaf, "committed", False):
+                return jax.device_put(zeros, leaf.sharding)
+        return jnp.asarray(zeros)
 
     @property
     def n_slots(self) -> int:
@@ -873,7 +923,7 @@ class DecodeEngine:
             self.prefill_chunk_call(
                 0, [0] * b, 0, 1.0, None, None, False, 0)
         s = self.n_slots
-        self.decode_step(
+        parked = (
             np.zeros(s, np.int32),
             np.full(s, self.cfg.block_size - 1, np.int32),
             np.ones(s, np.float32), np.zeros(s, np.int32),
@@ -881,6 +931,11 @@ class DecodeEngine:
             np.zeros(s, np.uint32), np.zeros(s, np.int32),
             np.zeros(s, bool),
         )
+        # both ways the serving loop calls the one program: after nothing,
+        # and ahead of a step whose tokens are still on the device
+        first = self.launch_decode(*parked)
+        self.sync_decode(self.launch_decode(
+            *parked, prev=first, from_prev=np.zeros(s, bool)))
         if self.prefix_store is not None:
             for b in self.buckets:
                 if b <= self.prefill_len - 1:
@@ -899,6 +954,62 @@ class DecodeEngine:
                 self.pool.cache[name] = jax.device_put(
                     zeroed, old.sharding) if old.committed else zeroed
 
+    def launch_decode(
+        self,
+        tokens: np.ndarray,
+        positions: np.ndarray,
+        temps: np.ndarray,
+        top_ks: np.ndarray,
+        top_ps: np.ndarray,
+        do_sample: np.ndarray,
+        seeds,
+        token_index: Optional[np.ndarray] = None,
+        live: Optional[np.ndarray] = None,
+        prev: Optional[DecodeLaunch] = None,
+        from_prev: Optional[np.ndarray] = None,
+    ) -> DecodeLaunch:
+        """Hand the device one decode step (:meth:`decode_step` has the
+        vectors) and return without waiting for it: the span
+        ``serve.decode_launch`` is the staging of the arguments and the jit
+        call up to its return (the enqueue). ``prev`` is the launch before
+        this one, synced or not, and ``from_prev`` (S,) bool the lanes whose
+        input token is that step's output, taken on the device; every other
+        lane, and every lane where ``prev`` is None, feeds ``tokens``. The
+        vectors are copied here: the caller may change its own while the
+        step runs. The pool is donated from step to step, so whatever is
+        launched after this, a prefill or a row copy too, runs after it."""
+        with self.tracer.span("serve.decode_launch"):
+            s = len(tokens)
+            if token_index is None:
+                token_index = np.zeros(s, np.int32)
+            if live is None:
+                live = np.asarray(positions) < self.cfg.block_size - 1
+            if prev is None or from_prev is None:
+                from_prev = np.zeros(s, bool)
+            nxt, cache = self._decode_jit(
+                self.program_params, self.pool.cache,
+                np.array(tokens, np.int32),
+                np.array(positions, np.int32),
+                np.array(temps, np.float32),
+                np.array(top_ks, np.int32),
+                np.array(top_ps, np.float32),
+                np.array(do_sample, bool),
+                np.array(request_seeds(seeds)),
+                np.array(token_index, np.int32),
+                np.array(live, bool),
+                self._idle_tokens if prev is None else prev.tokens,
+                np.array(from_prev, bool),
+            )
+            self.pool.cache = cache
+        return DecodeLaunch(nxt)
+
+    def sync_decode(self, launch: DecodeLaunch) -> np.ndarray:
+        """The (S,) tokens of a launched step, on the host: the span
+        ``serve.decode_sync`` is the wait for them. With a later step
+        already launched the device works on through the wait."""
+        with self.tracer.span("serve.decode_sync"):
+            return np.asarray(jax.device_get(launch.tokens))
+
     def decode_step(
         self,
         tokens: np.ndarray,
@@ -911,7 +1022,9 @@ class DecodeEngine:
         token_index: Optional[np.ndarray] = None,
         live: Optional[np.ndarray] = None,
     ) -> np.ndarray:
-        """Advance every slot one token; caller masks inactive lanes.
+        """Advance every slot one token and wait for it: a
+        :meth:`launch_decode` whose every lane feeds the host's ``tokens``,
+        followed by its :meth:`sync_decode`; caller masks inactive lanes.
         Lane ``s`` samples under ``fold_in(key(seeds[s]), token_index[s])``,
         derived inside the program: ``seeds`` are the (S,) request seeds
         (:func:`request_seeds`), ``token_index`` how many tokens each
@@ -924,30 +1037,13 @@ class DecodeEngine:
         (:func:`decode_rows_read`); a routed model's experts run for the
         live lanes' routes alone, and a lane that is not live keeps its
         recurrent state; it is always an argument of the one program. The
-        host vectors go to the one jit call as they are; no other program
-        is dispatched. Two spans split the host's part:
-        ``serve.decode_launch`` is the staging of the arguments and the jit
-        call up to its return (the enqueue), ``serve.decode_sync`` the wait
-        for the tokens."""
-        with self.tracer.span("serve.decode_launch"):
-            if token_index is None:
-                token_index = np.zeros(len(tokens), np.int32)
-            if live is None:
-                live = np.asarray(positions) < self.cfg.block_size - 1
-            nxt, cache = self._decode_jit(
-                self.program_params, self.pool.cache,
-                np.asarray(tokens, np.int32),
-                np.asarray(positions, np.int32),
-                np.asarray(temps, np.float32),
-                np.asarray(top_ks, np.int32),
-                np.asarray(top_ps, np.float32),
-                np.asarray(do_sample, bool),
-                request_seeds(seeds), np.asarray(token_index, np.int32),
-                np.asarray(live, bool),
-            )
-            self.pool.cache = cache
-        with self.tracer.span("serve.decode_sync"):
-            return np.asarray(jax.device_get(nxt))
+        host vectors go to the one jit call; no other program is
+        dispatched. The scheduler calls the two halves itself, and the
+        launch of the next step before the sync of this one
+        (``InferenceServer.step``)."""
+        return self.sync_decode(self.launch_decode(
+            tokens, positions, temps, top_ks, top_ps, do_sample, seeds,
+            token_index, live))
 
     def compile_counts(self) -> Dict[str, int]:
         """Distinct traces per program family. After warmup: decode 1,
@@ -990,7 +1086,8 @@ class DecodeEngine:
                 np.ones(s, np.float32), np.zeros(s, np.int32),
                 np.ones(s, np.float32), np.zeros(s, bool),
                 np.zeros(s, np.uint32), np.zeros(s, np.int32),
-                np.ones(s, bool)), {})
+                np.ones(s, bool), program_lib.abstract(self._idle_tokens),
+                np.zeros(s, bool)), {})
         if self.prefix_store is None:
             return
         for b in self.buckets:

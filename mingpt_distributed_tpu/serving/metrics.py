@@ -95,6 +95,18 @@ class ServingMetrics:
             "mingpt_serve_decode_rows_reserved_total",
             help="rows the slots of those decode steps reserve "
                  "(n_slots x block_size a step)")
+        self._decode_launches = r.counter(
+            "mingpt_serve_decode_launches_total",
+            help="decode steps handed to the device")
+        self._decode_rounds_ahead = r.counter(
+            "mingpt_serve_decode_rounds_ahead_total",
+            help="decode steps launched before the sync of the step "
+                 "before them, whose tokens they take on the device")
+        self._lane_steps_discarded = r.counter(
+            "mingpt_serve_decode_lane_steps_discarded_total",
+            help="lane-steps computed for a request that had stopped by "
+                 "their sync (an EOS, a cancel, a deadline or a raising "
+                 "callback behind a step launched ahead)")
         # prefill accounting (ISSUE 3): real prompt tokens forwarded, the
         # padded bucket fit (how well the ladder matches the traffic), and
         # wall time inside prefill calls — the decode-stall budget
@@ -397,12 +409,24 @@ class ServingMetrics:
         self._decode_rows_read.inc(read)
         self._decode_rows_reserved.inc(reserved)
 
+    def on_decode_launch(self, ahead: bool) -> None:
+        """A decode step handed to the device; ``ahead``: before the sync
+        of the step before it."""
+        self._decode_launches.inc()
+        if ahead:
+            self._decode_rounds_ahead.inc()
+
+    def on_lane_steps_discarded(self, n: int) -> None:
+        """``n`` lane-steps in flight for a request that has just stopped."""
+        self._lane_steps_discarded.inc(n)
+
     def on_step(
         self, queue_depth: int, slots_active: int, lanes_used: Optional[int] = None
     ) -> None:
         """queue_depth/slots_active: end-of-round gauges (occupancy after
-        retirement). lanes_used: slots that actually decoded this step —
-        what utilization of the shared decode batch means."""
+        retirement). lanes_used: lane-steps the round launched (a lane-step
+        is counted once, at its launch) — what utilization of the shared
+        decode batch means."""
         self._steps.inc()
         self._queue_depth.set(queue_depth)
         self._slots_active.set(slots_active)
@@ -639,6 +663,10 @@ class ServingMetrics:
             "sampler_sorted_rounds": self.sampler_sorted_rounds,
             "decode_rows_read": self.decode_rows_read,
             "decode_rows_reserved": self.decode_rows_reserved,
+            "decode_launches": int(self._decode_launches.value),
+            "decode_rounds_ahead": int(self._decode_rounds_ahead.value),
+            "decode_lane_steps_discarded": int(
+                self._lane_steps_discarded.value),
             "queue_depth": self.queue_depth,
             "slots_active": self.slots_active,
             "slot_utilization": self.slot_utilization,

@@ -25,6 +25,10 @@ The loop the server runs (``step()`` = one scheduling round):
    propose→verify→accept-n and emit a burst of 1..k+1 tokens per round
    — every one of them still the target model's own greedy choice, so
    parity, retry idempotence and token-index dedup are untouched.
+   The round runs one step ahead: it launches step N+1, which takes
+   step N's tokens on the device, before it waits for step N and emits
+   its tokens (``step``'s docstring has when it does not); what a round
+   emits is the step launched a round before.
 4. **Retire** — requests hitting a stop condition (per-request
    ``max_new_tokens`` or EOS token) finish, free their slot, and the next
    round's admissions reuse it. Mid-decode admission is the whole point:
@@ -83,7 +87,17 @@ from __future__ import annotations
 import itertools
 import time
 from collections import deque
-from typing import Any, Callable, Deque, Dict, List, Optional, Sequence
+from typing import (
+    Any,
+    Callable,
+    Deque,
+    Dict,
+    List,
+    NamedTuple,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 import numpy as np
 
@@ -91,6 +105,7 @@ from mingpt_distributed_tpu.config import ConfigError, GPTConfig
 from mingpt_distributed_tpu.serving.admission import AdmissionPolicy, FifoPolicy
 from mingpt_distributed_tpu.serving.engine import (
     DecodeEngine,
+    DecodeLaunch,
     decode_rows_read,
     sampler_orders,
 )
@@ -174,6 +189,14 @@ class _Phase:
                          request_id=handle.request_id)
 
 
+class _InFlight(NamedTuple):
+    """A decode step the device has been handed: the engine's handle on its
+    tokens, and each lane it was launched for as (slot, request)."""
+
+    step: DecodeLaunch
+    lanes: List[Tuple[int, RequestHandle]]
+
+
 class SlotTable:
     """Slot-side state of one engine replica: the handle occupying each
     KV lane plus the per-slot decode-state arrays fed whole to the shared
@@ -185,6 +208,13 @@ class SlotTable:
     writer is guaranteed to refill before any query can attend it —
     parking anywhere lower could clobber rows a chunked prefill has
     already written.
+
+    The decode round runs one step ahead (``InferenceServer.step``), so a
+    decoding lane's ``positions`` entry is where its *next launch* feeds a
+    token: it moves at the launch, not at the emit, and ``ahead`` counts
+    the lane's tokens the device has been handed and the host has not
+    seen. ``tokens`` holds what the host last emitted; a lane with a token
+    on its way feeds that one instead, on the device.
     """
 
     def __init__(self, n_slots: int, block_size: int):
@@ -200,6 +230,10 @@ class SlotTable:
         # request seeds, the low 32 bits (all jax.random.key keeps of a
         # seed); free lanes hold 0
         self.seeds = np.zeros(n_slots, np.uint32)
+        # tokens of the slot's request the device has been handed and the
+        # host has not seen: 1 while a step launched for it is in flight (2
+        # between the launch of the next and the sync of that one)
+        self.ahead = np.zeros(n_slots, np.int32)
 
     def bind(self, slot: int, handle: RequestHandle, seed: int) -> None:
         handle.slot = slot
@@ -212,6 +246,7 @@ class SlotTable:
         decode program sorting for nobody: engine.sampler_orders)."""
         self.handles[slot] = None
         self.seeds[slot] = 0
+        self.ahead[slot] = 0
         self.positions[slot] = self.parked
         self.temps[slot], self.top_ks[slot], self.top_ps[slot] = 1.0, 0, 1.0
         self.do_sample[slot] = False
@@ -229,12 +264,12 @@ class SlotTable:
 
     def token_indices(self, slots: Sequence[int]) -> np.ndarray:
         """(n_slots,) int32: for each of ``slots`` the index of the token
-        its request samples next (how many it has emitted), 0 elsewhere.
-        With ``seeds`` this is all the decode program needs to derive the
-        round's keys."""
+        its request samples next (how many it has emitted, and how many
+        more are on their way: ``ahead``), 0 elsewhere. With ``seeds`` this
+        is all the decode program needs to derive the round's keys."""
         index = np.zeros(self.n_slots, np.int32)
         for s in slots:
-            index[s] = len(self.handles[s].tokens)
+            index[s] = len(self.handles[s].tokens) + self.ahead[s]
         return index
 
     def live_handles(self) -> List[RequestHandle]:
@@ -401,6 +436,9 @@ class InferenceServer:
                                  is not None else FifoPolicy())
         self.queue: Deque[RequestHandle] = deque()
         self.slots = SlotTable(n_slots, cfg.block_size)
+        # decode steps launched and not yet synced, oldest first: one
+        # between rounds at most, two inside a round that runs ahead
+        self._flight: Deque[_InFlight] = deque()
         self._ids = itertools.count()
         if warmup:
             self.engine.warmup()
@@ -524,6 +562,8 @@ class InferenceServer:
         if slot is not None:
             handle.slot = None
             handle.prefilling = False
+            # lane-steps launched for a request that has now stopped
+            self.metrics.on_lane_steps_discarded(int(self.slots.ahead[slot]))
             self.slots.release(slot)
             self.engine.pool.free(slot)
             if self.spec is not None:
@@ -683,9 +723,101 @@ class InferenceServer:
         if self.fault_hook is not None:
             self.fault_hook(where)
 
+    def _may_launch(self, lanes: List[int]) -> bool:
+        """Whether the round may hand the device a step for ``lanes`` now.
+        With nothing in flight, always. Ahead of a step in flight, unless
+        one of that step's tokens is its request's last by length, the one
+        stop the host knows before it sees the token: the round then syncs
+        first, the slot is freed with nothing in flight and the prefill of
+        the request that takes it starts at once (a closed loop sends that
+        request the moment the reply ends), and no step is launched to be
+        discarded."""
+        st = self.slots
+        return not self._flight or not any(
+            st.ahead[s] and len(st.handles[s].tokens) + st.ahead[s]
+            >= st.handles[s].max_new_effective for s in lanes)
+
+    def _launch(self, lanes: List[int]) -> None:
+        """Hand the device one decode step for ``lanes`` and move the host's
+        state on as far as it can without the tokens: a lane with a token
+        on its way (``SlotTable.ahead``) feeds that one, taken on the
+        device from the step in flight, and samples under the index after
+        it; its position and its count of tokens ahead go up by one. The
+        launch is filed with the *requests* it was made for: the slot of
+        one that stops meanwhile may have another tenant by the sync."""
+        st = self.slots
+        # the keys themselves are folded inside the program; what the host
+        # builds of them is the index vector
+        with self.tracer.span("serve.fold_keys", lanes=len(lanes)):
+            index = st.token_indices(lanes)
+        # the lanes this step is run for: the program reads a long slot
+        # only as far as the furthest of them stands; every other lane is
+        # parked (a speculating one too: the verify program writes its rows)
+        live = np.zeros(st.n_slots, bool)
+        live[lanes] = True
+        pos = np.where(live, st.positions, st.parked)
+        last = self._flight[-1].step if self._flight else None
+        step = self.engine.launch_decode(
+            st.tokens, pos, st.temps, st.top_ks, st.top_ps, st.do_sample,
+            st.seeds, index, live, prev=last, from_prev=live & (st.ahead > 0))
+        self.metrics.on_decode_launch(ahead=last is not None)
+        # the program's own rules, on the vectors it got
+        if sampler_orders(st.do_sample, st.top_ks, st.top_ps):
+            self.metrics.on_sampler_sorted()
+        read = int(decode_rows_read(pos, live, self.cfg))
+        self.metrics.on_decode_rows(
+            st.n_slots * read, st.n_slots * self.cfg.block_size)
+        self._flight.append(
+            _InFlight(step, [(s, st.handles[s]) for s in lanes]))
+        st.positions[lanes] += 1
+        st.ahead[lanes] += 1
+
+    def _sync(self) -> Dict[int, List[int]]:
+        """Wait for the oldest step in flight. Returns its token for every
+        lane whose request is still its slot's; the token of a request that
+        has stopped since the launch (an EOS, a cancel, a deadline, a
+        raising callback: ``_release_slot`` counted the lane-step as
+        discarded) is dropped, whoever holds the slot now."""
+        st = self.slots
+        done = self._flight.popleft()
+        nxt = self.engine.sync_decode(done.step)
+        burst: Dict[int, List[int]] = {}
+        for s, handle in done.lanes:
+            if st.handles[s] is handle:
+                st.ahead[s] -= 1
+                burst[s] = [int(nxt[s])]
+        return burst
+
+    def _forget_flight(self) -> None:
+        """Back to what the host has seen: every step in flight is let go
+        (its rows are written again, the same, when its tokens are computed
+        again) and each decoding lane stands where its last emitted token
+        is fed."""
+        st = self.slots
+        self._flight.clear()
+        st.ahead[:] = 0
+        for s in st.decoding_slots():
+            handle = st.handles[s]
+            st.positions[s] = len(handle.prompt_used) + len(handle.tokens) - 1
+
     def step(self) -> bool:
         """One scheduling round (expire → admit → prefill chunks → decode
-        → retire). Returns True while any request is queued or in flight."""
+        → retire). Returns True while any request is queued or in flight.
+
+        The decode round runs one step ahead: before it waits for step N's
+        tokens it launches step N+1, which feeds them on the device, so the
+        device goes from one step into the next while the host wakes, emits
+        and returns to its caller. The depth (0 or 1) follows from what the
+        round holds and from nothing else: a round syncs first where a
+        token in flight is a request's last by length or a lane
+        speculates, and the round after one that left nothing in flight
+        launches twice. A stop the host cannot foresee (EOS, cancel,
+        deadline, a raising ``on_token``) leaves one lane-step in flight
+        for a request that is gone: it writes a row inside the request's
+        own slot, which the stale-row invariant hides from the slot's next
+        tenant, its token is dropped, and ``decode_lane_steps_discarded``
+        counts it. A request's tokens, and the keys they were sampled
+        under, are those of the synchronous order."""
         # deadline sweep first: expired queued requests never take a slot,
         # expired in-flight requests release theirs before admission
         now = self.clock()
@@ -712,50 +844,45 @@ class InferenceServer:
                 self._prefill_one_chunk(h)
 
         active = self.slots.decoding_slots()
-        if active:
-            # serve.decode_round and its four children, one of each a
-            # round: serve.fold_keys and serve.emit here, serve.decode_launch
-            # and serve.decode_sync inside engine.decode_step
+        stepped = 0
+        if active or self._flight:
+            # serve.decode_round and its four children, one of each in a
+            # steady round: serve.fold_keys and serve.decode_launch (the
+            # next step, _launch), serve.decode_sync (the step before it,
+            # _sync) and serve.emit. A round that may not run ahead has the
+            # last two alone; the round after it launches twice
             with self._phase("serve.decode_round",
                              lanes=len(active)) as round_:
                 st = self.slots
-                # the keys themselves are folded inside the programs; what
-                # the host builds of them is the index vector
-                with self.tracer.span("serve.fold_keys", lanes=len(active)):
-                    index = st.token_indices(active)
                 # speculation split: greedy lanes with k+1 rows of window
                 # headroom run propose→verify→accept-n; sampled lanes and
                 # near-window tails keep the plain one-token step (parity
                 # and key-folding semantics unchanged on both paths)
-                spec_slots: List[int] = []
+                eligible: List[int] = []
                 if self.spec is not None and self.spec_enabled:
-                    spec_slots = [s for s in active if self.spec.eligible(
+                    eligible = [s for s in active if self.spec.eligible(
                         bool(st.do_sample[s]), int(st.positions[s]))]
-                plain = [s for s in active if s not in spec_slots]
-                burst: Dict[int, List[int]] = {}
-                if plain:
-                    # the lanes this step is run for: the program reads a
-                    # long slot only as far as the furthest of them stands
-                    live = np.zeros(st.n_slots, bool)
-                    live[plain] = True
-                    pos = st.positions
-                    if spec_slots:
-                        # park speculating lanes: the verify program is
-                        # their row-writer this round
-                        pos = np.where(live, st.positions, st.parked)
-                    nxt = self.engine.decode_step(
-                        st.tokens, pos, st.temps, st.top_ks,
-                        st.top_ps, st.do_sample, st.seeds, index, live,
-                    )
-                    # the program's own rules, on the vectors it got
-                    if sampler_orders(st.do_sample, st.top_ks, st.top_ps):
-                        self.metrics.on_sampler_sorted()
-                    read = int(decode_rows_read(pos, live, self.cfg))
-                    self.metrics.on_decode_rows(
-                        st.n_slots * read, st.n_slots * self.cfg.block_size)
-                    for s in plain:
-                        burst[s] = [int(nxt[s])]
+                plain = [s for s in active if s not in eligible]
+                # how many tokens a burst accepts is the host's to find, so
+                # a round with a lane that speculates keeps nothing in
+                # flight past its own sync, and speculates only once
+                # nothing is
+                spec_slots = [] if self._flight else eligible
+                depth = 0 if eligible else 1
+                # one step ahead, where the round's own state allows
+                # (_may_launch): the next step is launched before the host
+                # waits for the last one, whose tokens it takes on the
+                # device
+                while (plain and len(self._flight) <= depth
+                       and self._may_launch(plain)):
+                    self._launch(plain)
+                    stepped += len(plain)
+                burst = self._sync() if self._flight else {}
                 if spec_slots:
+                    stepped += len(spec_slots)
+                    with self.tracer.span("serve.fold_keys",
+                                          lanes=len(spec_slots)):
+                        index = st.token_indices(spec_slots)
                     smask = np.zeros(st.n_slots, bool)
                     smask[spec_slots] = True
                     proposals = self.spec.propose(
@@ -787,21 +914,27 @@ class InferenceServer:
                 # span would be dropped as an orphan
                 if self.trace_recorder is not None:
                     round_.lap()
-                    for s in active:
+                    for s in sorted(burst):
                         if s in spec_slots:
                             round_.file(st.handles[s], "serve.spec_round",
                                         proposed=self.spec.k,
                                         accepted=len(burst[s]) - 1)
                         else:
                             round_.file(st.handles[s])
-                # chaos fault point: a raise here loses this round's
-                # computed tokens (the whole accepted burst included)
-                # before any of them is emitted — the crash-mid-decode
-                # case the fleet retry must survive without double-
-                # emission
-                self._fire_fault("decode_round")
+                # chaos fault point: a raise here loses the synced step's
+                # tokens (the whole accepted burst included) before any of
+                # them is emitted, and those of the step launched ahead
+                # with them — the crash-mid-decode case the fleet retry
+                # must survive without double-emission
+                try:
+                    self._fire_fault("decode_round")
+                except BaseException:
+                    # a server that outlives the fault (a poisoned round)
+                    # computes the lost tokens again: from what it emitted
+                    self._forget_flight()
+                    raise
                 with self.tracer.span("serve.emit"):
-                    for s in active:
+                    for s in sorted(burst):
                         handle = st.handles[s]
                         toks = burst[s]
                         if s in spec_slots:
@@ -811,7 +944,9 @@ class InferenceServer:
                         for token in toks:
                             ok = self._emit(handle, token)
                             st.tokens[s] = token
-                            st.positions[s] += 1
+                            if s in spec_slots:
+                                # a plain lane moved on at its launch
+                                st.positions[s] += 1
                             if not ok:
                                 self._fail(handle, "error")
                                 break
@@ -828,7 +963,7 @@ class InferenceServer:
                                 break
 
         occupied = self.slots.occupied
-        self.metrics.on_step(len(self.queue), occupied, lanes_used=len(active))
+        self.metrics.on_step(len(self.queue), occupied, lanes_used=stepped)
         self.watchdog.check()
         return bool(self.queue) or occupied > 0
 

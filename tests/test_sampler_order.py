@@ -277,8 +277,9 @@ def _filtered_live(server):
 def test_mixed_requests_compile_nothing_and_count_their_rounds(cfg_params):
     """Greedy, temperature and nucleus requests through one warmed server:
     decode stays one executable, prefill within its buckets, the watchdog
-    counts nothing, and ``sampler_sorted_rounds`` is the number of rounds
-    with a filtered sampling request live."""
+    counts nothing, and ``sampler_sorted_rounds`` is the number of decode
+    steps launched with a filtered sampling request live (a round launches
+    one, none where it syncs first, two after that: scheduler ``step``)."""
     cfg, params = cfg_params
     server = InferenceServer(params, cfg, n_slots=3, warmup=True,
                              prefill_buckets=(8, 32), recompile_fail=True)
@@ -297,12 +298,14 @@ def test_mixed_requests_compile_nothing_and_count_their_rounds(cfg_params):
                                   do_sample=True, top_k=5))
     expected = 0
     busy = True
+    launches = lambda: server.summary()["decode_launches"]
     while busy:
-        expected += _filtered_live(server)
+        live, before_round = _filtered_live(server), launches()
         busy = server.step()
+        expected += live * (launches() - before_round)
     assert all(h.finished for h in (greedy, warm, nucleus, top_k))
     summary = server.summary()
-    assert 0 < expected < summary["steps"]
+    assert 0 < expected < summary["decode_launches"]
     assert summary["sampler_sorted_rounds"] == expected
     assert server.compile_counts() == before
     fam = server.metrics.registry.counter(

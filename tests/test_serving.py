@@ -330,10 +330,10 @@ def test_a_round_dispatches_nothing_but_its_programs(cfg_params, monkeypatch):
     monkeypatch.setattr(jax.random, "key", refuse("jax.random.key"))
     monkeypatch.setattr(jnp, "stack", refuse("jnp.stack"))
     decode_calls = []
-    real_decode = server.engine.decode_step
+    real_decode = server.engine.launch_decode
     monkeypatch.setattr(
-        server.engine, "decode_step",
-        lambda *a: decode_calls.append(1) or real_decode(*a))
+        server.engine, "launch_decode",
+        lambda *a, **k: decode_calls.append(1) or real_decode(*a, **k))
     rounds = 0
     for _ in range(3):
         server.step()
@@ -346,6 +346,7 @@ def test_a_round_dispatches_nothing_but_its_programs(cfg_params, monkeypatch):
     rounds += 1
     monkeypatch.undo()
     assert all(h.finished for h in handles) and late.finished
+    # every launch is synced, and a round syncs one step at most
     assert 0 < len(decode_calls) <= rounds
     assert server.compile_counts() == counts
     assert server.watchdog.recompiles == 0

@@ -44,25 +44,72 @@ def served(cfg_params, tracer, **kw):
     return InferenceServer(params, cfg, n_slots=2, tracer=tracer, **kw)
 
 
+def kids_of(tracer, round_):
+    kids = [r for r in spans_of(tracer) if r["parent"] == round_["id"]]
+    # in the order the round runs them, all one level down
+    assert sorted(kids, key=lambda r: r["ts"]) == kids
+    assert all(r["depth"] == 1 for r in kids)
+    assert 0 < sum(r["dur_s"] for r in kids) <= round_["dur_s"]
+    return kids
+
+
 def test_one_round_has_one_of_each_child_under_one_decode_round(cfg_params):
+    """A steady round, one with a step in flight that may be run ahead of:
+    the launch of the next step, the sync of the last, the emit."""
     tracer = SpanTracer()
     srv = served(cfg_params, tracer)
     for i, p in enumerate(PROMPTS[:2]):
-        srv.submit(Request(prompt=p, max_new_tokens=4, request_id=f"r{i}"))
+        srv.submit(Request(prompt=p, max_new_tokens=5, request_id=f"r{i}"))
+    srv.step()          # nothing in flight yet: its own case, below
+    srv.step()
+    first, round_ = spans_of(tracer, "serve.decode_round")
+    assert round_["parent"] is None and round_["lanes"] == 2
+    kids = kids_of(tracer, round_)
+    # one of each a round (never one a lane)
+    assert [r["name"] for r in kids] == CHILDREN
+    assert kids[0]["lanes"] == 2
+    # a third round adds exactly one more of each
+    before = {name: len(spans_of(tracer, name)) for name in CHILDREN}
+    srv.step()
+    for name in CHILDREN:
+        assert len(spans_of(tracer, name)) == before[name] + 1, name
+    assert len(spans_of(tracer, "serve.decode_round")) == 3
+
+
+def test_a_round_with_nothing_in_flight_launches_twice(cfg_params):
+    """The first decode round, and the round after one that synced first:
+    the step it syncs and the step ahead of it are both its own launches."""
+    tracer = SpanTracer()
+    srv = served(cfg_params, tracer)
+    for i, p in enumerate(PROMPTS[:2]):
+        srv.submit(Request(prompt=p, max_new_tokens=5, request_id=f"r{i}"))
     srv.step()
     [round_] = spans_of(tracer, "serve.decode_round")
     assert round_["parent"] is None and round_["lanes"] == 2
-    kids = [r for r in spans_of(tracer) if r["parent"] == round_["id"]]
-    # one of each a round (never one a lane), in the order the round runs them
-    assert sorted(kids, key=lambda r: r["ts"]) == kids
-    assert [r["name"] for r in kids] == CHILDREN
-    assert all(r["depth"] == 1 for r in kids)
-    assert kids[0]["lanes"] == 2
-    assert 0 < sum(r["dur_s"] for r in kids) <= round_["dur_s"]
-    # a second round adds exactly one more of each
-    srv.step()
-    for name in CHILDREN + ["serve.decode_round"]:
-        assert len(spans_of(tracer, name)) == 2, name
+    assert [r["name"] for r in kids_of(tracer, round_)] == [
+        "serve.fold_keys", "serve.decode_launch"] * 2 + [
+        "serve.decode_sync", "serve.emit"]
+    s = srv.summary()
+    assert (s["decode_launches"], s["decode_rounds_ahead"]) == (2, 1)
+
+
+def test_a_round_that_may_not_run_ahead_syncs_and_emits_alone(cfg_params):
+    """The token in flight is its request's last by length: the round
+    launches nothing, and leaves nothing in flight."""
+    tracer = SpanTracer()
+    srv = served(cfg_params, tracer)
+    h = srv.submit(Request(prompt=PROMPTS[1], max_new_tokens=3))
+    srv.step()          # the prompt's token and one more; the last in flight
+    assert len(h.tokens) == 2 and len(srv._flight) == 1
+    assert not srv.step()
+    assert h.finish_reason == "length" and len(h.tokens) == 3
+    assert not srv._flight
+    _, last = spans_of(tracer, "serve.decode_round")
+    assert [r["name"] for r in kids_of(tracer, last)] == [
+        "serve.decode_sync", "serve.emit"]
+    s = srv.summary()
+    assert (s["decode_launches"], s["decode_rounds_ahead"],
+            s["decode_lane_steps_discarded"]) == (2, 1, 0)
 
 
 def test_queue_wait_and_prefill_spans_need_no_trace_recorder(cfg_params):
@@ -121,12 +168,22 @@ class _RoundClock:
         return self.t
 
 
-@pytest.mark.parametrize("mode", ["plain", "spec"])
-def test_request_traces_are_field_for_field_what_they_were(cfg_params, mode):
+@pytest.mark.parametrize("mode", ["plain", "spec", "ahead"])
+def test_request_traces_are_field_for_field_what_they_were(
+        cfg_params, mode, monkeypatch):
     """The per-request records of three requests through two slots, against
     the same run of the commit before the call sites were merged (PR 22:
-    chunked prefill with a prefix hit, and speculation)."""
+    chunked prefill with a prefix hit, and speculation). ``plain`` is the
+    synchronous order (no step is launched ahead of one in flight: the
+    server's own rule, answered by a double) and ``spec`` keeps it by the
+    rule; ``ahead`` is the same traffic one step ahead (PR 43): the same
+    records, but that a request which starts decoding behind a step in
+    flight has its second token a round later, and a round that syncs
+    first counts the lanes it holds."""
     cfg, params = cfg_params
+    if mode == "plain":
+        monkeypatch.setattr(InferenceServer, "_may_launch",
+                            lambda self, lanes: not self._flight)
     sink, clock = _Sink(), _RoundClock()
     rec = TraceRecorder(sink=sink, sample=1.0)
     kw = (dict(draft_params=params, draft_cfg=cfg, spec_k=2) if mode == "spec"
@@ -185,8 +242,10 @@ def test_a_profile_of_two_rounds_holds_the_span_names(cfg_params, tmp_path):
                 for e in line.events:
                     if e.name.startswith("serve."):
                         seen[e.name] = seen.get(e.name, 0) + 1
+    # the first round launches twice (nothing was in flight), the second once
     for name in CHILDREN + ["serve.decode_round"]:
-        assert seen.get(name) == 2, (name, seen)
+        assert seen.get(name) == 2 + (
+            name in ("serve.fold_keys", "serve.decode_launch")), (name, seen)
     assert seen.get("serve.admit") == 2 and seen.get("serve.prefill_chunk") == 2
     # a wait filed at its end is a ring record and no annotation
     assert "serve.queue_wait" not in seen

@@ -479,12 +479,16 @@ def forward_digest(cfg: GPTConfig) -> str:
 
 
 def digest(cfg: GPTConfig) -> str:
-    """The engine's own prefill and decode programs over a 3-slot pool."""
+    """The engine's own prefill and decode programs over a 3-slot pool. The
+    decode program is traced with the eleven arguments the parent's had:
+    without the step's tokens and their mask (PR 43: the last two, one
+    ``select`` at the program's head; tests/test_run_ahead.py holds that it
+    is all they add) it is the parent's program still."""
     params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
     engine = DecodeEngine(
         jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params), cfg,
         n_slots=3, prefill_buckets=(8, 16))
-    return _sha([jitted.trace(*args, **kwargs).jaxpr
+    return _sha([jitted.trace(*args[:11], **kwargs).jaxpr
                  for _, _, jitted, args, kwargs in engine.programs()])
 
 
