@@ -83,7 +83,7 @@ COUNTERS = (MOE_ROWS, SPARSE_ROWS, LOOP_PASSES)
 
 #: lanes of the device's tile: the minor axis of a buffer is laid out in
 #: pieces of this many elements
-LANE_TILE = 128
+LANE_TILE = attn_ops.LANE_TILE
 
 
 def cache_leaf_shapes(cfg: GPTConfig, batch: int) -> Dict[str, Tuple[int, ...]]:
@@ -159,6 +159,18 @@ def row_tiles(cfg: GPTConfig) -> int:
     if heads == 1:
         return planes * -(-width // LANE_TILE)
     return planes * heads * width // 16
+
+
+def cache_walk(cfg: GPTConfig, cache) -> attn_ops.StepWalk:
+    """How a decode step walks ``cache``'s slices, from the ``"k"``, ``"v"``
+    leaves as the step is handed them (arrays or their
+    ``ShapeDtypeStruct``: shape and dtype are all it reads). The leaf whose
+    row says how the slices lie goes first: a latent cache's ``"v"`` is the
+    latent, ``"k"`` the rope key beside it. The one place that knows which
+    leaves those are, for a bare step and for the serving engine alike."""
+    return attn_ops.step_walk(
+        [cache[n].shape for n in ("v", "k")], cache["v"].dtype.itemsize,
+        latent=bool(cfg.kv_lora_rank))
 
 
 def init_cache(cfg: GPTConfig, batch: int, dtype=None) -> Cache:
@@ -244,7 +256,8 @@ def _cached_block(
     cfg: GPTConfig,
     valid: Optional[jax.Array] = None,  # (B, T) bool: tokens of a request
     expert_layer: Optional[int] = None,  # blk's EXPERT_LEAVES are the stack's
-    frontier: Optional[jax.Array] = None,  # furthest (B,) offset that counts
+    frontier: Optional[jax.Array] = None,  # (B,): how far each lane is read
+    walk: Optional[attn_ops.StepWalk] = None,  # and how (``cache_walk``)
 ) -> Tuple[jax.Array, Cache, Cache, Optional[jax.Array]]:
     """One pre-LN block against the cache. Returns (y, cache, rows,
     counts): the block's own (B, T, heads, size) k/v ``rows`` in the
@@ -277,9 +290,12 @@ def _cached_block(
     cache as it lies: a lane attends the rows before its position out of
     the cache and its own new row beside them, under one softmax in two
     parts (``attn_ops.causal_attend_step``, ``latent_attend_step``; the
-    row a lane attends for its own token is the row as cached), a slice
-    of more than one block only as far as ``frontier``, the furthest
-    position of a lane whose output counts (None: the furthest of all).
+    row a lane attends for its own token is the row as cached), lane ``b``
+    in blocks only as far as ``frontier[b]``, its reach: its position
+    where its output counts and 0 where it does not (None: every lane to
+    its own position; ``attn_ops.step_plan``), by ``walk`` (None:
+    :func:`cache_walk` of this cache; the serving engine works its pool's
+    out once and hands the same one to the program and to its counter).
     It returns the cache as it came: the caller writes all planes' rows
     after the last (``_write_lane_rows``). A capacity-routed expert MLP
     routes each lane alone, since lanes are other users' requests: a
@@ -334,12 +350,14 @@ def _cached_block(
     # attend against the whole cache; kv_offset makes query absolute
     # positions correct, and the causal mask kills both future tokens and
     # never-written (zero) slots beyond offset+t
+    if per_lane and walk is None:
+        walk = cache_walk(cfg, cache)
     if cfg.kv_lora_rank:
         scale = cfg.qk_head_dim ** -0.5
         if per_lane:
             att = attn_ops.latent_attend_step(
                 q_lat, q_pe, cache["v"], cache["k"], plane, rows["v"],
-                rows["k"], offset, frontier=frontier, scale=scale)
+                rows["k"], offset, walk, frontier=frontier, scale=scale)
         else:
             att = attn_ops.latent_attention(
                 q_lat, q_pe, big_v, big_k, kv_offset=offset, scale=scale)
@@ -348,7 +366,7 @@ def _cached_block(
     elif per_lane:
         att = attn_ops.causal_attend_step(
             q, cache["k"], cache["v"], plane, rows["k"], rows["v"], offset,
-            frontier=frontier, window=cfg.attention_window,
+            walk, frontier=frontier, window=cfg.attention_window,
             logit_softcap=cfg.attn_logit_softcap,
         ).reshape(b, t, nh * hd)
     else:
@@ -530,6 +548,7 @@ def _forward_cached_hybrid(params, x, cache: Cache, offset, cfg: GPTConfig,
 def _forward_cached_hidden(
     params: gpt.Params, tokens: jax.Array, cache: Cache, offset, cfg: GPTConfig,
     valid: Optional[jax.Array] = None, frontier: Optional[jax.Array] = None,
+    walk: Optional[attn_ops.StepWalk] = None,
 ) -> Tuple[jax.Array, Cache]:
     """Forward (B, T) tokens at absolute position ``offset`` (a scalar, or
     a ``(B,)`` vector of one position a row: see ``_cached_block``) through
@@ -543,9 +562,10 @@ def _forward_cached_hidden(
     layers' counts of the ``valid`` (B, T) tokens' routed rows added to it
     (None: every token is routed and counts; a prefill's padding and a
     decode lane without a request take no routed expert, and what they
-    leave in the cache is nobody's to read). ``frontier`` bounds what a
-    step under a position a lane reads of a long slice (``_cached_block``;
-    a hybrid stack's sparse layers read every row and take no notice).
+    leave in the cache is nobody's to read). ``frontier`` (B,) bounds what
+    a step under a position a lane reads of each lane's slice, walked as
+    ``walk`` says (``_cached_block``; a hybrid stack's sparse layers read
+    every row and take no notice).
 
     The layers are a static python loop: every layer's body is in the
     program, with its weights sliced out of their stack at a static index
@@ -598,7 +618,8 @@ def _forward_cached_hidden(
             blk = {n: a if n in whole else a[at] for n, a in stack.items()}
             x, cache, new, routed = _cached_block(
                 x, blk, cache, index * cfg.n_layer + layer, offset, cfg, valid,
-                expert_layer=at if whole else None, frontier=frontier)
+                expert_layer=at if whole else None, frontier=frontier,
+                walk=walk)
             rows.append(new)
             if routed is not None:
                 counts.append(routed)
@@ -696,13 +717,14 @@ def cast_once_params(
 def _forward_cached(
     params: gpt.Params, tokens: jax.Array, cache: Cache, offset, cfg: GPTConfig,
     valid: Optional[jax.Array] = None, frontier: Optional[jax.Array] = None,
+    walk: Optional[attn_ops.StepWalk] = None,
 ) -> Tuple[jax.Array, Cache]:
     """Forward (B, T) tokens at position ``offset`` through all layers.
     Returns (last-position logits (B, V), cache). Thin composition of
     ``_forward_cached_hidden`` + ``_head_logits`` — the serving engine
     (serving/engine.py) shares the same two pieces."""
     x, cache = _forward_cached_hidden(
-        params, tokens, cache, offset, cfg, valid, frontier)
+        params, tokens, cache, offset, cfg, valid, frontier, walk)
     logits = _head_logits(params, x[:, -1:], cfg)[:, 0]
     return logits, cache
 

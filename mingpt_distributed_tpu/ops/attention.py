@@ -20,10 +20,11 @@ are tested for parity against it.
 from __future__ import annotations
 
 import math
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1e30  # large-finite instead of -inf: keeps softmax NaN-free in bf16
 
@@ -176,82 +177,200 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
     return out.astype(x.dtype)
 
 
-#: cached rows a step of a walk over a slice attends: ``latent_attention``'s
-#: prefill loop, and a decode step's walk to its lanes' frontier
+#: cached rows a step of ``latent_attention``'s prefill walk over a slice
+#: attends, and of a latent decode step's (``step_block``)
 LATENT_KV_BLOCK = 1024
+
+#: lanes of a vector register: a minor dimension narrower than this fills no
+#: tile of its own
+LANE_TILE = 128
+
+#: what a step of the decode step's walk costs whatever it reads, in the
+#: bytes the chip reads meanwhile (6 us and more at 726 GB/s: PERF.md, PR
+#: 45): a lane's block is no larger, and lanes are read together where that
+#: saves more steps than it adds bytes (``step_block``, ``step_plan``)
+STEP_COST_BYTES = 4 << 20
+
+#: slices that all lanes' together read in the time of this many steps are
+#: not walked: that is all a walk of them could save, a layer, and two loops
+#: in every layer's body are not free to trace and compile (``step_block``;
+#: PERF.md, PR 45: ouro's three slots of 8 MiB a plane in 192 written-out
+#: bodies, ``setup_s`` +20%). No crossover of the attention's own time: the
+#: chip reads a walked house of 16-32 MiB -60% to +22% of one pass
+ONE_PASS_STEPS = 8
 
 
 def _walked(s: int) -> bool:
-    """Whether an ``s``-row slice is attended a block at a time: more than
-    one block, and a whole number of them."""
+    """Whether an ``s``-row slice is attended a block at a time by a chunk
+    of queries (``latent_attention``): more than one block, and a whole
+    number of them."""
     return s > LATENT_KV_BLOCK and s % LATENT_KV_BLOCK == 0
 
 
-def step_rows_read(s: int, frontier):
-    """Rows of an ``s``-row slice a decode step reads
-    (:func:`causal_attend_step`, :func:`latent_attend_step`) when no lane
-    that counts stands past ``frontier``: whole blocks of LATENT_KV_BLOCK
-    up to it. A slice of one block or less (or of no whole number of them)
-    is read whole, in one pass. The one rule for the program and for
-    whoever counts what it read: NumPy or ``jnp`` alike."""
-    if not _walked(s):
-        return s
-    blk = LATENT_KV_BLOCK
-    return (frontier + blk - 1) // blk * blk
+class StepWalk(NamedTuple):
+    """What a decode step's walk knows before it sees a position: a slice's
+    rows, the bytes of a cached row (keys and values, a plane) and the rows
+    a step takes of one lane (0: the slices are not walked, every lane's is
+    read whole in one pass). Worked out once from the leaves the step is
+    handed (:func:`step_walk`) and given as it is to the program and to
+    whoever counts what it read."""
+    s: int
+    row_bytes: int
+    block: int
 
 
-def _attend_step(take, score, weigh, own, own_value, s: int, frontier,
+def step_block(s: int, row_bytes: int, lanes: int,
+               latent: bool = False) -> int:
+    """Rows of one lane's ``s``-row slice a step of the decode step's walk
+    takes (:func:`_attend_step`), from what the code can see; 0: the
+    ``lanes`` slices are not walked. The largest power of two of rows whose
+    bytes do not pass STEP_COST_BYTES, and at most half the slice: a block
+    that is the whole slice is no function of a loop's index, and the
+    chip's compiler then computes its scores before the loop, whether a
+    step runs or not (PERF.md, PR 45). A latent's block is made a buffer of
+    its own (``reread``), which all lanes' together must fit in fast
+    memory: LATENT_KV_BLOCK, as PR 33 found it. A slice that is no whole
+    number of such blocks is one block. Slices that one pass reads, all of
+    them whole, in the time of ONE_PASS_STEPS steps are not walked."""
+    if lanes * s * row_bytes <= ONE_PASS_STEPS * STEP_COST_BYTES:
+        return 0
+    rows = LATENT_KV_BLOCK if latent else min(s // 2, 1 << max(
+        0, (STEP_COST_BYTES // row_bytes).bit_length() - 1))
+    return rows if rows and s % rows == 0 else s
+
+
+def step_walk(leaves: Sequence[Tuple[int, ...]], itemsize: int,
+              latent: bool = False) -> StepWalk:
+    """The walk over cache leaves of shapes ``leaves`` ``(L, B, S, heads,
+    size)``, ``itemsize`` bytes a number, as the step reads them. A leaf
+    that keeps each of several heads narrower than a lane tile an axis
+    entry of its own (GPT-2 XL's 25 of 64) lies positions minor on the
+    device, and a block cut out of it by a traced index is copied before it
+    is read (PERF.md, PR 45: 38 us a 6.5 MB block where the whole slices
+    read in 10 us a lane): such slices are not walked."""
+    lanes, s, heads, size = leaves[0][1:]
+    row_bytes = itemsize * sum(math.prod(leaf[3:]) for leaf in leaves)
+    block = 0 if heads > 1 and size < LANE_TILE \
+        else step_block(s, row_bytes, lanes, latent)
+    return StepWalk(s, row_bytes, block)
+
+
+def step_plan(walk: StepWalk, reach):
+    """How a decode step walks its lanes' slices, from their ``reach`` (B,)
+    (a lane's position where it holds a request, 0 where it does not):
+    ``(shared, need)``. Lane ``b`` is read in blocks of ``walk.block`` rows
+    as far as its reach. Block ``j`` of the slices is read for all lanes
+    in one step where that is the cheaper, a step costing STEP_COST_BYTES
+    beside what it reads: where the lanes that need it, each at a step and
+    a block of its own, would cost more than one step and ``B`` blocks.
+    Fewer lanes need a later block, so those are the first ``shared``
+    blocks; ``need`` (B,) are the blocks each lane is read alone after
+    them. NumPy or ``jnp`` alike."""
+    s, row_bytes, block = walk
+    own, whole = (STEP_COST_BYTES + n * block * row_bytes
+                  for n in (1, len(reach)))
+    blocks = (reach + block - 1) // block
+    # the lanes that need block j of their slice, for every j
+    wanted = (blocks[None, :] > np.arange(s // block)[:, None]).sum(-1)
+    shared = (wanted >= whole / own).sum()
+    return shared, (blocks - shared) * (blocks > shared)
+
+
+def step_rows_read(walk: StepWalk, reach):
+    """Rows a decode step reads of its lanes' slices, a plane, summed over
+    the lanes (:func:`causal_attend_step`, :func:`latent_attend_step`): by
+    :func:`step_plan`, whole blocks up to each lane's reach and, of every
+    lane, the blocks read together; every slice whole where they are not
+    walked. The one rule for the program and for whoever counts what it
+    read: NumPy or ``jnp`` alike."""
+    if not walk.block:
+        return len(reach) * walk.s
+    shared, need = step_plan(walk, reach)
+    return (shared * len(reach) + need.sum()) * walk.block
+
+
+def _attend_step(take, score, weigh, own, own_value, walk: StepWalk, reach,
                  reread: bool = False):
-    """One softmax in two parts for a decode step: a query's cached rows,
-    read as they lie, and its own new row beside them, which starts the
-    running maximum and sum (its score is finite, so a masked row's
+    """One softmax in parts for a decode step: a query's cached rows, read
+    as they lie, and its own new row beside them, which starts the running
+    maximum and sum (its score is finite, so a masked row's
     ``exp(NEG_INF - m)`` is 0 exactly and a block of masked rows changes
-    nothing). ``take(start, size)`` cuts rows ``[start, start + size)``
-    out of the whole cache buffers (inside the loop's body: a layer's
-    slice taken before the loop would be copied whole into it);
-    ``score(rows, k_pos)`` gives their (..., size) float32 scores, NEG_INF
-    where masked; ``weigh(p, rows)`` the (..., d) float32 sum of their
-    values under ``p``. ``own`` (...,) float32 and ``own_value`` (..., d)
-    float32 (broadcastable) are the new row's. ``reread``: ``score`` and
-    ``weigh`` read the same rows (a latent is key and value), so a walk's
-    block is made a buffer of its own: small enough for the chip's
-    compiler to keep in fast memory, and the cache's rows then leave HBM
-    once a step and not twice (PERF.md, PR 33: 6.0 -> 4.5 ms).
-    Returns (..., d) float32."""
-    if not _walked(s):
-        rows = take(0, s)
-        z = score(rows, jnp.arange(s))
+    nothing). The walk is :func:`step_plan`'s: the blocks enough lanes
+    need, a step each for all lanes; then (lane, block) pairs, lane ``b``
+    in blocks up to ``reach[b]``, so a lane whose reach is 0 (it holds no
+    request, or stands at position 0) is passed by and attends its own row
+    alone where no block is shared. Two ``fori_loop`` of one body, their
+    trip counts the shared blocks and the pairs (``step_rows_read`` is the
+    rows they take); step ``i`` of the second finds its lane and block in
+    the running sum of the blocks the lanes need. Slices that are not
+    walked (``walk.block`` 0) are read whole in one pass, every lane's.
+    ``take(lane, lanes, start, size)`` cuts rows ``[start, start + size)``
+    of ``lanes`` lanes from ``lane`` on out of the whole cache buffers
+    (inside the loop's body: a slice taken before the loop would be copied
+    whole into it); ``score(rows, lane, lanes, k_pos)`` gives their
+    (lanes, ..., size) float32 scores against those lanes' queries, NEG_INF
+    where masked; ``weigh(p, rows)`` the (lanes, ..., d) float32 sum of
+    their values under ``p``. ``own`` (B, ...) float32 and ``own_value``
+    (B, ..., d) float32 (broadcastable) are the new rows'. ``reread``:
+    ``score`` and ``weigh`` read the same rows (a latent is key and
+    value), so a block is made a buffer of its own: small enough for the
+    chip's compiler to keep in fast memory, and the cache's rows then
+    leave HBM once a step and not twice (PERF.md, PR 33: 6.0 -> 4.5 ms).
+    Returns (B, ..., d) float32."""
+    n, (s, _, block) = own.shape[0], walk
+    if not block:
+        rows = take(0, n, 0, s)
+        z = score(rows, 0, n, jnp.arange(s))
         m = jnp.maximum(z.max(-1), own)
         p, p_own = jnp.exp(z - m[..., None]), jnp.exp(own - m)
         acc = weigh(p, rows) + p_own[..., None] * own_value
         return acc / (p.sum(-1) + p_own)[..., None]
 
-    blk = LATENT_KV_BLOCK
+    shared, need = step_plan(walk, reach.astype(jnp.int32))
+    ends = jnp.cumsum(need)
+    # step i's lane and first row, for every pair the walk can take
+    at = jnp.arange(n * (s // block))
+    lane_of = jnp.minimum((ends[None, :] <= at[:, None]).sum(-1),
+                          n - 1).astype(jnp.int32)
+    start_of = (shared + at - (ends - need)[lane_of]).astype(jnp.int32) * block
 
-    def step(i, carry):
-        m, l, acc = carry
-        rows = take(i * blk, blk)
+    def step(carry, lane, lanes, start):
+        rows = take(lane, lanes, start, block)
         if reread:
             rows = jax.lax.optimization_barrier(rows)
-        z = score(rows, i * blk + jnp.arange(blk))
+        z = score(rows, lane, lanes, start + jnp.arange(block))
+        m, l, acc = (_of_lanes(c, lane, lanes) for c in carry)
         m_new = jnp.maximum(m, z.max(-1))
         p, fix = jnp.exp(z - m_new[..., None]), jnp.exp(m - m_new)
-        return (m_new, l * fix + p.sum(-1),
-                acc * fix[..., None] + weigh(p, rows))
+        new = (m_new, l * fix + p.sum(-1),
+               acc * fix[..., None] + weigh(p, rows))
+        return tuple(jax.lax.dynamic_update_slice_in_dim(c, a, lane, 0)
+                     for c, a in zip(carry, new))
 
+    carry = (own, jnp.ones_like(own),
+             jnp.broadcast_to(own_value, own.shape + own_value.shape[-1:]))
+    carry = jax.lax.fori_loop(
+        0, shared, lambda j, c: step(c, 0, n, j * block), carry)
     _, l, acc = jax.lax.fori_loop(
-        0, step_rows_read(s, frontier) // blk, step,
-        (own, jnp.ones_like(own),
-         jnp.broadcast_to(own_value, own.shape + own_value.shape[-1:])))
+        0, ends[-1], lambda i, c: step(c, lane_of[i], 1, start_of[i]), carry)
     return acc / l[..., None]
 
 
-def _layer_rows(buf: jax.Array, layer: int, start, size: int) -> jax.Array:
-    """Rows ``[start, start + size)`` of layer ``layer`` out of a whole
-    ``(L, B, S, heads, size)`` cache buffer: (B, size, heads, size)."""
+def _lane_rows(buf: jax.Array, layer: int, lane, lanes: int, start,
+               size: int) -> jax.Array:
+    """Rows ``[start, start + size)`` of ``lanes`` lanes from ``lane`` on,
+    of layer ``layer``, out of a whole ``(L, B, S, heads, size)`` cache
+    buffer: (lanes, size, heads, size)."""
     return jax.lax.dynamic_slice(
-        buf, (layer, 0, start, 0, 0), (1, buf.shape[1], size) + buf.shape[3:]
-    )[0]
+        buf, (layer, lane, start, 0, 0), (1, lanes, size) + buf.shape[3:])[0]
+
+
+def _of_lanes(a: jax.Array, lane, lanes: int) -> jax.Array:
+    """(B, ...) -> (lanes, ...): the entries of ``lanes`` lanes from
+    ``lane`` on (all of them: ``a`` as it is)."""
+    if lanes == a.shape[0]:
+        return a
+    return jax.lax.dynamic_slice_in_dim(a, lane, lanes, 0)
 
 
 @jax.named_scope("cached_attn")
@@ -263,6 +382,7 @@ def causal_attend_step(
     k_new: jax.Array,     # (B, 1, ...): each lane's own new row, shaped as
     v_new: jax.Array,     #   the cache's rows
     positions: jax.Array,  # (B,): where each lane's query stands
+    walk: StepWalk,       # how the slices are walked (``step_walk``)
     *,
     frontier=None,
     window: Optional[int] = None,
@@ -274,9 +394,13 @@ def causal_attend_step(
     ``v_new``, under one softmax in two parts (:func:`_attend_step`).
     Nothing of the slice's size is selected, copied or written. ``window``
     and ``logit_softcap`` as in ``causal_attention`` (the own row is always
-    inside the window). The slice is read as far as
-    ``step_rows_read(S, frontier)`` rows (``frontier``: the furthest
-    position of a lane whose output counts; None, the furthest of all).
+    inside the window). Lane ``b``'s slice is read in blocks as far as
+    ``frontier[b]`` (B,), its reach: its position where it holds a request
+    and 0 where it does not (None: every lane to its own position), and
+    a block that enough lanes need for all lanes in one step
+    (:func:`_attend_step`, :func:`step_plan`); a per-head leaf of heads
+    under a lane tile is read whole, every lane's (:func:`step_walk`), and
+    so are slices too few and short for a walk to pay (:func:`step_block`).
 
     A cache that keeps each head an axis entry of its own is scored a KV
     head at a time, the grouped queries beside their head. A cache that
@@ -292,11 +416,11 @@ def causal_attend_step(
     out again, positions minor, before it scored it (compile rehearsal,
     PR 39: a copy and a float32 convert of a layer's slice, each leaf).
     Returns (B, 1, H, hd) in q's dtype."""
-    b, _, h, hd = q.shape
-    s, kv = k_cache.shape[2], math.prod(k_cache.shape[3:]) // hd
-    side_by_side = k_cache.shape[3] != kv
     if frontier is None:
-        frontier = jnp.max(positions)
+        frontier = positions
+    b, _, h, hd = q.shape
+    kv = math.prod(k_cache.shape[3:]) // hd
+    side_by_side = k_cache.shape[3] != kv
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, dtype=jnp.float32))
     if side_by_side:
         # the queries to the rows' width: (B, H, KV x hd)
@@ -307,16 +431,17 @@ def causal_attend_step(
         qg = q.reshape(b, kv, h // kv, hd)
         by_q, by_rows, by_new, scored = "bkgd", "bskd", "bkd", "bkg"
 
-    def take(start, size):
-        rows = (_layer_rows(k_cache, layer, start, size),
-                _layer_rows(v_cache, layer, start, size))
+    def take(lane, lanes, start, size):
+        rows = (_lane_rows(k_cache, layer, lane, lanes, start, size),
+                _lane_rows(v_cache, layer, lane, lanes, start, size))
         return tuple(r[:, :, 0] for r in rows) if side_by_side else rows
 
-    def score(rows, k_pos):
+    def score(rows, lane, lanes, k_pos):
         z = softcap(jnp.einsum(
-            f"{by_q},{by_rows}->{scored}s", qg, rows[0],
-            preferred_element_type=jnp.float32) * scale, logit_softcap)
-        behind = positions[:, None] - k_pos[None, :]      # (B, S')
+            f"{by_q},{by_rows}->{scored}s", _of_lanes(qg, lane, lanes),
+            rows[0], preferred_element_type=jnp.float32) * scale,
+            logit_softcap)
+        behind = _of_lanes(positions, lane, lanes)[:, None] - k_pos[None, :]
         allowed = behind > 0
         if window is not None:
             allowed = allowed & (behind < window)
@@ -336,7 +461,7 @@ def causal_attend_step(
     # the own row's value beside the scores' axes: (B, 1, W), (B, KV, 1, hd)
     v_own = v_new[:, 0] if side_by_side else v_new[:, 0, :, None]
     out = _attend_step(take, score, weigh, own, v_own.astype(jnp.float32),
-                       s, frontier)
+                       walk, frontier)
     if side_by_side:
         return own_part(out[:, None], kv).astype(q.dtype)
     return out.reshape(b, 1, h, hd).astype(q.dtype)
@@ -352,6 +477,7 @@ def latent_attend_step(
     latent_new: jax.Array,    # (B, 1, 1, r): each lane's own new latent
     pe_new: jax.Array,        # (B, 1, 1, e): and rotated rope key
     positions: jax.Array,     # (B,)
+    walk: StepWalk,           # ``step_walk`` of the two, ``latent``
     *,
     frontier=None,
     scale: float,
@@ -360,21 +486,20 @@ def latent_attend_step(
     as it lies, as :func:`causal_attend_step` reads a per-head one: the own
     row's score is ``q_lat . latent_new + q_pe . pe_new``, its value the
     new latent. Returns the heads' averaged latents (B, 1, H, r)."""
-    s = latent_cache.shape[2]
     if frontier is None:
-        frontier = jnp.max(positions)
+        frontier = positions
     q_lat, q_pe = q_lat[:, 0], q_pe[:, 0]                 # (B, H, .)
 
-    def take(start, size):
-        return (_layer_rows(latent_cache, layer, start, size)[:, :, 0],
-                _layer_rows(pe_cache, layer, start, size)[:, :, 0])
+    def take(lane, lanes, start, size):
+        return tuple(_lane_rows(c, layer, lane, lanes, start, size)[:, :, 0]
+                     for c in (latent_cache, pe_cache))
 
-    def score(rows, k_pos):
-        z = jnp.einsum("bhr,bsr->bhs", q_lat, rows[0],
-                       preferred_element_type=jnp.float32)
-        z = z + jnp.einsum("bhe,bse->bhs", q_pe, rows[1],
-                           preferred_element_type=jnp.float32)
-        allowed = k_pos[None, :] < positions[:, None]     # (B, S')
+    def score(rows, lane, lanes, k_pos):
+        z = jnp.einsum("bhr,bsr->bhs", _of_lanes(q_lat, lane, lanes),
+                       rows[0], preferred_element_type=jnp.float32)
+        z = z + jnp.einsum("bhe,bse->bhs", _of_lanes(q_pe, lane, lanes),
+                           rows[1], preferred_element_type=jnp.float32)
+        allowed = k_pos[None, :] < _of_lanes(positions, lane, lanes)[:, None]
         return jnp.where(allowed[:, None, :], z * scale, NEG_INF)
 
     def weigh(p, rows):
@@ -387,7 +512,7 @@ def latent_attend_step(
            + jnp.einsum("bhe,be->bh", q_pe, pe,
                         preferred_element_type=jnp.float32)) * scale
     out = _attend_step(take, score, weigh, own,
-                       new[:, None].astype(jnp.float32), s, frontier,
+                       new[:, None].astype(jnp.float32), walk, frontier,
                        reread=True)
     return out[:, None].astype(q_lat.dtype)
 

@@ -22,11 +22,15 @@ lifetime:
    call of the same ``_forward_cached`` the solo scan uses, at a ``(S,)``
    vector of absolute positions (per-slot ``kv_offset`` and RoPE /
    learned-position index). Each layer reads its slice of the pool as
-   it lies, each lane's new row attended beside it under one softmax, and
-   a slice longer than one block only as far as the furthest live lane
-   stands (``decode_rows_read``); after the last layer one row-sized
-   ``dynamic_update_slice`` a lane writes all layers' rows into the
-   donated pool where they lie (``generate._write_lane_rows``). The
+   it lies, each lane's new row attended beside it under one softmax: the
+   lanes that hold a request alone, each in blocks as far as its own
+   position, a block that enough of them need for all lanes at once
+   (``attn_ops.step_plan``), by the one ``StepWalk`` the engine works
+   out of its pool's leaves (``decode_walk``: the program is built with
+   it and ``decode_rows_read`` counts by it); after the last layer
+   one row-sized ``dynamic_update_slice`` a lane writes all layers' rows
+   into the donated pool where they lie
+   (``generate._write_lane_rows``). The
    pool is never transposed, copied or rebuilt inside the program: a
    ``vmap`` over lanes of a batch of one would batch each lane's update
    into a scatter with the slot axis in front, and the TPU compiler then
@@ -229,24 +233,40 @@ def sampler_orders(do_sample, top_ks, top_ps):
 
 
 def decode_frontier(positions, live):
-    """The furthest position of a lane the decode step is run for: how far
-    the step has to read a slot. ``live`` and not the positions says which
-    lanes those are (a free lane is parked at the window's last row, and
-    so is a request's last step)."""
-    return (positions * live).max()
+    """Each lane's reach (S,): how far the decode step reads its slot. Its
+    position where the step is run for it and 0 where it is not. ``live``
+    and not the positions says which lanes those are (a free lane is
+    parked at the window's last row, and so is a request's last step);
+    ``live`` None: every lane."""
+    return positions if live is None else positions * live
 
 
-def decode_rows_read(positions, live, cfg: GPTConfig):
-    """Rows of every slot's slice a decode step reads, a layer: whole
-    blocks up to :func:`decode_frontier` where the slice is walked
-    (``attn_ops.step_rows_read``), else the whole slice (one block or
-    less; a hybrid stack's sparse layers). NumPy or ``jnp`` vectors alike:
-    the decode program walks as far as this says and the scheduler counts
-    it, on the host's copy of the same vectors."""
+def decode_rows_read(positions, live, walk: attn_ops.StepWalk):
+    """Rows of the slots a decode step reads, a plane, summed over the
+    lanes: whole blocks up to each live lane's own position, nothing of a
+    lane that is not live but the blocks read for all lanes together
+    (``attn_ops.step_rows_read`` on :func:`decode_frontier`), or every slot
+    whole where ``walk`` walks none (rows the device keeps positions minor;
+    a pool one pass reads in a few steps' time, ``attn_ops.step_block``; a
+    hybrid stack's sparse layers). ``walk`` is the engine's
+    (``DecodeEngine.walk``, :func:`decode_walk`): the one object its decode
+    program was built with. NumPy or ``jnp`` vectors alike: the program's
+    walk takes this many rows a plane and the scheduler counts it, on the
+    host's copy of the same vectors."""
+    return attn_ops.step_rows_read(walk, decode_frontier(positions, live))
+
+
+def decode_walk(cfg: GPTConfig, cache, kv_quant=None) -> attn_ops.StepWalk:
+    """The decode step's walk over the pool ``cache``, worked out once from
+    the pool's own leaves as the step reads them (``generate.cache_walk``
+    on their shapes and the dtype they have after :func:`_dequant_lane`: a
+    ``cache_dtype`` of the engine's, or ``cfg.dtype`` out of a quantized
+    pool). A hybrid stack's layers read every row of every slot and take no
+    walk: its rule walks nothing."""
     if cfg.mixer_types is not None:
-        return cfg.block_size
-    return attn_ops.step_rows_read(
-        cfg.block_size, decode_frontier(positions, live))
+        return attn_ops.StepWalk(cfg.block_size, 0, 0)
+    return gen.cache_walk(cfg, jax.eval_shape(
+        lambda c: _dequant_lane(c, kv_quant, cfg), cache))
 
 
 @jax.named_scope("sample")
@@ -429,7 +449,7 @@ def _prefill_impl(
 def _decode_impl(
     params, cache, tokens, positions, temps, top_ks, top_ps, do_sample,
     seeds, token_index=None, live=None, prev_tokens=None, from_prev=None,
-    *, cfg: GPTConfig, kv_sharding=None, kv_quant=None,
+    *, cfg: GPTConfig, kv_sharding=None, kv_quant=None, walk=None,
 ):
     """One token for every slot: tokens/positions (S,), sampling arrays
     (S,), request seeds (S,) uint32, the index (S,) of the token each
@@ -448,10 +468,15 @@ def _decode_impl(
     as it lies, under each lane's own causal mask, with the lane's new
     row attended beside it, and all layers' rows are written into the
     donated pool after the last, one row-sized update a lane. Nothing in
-    the program has the pool's size but the pool, and nothing a slice's. A
-    slice of more than one block is read only as far as the furthest
-    ``live`` lane stands (:func:`decode_rows_read`): a lane that is not
-    live attends what that leaves it, a routed model lays none of its
+    the program has the pool's size but the pool, and nothing a slice's.
+    Each lane's reach is worked out here from ``positions`` and ``live``
+    (:func:`decode_frontier`) and the attention walks the lanes by it as
+    ``walk`` says, the engine's (:func:`decode_walk`; None: the cache's
+    own, worked out here), which is what :func:`decode_rows_read` counts
+    by: a ``live`` lane is read in blocks as far as
+    its own position, a lane that is not live is passed by and attends its
+    own new row (and what a block read for all lanes leaves it), a routed
+    model lays none of its
     routes and counts none (``moe.grouped_swiglu``: it takes the shared
     expert alone), a hybrid stack leaves its state as it was, and its
     token is the caller's to discard; a live lane attends every row its
@@ -469,8 +494,9 @@ def _decode_impl(
         params, tokens[:, None],
         _with_counter(_dequant_lane(cache, kv_quant, cfg), cache),
         safe_pos, cfg, valid=None if live is None else live[:, None],
-        frontier=decode_frontier(
-            safe_pos, True if live is None else live))
+        # a hybrid stack's layers read every row and take no reach
+        frontier=None if cfg.mixer_types is not None
+        else decode_frontier(safe_pos, live), walk=walk)
     cache = _with_counter(_requant_lane(stepped, kv_quant, cfg), stepped)
     nxt = _select_next_slots(logits, lane_keys(seeds, token_index),
                              temps, top_ks, top_ps, do_sample)
@@ -653,8 +679,12 @@ class DecodeEngine:
         self._prefill_jit = jax.jit(
             bind_static(_prefill_impl, cfg=cfg, kv_sharding=kv, kv_quant=kq),
             donate_argnums=(1,))
+        # how the decode step walks this pool: the program is built with it
+        # and the scheduler counts by it (decode_rows_read)
+        self.walk = decode_walk(cfg, self.pool.cache, kq)
         self._decode_jit = jax.jit(
-            bind_static(_decode_impl, cfg=cfg, kv_sharding=kv, kv_quant=kq),
+            bind_static(_decode_impl, cfg=cfg, kv_sharding=kv, kv_quant=kq,
+                        walk=self.walk),
             donate_argnums=(1,))
         # prefix copy programs: `rows` is static, so one jit wrapper traces
         # once per bucket-quantized prefix length

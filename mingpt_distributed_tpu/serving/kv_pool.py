@@ -37,8 +37,10 @@ lets the decode program stay compiled once for the server's lifetime.
 The decode step reads the buffers as they lie: a layer's slice is never
 selected over, copied or converted on its way into the attention (each
 lane's new row is attended beside the cached ones and written after the
-last layer), and a slot longer than one block of rows is read only as far
-as the furthest live lane stands (``engine.decode_rows_read``;
+last layer), and only the slots that hold a request are read, each in
+blocks as far as its own position (a block that enough of them need, for
+all slots at once; a pool one pass reads in a few steps' time whole:
+``attention.step_plan``, ``step_block``, ``engine.decode_rows_read``;
 ``ServingMetrics`` counts ``decode_rows_read`` against
 ``decode_rows_reserved``).
 

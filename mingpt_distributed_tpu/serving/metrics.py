@@ -89,8 +89,8 @@ class ServingMetrics:
         self._decode_rows_read = r.counter(
             "mingpt_serve_decode_rows_read_total",
             help="rows of the pool's slots the decode steps read, a layer: "
-                 "every slot as far as the furthest live lane stands "
-                 "(engine.decode_rows_read)")
+                 "each live lane's slot in blocks as far as its own "
+                 "position (engine.decode_rows_read)")
         self._decode_rows_reserved = r.counter(
             "mingpt_serve_decode_rows_reserved_total",
             help="rows the slots of those decode steps reserve "

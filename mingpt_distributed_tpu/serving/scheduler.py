@@ -764,9 +764,9 @@ class InferenceServer:
         # the program's own rules, on the vectors it got
         if sampler_orders(st.do_sample, st.top_ks, st.top_ps):
             self.metrics.on_sampler_sorted()
-        read = int(decode_rows_read(pos, live, self.cfg))
         self.metrics.on_decode_rows(
-            st.n_slots * read, st.n_slots * self.cfg.block_size)
+            int(decode_rows_read(pos, live, self.engine.walk)),
+            st.n_slots * self.cfg.block_size)
         self._flight.append(
             _InFlight(step, [(s, st.handles[s]) for s in lanes]))
         st.positions[lanes] += 1

@@ -41,3 +41,28 @@ def eight_devices():
     devs = jax.devices()
     assert len(devs) >= 8, f"expected 8 virtual CPU devices, got {len(devs)}"
     return devs[:8]
+
+
+@pytest.fixture
+def walk_in_blocks(monkeypatch):
+    """``walk_in_blocks(rows)``: every walk worked out after it
+    (``attention.step_walk``: a bare step's, an engine's at its
+    construction) is a ``StepWalk`` of ``rows``-row blocks, whatever the
+    leaves; the rule itself reads tiny slices in one pass, as it does
+    narrow per-head leaves. ``alone=True``, the default, gives the walk a
+    row beside whose bytes a step's cost is nothing, so a block is read
+    for all lanes together only where every lane needs it; ``alone=False``
+    a row of one byte, beside which the cost is everything, so any block
+    two lanes need is read for all. Returns the walk of an ``s``-row
+    slice, for a test that hands one to the attention itself."""
+    from mingpt_distributed_tpu.ops import attention
+
+    def patch(rows, alone=True):
+        def walk_of(s):
+            return attention.StepWalk(
+                s, 1 << 40 if alone else 1, rows if s % rows == 0 else s)
+        monkeypatch.setattr(
+            attention, "step_walk",
+            lambda leaves, itemsize, latent=False: walk_of(leaves[0][2]))
+        return walk_of
+    return patch

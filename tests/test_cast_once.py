@@ -318,13 +318,14 @@ WIDE_FORMS = {
     ids=["per-head-one-pass", "per-head-walked", "latent-one-pass",
          "latent-walked", "side-by-side-one-pass", "side-by-side-walked"])
 def test_decode_program_reads_the_cache_as_it_lies(form, block, one_chip,
-                                                   monkeypatch):
+                                                   walk_in_blocks):
     """The decode program compiled for the chip selects, copies and converts
     nothing of a slice's size (PR 33): each layer reads the pool's buffers
-    where they lie, in one pass or block by block, and attends the lanes'
-    new rows beside them. Laying the rows over the slice first, as the step
+    where they lie, a lane's slot in one block or in several (the walk is a
+    loop either way since PR 45), and attends the lanes' new rows beside
+    them. Laying the rows over the slice first, as the step
     did before, is the control: the chip's compiler keeps that select."""
-    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", block)
+    walk_in_blocks(block)
     lanes = 8
     cfg = GPTConfig.make(**WIDE, **WIDE_FORMS[form])
     params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
@@ -337,7 +338,7 @@ def test_decode_program_reads_the_cache_as_it_lies(form, block, one_chip,
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         tree)
     text = jitted.lower(*on_chip(args), **kwargs).compile().as_text()
-    assert ("while" in text) == (block < cfg.block_size)
+    assert "while" in text
     assert not slice_sized(text, lanes, cfg.block_size)
 
     def laid_over(cache, rows, positions, q):

@@ -121,7 +121,8 @@ def test_the_read_row_share_reader_reads_the_scheduler_s_counters(cell_run):
     play.trace_open, play.trace_close = {"steps": 1}, {"steps": 9}
     assert read({"play": play}) is None           # the parent's summary
     assert read({"play": None}) is None
-    # the rehearsal's slots are one block: every step reads them whole
+    # the rehearsal's tiny slots are read in one pass (a house of a few KB:
+    # ``attention.step_block``): every step reads them whole
     run = cell_run["evidence"]["play"]
     opened, closed = run.open_counters, run.close_counters
     untraced = dataclasses.replace(run, trace_open=None, trace_close=None)

@@ -197,15 +197,15 @@ def test_a_long_prefill_walks_the_slice_in_blocks(monkeypatch):
 
 
 @pytest.mark.parametrize("walk", [False, True], ids=["one-pass", "walked"])
-def test_the_two_part_step_is_the_laid_over_step(walk, monkeypatch):
+def test_the_two_part_step_is_the_laid_over_step(walk, walk_in_blocks):
     """``latent_attend_step`` reads the cache as it lies and attends each
     lane's own new row beside it; what it replaced laid the new rows over
     the slice (``_lay_rows_over``) and attended that in one pass. The same
     sums in another order: float32 rounding apart."""
-    if walk:
-        monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", 8)
     keys = jax.random.split(jax.random.key(6), 6)
     lanes, rows, heads, r, e = 3, 32, 4, 16, 4
+    # walked, or as the rule has so small a house: in one pass
+    plan = walk_in_blocks(8)(rows) if walk else attn_ops.StepWalk(rows, 1, 0)
     q_lat = jax.random.normal(keys[0], (lanes, 1, heads, r))
     q_pe = jax.random.normal(keys[1], (lanes, 1, heads, e))
     latents = jax.random.normal(keys[2], (2, lanes, rows, 1, r))
@@ -214,17 +214,18 @@ def test_the_two_part_step_is_the_laid_over_step(walk, monkeypatch):
     new_pe = jax.random.normal(keys[5], (lanes, 1, 1, e))
     positions = jnp.array([0, 13, rows - 1])
     got = attn_ops.latent_attend_step(
-        q_lat, q_pe, latents, pes, 1, new, new_pe, positions, scale=0.2)
+        q_lat, q_pe, latents, pes, 1, new, new_pe, positions, plan,
+        scale=0.2)
     want = attn_ops.latent_attention(
         q_lat, q_pe, gen._lay_rows_over(latents[1], new, positions),
         gen._lay_rows_over(pes[1], new_pe, positions), kv_offset=positions,
         scale=0.2)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
-    # a frontier short of a lane's position cuts that lane alone
+    # a reach short of a lane's position cuts that lane alone
     if walk:
         short = attn_ops.latent_attend_step(
-            q_lat, q_pe, latents, pes, 1, new, new_pe, positions,
-            frontier=13, scale=0.2)
+            q_lat, q_pe, latents, pes, 1, new, new_pe, positions, plan,
+            frontier=jnp.minimum(positions, 13), scale=0.2)
         np.testing.assert_array_equal(short[:2], got[:2])
         assert not np.array_equal(short[2], got[2])
 
@@ -239,12 +240,12 @@ def slice_sized_selects(text, lanes, rows):
         re.M)
 
 
-def test_the_decode_program_selects_nothing_of_a_slice_s_size(monkeypatch):
+def test_the_decode_program_selects_nothing_of_a_slice_s_size(walk_in_blocks):
     """The engine's decode program for a latent pool, lowered and compiled
     for this backend: no ``select`` of a ``(lanes, rows, 1, size)`` operand
     (the laid-over form had one a leaf and layer, and on the chip it bound
     the read: PERF.md, PRs 30 and 33). The old form is the control."""
-    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", 16)
+    walk_in_blocks(16)
     cfg, params = model()
     eng = DecodeEngine(params, cfg, n_slots=5, prefill_len=32)
     (_, _, jitted, args, kwargs), = [
@@ -302,13 +303,13 @@ def test_the_decode_program_copies_nothing_of_an_expert_stack_s_size():
 @pytest.mark.parametrize("furthest", [15, 16, 17],
                          ids=["below-an-edge", "on-an-edge", "above-an-edge"])
 def test_the_engine_s_step_under_a_live_mask_is_its_step_under_all_true(
-        furthest, monkeypatch):
+        furthest, walk_in_blocks):
     """Through ``DecodeEngine.decode_step`` on the whole tiny model (a
     dense layer, routed layers): the live lanes' tokens equal and their
     written rows equal to 1e-6 (the routed layers' grouped matmuls see the
     dead lanes' other rows beside them). A live lane at the window's last
     row beside parked lanes reads its whole slot."""
-    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", 16)
+    walk_in_blocks(16)
     cfg, params = model()
     eng = DecodeEngine(params, cfg, n_slots=4, prefill_len=32)
     keys = jax.random.split(jax.random.key(8), 2)

@@ -384,20 +384,22 @@ BEFORE = {
                         moe_scoring="sigmoid", moe_route_scale=2.448),
     "minicpm-tiny": dict(model_type="minicpm-sala-tiny"),
 }
-#: sha256 of the three programs' jaxprs, made on the commit before this PR
-#: (5f3af14) by this file's ``digest`` and equal on this one: ``gpt.forward``,
-#: the cached forward of a chunk (a scalar offset) and of a decode step (a
-#: position a lane). A PR that changes one of these programs on purpose
-#: makes them again.
+#: sha256 of the programs' jaxprs by this file's ``digest``: ``gpt.forward``
+#: with the cached forward of a chunk (a scalar offset), and the cached
+#: forward of a decode step (a position a lane). The first made on the commit
+#: before PR 45 (182a0b7) and equal on this one; the step's made again by PR
+#: 45, which changed it on purpose for every stack but the hybrid (the walk
+#: over lanes and blocks: tests/test_lane_walk.py). A PR that changes one of
+#: these programs on purpose makes them again.
 DIGESTS = {
-    "gpt2": "ee1044f40014eb22",
-    "rope-dense": "ab011a3aae61c1f0",
-    "kanana-tiny": "7a026b2845a822d8",
-    "minicpm-tiny": "89f02437ace6b642",
+    "gpt2": ("619763836527199f", "6abd6276e2abe99c"),
+    "rope-dense": ("8f70e9db6dc0658d", "f84bf78857dd164e"),
+    "kanana-tiny": ("b4c18bd3cd603c4a", "ae2c9fac9d3e1978"),
+    "minicpm-tiny": ("3073a533c705512c", "efe5947757312c99"),
 }
 
 
-def digest(cfg: GPTConfig) -> str:
+def digest(cfg: GPTConfig):
     params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
     cache = jax.eval_shape(lambda: dict(
         gen.init_cache(cfg, 3),
@@ -416,8 +418,9 @@ def digest(cfg: GPTConfig) -> str:
             p, t, c, o, cfg, valid=jnp.ones(t.shape, bool)))(
                 params, ids(3, 1), cache, ids(3)),
     ]
-    text = re.sub(r"0x[0-9a-f]+", "", "\n".join(map(str, texts)))
-    return hashlib.sha256(text.encode()).hexdigest()[:16]
+    sha = lambda ts: hashlib.sha256(re.sub(
+        r"0x[0-9a-f]+", "", "\n".join(map(str, ts))).encode()).hexdigest()[:16]
+    return sha(texts[:2]), sha(texts[2:])
 
 
 @pytest.mark.parametrize("arch", sorted(BEFORE))

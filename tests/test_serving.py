@@ -1073,8 +1073,9 @@ def test_spec_constructor_validation(cfg_params):
 
 
 # ---------------------------------------------------------------------------
-# the decode step reads the cache as it lies, as far as the live lanes stand
-# (PR 33)
+# the decode step reads the cache as it lies (PR 33), the lanes that hold a
+# request alone, each in blocks to its own position (PR 45; the walk itself:
+# tests/test_lane_walk.py)
 # ---------------------------------------------------------------------------
 
 STEP_BLOCK, STEP_ROWS = 16, 64
@@ -1122,22 +1123,24 @@ def lanes_step(cfg, params, cache, tokens, positions, live):
                               "two-blocks-on"])
 @pytest.mark.parametrize("form", sorted(STEP_FORMS))
 def test_a_step_under_a_live_mask_is_the_step_under_all_true(
-        form, furthest, monkeypatch):
-    """The walk stops at the furthest live lane; rows past it are masked
-    for every live lane and add exactly 0 under a block loop: logits and
-    written rows of the live lanes are the full walk's, bit for bit. A
-    lane that is not live (parked, or standing further on) is read short
-    and is nobody's."""
-    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", STEP_BLOCK)
+        form, furthest, walk_in_blocks):
+    """The walk takes a live lane in blocks to its own position whatever
+    the other lanes do: its logits and written rows are those of the step
+    with every lane live, bit for bit. A lane that is not live (parked, or
+    standing further on) is passed by and is nobody's."""
+    walk = walk_in_blocks(STEP_BLOCK)(STEP_ROWS)
     cfg, params = step_model(form)
     cache = stale_pool(cfg, 4)
+    assert gen.cache_walk(cfg, cache) == walk
     tokens = np.array([3, 9, 27, 41], np.int32)
     positions = np.array([furthest, 5, STEP_ROWS - 1, 50], np.int32)
     live = np.array([True, True, False, False])
-    want_rows = -(-furthest // STEP_BLOCK) * STEP_BLOCK
-    assert engine_mod.decode_rows_read(positions, live, cfg) == want_rows
+    want_rows = -(-furthest // STEP_BLOCK) * STEP_BLOCK + STEP_BLOCK
+    assert engine_mod.decode_rows_read(positions, live, walk) == want_rows
     assert engine_mod.decode_rows_read(
-        positions, np.ones(4, bool), cfg) == STEP_ROWS
+        positions, np.ones(4, bool), walk) == want_rows + 2 * STEP_ROWS
+    assert engine_mod.decode_rows_read(positions, None, walk) \
+        == want_rows + 2 * STEP_ROWS
     got, got_cache = lanes_step(cfg, params, cache, tokens, positions, live)
     want, want_cache = lanes_step(cfg, params, cache, tokens, positions,
                                   np.ones(4, bool))
@@ -1146,16 +1149,15 @@ def test_a_step_under_a_live_mask_is_the_step_under_all_true(
         for lane in (0, 1):
             np.testing.assert_array_equal(
                 got_cache[name][:, lane], want_cache[name][:, lane])
-    if want_rows < 48:
-        # the lane at 50 was cut short: the bound is real
-        assert not np.array_equal(got[3], want[3])
+    # the lane at 50 was passed by: it attended its own row alone
+    assert not np.array_equal(got[3], want[3])
 
 
 @pytest.mark.parametrize("form", sorted(STEP_FORMS))
-def test_a_live_lane_at_the_last_row_attends_all_its_rows(form, monkeypatch):
+def test_a_live_lane_at_the_last_row_attends_all_its_rows(form, walk_in_blocks):
     """A request's last step stands where free lanes are parked: liveness
-    is the mask's to say, not the position's. Beside parked lanes it reads
-    the whole slot, and what it computes is what it computes alone in a
+    is the mask's to say, not the position's. Beside parked lanes it alone
+    is read, the whole slot, and what it computes is what it computes in a
     pool of one block."""
     cfg, params = step_model(form)
     cache = stale_pool(cfg, 3)
@@ -1163,10 +1165,10 @@ def test_a_live_lane_at_the_last_row_attends_all_its_rows(form, monkeypatch):
     positions = np.full(3, STEP_ROWS - 1, np.int32)
     live = np.array([True, False, False])
     one_pass, _ = lanes_step(cfg, params, cache, tokens, positions, live)
-    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", STEP_BLOCK)
-    assert engine_mod.decode_rows_read(positions, live, cfg) == STEP_ROWS
+    walk = walk_in_blocks(STEP_BLOCK)(STEP_ROWS)
+    assert engine_mod.decode_rows_read(positions, live, walk) == STEP_ROWS
     assert engine_mod.decode_rows_read(
-        positions, np.zeros(3, bool), cfg) == 0
+        positions, np.zeros(3, bool), walk) == 0
     walked, _ = lanes_step(cfg, params, cache, tokens, positions, live)
     np.testing.assert_allclose(walked[0], one_pass[0], rtol=2e-5, atol=2e-6)
     # a stale row inside the mask does move the lane: all 63 are attended
@@ -1176,8 +1178,8 @@ def test_a_live_lane_at_the_last_row_attends_all_its_rows(form, monkeypatch):
 
 
 @pytest.mark.parametrize("form", sorted(STEP_FORMS))
-def test_a_lane_that_is_not_live_changes_no_live_lane(form, monkeypatch):
-    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", STEP_BLOCK)
+def test_a_lane_that_is_not_live_changes_no_live_lane(form, walk_in_blocks):
+    walk_in_blocks(STEP_BLOCK)
     cfg, params = step_model(form)
     cache = stale_pool(cfg, 3)
     live = np.array([True, True, False])
@@ -1196,13 +1198,11 @@ def test_a_lane_that_is_not_live_changes_no_live_lane(form, monkeypatch):
 @pytest.mark.parametrize("kv_heads", [4, 2], ids=["mha", "gqa"])
 @pytest.mark.parametrize("walk", [False, True], ids=["one-pass", "walked"])
 def test_the_two_part_step_is_the_laid_over_step(case, kv_heads, walk,
-                                                 monkeypatch):
+                                                 walk_in_blocks):
     """``causal_attend_step`` against what it replaced: the new rows laid
     over the slice (``_lay_rows_over``) and ``causal_attention`` under a
     position a lane. Float32 rounding apart: the same sums in another
     order."""
-    if walk:
-        monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", 8)
     keys = jax.random.split(jax.random.key(4), 5)
     lanes, rows, heads, size = 3, 32, 4, 8
     q = jax.random.normal(keys[0], (lanes, 1, heads, size))
@@ -1211,8 +1211,12 @@ def test_the_two_part_step_is_the_laid_over_step(case, kv_heads, walk,
     k_new, v_new = (jax.random.normal(k, (lanes, 1, kv_heads, size))
                     for k in keys[3:5])
     positions = jnp.array([0, 13, rows - 1])
+    # walked, or by the rule itself: so small a house is read in one pass
+    walk = walk_in_blocks(8)(rows) if walk else attn_ops.step_walk(
+        (k_cache.shape, v_cache.shape), 4)
+    assert walk.block == (8 if walk.row_bytes > 1 << 30 else 0)
     got = attn_ops.causal_attend_step(
-        q, k_cache, v_cache, 1, k_new, v_new, positions, **case)
+        q, k_cache, v_cache, 1, k_new, v_new, positions, walk, **case)
     want = attn_ops.causal_attention(
         q, gen._lay_rows_over(k_cache[1], k_new, positions),
         gen._lay_rows_over(v_cache[1], v_new, positions),
@@ -1220,21 +1224,21 @@ def test_the_two_part_step_is_the_laid_over_step(case, kv_heads, walk,
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
 
 
-def walked_server(form, monkeypatch, **kwargs):
-    monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", STEP_BLOCK)
+def walked_server(form, walk_in_blocks, **kwargs):
+    walk_in_blocks(STEP_BLOCK)
     cfg, params = step_model(form)
     return cfg, params, InferenceServer(
         params, cfg, prefill_buckets=(8, 32), **kwargs)
 
 
 @pytest.mark.parametrize("form", sorted(STEP_FORMS))
-def test_one_decode_program_whatever_the_frontier(form, monkeypatch):
+def test_one_decode_program_whatever_the_frontier(form, walk_in_blocks):
     """``live`` is always an argument of the one decode program: rounds
-    that stop at different blocks, the warm-up's round with no lane live
-    and a caller that names no mask all run the same executable, and the
-    tokens are solo ``generate``'s."""
+    that walk other lanes to other blocks, the warm-up's round with no
+    lane live and a caller that names no mask all run the same executable,
+    and the tokens are solo ``generate``'s."""
     cfg, params, server = walked_server(
-        form, monkeypatch, n_slots=3, warmup=True, recompile_fail=True)
+        form, walk_in_blocks, n_slots=3, warmup=True, recompile_fail=True)
     before = server.compile_counts()
     assert before["decode"] == 1
     prompts = [list(range(1, 4)), list(range(5, 25)), list(range(9, 42))]
@@ -1247,8 +1251,11 @@ def test_one_decode_program_whatever_the_frontier(form, monkeypatch):
         active = st.decoding_slots()
         if active:
             seen.add(int(engine_mod.decode_rows_read(
-                st.positions, np.isin(np.arange(3), active), cfg)))
-    assert seen == {STEP_BLOCK, 2 * STEP_BLOCK, 3 * STEP_BLOCK}
+                st.positions, np.isin(np.arange(3), active),
+                server.engine.walk)))
+    # three lanes in the first, second and third block; then two; then
+    # the first alone, which ends in its own first block
+    assert seen == {6 * STEP_BLOCK, 3 * STEP_BLOCK, STEP_BLOCK}
     for h, p, n in zip(handles, prompts, budgets):
         assert h.tokens == solo_greedy(params, cfg, p, n)
     s = server.engine.n_slots
@@ -1262,30 +1269,34 @@ def test_one_decode_program_whatever_the_frontier(form, monkeypatch):
     assert sum(child.value for _, child in fam.children()) == 0
 
 
-def test_the_decode_rows_counters_count_what_the_program_reads(monkeypatch):
-    """``decode_rows_read`` over ``decode_rows_reserved``: equal where a
-    slot is one block (every step reads it whole), a known fraction where
-    the walk stops: one request that never leaves the first of four
-    blocks reads a quarter."""
+def test_the_decode_rows_counters_count_what_the_program_reads(
+        walk_in_blocks):
+    """``decode_rows_read`` over ``decode_rows_reserved``: equal where the
+    slices are not walked (a pool of a few KB is read in one pass, and two
+    heads of 8 an axis entry each lie positions minor on the chip besides:
+    every step reads every slot whole), a known fraction
+    where they are: one request in two slots that never leaves the first
+    of four blocks reads a quarter of its slot and nothing of the other."""
     cfg, params = step_model("per-head")
     whole = InferenceServer(params, cfg, n_slots=2)
     whole.generate_batch([Request(prompt=[1, 2, 3], max_new_tokens=5)])
     got = whole.summary()
     assert got["decode_rows_read"] == got["decode_rows_reserved"] \
         == 4 * 2 * STEP_ROWS
-    _, _, server = walked_server("per-head", monkeypatch, n_slots=2,
+    _, _, server = walked_server("per-head", walk_in_blocks, n_slots=2,
                                  warmup=True)
     assert server.summary()["decode_rows_reserved"] == 0  # the warm-up's
     server.generate_batch([Request(prompt=[1, 2, 3], max_new_tokens=5)])
     got = server.summary()
     assert got["decode_rows_reserved"] == 4 * 2 * STEP_ROWS
-    assert got["decode_rows_read"] * 4 == got["decode_rows_reserved"]
-    # a second request that stands in the third block: three quarters
+    assert got["decode_rows_read"] == 4 * STEP_BLOCK
+    # a second request that stands in the third block: three quarters of
+    # its slot
     server.generate_batch([Request(prompt=list(range(1, 36)),
                                    max_new_tokens=3)])
     after = server.summary()
     assert after["decode_rows_read"] - got["decode_rows_read"] \
-        == 2 * 2 * 3 * STEP_BLOCK
+        == 2 * 3 * STEP_BLOCK
     from mingpt_distributed_tpu.telemetry.export import render_prometheus
     text = render_prometheus(server.metrics.registry)
     assert f"mingpt_serve_decode_rows_read_total {after['decode_rows_read']}" \

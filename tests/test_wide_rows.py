@@ -142,14 +142,14 @@ def test_the_per_head_leaf_touched_eight_times_the_tiles(monkeypatch):
 @pytest.mark.parametrize("kv_heads", [4, 2, 1], ids=["mha", "gqa", "mqa"])
 @pytest.mark.parametrize("walk", [False, True], ids=["one-pass", "walked"])
 def test_the_step_over_a_wide_cache_is_the_step_over_a_per_head_one(
-        case, kv_heads, walk, monkeypatch):
+        case, kv_heads, walk, walk_in_blocks):
     """``causal_attend_step`` reads whole rows where they lie, the queries
     spread to the rows' width: the per-head sums with zero products beside
     them, so equal to float32 rounding."""
-    if walk:
-        monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", 8)
     keys = jax.random.split(jax.random.key(4), 5)
     lanes, rows, heads, size = 3, 32, 4, 8
+    # walked, or as the rule has so small a house: in one pass
+    walk = walk_in_blocks(8)(rows) if walk else attn_ops.StepWalk(rows, 1, 0)
     q = jax.random.normal(keys[0], (lanes, 1, heads, size))
     k_cache, v_cache = (jax.random.normal(k, (2, lanes, rows, kv_heads, size))
                         for k in keys[1:3])
@@ -158,10 +158,10 @@ def test_the_step_over_a_wide_cache_is_the_step_over_a_per_head_one(
     positions = jnp.array([0, 13, rows - 1])
     side_by_side = lambda a: a.reshape(*a.shape[:-2], 1, kv_heads * size)
     want = attn_ops.causal_attend_step(
-        q, k_cache, v_cache, 1, k_new, v_new, positions, **case)
+        q, k_cache, v_cache, 1, k_new, v_new, positions, walk, **case)
     got = attn_ops.causal_attend_step(
         q, side_by_side(k_cache), side_by_side(v_cache), 1,
-        side_by_side(k_new), side_by_side(v_new), positions, **case)
+        side_by_side(k_new), side_by_side(v_new), positions, walk, **case)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
 
 
@@ -204,13 +204,13 @@ def cached_run(cfg, params, tokens, cache):
 @pytest.mark.parametrize("walk", [False, True], ids=["one-pass", "walked"])
 @pytest.mark.parametrize("form", WIDE)
 def test_prefill_then_decode_over_wide_rows_is_the_per_head_cache_s(
-        form, walk, monkeypatch):
+        form, walk, monkeypatch, walk_in_blocks):
     """Logits of every program and the rows left in the cache are the
     per-head cache's: bit for bit after the chunks (a chunk views its
     lane's rows as heads), to float32 rounding after the steps (whole rows
     against spread queries), and the full forward's likewise."""
     if walk:
-        monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", 8)
+        walk_in_blocks(8)
     cfg, params = model(form)
     tokens = jax.random.randint(jax.random.key(2), (2, 13), 0, cfg.vocab_size)
     counters = {gen.LOOP_PASSES: gen.init_loop_passes(cfg)} \
@@ -445,20 +445,46 @@ def test_tp_shards_the_axis_that_holds_the_heads(form, over, tp, axis, shards,
 
 # -- the models this PR passes by trace to the parent's programs -------------------
 
-#: sha256 of ``digest``'s jaxprs on the commit before this PR (c62d8ed), made
-#: by this function and equal on this one: a latent, a hybrid stack's rows
-#: beside a state, heads of 128 (looped and not), widths of no whole tiles
-#: (five heads of 64, as GPT-2 XL's 25) are what they were; so is
-#: ``gpt.forward`` of the models whose cached forward did change. A PR that
-#: changes one of these programs on purpose makes them again.
-PARENT_DIGESTS = {
-    "latent": "95755085e84810af",
-    "hybrid": "96b6e9c29447a695",
-    "looped-heads-of-128": "73823fb873a5bc56",
-    "heads-of-128": "77a8654dfb68e62e",
-    "five-heads-of-64": "eb0810e385b4a130",
-    "narrow": "4ab75d5844af7979",
-    "mqa-rope": "3d792133826782ff",
+#: sha256 of ``digest``'s jaxprs. The prefill programs: on the commit before
+#: PR 45 (182a0b7), made by this function there and equal on this one, for
+#: every form: since PR 39 only the decode step has changed. The decode
+#: program: made again by PR 45, which changed it on purpose for every form.
+#: A pool of three tiny slots is read in one pass (``attention.step_block``),
+#: the parent's read with each lane's reach where the scalar frontier was, a
+#: dead equation there; a hybrid stack loses the frontier's two. The walk
+#: over lanes and blocks itself is WALKED_DECODE_DIGESTS: the same programs
+#: under ``walk_in_blocks(8)``, where a leaf that lies positions minor on the
+#: chip (five heads of 64, GPT-2 XL's 25) is walked too. A PR that changes one
+#: of these programs on purpose makes them again.
+PARENT_PREFILL_DIGESTS = {
+    "five-heads-of-64": "e48b504c543f2155",
+    "gqa-rope": "996c36df05f920bc",
+    "heads-of-128": "c695671b5cdd4b04",
+    "hybrid": "dd762e1e02072ad3",
+    "latent": "a4f7c3c970e024fc",
+    "looped": "7a71c6b7f8d1cf85",
+    "looped-heads-of-128": "c59d0a1539fd91b5",
+    "mha": "7896012cc8a4b9ed",
+    "mqa-rope": "9cfc64d4c0a56333",
+    "narrow": "384a636bce36f32a",
+    "two-tiles": "4a1b36b0e7d9a8ca",
+    "window-softcap": "034f2065e8be1a07",
+}
+DECODE_DIGESTS = {
+    "latent": "90715eb71d7c562a",
+    "hybrid": "6fcfdcf8a1b7f29c",
+    "looped-heads-of-128": "69d421053ab592fe",
+    "heads-of-128": "3be9b1ee5821a527",
+    "five-heads-of-64": "023e4278f7c3f7b7",
+    "narrow": "dceeb701a0e6a7a2",
+    "mqa-rope": "8fcb717b485f474d",
+}
+WALKED_DECODE_DIGESTS = {
+    "latent": "4a28bd5ebf57950b",
+    "looped-heads-of-128": "2d6d126da1b9ff6d",
+    "heads-of-128": "934bd3b284cf760c",
+    "mha": "01a10a357520b3da",
+    "mqa-rope": "d255c0bdce06b01c",
 }
 PARENT_FORWARD_DIGESTS = {
     "mha": "4df8e026ac68301c",
@@ -478,24 +504,45 @@ def forward_digest(cfg: GPTConfig) -> str:
         params, jax.ShapeDtypeStruct((2, 16), jnp.int32))])
 
 
-def digest(cfg: GPTConfig) -> str:
-    """The engine's own prefill and decode programs over a 3-slot pool. The
-    decode program is traced with the eleven arguments the parent's had:
-    without the step's tokens and their mask (PR 43: the last two, one
-    ``select`` at the program's head; tests/test_run_ahead.py holds that it
-    is all they add) it is the parent's program still."""
+def digest(cfg: GPTConfig, decode: bool) -> str:
+    """The engine's own decode program, or its prefill programs, over a
+    3-slot pool. The decode program is traced with the eleven arguments
+    PR 39's had: without the step's tokens and their mask (PR 43: the last
+    two, one ``select`` at the program's head; tests/test_run_ahead.py
+    holds that it is all they add)."""
     params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
     engine = DecodeEngine(
         jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params), cfg,
         n_slots=3, prefill_buckets=(8, 16))
     return _sha([jitted.trace(*args[:11], **kwargs).jaxpr
-                 for _, _, jitted, args, kwargs in engine.programs()])
+                 for name, _, jitted, args, kwargs in engine.programs()
+                 if (name == "decode") == decode])
 
 
-@pytest.mark.parametrize("form", sorted(PARENT_DIGESTS))
-def test_a_model_whose_rows_were_wide_or_whole_runs_the_parent_s_programs(form):
+@pytest.mark.parametrize("form", sorted(PARENT_PREFILL_DIGESTS))
+def test_every_prefill_program_is_the_parent_s(form):
     cfg, _ = model(form)
-    assert digest(cfg) == PARENT_DIGESTS[form]
+    assert digest(cfg, decode=False) == PARENT_PREFILL_DIGESTS[form]
+
+
+@pytest.mark.parametrize("form", sorted(DECODE_DIGESTS))
+def test_a_model_whose_rows_were_wide_or_whole_runs_the_parent_s_programs(form):
+    """The decode programs of the forms PR 39 passed by, as PR 45 made
+    them again."""
+    cfg, _ = model(form)
+    assert digest(cfg, decode=True) == DECODE_DIGESTS[form]
+
+
+@pytest.mark.parametrize("form", sorted(WALKED_DECODE_DIGESTS))
+def test_the_walk_s_own_equations_are_pinned(form, walk_in_blocks):
+    """The equations of the two loops and their plan, at a block of 8 rows
+    a tiny slice (``walk_in_blocks``: the chip's programs walk 512 and 1,024
+    by the same code, and tiny heads not at all). Equal, under a rule that
+    gives both the same walk, to those of the tree PERF.md's numbers for
+    PR 45 were measured on."""
+    walk_in_blocks(8)
+    cfg, _ = model(form)
+    assert digest(cfg, decode=True) == WALKED_DECODE_DIGESTS[form]
 
 
 @pytest.mark.parametrize("form", sorted(PARENT_FORWARD_DIGESTS))
@@ -505,11 +552,18 @@ def test_training_s_forward_is_the_parent_s(form):
 
 
 def test_the_per_head_rule_traces_to_the_parent_s_gpt2_programs(monkeypatch):
-    """One algorithm: with the rule as it was, the code of this PR traces
-    to the parent's programs for the models it moved."""
+    """One algorithm: with the rule as it was, the code of PR 39 traced to
+    its parent's programs for the models it moved. Their prefill programs
+    still do; the decode programs are PR 45's (a per-head leaf of heads
+    under a lane tile is read in one pass, the parent's read less the dead
+    frontier)."""
     per_head(monkeypatch)
-    assert digest(model("mha")[0]) == "158cc664c272be9a"
-    assert digest(model("gqa-rope")[0]) == "a65d70c3b6905a70"
+    for form, prefill, decode in (
+            ("mha", "e8a706ae5decb1b3", "b2ec58f36ce86c36"),
+            ("gqa-rope", "a742b814360f967c", "44be29f2a25c525a")):
+        cfg, _ = model(form)
+        assert digest(cfg, decode=False) == prefill
+        assert digest(cfg, decode=True) == decode
 
 
 # -- the check holds the rows, whichever way they lie ------------------------------
