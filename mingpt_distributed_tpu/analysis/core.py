@@ -147,9 +147,6 @@ class Config:
         "mingpt_distributed_tpu/analysis/",   # lint reports go to stdout
         "telemetry/spans.py",                 # log_event's own print
     )
-    # GL004: compile-behaviour experiment scripts construct jits in
-    # loops on purpose (they measure exactly that)
-    jit_loop_exempt_paths: Tuple[str, ...] = ("tools/exp_", "tools/proto_")
 
     def clock_in_scope(self, relpath: str) -> bool:
         return _match_any(relpath, self.clock_paths)
@@ -160,9 +157,6 @@ class Config:
     def print_in_scope(self, relpath: str) -> bool:
         return (_match_any(relpath, self.print_paths)
                 and not _match_any(relpath, self.print_exempt_paths))
-
-    def jit_loop_in_scope(self, relpath: str) -> bool:
-        return not _match_any(relpath, self.jit_loop_exempt_paths)
 
 
 # ---------------------------------------------------------------------

@@ -20,8 +20,7 @@ the three coding patterns that cause them, at review time:
   ``for``/``while`` body. A fresh jit wrapper has a fresh trace cache,
   so per-step/per-request construction recompiles every iteration —
   the serving engine's whole design (two lifetime-compiled programs) is
-  the counter-pattern. Compile-behaviour experiments under
-  ``tools/exp_*`` do this on purpose and are exempt by config.
+  the counter-pattern.
 * **GL005 unhashable-static** — a list/dict/set literal passed at a
   ``static_argnums``/``static_argnames`` position of a module-local
   jitted callable. Static args are cache keys; unhashables raise at
@@ -132,8 +131,6 @@ class JitInLoopRule(Rule):
             "iteration; hoist construction out of the loop")
 
     def check_file(self, ctx: FileContext) -> List[Finding]:
-        if not ctx.config.jit_loop_in_scope(ctx.relpath):
-            return []
         findings: List[Finding] = []
         # walk with an explicit loop-depth stack, resetting at function
         # boundaries (a jit built in a def that happens to be defined in

@@ -589,6 +589,10 @@ class GPTConfig:
             raise ConfigError(
                 "a hybrid stack keeps per-head rows and a dense MLP: "
                 "latent attention and experts are not written for it")
+        if self.rope_interleave:
+            raise ConfigError(
+                "a hybrid stack's lightning layers rotate the halves of a "
+                "head: rope_interleave is not written for it")
         if self.pp_microbatches:
             raise ConfigError(
                 "a hybrid stack is not pipelined (pp_microbatches): the "

@@ -259,16 +259,17 @@ def _cached_block(
     frontier: Optional[jax.Array] = None,  # (B,): how far each lane is read
     walk: Optional[attn_ops.StepWalk] = None,  # and how (``cache_walk``)
 ) -> Tuple[jax.Array, Cache, Cache, Optional[jax.Array]]:
-    """One pre-LN block against the cache. Returns (y, cache, rows,
-    counts): the block's own (B, T, heads, size) k/v ``rows`` in the
-    cache's dtype and row shape (``cache_leaf_shapes``: a position's
-    ``(kv_heads, head_dim)`` as computed, or the same numbers side by
-    side, ``(1, kv_heads x head_dim)``; the queries stay ``(B, T, H,
-    hd)``, a chunk's attention views its lane's rows as heads and the
-    decode step reads them whole), and a dropless expert layer's counts
-    of the ``valid``
-    tokens' routed rows (ops/moe.grouped_swiglu, which routes no other
-    token; None for any other MLP).
+    """One pre-LN block against the cache, ``gpt._block`` with another
+    middle word: norm, parts, attend the cache, out, add; norm, MLP, add
+    (``gpt.attention_parts``, ``attention_out``, ``mlp_branch``: the layer
+    is written there, once). Returns (y, cache, rows, counts): the block's
+    own (B, T, heads, size) k/v ``rows`` in the cache's dtype and row shape
+    (``cache_leaf_shapes``: a position's ``(kv_heads, head_dim)`` as
+    computed, or the same numbers side by side, ``(1, kv_heads x
+    head_dim)``; the queries stay ``(B, T, H, hd)``, a chunk's attention
+    views its lane's rows as heads and the decode step reads them whole),
+    and a dropless expert layer's counts of the ``valid`` tokens' routed
+    rows (``gpt.mlp_branch``).
 
     ``blk`` is the layer's weights and ``plane`` where its keys and values
     lie in the cache: the layer's own number where the layers run once, and
@@ -297,20 +298,14 @@ def _cached_block(
     :func:`cache_walk` of this cache; the serving engine works its pool's
     out once and hands the same one to the program and to its counter).
     It returns the cache as it came: the caller writes all planes' rows
-    after the last (``_write_lane_rows``). A capacity-routed expert MLP
-    routes each lane alone, since lanes are other users' requests: a
-    lane's routes must not depend on which other lanes are live. The
-    dropless route has that property by itself and takes the lanes' rows
-    together.
+    after the last (``_write_lane_rows``), and the capacity route takes
+    each lane alone (``mlp_branch``'s ``lanes_apart``).
 
     Latent attention (``cfg.kv_lora_rank``) caches a token's rotated rope
     key and normed latent and attends them absorbed: the queries go
     through W_UK to the latent's size, the heads average latents, and the
     averages go through W_UV. Per-head keys and values of the cache are
     never built, in prefill or in decode.
-
-    ``cfg.post_norms``: each sublayer's output is RMS-normed before it
-    joins the residual stream, as in ``gpt._block``.
     """
     b, t, _ = x.shape
     nh, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
@@ -319,10 +314,9 @@ def _cached_block(
         raise ValueError(f"a position a lane takes one token a lane, not {t}")
 
     h = gpt.sublayer_input(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
-    if cfg.rope:
-        rope = attn_ops.rope_tables(
-            jnp.asarray(offset)[..., None] + jnp.arange(t),
-            cfg.rope_dim, cfg.rope_theta)
+    rope = attn_ops.rope_tables(
+        jnp.asarray(offset)[..., None] + jnp.arange(t),
+        cfg.rope_dim, cfg.rope_theta) if cfg.rope else None
     if cfg.kv_lora_rank:
         # what is cached: "v" the normed latent, "k" the rotated rope key
         nope = cfg.qk_nope_head_dim
@@ -331,12 +325,7 @@ def _cached_block(
             cfg.kv_lora_rank, nh, nope + cfg.v_head_dim)
         q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, w_kv_b[..., :nope])
     else:
-        q = L.dense(h, blk["wq"], blk.get("bq")).reshape(b, t, nh, hd)
-        k = L.dense(h, blk["wk"], blk.get("bk")).reshape(b, t, kv, hd)
-        v = L.dense(h, blk["wv"], blk.get("bv")).reshape(b, t, kv, hd)
-        if cfg.rope:
-            q = attn_ops.apply_rope(q, *rope, cfg.rope_interleave)
-            k = attn_ops.apply_rope(k, *rope, cfg.rope_interleave)
+        q, k, v = gpt.attention_parts(h, blk, cfg, (nh, kv, hd), rope)
 
     # in the cache's row shape: a latent's parts have it, a per-head row
     # takes it here (heads side by side where the leaf keeps them so)
@@ -375,36 +364,11 @@ def _cached_block(
             kv_offset=offset, window=cfg.attention_window,
             logit_softcap=cfg.attn_logit_softcap,
         ).reshape(b, t, nh * hd)
-    att = L.dense(att, blk["wo"], blk.get("bo"))
-    if cfg.post_norms:
-        att = L.rms_norm(att, blk["ln1_post_scale"], eps=cfg.norm_eps)
-    x = x + att
+    x = x + gpt.attention_out(att, blk, cfg)
 
     h2 = gpt.sublayer_input(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
-    counts = None
-    if "w_router" in blk and cfg.moe_scoring == "sigmoid":
-        m, counts = gpt.routed_and_shared(h2, blk, cfg, valid, expert_layer)
-    elif "w_router" in blk:
-        from mingpt_distributed_tpu.ops import moe
-
-        def experts(tokens):
-            return moe.moe_mlp(
-                tokens, blk["w_router"], blk["w_e1"], blk["w_e2"],
-                top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
-                w_gate=blk.get("w_eg"),
-            )[0]
-
-        if per_lane:
-            m = jax.vmap(lambda lane: experts(lane[None])[0])(h2)
-        else:
-            m = experts(h2)
-    elif cfg.swiglu:
-        m = L.mlp_swiglu(h2, blk["w_gate"], blk["w_up"], blk["w_down"])
-    else:
-        m = L.mlp_gelu(h2, blk["w_fc"], blk.get("b_fc"), blk["w_proj"],
-                       blk.get("b_proj"))
-    if cfg.post_norms:
-        m = L.rms_norm(m, blk["ln2_post_scale"], eps=cfg.norm_eps)
+    m, _, counts = gpt.mlp_branch(h2, blk, cfg, valid=valid,
+                                  layer=expert_layer, lanes_apart=per_lane)
     return x + m, cache, rows, counts
 
 

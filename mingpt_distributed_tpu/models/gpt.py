@@ -38,6 +38,7 @@ from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.ops import lightning as lightning_ops
 from mingpt_distributed_tpu.ops import sparse_attention as sparse_ops
 from mingpt_distributed_tpu.ops import layers as L
+from mingpt_distributed_tpu.ops import moe
 from mingpt_distributed_tpu.parallel.mesh import BATCH_AXES
 
 Params = Dict[str, Any]
@@ -295,7 +296,7 @@ def _manual_sp_attention(cfg: GPTConfig):
 
     def fn(q, k, v, *, attn_pdrop=0.0, dropout_key=None, deterministic=True,
            window=None, logit_softcap=None):
-        # attention dropout composes here too (VERDICT r3 weak #4): the
+        # attention dropout composes here too: the
         # shard bodies take (pdrop, key) directly and fold the chunk /
         # head-group index in, so every (pair, head) mask is drawn exactly
         # once. NOTE: under pp the enclosing body_pp has already folded the
@@ -387,8 +388,6 @@ def routed_and_shared(h2, blk: Params, cfg: GPTConfig, valid=None,
     experts (ops/moe.moe_dropless) plus the shared expert every token
     takes. Returns (out, the route's counts). With ``layer``, the
     EXPERT_LEAVES of ``blk`` are the whole stack's."""
-    from mingpt_distributed_tpu.ops import moe
-
     m, counts = moe.moe_dropless(
         h2, blk["w_router"], blk["e_bias"], blk["w_eg"], blk["w_e1"],
         blk["w_e2"], top_k=cfg.moe_top_k, norm_topk=cfg.moe_norm_topk,
@@ -397,6 +396,97 @@ def routed_and_shared(h2, blk: Params, cfg: GPTConfig, valid=None,
         with jax.named_scope("moe_shared"):
             m = m + L.mlp_swiglu(h2, blk["w_sg"], blk["w_su"], blk["w_sd"])
     return m, counts
+
+
+def rotated(q, k, rope, cfg: GPTConfig):
+    """Per-head queries and keys turned by the caller's ``(cos, sin)``."""
+    cos, sin = rope
+    return (attn_ops.apply_rope(q, cos, sin, cfg.rope_interleave),
+            attn_ops.apply_rope(k, cos, sin, cfg.rope_interleave))
+
+
+def attention_parts(h, blk: Params, cfg: GPTConfig, heads, rope=None):
+    """What per-head attention makes of (B, T, D) normed activations before
+    it attends, the one place the projections are written (``latent_parts``
+    is the latent's form of it): q (B, T, H, hd) and k, v (B, T, KV, hd) for
+    the caller's ``heads`` = (H, KV, hd) (a manual-``tp`` shard passes its
+    own), queries and keys RMS-normed per head where ``blk`` carries the
+    scales (``qk_norm``), then rotated by ``rope`` (None: not rotated)."""
+    b, t, _ = h.shape
+    nh, kv, hd = heads
+    q = L.dense(h, blk["wq"], blk.get("bq")).reshape(b, t, nh, hd)
+    k = L.dense(h, blk["wk"], blk.get("bk")).reshape(b, t, kv, hd)
+    v = L.dense(h, blk["wv"], blk.get("bv")).reshape(b, t, kv, hd)
+    if "q_norm_scale" in blk:
+        q = L.rms_norm(q, blk["q_norm_scale"], eps=cfg.norm_eps)
+        k = L.rms_norm(k, blk["k_norm_scale"], eps=cfg.norm_eps)
+    if rope is not None:
+        q, k = rotated(q, k, rope, cfg)
+    return q, k, v
+
+
+def _row_parallel(x, w, b, tp_axis: Optional[str]):
+    """``L.dense``, its matmul summed over ``tp_axis`` (the manual megatron
+    recipe's one collective a branch) before the bias joins, so that the
+    bias is not multiplied by ``tp``."""
+    y = L.dense(x, w)
+    if tp_axis is not None:
+        y = jax.lax.psum(y, tp_axis)
+    return y if b is None else y + b.astype(y.dtype)
+
+
+def attention_out(att, blk: Params, cfg: GPTConfig,
+                  tp_axis: Optional[str] = None):
+    """Attention's (B, T, H * hd) output on its way back to the stream:
+    through ``wo`` and, under ``cfg.post_norms``, its RMS norm."""
+    att = _row_parallel(att, blk["wo"], blk.get("bo"), tp_axis)
+    if cfg.post_norms:
+        att = L.rms_norm(att, blk["ln1_post_scale"], eps=cfg.norm_eps)
+    return att
+
+
+def mlp_branch(h2, blk: Params, cfg: GPTConfig, *, valid=None, layer=None,
+               lanes_apart: bool = False, tp_axis: Optional[str] = None,
+               ep_axis: Optional[str] = None):
+    """A layer's MLP over (B, T, D) normed activations, whichever it has,
+    and its post-norm: (the branch, the capacity route's load-balancing
+    term (zero for any other), a dropless route's counts of the ``valid``
+    tokens' rows (``routed_and_shared``, which ``layer`` is for; None for
+    any other)). ``lanes_apart``: the rows are other users' requests, so
+    the capacity route, where a token's room depends on who else is routed,
+    takes each alone. ``tp_axis``, ``ep_axis``: ``_block``'s manual forms."""
+    aux, counts = jnp.zeros((), jnp.float32), None
+    if "w_router" in blk and cfg.moe_scoring == "sigmoid":
+        m, counts = routed_and_shared(h2, blk, cfg, valid, layer)
+    elif "w_router" in blk:
+        def experts(tokens):
+            return moe.moe_mlp(
+                tokens, blk["w_router"], blk["w_e1"], blk["w_e2"],
+                top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
+                w_gate=blk.get("w_eg"), ep_axis=ep_axis)
+
+        if lanes_apart:
+            m = jax.vmap(lambda lane: experts(lane[None])[0][0])(h2)
+        else:
+            m, aux = experts(h2)
+    elif cfg.swiglu and tp_axis is not None:
+        # gate and up each rounded to the compute dtype, where L.mlp_swiglu
+        # rounds their product once: kept until a cell runs pp x tp
+        # (ROADMAP.md, Design)
+        inner = jax.nn.silu(L.dense(h2, blk["w_gate"])) \
+            * L.dense(h2, blk["w_up"])
+        m = _row_parallel(inner, blk["w_down"], None, tp_axis)
+    elif cfg.swiglu:
+        m = L.mlp_swiglu(h2, blk["w_gate"], blk["w_up"], blk["w_down"])
+    elif tp_axis is not None:
+        inner = L.gelu(L.dense(h2, blk["w_fc"], blk.get("b_fc")))
+        m = _row_parallel(inner, blk["w_proj"], blk.get("b_proj"), tp_axis)
+    else:
+        m = L.mlp_gelu(h2, blk["w_fc"], blk.get("b_fc"), blk["w_proj"],
+                       blk.get("b_proj"))
+    if cfg.post_norms:
+        m = L.rms_norm(m, blk["ln2_post_scale"], eps=cfg.norm_eps)
+    return m, aux, counts
 
 
 def hybrid_layer_params(params: Params, cfg: GPTConfig, layer: int):
@@ -408,23 +498,15 @@ def hybrid_layer_params(params: Params, cfg: GPTConfig, layer: int):
 
 
 def mixer_qkv(u, blk: Params, cfg: GPTConfig, kind: str, positions):
-    """What a hybrid layer's mixer makes of (B, T, D) normed activations
-    before it mixes: per-head q (B, T, H, hd) and k, v (B, T, KV, hd),
+    """``attention_parts`` for a hybrid layer's mixer and its head counts:
     queries and keys RMS-normed per head (``qk_norm``) and, in a lightning
-    layer, rotated to ``positions`` ((T,) or (B, T)). A sparse layer's keys
-    are not rotated: normed is how it caches them."""
-    b, t, _ = u.shape
-    nh, kv, hd = cfg.mixer_heads(kind)
-    q = L.dense(u, blk["wq"]).reshape(b, t, nh, hd)
-    k = L.dense(u, blk["wk"]).reshape(b, t, kv, hd)
-    v = L.dense(u, blk["wv"]).reshape(b, t, kv, hd)
-    if cfg.qk_norm:
-        q = L.rms_norm(q, blk["q_norm_scale"], eps=cfg.norm_eps)
-        k = L.rms_norm(k, blk["k_norm_scale"], eps=cfg.norm_eps)
+    layer, rotated to ``positions`` ((T,) or (B, T)) by tables of its own
+    head size. A sparse layer's keys are not rotated: it caches them normed."""
+    heads = cfg.mixer_heads(kind)
+    q, k, v = attention_parts(u, blk, cfg, heads)
     if kind == LIGHTNING:
-        cos, sin = attn_ops.rope_tables(positions, hd, cfg.rope_theta)
-        q = attn_ops.apply_rope(q, cos, sin)
-        k = attn_ops.apply_rope(k, cos, sin)
+        q, k = rotated(q, k, attn_ops.rope_tables(
+            positions, heads[2], cfg.rope_theta), cfg)
     return q, k, v
 
 
@@ -475,7 +557,7 @@ def hybrid_mlp(x, mixed, blk: Params, cfg: GPTConfig):
     scale = cfg.residual_scale
     x = x + (scale * mixed).astype(x.dtype)
     h2 = L.rms_norm(x, blk["ln2_scale"], eps=cfg.norm_eps)
-    m = L.mlp_swiglu(h2, blk["w_gate"], blk["w_up"], blk["w_down"])
+    m, _, _ = mlp_branch(h2, blk, cfg)
     return x + (scale * m).astype(x.dtype)
 
 
@@ -515,18 +597,19 @@ def _block(
     tp_axis: Optional[str] = None,  # manual megatron-tp inside shard_map
     ep_axis: Optional[str] = None,  # manual expert parallelism in shard_map
 ) -> Tuple[jax.Array, jax.Array]:
-    """One pre-LN transformer block: x + attn(ln1(x)); x + mlp(ln2(x)).
-
-    Returns (x, aux): aux is the MoE load-balancing loss for this layer
-    (zero for dense MLPs) — accumulated across layers by the caller.
+    """One pre-LN transformer block over a whole sequence: norm, parts,
+    attend, out, add; norm, MLP, add. The residual sums, dropout and the
+    ``attn`` / ``mlp`` scopes are this body's; the rest is the functions
+    ``generate._cached_block`` calls too. Returns (x, aux): aux is the MoE
+    load-balancing loss for this layer (zero for dense MLPs), accumulated
+    across layers by the caller.
 
     ``tp_axis`` (inside an enclosing shard_map, e.g. the pipeline) runs the
     megatron recipe manually: this shard's weights hold n_head/tp heads and
     ffn/tp columns (column-parallel in, row-parallel out), activations stay
     replicated over tp, and the only tp collectives are one psum per
-    residual branch (after wo and after the MLP down-projection), applied
-    *before* the output bias so the bias isn't multiplied by tp."""
-    b, t, d = x.shape
+    residual branch (``_row_parallel``)."""
+    b, t, _ = x.shape
     nh, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
     if tp_axis is not None:
         assert not cfg.n_experts, "tp_axis doesn't compose with MoE blocks"
@@ -547,15 +630,8 @@ def _block(
         h = sublayer_input(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
         if cfg.kv_lora_rank:
             q, k, v = latent_qkv(h, blk, cfg, rope)
-            hd = cfg.v_head_dim
         else:
-            q = L.dense(h, blk["wq"], blk.get("bq")).reshape(b, t, nh, hd)
-            k = L.dense(h, blk["wk"], blk.get("bk")).reshape(b, t, kv, hd)
-            v = L.dense(h, blk["wv"], blk.get("bv")).reshape(b, t, kv, hd)
-            if rope is not None:
-                cos, sin = rope
-                q = attn_ops.apply_rope(q, cos, sin, cfg.rope_interleave)
-                k = attn_ops.apply_rope(k, cos, sin, cfg.rope_interleave)
+            q, k, v = attention_parts(h, blk, cfg, (nh, kv, hd), rope)
         # window/softcap compose with every attention impl, including the
         # manual-sp attn_fn override inside pipeline stages
         attn_kw = {}
@@ -564,54 +640,15 @@ def _block(
         if cfg.attn_logit_softcap:
             attn_kw["logit_softcap"] = cfg.attn_logit_softcap
         att = (attn_fn or _attention_dispatch(cfg, mesh))(
-            q, k, v,
-            attn_pdrop=cfg.attn_pdrop,
-            dropout_key=k_attn,
-            deterministic=deterministic,
-            **attn_kw,
-        ).reshape(b, t, nh * hd)
-        if tp_axis is not None:
-            att = jax.lax.psum(L.dense(att, blk["wo"]), tp_axis)
-            if blk.get("bo") is not None:
-                att = att + blk["bo"].astype(att.dtype)
-        else:
-            att = L.dense(att, blk["wo"], blk.get("bo"))
-        if cfg.post_norms:
-            att = L.rms_norm(att, blk["ln1_post_scale"], eps=cfg.norm_eps)
-        att = L.dropout(att, cfg.resid_pdrop, k_resid1, deterministic)
-        x = x + att
+            q, k, v, attn_pdrop=cfg.attn_pdrop, dropout_key=k_attn,
+            deterministic=deterministic, **attn_kw).reshape(b, t, -1)
+        att = attention_out(att, blk, cfg, tp_axis)
+        x = x + L.dropout(att, cfg.resid_pdrop, k_resid1, deterministic)
 
     with jax.named_scope("mlp"):
         h2 = sublayer_input(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
-        aux = jnp.zeros((), jnp.float32)
-        if "w_router" in blk and cfg.moe_scoring == "sigmoid":
-            m, _ = routed_and_shared(h2, blk, cfg)
-        elif "w_router" in blk:
-            from mingpt_distributed_tpu.ops import moe
-
-            m, aux = moe.moe_mlp(
-                h2, blk["w_router"], blk["w_e1"], blk["w_e2"],
-                top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
-                w_gate=blk.get("w_eg"), ep_axis=ep_axis,
-            )
-        elif cfg.swiglu:
-            if tp_axis is not None:
-                inner = jax.nn.silu(L.dense(h2, blk["w_gate"])) * L.dense(h2, blk["w_up"])
-                m = jax.lax.psum(L.dense(inner, blk["w_down"]), tp_axis)
-            else:
-                m = L.mlp_swiglu(h2, blk["w_gate"], blk["w_up"], blk["w_down"])
-        else:
-            if tp_axis is not None:
-                inner = L.gelu(L.dense(h2, blk["w_fc"], blk.get("b_fc")))
-                m = jax.lax.psum(L.dense(inner, blk["w_proj"]), tp_axis)
-                if blk.get("b_proj") is not None:
-                    m = m + blk["b_proj"].astype(m.dtype)
-            else:
-                m = L.mlp_gelu(h2, blk["w_fc"], blk.get("b_fc"), blk["w_proj"], blk.get("b_proj"))
-        if cfg.post_norms:
-            m = L.rms_norm(m, blk["ln2_post_scale"], eps=cfg.norm_eps)
-        m = L.dropout(m, cfg.resid_pdrop, k_resid2, deterministic)
-        return x + m, aux
+        m, aux, _ = mlp_branch(h2, blk, cfg, tp_axis=tp_axis, ep_axis=ep_axis)
+        return x + L.dropout(m, cfg.resid_pdrop, k_resid2, deterministic), aux
 
 
 @jax.named_scope("exit_gate")
@@ -772,7 +809,7 @@ def forward(
             if t % sp:
                 raise ValueError(f"T={t} not divisible by sp={sp} under pp")
             # (ulysses head-divisibility is checked below, tp-aware)
-        # ep x pp (VERDICT r3 next #6): expert leaves (w_e*) keep their ep
+        # ep x pp: expert leaves (w_e*) keep their ep
         # sharding through xs_specs; the MoE runs manual expert parallelism
         # inside the region (two all_to_alls over ep — ops/moe.py ep_axis)
         ep_n = mesh.shape.get("ep", 1)
@@ -784,9 +821,8 @@ def forward(
         manual_attn = _manual_sp_attention(cfg) if seq_sharded else None
 
         # --- keep tp/fsdp sharding LIVE inside the pipeline region --------
-        # (VERDICT r2 next #5). Megatron-tp is run manually when every
-        # split dimension divides; otherwise tp falls back to gathered
-        # (replicated) stage params, exactly the previous behaviour.
+        # Megatron-tp is run manually when every split dimension divides;
+        # otherwise tp falls back to gathered (replicated) stage params.
         # fsdp stays sharded per-leaf regardless and is all-gathered
         # per *layer* inside the scan (ZeRO-3-style JIT gather: one layer's
         # params live at a time; remat re-gathers in backward).

@@ -43,10 +43,9 @@ def eight_devices():
     return devs[:8]
 
 
-@pytest.fixture
-def walk_in_blocks(monkeypatch):
-    """``walk_in_blocks(rows)``: every walk worked out after it
-    (``attention.step_walk``: a bare step's, an engine's at its
+def walk_in_blocks_with(monkeypatch):
+    """``walk_in_blocks_with(monkeypatch)(rows)``: every walk worked out
+    after it (``attention.step_walk``: a bare step's, an engine's at its
     construction) is a ``StepWalk`` of ``rows``-row blocks, whatever the
     leaves; the rule itself reads tiny slices in one pass, as it does
     narrow per-head leaves. ``alone=True``, the default, gives the walk a
@@ -66,3 +65,9 @@ def walk_in_blocks(monkeypatch):
             lambda leaves, itemsize, latent=False: walk_of(leaves[0][2]))
         return walk_of
     return patch
+
+
+@pytest.fixture
+def walk_in_blocks(monkeypatch):
+    """:func:`walk_in_blocks_with` this test's ``monkeypatch``."""
+    return walk_in_blocks_with(monkeypatch)

@@ -360,6 +360,7 @@ def test_the_state_is_float32_and_fewer_bits_would_show(reference,
     (dict(attention_window=64), "no attention_window"),
     (dict(attn_logit_softcap=30.0), "no attention_window"),
     (dict(pp_microbatches=2), "not pipelined"),
+    (dict(rope_interleave=True), "rope_interleave is not written"),
     (dict(n_experts=4), "latent attention and experts are not written"),
     (dict(rope=False), "needs rope, rmsnorm and swiglu"),
     (dict(mixer_types=("lightning-attn",) * 4), "needs a sparse layer"),

@@ -5,7 +5,6 @@ a plane a pass and layer. CPU, tiny, float32, seeded weights, against the
 plain reference ``benchmarks/references/ouro.py``."""
 
 import dataclasses
-import hashlib
 import re
 
 import jax
@@ -19,6 +18,7 @@ from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.serving import InferenceServer, Request
+from program_digests import cached_digests
 
 PASSES, LAYERS, BLOCK, VOCAB = 4, 3, 64, 96
 LOOPED = dict(n_layer=LAYERS, n_head=4, n_embd=64, vocab_size=VOCAB,
@@ -384,9 +384,10 @@ BEFORE = {
                         moe_scoring="sigmoid", moe_route_scale=2.448),
     "minicpm-tiny": dict(model_type="minicpm-sala-tiny"),
 }
-#: sha256 of the programs' jaxprs by this file's ``digest``: ``gpt.forward``
-#: with the cached forward of a chunk (a scalar offset), and the cached
-#: forward of a decode step (a position a lane). The first made on the commit
+#: sha256 of the programs' jaxprs (``program_digests.cached_digests``; run
+#: that module and it prints this table): ``gpt.forward`` with the cached
+#: forward of a chunk (a scalar offset), and the cached forward of a decode
+#: step (a position a lane). The first made on the commit
 #: before PR 45 (182a0b7) and equal on this one; the step's made again by PR
 #: 45, which changed it on purpose for every stack but the hybrid (the walk
 #: over lanes and blocks: tests/test_lane_walk.py). A PR that changes one of
@@ -399,30 +400,6 @@ DIGESTS = {
 }
 
 
-def digest(cfg: GPTConfig):
-    params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
-    cache = jax.eval_shape(lambda: dict(
-        gen.init_cache(cfg, 3),
-        **{name: make(cfg) for name, make in (
-            (gen.MOE_ROWS, gen.init_moe_rows),
-            (gen.SPARSE_ROWS, gen.init_sparse_rows))
-           if make(cfg) is not None}))
-    ids = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    texts = [
-        jax.make_jaxpr(lambda p, t: gpt.forward(p, t, cfg))(
-            params, ids(2, 16)),
-        jax.make_jaxpr(lambda p, t, c, o: gen._forward_cached(
-            p, t, c, o, cfg, valid=jnp.ones(t.shape, bool)))(
-                params, ids(3, 8), cache, ids()),
-        jax.make_jaxpr(lambda p, t, c, o: gen._forward_cached(
-            p, t, c, o, cfg, valid=jnp.ones(t.shape, bool)))(
-                params, ids(3, 1), cache, ids(3)),
-    ]
-    sha = lambda ts: hashlib.sha256(re.sub(
-        r"0x[0-9a-f]+", "", "\n".join(map(str, ts))).encode()).hexdigest()[:16]
-    return sha(texts[:2]), sha(texts[2:])
-
-
 @pytest.mark.parametrize("arch", sorted(BEFORE))
 def test_one_pass_and_no_new_norm_trace_to_the_programs_of_before(arch):
     """Every architecture the repo had traces, jaxpr for jaxpr, to the
@@ -431,7 +408,7 @@ def test_one_pass_and_no_new_norm_trace_to_the_programs_of_before(arch):
     cfg = GPTConfig.make(**BEFORE[arch])
     assert (cfg.n_passes, cfg.post_norms, cfg.exit_gate) == (1, False, False)
     assert cfg.cache_planes == cfg.n_layer
-    assert digest(cfg) == DIGESTS[arch]
+    assert cached_digests(cfg) == DIGESTS[arch]
     assert gen.init_loop_passes(cfg) is None
     params = gpt.init(jax.random.key(0), cfg)
     assert not {"exit_gate_w", "exit_gate_b"} & set(params)

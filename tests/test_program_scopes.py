@@ -336,6 +336,27 @@ def test_the_trainers_record_names_the_step(tmp_path):
         record["scopes"].values())
 
 
+def test_the_layer_s_scopes_are_training_s_alone(cfg_params, tmp_path):
+    """``attn`` and ``mlp`` are marks of ``gpt._block``'s own body. The
+    cached forward carries neither, and the serving readers count on it:
+    ``engine.unscoped_ms_per_step`` is the trunk because nothing of a decode
+    step's trunk has a scope (PERF.md, section 3). A function the two bodies
+    share that brought a scope of its own into the cached forward fails
+    this."""
+    cfg, params = cfg_params
+    tracer = SpanTracer()
+    InferenceServer(params, cfg, n_slots=2, tracer=tracer, warmup=True,
+                    prefill_buckets=(8, 16))
+    served = programs_of(tracer)
+    assert {r["family"] for r in served} == {"prefill", "decode"}
+    for record in served:
+        assert not {"attn", "mlp"} & set(record["scopes"].values()), \
+            (record["family"], record["variant"])
+    [step] = programs_of(make_trainer(tmp_path, MeshConfig(dp=1), 1).tracer)
+    assert step["family"] == "train_step"
+    assert {"attn", "mlp"} <= set(step["scopes"].values())
+
+
 def test_the_trainers_spans_jsonl_holds_the_record(tmp_path):
     path = tmp_path / "spans.jsonl"
     trainer = make_trainer(tmp_path, MeshConfig(dp=1), 1,

@@ -7,8 +7,6 @@ per-head pool's (the same sums with zero products beside them: equal to
 float32 rounding, the tokens exactly); the models the rule passes by trace
 to the parent's programs. CPU, tiny, float32."""
 
-import hashlib
-import re
 import types
 
 import jax
@@ -28,6 +26,8 @@ from mingpt_distributed_tpu.serving import engine as engine_mod
 from mingpt_distributed_tpu.serving import quant as quant_lib
 from mingpt_distributed_tpu.serving.engine import DecodeEngine
 from mingpt_distributed_tpu.telemetry import render_prometheus
+from program_digests import engine_digest as digest
+from program_digests import forward_digest
 
 OFF = dict(embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32")
 TINY = dict(n_layer=2, n_head=4, n_embd=32, vocab_size=64, block_size=32,
@@ -61,6 +61,9 @@ FORMS = {
                    moe_ffn_dim=16, n_shared_experts=2, moe_scoring="sigmoid",
                    moe_route_scale=2.448),
     "hybrid": dict(model_type="minicpm-sala-tiny"),
+    # the capacity route (mixtral-tiny's keys): softmax-routed experts, each
+    # with room for its share of the tokens
+    "capacity": dict(TINY, n_kv_head=2, n_experts=4, moe_top_k=2, **ROPE),
 }
 #: the forms whose heads lie side by side, which this PR moved
 WIDE = ("mha", "two-tiles", "gqa-rope", "window-softcap", "looped")
@@ -454,9 +457,15 @@ def test_tp_shards_the_axis_that_holds_the_heads(form, over, tp, axis, shards,
 #: dead equation there; a hybrid stack loses the frontier's two. The walk
 #: over lanes and blocks itself is WALKED_DECODE_DIGESTS: the same programs
 #: under ``walk_in_blocks(8)``, where a leaf that lies positions minor on the
-#: chip (five heads of 64, GPT-2 XL's 25) is walked too. A PR that changes one
-#: of these programs on purpose makes them again.
+#: chip (five heads of 64, GPT-2 XL's 25) is walked too. The forward's:
+#: ``gpt.forward`` alone; a ``form/train`` key is the training-mode forward
+#: (``forward_digest_of``). PR 46's rows (every ``capacity``; the forward's
+#: ``window-softcap``, ``latent`` and ``/train``) were made on its parent
+#: (f3c5a18), before it moved a line of either body. A PR that changes one of
+#: these programs on purpose makes them again: tests/program_digests.py, run,
+#: prints every table.
 PARENT_PREFILL_DIGESTS = {
+    "capacity": "9ca244167a1bf8c5",
     "five-heads-of-64": "e48b504c543f2155",
     "gqa-rope": "996c36df05f920bc",
     "heads-of-128": "c695671b5cdd4b04",
@@ -471,6 +480,7 @@ PARENT_PREFILL_DIGESTS = {
     "window-softcap": "034f2065e8be1a07",
 }
 DECODE_DIGESTS = {
+    "capacity": "6b0328499302b987",
     "latent": "90715eb71d7c562a",
     "hybrid": "6fcfdcf8a1b7f29c",
     "looped-heads-of-128": "69d421053ab592fe",
@@ -480,6 +490,7 @@ DECODE_DIGESTS = {
     "mqa-rope": "8fcb717b485f474d",
 }
 WALKED_DECODE_DIGESTS = {
+    "capacity": "41d66f5904c7487d",
     "latent": "4a28bd5ebf57950b",
     "looped-heads-of-128": "2d6d126da1b9ff6d",
     "heads-of-128": "934bd3b284cf760c",
@@ -490,33 +501,23 @@ PARENT_FORWARD_DIGESTS = {
     "mha": "4df8e026ac68301c",
     "gqa-rope": "c6eb2e0998a3e106",
     "looped": "2862654be5e34702",
+    "window-softcap": "9ad431b0213dca45",
+    "latent": "8dc88bbd8d3bbbc2",
+    "capacity": "0126daae348e6d6f",
+    "mha/train": "8559ec7fca433251",
+    "gqa-rope/train": "bfbed589e21292c8",
+    "capacity/train": "de10ad6f58960ab5",
 }
 
 
-def _sha(jaxprs) -> str:
-    text = re.sub(r"0x[0-9a-f]+", "", "\n".join(map(str, jaxprs)))
-    return hashlib.sha256(text.encode()).hexdigest()[:16]
-
-
-def forward_digest(cfg: GPTConfig) -> str:
-    params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
-    return _sha([jax.make_jaxpr(lambda p, t: gpt.forward(p, t, cfg))(
-        params, jax.ShapeDtypeStruct((2, 16), jnp.int32))])
-
-
-def digest(cfg: GPTConfig, decode: bool) -> str:
-    """The engine's own decode program, or its prefill programs, over a
-    3-slot pool. The decode program is traced with the eleven arguments
-    PR 39's had: without the step's tokens and their mask (PR 43: the last
-    two, one ``select`` at the program's head; tests/test_run_ahead.py
-    holds that it is all they add)."""
-    params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
-    engine = DecodeEngine(
-        jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params), cfg,
-        n_slots=3, prefill_buckets=(8, 16))
-    return _sha([jitted.trace(*args[:11], **kwargs).jaxpr
-                 for name, _, jitted, args, kwargs in engine.programs()
-                 if (name == "decode") == decode])
+def forward_digest_of(key: str) -> str:
+    """A PARENT_FORWARD_DIGESTS key's digest: ``form`` is the deterministic
+    forward, ``form/train`` the training-mode one, with a dropout key and
+    both dropouts on."""
+    form, _, mode = key.partition("/")
+    over = dict(resid_pdrop=0.1, attn_pdrop=0.1) if mode else {}
+    return forward_digest(GPTConfig.make(**{**FORMS[form], **over}),
+                          train=bool(mode))
 
 
 @pytest.mark.parametrize("form", sorted(PARENT_PREFILL_DIGESTS))
@@ -547,8 +548,7 @@ def test_the_walk_s_own_equations_are_pinned(form, walk_in_blocks):
 
 @pytest.mark.parametrize("form", sorted(PARENT_FORWARD_DIGESTS))
 def test_training_s_forward_is_the_parent_s(form):
-    cfg, _ = model(form)
-    assert forward_digest(cfg) == PARENT_FORWARD_DIGESTS[form]
+    assert forward_digest_of(form) == PARENT_FORWARD_DIGESTS[form]
 
 
 def test_the_per_head_rule_traces_to_the_parent_s_gpt2_programs(monkeypatch):
