@@ -340,6 +340,45 @@ def sublayer_input(x, scale, bias, cfg: GPTConfig):
     return h.astype(cfg.dtype) if cfg.residual_dtype else h
 
 
+def head_projection(h, w, b, turned: bool):
+    """``L.dense`` of (B, T, D) activations whose (B, T, N) product the
+    caller views per head next. In a decode step (one position a sequence,
+    fewer rows than ``w`` has) whose caller then turns that view head by
+    head (``turned``: a norm, a rotation), the product stands behind an
+    ``optimization_barrier``. The TPU compiler's layout assignment wants a
+    turned product heads-major, and where ``w`` is a slice of a stack, whose
+    layout it may choose as it may not a parameter's, it buys that by
+    writing every layer's weight out transposed, every step; behind the
+    barrier the layout is the product's to pay for, ``rows / D`` of the
+    weight's bytes, and the matmul reads the weight where it lies in the
+    stack. A call of more positions (training, a prefill bucket) pays the
+    weight's copy once for all its rows and keeps its program; a product
+    nothing turns (GPT-2's) was never re-laid, and there a boundary can only
+    cost."""
+    y = L.dense(h, w, b)
+    if turned and h.shape[1] == 1 and h.shape[0] < w.shape[0]:
+        y = jax.lax.optimization_barrier(y)
+    return y
+
+
+def head_boundaries(jaxpr) -> int:
+    """How many projections of a traced program took ``head_projection``'s
+    boundary: the ``optimization_barrier`` equations, at any depth, that
+    stand on a matmul's product or on that product plus its bias (a barrier
+    over anything else, a block of cached rows, is another mechanism's)."""
+    made_by, found = {}, 0
+    for eqn in jaxpr.eqns:
+        made_by.update(dict.fromkeys(eqn.outvars, eqn))
+        if eqn.primitive.name == "optimization_barrier":
+            maker = made_by.get(eqn.invars[0])
+            if maker is not None and maker.primitive.name == "add":
+                maker = made_by.get(maker.invars[0])
+            found += maker is not None and maker.primitive.name == "dot_general"
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            found += head_boundaries(inner)
+    return found
+
+
 def latent_parts(h, blk: Params, cfg: GPTConfig, rope):
     """What latent attention makes of (B, T, D) normed activations before
     it attends, in either form: the queries' nope part (B, T, H, nope) and
@@ -349,7 +388,8 @@ def latent_parts(h, blk: Params, cfg: GPTConfig, rope):
     b, t, _ = h.shape
     r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     cos, sin = rope
-    q = L.dense(h, blk["wq"]).reshape(b, t, cfg.n_head, cfg.qk_head_dim)
+    q = head_projection(h, blk["wq"], None, turned=True).reshape(
+        b, t, cfg.n_head, cfg.qk_head_dim)
     q_pe = attn_ops.apply_rope(q[..., nope:], cos, sin, cfg.rope_interleave)
     kv_a = L.dense(h, blk["w_kv_a"])
     latent = L.rms_norm(kv_a[..., None, :r], blk["kv_norm_scale"],
@@ -414,9 +454,11 @@ def attention_parts(h, blk: Params, cfg: GPTConfig, heads, rope=None):
     scales (``qk_norm``), then rotated by ``rope`` (None: not rotated)."""
     b, t, _ = h.shape
     nh, kv, hd = heads
-    q = L.dense(h, blk["wq"], blk.get("bq")).reshape(b, t, nh, hd)
-    k = L.dense(h, blk["wk"], blk.get("bk")).reshape(b, t, kv, hd)
-    v = L.dense(h, blk["wv"], blk.get("bv")).reshape(b, t, kv, hd)
+    turned = rope is not None or "q_norm_scale" in blk
+    q, k, v = (
+        head_projection(h, blk[w], blk.get(bias), turned).reshape(b, t, n, hd)
+        for w, bias, n in (("wq", "bq", nh), ("wk", "bk", kv),
+                           ("wv", "bv", kv)))
     if "q_norm_scale" in blk:
         q = L.rms_norm(q, blk["q_norm_scale"], eps=cfg.norm_eps)
         k = L.rms_norm(k, blk["k_norm_scale"], eps=cfg.norm_eps)

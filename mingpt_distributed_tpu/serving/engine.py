@@ -112,6 +112,7 @@ import numpy as np
 
 from mingpt_distributed_tpu.config import ConfigError, GPTConfig
 from mingpt_distributed_tpu.models import generate as gen
+from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.serving import quant as quant_lib
@@ -686,6 +687,7 @@ class DecodeEngine:
             bind_static(_decode_impl, cfg=cfg, kv_sharding=kv, kv_quant=kq,
                         walk=self.walk),
             donate_argnums=(1,))
+        self._head_boundaries: Optional[int] = None
         # prefix copy programs: `rows` is static, so one jit wrapper traces
         # once per bucket-quantized prefix length
         self._extract_jit = jax.jit(
@@ -760,6 +762,19 @@ class DecodeEngine:
         """``generate.SPARSE_ROWS`` as it stands, fetched likewise: (2,)
         [rows attended, rows at or before the query], or None."""
         return self._counter(gen.SPARSE_ROWS)
+
+    def head_boundaries(self) -> int:
+        """Projections of the decode program whose product stands behind
+        ``gpt.head_projection``'s boundary (0: the rule passed the model
+        by), counted in the program's own trace, once: the jit's own where
+        a step has run, which ``warmup`` sees to, so a serving loop's first
+        ``summary()`` traces nothing."""
+        if self._head_boundaries is None:
+            (_, _, jitted, args, kwargs), = [
+                p for p in self.programs() if p[0] == "decode"]
+            self._head_boundaries = gpt.head_boundaries(
+                jitted.trace(*args, **kwargs).jaxpr)
+        return self._head_boundaries
 
     def loop_passes(self) -> Optional[np.ndarray]:
         """``generate.LOOP_PASSES`` as it stands, fetched likewise: (2 +
@@ -966,6 +981,9 @@ class DecodeEngine:
         first = self.launch_decode(*parked)
         self.sync_decode(self.launch_decode(
             *parked, prev=first, from_prev=np.zeros(s, bool)))
+        # counted now, off the trace the step above made: a serving loop's
+        # first summary() then traces nothing
+        self.head_boundaries()
         if self.prefix_store is not None:
             for b in self.buckets:
                 if b <= self.prefill_len - 1:

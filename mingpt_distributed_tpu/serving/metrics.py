@@ -236,6 +236,7 @@ class ServingMetrics:
         self._moe_rows_source: Optional[Callable[[], Any]] = None
         self._sparse_rows_source: Optional[Callable[[], Any]] = None
         self._loop_passes_source: Optional[Callable[[], Any]] = None
+        self._head_boundaries_source: Optional[Callable[[], int]] = None
         self._util_sum = 0.0
         self._prefill_rate = RateWindow()
         self._prefill_tokens_per_sec: Optional[float] = None
@@ -550,6 +551,7 @@ class ServingMetrics:
                      sparse_rows_source: Optional[Callable[[], Any]] = None,
                      loop_passes_source: Optional[Callable[[], Any]] = None,
                      kv_row_width: int = 0, kv_row_tiles: int = 0,
+                     head_boundaries_source: Optional[Callable[[], int]] = None,
                      ) -> None:
         """What the engine's programs read and what a cached token and a
         slot's state cost, known once it is built; ``kv_row_width`` and
@@ -561,7 +563,9 @@ class ServingMetrics:
         sparse layers' decode steps attended (``DecodeEngine.sparse_rows``),
         ``loop_passes_source`` a looped stack's (2 + n_passes,) counter of
         its passes (``DecodeEngine.loop_passes``);
-        only ``summary()`` calls them."""
+        ``head_boundaries_source`` reads, off the decode program's trace,
+        the projections whose product stands behind a boundary
+        (``DecodeEngine.head_boundaries``); only ``summary()`` calls them."""
         self._program_weight_bytes.set(program_weight_bytes)
         self._program_weights_cast.set(program_weights_cast)
         self._kv_bytes_per_row.set(kv_bytes_per_row)
@@ -571,6 +575,7 @@ class ServingMetrics:
         self._moe_rows_source = moe_rows_source
         self._sparse_rows_source = sparse_rows_source
         self._loop_passes_source = loop_passes_source
+        self._head_boundaries_source = head_boundaries_source
 
     def _loop_summary(self) -> Dict[str, Any]:
         """A looped stack's passes since the server was built
@@ -635,6 +640,8 @@ class ServingMetrics:
             "state_bytes_per_slot": int(self._state_bytes_per_slot.value),
             "kv_row_width": int(self._kv_row_width.value),
             "kv_row_tiles": int(self._kv_row_tiles.value),
+            "decode_head_boundaries": self._head_boundaries_source()
+            if self._head_boundaries_source else None,
             **self._moe_summary(),
             **self._sparse_summary(),
             **self._loop_summary(),

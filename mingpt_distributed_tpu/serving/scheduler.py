@@ -375,7 +375,7 @@ class InferenceServer:
             self.engine.kv_bytes_per_row, self.engine.moe_rows,
             self.engine.state_bytes_per_slot, self.engine.sparse_rows,
             self.engine.loop_passes, self.engine.pool.row_width,
-            self.engine.pool.row_tiles)
+            self.engine.pool.row_tiles, self.engine.head_boundaries)
         # post-warmup recompile watchdog over the compiled program families
         # (the merged server-level counts, so draft/verify traces are
         # watched too; armed after warmup(); checked every round)

@@ -15,7 +15,12 @@ given, but for the leaves the cached forward reads only through
 * under a mesh every leaf of ``program_params`` keeps the sharding of the
   leaf it was cast from;
 * the compiled decode program converts no weight, and ``ServingMetrics``
-  says what the programs read.
+  says what the programs read;
+* nor does it write a projection's weight again (PR 49): where the product
+  of ``wq``, ``wk`` or ``wv`` is turned per head, a decode step keeps it
+  behind a boundary (``gpt.head_projection``), which adds its own equations
+  to the decode program and nothing else, changes no number, and leaves
+  every prefill program as it was.
 """
 
 import re
@@ -30,6 +35,7 @@ from mingpt_distributed_tpu.config import ConfigError, GPTConfig, MeshConfig
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.ops import attention as attn_ops
+from mingpt_distributed_tpu.ops import layers as L
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.serving import InferenceServer
 from mingpt_distributed_tpu.serving.engine import DecodeEngine
@@ -281,6 +287,166 @@ def test_decode_program_converts_no_weight(arch, one_chip):
 
     assert weight_converts(compiled_text(engine.params), params)
     assert not weight_converts(compiled_text(engine.program_params), params)
+
+
+#: the decode step's boundaries an architecture of ARCHS takes: three
+#: projections a layer and pass where rope or ``qk_norm`` turns their product
+#: per head, the latent's one (``wq``), none in GPT-2
+BOUNDARIES = {"gpt2-untied": 0, "gpt2-tied": 0, "rope-swiglu-rmsnorm": 6,
+              "rope-experts": 6, "latent-experts": 2, "hybrid": 6,
+              "looped": 18}
+
+
+def without_boundary(monkeypatch):
+    """The parent's projections: ``L.dense`` and nothing after it."""
+    monkeypatch.setattr(gpt, "head_projection",
+                        lambda h, w, b, turned: L.dense(h, w, b))
+
+
+def traces(engine):
+    """{(family, variant): the program's jaxpr}, traced as ``programs()``
+    states them."""
+    return {(family, variant): jitted.trace(*args, **kwargs).jaxpr
+            for family, variant, jitted, args, kwargs in engine.programs()}
+
+
+@pytest.mark.parametrize("arch", sorted(ARCHS))
+def test_the_boundary_adds_its_own_equations_and_changes_no_number(
+        arch, monkeypatch):
+    """The decode program is the parent's with one ``optimization_barrier``
+    a turned projection, equation for equation; every prefill program is
+    the parent's, text for text; the engine counts the boundaries off the
+    program's own trace; and tokens (greedy and sampling lanes) and pool are
+    the parent's bit for bit."""
+    cfg, params = model(arch, "bfloat16")
+    build = lambda: DecodeEngine(params, cfg, n_slots=SLOTS, prefill_len=16,
+                                 prefill_buckets=[16])
+    engine = build()
+    new = traces(engine)
+    toks, pool = serve(engine, engine.program_params)
+    with monkeypatch.context() as patch:
+        without_boundary(patch)
+        parent = build()
+        old = traces(parent)
+        toks_parent, pool_parent = serve(parent, parent.program_params)
+
+    names = lambda jaxpr: [e.primitive.name for e in jaxpr.eqns]
+    decode = names(new["decode", ""])
+    assert decode.count("optimization_barrier") == BOUNDARIES[arch]
+    assert [n for n in decode if n != "optimization_barrier"] \
+        == names(old["decode", ""])
+    assert "optimization_barrier" not in names(old["decode", ""])
+    assert engine.head_boundaries() == BOUNDARIES[arch]
+    assert parent.head_boundaries() == 0
+    for key in new.keys() - {("decode", "")}:
+        assert str(new[key]) == str(old[key]), key
+
+    np.testing.assert_array_equal(toks, toks_parent)
+    assert pool.keys() == pool_parent.keys()
+    for name in pool:
+        np.testing.assert_array_equal(pool[name], pool_parent[name])
+
+
+def test_a_barrier_over_anything_but_a_product_is_not_counted():
+    """``gpt.head_boundaries`` reads a trace: a projection's product behind
+    a barrier, with its bias or without, at any depth; not the barrier the
+    walked attention keeps over a block of cached rows."""
+    def program(x, w, b, rows):
+        held = jax.lax.optimization_barrier(x @ w)
+        biased = jax.lax.optimization_barrier(x @ w + b)
+        inner = jax.lax.fori_loop(0, 2, lambda i, c: c + jnp.sum(
+            jax.lax.optimization_barrier(x @ w)), 0.0)
+        return held, biased, inner, jax.lax.optimization_barrier(rows[:2])
+    x, w = jnp.ones((2, 4)), jnp.ones((4, 4))
+    jaxpr = jax.make_jaxpr(program)(x, w, jnp.ones(4), jnp.ones((3, 4)))
+    assert str(jaxpr).count("optimization_barrier") == 4
+    assert gpt.head_boundaries(jaxpr.jaxpr) == 3
+
+
+def test_the_summary_counts_the_boundaries_and_traces_nothing_more(
+        monkeypatch):
+    """``decode_head_boundaries`` rides every ``summary()``; after a warm-up
+    it is read off the trace the warm-up's own step made (the jit's), so a
+    serving loop's first reading of its counters traces nothing."""
+    cfg, params = model("hybrid", "bfloat16")
+    steps = []
+    real = gpt.head_projection
+
+    def counted(h, w, b, turned):
+        steps.append(h.shape[1] == 1)
+        return real(h, w, b, turned)
+    monkeypatch.setattr(gpt, "head_projection", counted)
+    server = InferenceServer(params, cfg, n_slots=SLOTS, warmup=True)
+    assert sum(steps) == BOUNDARIES["hybrid"]     # the decode program, once
+    traced = len(steps)
+    assert server.metrics.summary()["decode_head_boundaries"] \
+        == BOUNDARIES["hybrid"]
+    assert len(steps) == traced
+    assert server.compile_counts()["decode"] == 1
+    cold = InferenceServer(params, cfg, n_slots=SLOTS, warmup=False)
+    assert cold.metrics.summary()["decode_head_boundaries"] \
+        == BOUNDARIES["hybrid"]
+    assert cold.compile_counts()["decode"] == 0
+
+
+#: small stacks at widths that fill lane tiles (four heads of 128 over a
+#: width of 512, four layers: two to a mixer's stack), where the chip's
+#: compiler does to a projection's weight what it does at a cell's sizes
+TILED = dict(n_layer=4, n_head=4, n_embd=512, vocab_size=256, block_size=512,
+             embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="bfloat16",
+             tie_weights=False, rope=True, swiglu=True, rmsnorm=True)
+TILED_FORMS = {
+    "rope": dict(),
+    "qk-norm-hybrid": dict(
+        n_kv_head=1, mixer_types=("lightning-attn", "minicpm4") * 2,
+        lightning_heads=4, lightning_head_dim=128, qk_norm=True,
+        output_gate=True, scale_emb=12.0, scale_depth=1.4,
+        scale_depth_layers=32, dim_model_base=256, sparse_kernel_size=32,
+        sparse_kernel_stride=16, sparse_block_size=64, sparse_topk=4,
+        sparse_window=128, sparse_dense_len=128),
+    "latent": dict(rope_interleave=True, kv_lora_rank=128,
+                   qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=64),
+}
+
+
+def weights_laid_out_again(text, params):
+    """Arrays of compiled HLO ``text`` shaped as one layer's ``wq``, ``wk``
+    or ``wv`` and laid out columns major (``{0,1``): a stack keeps a layer's
+    matrix rows major, and its matmul reads it so, so such an array is the
+    weight written out again, transposed, for whatever reads the product."""
+    shapes = {a.shape[1:] for stack in params.values()
+              if isinstance(stack, dict) for name, a in stack.items()
+              if name in ("wq", "wk", "wv")}
+    return [m for d, n in sorted(shapes) for m in re.findall(
+        rf"%[\w.\-]+ = \(?bf16\[{d},{n}\]\{{0,1\S*", text)]
+
+
+@pytest.mark.parametrize("form", sorted(TILED_FORMS))
+def test_decode_program_writes_no_projection_weight_again(form, one_chip,
+                                                          monkeypatch):
+    """The decode program compiled for the chip reads each layer's q/k/v
+    (the latent's q) weight where it lies in its stack (PR 49): no array of
+    a projection weight's shape is written transposed. Without the boundary
+    (the parent's projections) the chip's compiler writes every layer's out
+    again, every step, to have the per-head product in the layout its norm
+    or rotation wants: 3.8 + 1.6 ms of minicpm's 22.95 ms step."""
+    cfg = GPTConfig.make(**TILED, **TILED_FORMS[form])
+    params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
+
+    def compiled_text():
+        engine = DecodeEngine(
+            jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params), cfg,
+            n_slots=8)
+        (_, _, jitted, args, kwargs), = [
+            p for p in engine.programs() if p[0] == "decode"]
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), args)
+        return jitted.lower(*shapes, **kwargs).compile().as_text()
+
+    assert not weights_laid_out_again(compiled_text(), params)
+    without_boundary(monkeypatch)
+    assert weights_laid_out_again(compiled_text(), params)
 
 
 def slice_sized(text, lanes, rows, ops=("select", "copy", "convert")):

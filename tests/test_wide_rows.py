@@ -461,7 +461,12 @@ def test_tp_shards_the_axis_that_holds_the_heads(form, over, tp, axis, shards,
 #: ``gpt.forward`` alone; a ``form/train`` key is the training-mode forward
 #: (``forward_digest_of``). PR 46's rows (every ``capacity``; the forward's
 #: ``window-softcap``, ``latent`` and ``/train``) were made on its parent
-#: (f3c5a18), before it moved a line of either body. A PR that changes one of
+#: (f3c5a18), before it moved a line of either body. PR 49 made the decode
+#: rows again, walked or not, of the forms whose projections are turned per
+#: head (``capacity``, ``latent``, ``hybrid``, ``looped-heads-of-128``,
+#: ``mqa-rope``: ``gpt.head_projection``'s boundary, which
+#: tests/test_cast_once.py holds to be all that moved); the GPT-2 forms', every
+#: prefill program's and every forward's are untouched. A PR that changes one of
 #: these programs on purpose makes them again: tests/program_digests.py, run,
 #: prints every table.
 PARENT_PREFILL_DIGESTS = {
@@ -480,22 +485,22 @@ PARENT_PREFILL_DIGESTS = {
     "window-softcap": "034f2065e8be1a07",
 }
 DECODE_DIGESTS = {
-    "capacity": "6b0328499302b987",
-    "latent": "90715eb71d7c562a",
-    "hybrid": "6fcfdcf8a1b7f29c",
-    "looped-heads-of-128": "69d421053ab592fe",
+    "capacity": "42748f164b0c9fbf",
+    "latent": "4eaf410bbf89068d",
+    "hybrid": "daedec3b4381ea97",
+    "looped-heads-of-128": "21a214eace867bfb",
     "heads-of-128": "3be9b1ee5821a527",
     "five-heads-of-64": "023e4278f7c3f7b7",
     "narrow": "dceeb701a0e6a7a2",
-    "mqa-rope": "8fcb717b485f474d",
+    "mqa-rope": "dd26b9ebcedf0137",
 }
 WALKED_DECODE_DIGESTS = {
-    "capacity": "41d66f5904c7487d",
-    "latent": "4a28bd5ebf57950b",
-    "looped-heads-of-128": "2d6d126da1b9ff6d",
+    "capacity": "1d0fc943042274a3",
+    "latent": "f2c1b81dc94ba678",
+    "looped-heads-of-128": "5db60c232ed1b1aa",
     "heads-of-128": "934bd3b284cf760c",
     "mha": "01a10a357520b3da",
-    "mqa-rope": "d255c0bdce06b01c",
+    "mqa-rope": "299f6b4c03089557",
 }
 PARENT_FORWARD_DIGESTS = {
     "mha": "4df8e026ac68301c",
@@ -556,11 +561,12 @@ def test_the_per_head_rule_traces_to_the_parent_s_gpt2_programs(monkeypatch):
     its parent's programs for the models it moved. Their prefill programs
     still do; the decode programs are PR 45's (a per-head leaf of heads
     under a lane tile is read in one pass, the parent's read less the dead
-    frontier)."""
+    frontier), ``gqa-rope``'s with PR 49's boundary on its rotated
+    projections."""
     per_head(monkeypatch)
     for form, prefill, decode in (
             ("mha", "e8a706ae5decb1b3", "b2ec58f36ce86c36"),
-            ("gqa-rope", "a742b814360f967c", "44be29f2a25c525a")):
+            ("gqa-rope", "a742b814360f967c", "0cd0e4b368f6d07f")):
         cfg, _ = model(form)
         assert digest(cfg, decode=False) == prefill
         assert digest(cfg, decode=True) == decode
