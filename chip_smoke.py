@@ -59,7 +59,7 @@ DEADLINE_S = 1150.0
 # bf16 resolution at these magnitudes; gradients relative to their scale
 KERNEL_TOL = 2.5e-2
 # kernel choices are made from the shape; these variables override them
-FLASH_ENV = ("FLASH_LAYOUT", "FLASH_FUSED_BWD", "FLASH_BLOCK")
+FLASH_ENV = ("FLASH_LAYOUT", "FLASH_BLOCK")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -25,8 +25,10 @@ Forward: grid (B*H, T/B, T/B) with the k-block index innermost; emits the
 log-sum-exp per row for the backward.
 Backward: two kernels — dq streams K/V blocks per q block; dk/dv streams
 Q/dO blocks per k block — both recomputing probabilities from the saved LSE.
-No stored attention matrix anywhere. The native-layout path fuses the two
-into one dq+dk+dv kernel when its dq scratch fits VMEM (_dqkv_kernel_btd).
+No stored attention matrix anywhere. The native-layout path computes a
+cell's probabilities once, in one dq+dk+dv kernel (_dqkv_kernel_btd),
+wherever that kernel's dq scratch fits VMEM (_fused_bwd_fits: a rule over
+static shapes), and keeps the two-kernel form for the shapes beyond it.
 
 Falls back to the einsum oracle when the shape/config doesn't fit the kernel
 (attention dropout on, decode-time cross lengths, T not a multiple of the
@@ -900,8 +902,11 @@ def _dqkv_kernel_btd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     The split dq / dkv kernels each recompute s, p and dp per active cell
     — 7 matmuls and 2 full VPU softmax chains per cell across the two
     passes, plus double DMA of every q/k/v/do block. Sharing them costs 5
-    matmuls and ONE chain: measured on-chip (round 5), the backward is
-    VPU-bound at hd=64, so this is the dominant remaining lever.
+    matmuls and ONE chain, and the backward is VPU-bound at hd=64.
+    Measured on a v5e (PERF.md, PR 50), us a sequence and pair of heads at
+    T=1024: 13.8 against the pair's 20.8; a third less at every shape
+    probed (nb 1 to 16, hd 64 and 128, windowed), the gradients equal bit
+    for bit (dq sums over k blocks in ascending order in both forms).
 
     Mechanics: grid (B, H/pack, kj, qi) with qi innermost (the dkv
     ordering). dk/dv accumulate per kj in scratch exactly as before. dq
@@ -1086,6 +1091,35 @@ def _flash_fwd_btd(q, k, v, h, scale, block, window=None, softcap=None):
     return out, lse
 
 
+#: VMEM the fused backward may spend on its dq slab, (nq, pack, block, hd)
+#: float32 = T x 128 lanes x 4 B: every T up to 8,192. A budget, not a
+#: crossover: on a v5e the one kernel took 27-37% less device time than the
+#: pair at every shape probed under it (nb 1 to 16, hd 64 and 128, windowed
+#: or not: PERF.md, PR 50). What bounds it is the compiler: with a slab of
+#: 8 MiB (T = 16,384) the kernel asks 17 MiB of scoped VMEM of the 16 it may
+#: have (compile rehearsal, PR 50): 4 MiB is what has compiled and run.
+FUSED_DQ_SCRATCH_BYTES = 4 * 2**20
+
+
+def _fused_bwd_fits(nb: int, pack: int, block: int, hd: int) -> bool:
+    """Whether the native-layout backward runs as one dq+dk+dv kernel: its
+    dq slab has to stay in VMEM across the outer k sweeps. Static shapes
+    only."""
+    return nb * pack * block * hd * 4 <= FUSED_DQ_SCRATCH_BYTES
+
+
+def _btd_delta(out, do, h):
+    """delta = rowsum(out * do) per head: (B, T, H) -> the lse's layout,
+    (B, H, T, 1) float32 (see _flash_fwd_btd). The transpose is on a
+    (B, H, T) fp32 vector — trivial next to the (B, T, D) activation
+    transposes this path exists to kill."""
+    b, t, d = out.shape
+    delta = jnp.sum(
+        out.astype(jnp.float32).reshape(b, t, h, d // h)
+        * do.astype(jnp.float32).reshape(b, t, h, d // h), axis=-1)
+    return delta.transpose(0, 2, 1)[..., None]
+
+
 def _flash_bwd_btd(q, k, v, out, lse, do, h, scale, block, window=None,
                    softcap=None):
     """Native-layout backward: dq, dk, dv in (B, T, H*hd)."""
@@ -1093,30 +1127,19 @@ def _flash_bwd_btd(q, k, v, out, lse, do, h, scale, block, window=None,
     hd = d // h
     pack = _btd_pack(h, hd)
     nb = t // block
-    # delta = rowsum(out * do) per head: (B, T, H) -> the lse's layout
-    # (tiled (B, H, T//128, 128) plane or (B, H, T, 1) — see
-    # _flash_fwd_btd). The transpose is on a (B, H, T) fp32 vector —
-    # trivial next to the (B, T, D) activation transposes this path exists
-    # to kill.
-    delta = jnp.sum(
-        out.astype(jnp.float32).reshape(b, t, h, hd)
-        * do.astype(jnp.float32).reshape(b, t, h, hd), axis=-1)
-    delta = delta.transpose(0, 2, 1)[..., None]
+    backward = (_flash_bwd_btd_fused if _fused_bwd_fits(nb, pack, block, hd)
+                else _flash_bwd_btd_split)
+    return backward(q, k, v, do, lse, _btd_delta(out, do, h), b, t, hd, pack,
+                    nb, scale, block, window, softcap)
 
-    # fused dq+dk+dv kernel (see _dqkv_kernel_btd) whenever its
-    # (nq, pack, block, hd) dq scratch stays within a VMEM budget —
-    # covers every shipped block_size. OPT-IN (FLASH_FUSED_BWD=1) until
-    # validated on real silicon: it is parity-tested in interpret mode,
-    # but its dynamic leading-dim scratch indexing has not met Mosaic yet
-    # (the r5 tiled-lse layout died on exactly that class of gap), and the
-    # on-chip A/B has not run.
-    fused = (nb * pack * block * hd * 4 <= 4 * 2**20
-             and os.environ.get("FLASH_FUSED_BWD", "0") == "1")
-    if fused:
-        return _flash_bwd_btd_fused(q, k, v, do, lse, delta, b, t, hd,
-                                    pack, nb, scale, block, window, softcap)
 
-    grid = (b, h // pack, nb, nb)
+def _flash_bwd_btd_split(q, k, v, do, lse, delta, b, t, hd, pack, nb,
+                         scale, block, window, softcap):
+    """Two pallas_calls, dq then dk+dv, each recomputing the cell's
+    probabilities: the form for a T whose dq slab the fused kernel's
+    scratch cannot hold (_fused_bwd_fits)."""
+    d = q.shape[2]
+    grid = (b, d // (pack * hd), nb, nb)
     io_q = pl.BlockSpec((1, block, pack * hd),
                         lambda bb, hh, i, j: (bb, i, hh))
     if window is not None:

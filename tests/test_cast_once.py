@@ -571,6 +571,84 @@ def test_the_experts_loop_takes_the_stacked_leaves_as_they_lie(one_chip):
     assert expert_sized(control.compile().as_text())
 
 
+FLASH_CALLS = ("flash_fwd", "flash_bwd_fused", "flash_bwd_dq",
+               "flash_bwd_dkv")
+
+
+@pytest.fixture
+def flash_compiled(monkeypatch):
+    """``ops.flash_attention`` with its kernels compiled, not interpreted
+    (the module asks the backend, which is the CPU here), and no variable
+    steering its choices."""
+    from mingpt_distributed_tpu.ops import flash_attention as flash
+    monkeypatch.setattr(flash, "_interpret", lambda: False)
+    monkeypatch.delenv("FLASH_BLOCK", raising=False)
+    monkeypatch.delenv("FLASH_LAYOUT", raising=False)
+    return flash
+
+
+def mosaic_calls(text):
+    """How many Mosaic calls of a compiled text are named after each of the
+    flash kernels (the instruction's own name: a backward call also names
+    the forward's outputs among its operands)."""
+    names = re.findall(
+        r'^\s*(?:ROOT )?%([\w.\-]+) = .*custom_call_target="tpu_custom_call"',
+        text, re.M)
+    return {call: sum(call in name for name in names) for call in FLASH_CALLS}
+
+
+@pytest.mark.parametrize("shape", [(1, 1024, 12, 64), (1, 1024, 25, 64)],
+                         ids=["124m", "xl-odd-heads"])
+def test_the_flash_backward_compiles_as_one_kernel(shape, one_chip,
+                                                   flash_compiled):
+    """PR 50: at the training cells' shapes (XL's 25 heads through the
+    zero-head pad) the gradient of ``causal_attention`` is the forward and
+    ONE dq+dk+dv Mosaic kernel, and the chip's compiler takes it: the dq
+    slab's dynamic leading index, its VMEM. Interpret mode says nothing of
+    either."""
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(flash_compiled.causal_attention(q, k, v)
+                                .astype(jnp.float32)),
+        argnums=(0, 1, 2))).lower(x, x, x).compile().as_text()
+    assert mosaic_calls(text) == {"flash_fwd": 1, "flash_bwd_fused": 1,
+                                  "flash_bwd_dq": 0, "flash_bwd_dkv": 0}
+
+
+def test_a_training_step_s_table_names_the_fused_backward(one_chip,
+                                                          flash_compiled):
+    """The engagement the benchmark reads (PR 50): the scope table a
+    trainer's ``program`` record holds (``telemetry.programs.scope_table``
+    of the compiled step) lists, for a flash model with its layers written
+    out, ``n_layer`` instructions named ``flash_bwd_fused`` under ``attn``
+    and none of the split pair. On the CPU the interpreted kernel is a
+    loop of plain instructions that carry the name in their ``op_name``
+    only, so this table is made of a step compiled for the described
+    chip."""
+    from mingpt_distributed_tpu.telemetry.programs import scope_table
+    cfg = GPTConfig.make(
+        n_layer=3, n_head=2, n_embd=128, vocab_size=64, block_size=256,
+        embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="bfloat16",
+        attention="flash", unroll_layers=True)
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
+    tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+
+    def loss(params, tokens):
+        return gpt.forward(params, tokens, cfg, targets=tokens,
+                           return_logits=False)[1]
+
+    table = scope_table(jax.jit(jax.grad(loss)).lower(
+        *on_chip((params, tokens))).compile().as_text())
+    named = lambda part: sorted(k for k in table if part in k)
+    assert len(named("flash_bwd_fused")) == cfg.n_layer
+    assert {table[k] for k in named("flash_bwd_fused")} == {"attn"}
+    assert len(named("flash_fwd")) == cfg.n_layer
+    assert not named("flash_bwd_dq") and not named("flash_bwd_dkv")
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_metrics_say_what_the_programs_read(dtype):
     cfg, params = model("gpt2-untied", dtype)

@@ -2,6 +2,8 @@
 Pallas interpret mode on CPU (SURVEY §7 hard-part #4: correctness vs the
 oracle first, performance on hardware second)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -299,42 +301,89 @@ def test_btd_gqa_grad_parity(monkeypatch):
         )
 
 
-def test_btd_fused_backward_parity(monkeypatch):
-    """The fused dq+dk+dv kernel (FLASH_FUSED_BWD=1, opt-in until
-    chip-validated) must match the split kernels AND the oracle — plain
-    causal, then window+softcap (every masked-cell branch).
+def btd_backwards(q, k, v, block, window=None, softcap=None):
+    """(dq, dk, dv) of sum(out**2) by each native-layout backward, called by
+    name on one forward's residuals: ``(fused, split)``, each in the
+    model's (B, T, H, hd). An odd head count is padded with zero heads up
+    to the pack unit, as ``causal_attention`` pads it."""
+    b, t, h, hd = q.shape
+    unit = max(128 // hd, 1)
+    hp = -(-h // unit) * unit
+    flat = lambda x: jnp.pad(x.reshape(b, t, h * hd),
+                             ((0, 0), (0, 0), (0, (hp - h) * hd)))
+    q2, k2, v2 = flat(q), flat(k), flat(v)
+    scale = 1.0 / np.sqrt(hd)
+    out, lse = flash._flash_fwd_btd(q2, k2, v2, hp, scale, block,
+                                    window=window, softcap=softcap)
+    do = 2.0 * out
+    args = (q2, k2, v2, do, lse, flash._btd_delta(out, do, hp), b, t, hd,
+            flash._btd_pack(hp, hd), t // block, scale, block, window,
+            softcap)
+    unflat = lambda g: g[..., :h * hd].reshape(b, t, h, hd)
+    return ([unflat(g) for g in flash._flash_bwd_btd_fused(*args)],
+            [unflat(g) for g in flash._flash_bwd_btd_split(*args)])
 
-    FLASH_BLOCK=128 forces nb=2 at t=256: without it the whole fused
-    machinery under test — the cross-kj dq slab accumulation, the parked
-    dq out-spec flush, and the full-cell qi>kj branch — never runs (a
-    single-block grid has one diagonal cell and nothing to accumulate
-    across)."""
-    monkeypatch.setenv("FLASH_LAYOUT", "auto")
-    monkeypatch.setenv("FLASH_BLOCK", "128")
 
-    for kw in ({}, dict(window=40, logit_softcap=30.0)):
-        q, k, v = qkv(t=256, seed=29)
+#: what the rule (``_fused_bwd_fits``) now decides for every training
+#: shape: nb = 2 carries the cross-kj dq slab, the parked dq out-spec flush
+#: and the full-cell qi > kj branch; window + softcap every masked-cell
+#: branch; nb = 1 the one diagonal cell; nb = 4 a slab written over three
+#: outer sweeps; h = 3 the zero-head pad; hd = 128 one head a cell
+FUSED_CASES = {
+    "causal-nb2": dict(t=256, block=128),
+    "window-softcap-nb2": dict(t=256, block=128, window=40, softcap=30.0),
+    "nb1": dict(t=128, block=128),
+    "nb4": dict(t=512, block=128),
+    "nb4-window": dict(t=512, block=128, window=200),
+    "odd-heads-pad": dict(t=256, block=128, h=3),
+    "hd128-pack1": dict(t=256, block=128, h=2, hd=128),
+}
 
-        def loss(fn, q, k, v):
-            return jnp.sum(jnp.square(fn(q, k, v, **kw)))
 
-        monkeypatch.setenv("FLASH_FUSED_BWD", "1")
-        g_fused = jax.grad(lambda *a: loss(flash.causal_attention, *a),
-                           argnums=(0, 1, 2))(q, k, v)
-        monkeypatch.setenv("FLASH_FUSED_BWD", "0")
-        g_split = jax.grad(lambda *a: loss(flash.causal_attention, *a),
-                           argnums=(0, 1, 2))(q, k, v)
-        g_want = jax.grad(lambda *a: loss(attn_ops.causal_attention, *a),
-                          argnums=(0, 1, 2))(q, k, v)
-        for want, fused, split, name in zip(g_want, g_fused, g_split, "qkv"):
-            np.testing.assert_allclose(
-                np.asarray(fused), np.asarray(want), rtol=2e-4, atol=2e-4,
-                err_msg=f"d{name} fused-vs-oracle mismatch ({kw})",
-            )
-            np.testing.assert_allclose(
-                np.asarray(fused), np.asarray(split), rtol=1e-6, atol=1e-6,
-                err_msg=f"d{name} fused-vs-split mismatch ({kw})",
-            )
+@pytest.mark.parametrize("case", FUSED_CASES)
+def test_btd_fused_backward_parity(case):
+    """The fused dq+dk+dv kernel must match the split kernels (to 1e-6:
+    dq sums over k blocks in ascending order in both) AND the oracle."""
+    kw = dict(FUSED_CASES[case])
+    block, window, softcap = (kw.pop("block"), kw.pop("window", None),
+                              kw.pop("softcap", None))
+    q, k, v = qkv(seed=29, **kw)
+    g_fused, g_split = btd_backwards(q, k, v, block, window, softcap)
+    g_want = jax.grad(
+        lambda *a: jnp.sum(jnp.square(attn_ops.causal_attention(
+            *a, window=window, logit_softcap=softcap))),
+        argnums=(0, 1, 2))(q, k, v)
+    for want, fused, split, name in zip(g_want, g_fused, g_split, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(fused), np.asarray(want), rtol=2e-4, atol=2e-4,
+            err_msg=f"d{name} fused-vs-oracle mismatch ({case})",
+        )
+        np.testing.assert_allclose(
+            np.asarray(fused), np.asarray(split), rtol=1e-6, atol=1e-6,
+            err_msg=f"d{name} fused-vs-split mismatch ({case})",
+        )
+
+
+@pytest.mark.parametrize("shape,fused", [
+    ((1, 1024, 12, 64), True),      # the training cells' T: nb = 2
+    ((1, 512, 3, 32), True),        # nb = 1, padded heads
+    ((1, 8192, 1, 128), True),      # the scratch at its limit: 4 MiB
+    ((1, 16384, 1, 128), False),    # 8 MiB: the split pair (traced only)
+    ((1, 16384, 2, 64), False),
+])
+def test_backward_is_chosen_from_the_shape(shape, fused, monkeypatch):
+    """One dq+dk+dv kernel wherever its dq slab fits the budget, the split
+    pair beyond: by static shapes alone (traced, never run)."""
+    monkeypatch.delenv("FLASH_BLOCK", raising=False)
+    monkeypatch.delenv("FLASH_LAYOUT", raising=False)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(flash.causal_attention(q, k, v)
+                                .astype(jnp.float32)),
+        argnums=(0, 1, 2)))(x, x, x))
+    calls = [len(re.findall(rf"name={n}\b", text)) for n in (
+        "flash_fwd", "flash_bwd_fused", "flash_bwd_dq", "flash_bwd_dkv")]
+    assert calls == ([1, 1, 0, 0] if fused else [1, 0, 1, 1])
 
 
 def test_btd_odd_head_count_pads(monkeypatch):

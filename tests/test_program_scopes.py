@@ -60,15 +60,15 @@ def programs_of(tracer):
     return [r for r in tracer.records() if r["kind"] == "program"]
 
 
-def make_trainer(tmp_path, mesh_cfg, n_devices, **trainer_kw):
+def make_trainer(tmp_path, mesh_cfg, n_devices, gpt_kw=None, **trainer_kw):
     ds = CharDataset(
         DataConfig(path="<inline>", block_size=16, train_split=0.9),
         text="the step is lowered as it runs, shardings and all. " * 60)
     train, test = ds.split()
-    gcfg = GPTConfig.make(
+    gcfg = GPTConfig.make(**{**dict(
         n_layer=2, n_head=2, n_embd=32, vocab_size=ds.vocab_size,
         block_size=16, embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0,
-        dtype="float32")
+        dtype="float32"), **(gpt_kw or {})})
     tcfg = TrainerConfig.make(
         max_epochs=1, batch_size=16, grad_norm_clip=1.0, save_every=100,
         log_every=1000, seed=7, snapshot_path=str(tmp_path / "s.msgpack"),
@@ -355,6 +355,31 @@ def test_the_layer_s_scopes_are_training_s_alone(cfg_params, tmp_path):
     [step] = programs_of(make_trainer(tmp_path, MeshConfig(dp=1), 1).tracer)
     assert step["family"] == "train_step"
     assert {"attn", "mlp"} <= set(step["scopes"].values())
+
+
+def test_the_trainers_step_takes_the_fused_flash_backward(tmp_path):
+    """PR 50: the step a flash trainer runs holds, a layer, one forward
+    kernel and ONE backward kernel named ``flash_bwd_fused`` (the name the
+    benchmark's ``kernel.flash_bwd_ms_per_step`` and ``breakdown`` read),
+    and neither of the split pair; its ``program`` record files them under
+    ``attn``. On the CPU the interpreted kernel is a loop of plain
+    instructions, so the names are counted in the step's own jaxpr here;
+    ``tests/test_cast_once.py`` holds the record's table of a step compiled
+    for the described chip."""
+    trainer = make_trainer(
+        tmp_path, MeshConfig(dp=1), 1,
+        gpt_kw=dict(n_layer=3, n_embd=128, attention="flash",
+                    unroll_layers=True))
+    batch = trainer._put_batch(next(iter(trainer.train_iter.epoch_batches())))
+    text = str(jax.make_jaxpr(trainer._train_step)(
+        trainer.state, batch, trainer.base_rng))
+    calls = {n: len(re.findall(rf"name={n}\b", text)) for n in (
+        "flash_fwd", "flash_bwd_fused", "flash_bwd_dq", "flash_bwd_dkv")}
+    assert calls == {"flash_fwd": 3, "flash_bwd_fused": 3,
+                     "flash_bwd_dq": 0, "flash_bwd_dkv": 0}
+    [record] = programs_of(trainer.tracer)
+    assert record["family"] == "train_step"
+    assert "attn" in set(record["scopes"].values())
 
 
 def test_the_trainers_spans_jsonl_holds_the_record(tmp_path):
