@@ -40,12 +40,20 @@ loaded, so a reference reads its published keys under their own names):
       ``GPTConfig.n_experts`` is set), ``hidden`` owes two things more, and
       a reference that lacks them is an error there, not a dense check: it
       takes ``experts=`` (L, B, T, k) int32, the experts every token takes
-      in every layer (None: the router's own k best), and returns a fourth
-      array, the router's float32 logits (L, B, T, E). Nothing else: no
-      tolerance, no margin, no law. ``serve_verdict`` then has the reference
-      route as the program routed (``follow_routes``) before it holds the
-      rows to the twin, which takes the same table. A dense reference owes
-      neither.
+      in every layer (None: the router's own k best; **a token's row with
+      an entry under 0: the router's own k best for that token in that
+      layer**, which is how a layer not yet followed runs), and returns a
+      fourth array, the router's float32 logits (L, B, T, E), L counting
+      every model layer. Nothing else: no tolerance, no margin, no law.
+      ``serve_verdict`` then has the reference route as the program routed
+      (``follow_routes``) before it holds the rows to the twin, which takes
+      the same table. A dense reference owes neither.
+  ``cached_layers(sizes)`` -> the model layers whose rows are ``ks[i]``,
+      ``vs[i]``, in order: owed only where some layer caches no rows (a
+      state layer of a hybrid stack). A reference that brings none caches a
+      plane a layer. A dense check never reads it (``ks`` against the
+      pool's planes, one for one); a routed one scores a layer's routes by
+      the first cached layer above it (``follow_routes``).
   ``logits(weights, x (..., d))`` -> float32 (..., V), any final softcap in.
   ``loss(weights, tokens, targets, sizes)`` -> mean cross-entropy over the
       targets that are not -1 (training cells).
@@ -159,6 +167,35 @@ SERVE_ROUTE_MARGIN = 8.0
 #: are counted in the notes: a token has more only with three logits on one
 #: side of the boundary inside the margin and two on the other.
 SERVE_ROUTE_TRIES = 8
+#: Where a stack's rows skip layers (``follow_routes``), a token is settled
+#: once its rows lie near the program's: within this many times the square
+#: of the rows' tolerance, in the squared distance ``row_distance`` gives
+#: (keys and values added: 32 is both four times the tolerance away). On the
+#: fixture a token under the program's own sets reads 0.9-1.3 at the median,
+#: 5 at the most, and 10 while a neighbour a few positions before it still
+#: stands elsewhere than the program had it; one with a single expert
+#: swapped reads 900-3,000 (one expert moves a row by tens of percent,
+#: rounding by about one): the level lies between, nearer the settled side.
+#: A rule of the search and not of the verdict: it says which tokens go
+#: round again and when a set is taken for good; every row is held to the
+#: twin in the end, whatever the search took.
+SERVE_ROUTE_NEAR = 32.0
+#: The rounds a group of layers that share their scoring layer may take
+#: (``follow_routes``): a sweep of each of its layers a round, each sweep one
+#: forward for the router's logits, at most 1 + SERVE_ROUTE_TRIES for the
+#: sets and one to confirm, and one forward a round to see what is settled;
+#: so a case costs at most layers x (3 + SERVE_ROUTE_TRIES) x
+#: SERVE_ROUTE_SWEEPS forwards of the reference and one a round. A stack that
+#: caches a plane a layer takes one sweep a layer, the parent's. Where rows
+#: skip layers, the first round settles the tokens that moved in one layer
+#: at most and have no such neighbour just before them (on the chip one
+#: token in twenty moves in a layer: ledger, kanana), a second their
+#: neighbours, a third finds nothing more, and the rounds after it take the
+#: tokens that moved in two layers, one rule a round (``follow_routes``): 8
+#: holds three rounds and a rule for each of four layers and the one before
+#: them. What is still unsettled then is noted (``route_unsettled_layers``)
+#: and keeps the nearest sets it found, which the rows then pass or fail by.
+SERVE_ROUTE_SWEEPS = 8
 #: Each token the engine emitted greedily must be the reference's best or
 #: within this of it, in the reference's own logits. With seeded weights the
 #: best two logits are often closer than bf16 resolves, so tokens are never
@@ -242,76 +279,222 @@ def route_alternatives(logits: np.ndarray, top_k: int,
 
 def follow_routes(forward: Callable, row_distance: Callable, n_layer: int,
                   n_positions: int, top_k: int, n_prompt: int,
-                  emitted: Sequence[int], kv_tol: float):
+                  emitted: Sequence[int], kv_tol: float,
+                  cached: Sequence[int] = None):
     """The table of experts (L, T, k) under which the reference goes the way
     the program went, and the notes of how it was found.
 
     ``forward(table)`` is one forward of the reference over the checked
     sequence: ``(logits of the rows that emitted a token, ks, vs, router
-    logits (L, T, E))``. ``row_distance(ks, vs, layer)`` is every position's
-    squared relative distance between the reference's keys and values of
-    that layer and the rows the program cached.
+    logits (L, T, E))``; a row of the table with an entry under 0 runs under
+    the router's own choice. ``row_distance(ks, vs, plane)`` is every
+    position's squared relative distance between the reference's keys and
+    values of that cached plane and the rows the program cached. ``cached``
+    names the model layers whose rows the planes are, in order (None: a
+    plane a layer).
 
-    Layer by layer, the layers below fixed. Row t of layer l+1's keys and
-    values is made of x_{l+1}(t) alone, and with everything before layer l
-    fixed that depends on token t's own experts at layer l alone: one
-    expert swapped moves the row by tens of percent, bf16 rounding by about
-    one. So each token whose logits leave a choice inside the margin takes,
-    of its admissible sets, the one whose layer-l+1 rows lie nearest the
-    program's; all such tokens try their n-th alternative in one forward.
-    Rows written by prefill and by decode are treated alike. The last
-    layer's experts feed nothing cached: there the rows that emitted a
-    token take the set under which that token's logit gap is least."""
+    A layer's routes are scored by the first cached layer above it, and the
+    layers above the last cached one by the tokens the program emitted.
+    Each token whose logits leave a choice inside the margin takes, of its
+    admissible sets, the one whose rows there lie nearest the program's (of
+    the rows that emitted a token: under which that token's logit gap is
+    least); all such tokens try their n-th set in one forward, a sweep. One
+    expert swapped moves a row by tens of percent, bf16 rounding by about
+    one. Rows written by prefill and by decode are treated alike.
+
+    Where the scoring layer is the next one (every layer of a stack that
+    caches a plane a layer), row t there is made of x_{l+1}(t) alone and
+    depends on token t's own experts at layer l alone, whatever the layers
+    above do: layer by layer, the layers below fixed, one sweep each. That
+    is the whole of it there.
+
+    Where rows skip layers (a hybrid stack's state layers cache none), the
+    layers that share a scoring layer are followed together, in rounds, the
+    lowest layer first. A token's rows at the scoring layer move with its
+    sets in every layer of the group, and a layer not yet followed runs
+    under the reference's own choice (entries of -1): the program's for all
+    but the one token in twenty the program sent elsewhere there. For such a
+    token the right set below does not bring the rows near, and a wrong one
+    can read nearer than it (the layer above, chosen anew under another
+    input, may fall the program's way by chance), so nearest alone is no
+    rule. What is sure is the level: under the program's sets in every
+    layer a token's rows lie within rounding of the program's
+    (``SERVE_ROUTE_NEAR``), under any other at a swap's distance. So a token
+    takes another set only where that brings its rows near (read in a
+    forward of its own, in which only the tokens that found a nearer set
+    stand elsewhere than the reference: ``sweep``), which settles
+    every token that moved in one layer at most; the tokens still far after
+    a round go round again from the reference's own choice. The sets of the
+    tokens before it reach a token's rows too, through a convolution's taps
+    or a state (on the fixture a neighbour's swap reads a quarter of a
+    token's own), so a round that settled some tokens is followed by
+    another under the same rule: their neighbours' rows are clear now, and
+    rows before a token never depend on it. A round that settled none hands
+    the rest to the next rule: each layer takes its nearest set (a token
+    that moved in two layers: the lower one's right set reads a swap's
+    distance like all the others, and is the nearest more often than not),
+    then the token is held off its own set in the first, the second, ... of
+    the layers it has a choice in. Under those a token keeps what a round
+    gave it only if its rows are near at the round's end, and the nearest it
+    ever stood otherwise."""
+    cached = list(range(n_layer)) if cached is None else [int(c) for c in cached]
     n_rows = n_prompt + len(emitted) - 1          # rows the program cached
     emitting = np.arange(n_prompt - 1, n_rows)
-    table = np.broadcast_to(np.arange(top_k, dtype=np.int32),
-                            (n_layer, n_positions, top_k)).copy()
-    notes: Dict[str, list] = {}
+    table = np.full((n_layer, n_positions, top_k), -1, np.int32)
+    # plane -> the layers it scores, lowest first; None: the emitted tokens
+    groups: Dict = {}
     for layer in range(n_layer):
-        # the table's rows from this layer on are not yet the reference's
-        # choice, and this layer's logits do not depend on them
-        router = np.asarray(forward(table)[3][layer])
+        groups.setdefault(next(
+            (i for i, c in enumerate(cached) if c > layer), None), []
+        ).append(layer)
+    # a layer's tokens with a choice: position -> the sets it may take,
+    # [(gap, experts), ...], its own first, and the sets cut beyond the tries
+    state = [{"sweeps": 0, "unsettled": 0, "choices": {}, "cut": {}}
+             for _ in range(n_layer)]
+    forwards = 0
+
+    def run(trial):
+        nonlocal forwards
+        forwards += 1
+        return forward(trial)
+
+    def scored(out, plane) -> np.ndarray:
+        if plane is not None:
+            return np.asarray(row_distance(out[1], out[2], plane))
+        rows = np.asarray(out[0])
+        score = np.full(n_positions, np.inf)
+        score[emitting] = rows.max(-1) - rows[
+            np.arange(len(emitted)), list(emitted)]
+        return score
+
+    def sweep(layer: int, plane, trying, take: Callable, near=None) -> None:
+        """The tokens of ``trying`` (None: all) with a choice in this layer
+        try their sets at once, each from the reference's own;
+        ``take(t, scores)`` says which of them token t keeps, and with a
+        level ``near`` a token keeps another set than its own only if its
+        rows lie that near under it."""
+        at = state[layer]
+        # this layer's logits depend on the layers below alone
+        router = np.asarray(run(table)[3][layer])
         live = router[:n_rows]
         spread = float(np.sqrt(np.mean(
             (live - live.mean(-1, keepdims=True)) ** 2)))
         margin = SERVE_ROUTE_MARGIN * kv_tol * spread
-        table[layer] = np.argsort(-router, axis=-1, kind="stable")[:, :top_k]
-        last = layer == n_layer - 1
-        # position -> the sets it may take, [(gap, experts), ...], its own
-        # first: only positions with a choice
-        choices, cut = {}, 0
-        for t in (emitting if last else range(n_rows)):
+        own = np.argsort(-router, axis=-1, kind="stable")[:, :top_k]
+        if trying is None:
+            table[layer] = own
+        else:
+            rows = sorted(trying)
+            table[layer, rows] = own[rows]
+        # a later sweep reads anew only the tokens it tries: the layers
+        # below moved under them alone
+        choices, cut = at["choices"], at["cut"]
+        for t in map(int, (emitting if plane is None else range(n_rows))
+                     if trying is None else trying):
             others = route_alternatives(router[t], top_k, margin)
+            choices.pop(t, None)
             if others:
-                choices[int(t)] = [(0.0, table[layer, t].copy()),
-                                   *others[:SERVE_ROUTE_TRIES]]
-                cut += max(0, len(others) - SERVE_ROUTE_TRIES)
-        scores = np.full((max(map(len, choices.values()), default=0),
-                          n_positions), np.inf)
+                choices[t] = [(0.0, own[t].copy()),
+                              *others[:SERVE_ROUTE_TRIES]]
+                cut[t] = max(0, len(others) - SERVE_ROUTE_TRIES)
+        at.update(margin=margin, own=own, router=router,
+                  sweeps=at["sweeps"] + 1)
+        members = sorted(choices if trying is None else trying & set(choices))
+        if not members:
+            return
+        scores = np.full((max(len(choices[t]) for t in members), n_positions),
+                         np.inf)
         for c in range(len(scores)):
-            trying = [t for t, sets in choices.items() if c < len(sets)]
+            taking = [t for t in members if c < len(choices[t])]
             trial = table.copy()
-            for t in trying:
+            for t in taking:
                 trial[layer, t] = choices[t][c][1]
-            out = forward(trial)
-            if last:
-                rows = np.asarray(out[0])
-                score = np.full(n_positions, np.inf)
-                score[emitting] = rows.max(-1) - rows[
-                    np.arange(len(emitted)), list(emitted)]
-            else:
-                score = np.asarray(row_distance(out[1], out[2], layer + 1))
-            scores[c, trying] = score[trying]
-        best = {t: int(np.argmin(scores[:, t])) for t in choices}
-        for t, c in best.items():
+            scores[c, taking] = scored(run(trial), plane)[taking]
+        taken = {t: take(t, scores[:, t]) for t in members}
+        if near is not None:
+            # the forwards above held most neighbours at sets they did not
+            # take, and what that carries into a token's rows can hide that
+            # a set is the right one: every token that found a nearer set
+            # than its own takes it in one more forward, the others at
+            # their own, and keeps it only if its rows lie near there
+            moving = {t: c for t, c in taken.items() if c}
+            trial = table.copy()
+            for t, c in moving.items():
+                trial[layer, t] = choices[t][c][1]
+            score = scored(run(trial), plane) if moving else None
+            taken = {t: c if c and score[t] <= near else 0
+                     for t, c in taken.items()}
+        for t, c in taken.items():
             table[layer, t] = choices[t][c][1]
-        gaps = [choices[t][c][0] for t, c in best.items() if c]
-        for key, value in (("route_margin_layers", margin),
-                           ("route_banded_layers", len(choices)),
+
+    # 0: only what brings a token's rows near (a layer alone: what lies
+    # nearest); 1: nearest; 1 + j: held off its own set in the j-th layer
+    # the token has a choice in, nearest in the others
+    rule, turn = 0, {}
+
+    def take(t, scores):
+        if rule:
+            turn[t] += 1
+            if turn[t] == rule - 1:
+                return 1 + int(np.argmin(scores[1:]))
+        return int(np.argmin(scores))
+
+    for plane, layers in groups.items():
+        if len(layers) == 1:            # the next layer scores it
+            sweep(layers[0], plane, None, take)
+            continue
+        near = SERVE_LOGIT_GAP_TOL if plane is None \
+            else SERVE_ROUTE_NEAR * kv_tol ** 2
+        far, kept = None, {}    # token -> (its distance, its sets) at its best
+        tried = 0               # the last rule a round ran under
+        for _ in range(SERVE_ROUTE_SWEEPS):
+            if far is not None:
+                table[np.ix_(layers, sorted(far))] = -1
+            turn = {t: 0 for t in far or ()}
+            for layer in layers:
+                sweep(layer, plane, far, take, None if rule else near)
+            score = scored(run(table), plane)
+            if far is None:
+                far = set().union(*(state[layer]["choices"]
+                                    for layer in layers))
+            for t in far:
+                if t not in kept or score[t] < kept[t][0]:
+                    kept[t] = (float(score[t]), table[layers, t].copy())
+            still = {t for t in far if not kept[t][0] <= near}
+            for t in still:             # the nearest it ever stood
+                table[layers, t] = kept[t][1]
+            # a round that settled some clears their neighbours' rows: the
+            # rest try once more for what brings them near; one that settled
+            # none hands them to the next rule
+            if len(still) == len(far):
+                rule = tried = tried + 1
+            else:
+                rule = 0
+            far = still
+            if not far or rule > 1 + len(layers):
+                break
+        rule = 0
+        for layer in layers:
+            state[layer]["unsettled"] = len(far & set(state[layer]["choices"]))
+    notes: Dict[str, list] = {}
+    for layer, at in enumerate(state):
+        moved = [t for t in range(n_rows)
+                 if set(table[layer, t]) != set(at["own"][t])]
+        gaps = [float(max(at["router"][t][[e for e in at["own"][t]
+                                           if e not in table[layer, t]]])
+                      - min(at["router"][t][[e for e in table[layer, t]
+                                             if e not in at["own"][t]]]))
+                for t in moved]
+        for key, value in (("route_margin_layers", at["margin"]),
+                           ("route_banded_layers", len(at["choices"])),
                            ("route_followed_layers", len(gaps)),
                            ("route_gap_max_layers", max(gaps, default=0.0)),
-                           ("route_cut_layers", cut)):
+                           ("route_cut_layers", sum(
+                               at["cut"][t] for t in at["choices"])),
+                           ("route_sweeps_layers", at["sweeps"]),
+                           ("route_unsettled_layers", at["unsettled"])):
             notes.setdefault(key, []).append(value)
+    notes["route_forwards"] = forwards
     return table, notes
 
 
@@ -368,8 +551,10 @@ def pool_errors(cache, ref_k, ref_v, slot, n_rows) -> Dict:
 
 
 def row_distance(cache, ref_k, ref_v, slot, layer):
-    """Every position's squared relative distance between one layer's rows
-    of a slot and the reference's, keys and values added."""
+    """Every position's squared relative distance between one cached plane's
+    rows of a slot and the reference's, keys and values added. ``layer``
+    counts the planes the pool holds, which are the model's layers only
+    where every layer caches rows."""
     import jax
     import jax.numpy as jnp
 
@@ -464,6 +649,10 @@ def serve_verdict(reference, sizes: Dict, server, prompts: List[np.ndarray],
         raise RuntimeError(
             "the reference's hidden() takes no act_dtype=: it owes the twin "
             "the rows' tolerance is read from (harness/check.py)")
+    # the model layers whose rows the pool's planes are: the reference's
+    # word, and a plane a layer where it says nothing
+    cached = reference.cached_layers(sizes) \
+        if hasattr(reference, "cached_layers") else None
     act_dtype = jnp.dtype(cfg.dtype)
     weights = reference.weights_from_program(eng.params)
     n_slots, parked = eng.n_slots, cfg.block_size - 1
@@ -546,10 +735,11 @@ def serve_verdict(reference, sizes: Dict, server, prompts: List[np.ndarray],
             twin = read_twin(seq, ref_k, ref_v, n_rows, own.astype(np.int32))
             table, routes = follow_routes(
                 lambda experts: ref_forward(weights, seq, np.int32(n), experts),
-                lambda ks, vs, layer: distance_of_layer(
-                    eng.pool.cache, ks, vs, np.int32(slot), np.int32(layer)),
-                cfg.n_layer, t_pad, cfg.moe_top_k, n, emitted,
-                twin_tolerance(max(twin["k_rel"], twin["v_rel"]), twin_ratio))
+                lambda ks, vs, plane: distance_of_layer(
+                    eng.pool.cache, ks, vs, np.int32(slot), np.int32(plane)),
+                len(own), t_pad, cfg.moe_top_k, n, emitted,
+                twin_tolerance(max(twin["k_rel"], twin["v_rel"]), twin_ratio),
+                cached)
             ref_logits, ref_k, ref_v, _ = ref_forward(
                 weights, seq, np.int32(n), table)
         else:

@@ -24,14 +24,25 @@ Arrivals likewise: ``poisson`` is a Poisson process, and
 in seeded order, so every block of ``stratum`` requests takes the same total
 time and a window holds the same number of requests to within one: a
 measurement's variance reduction, with the bursts of a real Poisson stream
-removed. A mix that uses either says so in its ``what``.
+removed. A mix that uses either says so in its ``what``. With ``"order":
+"cycle"`` and an ``order_seed`` the gaps come as a length spec's strata do:
+one order, drawn from ``order_seed``, that every block repeats and no
+``--seed`` changes, so every run's requests are due at the same moments.
 
 An open-loop mix can start warm (``warm_start``): at time 0 the generator
 sends as many requests as the cell's file says are in flight in steady state
 (``warm_inflight``), each with what a request caught at a random moment of
 its life has left to generate (a length drawn in proportion to its size,
 times a uniform share). Occupancy then starts where it would settle, and
-the ramp need not last a request's life.
+the ramp need not last a request's life. ``warm_start`` may be an object
+``{"order": "cycle", "order_seed": n}``: the pairing of the residual lengths
+with their shares is then drawn from ``order_seed`` and not from ``--seed``
+(``true`` pairs them by ``--seed``, as before). A mix that deals its gaps,
+both lengths and its warm start in cycles gives every seed the same due
+times and the same lengths in the same order: two seeds differ in token ids
+and weights alone. Give each spec of a mix an ``order_seed`` of its own: two
+specs of one stratum under one seed are dealt in the same order, and long
+prompts then always meet long answers.
 """
 
 from __future__ import annotations
@@ -67,6 +78,18 @@ def quantiles(spec: Mapping, k: int) -> np.ndarray:
     return np.clip(np.rint(vals), spec["min"], spec["max"]).astype(np.int64)
 
 
+def _dealt(grid: np.ndarray, n_blocks: int, spec: Mapping,
+           rng: np.random.Generator) -> List[np.ndarray]:
+    """``n_blocks`` blocks of a stratum's quantile midpoints: each in an
+    order of its own drawn from ``rng`` (the run's seed), or, under
+    ``"order": "cycle"``, all in the one order the spec's ``order_seed``
+    draws."""
+    if spec.get("order") == "cycle":
+        return [_rng(int(spec["order_seed"]), "cycle").permutation(grid)] \
+            * n_blocks
+    return [rng.permutation(grid) for _ in range(n_blocks)]
+
+
 def lengths(spec: Mapping, n: int, rng: np.random.Generator) -> np.ndarray:
     if "stratum" not in spec:       # independent draws, clipped
         dist = spec["dist"]
@@ -82,12 +105,7 @@ def lengths(spec: Mapping, n: int, rng: np.random.Generator) -> np.ndarray:
         return np.clip(np.rint(vals), spec["min"], spec["max"]).astype(np.int64)
     k = int(spec["stratum"])
     grid = quantiles(spec, k)
-    n_blocks = -(-n // k)
-    if spec.get("order") == "cycle":
-        block = _rng(int(spec["order_seed"]), "cycle").permutation(grid)
-        blocks = [block] * n_blocks
-    else:
-        blocks = [rng.permutation(grid) for _ in range(n_blocks)]
+    blocks = _dealt(grid, -(-n // k), spec, rng)
     return np.concatenate(blocks)[:n] if blocks else np.zeros(0, np.int64)
 
 
@@ -105,16 +123,18 @@ def arrival_times(spec: Mapping, rate: float, horizon_s: float,
                   rng: np.random.Generator) -> np.ndarray:
     """Times in [0, horizon) of an arrival process with mean ``rate`` a
     second. ``poisson``: homogeneous. ``stratified_poisson``: exponential
-    gaps dealt by strata of ``stratum``. ``bursty``: on/off, each ``period``
-    seconds spends ``duty`` of its length at ``peak_to_mean`` times the mean
-    and the rest at whatever keeps the mean."""
+    gaps dealt by strata of ``stratum``, in seeded order or, under ``"order":
+    "cycle"``, in the one order of the spec's ``order_seed``. ``bursty``:
+    on/off, each ``period`` seconds spends ``duty`` of its length at
+    ``peak_to_mean`` times the mean and the rest at whatever keeps the
+    mean."""
     process = spec["process"]
     if process == "stratified_poisson":
         k = int(spec["stratum"])
         grid = -np.log1p(-(np.arange(k) + 0.5) / k)
         grid *= 1.0 / (rate * grid.mean())          # mean gap exactly 1/rate
         n_blocks = int(horizon_s * rate / k) + 2
-        gaps = np.concatenate([rng.permutation(grid) for _ in range(n_blocks)])
+        gaps = np.concatenate(_dealt(grid, n_blocks, spec, rng))
         t = np.cumsum(gaps)
         return t[t < horizon_s]
     if process == "poisson":
@@ -152,7 +172,8 @@ def residual_lengths(spec: Mapping, n: int,
     """What ``n`` requests caught mid-life have left to generate: a length
     from the size-biased distribution (a long request is in flight for
     longer) times a uniform share, at least 1. Both are quantile midpoints
-    paired in seeded order, so every seed gets the same two multisets."""
+    paired in the order ``rng`` draws (the run's seed, or a warm start's own
+    ``order_seed``), so every seed gets the same two multisets."""
     if n == 0:
         return np.zeros(0, np.int64)
     grid = np.sort(quantiles(spec, int(spec.get("stratum", 64))))
@@ -169,7 +190,11 @@ def requests(mix: Mapping, vocab: int, seed: int, *, rate: Optional[float],
     [0, horizon), after ``warm_inflight`` requests due at 0 if the mix starts
     warm. Closed loop: a pool of ``mix['pool']`` that the clients take in
     order, each its next when its last has completed."""
-    n_warm = int(warm_inflight) if mix.get("warm_start") else 0
+    warm = mix.get("warm_start")
+    n_warm = int(warm_inflight) if warm else 0
+    warm_rng = _rng(int(warm["order_seed"]), "cycle") \
+        if isinstance(warm, Mapping) and warm.get("order") == "cycle" \
+        else _rng(seed, "warm")
     if mix["loop"] == "open":
         due = np.concatenate([np.zeros(n_warm), arrival_times(
             mix["arrivals"], float(rate), horizon_s, _rng(seed, "arrivals"))])
@@ -180,7 +205,7 @@ def requests(mix: Mapping, vocab: int, seed: int, *, rate: Optional[float],
         raise ValueError(f"unknown loop {mix['loop']!r}")
     n_prompt = lengths(mix["prompt_len"], n, _rng(seed, "prompt_len"))
     n_out = np.concatenate([
-        residual_lengths(mix["output_len"], n_warm, _rng(seed, "warm")),
+        residual_lengths(mix["output_len"], n_warm, warm_rng),
         lengths(mix["output_len"], n - n_warm, _rng(seed, "output_len"))])
     tok = _rng(seed, "tokens")
     return [

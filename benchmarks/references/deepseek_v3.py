@@ -25,7 +25,9 @@ The equations, ``rms(x) = x / sqrt(mean(x^2) + eps)``:
     the others:
       s = sigmoid(h router)                         E scores, float32
       the chosen k: those of the table the caller hands over, or, with no
-      table, the k largest of s + bias (``e_score_correction_bias``; the
+      table or where a token's row of it has an entry under 0 (a layer the
+      check has not followed yet), the k largest of s + bias
+      (``e_score_correction_bias``; the
       group limit of ``noaux_tc`` is not written: ``n_group`` and
       ``topk_group`` must be 1, which is no limit)
       g = s[chosen] (the scores, never the bias), over their sum + 1e-20
@@ -99,6 +101,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.references.rounding import rounder
+from benchmarks.references.routes import chosen_experts
 
 #: experts whose float32 weights exist at one time
 EXPERT_BLOCK = 8
@@ -186,8 +189,7 @@ def _routed(h, w, stack, at, sizes, chosen, r):
     top_k = sizes["num_experts_per_tok"]
     s = jax.nn.sigmoid(h @ _f32(w["router"]))
     select = s + _f32(w["bias"])
-    if chosen is None:
-        chosen = jax.lax.top_k(select, top_k)[1]
+    chosen = chosen_experts(select, top_k, chosen)
     g = jnp.take_along_axis(s, chosen, -1)
     if sizes["norm_topk_prob"]:
         g = g / (g.sum(-1, keepdims=True) + 1e-20)
@@ -221,7 +223,8 @@ def hidden(weights, tokens, sizes, experts=None, act_dtype=None):
     shared rope keys (L, B, T, 1, rope) and the normed latents (L, B, T, 1,
     kv_lora_rank) of every layer; what every layer takes its k best of (L,
     B, T, E)). ``experts`` (L, B, T, k) int32: the experts every token takes
-    in every layer (a dense layer's row is ignored); None: the k best of
+    in every layer (a dense layer's row is ignored; a row with an entry
+    under 0: that token's k best of s + bias there); None: the k best of
     s + bias. ``act_dtype``: the twin (module docstring); None: float32
     throughout."""
     if sizes.get("q_lora_rank") is not None:

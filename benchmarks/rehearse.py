@@ -81,6 +81,7 @@ def tiny(cell: spec.Cell, sizes=None, mix_too: bool = True) -> spec.Cell:
                      for published, field in program["key_map"].items()})
     if not mix_too:
         return dataclasses.replace(cell, config=config)
+    # the mix's own arrivals and warm start stay, cycles and all
     mix = dict(cell.mix, **(TINY_TRAIN if cell.kind == "train" else TINY_SERVE))
     for key in ("prompt_len", "output_len"):
         # tiny lengths, dealt in the order the cell's own mix deals its own

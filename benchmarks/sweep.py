@@ -8,8 +8,9 @@ cell's open-loop mix several times in turn, from an idle server each time.
 
 A play is a JSON object; each key is optional: ``rate_req_s`` and
 ``warm_inflight`` (default: the cell's), ``seed`` and ``seconds`` (default:
-the command's), ``mix`` (keys that replace the mix's: another arrival
-process, unstratified lengths). The server's own options are the cell's, or
+the command's), ``mix`` (keys that replace the mix's, each whole: another
+arrival process, unstratified lengths, ``arrivals`` or ``warm_start`` with or
+without ``"order": "cycle"`` and an ``order_seed``). The server's own options are the cell's, or
 ``--override``'s, for every play.
 
 Prints one JSON line a play: offered and completed requests a second, the

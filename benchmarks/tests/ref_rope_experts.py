@@ -20,7 +20,8 @@ code with them):
     z = h w_router                                E router logits, float32
     p = softmax(z)                                over all E experts
     the chosen k: those of the table the caller hands over, or, with no
-    table, the k largest p; their p renormalised to sum to 1 (k = 1, or
+    table or where a token's row of it has an entry under 0, the k largest
+    p; their p renormalised to sum to 1 (k = 1, or
     ``norm_topk_prob`` false: the raw p)
     x = x + sum over the chosen e of
             gate_e * (silu(h w_eg[e]) * (h w_e1[e])) w_e2[e]
@@ -64,6 +65,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.references.rounding import rounder
+from benchmarks.references.routes import chosen_experts
 
 
 def weights_from_program(params) -> dict:
@@ -98,12 +100,11 @@ def _rotate(x, theta):
 def _experts(h, w, top_k, renormalise, r, chosen=None):
     """(B, T, d) -> ((B, T, d), router logits (B, T, E)): every expert on
     every token, weighted by its gate, which is zero where the expert was
-    not chosen. ``chosen`` (B, T, k): the experts to take; None: the k of
-    the largest probability."""
+    not chosen. ``chosen`` (B, T, k): the experts to take; None, or a token's
+    row with an entry under 0: the k of the largest probability."""
     z = h @ w["w_router"]                                       # (B, T, E)
     p = jax.nn.softmax(z, -1)
-    if chosen is None:
-        chosen = jax.lax.top_k(p, top_k)[1]
+    chosen = chosen_experts(p, top_k, chosen)
     top = jnp.take_along_axis(p, chosen, -1)
     if renormalise:
         top = top / top.sum(-1, keepdims=True)
@@ -117,7 +118,8 @@ def hidden(weights, tokens, sizes, experts=None, act_dtype=None):
     """tokens (B, T) int32 -> (final-RMSNorm hidden (B, T, d), keys (rotated)
     and values of every layer, each (L, B, T, KV, hd), the router's logits
     (L, B, T, E)). ``experts`` (L, B, T, k) int32: the experts every token
-    takes in every layer; None: the router's own k best. ``act_dtype``: the
+    takes in every layer (a row with an entry under 0: the router's own k
+    best for that token there); None: the router's own k best. ``act_dtype``: the
     twin (module docstring); None: float32 throughout."""
     n_head, n_kv = sizes["num_attention_heads"], sizes["num_key_value_heads"]
     top_k, eps = sizes["num_experts_per_tok"], sizes["rms_norm_eps"]

@@ -659,3 +659,230 @@ def test_both_row_layouts_of_a_pool_read_the_same_numbers(side_by_side):
     wrong = {n: a[..., :-1] for n, a in flat.items()}
     with pytest.raises(RuntimeError, match="another shape"):
         check.pool_errors(wrong, ref["k"], ref["v"], 1, 12)
+
+
+# -- a routed stack whose rows skip layers: the hybrid fixture ------------------
+
+from benchmarks.tests import hybrid_standin, ref_hybrid_experts  # noqa: E402
+
+
+def hybrid_sizes(**sizes):
+    with open(os.path.join(HERE, "fixtures", "hybrid-experts.json")) as f:
+        return {**json.load(f), **sizes}
+
+
+def hybrid_verdict(dtype, seed=14, sizes=None, lengths=(33, 60), **engine):
+    """``check.serve_verdict`` whole over the stand-in engine: the fixture's
+    reference in ``dtype`` under its own routes (``hybrid_standin``), two
+    prompts and four decode steps."""
+    sizes = hybrid_sizes(**(sizes or {}))
+    server = hybrid_standin.server(sizes, seed, dtype=dtype, **engine)
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(0, sizes["vocab_size"], size=n, dtype=np.int32)
+               for n in lengths]
+    verdict = check.serve_verdict(ref_hybrid_experts, sizes, server, prompts, 4)
+    assert_held_to_the_twin(verdict)
+    assert server.engine.pool.used_count == 0
+    return verdict
+
+
+@pytest.mark.parametrize("dtype,margin,ok", [
+    ("bfloat16", None, True), ("bfloat16", 0.0, False),
+    ("float32", None, True), ("float32", 0.0, True)])
+def test_routes_are_followed_across_layers_that_cache_no_rows(
+        monkeypatch, dtype, margin, ok):
+    """Rows, state, state, state, rows: the four layers under the second
+    cached one are scored by its rows, the last by the emitted tokens. In
+    bf16 the stand-in goes another way than float32 in a few tokens of every
+    layer, one of them (seed 14, position 44) with a neighbour unsettled
+    just before it; the check lands on its table. With no margin there is
+    nothing to follow: bf16 fails, and float32, which routes as the
+    reference does, still agrees."""
+    if margin is not None:
+        monkeypatch.setattr(check, "SERVE_ROUTE_MARGIN", margin)
+    verdict = hybrid_verdict(dtype)
+    assert verdict["ok"] is ok, verdict
+    cap = 5 * (3 + check.SERVE_ROUTE_TRIES) * check.SERVE_ROUTE_SWEEPS \
+        + check.SERVE_ROUTE_SWEEPS
+    for case in verdict["cases"]:
+        # two planes under five routed layers, and the notes a layer
+        assert len(case["k_rel_layers"]) == len(case["twin_k_rel_layers"]) == 2
+        for key in ("margin", "banded", "followed", "gap_max", "cut",
+                    "sweeps", "unsettled"):
+            assert len(case[f"route_{key}_layers"]) == 5
+        assert case["route_gap_max_layers"] <= case["route_margin_layers"]
+        assert max(case["route_sweeps_layers"]) <= check.SERVE_ROUTE_SWEEPS
+        assert 5 <= case["route_forwards"] <= cap
+        # the last layer has the next layer's place: one sweep, the parent's
+        assert case["route_sweeps_layers"][4] == 1
+        if ok:
+            assert case["route_unsettled_layers"] == [0] * 5
+    followed = [sum(c["route_followed_layers"][:4]) for c in verdict["cases"]]
+    if dtype == "float32" or margin == 0.0:
+        assert followed == [0, 0]
+    else:
+        assert min(followed) > 0
+        # some tokens needed a second round, none the cap
+        assert max(max(c["route_sweeps_layers"]) for c in verdict["cases"]) > 1
+
+
+@pytest.mark.parametrize("fault", ["outside-the-margin", "a-dropped-route",
+                                   "gates-not-renormalised", "an-int8-row"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_hybrid_verdict_fails(fault, dtype):
+    """What following across layers may not forgive either (the faults of
+    ``test_the_routed_verdict_fails``, planted in the stand-in): the search
+    takes what brings rows near and the rows are held to the twin in the
+    end, so a fault leaves tokens unsettled and the verdict false."""
+    verdict = hybrid_verdict(dtype, fault=fault)
+    assert verdict["ok"] is False, verdict
+    worst = max(max(c["k_rel"], c["v_rel"]) for c in verdict["cases"])
+    assert worst > verdict["kv_rel_tol"]
+
+
+def test_a_stack_whose_last_layers_cache_nothing_is_scored_by_its_tokens():
+    """Rows, state, state: no cached layer stands over any routed layer, so
+    the three are followed together by the logit gaps of the tokens the
+    program emitted, and the rows of the one plane agree from the start."""
+    verdict = hybrid_verdict(
+        "bfloat16", sizes={"layer_types": ["rows", "state", "state"]})
+    assert verdict["ok"], verdict
+    for case in verdict["cases"]:
+        assert len(case["k_rel_layers"]) == 1
+        assert len(case["route_banded_layers"]) == 3
+        # only the rows that emitted a token have a say, five of them
+        assert max(case["route_banded_layers"]) <= 5
+        assert case["compared"]["logit_gap"][0] <= check.SERVE_LOGIT_GAP_TOL
+
+
+def planted(seed, flips):
+    """``follow_routes`` on the fixture in float32 against rows made by the
+    reference itself under a planted table: the reference's own choice but
+    for ``flips``, ``{(layer, position): which of its admissible sets}``.
+    -> (the planted table, the table found, the notes, and a layer's
+    positions that had a choice when the table was planted)."""
+    sizes = hybrid_sizes()
+    weights = ref_hybrid_experts.init_weights(jax.random.key(seed), sizes)
+    tokens = np.random.default_rng(seed).integers(
+        0, sizes["vocab_size"], size=(1, 72), dtype=np.int32)
+    top_k, n_prompt, emitted = sizes["num_experts_per_tok"], 60, [0] * 5
+    kv_tol = 0.01
+
+    @jax.jit
+    def hidden(experts):
+        x, ks, vs, router = ref_hybrid_experts.hidden(
+            weights, tokens, sizes, experts=experts[:, None])
+        return (ref_hybrid_experts.logits(weights, x[0, 59:64]),
+                ks[:, 0], vs[:, 0], router[:, 0])
+
+    # the planted table, layer by layer: a flip moves the logits above it
+    table, banded = np.full((5, 72, top_k), -1, np.int32), []
+    for layer in range(5):
+        router = np.asarray(hidden(table)[3][layer])
+        table[layer] = np.argsort(-router, -1, kind="stable")[:, :top_k]
+        live = router[:64]
+        margin = check.SERVE_ROUTE_MARGIN * kv_tol * float(np.sqrt(np.mean(
+            (live - live.mean(-1, keepdims=True)) ** 2)))
+        sets = {t: check.route_alternatives(router[t], top_k, margin)
+                for t in range(60)}
+        banded.append([t for t in sets if sets[t]])
+        for (at, t), which in flips.items():
+            if at == layer:
+                assert len(sets[t]) > which, "no such set inside the margin"
+                table[layer, t] = sets[t][which][1]
+    _, want_k, want_v, _ = hidden(table)
+
+    def row_distance(ks, vs, plane):
+        return sum(np.sum((np.asarray(a[plane]) - np.asarray(b[plane])) ** 2,
+                          (1, 2)) / np.sum(np.asarray(b[plane]) ** 2, (1, 2))
+                   for a, b in ((ks, want_k), (vs, want_v)))
+
+    found, notes = check.follow_routes(
+        hidden, row_distance, 5, 72, top_k, n_prompt, emitted, kv_tol,
+        ref_hybrid_experts.cached_layers(sizes))
+    return table, found, notes, banded
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_two_tokens_within_a_convolution_s_reach_are_both_followed(layer):
+    """A token and its neighbour both went elsewhere than the reference in
+    a layer under three layers that cache nothing (a state and a
+    convolution carry the first one's swap into the second one's rows):
+    the table found is the planted one, set for set, and so it is where
+    one of them also moved in the layer above."""
+    _, _, notes, banded = planted(5, {})
+    assert notes["route_followed_layers"] == [0] * 5    # nothing planted
+    first = next(t for t in banded[layer] if t + 1 in banded[layer])
+    second = first + 1
+    for flips in ({(layer, first): 0, (layer, second): 0},
+                  {(layer, first): 0, (layer, second): 0,
+                   (layer + 1, second): 0}):
+        want, found, notes, _ = planted(5, flips)
+        assert np.array_equal(np.sort(found[:4, :64]), np.sort(want[:4, :64]))
+        assert notes["route_unsettled_layers"] == [0] * 5
+        assert notes["route_followed_layers"][layer] == 2
+
+
+@pytest.mark.parametrize("module,file", [
+    (ref_hybrid_experts, None), (None, "tests/ref_rope_experts.py"),
+    (None, "references/deepseek_v3.py")])
+def test_a_table_of_entries_under_0_is_the_reference_s_own_choice(module, file):
+    """``experts`` all -1 is ``experts=None`` bit for bit, and a table that
+    holds the reference's own choice in some rows and -1 in the others too:
+    how a layer not yet followed runs."""
+    if module is not None:
+        sizes = hybrid_sizes()
+        weights = module.init_weights(jax.random.key(3), sizes)
+        tokens = np.random.default_rng(3).integers(
+            0, sizes["vocab_size"], size=(2, 40), dtype=np.int32)
+    elif file == "tests/ref_rope_experts.py":
+        cell = rehearse.tiny(fixture_cell())
+        module = spec.load_reference(cell.config)
+        sizes = cell.config
+        weights = module.weights_from_program(serve_cell.init_params(
+            spec.gpt_config(cell, training=False), SEED))
+        tokens = np.random.default_rng(3).integers(
+            0, 384, size=(2, 40), dtype=np.int32)
+    else:
+        from benchmarks.tests import reference_cases
+        module, weights, tokens, sizes = reference_cases.case(file)
+    plain = module.hidden(weights, tokens, sizes)
+    top_k = sizes["num_experts_per_tok"]
+    n_layer = plain[3].shape[0]
+    none = np.full((n_layer, *tokens.shape, top_k), -1, np.int32)
+    own = np.asarray(jax.lax.top_k(plain[3], top_k)[1], np.int32)
+    mixed = np.where(np.arange(tokens.shape[1])[:, None] % 2 == 0, own, -1)
+    for table in (none, mixed):
+        for a, b in zip(plain, module.hidden(weights, tokens, sizes,
+                                             experts=table)):
+            np.testing.assert_array_equal(a, b)
+
+
+def test_a_reference_that_names_no_cached_layers_gets_the_parent_s_table(
+        monkeypatch):
+    """``rope-experts`` caches a plane a layer and brings no
+    ``cached_layers``: the table found and the notes are the parent's
+    (PR 50's tree, this test's two prompts in bf16: the tables' digests and
+    the counts a layer), each layer in one sweep."""
+    import hashlib
+
+    tables = []
+    follow = check.follow_routes
+
+    def spy(*args, **kwargs):
+        assert len(args) + len(kwargs) == 9 and args[-1] is None
+        tables.append(follow(*args, **kwargs))
+        return tables[-1]
+
+    monkeypatch.setattr(check, "follow_routes", spy)
+    verdict = routed_verdict(dtype="bfloat16")
+    assert verdict["ok"]
+    assert [hashlib.sha256(table.tobytes()).hexdigest()[:16]
+            for table, _ in tables] == ["27f2bd8cc035d3bf", "a0948d8f77f63b3c"]
+    assert [notes["route_followed_layers"] for _, notes in tables] \
+        == [[0, 0, 0], [1, 2, 1]]
+    assert [notes["route_banded_layers"] for _, notes in tables] \
+        == [[6, 2, 2], [13, 14, 1]]
+    for _, notes in tables:
+        assert notes["route_sweeps_layers"] == [1, 1, 1]
+        assert notes["route_unsettled_layers"] == [0, 0, 0]

@@ -115,3 +115,93 @@ def test_a_cycle_deals_every_block_and_every_seed_the_same_lengths():
     # sum(n - 1) lane-rounds of decoding: a whole number of rounds of the
     # cell's 4 lanes, or the loop's cycle would be four blocks long, not one
     assert (n_out - 1).sum() % 4 == 0
+
+
+# -- every seed the same arrivals (PR 51) --------------------------------------
+
+#: ``traffic.digest`` of every mix on seeds 0-2 as the parent of PR 51
+#: generated it (vocabulary 50,257, a 36 s horizon, the rate and the warm
+#: start its cell had then). The two mixes PR 51 put into cycles are held
+#: with their cycle keys taken out again: the generator, not the file.
+PARENT_DIGESTS = {
+    "serve-decode": (1.75, 44, ["716dec64", "4808158d", "60e129b5"]),
+    "serve-long-decode": (3.08, 21, ["ba1a83b8", "2a402185", "8c73a1b6"]),
+    "serve-long-context": (0.28, 5, ["30624979", "f169d135", "cf7bcfc6"]),
+    "serve-looped-decode": (0.126, 2, ["1940c1d9", "9e7b7bd7", "52a8b6f8"]),
+    "serve-prefill": (None, 0, ["b119a338", "af453eb0", "05248c4a"]),
+}
+CYCLED = ("serve-decode", "serve-long-decode")
+
+
+def without_cycles(mix):
+    """The mix as it was before its gaps and warm start came in cycles."""
+    plain = lambda spec_: {k: v for k, v in spec_.items()
+                           if k not in ("order", "order_seed")}
+    if mix["loop"] != "open" or "order" not in mix["arrivals"]:
+        return mix
+    return dict(mix, arrivals=plain(mix["arrivals"]),
+                prompt_len=plain(mix["prompt_len"]),
+                output_len=plain(mix["output_len"]), warm_start=True)
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_DIGESTS))
+def test_a_mix_that_sets_no_cycle_generates_its_parent_s_requests(name):
+    rate, warm, want = PARENT_DIGESTS[name]
+    mix = without_cycles(load(name))
+    assert (mix != load(name)) == (name in CYCLED)
+    got = [traffic.digest(traffic.requests(
+        mix, 50257, seed, rate=rate, horizon_s=36.0, warm_inflight=warm))
+        for seed in (0, 1, 2)]
+    assert got == want
+
+
+@pytest.mark.parametrize("name", CYCLED)
+def test_under_cycles_two_seeds_differ_in_token_ids_alone(name):
+    mix = load(name)
+    assert mix["warm_start"]["order"] == mix["arrivals"]["order"] == "cycle"
+    seeds = {mix[k]["order_seed"] for k in
+             ("arrivals", "prompt_len", "output_len", "warm_start")}
+    assert len(seeds) == 4      # one order each, or long would meet long
+    a, b = (traffic.requests(mix, 50257, seed, rate=7.0, horizon_s=36.0,
+                             warm_inflight=21) for seed in (1, 2_500_000_007))
+    assert len(a) == len(b) > 21 + 200
+    assert [r.due_s for r in a] == [r.due_s for r in b]
+    assert [len(r.prompt) for r in a] == [len(r.prompt) for r in b]
+    assert [r.max_new_tokens for r in a] == [r.max_new_tokens for r in b]
+    assert any((x.prompt != y.prompt).any() for x, y in zip(a, b))
+    assert traffic.digest(a) != traffic.digest(b)
+    # the warm start's residual lengths too, and they are not all alike
+    assert len({r.max_new_tokens for r in a[:21]}) > 10
+    assert all(r.due_s == 0.0 for r in a[:21]) and a[21].due_s > 0.0
+
+
+@pytest.mark.parametrize("name", CYCLED)
+def test_every_block_of_a_cycle_repeats_its_gaps_and_lengths(name):
+    mix = load(name)
+    k = mix["arrivals"]["stratum"]
+    reqs = traffic.requests(mix, 50257, 3, rate=5.0, horizon_s=40.0)
+    assert len(reqs) >= 4 * k
+    gaps = np.diff([0.0] + [r.due_s for r in reqs])
+    for first in range(k, 3 * k + 1, k):
+        np.testing.assert_allclose(gaps[first:first + k], gaps[:k], rtol=1e-9)
+        assert [len(r.prompt) for r in reqs[first:first + k]] \
+            == [len(r.prompt) for r in reqs[:k]]
+        assert [r.max_new_tokens for r in reqs[first:first + k]] \
+            == [r.max_new_tokens for r in reqs[:k]]
+    # a block is the stratum's gaps once each and takes k / rate seconds
+    assert gaps[:k].sum() == pytest.approx(k / 5.0)
+    assert len(set(np.round(gaps[:k], 9))) == k
+    # no order is another's: short gaps do not always bring long prompts
+    n_in = np.array([len(r.prompt) for r in reqs[:k]])
+    n_out = np.array([r.max_new_tokens for r in reqs[:k]])
+    for x, y in ((gaps[:k], n_in), (gaps[:k], n_out), (n_in, n_out)):
+        assert abs(np.corrcoef(x, y)[0, 1]) < 0.5
+
+
+def test_a_warm_start_that_is_true_pairs_by_the_run_s_seed():
+    mix = dict(load("serve-long-decode"), warm_start=True)
+    a, b = (traffic.requests(mix, 50257, seed, rate=3.0, horizon_s=10.0,
+                             warm_inflight=21) for seed in (1, 2))
+    left = [[r.max_new_tokens for r in reqs[:21]] for reqs in (a, b)]
+    assert left[0] != left[1] and sum(left[0]) != sum(left[1])
+    assert [r.due_s for r in a] == [r.due_s for r in b]     # gaps in cycles
