@@ -30,6 +30,17 @@ cell's probabilities once, in one dq+dk+dv kernel (_dqkv_kernel_btd),
 wherever that kernel's dq scratch fits VMEM (_fused_bwd_fits: a rule over
 static shapes), and keeps the two-kernel form for the shapes beyond it.
 
+A diagonal cell (qi == kj) of the native-layout forward and fused backward
+computes nothing above the diagonal: where the block holds two or more
+groups of DIAG_GROUP_ROWS rows and the layer has no window, the cell is
+walked as a staircase, group r of the rows against keys [0, (r + 1) * g)
+of the cell, with the constant row >= col mask on the last g columns only
+(_diag_group_rows: a rule over the static block; 10 of a 512-cell's 16
+sub-tiles). At T = 1,024, where two of a sequence's three live cells are
+diagonal, that took 17.5% off the forward and 21.7% off the fused backward
+on a v5e (PERF.md, PR 54, the A/B). Windowed layers, blocks under 256, the
+split pair and the (BH, T, hd) kernels keep the whole-cell body.
+
 Falls back to the einsum oracle when the shape/config doesn't fit the kernel
 (attention dropout on, decode-time cross lengths, T not a multiple of the
 block) — correctness is never gated on the fast path. On CPU the kernel runs
@@ -141,7 +152,15 @@ def _dispatch_cells(compute, qi, kj, block, active, *, causal, window,
     and the mask-free interior (min q_pos at or past max k_pos, which
     generalises "strictly below the diagonal" to the ring's q_offset
     hops — full cells also cannot hold dead rows, so their p needs no
-    structural mask); non-causal is always mask-free."""
+    structural mask); non-causal is always mask-free.
+
+    What a masked cell then does is its kernel's: the (BH, T, hd) kernels
+    and the split native-layout pair mask the whole (block, block) cell by
+    position; the native-layout forward and fused backward, whose
+    q_offset is 0 (so a masked cell without a window IS the cell on the
+    diagonal, its corner the positions' origin), walk it as a staircase
+    of row groups that end at the diagonal wherever ``_diag_group_rows``
+    says so (PERF.md, PR 54: the A/B)."""
     if causal and window is not None:
         @pl.when(active)
         def _m():
@@ -675,6 +694,74 @@ def _btd_pack(h: int, hd: int) -> Optional[int]:
     return None
 
 
+#: Query rows a group of a diagonal cell takes (_diag_group_rows). Measured
+#: on a v5e (PERF.md, PR 54: jax.grad of causal_attention alone, bfloat16,
+#: device us of the Mosaic calls a call; whole cell -> groups of 256 -> of
+#: 128), forward and fused backward: (32, 1024, 12, 64), the 124M cell,
+#: 2,606 -> 2,413 -> 2,149 and 2,650 -> 2,227 -> 2,074; (32, 512, 12, 64),
+#: every cell diagonal, 860 -> 844 -> 606 and 835 -> 651 -> 573;
+#: (2, 4096, 32, 128), 8 diagonal cells of 36, 5,185 -> 5,118 -> 5,017 and
+#: 5,979 -> 5,649 -> 5,573. 128 won in both kernels at all five shapes
+#: probed (10 of 16 sub-tiles against 12), and is a lane tile's width: a
+#: narrower group's slices would cut registers. The parent's kernels at a
+#: grid block of 256, which skip the same work by grid steps, read 4,095
+#: and 4,302.
+DIAG_GROUP_ROWS = 128
+
+
+def _diag_group_rows(block: int) -> Optional[int]:
+    """How a native-layout kernel walks a diagonal cell (``qi == kj``,
+    causal, no window): in static groups of this many query rows, each
+    against the cell's keys up to the end of its own rows only, or None
+    for the whole cell at once (a block that holds fewer than two groups,
+    or no whole number of them). A rule over the static block alone: no
+    shape probed lost (hd 64 and 128, pack 2 and 1, nb 1 to 8), so neither
+    ``hd`` nor ``pack`` enters it, and ``FLASH_BLOCK`` does not either.
+
+    The forward takes a group as one pass of its chain over the group's
+    own width. The fused backward forms a group's scores and dp out of
+    matmuls made a tile of keys at a time against every row at or under
+    the tile: written a group at a time (128 rows against each tile of
+    keys) the same five matmuls gained nothing over the whole cell
+    (2,675 us against 2,650 at the 124M shape), and the forward written a
+    tile of keys at a time lost (2,443 against 2,149): PERF.md, PR 54."""
+    g = DIAG_GROUP_ROWS
+    return g if block >= 2 * g and block % g == 0 else None
+
+
+def _diag_groups(block: int, group: int):
+    """(rows, keys) of each group of a diagonal cell: the slice of its
+    query rows and how many of the cell's keys they can see."""
+    return [(slice(r * group, (r + 1) * group), (r + 1) * group)
+            for r in range(block // group)]
+
+
+def _diag_tile(group: int):
+    """``row >= col`` of a (group, group) tile: on the diagonal the cell's
+    corner is the positions' origin, so the mask is a constant."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (group, group), 0)
+            >= jax.lax.broadcasted_iota(jnp.int32, (group, group), 1))
+
+
+def _mask_diag_tile(s, tri):
+    """Scores of a group, (group, keys): every column but the last
+    ``group`` lies under the diagonal; those are wiped above it."""
+    keys, group = s.shape[1], tri.shape[0]
+    tile = jnp.where(tri, s[:, keys - group:], NEG_INF)
+    if keys == group:
+        return tile
+    return jnp.concatenate([s[:, :keys - group], tile], axis=1)
+
+
+def _diag_group_of(tall, r: int, group: int):
+    """Group ``r``'s (group, (r + 1) * group) out of products made a tile
+    of keys at a time: ``tall[c]`` holds key tile ``c`` against the rows
+    from its own group down, (block - c * group, group)."""
+    tiles = [tall[c][(r - c) * group:(r - c + 1) * group]
+             for c in range(r + 1)]
+    return tiles[0] if r == 0 else jnp.concatenate(tiles, axis=1)
+
+
 def _fwd_kernel_btd(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                     acc_scr, *, scale, block, hd, pack, window=None,
                     softcap=None):
@@ -688,11 +775,31 @@ def _fwd_kernel_btd(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    def _softmax_step(rows, s, vblk):
+        """One online-softmax update of the scratch rows ``rows`` (a
+        sub-head, or a sub-head and a slice of its rows) by the scores
+        ``s`` of those rows against the keys whose values ``vblk`` holds."""
+        m = m_scr[rows]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp2(s - m_new)
+        alpha = jnp.exp2(m - m_new)
+        m_scr[rows] = m_new
+        l_scr[rows] = l_scr[rows] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[rows] = acc_scr[rows] * alpha + jax.lax.dot_general(
+            p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    group = None if window is not None else _diag_group_rows(block)
+
     def _compute(masked):
         q_all = q_ref[0]  # (block, pack*hd)
         k_all = k_ref[0]
         v_all = v_ref[0]
-        if masked:
+        staircase = masked and group is not None  # qi == kj: see the rule
+        if staircase:
+            tri = _diag_tile(group)
+        elif masked:
             # causal/band mask built ONCE per cell, shared by all sub-heads
             q_pos = qi * block + jax.lax.broadcasted_iota(
                 jnp.int32, (block, block), 0)
@@ -706,22 +813,22 @@ def _fwd_kernel_btd(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             q = q_all[:, lo:hi]
             kblk = k_all[:, lo:hi]
             vblk = v_all[:, lo:hi]
+            if staircase:
+                # each group of rows is one pass of the chain over its own
+                # width: its own maximum, exp2, sum and p @ v
+                for rows, keys in _diag_groups(block, group):
+                    s, _ = _scores_base2(q[rows], kblk[:keys], scale,
+                                         softcap)
+                    _softmax_step((sh, rows), _mask_diag_tile(s, tri),
+                                  vblk[:keys])
+                continue
             s, _ = _scores_base2(q, kblk, scale, softcap)
             if masked:
                 # wipe-by-underflow invariant holds exactly as in
                 # _fwd_kernel (q_offset is always 0 here: every q row owns
                 # a live diagonal)
                 s = jnp.where(ok, s, NEG_INF)
-            m = m_scr[sh]
-            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp2(s - m_new)
-            alpha = jnp.exp2(m - m_new)
-            m_scr[sh] = m_new
-            l_scr[sh] = l_scr[sh] * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[sh] = acc_scr[sh] * alpha + jax.lax.dot_general(
-                p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            _softmax_step(sh, s, vblk)
 
     # full/masked cell routing shared with every kernel (_dispatch_cells)
     # — a large cut in a kernel that is VPU-bound, not MXU-bound, at hd=64
@@ -907,6 +1014,10 @@ def _dqkv_kernel_btd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     T=1024: 13.8 against the pair's 20.8; a third less at every shape
     probed (nb 1 to 16, hd 64 and 128, windowed), the gradients equal bit
     for bit (dq sums over k blocks in ascending order in both forms).
+    A diagonal cell is a staircase of row groups (_diag_group_rows): on
+    the chip its gradients are the whole cell's bit for bit too (PR 54: a
+    group is the 128 rows the MXU accumulates at a time, and what is
+    skipped added exact zeros).
 
     Mechanics: grid (B, H/pack, kj, qi) with qi innermost (the dkv
     ordering). dk/dv accumulate per kj in scratch exactly as before. dq
@@ -932,12 +1043,46 @@ def _dqkv_kernel_btd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
+    def _dp(do, vblk):
+        return jax.lax.dot_general(
+            do, vblk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    def _grads(kv_rows, dq_rows, p, t, q, kblk, vblk, do, delta, dp=None):
+        """What a cell (or a group of a diagonal cell) adds to the three
+        accumulators: p and do into ``dv_scr[kv_rows]``, ds and q into
+        ``dk_scr[kv_rows]``, ds and k into ``dq_all_scr[dq_rows]``."""
+        dv_scr[kv_rows] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if dp is None:
+            dp = _dp(do, vblk)
+        ds = p * (dp - delta.astype(jnp.float32))
+        if softcap is not None:
+            ds = ds * (1.0 - t * t)
+        ds = ds * scale
+        dk_scr[kv_rows] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dq_all_scr[dq_rows] += jax.lax.dot_general(
+            ds.astype(kblk.dtype), kblk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    group = None if window is not None else _diag_group_rows(block)
+
     def _compute(masked):
         q_all = q_ref[0]
         k_all = k_ref[0]
         v_all = v_ref[0]
         do_all = do_ref[0]
-        if masked:
+        staircase = masked and group is not None  # qi == kj: see the rule
+        if staircase:
+            tri = _diag_tile(group)
+        elif masked:
             q_pos = qi * block + jax.lax.broadcasted_iota(
                 jnp.int32, (block, block), 0)
             k_pos = kj * block + jax.lax.broadcasted_iota(
@@ -951,6 +1096,31 @@ def _dqkv_kernel_btd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             kblk = k_all[:, lo:hi]
             vblk = v_all[:, lo:hi]
             do = do_all[:, lo:hi]
+            if staircase:
+                # scores and dp a tile of keys at a time, each against all
+                # the rows at or under it (the matmul shape the A/B took:
+                # _diag_group_rows), then the chain and the three
+                # accumulating matmuls a group of rows at a time. No row
+                # is dead (q_offset is 0): lse is finite, and a score
+                # wiped to NEG_INF gives p = 0 by underflow
+                tall = [(slice(c * group, block),
+                         slice(c * group, (c + 1) * group))
+                        for c in range(block // group)]
+                s_t = [_scores_base2(q[rows], kblk[cols], scale, softcap)
+                       for rows, cols in tall]
+                dp = [_dp(do[rows], vblk[cols]) for rows, cols in tall]
+                for r, (rows, keys) in enumerate(
+                        _diag_groups(block, group)):
+                    of_group = lambda tiles: _diag_group_of(tiles, r, group)
+                    s = of_group([s for s, _ in s_t])
+                    t = None if softcap is None else of_group(
+                        [t for _, t in s_t])
+                    lse = lse_ref[0, sh, rows] * LOG2E
+                    _grads((sh, slice(0, keys)), (qi, sh, rows),
+                           jnp.exp2(_mask_diag_tile(s, tri) - lse), t,
+                           q[rows], kblk[:keys], vblk[:keys], do[rows],
+                           delta_ref[0, sh, rows], dp=of_group(dp))
+                continue
             lse = lse_ref[0, sh] * LOG2E  # natural -> base-2
             delta = delta_ref[0, sh]
             s, t = _scores_base2(q, kblk, scale, softcap)
@@ -959,26 +1129,7 @@ def _dqkv_kernel_btd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 p = jnp.where(ok, jnp.exp2(s - lse), 0.0)
             else:
                 p = jnp.exp2(s - lse)
-            dv_scr[sh] += jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dp = jax.lax.dot_general(
-                do, vblk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            ds = p * (dp - delta.astype(jnp.float32))
-            if softcap is not None:
-                ds = ds * (1.0 - t * t)
-            ds = ds * scale
-            dk_scr[sh] += jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dq_all_scr[qi, sh] += jax.lax.dot_general(
-                ds.astype(kblk.dtype), kblk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            _grads(sh, (qi, sh), p, t, q, kblk, vblk, do, delta)
 
     if window is not None:
         active = (qi >= _q_lo(kj, block, 0)) & (
@@ -1229,24 +1380,46 @@ def _flash_bwd_btd_fused(q, k, v, do, lse, delta, b, t, hd, pack, nb,
     return dq, dk, dv
 
 
+# The model reaches the native-layout kernels through two jitted functions,
+# so that a stack whose layers are written out traces and lowers each kernel
+# once a shape, not once a layer: the 124M step (12 layers) takes 4.6 s to
+# trace and lower here against 9.0 called bare, the staircase bodies having
+# five times the whole cell's equations, and 6.3 at PR 54's parent; the
+# compiled step is the same program either way (compile rehearsal, PR 54:
+# code size, temporaries, every instruction but the kernels' embedded
+# source lines). Their names hold neither kernel's: XLA names instructions
+# after them, and readers and tests find the kernels by name. What they
+# traced holds what _interpret() said then, a process's one answer; a test
+# that steers it forgets the traces (clear_cache) on both sides.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _native_forward(q, k, v, h, scale, block, window, softcap):
+    return _flash_fwd_btd(q, k, v, h, scale, block, window=window,
+                          softcap=softcap)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
+def _native_backward(q, k, v, out, lse, do, h, scale, block, window,
+                     softcap):
+    return _flash_bwd_btd(q, k, v, out, lse, do, h, scale, block,
+                          window=window, softcap=softcap)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_btd(q, k, v, h: int, scale: float, block: int, window=None,
                softcap=None):
-    out, _ = _flash_fwd_btd(q, k, v, h, scale, block, window=window,
-                            softcap=softcap)
+    out, _ = _native_forward(q, k, v, h, scale, block, window, softcap)
     return out
 
 
 def _flash_btd_fwd_rule(q, k, v, h, scale, block, window, softcap):
-    out, lse = _flash_fwd_btd(q, k, v, h, scale, block, window=window,
-                              softcap=softcap)
+    out, lse = _native_forward(q, k, v, h, scale, block, window, softcap)
     return out, (q, k, v, out, lse)
 
 
 def _flash_btd_bwd_rule(h, scale, block, window, softcap, res, do):
     q, k, v, out, lse = res
-    return _flash_bwd_btd(q, k, v, out, lse, do, h, scale, block,
-                          window=window, softcap=softcap)
+    return _native_backward(q, k, v, out, lse, do, h, scale, block, window,
+                            softcap)
 
 
 _flash_btd.defvjp(_flash_btd_fwd_rule, _flash_btd_bwd_rule)

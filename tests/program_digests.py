@@ -7,6 +7,10 @@ again with one command, which prints every table as its file spells it:
 
     PYTHONPATH=. python tests/program_digests.py
 
+It also reads the Pallas kernels out of a traced program (``pallas_calls``,
+``kernel_matmuls``, ``kernels_digest``: tests/test_flash_attention.py pins
+the kernels by themselves, whatever calls them).
+
 CPU, tiny, traced only: nothing is compiled."""
 
 import hashlib
@@ -30,6 +34,51 @@ def sha(jaxprs) -> str:
     text = re.sub(r"frozenset\(\{([^}]*)\}\)", lambda m: "frozenset({%s})"
                   % ", ".join(sorted(m.group(1).split(", "))), text)
     return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def pallas_calls(jaxpr, name=None):
+    return [eqn for eqn in _equations(getattr(jaxpr, "jaxpr", jaxpr))
+            if eqn.primitive.name == "pallas_call"
+            and name in (None, eqn.params["name"])]
+
+
+def kernel_matmuls(jaxpr, name):
+    """``dot_general``s in each ``pl.when`` body of the Pallas kernels named
+    ``name`` in a (closed) jaxpr: one list a call, the bodies in the
+    kernel's own order, bodies without a matmul left out."""
+    matmuls = lambda jaxpr: sum(
+        e.primitive.name == "dot_general" for e in _equations(jaxpr))
+    calls = []
+    for call in pallas_calls(jaxpr, name):
+        bodies = [matmuls(branch.jaxpr) for e in call.params["jaxpr"].eqns
+                  if e.primitive.name == "cond"
+                  for branch in e.params["branches"]]
+        calls.append([n for n in bodies if n])
+    return calls
+
+
+def kernels_digest(jaxpr):
+    """sha256 (tests/program_digests.sha) of a program's Pallas kernels and
+    nothing around them: each call's name, body, grid and block shapes and
+    its index maps."""
+    calls = pallas_calls(jaxpr)
+    assert calls
+    return sha([part for call in calls for part in (
+        [call.params["name"], call.params["jaxpr"],
+         call.params["grid_mapping"]]
+        + [bm.index_map_jaxpr
+           for bm in call.params["grid_mapping"].block_mappings])])
 
 
 def _abstract_params(cfg: GPTConfig):

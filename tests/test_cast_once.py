@@ -579,12 +579,19 @@ FLASH_CALLS = ("flash_fwd", "flash_bwd_fused", "flash_bwd_dq",
 def flash_compiled(monkeypatch):
     """``ops.flash_attention`` with its kernels compiled, not interpreted
     (the module asks the backend, which is the CPU here), and no variable
-    steering its choices."""
+    steering its choices. The kernels' jitted callers (PR 54) keep what they
+    traced, which holds the backend's answer: forgotten on the way in and on
+    the way out, so no trace of one kind meets a test of the other."""
     from mingpt_distributed_tpu.ops import flash_attention as flash
     monkeypatch.setattr(flash, "_interpret", lambda: False)
     monkeypatch.delenv("FLASH_BLOCK", raising=False)
     monkeypatch.delenv("FLASH_LAYOUT", raising=False)
-    return flash
+    callers = (flash._native_forward, flash._native_backward)
+    for jitted in callers:
+        jitted.clear_cache()
+    yield flash
+    for jitted in callers:
+        jitted.clear_cache()
 
 
 def mosaic_calls(text):
@@ -597,15 +604,19 @@ def mosaic_calls(text):
     return {call: sum(call in name for name in names) for call in FLASH_CALLS}
 
 
-@pytest.mark.parametrize("shape", [(1, 1024, 12, 64), (1, 1024, 25, 64)],
-                         ids=["124m", "xl-odd-heads"])
+@pytest.mark.parametrize("shape", [(1, 1024, 12, 64), (1, 1024, 25, 64),
+                                   (1, 2048, 2, 128), (1, 256, 12, 64)],
+                         ids=["124m", "xl-odd-heads", "hd128-pack1",
+                              "block256"])
 def test_the_flash_backward_compiles_as_one_kernel(shape, one_chip,
                                                    flash_compiled):
     """PR 50: at the training cells' shapes (XL's 25 heads through the
     zero-head pad) the gradient of ``causal_attention`` is the forward and
     ONE dq+dk+dv Mosaic kernel, and the chip's compiler takes it: the dq
-    slab's dynamic leading index, its VMEM. Interpret mode says nothing of
-    either."""
+    slab's dynamic leading index, its VMEM and, since PR 54, the diagonal
+    cells' staircases (row and lane slices of 128, a group's slices of the
+    scratch; one head a cell and a block of 256 beside the cells' shapes).
+    Interpret mode says nothing of any."""
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     text = jax.jit(jax.grad(
         lambda q, k, v: jnp.sum(flash_compiled.causal_attention(q, k, v)

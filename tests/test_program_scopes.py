@@ -34,6 +34,7 @@ from mingpt_distributed_tpu.telemetry import SpanTracer
 from mingpt_distributed_tpu.telemetry import programs as program_lib
 from mingpt_distributed_tpu.telemetry.programs import SCOPES, scope_table
 from mingpt_distributed_tpu.training.trainer import GPTTrainer
+from program_digests import kernel_matmuls, pallas_calls
 
 LOWERED = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
@@ -60,17 +61,19 @@ def programs_of(tracer):
     return [r for r in tracer.records() if r["kind"] == "program"]
 
 
-def make_trainer(tmp_path, mesh_cfg, n_devices, gpt_kw=None, **trainer_kw):
+def make_trainer(tmp_path, mesh_cfg, n_devices, gpt_kw=None, block_size=16,
+                 batch_size=16, **trainer_kw):
     ds = CharDataset(
-        DataConfig(path="<inline>", block_size=16, train_split=0.9),
+        DataConfig(path="<inline>", block_size=block_size, train_split=0.9),
         text="the step is lowered as it runs, shardings and all. " * 60)
     train, test = ds.split()
     gcfg = GPTConfig.make(**{**dict(
         n_layer=2, n_head=2, n_embd=32, vocab_size=ds.vocab_size,
-        block_size=16, embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0,
-        dtype="float32"), **(gpt_kw or {})})
+        block_size=block_size, embd_pdrop=0.0, resid_pdrop=0.0,
+        attn_pdrop=0.0, dtype="float32"), **(gpt_kw or {})})
     tcfg = TrainerConfig.make(
-        max_epochs=1, batch_size=16, grad_norm_clip=1.0, save_every=100,
+        max_epochs=1, batch_size=batch_size, grad_norm_clip=1.0,
+        save_every=100,
         log_every=1000, seed=7, snapshot_path=str(tmp_path / "s.msgpack"),
         **trainer_kw)
     mesh = mesh_lib.make_mesh(mesh_cfg, devices=jax.devices()[:n_devices])
@@ -371,15 +374,37 @@ def test_the_trainers_step_takes_the_fused_flash_backward(tmp_path):
         gpt_kw=dict(n_layer=3, n_embd=128, attention="flash",
                     unroll_layers=True))
     batch = trainer._put_batch(next(iter(trainer.train_iter.epoch_batches())))
-    text = str(jax.make_jaxpr(trainer._train_step)(
-        trainer.state, batch, trainer.base_rng))
-    calls = {n: len(re.findall(rf"name={n}\b", text)) for n in (
+    jaxpr = jax.make_jaxpr(trainer._train_step)(
+        trainer.state, batch, trainer.base_rng)
+    # by equation, not by text: since PR 54 the layers share one jitted
+    # caller a kernel, whose body the text prints once
+    calls = {n: len(pallas_calls(jaxpr, n)) for n in (
         "flash_fwd", "flash_bwd_fused", "flash_bwd_dq", "flash_bwd_dkv")}
     assert calls == {"flash_fwd": 3, "flash_bwd_fused": 3,
                      "flash_bwd_dq": 0, "flash_bwd_dkv": 0}
     [record] = programs_of(trainer.tracer)
     assert record["family"] == "train_step"
     assert "attn" in set(record["scopes"].values())
+
+
+def test_the_trainers_step_walks_diagonal_cells_as_staircases(tmp_path):
+    """PR 54: at the training cells' kernel shape (T = 1,024, so blocks of
+    512; heads of 64 in pairs) the step a flash trainer runs holds, in
+    every layer's ``flash_fwd`` and ``flash_bwd_fused``, a diagonal body of
+    four row groups a sub-head: 2 x 2 x 4 and 5 x 2 x 4 ``dot_general``s
+    beside the full cell's 2 x 2 and 5 x 2. Traced, never run;
+    ``tests/test_cast_once.py`` holds the kernels' names in a step
+    compiled for the described chip, ``tests/test_flash_attention.py``
+    the counts at the other shapes."""
+    trainer = make_trainer(
+        tmp_path, MeshConfig(dp=1), 1, block_size=1024, batch_size=2,
+        gpt_kw=dict(n_layer=3, n_embd=128, attention="flash",
+                    dtype="bfloat16", unroll_layers=True))
+    batch = trainer._put_batch(next(iter(trainer.train_iter.epoch_batches())))
+    jaxpr = jax.make_jaxpr(trainer._train_step)(
+        trainer.state, batch, trainer.base_rng)
+    assert kernel_matmuls(jaxpr, "flash_fwd") == [[16, 4]] * 3
+    assert kernel_matmuls(jaxpr, "flash_bwd_fused") == [[40, 10]] * 3
 
 
 def test_the_trainers_spans_jsonl_holds_the_record(tmp_path):
