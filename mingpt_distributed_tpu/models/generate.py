@@ -314,16 +314,18 @@ def _cached_block(
         raise ValueError(f"a position a lane takes one token a lane, not {t}")
 
     h = gpt.sublayer_input(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
-    rope = attn_ops.rope_tables(
-        jnp.asarray(offset)[..., None] + jnp.arange(t),
-        cfg.rope_dim, cfg.rope_theta) if cfg.rope else None
+    with jax.named_scope("qkv"):
+        rope = attn_ops.rope_tables(
+            jnp.asarray(offset)[..., None] + jnp.arange(t),
+            cfg.rope_dim, cfg.rope_theta) if cfg.rope else None
     if cfg.kv_lora_rank:
         # what is cached: "v" the normed latent, "k" the rotated rope key
         nope = cfg.qk_nope_head_dim
         q_nope, q_pe, v, k = gpt.latent_parts(h, blk, cfg, rope)
-        w_kv_b = blk["w_kv_b"].astype(x.dtype).reshape(
-            cfg.kv_lora_rank, nh, nope + cfg.v_head_dim)
-        q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, w_kv_b[..., :nope])
+        with jax.named_scope("qkv"):    # W_UK, absorbed into the queries
+            w_kv_b = blk["w_kv_b"].astype(x.dtype).reshape(
+                cfg.kv_lora_rank, nh, nope + cfg.v_head_dim)
+            q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, w_kv_b[..., :nope])
     else:
         q, k, v = gpt.attention_parts(h, blk, cfg, (nh, kv, hd), rope)
 
@@ -350,8 +352,9 @@ def _cached_block(
         else:
             att = attn_ops.latent_attention(
                 q_lat, q_pe, big_v, big_k, kv_offset=offset, scale=scale)
-        att = jnp.einsum("bthr,rhv->bthv", att, w_kv_b[..., nope:]).reshape(
-            b, t, nh * cfg.v_head_dim)
+        with jax.named_scope("qkv"):    # W_UV, on the heads' averages
+            att = jnp.einsum("bthr,rhv->bthv", att, w_kv_b[..., nope:]
+                             ).reshape(b, t, nh * cfg.v_head_dim)
     elif per_lane:
         att = attn_ops.causal_attend_step(
             q, cache["k"], cache["v"], plane, rows["k"], rows["v"], offset,
@@ -364,12 +367,16 @@ def _cached_block(
             kv_offset=offset, window=cfg.attention_window,
             logit_softcap=cfg.attn_logit_softcap,
         ).reshape(b, t, nh * hd)
-    x = x + gpt.attention_out(att, blk, cfg)
+    # a sum stands under the mark of the part it takes in: fused with that
+    # part's last matmul, the sum is the fusion's root
+    with jax.named_scope("attn_out"):
+        x = x + gpt.attention_out(att, blk, cfg)
 
     h2 = gpt.sublayer_input(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
     m, _, counts = gpt.mlp_branch(h2, blk, cfg, valid=valid,
                                   layer=expert_layer, lanes_apart=per_lane)
-    return x + m, cache, rows, counts
+    with jax.named_scope("ffn"):
+        return x + m, cache, rows, counts
 
 
 def _cached_hybrid_block(
@@ -407,7 +414,8 @@ def _cached_hybrid_block(
     b, t, _ = x.shape
     per_lane = jnp.ndim(offset) == 1
     positions = jnp.asarray(offset)[..., None] + jnp.arange(t)  # (T,) | (B,T)
-    u = L.rms_norm(x, blk["ln1_scale"], eps=cfg.norm_eps)
+    with jax.named_scope("norm"):
+        u = L.rms_norm(x, blk["ln1_scale"], eps=cfg.norm_eps)
     rows = counted = None
     if kind == LIGHTNING:
         state = cache[STATE][at]
@@ -552,13 +560,14 @@ def _forward_cached_hidden(
     the tokens it ran).
     """
     b, t = tokens.shape
-    x = params["wte"][tokens]
-    if not cfg.rope:
-        pos = jnp.asarray(offset)[..., None] + jnp.arange(t)  # (T,) or (B, T)
-        x = x + jnp.take(params["wpe"], pos, axis=0)
-    if cfg.scale_emb != 1.0:
-        x = x * cfg.scale_emb
-    x = x.astype(cfg.stream_dtype)
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens]
+        if not cfg.rope:
+            pos = jnp.asarray(offset)[..., None] + jnp.arange(t)  # (T,) | (B, T)
+            x = x + jnp.take(params["wpe"], pos, axis=0)
+        if cfg.scale_emb != 1.0:
+            x = x * cfg.scale_emb
+        x = x.astype(cfg.stream_dtype)
 
     if cfg.mixer_types is not None:
         x, cache = _forward_cached_hybrid(params, x, cache, offset, cfg, valid)
@@ -609,6 +618,7 @@ def _forward_cached_hidden(
     return x, cache
 
 
+@jax.named_scope("head")
 def _head_logits(params: gpt.Params, x: jax.Array, cfg: GPTConfig) -> jax.Array:
     """LM head over (B, t, D) hidden states -> (B, t, V) fp32 logits
     (with the Gemma-2 final softcap when configured)."""

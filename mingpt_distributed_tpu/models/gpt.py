@@ -325,12 +325,14 @@ def _manual_sp_attention(cfg: GPTConfig):
     return fn
 
 
+@jax.named_scope("norm")
 def _norm(x, scale, bias, cfg: GPTConfig):
     if cfg.rmsnorm:
         return L.rms_norm(x, scale, eps=cfg.norm_eps)
     return L.layer_norm(x, scale, bias, eps=cfg.norm_eps)
 
 
+@jax.named_scope("norm")
 def sublayer_input(x, scale, bias, cfg: GPTConfig):
     """A sublayer's normed input in the compute dtype: the norm of the
     residual stream, rounded to ``cfg.dtype`` where the stream is carried in
@@ -379,6 +381,7 @@ def head_boundaries(jaxpr) -> int:
     return found
 
 
+@jax.named_scope("qkv")
 def latent_parts(h, blk: Params, cfg: GPTConfig, rope):
     """What latent attention makes of (B, T, D) normed activations before
     it attends, in either form: the queries' nope part (B, T, H, nope) and
@@ -399,6 +402,7 @@ def latent_parts(h, blk: Params, cfg: GPTConfig, rope):
     return q[..., :nope], q_pe, latent, k_pe
 
 
+@jax.named_scope("qkv")
 def latent_qkv(h, blk: Params, cfg: GPTConfig, rope):
     """Latent attention as published, not absorbed: (B, T, D) normed
     activations -> per-head q, k (B, T, H, nope + rope) and v (B, T, H,
@@ -445,6 +449,7 @@ def rotated(q, k, rope, cfg: GPTConfig):
             attn_ops.apply_rope(k, cos, sin, cfg.rope_interleave))
 
 
+@jax.named_scope("qkv")
 def attention_parts(h, blk: Params, cfg: GPTConfig, heads, rope=None):
     """What per-head attention makes of (B, T, D) normed activations before
     it attends, the one place the projections are written (``latent_parts``
@@ -477,16 +482,19 @@ def _row_parallel(x, w, b, tp_axis: Optional[str]):
     return y if b is None else y + b.astype(y.dtype)
 
 
+@jax.named_scope("attn_out")
 def attention_out(att, blk: Params, cfg: GPTConfig,
                   tp_axis: Optional[str] = None):
     """Attention's (B, T, H * hd) output on its way back to the stream:
     through ``wo`` and, under ``cfg.post_norms``, its RMS norm."""
     att = _row_parallel(att, blk["wo"], blk.get("bo"), tp_axis)
     if cfg.post_norms:
-        att = L.rms_norm(att, blk["ln1_post_scale"], eps=cfg.norm_eps)
+        with jax.named_scope("norm"):
+            att = L.rms_norm(att, blk["ln1_post_scale"], eps=cfg.norm_eps)
     return att
 
 
+@jax.named_scope("ffn")
 def mlp_branch(h2, blk: Params, cfg: GPTConfig, *, valid=None, layer=None,
                lanes_apart: bool = False, tp_axis: Optional[str] = None,
                ep_axis: Optional[str] = None):
@@ -527,7 +535,8 @@ def mlp_branch(h2, blk: Params, cfg: GPTConfig, *, valid=None, layer=None,
         m = L.mlp_gelu(h2, blk["w_fc"], blk.get("b_fc"), blk["w_proj"],
                        blk.get("b_proj"))
     if cfg.post_norms:
-        m = L.rms_norm(m, blk["ln2_post_scale"], eps=cfg.norm_eps)
+        with jax.named_scope("norm"):
+            m = L.rms_norm(m, blk["ln2_post_scale"], eps=cfg.norm_eps)
     return m, aux, counts
 
 
@@ -539,6 +548,7 @@ def hybrid_layer_params(params: Params, cfg: GPTConfig, layer: int):
     return kind, {n: a[at] for n, a in params[MIXER_STACKS[kind]].items()}, at
 
 
+@jax.named_scope("qkv")
 def mixer_qkv(u, blk: Params, cfg: GPTConfig, kind: str, positions):
     """``attention_parts`` for a hybrid layer's mixer and its head counts:
     queries and keys RMS-normed per head (``qk_norm``) and, in a lightning
@@ -561,6 +571,7 @@ def sparse_rows(q, k, v):
             v.reshape(b, t, 1, kv * hd))
 
 
+@jax.named_scope("attn_out")
 def mixer_out(mixed, u, blk: Params, cfg: GPTConfig, kind: str):
     """A mixer's (B, T, H, hd) output to the residual stream's width: the
     lightning mixer's output norm, the gate ``sigmoid(W_g u)``, then W_o."""
@@ -597,10 +608,15 @@ def hybrid_mlp(x, mixed, blk: Params, cfg: GPTConfig):
     """The rest of a hybrid layer: the mixer's branch and the SwiGLU MLP's
     onto the residual stream, each times ``cfg.residual_scale``."""
     scale = cfg.residual_scale
-    x = x + (scale * mixed).astype(x.dtype)
-    h2 = L.rms_norm(x, blk["ln2_scale"], eps=cfg.norm_eps)
+    # a sum stands under the mark of the part it takes in: fused with that
+    # part's last matmul, the sum is the fusion's root
+    with jax.named_scope("attn_out"):
+        x = x + (scale * mixed).astype(x.dtype)
+    with jax.named_scope("norm"):
+        h2 = L.rms_norm(x, blk["ln2_scale"], eps=cfg.norm_eps)
     m, _, _ = mlp_branch(h2, blk, cfg)
-    return x + (scale * m).astype(x.dtype)
+    with jax.named_scope("ffn"):
+        return x + (scale * m).astype(x.dtype)
 
 
 def _hybrid_block(x, blk: Params, cfg: GPTConfig, kind: str) -> jax.Array:
@@ -608,7 +624,8 @@ def _hybrid_block(x, blk: Params, cfg: GPTConfig, kind: str) -> jax.Array:
     nothing cached: the form training and the uncached forward take, and
     the one the cached forms are held to."""
     b, t, _ = x.shape
-    u = L.rms_norm(x, blk["ln1_scale"], eps=cfg.norm_eps)
+    with jax.named_scope("norm"):
+        u = L.rms_norm(x, blk["ln1_scale"], eps=cfg.norm_eps)
     positions = jnp.arange(t)
     if kind == LIGHTNING:
         mixed, _ = lightning_mixer(u, blk, cfg, positions,
@@ -758,19 +775,20 @@ def forward(
     if return_gates and not cfg.exit_gate:
         raise ValueError("return_gates needs cfg.exit_gate")
 
-    x = params["wte"][tokens]  # (B, T, D) fp32 gather
-    if not cfg.rope:
-        # slice by *position*, add (the B4 fix: reference indexed pos table
-        # by token values and called a Parameter)
-        x = x + params["wpe"][:t]
-    if deterministic:
-        emb_key = None
-    else:
-        rng, emb_key = jax.random.split(rng)
-    x = L.dropout(x, cfg.embd_pdrop, emb_key, deterministic)
-    if cfg.scale_emb != 1.0:
-        x = x * cfg.scale_emb
-    x = x.astype(cfg.stream_dtype)
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens]  # (B, T, D) fp32 gather
+        if not cfg.rope:
+            # slice by *position*, add (the B4 fix: reference indexed pos
+            # table by token values and called a Parameter)
+            x = x + params["wpe"][:t]
+        if deterministic:
+            emb_key = None
+        else:
+            rng, emb_key = jax.random.split(rng)
+        x = L.dropout(x, cfg.embd_pdrop, emb_key, deterministic)
+        if cfg.scale_emb != 1.0:
+            x = x * cfg.scale_emb
+        x = x.astype(cfg.stream_dtype)
 
     if cfg.mixer_types is not None:
         if mesh is not None and mesh.shape.get("pp", 1) > 1:

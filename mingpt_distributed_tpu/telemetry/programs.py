@@ -21,16 +21,33 @@ program a call has already built, given the call's own abstract values
 (``abstract``), the lowering and the executable come back from the jit's own
 caches: the table is of the executable that runs and nothing is lowered
 again; a program no call has built yet is compiled here.
+
+A program whose owner is gone by the time somebody reads (a caller that
+drives the trainer's step itself and drops the trainer) files itself:
+``filing`` wraps a jitted program in a callable that, at its first call,
+puts the program and that call's abstract values in a process-wide, bounded
+index; ``filed_records`` makes the records of what the index holds, on
+demand, through ``program_records``.
+
+A record knows when it is stale. JAX's persistent cache keys an executable
+on its program less the debug information, and a scope is debug
+information: a program that differs from an earlier lowering only in its
+marks is served that lowering's executable, whose text carries the earlier
+names. So each record also holds the scopes its *lowering* carries, and
+where they are not the compiled text's it says which (``stale_scopes``): a
+reader takes nothing from such a table.
 """
 
 from __future__ import annotations
 
+import collections
 import re
+import threading
 import time
 from typing import Any, Dict, Iterable, Iterator, List, Tuple
 
-__all__ = ["SCOPES", "abstract", "compile_programs", "program_records",
-           "scope_table"]
+__all__ = ["SCOPES", "Filing", "abstract", "compile_programs", "filed_records",
+           "filing", "program_records", "scope_table"]
 
 #: every ``jax.named_scope`` name of the package
 #: (``tests/test_program_scopes.py`` holds the two lists equal)
@@ -38,6 +55,11 @@ SCOPES = (
     "attn", "mlp", "ce", "optimizer", "sample", "kv_layout", "cached_attn",
     "latent_attn", "moe_experts", "moe_shared", "lightning_scan",
     "lightning_step", "sparse_select", "sparse_attend", "exit_gate",
+    # the parts of a layer and the stack's two ends, in every program that
+    # runs them (``models/gpt.py``, ``models/generate.py``): with them
+    # ``attn`` and ``mlp`` are what is left of a training sublayer (the
+    # kernels, the residual sums, dropout)
+    "qkv", "attn_out", "ffn", "norm", "head", "embed",
 )
 
 Program = Tuple[str, str, Any, tuple, dict]
@@ -50,6 +72,11 @@ _COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
 _INSTRUCTION = re.compile(
     r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s.*?[\s)]([a-z][a-z0-9\-]*)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+# the name stack of a lowering's operation, as ``as_text(debug_info=True)``
+# prints it: `loc("jit(f)/attn/qkv/dot_general"(#loc7))`
+# (a file's location is `loc("gpt.py":12:3)`, no parenthesis after the quote,
+# and a traceback's frame is named after its function: no slash)
+_LOCATION = re.compile(r'loc\("([^"]*/[^"]*)"\(')
 # the computations a container runs as they stand (a fusion's `calls=` and a
 # reducer's `to_apply=` are the instruction's own insides)
 _CONTAINERS = ("while", "conditional", "call")
@@ -130,16 +157,132 @@ def scope_table(hlo_text: str) -> Dict[str, str]:
     return table
 
 
+def _scopes_in(names: Iterable[str]) -> set:
+    return {_scope_of(name) for name in names} - {""}
+
+
 def program_records(programs: Iterable[Program]) -> List[Dict[str, Any]]:
     """One ``program`` record for each of an owner's ``programs()``: the jit
     name a profile shows (the text's own ``HloModule`` line), when it was
-    made, the owner's family and variant, and the scope table."""
+    made, the owner's family and variant, the scope table, and the scopes
+    the program's lowering carries (``lowered_scopes``). Where those are not
+    the scopes of the compiled text, fused computations' insides included,
+    the executable is of another lowering (one the persistent cache served)
+    and the record names the scopes the two disagree on
+    (``stale_scopes``)."""
     records = []
-    for family, variant, compiled in compile_programs(programs):
-        text = compiled.as_text()
-        records.append({
+    for family, variant, jitted, args, kwargs in programs:
+        lowered = jitted.lower(*args, **kwargs)
+        text = lowered.compile().as_text()
+        ours = _scopes_in(_LOCATION.findall(lowered.as_text(debug_info=True)))
+        record = {
             "name": _MODULE.search(text).group(1), "ts": time.time(),
             "family": family, "variant": variant,
-            "scopes": scope_table(text),
-        })
+            "scopes": scope_table(text), "lowered_scopes": sorted(ours),
+        }
+        stale = ours ^ _scopes_in(_OP_NAME.findall(text))
+        if stale:
+            record["stale_scopes"] = sorted(stale)
+        records.append(record)
     return records
+
+
+class Filing:
+    """A jitted program that files itself at its first call: ``(family,
+    variant, jitted, the call's abstract values)`` goes into ``index``, and
+    every call is the jit's own. It holds no owner and no array, and costs a
+    step one flag test. Everything else (``lower``, ``trace``,
+    ``_cache_size``) is the jitted program's."""
+
+    def __init__(self, jitted, family: str, variant: str, index: "_Index"):
+        self.jitted, self.family, self.variant = jitted, family, variant
+        self._index, self._unfiled = index, True
+
+    def __call__(self, *args, **kwargs):
+        if self._unfiled:
+            self.file(args, kwargs)
+        return self.jitted(*args, **kwargs)
+
+    def __getattr__(self, name):
+        return getattr(self.jitted, name)
+
+    def file(self, args: tuple, kwargs: dict) -> None:
+        """File the program as a call with ``args`` builds it (arrays, or
+        their abstract values). A call under a trace (``jax.make_jaxpr`` of
+        the wrapper) is not a call of the program and files nothing."""
+        import jax
+
+        if any(isinstance(x, jax.core.Tracer)
+               for x in jax.tree.leaves((args, kwargs))):
+            return
+        self._unfiled = False
+        self._index.put(self, (self.family, self.variant, self.jitted,
+                               abstract(args), abstract(kwargs)))
+
+    def record(self, args: tuple, kwargs: dict) -> Dict[str, Any]:
+        """The program's record, out of the index: filed with ``args`` (an
+        owner's own statement of its program) where no call has filed it or
+        the index has since let it go."""
+        found = self._index.record_of(self)
+        if found is None:
+            self.file(args, kwargs)
+            found = self._index.record_of(self)
+        return found
+
+
+class _Index:
+    """The programs that filed themselves, oldest first, at most
+    ``capacity`` of them (a process may build hundreds of trainers): each
+    with its record once somebody has read it. An entry holds the jitted
+    program (its traces and executables, which is where a record comes
+    from) and abstract values; never an array."""
+
+    def __init__(self, capacity: int):
+        self.capacity = capacity
+        self._lock = threading.Lock()
+        self._entries: "collections.OrderedDict[Filing, list]" = \
+            collections.OrderedDict()
+
+    def put(self, key: Filing, program: Program) -> None:
+        with self._lock:
+            self._entries.pop(key, None)
+            self._entries[key] = [program, None]
+            while len(self._entries) > self.capacity:
+                self._entries.popitem(last=False)
+
+    def record_of(self, key: Filing):
+        with self._lock:
+            entry = self._entries.get(key)
+        if entry is None:
+            return None
+        if entry[1] is None:
+            entry[1] = dict(program_records([entry[0]])[0], kind="program")
+        return dict(entry[1])
+
+    def records(self) -> List[Dict[str, Any]]:
+        with self._lock:
+            keys = list(self._entries)
+        return [r for r in map(self.record_of, keys) if r is not None]
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+
+#: the process's index; 8 programs are more than one process runs at a time
+FILED = _Index(capacity=8)
+
+
+def filing(jitted, family: str, variant: str = "") -> Filing:
+    """``jitted`` as a callable that files itself in the process's index at
+    its first call (:class:`Filing`)."""
+    return Filing(jitted, family, variant, FILED)
+
+
+def filed_records() -> List[Dict[str, Any]]:
+    """The ``program`` records of the programs that have filed themselves
+    in this process, oldest first: made at the first read and kept (nothing
+    is lowered or compiled until somebody reads, and then lowering and
+    executable are the jit's own, found by the call's abstract values). A
+    reader that holds a device profile sums it by scope with them, whoever
+    owned the programs and whether or not that owner still exists."""
+    return FILED.records()

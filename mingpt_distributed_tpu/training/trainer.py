@@ -27,6 +27,8 @@ logs (B11).
 from __future__ import annotations
 
 import dataclasses
+import json
+import os
 import signal
 import threading
 from typing import Any, Dict, Optional, Tuple
@@ -491,6 +493,14 @@ class GPTTrainer:
             out_shardings=(self.shardings, self.repl),
             donate_argnums=(0,),
         )
+        # the step files itself at its first call, whoever makes it
+        # (telemetry/programs.py): a caller that drives it and drops the
+        # trainer leaves the step's table behind for whoever holds a profile
+        # (wrapped in a statement of its own: graftlint's donation rule
+        # reads the ``jax.jit(.., donate_argnums=..)`` assignment above)
+        self._train_step = program_lib.filing(
+            self._train_step, "train_step",
+            "zero" if self.zero_plan is not None else "dense")
         self._eval_step = jax.jit(
             make_eval_step(gpt_config, self.mesh),
             in_shardings=(self.shardings, (self.batch_sharding,) * 2),
@@ -511,9 +521,11 @@ class GPTTrainer:
             log_event(gpt.model_size_report(self.state["params"], gpt_config),
                       tracer=self.tracer)
         # which named scope each instruction of the step came from: made
-        # when somebody first reads the tracer (or with ``spans_jsonl``, now)
-        self.tracer.pin("program", lambda: program_lib.program_records(
-            self.programs()))
+        # when somebody first reads the tracer (or with ``spans_jsonl``,
+        # now), and the one record the step's own filing keeps
+        self.tracer.pin("program", lambda: [
+            step.record(args, kwargs)
+            for _, _, step, args, kwargs in self.programs()])
 
     # ------------------------------------------------------------------
     def _fresh_state(self, rng) -> TrainState:
@@ -549,8 +561,7 @@ class GPTTrainer:
         tok = jax.ShapeDtypeStruct(
             (self.config.batch_size, block), jnp.int32)
         rng_abs = jax.eval_shape(lambda: self.base_rng)
-        yield ("train_step",
-               "zero" if self.zero_plan is not None else "dense",
+        yield (self._train_step.family, self._train_step.variant,
                self._train_step, (state_abs, (tok, tok), rng_abs), {})
 
     def audit_contracts(self) -> dict:
@@ -723,6 +734,12 @@ class GPTTrainer:
                         jax.block_until_ready(m)
                         jax.profiler.stop_trace()
                         self._tracing = False
+                        # the tables of the programs that ran, beside the
+                        # trace: the profile can be summed by scope as it
+                        # stands, with or without ``spans_jsonl``
+                        with open(os.path.join(cfg.profile_dir,
+                                               "programs.json"), "w") as f:
+                            json.dump(program_lib.filed_records(), f)
                         log_event(
                             f"profiler trace written to {cfg.profile_dir}",
                             tracer=self.tracer, step=step,

@@ -8,8 +8,9 @@ import importlib
 
 import pytest
 
-MODULES = ("test_flops", "test_reference", "test_scopes", "test_serve_blocks",
-           "test_spans", "test_spec", "test_trace", "test_traffic")
+MODULES = ("test_flops", "test_model_scopes", "test_reference", "test_scopes",
+           "test_serve_blocks", "test_spans", "test_spec", "test_trace",
+           "test_traffic")
 
 _OWNER = {}     # public name -> the module of MODULES that defines it
 for _name in MODULES:

@@ -30,6 +30,7 @@ from mingpt_distributed_tpu.data.char_dataset import CharDataset
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.serving import InferenceServer, Request
+from mingpt_distributed_tpu.serving.engine import DecodeEngine
 from mingpt_distributed_tpu.telemetry import SpanTracer
 from mingpt_distributed_tpu.telemetry import programs as program_lib
 from mingpt_distributed_tpu.telemetry.programs import SCOPES, scope_table
@@ -55,6 +56,21 @@ def lowerings():
     monitoring.register_event_duration_secs_listener(listener)
     yield seen
     monitoring.unregister_event_duration_listener(listener)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def executables_of_this_tree():
+    """The suite's persistent cache keys a program less its scope names
+    (``test_a_mark_is_no_part_of_the_persistent_cache_s_key``): a cache an
+    earlier state of the code filled would serve these tests that state's
+    tables. They compile their own."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
 
 
 def programs_of(tracer):
@@ -339,25 +355,194 @@ def test_the_trainers_record_names_the_step(tmp_path):
         record["scopes"].values())
 
 
-def test_the_layer_s_scopes_are_training_s_alone(cfg_params, tmp_path):
-    """``attn`` and ``mlp`` are marks of ``gpt._block``'s own body. The
-    cached forward carries neither, and the serving readers count on it:
-    ``engine.unscoped_ms_per_step`` is the trunk because nothing of a decode
-    step's trunk has a scope (PERF.md, section 3). A function the two bodies
-    share that brought a scope of its own into the cached forward fails
-    this."""
+#: the parts of a layer and the stack's two ends (PR 55)
+PARTS = {"qkv", "attn_out", "ffn", "norm", "head", "embed"}
+
+
+@pytest.fixture(scope="module")
+def served(cfg_params):
     cfg, params = cfg_params
     tracer = SpanTracer()
     InferenceServer(params, cfg, n_slots=2, tracer=tracer, warmup=True,
                     prefill_buckets=(8, 16))
-    served = programs_of(tracer)
-    assert {r["family"] for r in served} == {"prefill", "decode"}
-    for record in served:
-        assert not {"attn", "mlp"} & set(record["scopes"].values()), \
-            (record["family"], record["variant"])
+    return programs_of(tracer)
+
+
+@pytest.mark.parametrize("family", ["prefill", "decode"])
+def test_a_served_table_names_the_parts_of_a_layer(served, family):
+    """Every part of a layer says which part it is in every program that
+    runs it: the cached forward's tables hold the six marks, so that
+    ``engine.unscoped_ms_per_step`` is the true residue (PERF.md, section
+    3). ``attn`` and ``mlp`` stay ``gpt._block``'s own: the cached bodies
+    carry neither."""
+    records = [r for r in served if r["family"] == family]
+    assert records and {r["family"] for r in served} == {"prefill", "decode"}
+    for record in records:
+        scoped = set(record["scopes"].values())
+        assert PARTS <= scoped, (record["variant"], PARTS - scoped)
+        assert not {"attn", "mlp", "ce", "optimizer"} & scoped
+        assert PARTS <= set(record["lowered_scopes"])
+        assert "stale_scopes" not in record
+
+
+@pytest.mark.parametrize("arch, own", [
+    ("latent-experts", {"latent_attn", "moe_experts", "moe_shared"}),
+    ("hybrid", {"lightning_step", "sparse_select", "sparse_attend"}),
+    ("looped", {"cached_attn", "exit_gate"})])
+def test_an_architecture_s_own_scopes_stay_innermost(arch, own):
+    """``moe_experts`` inside ``ffn``, a mixer's step between ``qkv`` and
+    ``attn_out``: the innermost mark names an instruction, so the readers
+    of PRs 34-37 keep their rows, and the parts' marks take the rest."""
+    from test_cast_once import model
+
+    cfg, params = model(arch, "bfloat16")
+    engine = DecodeEngine(params, cfg, n_slots=3)
+    [record] = program_lib.program_records(
+        p for p in engine.programs() if p[0] == "decode")
+    scoped = set(record["scopes"].values())
+    assert own | PARTS <= scoped, (own | PARTS) - scoped
+    assert "stale_scopes" not in record
+
+
+def test_the_trainers_table_holds_the_parts_with_attn_and_mlp_the_residue(
+        tmp_path):
+    """Training's head stays inside ``ce``; ``attn`` and ``mlp`` are what
+    is left of a sublayer once projections and norms are named (the
+    attention itself, the residual sums, dropout)."""
     [step] = programs_of(make_trainer(tmp_path, MeshConfig(dp=1), 1).tracer)
-    assert step["family"] == "train_step"
-    assert {"attn", "mlp"} <= set(step["scopes"].values())
+    assert step["family"] == "train_step" and "stale_scopes" not in step
+    scoped = set(step["scopes"].values())
+    assert (PARTS - {"head"}) | {"attn", "mlp", "ce", "optimizer"} <= scoped
+    assert "head" not in scoped
+    # the MLP's matmuls left ``mlp`` and the projections' left ``attn``,
+    # which keeps the attention's own two products
+    trainer = make_trainer(tmp_path, MeshConfig(dp=1), 1)
+    batch = trainer._put_batch(next(iter(trainer.train_iter.epoch_batches())))
+    text = trainer._train_step.lower(
+        trainer.state, batch, trainer.base_rng).as_text(debug_info=True)
+    dots = [program_lib._scope_of(name) for name in re.findall(
+        r'loc\("([^"]*dot_general)"\(', text)]
+    assert {"qkv", "attn", "attn_out", "ffn"} <= set(dots)
+    assert "mlp" not in dots
+
+
+@pytest.fixture
+def index(monkeypatch):
+    """An index of this test's own in the process's place."""
+    fresh = program_lib._Index(capacity=8)
+    monkeypatch.setattr(program_lib, "FILED", fresh)
+    return fresh
+
+
+@pytest.mark.parametrize("mesh_cfg, n_devices", [
+    (MeshConfig(dp=1), 1), (MeshConfig(dp=1, fsdp=4), 4)],
+    ids=["one-device", "fsdp4"])
+def test_a_step_files_itself_at_its_first_call_and_holds_no_array(
+        tmp_path, index, lowerings, mesh_cfg, n_devices):
+    """The benchmark's training loop drives ``_put_batch`` and
+    ``_train_step`` itself and drops the trainer: the step's table must
+    outlive both, cost a step one flag test, and be made, when somebody
+    reads, from the executable the calls built (XL's step takes minutes to
+    compile: a second lowering ended PR 52's traced run)."""
+    trainer = make_trainer(tmp_path, mesh_cfg, n_devices)
+    assert len(index) == 0                  # nothing is filed by building
+    jax.make_jaxpr(trainer._train_step)(
+        trainer.state, trainer._put_batch(
+            next(iter(trainer.train_iter.epoch_batches()))),
+        trainer.base_rng)
+    assert len(index) == 0                  # nor by a trace of the wrapper
+    for xy in list(trainer.train_iter.epoch_batches())[:2]:
+        trainer.state, m = trainer._train_step(
+            trainer.state, trainer._put_batch(xy), trainer.base_rng)
+    jax.block_until_ready(m)
+    assert len(index) == 1                  # once, at the first call
+    (_, _, jitted, args, kwargs), _ = next(iter(index._entries.values()))
+    assert jitted is trainer._train_step.jitted and kwargs == {}
+    assert all(isinstance(x, jax.ShapeDtypeStruct)
+               for x in jax.tree.leaves(args))
+    # the owner and its buffers go, as ``train_cell.run`` lets them
+    for leaf in jax.tree.leaves(trainer.state):
+        leaf.delete()
+    del trainer, jitted, args
+    before = len(lowerings)
+    [record] = program_lib.filed_records()
+    assert len(lowerings) == before         # lowering and executable: the
+    assert (record["kind"], record["name"], record["family"]) == (
+        "program", "jit_train_step", "train_step")      # jit's own caches
+    assert PARTS - {"head"} <= set(record["scopes"].values())
+    assert "stale_scopes" not in record
+    if n_devices > 1:       # the table of the sharded step that ran
+        assert any(re.match(r"all-(gather|reduce)|reduce-scatter", k)
+                   for k in record["scopes"])
+    assert program_lib.filed_records() == [record]      # made once, kept
+    assert len(lowerings) == before
+
+
+def test_the_tracers_record_is_the_filed_one(tmp_path, index):
+    """``tracer.pin`` is built on the index's record, not a second maker:
+    before any call the trainer files the step itself, with the abstract
+    values ``programs()`` states."""
+    trainer = make_trainer(tmp_path, MeshConfig(dp=1), 1)
+    [pinned] = programs_of(trainer.tracer)
+    [filed] = program_lib.filed_records()
+    assert pinned == filed and len(index) == 1
+
+
+def test_the_profile_window_leaves_the_tables_beside_the_trace(tmp_path,
+                                                               index):
+    """``TrainerConfig.profile_dir``: when the trainer's own window closes,
+    ``programs.json`` holds the filed records, so the profile can be summed
+    by scope without ``spans_jsonl``."""
+    trainer = make_trainer(tmp_path, MeshConfig(dp=1), 1, max_steps=3,
+                           profile_dir=str(tmp_path / "profile"),
+                           profile_steps=(1, 2))
+    trainer.train()
+    with open(tmp_path / "profile" / "programs.json") as f:
+        [record] = json.load(f)
+    assert record["name"] == "jit_train_step" and record["kind"] == "program"
+    assert {"qkv", "ffn", "ce"} <= set(record["scopes"].values())
+
+
+def test_a_mark_is_no_part_of_the_persistent_cache_s_key(tmp_path):
+    """JAX's persistent cache keys a program less its debug information,
+    and a ``named_scope`` is debug information: the second of two
+    lowerings that differ only in a mark is served the first's executable,
+    whose text carries the first's names. The record says so
+    (``stale_scopes``), and a reader takes nothing from it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def marked(scope):
+        def f(x, w):
+            with jax.named_scope(scope):
+                y = x @ w
+            return jnp.sin(y) + 1.0
+        return jax.jit(f)
+
+    x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
+    was = {name: getattr(jax.config, name) for name in (
+        "jax_enable_compilation_cache", "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        compilation_cache.reset_cache()
+        [first] = program_lib.program_records(
+            [("f", "", marked("qkv"), (x, x), {})])
+        [second] = program_lib.program_records(
+            [("f", "", marked("ffn"), (x, x), {})])
+    finally:
+        for name, value in was.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+    assert "stale_scopes" not in first
+    assert first["lowered_scopes"] == ["qkv"]
+    assert "qkv" in set(first["scopes"].values())
+    assert second["lowered_scopes"] == ["ffn"]
+    assert "qkv" in set(second["scopes"].values())      # the first's names
+    assert second["stale_scopes"] == ["ffn", "qkv"]
 
 
 def test_the_trainers_step_takes_the_fused_flash_backward(tmp_path):
@@ -423,6 +608,9 @@ def test_on_a_mesh_the_trainers_table_is_of_the_step_that_runs(tmp_path):
     of the executable a call with the live, sharded state builds."""
     trainer = make_trainer(tmp_path, MeshConfig(dp=1, fsdp=4), 4)
     batch = trainer._put_batch(next(iter(trainer.train_iter.epoch_batches())))
+    # the step files itself with this call's own abstract values (PR 55)
+    trainer.state, _ = trainer._train_step(
+        trainer.state, batch, trainer.base_rng)
     ran = trainer._train_step.lower(
         trainer.state, batch, trainer.base_rng).compile().as_text()
     assert {c["op"] for c in collective_inventory(ran)} >= {

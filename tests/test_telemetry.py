@@ -12,6 +12,7 @@ import urllib.request
 import pytest
 
 from mingpt_distributed_tpu import telemetry
+from mingpt_distributed_tpu.telemetry import programs as program_lib
 from mingpt_distributed_tpu.telemetry import (
     LATENCY_BUCKETS_S,
     PEAK_FLOPS,
@@ -608,3 +609,58 @@ def test_training_metrics_reexports_peaks():
 def test_get_registry_and_tracer_are_process_singletons():
     assert telemetry.get_registry() is telemetry.get_registry()
     assert telemetry.get_tracer() is telemetry.get_tracer()
+
+
+# ---------------------------------------------------------------------------
+# programs that file themselves (ISSUE 55)
+# ---------------------------------------------------------------------------
+
+
+def test_a_filing_program_files_itself_once_and_is_the_jit_otherwise():
+    import jax
+    import jax.numpy as jnp
+
+    index = program_lib._Index(capacity=4)
+
+    def f(x, scale=2.0):
+        with jax.named_scope("ffn"):
+            return x * scale
+
+    step = program_lib.Filing(jax.jit(f), "f", "v", index)
+    # a trace of the wrapper is no call of the program
+    jax.make_jaxpr(step)(jnp.ones(3))
+    assert len(index) == 0
+    assert step(jnp.ones(3), scale=3.0).tolist() == [3.0, 3.0, 3.0]
+    assert step(jnp.ones(3), scale=4.0).tolist() == [4.0, 4.0, 4.0]
+    assert len(index) == 1
+    (family, variant, jitted, args, kwargs), _ = index._entries[step]
+    assert (family, variant, jitted) == ("f", "v", step.jitted)
+    assert isinstance(args[0], jax.ShapeDtypeStruct) and args[0].shape == (3,)
+    assert kwargs["scale"].shape == ()          # abstract too: no array kept
+    # everything else is the jitted program's
+    assert "mul" in step.lower(jnp.ones(3)).as_text()
+    assert step.trace(jnp.ones(3)).jaxpr is not None
+    [record] = index.records()
+    assert record["kind"] == "program" and record["name"] == "jit_f"
+    assert record["lowered_scopes"] == ["ffn"] and "stale_scopes" not in record
+    assert "ffn" in set(record["scopes"].values())
+    json.dumps(record)                          # plain data
+
+
+def test_the_index_is_bounded_and_keeps_the_newest():
+    import jax
+    import jax.numpy as jnp
+
+    index = program_lib._Index(capacity=2)
+    steps = [program_lib.Filing(jax.jit(lambda x, k=k: x + k), "f", str(k),
+                                index) for k in range(3)]
+    for step in steps:
+        assert float(step(jnp.ones(()))) == 1.0 + int(step.variant)
+        step(jnp.ones(()))                  # a second call files nothing
+    assert len(index) == 2
+    assert [r["variant"] for r in index.records()] == ["1", "2"]
+    assert index.record_of(steps[0]) is None
+    # an owner that still holds the evicted program files it again
+    again = steps[0].record((jnp.ones(()),), {})
+    assert again["variant"] == "0" and len(index) == 2
+    assert [r["variant"] for r in index.records()] == ["2", "0"]
