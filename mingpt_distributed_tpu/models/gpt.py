@@ -752,7 +752,8 @@ def forward(
     are given. ``return_logits=False`` (the trainer's loss-only mode)
     returns ``(None, loss)`` and — when ``cfg.loss_chunks`` applies — never
     materialises the (B, T, V) logits at all: the LM head + softmax run per
-    sequence chunk under jax.checkpoint (see chunked_cross_entropy).
+    sequence chunk, and under differentiation a chunk's gradient is taken
+    in the same sweep (see chunked_cross_entropy).
 
     A looped stack (``cfg.n_passes`` > 1) runs its layers that many times
     over the one set of weights; the final norm closes every pass, and its
@@ -804,7 +805,7 @@ def forward(
             step = functools.partial(_hybrid_block, cfg=cfg, kind=kind)
             x = (jax.checkpoint(step) if cfg.remat else step)(x, blk)
         return _head_and_loss(params, x, cfg, targets, return_logits,
-                              jnp.zeros((), jnp.float32))
+                              jnp.zeros((), jnp.float32), mesh=mesh)
 
     rope = None
     if cfg.rope:
@@ -1018,12 +1019,13 @@ def forward(
             if cfg.exit_gate:
                 gates.append(exit_gate_logits(params, x))
     out = _head_and_loss(params, x, cfg, targets, return_logits, moe_aux,
-                         normed=cfg.closes_passes)
+                         normed=cfg.closes_passes, mesh=mesh)
     return (*out, jnp.stack(gates)) if return_gates else out
 
 
 def _head_and_loss(params: Params, x, cfg: GPTConfig, targets,
-                   return_logits: bool, moe_aux, normed: bool = False):
+                   return_logits: bool, moe_aux, normed: bool = False,
+                   mesh=None):
     """The final norm (but for ``normed`` hidden states, which a looped
     stack's last pass hands over), the LM head and the loss of ``forward``:
     (logits or None, loss or None)."""
@@ -1055,16 +1057,19 @@ def _head_and_loss(params: Params, x, cfg: GPTConfig, targets,
     loss = None
     if targets is not None:
         if chunked:
-            # loss-only mode: the LM head + softmax run per sequence chunk
-            # under jax.checkpoint, so the full (B, T, V) fp32 logits
-            # (1.6 GB at B=8/T=1024/V=50257 — the tensor that caps the
-            # per-chip batch) never materialises, forward or backward.
-            # When logits are requested they exist anyway, so dense CE
-            # costs no extra memory — no chunking in that case.
+            # loss-only mode: the LM head + softmax run per sequence chunk,
+            # so the full (B, T, V) fp32 logits (1.6 GB at B=8/T=1024/
+            # V=50257 — the tensor that caps the per-chip batch) never
+            # materialises: differentiated, a chunk leaves its dx and its
+            # addend to dW behind, not its logits, and the backward holds
+            # no head matmul. When logits are requested they exist anyway,
+            # so dense CE costs no extra memory — no chunking in that case.
             loss = chunked_cross_entropy(
                 x, w_head.astype(x.dtype), targets, nc,
                 softcap=cfg.final_logit_softcap,
                 unroll=cfg.unroll_layers,
+                batch_shards=1 if mesh is None else math.prod(
+                    mesh.shape[a] for a in BATCH_AXES),
             )
         else:
             loss = cross_entropy(logits, targets)
@@ -1091,25 +1096,47 @@ def chunked_cross_entropy(
     x: jax.Array, w_head: jax.Array, targets: jax.Array, n_chunks: int,
     softcap: Optional[float] = None,
     unroll: bool = False,
+    batch_shards: int = 1,
 ) -> jax.Array:
     """Same math as ``cross_entropy(x @ w_head, targets)``, but the head
-    matmul + softmax run per sequence chunk under ``jax.checkpoint``:
-    peak logits memory is (B, T/n_chunks, V) and the backward recomputes
-    each chunk's logits instead of storing them. Trades one extra head
-    matmul (in backward) for ~2x(B,T,V) fp32 of HBM — the dominant
-    activation for GPT-2-sized vocabularies.
+    matmul + softmax run per sequence chunk: peak logits memory is
+    (B, T/n_chunks, V), and nothing of vocabulary width outlives its chunk,
+    forward or backward.
+
+    The function is a ``jax.custom_vjp``. Differentiated, a chunk's
+    gradient of the logits is taken in the forward sweep, while the chunk's
+    logits are still there (the loss is a scalar, so its cotangent only
+    scales the result), and turned at once into the chunk's ``dx`` and its
+    addend to ``dW``, both kept in float32 until the backward has scaled
+    them by ``cotangent / count`` (its whole work) and casts them to the
+    primals' dtypes: three head-sized matmuls a chunk, none computed twice.
+    Not differentiated (an evaluation loss) it is the plain loop: one matmul
+    a chunk and the two reductions. Forward-mode differentiation
+    (``jax.jvp``) is what the rule gives up.
 
     The per-chunk loss is ``sum(lse - logit_target)`` — two reductions
     over the chunk logits — rather than ``log_softmax`` + gather, which
     would materialise a full (B, c, V) log-prob tensor only to read one
     column of it (round-4 trace: the CE machinery cost ~2.6x its matmul
-    ideal).
+    ideal). The gradient of the logits is ``jax.vjp`` of that same tail,
+    so a softcap's derivative is carried and no formula is written twice.
 
     ``unroll=True`` replaces the chunk lax.scan with a statically unrolled
     python loop over direct slices of ``x`` — no (n, B, c, D) transposed
-    copy of the activations, no while-loop overhead, and XLA can overlap
-    chunk k's matmul with chunk k-1's reductions (same rationale as
-    ``config.unroll_layers``, which the trainer threads through here).
+    copy of the activations, no while-loop overhead (same rationale as
+    ``config.unroll_layers``, which the trainer threads through here). An
+    ``optimization_barrier`` between a chunk and the next keeps the loop's
+    order, which is what keeps one chunk's logits live and not all of them.
+
+    ``batch_shards`` is the number of shards a mesh cuts the batch into
+    (``forward`` works it out of its ``mesh``). ``dW`` contracts over the
+    batch, so on such a mesh a chunk's addend is a partial sum a shard, and
+    a sum that is carried through a loop or a barrier as a (D, V) array is
+    reduced there, chunk by chunk (eight all-reduces of 322 MB at XL under
+    ``fsdp=4``: compile rehearsal, PR 56). Carried as (batch_shards, D, V),
+    a shard's slab stays on its device and the one sum over the leading
+    axis, after the last chunk, is the step's one reduction of the head's
+    gradient.
     """
     b, t, d = x.shape
     if t % n_chunks:
@@ -1118,40 +1145,89 @@ def chunked_cross_entropy(
         raise ValueError(f"T={t} not divisible by n_chunks={n_chunks}")
     c = t // n_chunks
 
-    def chunk_loss(xc, tc):
-        logits = jnp.einsum(
-            "bcd,dv->bcv", xc, w_head, preferred_element_type=jnp.float32
+    def chunk_logits(xc, w):
+        return jnp.einsum(
+            "bcd,dv->bcv", xc, w, preferred_element_type=jnp.float32
         )
+
+    def chunk_tail(logits, tc):
         logits = attn_ops.softcap(logits, softcap)
         valid = tc != -1
         safe = jnp.where(valid, tc, 0)
         lse = jax.nn.logsumexp(logits, axis=-1)  # (B, c) fp32
         s_t = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
-        return ((lse - s_t) * valid).sum(), valid.sum()
+        return ((lse - s_t) * valid).sum()
 
-    ck = jax.checkpoint(chunk_loss)
+    def over_chunks(step, carry, xs_full, ts_full):
+        """Fold ``step(carry, xc, tc) -> (carry, out)`` over the chunks;
+        the chunks' outputs (None, or (B, c, D) each) laid back along T."""
+        if unroll:
+            outs = []
+            for i in range(n_chunks):
+                xc = xs_full[:, i * c:(i + 1) * c]
+                if i:
+                    # a chunk starts when the one before it is done: nothing
+                    # else orders the unrolled chunks, and the TPU scheduler
+                    # was seen to run all eight logits matmuls first (6.6 GB
+                    # of logits live at once at 124M: compile rehearsal,
+                    # PR 56)
+                    carry, outs[-1], xc = jax.lax.optimization_barrier(
+                        (carry, outs[-1], xc))
+                carry, out = step(carry, xc, ts_full[:, i * c:(i + 1) * c])
+                outs.append(out)
+            return carry, jax.tree.map(
+                lambda *o: jnp.concatenate(o, axis=1), *outs)
+        xs = xs_full.reshape(b, n_chunks, c, d).swapaxes(0, 1)  # (n, B, c, D)
+        ts = ts_full.reshape(b, n_chunks, c).swapaxes(0, 1)
+        carry, outs = jax.lax.scan(
+            lambda cr, xt: step(cr, *xt), carry, (xs, ts))
+        return carry, jax.tree.map(
+            lambda o: o.swapaxes(0, 1).reshape(b, t, d), outs)
 
-    if unroll:
-        tot = jnp.zeros((), jnp.float32)
-        cnt = jnp.zeros((), jnp.int32)
-        for i in range(n_chunks):
-            li, ci = ck(x[:, i * c:(i + 1) * c], targets[:, i * c:(i + 1) * c])
-            tot, cnt = tot + li, cnt + ci
-        return tot / jnp.maximum(cnt, 1)
+    def count(ts_full):
+        return jnp.maximum((ts_full != -1).sum(), 1)
 
-    xs = x.reshape(b, n_chunks, c, d).swapaxes(0, 1)  # (n, B, c, D)
-    ts = targets.reshape(b, n_chunks, c).swapaxes(0, 1)
+    @jax.custom_vjp
+    def mean_loss(x, w, ts_full):
+        def step(tot, xc, tc):
+            return tot + chunk_tail(chunk_logits(xc, w), tc), None
 
-    def body(carry, xt):
-        li, ci = ck(*xt)
-        return (carry[0] + li, carry[1] + ci), None
+        tot, _ = over_chunks(step, jnp.zeros((), jnp.float32), x, ts_full)
+        return tot / count(ts_full)
 
-    (tot, cnt), _ = jax.lax.scan(
-        body,
-        (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
-        (xs, ts),
-    )
-    return tot / jnp.maximum(cnt, 1)
+    def forward_rule(x, w, ts_full):
+        shards = batch_shards if b % batch_shards == 0 else 1
+        by_shard = lambda a: a.reshape(shards, b // shards, *a.shape[1:])
+
+        def step(carry, xc, tc):
+            tot, dw = carry
+            li, pull = jax.vjp(lambda z: chunk_tail(z, tc),
+                               chunk_logits(xc, w))
+            (dz,) = pull(jnp.ones((), jnp.float32))  # (B, c, V) fp32
+            # the two transposes of chunk_logits, as jax.vjp would state
+            # them, but for dW's sum staying in float32 and by shard
+            dxc = jax.lax.dot_general(
+                dz, w, (((2,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dw = dw + jax.lax.dot_general(
+                by_shard(xc), by_shard(dz), (((1, 2), (1, 2)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            return (tot + li, dw), dxc
+
+        init = (jnp.zeros((), jnp.float32),
+                jnp.zeros((shards, *w.shape), jnp.float32))
+        (tot, dw), dx = over_chunks(step, init, x, ts_full)
+        n = count(ts_full)
+        return tot / n, (dx, dw.sum(0), n)
+
+    def backward_rule(res, g):
+        dx, dw, n = res
+        scale = g / n
+        return ((dx * scale).astype(x.dtype),
+                (dw * scale).astype(w_head.dtype), None)
+
+    mean_loss.defvjp(forward_rule, backward_rule)
+    return mean_loss(x, w_head, targets)
 
 
 # ---------------------------------------------------------------------------

@@ -90,14 +90,20 @@ def _ids(*shape):
 
 
 def forward_jaxpr(cfg: GPTConfig, mesh=None, train: bool = False,
-                  tokens=(2, 16)):
+                  tokens=(2, 16), loss: bool = False):
     """``gpt.forward`` over (B, T) ``tokens``; ``train``: with a dropout key
     for an argument and ``deterministic=False``; ``mesh``: as the trainer
-    hands it one."""
+    hands it one; ``loss``: the trainer's ``value_and_grad`` of the loss-only
+    forward, the tokens their own targets (the chunked loss and both its
+    rules; no other pinned program holds them)."""
     key = (jax.random.key(0),) if train else ()
-    return jax.make_jaxpr(lambda p, t, *rng: gpt.forward(
+    forward = lambda p, t, *rng: gpt.forward(
         p, t, cfg, rng=rng[0] if rng else None, deterministic=not train,
-        mesh=mesh))(_abstract_params(cfg), _ids(*tokens), *key)
+        mesh=mesh, **(dict(targets=t, return_logits=False) if loss else {}))
+    if loss:
+        forward = jax.value_and_grad(lambda *a, f=forward: f(*a)[1])
+    return jax.make_jaxpr(forward)(
+        _abstract_params(cfg), _ids(*tokens), *key)
 
 
 def forward_digest(cfg: GPTConfig, **how) -> str:

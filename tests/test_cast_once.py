@@ -660,6 +660,31 @@ def test_a_training_step_s_table_names_the_fused_backward(one_chip,
     assert not named("flash_bwd_dq") and not named("flash_bwd_dkv")
 
 
+@pytest.mark.parametrize("unroll", [False, True], ids=["scan", "unrolled"])
+def test_the_chunked_loss_compiles_to_three_matmuls_and_one_chunk_live(
+        unroll, one_chip):
+    """PR 56, as the chip's compiler plans it at the published vocabulary
+    (a quarter of the 124M cell's batch and a third of its width): the
+    differentiated loss holds three head-sized matmuls a chunk (the
+    compiler's own rematerialisation adds a fourth where memory runs out),
+    and its temporaries are about one chunk's float32 logits. Without the
+    barrier between the unrolled chunks this compiler ran all eight logits
+    matmuls first: 8.0 chunks live here, 6.6 GB at the cell's size."""
+    b, t, d, v, n = 8, 1024, 256, 50257, 8
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(
+        lambda x, w, tgt: gpt.chunked_cross_entropy(
+            x, w.astype(x.dtype), tgt, n, unroll=unroll),
+        argnums=(0, 1))).lower(
+            on_chip((b, t, d), jnp.bfloat16), on_chip((d, v), jnp.float32),
+            on_chip((b, t), jnp.int32)).compile()
+    matmuls = re.findall(r"= \S+ convolution\(", compiled.as_text())
+    assert len(matmuls) == (3 * n if unroll else 3)
+    chunk_logits = b * (t // n) * v * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * chunk_logits
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_metrics_say_what_the_programs_read(dtype):
     cfg, params = model("gpt2-untied", dtype)

@@ -2,6 +2,8 @@
 would have caught B6), loss at init ≈ ln(vocab), shapes, ignore_index, llama
 toggles, remat equivalence."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +11,7 @@ import pytest
 
 from mingpt_distributed_tpu.config import GPTConfig
 from mingpt_distributed_tpu.models import gpt
+from program_digests import _equations
 
 
 def small_cfg(**kw):
@@ -219,6 +222,107 @@ def test_chunked_cross_entropy_indivisible_t_snaps_to_divisor():
                          return_logits=False)
     _, w13 = gpt.forward(params13, toks13, cfg13_dense, targets=toks13)
     assert abs(float(l13) - float(w13)) < 1e-6
+
+
+@pytest.mark.parametrize("unroll, per_grad, per_call", [
+    (False, 3, 1), (True, 12, 4)], ids=["scan", "unrolled"])
+@pytest.mark.parametrize("cap", [None, 30.0], ids=["nocap", "cap30"])
+def test_chunked_cross_entropy_holds_three_head_matmuls_a_chunk(
+        unroll, per_grad, per_call, cap):
+    """The mechanism's counter (ISSUE 56): differentiated, a chunk's logits
+    are computed once and its gradient taken in the forward sweep, so the
+    program holds the matmul, ``dx`` and the addend to ``dW`` and nothing
+    recomputed (the scan's body once, the unrolled loop at 4 chunks 12;
+    under ``jax.checkpoint`` these read 4 and 16); not differentiated it
+    holds the one matmul a chunk. Unrolled, a barrier stands between a
+    chunk and the next, or nothing keeps the scheduler from computing every
+    chunk's logits before it uses the first."""
+    b, t, d, v = 2, 16, 8, 40
+    x = jnp.zeros((b, t, d), jnp.bfloat16)
+    w = jnp.zeros((d, v), jnp.bfloat16)
+    tgt = jnp.zeros((b, t), jnp.int32)
+    loss = lambda x, w: gpt.chunked_cross_entropy(
+        x, w, tgt, 4, softcap=cap, unroll=unroll)
+
+    def counts(jaxpr):
+        names = [eqn.primitive.name for eqn in _equations(jaxpr)
+                 if eqn.primitive.name != "dot_general" or any(
+                     v in var.aval.shape
+                     for var in (*eqn.invars, *eqn.outvars))]
+        return (names.count("dot_general"),
+                names.count("optimization_barrier"),
+                [n for n in names if "checkpoint" in n or "remat" in n])
+
+    barriers = 3 if unroll else 0
+    assert counts(jax.make_jaxpr(loss)(x, w).jaxpr) == (per_call, barriers, [])
+    grad = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, w)
+    assert counts(grad.jaxpr) == (per_grad, barriers, [])
+    # both rules stand under the ``ce`` mark, the backward's two products
+    # too (model.train_ce_ms_per_step reads all of it)
+    for eqn in grad.jaxpr.eqns:
+        assert "ce" in re.split(r"[/()]", str(eqn.source_info.name_stack)), eqn
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+@pytest.mark.parametrize("cap", [None, 30.0], ids=["nocap", "cap30"])
+@pytest.mark.parametrize("unroll", [False, True], ids=["scan", "unrolled"])
+def test_chunked_cross_entropy_gradients_match_dense(unroll, cap, tied):
+    """Loss and the gradients of ``x``, the head and, tied, ``wte`` (through
+    the transpose) equal the dense ``cross_entropy`` path's: with some
+    targets -1, with all of them -1 (loss 0, gradients 0, no NaN), under a
+    cotangent other than 1, and in the primals' dtypes under bfloat16."""
+    from mingpt_distributed_tpu.ops import attention as attn_ops
+
+    b, t, d, v = 4, 16, 32, 65
+    k = jax.random.split(jax.random.key(3), 3)
+    x = jax.random.normal(k[0], (b, t, d))
+    table = 0.3 * jax.random.normal(k[1], (v, d) if tied else (d, v))
+    tgt = jax.random.randint(k[2], (b, t), 0, v).at[0, :3].set(-1)
+
+    def head(x, table):
+        return (table.T if tied else table).astype(x.dtype)
+
+    def dense(x, table, tgt, scale=1.0):
+        logits = jnp.einsum("btd,dv->btv", x, head(x, table),
+                            preferred_element_type=jnp.float32)
+        return scale * gpt.cross_entropy(attn_ops.softcap(logits, cap), tgt)
+
+    def chunked(x, table, tgt, scale=1.0, shards=1):
+        return scale * gpt.chunked_cross_entropy(
+            x, head(x, table), tgt, 4, softcap=cap, unroll=unroll,
+            batch_shards=shards)
+
+    both = lambda f, *a: jax.value_and_grad(f, argnums=(0, 1))(*a)
+    for scale in (1.0, 3.0):
+        want, got = both(dense, x, table, tgt, scale), \
+            both(chunked, x, table, tgt, scale)
+        for a, c in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+            np.testing.assert_allclose(c, a, rtol=1e-5, atol=1e-6)
+
+    # dW carried a slab a batch shard (2 of a batch of 4; 3 does not divide
+    # it and falls back to one) sums to the same gradient
+    want = both(dense, x, table, tgt)
+    for shards in (2, 3):
+        got = both(lambda *a: chunked(*a, shards=shards), x, table, tgt)
+        for a, c in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+            np.testing.assert_allclose(c, a, rtol=1e-5, atol=1e-6)
+
+    loss, grads = both(chunked, x, table, jnp.full_like(tgt, -1))
+    assert float(loss) == 0.0
+    assert all(not np.asarray(g).any() for g in grads)
+
+    # bfloat16 activations over float32 master weights, as the trainer runs
+    xb = x.astype(jnp.bfloat16)
+    want, got = both(dense, xb, table, tgt), both(chunked, xb, table, tgt)
+    assert got[1][0].dtype == jnp.bfloat16 and got[1][1].dtype == jnp.float32
+    assert got[1][1].shape == table.shape
+    for a, c in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        np.testing.assert_allclose(
+            np.asarray(c, np.float32), np.asarray(a, np.float32),
+            rtol=2 ** -7, atol=1e-5)
+    # a bfloat16 head gets a bfloat16 gradient
+    gw = jax.grad(chunked, argnums=1)(xb, table.astype(jnp.bfloat16), tgt)
+    assert gw.dtype == jnp.bfloat16
 
 
 def test_loss_only_mode_returns_no_logits():

@@ -2,6 +2,8 @@
 a-pod strategy): DP-8 == DP-1 equivalence, FSDP/TP equivalence, loss
 decreases end-to-end, kill/resume continuity, snapshot round-trip."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -33,12 +35,13 @@ def tiny_gpt_cfg(**kw):
     return GPTConfig.make(**base)
 
 
-def make_trainer(tmp_path, mesh_cfg=None, snapshot=None, **trainer_kw):
+def make_trainer(tmp_path, mesh_cfg=None, snapshot=None, gpt_kw=None,
+                 **trainer_kw):
     ds = CharDataset(
         DataConfig(path="<inline>", block_size=16, train_split=0.9), text=CORPUS
     )
     train, test = ds.split()
-    gcfg = tiny_gpt_cfg(vocab_size=ds.vocab_size)
+    gcfg = tiny_gpt_cfg(vocab_size=ds.vocab_size, **(gpt_kw or {}))
     tkw = dict(
         max_epochs=1, batch_size=16, grad_norm_clip=1.0, save_every=100,
         log_every=1000, seed=7,
@@ -95,6 +98,48 @@ def test_fsdp_tp_matches_dp(tmp_path, eight_devices):
     l_dp = losses_for(tmp_path, MeshConfig(dp=-1), name="c")
     l_mix = losses_for(tmp_path, MeshConfig(dp=2, fsdp=2, tp=2, sp=1), name="d")
     np.testing.assert_allclose(l_dp, l_mix, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("unroll", [False, True], ids=["scan", "unrolled"])
+def test_fsdp4_step_matches_one_device_and_reduces_the_head_once(
+        tmp_path, eight_devices, unroll):
+    """The chunked loss takes its gradient in the forward sweep and carries
+    the head's in a loop (ISSUE 56): under ``fsdp=4`` the batch it contracts
+    over is sharded, and a step must still give one device's loss, gradient
+    norm and update, in both forms of the loop (the benchmark's cells run
+    the unrolled one), with no ``all-reduce`` of the head's size under the
+    ``ce`` mark but the one after the last chunk, and no more of them in all
+    than the recomputing form compiled to on these devices (scan 4: a
+    chunk's logits, the loss, the count, and in its backward the logits
+    again; unrolled 1, the sums being the optimizer's there)."""
+    from mingpt_distributed_tpu.telemetry.programs import scope_table
+
+    def one_step(mesh_cfg, name):
+        tr = make_trainer(tmp_path, mesh_cfg=mesh_cfg, snapshot=name,
+                          gpt_kw=dict(unroll_layers=unroll))
+        batch = tr._put_batch(next(iter(tr.train_iter.epoch_batches())))
+        compiled = tr._train_step.lower(
+            tr.state, batch, tr.base_rng).compile().as_text()
+        state, m = tr._train_step(tr.state, batch, tr.base_rng)
+        return jax.device_get((state["params"], m)), compiled, tr.gpt_config
+
+    (p1, m1), _, _ = one_step(MeshConfig(dp=1, fsdp=1), "one")
+    (p4, m4), compiled, cfg = one_step(MeshConfig(dp=1, fsdp=4), "four")
+    for key in ("loss", "grad_norm", "update_norm"):
+        np.testing.assert_allclose(m4[key], m1[key], rtol=2e-5)
+    for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p4)):
+        # Adam turns a gradient near its epsilon into an update of noise:
+        # the tolerance is a thousandth of the learning rate
+        np.testing.assert_allclose(b, a, rtol=2e-4, atol=1e-5)
+    of_ce = {name for name, scope in scope_table(compiled).items()
+             if scope == "ce" and name.startswith("all-reduce")}
+    reduced = [line for line in compiled.splitlines()
+               if (m := re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line))
+               and m.group(1) in of_ce]
+    assert reduced
+    head = rf"f32\[({cfg.n_embd},{cfg.vocab_size}|{cfg.vocab_size},{cfg.n_embd})\]"
+    assert sum(bool(re.search(head, line)) for line in reduced) <= 1
+    assert len(reduced) <= (1 if unroll else 4), reduced
 
 
 def test_params_actually_sharded(tmp_path, eight_devices):

@@ -512,17 +512,24 @@ PARENT_FORWARD_DIGESTS = {
     "mha/train": "8559ec7fca433251",
     "gqa-rope/train": "bfbed589e21292c8",
     "capacity/train": "de10ad6f58960ab5",
+    # the loss-only forward under value_and_grad, pinned first by PR 56,
+    # whose parent read "bbe5700240ef2a21" and "8d446719bbc144e0": the
+    # chunked loss took its gradient under jax.checkpoint there (a fourth
+    # head matmul a chunk, in a backward scan), and takes it in the forward
+    # sweep now. No other digest of this file moved with it.
+    "mha/loss": "89e3d616e8d18e40",
+    "latent/loss": "6a554607055800a2",
 }
 
 
 def forward_digest_of(key: str) -> str:
     """A PARENT_FORWARD_DIGESTS key's digest: ``form`` is the deterministic
     forward, ``form/train`` the training-mode one, with a dropout key and
-    both dropouts on."""
+    both dropouts on, ``form/loss`` the loss-only forward differentiated."""
     form, _, mode = key.partition("/")
-    over = dict(resid_pdrop=0.1, attn_pdrop=0.1) if mode else {}
+    over = dict(resid_pdrop=0.1, attn_pdrop=0.1) if mode == "train" else {}
     return forward_digest(GPTConfig.make(**{**FORMS[form], **over}),
-                          train=bool(mode))
+                          train=mode == "train", loss=mode == "loss")
 
 
 @pytest.mark.parametrize("form", sorted(PARENT_PREFILL_DIGESTS))
