@@ -120,6 +120,10 @@ MODEL_PRESETS: dict[str, dict[str, Any]] = {
 #: the two mixers a hybrid stack (``GPTConfig.mixer_types``) is made of,
 #: under their published names
 LIGHTNING, SPARSE = "lightning-attn", "minicpm4"
+#: the two kinds of softmax attention layer a stack of ``layer_types`` is
+#: made of, under their published names: every row of the context, or the
+#: last ``attention_window`` rows, kept in a ring
+FULL_ATTN, WINDOW_ATTN = "full_attention", "sliding_attention"
 
 
 class ConfigError(ValueError):
@@ -330,6 +334,45 @@ class GPTConfig:
     # boundary, at the cost of an n_layer-times-larger HLO (slower
     # compile). Ignored under pp (the pipeline has its own schedule).
     unroll_layers: bool = False
+    # A stack whose softmax attention layers differ in kind (Laguna): one of
+    # FULL_ATTN, WINDOW_ATTN a layer, under the published ``layer_types``.
+    # A full layer has ``n_head`` query heads, rotates by ``rope_theta``,
+    # ``rope_fraction`` and ``rope_yarn`` and caches a row a position; a
+    # window layer has ``window_n_head`` query heads, rotates by
+    # ``window_rope_theta`` and ``window_rope_fraction``, attends the last
+    # ``attention_window`` positions (its own included) and caches that many
+    # rows a slot in a ring (models/generate.py). Both kinds share
+    # ``n_kv_head`` and ``head_dim``. None: every layer is the stack's one
+    # attention.
+    layer_types: Optional[Tuple[str, ...]] = None
+    # The size of a head where a model states one (published ``head_dim``)
+    # that is not ``n_embd // n_head``; None = that quotient.
+    head_size: Optional[int] = None
+    # Query heads of the window layers (published
+    # ``num_attention_heads_per_layer``); None = ``n_head``.
+    window_n_head: Optional[int] = None
+    # The share of a head the rotary embedding turns, from its first
+    # dimension on (published ``partial_rotary_factor``); the rest passes
+    # through. ``layer_types`` only.
+    rope_fraction: float = 1.0
+    window_rope_theta: Optional[float] = None   # None = rope_theta
+    window_rope_fraction: float = 1.0
+    # YaRN on the full layers' rotation: (factor,
+    # original_max_position_embeddings, beta_fast, beta_slow,
+    # attention_factor), the published ``rope_parameters`` of that kind
+    # (ops/attention.yarn_rope_tables). None: the plain frequencies.
+    rope_yarn: Optional[Tuple[float, ...]] = None
+    # One sigmoid gate a head and token on the attention's output before the
+    # output projection, ``sigmoid(h W_g)`` with ``W_g`` (n_embd, heads)
+    # (published ``gating``; leaf ``w_hg``). ``layer_types`` only; a hybrid
+    # stack's ``output_gate`` is one a number.
+    head_gate: bool = False
+    # The dropless route (ops/moe.moe_dropless) under softmax scores: the k
+    # largest router logits, gates the chosen experts' softmax
+    # probabilities (over their sum under ``moe_norm_topk``) times
+    # ``moe_route_scale``, shared experts beside them, nothing dropped.
+    # False: "softmax" is the capacity route.
+    moe_dropless: bool = False
 
     @classmethod
     def make(cls, **kwargs: Any) -> "GPTConfig":
@@ -337,8 +380,9 @@ class GPTConfig:
         kwargs = dict(kwargs)
         if "n_embed" in kwargs:  # normalise the reference's stray spelling
             kwargs.setdefault("n_embd", kwargs.pop("n_embed"))
-        if kwargs.get("mixer_types") is not None:  # YAML and JSON give lists
-            kwargs["mixer_types"] = tuple(kwargs["mixer_types"])
+        for key in ("mixer_types", "layer_types", "rope_yarn"):
+            if kwargs.get(key) is not None:  # YAML and JSON give lists
+                kwargs[key] = tuple(kwargs[key])
         cfg = cls(**_reject_unknown(cls, kwargs))
         return cfg.resolved()
 
@@ -375,7 +419,7 @@ class GPTConfig:
     def validate(self) -> None:
         if self.n_embd is None or self.n_head is None or self.n_layer is None:
             raise ConfigError("model dims unresolved; call .resolved() first")
-        if self.n_embd % self.n_head != 0:
+        if self.head_size is None and self.n_embd % self.n_head != 0:
             raise ConfigError(
                 f"n_embd={self.n_embd} not divisible by n_head={self.n_head}"
             )
@@ -411,9 +455,9 @@ class GPTConfig:
             )
         if self.loss_chunks < 0:
             raise ConfigError(f"loss_chunks must be >= 0, got {self.loss_chunks}")
-        if self.rope and (self.n_embd // self.n_head) % 2 != 0:
+        if self.rope and self.head_dim % 2 != 0:
             raise ConfigError(
-                f"rope needs an even head_dim, got {self.n_embd // self.n_head}"
+                f"rope needs an even head_dim, got {self.head_dim}"
             )
         if self.block_size <= 0 or self.vocab_size <= 0:
             raise ConfigError("block_size and vocab_size must be positive")
@@ -424,17 +468,25 @@ class GPTConfig:
                 )
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ConfigError(f"unknown moe_scoring {self.moe_scoring!r}")
-        if self.moe_scoring == "sigmoid" and not (self.n_experts and self.swiglu):
+        if (self.moe_scoring == "sigmoid" or self.moe_dropless) \
+                and not (self.n_experts and self.swiglu):
             raise ConfigError(
-                "moe_scoring='sigmoid' is the dropless route of SwiGLU "
-                "experts: it needs n_experts > 0 and swiglu")
-        if self.moe_scoring == "softmax" and (
+                "moe_scoring='sigmoid' and moe_dropless are the dropless "
+                "route of SwiGLU experts: it needs n_experts > 0 and swiglu")
+        if self.moe_scoring == "softmax" and not self.moe_dropless and (
                 self.n_shared_experts or self.moe_route_scale != 1.0
                 or not self.moe_norm_topk):
             raise ConfigError(
                 "shared experts, a gate scale and un-renormalised gates are "
-                "built for moe_scoring='sigmoid' only: the capacity route "
+                "built for the dropless route only (moe_scoring='sigmoid', or "
+                "moe_dropless under 'softmax'): the capacity route "
                 "(ops/moe.py) renormalises softmax gates and adds nothing")
+        if self.moe_scoring == "softmax" and self.moe_dropless \
+                and not self.moe_norm_topk:
+            raise ConfigError(
+                "the dropless route under softmax scores renormalises the "
+                "chosen experts' probabilities (moe_norm_topk): gates left "
+                "as the softmax over all experts gives them are not written")
         if not 0 <= self.n_dense_layers <= self.n_layer:
             raise ConfigError(
                 f"n_dense_layers={self.n_dense_layers} outside "
@@ -471,6 +523,7 @@ class GPTConfig:
             raise ConfigError(
                 "scale_emb must be > 0, scale_depth and dim_model_base >= 0")
         self._validate_looped()
+        self._validate_kinds()
         if self.mixer_types is not None:
             self._validate_hybrid()
         elif self.lightning_heads or self.lightning_head_dim \
@@ -532,6 +585,90 @@ class GPTConfig:
                 "(pp_microbatches): a stage would have to hand its output "
                 "back to the first stage n_passes - 1 times, a schedule "
                 "parallel/pipeline.py does not have")
+
+    def _validate_kinds(self) -> None:
+        """The rules of a stack whose attention layers differ in kind
+        (``layer_types``), and what is not written for it, a sentence each."""
+        if self.head_size is not None and (
+                self.head_size < 1 or self.kv_lora_rank):
+            raise ConfigError(
+                "head_size states a per-head attention's head_dim: it is "
+                "positive, and latent attention has sizes of its own")
+        if self.layer_types is None:
+            if self.window_n_head is not None or self.rope_fraction != 1.0 \
+                    or self.window_rope_theta is not None \
+                    or self.window_rope_fraction != 1.0 \
+                    or self.rope_yarn is not None or self.head_gate:
+                raise ConfigError(
+                    "window_n_head, rope_fraction, window_rope_theta, "
+                    "window_rope_fraction, rope_yarn and head_gate belong to "
+                    "a stack of layer_types: set it")
+            return
+        kinds = set(self.layer_types)
+        if len(self.layer_types) != self.n_layer or not kinds <= {
+                FULL_ATTN, WINDOW_ATTN}:
+            raise ConfigError(
+                f"layer_types names one of {FULL_ATTN!r}, {WINDOW_ATTN!r} "
+                f"for each of the {self.n_layer} layers, got "
+                f"{self.layer_types}")
+        if FULL_ATTN not in kinds:
+            raise ConfigError(
+                "a stack of layer_types needs a full attention layer: the "
+                "serving pool, its audits and the benchmark's check read "
+                "the rows of a position, and a ring keeps a window's")
+        if WINDOW_ATTN in kinds and not self.attention_window:
+            raise ConfigError(
+                "a sliding_attention layer attends attention_window rows: "
+                "set it")
+        if not (self.rope and self.rmsnorm and self.swiglu):
+            raise ConfigError(
+                "a stack of layer_types rotates by kind, RMS-norms and has "
+                "SwiGLU MLPs: it needs rope, rmsnorm and swiglu")
+        wnh = self.window_n_head or self.n_head
+        if wnh % self.kv_heads:
+            raise ConfigError(
+                f"window_n_head={wnh} not divisible by the {self.kv_heads} "
+                "KV heads")
+        for kind in kinds:
+            dim = self.rope_spec(kind)[0]
+            if dim < 2 or dim % 2 or dim > self.head_dim:
+                raise ConfigError(
+                    f"a {kind} layer rotates {dim} of a head's "
+                    f"{self.head_dim} dimensions: an even number of them, "
+                    "at least 2")
+        if self.rope_yarn is not None and (
+                len(self.rope_yarn) != 5 or self.rope_yarn[0] <= 1.0
+                or min(self.rope_yarn[1:]) <= 0
+                or self.rope_yarn[2] <= self.rope_yarn[3]):
+            raise ConfigError(
+                "rope_yarn is (factor > 1, original_max_position_embeddings, "
+                "beta_fast > beta_slow > 0, attention_factor > 0), got "
+                f"{self.rope_yarn}")
+        if self.attention != "einsum":
+            raise ConfigError(
+                f"a stack of layer_types is built for attention='einsum': "
+                f"the {self.attention!r} path takes one head count, one "
+                "window and one rotation for every layer")
+        if self.mixer_types is not None or self.kv_lora_rank \
+                or self.n_passes > 1 or self.post_norms or self.exit_gate \
+                or self.residual_dtype:
+            raise ConfigError(
+                "a stack of layer_types keeps per-head rows and runs its "
+                "layers once: a hybrid stack (mixer_types), a latent "
+                "(kv_lora_rank), passes, post_norms, an exit gate and "
+                "residual_dtype are not written for it")
+        if self.attn_logit_softcap or self.rope_interleave \
+                or self.pp_microbatches:
+            raise ConfigError(
+                "a stack of layer_types takes no attn_logit_softcap, "
+                "rotates the halves of what it rotates (no "
+                "rope_interleave) and is not pipelined (pp_microbatches): "
+                "the pipeline splits one stack of like layers")
+        if self.n_experts and not self.dropless:
+            raise ConfigError(
+                "a stack of layer_types routes without dropping "
+                "(moe_dropless, or moe_scoring='sigmoid'): the capacity "
+                "route is not written for it")
 
     @property
     def closes_passes(self) -> bool:
@@ -612,7 +749,8 @@ class GPTConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.n_embd // self.n_head
+        return self.head_size if self.head_size is not None \
+            else self.n_embd // self.n_head
 
     @property
     def qk_head_dim(self) -> int:
@@ -641,8 +779,107 @@ class GPTConfig:
             else self.dense_width
 
     @property
+    def shared_width(self) -> int:
+        """Inner width of the shared experts' one MLP."""
+        return self.n_shared_experts * self.expert_width
+
+    @property
     def kv_heads(self) -> int:
         return self.n_kv_head if self.n_kv_head is not None else self.n_head
+
+    @property
+    def dropless(self) -> bool:
+        """Whether an expert layer takes the dropless route
+        (ops/moe.moe_dropless): under sigmoid scores always, under softmax
+        scores where ``moe_dropless`` says so."""
+        return bool(self.n_experts) and (
+            self.moe_scoring == "sigmoid" or self.moe_dropless)
+
+    def kind_layers(self, kind: str) -> Tuple[int, ...]:
+        """The layers of a stack of ``layer_types`` of attention ``kind``,
+        in order: a layer's place in this tuple is its place in its kind's
+        stack of attention parameters and in its kind's leaves of the
+        cache."""
+        return tuple(i for i, k in enumerate(self.layer_types or ())
+                     if k == kind)
+
+    def kind_heads(self, kind: Optional[str] = None) -> Tuple[int, int, int]:
+        """(query heads, KV heads, head size) of an attention layer of
+        ``kind`` (None: the stack's one attention)."""
+        nh = (self.window_n_head or self.n_head) if kind == WINDOW_ATTN \
+            else self.n_head
+        return nh, self.kv_heads, self.head_dim
+
+    def kind_window(self, kind: Optional[str] = None) -> Optional[int]:
+        """The positions a layer of ``kind`` attends, its own included
+        (None: every one before it). In a stack of ``layer_types`` the
+        window is the sliding layers'."""
+        if self.layer_types is not None and kind != WINDOW_ATTN:
+            return None
+        return self.attention_window
+
+    def rope_spec(self, kind: Optional[str] = None):
+        """How a layer of ``kind`` rotates: (the dimensions of a head it
+        turns, from the first on; theta; YaRN's five numbers or None)."""
+        if kind == WINDOW_ATTN:
+            theta = self.rope_theta if self.window_rope_theta is None \
+                else self.window_rope_theta
+            return (int(self.head_dim * self.window_rope_fraction), theta,
+                    None)
+        if self.layer_types is None:
+            return self.rope_dim, self.rope_theta, None
+        return (int(self.head_dim * self.rope_fraction), self.rope_theta,
+                self.rope_yarn)
+
+    @property
+    def ring_rows(self) -> int:
+        """Rows a window layer keeps a slot: its window, or every position
+        where the window is no shorter than the context."""
+        return min(self.attention_window or self.block_size, self.block_size)
+
+    @property
+    def layer_type_names(self) -> Optional[list]:
+        """``layer_types`` as a configuration file spells it (a list)."""
+        return None if self.layer_types is None else list(self.layer_types)
+
+    @property
+    def heads_per_layer(self) -> list:
+        """Query heads a layer (published ``num_attention_heads_per_layer``)."""
+        return [self.kind_heads(k)[0]
+                for k in self.layer_types or (None,) * self.n_layer]
+
+    @property
+    def mlp_layer_types(self) -> list:
+        """"dense" or "sparse" a layer (published ``mlp_layer_types``): the
+        leading ``n_dense_layers`` dense, the others routed."""
+        n_dense = self.n_dense_layers if self.n_experts else self.n_layer
+        return ["dense"] * n_dense + ["sparse"] * (self.n_layer - n_dense)
+
+    @property
+    def rope_parameters(self) -> Optional[dict]:
+        """The rotations of a stack of ``layer_types`` as the published
+        ``rope_parameters`` spells them, a kind of layer each."""
+        if self.layer_types is None:
+            return None
+        _, theta, yarn = self.rope_spec(FULL_ATTN)
+        full = {"rope_theta": theta, "rope_type": "default",
+                "partial_rotary_factor": self.rope_fraction}
+        out = {}
+        if yarn is not None:
+            factor, original, fast, slow, attention_factor = yarn
+            full = {"rope_theta": theta, "rope_type": "yarn",
+                    "factor": factor,
+                    "original_max_position_embeddings": original,
+                    "beta_slow": slow, "beta_fast": fast,
+                    "attention_factor": attention_factor,
+                    "partial_rotary_factor": self.rope_fraction}
+            out["original_max_position_embeddings"] = original
+        _, w_theta, _ = self.rope_spec(WINDOW_ATTN)
+        return {FULL_ATTN: full,
+                WINDOW_ATTN: {"rope_type": "default", "rope_theta": w_theta,
+                              "partial_rotary_factor":
+                                  self.window_rope_fraction},
+                **out}
 
     @property
     def mixer_names(self) -> Optional[list]:

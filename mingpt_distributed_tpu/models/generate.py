@@ -37,7 +37,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from mingpt_distributed_tpu.config import LIGHTNING, SPARSE, GPTConfig
+from mingpt_distributed_tpu.config import (
+    FULL_ATTN, LIGHTNING, SPARSE, WINDOW_ATTN, GPTConfig)
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.ops import layers as L
@@ -80,6 +81,15 @@ SPARSE_ROWS = "sparse_rows"
 LOOP_PASSES = "loop_passes"
 #: leaves of a cache tree that count and hold nothing of a request
 COUNTERS = (MOE_ROWS, SPARSE_ROWS, LOOP_PASSES)
+#: the window layers' rings of a stack of ``cfg.layer_types``, beside the
+#: full layers' ``"k"``, ``"v"`` rows: (window layers, B, ``cfg.ring_rows``,
+#: heads, size), the row of position ``p`` at index ``p mod ring_rows``. A
+#: ring has no row a position, so what hides a stale row is its age: a
+#: reader takes the row at an index for the last position before its own
+#: that lies there, and masks it where that position is negative or has
+#: left the window (``attn_ops.ring_attend_step``).
+RING_K, RING_V = "ring_k", "ring_v"
+RINGS = (RING_K, RING_V)
 
 #: lanes of the device's tile: the minor axis of a buffer is laid out in
 #: pieces of this many elements
@@ -112,6 +122,20 @@ def cache_leaf_shapes(cfg: GPTConfig, batch: int) -> Dict[str, Tuple[int, ...]]:
     and the first part of every key, so stored once). A looped stack has a plane a
     pass and layer (``cfg.cache_planes``): pass t of layer l keeps its own
     keys and values at plane ``t * n_layer + l``."""
+    if cfg.layer_types is not None:
+        # a row a position for the full layers alone; a window layer keeps
+        # its window's rows in a ring. A row holds its KV heads side by
+        # side whatever their size: the decode step walks these slices, and
+        # out of a per-head leaf of whole tiles a head (8 of 128) the chip's
+        # compiler copies the whole pool, heads before positions, at the
+        # head of every step (compile rehearsal, PR 59: 2 x 2 GB)
+        row = (1, cfg.kv_heads * cfg.head_dim)
+        full, ring = (len(cfg.kind_layers(k)) for k in (FULL_ATTN, WINDOW_ATTN))
+        shapes = {n: (full, batch, cfg.block_size) + row for n in ("k", "v")}
+        if ring:
+            shapes.update({n: (ring, batch, cfg.ring_rows) + row
+                           for n in RINGS})
+        return shapes
     if cfg.mixer_types is not None:
         # rows for the sparse layers alone; the lightning layers keep a
         # state a head and nothing a position
@@ -173,6 +197,15 @@ def cache_walk(cfg: GPTConfig, cache) -> attn_ops.StepWalk:
         latent=bool(cfg.kv_lora_rank))
 
 
+def ring_walk(cfg: GPTConfig, cache) -> Optional[attn_ops.StepWalk]:
+    """:func:`cache_walk` for the window layers' rings of ``cache``; None
+    where it holds none."""
+    if RING_K not in cache:
+        return None
+    return attn_ops.step_walk([cache[n].shape for n in RINGS],
+                              cache[RING_K].dtype.itemsize)
+
+
 def init_cache(cfg: GPTConfig, batch: int, dtype=None) -> Cache:
     dtype = dtype or jnp.dtype(cfg.dtype)
     # a state is float32 whatever the rows are kept in
@@ -198,7 +231,7 @@ def init_loop_passes(cfg: GPTConfig) -> Optional[jax.Array]:
 def init_moe_rows(cfg: GPTConfig) -> Optional[jax.Array]:
     """A zeroed MOE_ROWS leaf, or None where the model counts nothing (only
     the dropless route does)."""
-    if not (cfg.n_experts and cfg.moe_scoring == "sigmoid"):
+    if not cfg.dropless:
         return None
     return jnp.zeros((cfg.n_layer - cfg.n_dense_layers, cfg.n_experts + 3),
                      jnp.int32)
@@ -247,6 +280,41 @@ def _write_lane_rows(cache: Cache, rows, positions) -> Cache:
     return out
 
 
+def _ring_chunk(q, rows, cache: Cache, plane: int, offset, valid):
+    """A chunk of a window layer against its ring (the form of
+    ``_cached_block`` under one ``offset`` for the batch): (the chunk's
+    attention (B, T, H, hd), the cache with the ring as the chunk leaves
+    it). The ring holds the rows of the ``W`` positions before ``offset``
+    (those the sequence has; what else lies there is masked as a negative
+    position), the row of ``p`` at ``p mod W``: rolled by ``offset`` they
+    stand in the order of their positions, ``offset - W`` first, and the
+    chunk attends them and its own rows after them under the band
+    (``attn_ops.banded_attention``, which passes by the blocks no query's
+    window touches). Afterwards index ``i`` of the ring holds the row of
+    the last position before the chunk's end that is ``i`` modulo ``W``:
+    the chunk's own where that position is in the chunk, else the ring's as
+    it was. The chunk's end is its ``valid`` (B, T) tokens' (None: all
+    ``T``): a bucket's padding after them leaves no row in the ring, or a
+    decode step would read it as a position before its own."""
+    b, t = q.shape[:2]
+    w = cache[RING_K].shape[2]
+    offset = jnp.asarray(offset)
+    old = {n: cache[n][plane] for n in RINGS}                # (B, W, ...)
+    in_order = {n: jnp.roll(old[n], -(offset % w), axis=1) for n in RINGS}
+    att = attn_ops.banded_attention(
+        q, *(jnp.concatenate([in_order[n], rows[n]], 1) for n in RINGS),
+        q_start=offset, k_start=offset - w, window=w)
+    length = jnp.full((b,), t) if valid is None else valid.sum(-1)
+    last = offset + length[:, None] - 1                      # (B, 1)
+    held = last - jnp.mod(last - jnp.arange(w)[None, :], w)  # (B, W) positions
+    mine = (held >= offset)[:, :, None, None]
+    at = jnp.clip(held - offset, 0, t - 1)[:, :, None, None]
+    new = {n: jnp.where(mine, jnp.take_along_axis(rows[n], at, axis=1), old[n])
+           for n in RINGS}
+    return att, {**cache, **{n: jax.lax.dynamic_update_slice(
+        cache[n], new[n][None], (plane, 0, 0, 0, 0)) for n in RINGS}}
+
+
 def _cached_block(
     x: jax.Array,            # (B, T, D) — T = prompt length or 1
     blk: gpt.Params,         # one layer's params (no leading L axis)
@@ -258,6 +326,7 @@ def _cached_block(
     expert_layer: Optional[int] = None,  # blk's EXPERT_LEAVES are the stack's
     frontier: Optional[jax.Array] = None,  # (B,): how far each lane is read
     walk: Optional[attn_ops.StepWalk] = None,  # and how (``cache_walk``)
+    kind: Optional[str] = None,  # the layer's kind in a stack of layer_types
 ) -> Tuple[jax.Array, Cache, Cache, Optional[jax.Array]]:
     """One pre-LN block against the cache, ``gpt._block`` with another
     middle word: norm, parts, attend the cache, out, add; norm, MLP, add
@@ -306,18 +375,32 @@ def _cached_block(
     through W_UK to the latent's size, the heads average latents, and the
     averages go through W_UV. Per-head keys and values of the cache are
     never built, in prefill or in decode.
+
+    In a stack of ``cfg.layer_types`` the layer is of ``kind``, which says
+    its query heads, its rotation and where its rows lie. A full layer's
+    are plane ``plane`` of ``"k"``, ``"v"``; a chunk attends them in blocks
+    (``attn_ops.banded_attention``: a 4k prompt's scores are never whole).
+    A window layer's are plane ``plane`` of the rings (RINGS): a chunk
+    attends what the ring holds of the positions before it and its own
+    rows beside that, under the band, and leaves the ring holding the last
+    rows of its ``valid`` tokens (``_ring_chunk``); a decode step
+    reads the ring as it lies (``attn_ops.ring_attend_step``, by ``walk``,
+    here the rings': ``ring_walk``) and hands its row back under the
+    rings' names for the caller to write at ``position mod ring_rows``.
     """
     b, t, _ = x.shape
-    nh, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
+    nh, kv, hd = cfg.kind_heads(kind)
+    ring = kind == WINDOW_ATTN
+    leaves = RINGS if ring else ("k", "v")
     per_lane = jnp.ndim(offset) == 1
     if per_lane and t != 1:
         raise ValueError(f"a position a lane takes one token a lane, not {t}")
 
     h = gpt.sublayer_input(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
     with jax.named_scope("qkv"):
-        rope = attn_ops.rope_tables(
-            jnp.asarray(offset)[..., None] + jnp.arange(t),
-            cfg.rope_dim, cfg.rope_theta) if cfg.rope else None
+        rope = gpt.layer_rope(
+            cfg, kind, jnp.asarray(offset)[..., None] + jnp.arange(t)) \
+            if cfg.rope else None
     if cfg.kv_lora_rank:
         # what is cached: "v" the normed latent, "k" the rotated rope key
         nope = cfg.qk_nope_head_dim
@@ -332,8 +415,8 @@ def _cached_block(
     # in the cache's row shape: a latent's parts have it, a per-head row
     # takes it here (heads side by side where the leaf keeps them so)
     rows = {n: a.astype(cache[n].dtype).reshape(b, t, *cache[n].shape[3:])
-            for n, a in (("k", k), ("v", v))}
-    if not per_lane:
+            for n, a in zip(leaves, (k, v))}
+    if not per_lane and not ring:
         cache = {**cache, **{n: jax.lax.dynamic_update_slice(
             cache[n], rows[n][None], (plane, 0, offset, 0, 0))
             for n in ("k", "v")}}
@@ -342,8 +425,16 @@ def _cached_block(
     # positions correct, and the causal mask kills both future tokens and
     # never-written (zero) slots beyond offset+t
     if per_lane and walk is None:
-        walk = cache_walk(cfg, cache)
-    if cfg.kv_lora_rank:
+        walk = ring_walk(cfg, cache) if ring else cache_walk(cfg, cache)
+    if ring and per_lane:
+        att = attn_ops.ring_attend_step(
+            q, cache[RING_K], cache[RING_V], plane, rows[RING_K],
+            rows[RING_V], offset, walk, frontier=frontier,
+        ).reshape(b, t, nh * hd)
+    elif ring:
+        att, cache = _ring_chunk(q, rows, cache, plane, offset, valid)
+        att = att.reshape(b, t, nh * hd)
+    elif cfg.kv_lora_rank:
         scale = cfg.qk_head_dim ** -0.5
         if per_lane:
             att = attn_ops.latent_attend_step(
@@ -358,9 +449,12 @@ def _cached_block(
     elif per_lane:
         att = attn_ops.causal_attend_step(
             q, cache["k"], cache["v"], plane, rows["k"], rows["v"], offset,
-            walk, frontier=frontier, window=cfg.attention_window,
+            walk, frontier=frontier, window=cfg.kind_window(kind),
             logit_softcap=cfg.attn_logit_softcap,
         ).reshape(b, t, nh * hd)
+    elif kind is not None:
+        att = attn_ops.banded_attention(
+            q, big_k, big_v, q_start=offset).reshape(b, t, nh * hd)
     else:
         att = attn_ops.causal_attention(
             q, attn_ops.as_heads(big_k, hd), attn_ops.as_heads(big_v, hd),
@@ -370,7 +464,7 @@ def _cached_block(
     # a sum stands under the mark of the part it takes in: fused with that
     # part's last matmul, the sum is the fusion's root
     with jax.named_scope("attn_out"):
-        x = x + gpt.attention_out(att, blk, cfg)
+        x = x + gpt.attention_out(att, blk, cfg, h=h)
 
     h2 = gpt.sublayer_input(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
     m, _, counts = gpt.mlp_branch(h2, blk, cfg, valid=valid,
@@ -576,7 +670,11 @@ def _forward_cached_hidden(
     n_dense = cfg.n_dense_layers
     # a dropless layer's expert leaves stay the stack's: sliced out, they
     # would be copied whole into the route's loop
-    whole = gpt.EXPERT_LEAVES if cfg.moe_scoring == "sigmoid" else ()
+    whole = gpt.EXPERT_LEAVES if cfg.dropless else ()
+    if cfg.layer_types is not None:
+        x, cache = _forward_cached_kinds(
+            params, x, cache, offset, cfg, valid, frontier, walk, whole)
+        return gpt._norm(x, params["lnf_scale"], None, cfg), cache
     # the tokens a pass counts (LOOP_PASSES), where the cache counts any
     counting = LOOP_PASSES in cache
     if counting:
@@ -618,6 +716,41 @@ def _forward_cached_hidden(
     return x, cache
 
 
+def _forward_cached_kinds(params, x, cache: Cache, offset, cfg: GPTConfig,
+                          valid, frontier, walk, whole):
+    """The layers of a stack of ``cfg.layer_types`` over embedded ``x``,
+    reading and writing the cache: ``_forward_cached_hidden``'s loop for a
+    stack whose attention layers differ in kind. A layer's attention comes
+    out of its kind's stack and its MLP out of the dense or the expert
+    stack (``gpt.kind_layer_params``); a full layer reads and writes its
+    plane of ``"k"``, ``"v"`` by ``walk``, a window layer its plane of the
+    rings by the rings' own walk, and under a position a lane the new rows
+    of each are written together after the last layer, the rings' at
+    ``position mod ring_rows``."""
+    rings = ring_walk(cfg, cache)
+    rows = {FULL_ATTN: [], WINDOW_ATTN: []}
+    counts = []
+    for layer in range(cfg.n_layer):
+        kind, blk, at, mlp_at = gpt.kind_layer_params(
+            params, cfg, layer, whole)
+        routed = whole and "w_router" in blk
+        x, cache, new, counted = _cached_block(
+            x, blk, cache, at, offset, cfg, valid,
+            expert_layer=mlp_at if routed else None, frontier=frontier,
+            walk=rings if kind == WINDOW_ATTN else walk, kind=kind)
+        rows[kind].append(new)
+        if counted is not None:
+            counts.append(counted)
+    if jnp.ndim(offset) == 1:
+        cache = _write_lane_rows(cache, rows[FULL_ATTN], offset)
+        if rows[WINDOW_ATTN]:
+            cache = _write_lane_rows(cache, rows[WINDOW_ATTN],
+                                     offset % cfg.ring_rows)
+    if MOE_ROWS in cache and counts:
+        cache = {**cache, MOE_ROWS: cache[MOE_ROWS] + jnp.stack(counts)}
+    return x, cache
+
+
 @jax.named_scope("head")
 def _head_logits(params: gpt.Params, x: jax.Array, cfg: GPTConfig) -> jax.Array:
     """LM head over (B, t, D) hidden states -> (B, t, V) fp32 logits
@@ -645,7 +778,7 @@ _CAST_ONLY_BLOCK_LEAVES = frozenset({
     "w_fc", "b_fc", "w_proj", "b_proj",
     "w_gate", "w_up", "w_down",
     "w_e1", "w_e2", "w_eg",
-    "w_kv_a", "w_kv_b", "w_sg", "w_su", "w_sd", "w_og",
+    "w_kv_a", "w_kv_b", "w_sg", "w_su", "w_sd", "w_og", "w_hg",
 })
 
 
@@ -665,7 +798,8 @@ def cast_once_params(
     ``params``, and the device holds the weights once."""
     dtype = jnp.dtype(cfg.dtype)
     stacks = [s for s in ("dense_blocks", "blocks",
-                          *gpt.MIXER_STACKS.values()) if s in params]
+                          *gpt.MIXER_STACKS.values(),
+                          *gpt.KIND_STACKS.values()) if s in params]
     picked = {s: {n: a for n, a in params[s].items()
                   if n in _CAST_ONLY_BLOCK_LEAVES and a.dtype != dtype}
               for s in stacks}

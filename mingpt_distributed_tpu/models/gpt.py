@@ -33,7 +33,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from mingpt_distributed_tpu.config import LIGHTNING, SPARSE, GPTConfig
+from mingpt_distributed_tpu.config import (
+    FULL_ATTN, LIGHTNING, SPARSE, WINDOW_ATTN, GPTConfig)
 from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.ops import lightning as lightning_ops
 from mingpt_distributed_tpu.ops import sparse_attention as sparse_ops
@@ -46,6 +47,11 @@ Params = Dict[str, Any]
 #: where a hybrid stack's parameters lie: each mixer's layers stacked along
 #: a leading axis under its own name, in the order ``cfg.mixer_layers`` gives
 MIXER_STACKS = {LIGHTNING: "lightning_blocks", SPARSE: "sparse_blocks"}
+#: where the attention of a stack of ``cfg.layer_types`` lies: each kind's
+#: layers stacked under its own name, in the order ``cfg.kind_layers`` gives
+#: (their query-head counts differ, so ``wq``, ``wo`` and the gate do). The
+#: layers' MLPs lie in ``"dense_blocks"`` and ``"blocks"``, as any stack's
+KIND_STACKS = {FULL_ATTN: "full_attn_blocks", WINDOW_ATTN: "window_attn_blocks"}
 
 
 # ---------------------------------------------------------------------------
@@ -84,13 +90,16 @@ def init(key: jax.Array, cfg: GPTConfig) -> Params:
 
     def stack(nl: int, experts: bool) -> Params:
         """``nl`` layers of one kind, stacked: the model's attention and a
-        dense MLP or, under ``experts``, the routed one."""
+        dense MLP or, under ``experts``, the routed one. In a stack of
+        ``cfg.layer_types`` the attention lies apart (``kind_stack``)."""
         nh = cfg.n_head
         hd, kv = cfg.head_dim, cfg.kv_heads
         ffn = cfg.expert_width if experts else cfg.dense_width
         use_bias = not (cfg.swiglu or cfg.rmsnorm)  # GPT-2 mode has biases everywhere
 
-        blocks: Params = {"ln1_scale": ones((nl, d)), "ln2_scale": ones((nl, d))}
+        blocks: Params = {"ln2_scale": ones((nl, d))}
+        if cfg.layer_types is None:
+            blocks["ln1_scale"] = ones((nl, d))
         if cfg.post_norms:
             # drawn in [0.05, 0.2], not 1: seeded branches of unit size make
             # a stack of four norms a layer chaotic, and one that runs its
@@ -112,7 +121,7 @@ def init(key: jax.Array, cfg: GPTConfig) -> Params:
                     nl, r, nh * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
                 wo=normal(next(keys), (nl, nh * cfg.v_head_dim, d), resid_std),
             )
-        else:
+        elif cfg.layer_types is None:
             blocks.update(
                 wq=normal(next(keys), (nl, d, nh * hd)),
                 wk=normal(next(keys), (nl, d, kv * hd)),
@@ -191,12 +200,33 @@ def init(key: jax.Array, cfg: GPTConfig) -> Params:
             blocks["o_norm_scale"] = ones((nl, nh * hd))
         return blocks
 
+    def kind_stack(kind: str) -> Params:
+        """The attention of the layers of ``cfg.layer_types`` of ``kind``,
+        stacked: the input norm, the projections for the kind's head count
+        and the per-head gate, which is drawn (at zero it would gate by
+        one half whatever its input, and a gate left out would not show)."""
+        nl = len(cfg.kind_layers(kind))
+        nh, kv, hd = cfg.kind_heads(kind)
+        blocks: Params = {
+            "ln1_scale": ones((nl, d)),
+            "wq": normal(next(keys), (nl, d, nh * hd)),
+            "wk": normal(next(keys), (nl, d, kv * hd)),
+            "wv": normal(next(keys), (nl, d, kv * hd)),
+            "wo": normal(next(keys), (nl, nh * hd, d), resid_std),
+        }
+        if cfg.head_gate:
+            blocks["w_hg"] = normal(next(keys), (nl, d, nh))
+        return blocks
+
     params: Params = {}
     if cfg.mixer_types is not None:
         for kind in (LIGHTNING, SPARSE):
             if cfg.mixer_layers(kind):
                 params[MIXER_STACKS[kind]] = mixer_stack(kind)
     else:
+        for kind in (FULL_ATTN, WINDOW_ATTN):
+            if cfg.kind_layers(kind):
+                params[KIND_STACKS[kind]] = kind_stack(kind)
         if cfg.n_dense_layers:
             params["dense_blocks"] = stack(cfg.n_dense_layers, experts=False)
         params["blocks"] = stack(cfg.n_layer - cfg.n_dense_layers,
@@ -428,14 +458,16 @@ EXPERT_LEAVES = ("w_eg", "w_e1", "w_e2")
 
 def routed_and_shared(h2, blk: Params, cfg: GPTConfig, valid=None,
                       layer=None):
-    """The MLP of a sigmoid-routed expert layer: each token's k routed
-    experts (ops/moe.moe_dropless) plus the shared expert every token
-    takes. Returns (out, the route's counts). With ``layer``, the
-    EXPERT_LEAVES of ``blk`` are the whole stack's."""
+    """The MLP of a dropless expert layer: each token's k routed experts
+    (ops/moe.moe_dropless, the choice and the gates ``cfg.moe_scoring``'s)
+    plus the shared expert every token takes. Returns (out, the route's
+    counts). With ``layer``, the EXPERT_LEAVES of ``blk`` are the whole
+    stack's."""
     m, counts = moe.moe_dropless(
-        h2, blk["w_router"], blk["e_bias"], blk["w_eg"], blk["w_e1"],
+        h2, blk["w_router"], blk.get("e_bias"), blk["w_eg"], blk["w_e1"],
         blk["w_e2"], top_k=cfg.moe_top_k, norm_topk=cfg.moe_norm_topk,
-        route_scale=cfg.moe_route_scale, valid=valid, layer=layer)
+        route_scale=cfg.moe_route_scale, valid=valid, layer=layer,
+        scoring=cfg.moe_scoring)
     if "w_sg" in blk:
         with jax.named_scope("moe_shared"):
             m = m + L.mlp_swiglu(h2, blk["w_sg"], blk["w_su"], blk["w_sd"])
@@ -447,6 +479,33 @@ def rotated(q, k, rope, cfg: GPTConfig):
     cos, sin = rope
     return (attn_ops.apply_rope(q, cos, sin, cfg.rope_interleave),
             attn_ops.apply_rope(k, cos, sin, cfg.rope_interleave))
+
+
+def layer_rope(cfg: GPTConfig, kind: Optional[str], positions):
+    """The ``(cos, sin)`` tables a layer of ``kind`` rotates by at
+    ``positions`` (``cfg.rope_spec``: the dimensions of a head it turns,
+    which the tables' width says to ``apply_rope``; theta; YaRN's blend
+    where the kind has one)."""
+    dim, theta, yarn = cfg.rope_spec(kind)
+    if yarn is not None:
+        return attn_ops.yarn_rope_tables(positions, dim, theta, *yarn)
+    return attn_ops.rope_tables(positions, dim, theta)
+
+
+def kind_layer_params(params: Params, cfg: GPTConfig, layer: int, whole=()):
+    """A layer of a stack of ``cfg.layer_types``: (its kind of attention,
+    its parameters, its place among that kind's layers, its place in its
+    MLP's stack). The attention comes out of its kind's stack
+    (KIND_STACKS), the MLP out of ``"dense_blocks"`` or ``"blocks"``; the
+    leaves named in ``whole`` stay the stack's (``routed_and_shared``)."""
+    kind = cfg.layer_types[layer]
+    at = cfg.kind_layers(kind).index(layer)
+    stack, mlp_at = (params["dense_blocks"], layer) \
+        if layer < cfg.n_dense_layers \
+        else (params["blocks"], layer - cfg.n_dense_layers)
+    blk = {n: a[at] for n, a in params[KIND_STACKS[kind]].items()}
+    blk.update({n: a if n in whole else a[mlp_at] for n, a in stack.items()})
+    return kind, blk, at, mlp_at
 
 
 @jax.named_scope("qkv")
@@ -484,9 +543,19 @@ def _row_parallel(x, w, b, tp_axis: Optional[str]):
 
 @jax.named_scope("attn_out")
 def attention_out(att, blk: Params, cfg: GPTConfig,
-                  tp_axis: Optional[str] = None):
+                  tp_axis: Optional[str] = None, h=None):
     """Attention's (B, T, H * hd) output on its way back to the stream:
-    through ``wo`` and, under ``cfg.post_norms``, its RMS norm."""
+    where ``blk`` carries a per-head gate (``cfg.head_gate``) each head's
+    part times ``sigmoid(h W_g)`` of the layer's normed input ``h``, the
+    sigmoid in float32; then through ``wo`` and, under ``cfg.post_norms``,
+    its RMS norm."""
+    if "w_hg" in blk:
+        b, t, _ = att.shape
+        gate = jax.nn.sigmoid(jnp.dot(
+            h, blk["w_hg"].astype(h.dtype),
+            preferred_element_type=jnp.float32))            # (B, T, H)
+        att = (gate[..., None] * att.reshape(b, t, gate.shape[-1], -1)
+               ).astype(att.dtype).reshape(b, t, -1)
     att = _row_parallel(att, blk["wo"], blk.get("bo"), tp_axis)
     if cfg.post_norms:
         with jax.named_scope("norm"):
@@ -506,7 +575,7 @@ def mlp_branch(h2, blk: Params, cfg: GPTConfig, *, valid=None, layer=None,
     the capacity route, where a token's room depends on who else is routed,
     takes each alone. ``tp_axis``, ``ep_axis``: ``_block``'s manual forms."""
     aux, counts = jnp.zeros((), jnp.float32), None
-    if "w_router" in blk and cfg.moe_scoring == "sigmoid":
+    if "w_router" in blk and cfg.dropless:
         m, counts = routed_and_shared(h2, blk, cfg, valid, layer)
     elif "w_router" in blk:
         def experts(tokens):
@@ -655,6 +724,7 @@ def _block(
     attn_fn=None,  # override (e.g. manual sp attention inside the pipeline)
     tp_axis: Optional[str] = None,  # manual megatron-tp inside shard_map
     ep_axis: Optional[str] = None,  # manual expert parallelism in shard_map
+    kind: Optional[str] = None,  # the layer's kind in a stack of layer_types
 ) -> Tuple[jax.Array, jax.Array]:
     """One pre-LN transformer block over a whole sequence: norm, parts,
     attend, out, add; norm, MLP, add. The residual sums, dropout and the
@@ -669,7 +739,7 @@ def _block(
     replicated over tp, and the only tp collectives are one psum per
     residual branch (``_row_parallel``)."""
     b, t, _ = x.shape
-    nh, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
+    nh, kv, hd = cfg.kind_heads(kind)
     if tp_axis is not None:
         assert not cfg.n_experts, "tp_axis doesn't compose with MoE blocks"
         tp_n = jax.lax.psum(1, tp_axis)
@@ -694,14 +764,19 @@ def _block(
         # window/softcap compose with every attention impl, including the
         # manual-sp attn_fn override inside pipeline stages
         attn_kw = {}
-        if cfg.attention_window:
-            attn_kw["window"] = cfg.attention_window
+        if cfg.kind_window(kind):
+            attn_kw["window"] = cfg.kind_window(kind)
         if cfg.attn_logit_softcap:
             attn_kw["logit_softcap"] = cfg.attn_logit_softcap
-        att = (attn_fn or _attention_dispatch(cfg, mesh))(
-            q, k, v, attn_pdrop=cfg.attn_pdrop, dropout_key=k_attn,
-            deterministic=deterministic, **attn_kw).reshape(b, t, -1)
-        att = attention_out(att, blk, cfg, tp_axis)
+        if kind is not None:
+            # a chunk of thousands of positions, its scores never whole
+            att = attn_ops.banded_attention(
+                q, k, v, q_start=0, **attn_kw).reshape(b, t, -1)
+        else:
+            att = (attn_fn or _attention_dispatch(cfg, mesh))(
+                q, k, v, attn_pdrop=cfg.attn_pdrop, dropout_key=k_attn,
+                deterministic=deterministic, **attn_kw).reshape(b, t, -1)
+        att = attention_out(att, blk, cfg, tp_axis, h)
         x = x + L.dropout(att, cfg.resid_pdrop, k_resid1, deterministic)
 
     with jax.named_scope("mlp"):
@@ -804,6 +879,28 @@ def forward(
             kind, blk, _ = hybrid_layer_params(params, cfg, layer)
             step = functools.partial(_hybrid_block, cfg=cfg, kind=kind)
             x = (jax.checkpoint(step) if cfg.remat else step)(x, blk)
+        return _head_and_loss(params, x, cfg, targets, return_logits,
+                              jnp.zeros((), jnp.float32), mesh=mesh)
+
+    if cfg.layer_types is not None:
+        if mesh is not None and (mesh.shape.get("pp", 1) > 1
+                                 or mesh.shape.get("tp", 1) > 1):
+            raise NotImplementedError(
+                "a stack of layer_types is not split over pp or tp: its "
+                "kinds of layer have unlike stacks and head counts, and no "
+                "rule shards the two of them")
+        if not deterministic:
+            raise NotImplementedError(
+                "a stack of layer_types is served and evaluated, not "
+                "trained: its layers are written without dropout and its "
+                "attention walks its band in a loop no backward is written "
+                "for")
+        positions = jnp.arange(t)
+        for layer in range(cfg.n_layer):
+            kind, blk, _, _ = kind_layer_params(params, cfg, layer)
+            # the dropless route has no load-balancing term
+            x, _ = _block(x, blk, cfg, layer_rope(cfg, kind, positions),
+                          None, True, kind=kind)
         return _head_and_loss(params, x, cfg, targets, return_logits,
                               jnp.zeros((), jnp.float32), mesh=mesh)
 

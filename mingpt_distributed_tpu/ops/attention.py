@@ -154,13 +154,60 @@ def rope_tables(
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: float,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's blended frequencies for ``dim`` rotated dimensions, (dim/2,)
+    float64, as the published ``rope_parameters`` of ``rope_type`` "yarn"
+    state them: pair i turns by ``theta^(-2i/dim)`` (extrapolation) where it
+    completes more than ``beta_fast`` rotations over the ``original``
+    context, by that over ``factor`` (interpolation) where fewer than
+    ``beta_slow``, and by a linear blend between the two pairs where those
+    counts are met (floor and ceiling, clipped to [0, dim - 1])."""
+    i = np.arange(dim // 2, dtype=np.float64)
+    extra = theta ** (-2.0 * i / dim)
+    inter = extra / factor
+
+    def pair_of(rotations):
+        return dim * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001       # the published code's guard against 0 / 0
+    ramp = np.clip((i - low) / (high - low), 0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def yarn_rope_tables(positions: jax.Array, dim: int, theta: float,
+                     factor: float, original: float, beta_fast: float,
+                     beta_slow: float, attention_factor: float,
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """:func:`rope_tables` under YaRN (:func:`yarn_inv_freq`): the blended
+    frequencies, and the cosine and the sine both times
+    ``attention_factor``, which is how the published code scales the
+    scores of a stretched context."""
+    freqs = jnp.asarray(yarn_inv_freq(
+        dim, theta, factor, original, beta_fast, beta_slow), jnp.float32)
+    angles = positions.astype(jnp.float32)[..., None] * freqs
+    return jnp.cos(angles) * attention_factor, \
+        jnp.sin(angles) * attention_factor
+
+
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
                interleave: bool = False) -> jax.Array:
     """Rotate (B, T, H, hd) by per-position tables (T, hd/2), or by a
     table a batch entry (B, T, hd/2). Pair i is the halves' ``(x[i],
     x[i + hd/2])`` or, under ``interleave`` (DeepSeek's ``rope_interleave``),
     the neighbours ``(x[2i], x[2i + 1])``; either way the rotated pair goes
-    back where it came from."""
+    back where it came from. Tables narrower than half a head turn the
+    head's first ``2 x width`` dimensions and the others pass through (a
+    ``partial_rotary_factor`` under 1)."""
+    if 2 * cos.shape[-1] < x.shape[-1]:
+        turned = 2 * cos.shape[-1]
+        return jnp.concatenate([
+            apply_rope(x[..., :turned], cos, sin, interleave),
+            x[..., turned:]], axis=-1)
     half = x.shape[-1] // 2
     if interleave:
         x1, x2 = x[..., 0::2], x[..., 1::2]
@@ -175,6 +222,91 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
     else:
         out = jnp.concatenate([r1, r2], axis=-1)
     return out.astype(x.dtype)
+
+
+#: queries, and keys, of one step of :func:`banded_attention`'s walk
+BAND_BLOCK = 512
+
+
+def banded_attention(
+    q: jax.Array,  # (B, T, H, hd): query i stands at position q_start + i
+    k: jax.Array,  # (B, S, KV, hd) or (B, S, 1, KV x hd): key j stands at
+    v: jax.Array,  #   position k_start + j
+    *,
+    q_start, k_start=0, window: Optional[int] = None,
+    block: int = BAND_BLOCK,
+) -> jax.Array:
+    """Causal attention of a chunk of queries against keys that stand at
+    positions of their own, the scores never whole: ``causal_attention``
+    for a prefill of thousands of positions over per-head rows. A key is
+    seen where its position is not negative (``k_start`` may be: rows a
+    ring has not written yet), not after the query's and, under
+    ``window``, within the query's last ``window`` positions, its own
+    included. Queries go a block at a time; a block walks the blocks of
+    keys its band touches under a running softmax and no other, so a
+    window layer pays ``O(T x window)`` and a full layer stops at the
+    diagonal. Grouped queries stand beside their KV head: no key is
+    repeated. Rows that keep a position's heads side by side (a cache's:
+    ``generate.cache_leaf_shapes``) are viewed as heads a block at a time,
+    once the block is cut out (:func:`as_heads`: never of a buffer). Where
+    ``T`` or ``S`` is no whole number of blocks, one pass over everything
+    under the same mask. Returns (B, T, H, hd) in q's dtype."""
+    b, t, h, hd = q.shape
+    s, kv = k.shape[1], math.prod(k.shape[2:]) // hd
+    g = h // kv
+    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, dtype=jnp.float32))
+    q_start, k_start = jnp.asarray(q_start), jnp.asarray(k_start)
+
+    def scores(qb, kb, q_pos, k_pos):
+        z = jnp.einsum("bqkgd,bskd->bkgqs", qb, kb,
+                       preferred_element_type=jnp.float32) * scale
+        behind = q_pos[:, None] - k_pos[None, :]
+        allowed = (behind >= 0) & (k_pos >= 0)[None, :]
+        if window is not None:
+            allowed = allowed & (behind < window)
+        return jnp.where(allowed, z, NEG_INF)
+
+    def weigh(p, vb):
+        return jnp.einsum("bkgqs,bskd->bqkgd", p.astype(vb.dtype), vb,
+                          preferred_element_type=jnp.float32)
+
+    if t % block or s % block:
+        qg = q.reshape(b, t, kv, g, hd)
+        p = jax.nn.softmax(scores(qg, as_heads(k, hd), q_start + jnp.arange(t),
+                                  k_start + jnp.arange(s)), axis=-1)
+        return weigh(p, as_heads(v, hd)).reshape(b, t, h, hd).astype(q.dtype)
+
+    n_k = s // block
+    qg = q.reshape(b, t // block, block, kv, g, hd)
+
+    def one_block(i):
+        qb = jax.lax.dynamic_index_in_dim(qg, i, 1, keepdims=False)
+        q_pos = q_start + i * block + jnp.arange(block)
+        # the keys' indices the band touches: up to the last query's own,
+        # from the first query's window's start (and no negative position)
+        first = -k_start if window is None else jnp.maximum(
+            -k_start, q_pos[0] - window + 1 - k_start)
+        lo = jnp.clip(first // block, 0, n_k)
+        hi = jnp.clip((q_pos[-1] - k_start) // block + 1, lo, n_k)
+
+        def step(j, carry):
+            m, l, acc = carry
+            kb, vb = (as_heads(jax.lax.dynamic_slice_in_dim(
+                a, j * block, block, axis=1), hd) for a in (k, v))
+            z = scores(qb, kb, q_pos, k_start + j * block + jnp.arange(block))
+            m_new = jnp.maximum(m, z.max(-1))
+            p, fix = jnp.exp(z - m_new[..., None]), jnp.exp(m - m_new)
+            fix_acc = jnp.moveaxis(fix, -1, 1)[..., None]   # (B, q, KV, G, 1)
+            return m_new, l * fix + p.sum(-1), acc * fix_acc + weigh(p, vb)
+
+        _, l, acc = jax.lax.fori_loop(lo, hi, step, (
+            jnp.full((b, kv, g, block), NEG_INF, jnp.float32),
+            jnp.zeros((b, kv, g, block), jnp.float32),
+            jnp.zeros((b, block, kv, g, hd), jnp.float32)))
+        return acc / jnp.moveaxis(l, -1, 1)[..., None]
+
+    out = jax.lax.map(one_block, jnp.arange(t // block))   # (T/blk, B, blk, ..)
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, h, hd).astype(q.dtype)
 
 
 #: cached rows a step of ``latent_attention``'s prefill walk over a slice
@@ -416,8 +548,23 @@ def causal_attend_step(
     out again, positions minor, before it scored it (compile rehearsal,
     PR 39: a copy and a float32 convert of a layer's slice, each leaf).
     Returns (B, 1, H, hd) in q's dtype."""
-    if frontier is None:
-        frontier = positions
+    def allowed(pos, k_pos):
+        behind = pos - k_pos
+        seen = behind > 0
+        return seen & (behind < window) if window is not None else seen
+
+    return _rows_attend_step(
+        q, k_cache, v_cache, layer, k_new, v_new, positions, walk,
+        positions if frontier is None else frontier, allowed, logit_softcap)
+
+
+def _rows_attend_step(q, k_cache, v_cache, layer, k_new, v_new, positions,
+                      walk, frontier, allowed_rows, logit_softcap=None):
+    """The body of :func:`causal_attend_step` and :func:`ring_attend_step`:
+    one query a lane against its slice of per-head rows as they lie and
+    its own new row beside them. ``allowed_rows(pos (n, 1), index (1, s))``
+    says which rows of a slice a lane at ``pos`` sees, by their index in
+    the slice: the one thing the two differ in."""
     b, _, h, hd = q.shape
     kv = math.prod(k_cache.shape[3:]) // hd
     side_by_side = k_cache.shape[3] != kv
@@ -441,10 +588,8 @@ def causal_attend_step(
             f"{by_q},{by_rows}->{scored}s", _of_lanes(qg, lane, lanes),
             rows[0], preferred_element_type=jnp.float32) * scale,
             logit_softcap)
-        behind = _of_lanes(positions, lane, lanes)[:, None] - k_pos[None, :]
-        allowed = behind > 0
-        if window is not None:
-            allowed = allowed & (behind < window)
+        allowed = allowed_rows(_of_lanes(positions, lane, lanes)[:, None],
+                               k_pos[None, :])
         allowed = allowed[:, None, :] if side_by_side \
             else allowed[:, None, None, :]
         return jnp.where(allowed, z, NEG_INF)
@@ -465,6 +610,43 @@ def causal_attend_step(
     if side_by_side:
         return own_part(out[:, None], kv).astype(q.dtype)
     return out.reshape(b, 1, h, hd).astype(q.dtype)
+
+
+@jax.named_scope("ring_attn")
+def ring_attend_step(
+    q: jax.Array,         # (B, 1, H, hd): one query a lane
+    k_ring: jax.Array,    # (L, B, W, KV, hd) or (L, B, W, 1, KV x hd): whole,
+    v_ring: jax.Array,    #   as it lies, the new rows not yet written
+    layer: int,
+    k_new: jax.Array,     # (B, 1, ...): each lane's own new row
+    v_new: jax.Array,
+    positions: jax.Array,  # (B,): where each lane's query stands
+    walk: StepWalk,       # ``step_walk`` of the two rings
+    *,
+    frontier=None,
+) -> jax.Array:
+    """:func:`causal_attend_step` for a window layer whose slot keeps its
+    last ``W`` rows in a ring, the row of position ``p`` at index ``p mod
+    W``: a lane at position ``p`` attends the rows of positions ``(p - W,
+    p)`` out of the ring as it lies and its own new row beside them. The
+    row at index ``i`` is that of the last position before ``p`` that is
+    ``i`` modulo ``W``; it is ``1 + (p - 1 - i) mod W`` positions behind.
+    At ``W`` behind (index ``p mod W``: the row the lane's own will
+    replace) it has left the window, and a row further behind than ``p``
+    was never written by this request: what a slot's last tenant left there
+    is masked by age as a stale row of a full layer is by position. A lane
+    younger than the window so attends what it has written and no more.
+    ``frontier`` (B,) as in :func:`causal_attend_step`; a lane's reach into
+    a ring ends at ``W``."""
+    w = k_ring.shape[2]
+
+    def allowed(pos, index):
+        behind = 1 + jnp.mod(pos - 1 - index, w)
+        return (behind < w) & (behind <= pos)
+
+    reach = jnp.minimum(positions if frontier is None else frontier, w)
+    return _rows_attend_step(q, k_ring, v_ring, layer, k_new, v_new,
+                             positions, walk, reach, allowed)
 
 
 @jax.named_scope("latent_attn")

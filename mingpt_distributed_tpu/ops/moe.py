@@ -8,7 +8,9 @@
   a group limit), which kanana-2-30b-a3b takes: sigmoid scores, the k best
   of score + bias, the chosen scores normalised and scaled as gates, every
   route computed whatever the load, a shared expert added by the caller.
-  See the function.
+  See the function. Under ``GPTConfig.moe_dropless`` a "softmax" model takes
+  it too (Laguna-XS.2: ``softmax_routes``, the k largest logits, their
+  softmax probabilities renormalised and scaled as gates).
 
 The capacity route:
 
@@ -228,6 +230,20 @@ def sigmoid_routes(h, w_router, bias, *, top_k: int, norm_topk: bool,
     return chosen.astype(jnp.int32), gates * route_scale, select
 
 
+def softmax_routes(h, w_router, *, top_k: int, route_scale: float):
+    """:func:`sigmoid_routes` for a router that scores with a softmax
+    (Qwen2-MoE's, Laguna's): (N, D) tokens -> (chosen experts (N, k) int32,
+    gates (N, k) float32, the router's logits (N, E) float32). The k chosen
+    are the largest logits (ties to the lowest index); the gates are their
+    softmax probabilities over all E experts, in float32, over the chosen
+    ones' sum (``norm_topk_prob``), times ``route_scale``."""
+    z = h.astype(jnp.float32) @ w_router.astype(jnp.float32)
+    chosen = jax.lax.top_k(z, top_k)[1]
+    gates = jnp.take_along_axis(jax.nn.softmax(z, axis=-1), chosen, axis=-1)
+    gates = gates / gates.sum(-1, keepdims=True)
+    return chosen.astype(jnp.int32), gates * route_scale, z
+
+
 @jax.named_scope("moe_experts")
 def grouped_swiglu(x, chosen, w_gate, w_up, w_down, valid=None, layer=None):
     """The chosen experts on every ``valid`` token: (N, D) tokens and (N, k)
@@ -338,16 +354,23 @@ def moe_dropless(
     route_scale: float = 1.0,
     valid: jax.Array = None,   # (B, T) bool: the tokens that are routed
     layer: int = None,         # the expert weights are the stack's (L, E, ..)
+    scoring: str = "sigmoid",
 ) -> Tuple[jax.Array, jax.Array]:
-    """The routed part of a DeepSeek-V3 expert layer: ``sum_i g_i
+    """The routed part of a dropless expert layer: ``sum_i g_i
     expert_i(x)`` over each ``valid`` token's k experts (zeros for any
-    other). Returns (out (B, T, D), counts (E + 3,) int32:
-    ``grouped_swiglu``, which also says what ``layer`` is for)."""
+    other), the choice and the gates DeepSeek-V3's (:func:`sigmoid_routes`)
+    or, under ``scoring`` "softmax", :func:`softmax_routes`' (no ``bias``,
+    gates always renormalised). Returns (out (B, T, D), counts (E + 3,)
+    int32: ``grouped_swiglu``, which also says what ``layer`` is for)."""
     b, t, d = x.shape
     tokens = x.reshape(b * t, d)
-    chosen, gates, _ = sigmoid_routes(
-        tokens, w_router, bias, top_k=top_k, norm_topk=norm_topk,
-        route_scale=route_scale)
+    if scoring == "softmax":
+        chosen, gates, _ = softmax_routes(
+            tokens, w_router, top_k=top_k, route_scale=route_scale)
+    else:
+        chosen, gates, _ = sigmoid_routes(
+            tokens, w_router, bias, top_k=top_k, norm_topk=norm_topk,
+            route_scale=route_scale)
     out, counts = grouped_swiglu(
         tokens, chosen, w_gate, w_up, w_down,
         None if valid is None else valid.reshape(b * t), layer)

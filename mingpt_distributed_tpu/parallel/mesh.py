@@ -201,6 +201,7 @@ PARAM_RULES: dict[str, P] = {
     # gate's columns are the heads', as wq's; the per-head qk-norm weights
     # and the linear mixer's output norm stay whole
     "w_og": P("pp", "fsdp", "tp"),
+    "w_hg": P("pp", "fsdp"),
     "q_norm_scale": P("pp"),
     "k_norm_scale": P("pp"),
     "o_norm_scale": P("pp"),

@@ -257,6 +257,20 @@ def decode_rows_read(positions, live, walk: attn_ops.StepWalk):
     return attn_ops.step_rows_read(walk, decode_frontier(positions, live))
 
 
+def ring_rows(positions, live, walk: attn_ops.StepWalk):
+    """(rows read, rows inside their lanes' windows) of a window layer's
+    rings in a decode step, a plane, summed over the lanes:
+    :func:`decode_rows_read` for a ring of ``walk.s`` rows
+    (``attn_ops.ring_attend_step``). A lane's reach into its ring ends at
+    the ring's size, and of the rows it is read, those of the ``ring - 1``
+    positions before its own are inside its window (its own new row is
+    attended beside the ring and is none of the ring's). ``walk`` is the
+    engine's (``DecodeEngine.ring_walk``). NumPy or ``jnp`` vectors alike."""
+    reach = decode_frontier(positions, live)
+    return (attn_ops.step_rows_read(walk, reach.clip(0, walk.s)),
+            reach.clip(0, walk.s - 1).sum())
+
+
 def decode_walk(cfg: GPTConfig, cache, kv_quant=None) -> attn_ops.StepWalk:
     """The decode step's walk over the pool ``cache``, worked out once from
     the pool's own leaves as the step reads them (``generate.cache_walk``
@@ -614,6 +628,19 @@ class DecodeEngine:
                 "pooled key, and a stored prefix would have to carry the "
                 "state at its last row, which rows copied up to a bucket "
                 "do not")
+        if cfg.layer_types is not None and (
+                self.kv_quant is not None or mesh is not None
+                or prefix_cache_mb > 0 or prefill_chunk is not None):
+            raise ConfigError(
+                "a stack of layer_types is served on one device with an "
+                "unquantized pool, whole prompts and no prefix store: no "
+                "rule splits two kinds of layer's unlike head counts over a "
+                f"mesh, kv_dtype={self.kv_dtype!r} has no scale for a ring, "
+                "a stored prefix or a migrated slot would have to carry the "
+                "window layers' rings at its last row, which rows copied up "
+                "to a bucket do not, and a last chunk shifted back to stay "
+                "inside the context would lay rows in a ring that the "
+                "chunk before it has already passed")
         if mesh is not None:
             # One placement decision, made once: params follow the megatron
             # column/row rules, the pool shards heads over the tp axis (or
@@ -683,6 +710,8 @@ class DecodeEngine:
         # how the decode step walks this pool: the program is built with it
         # and the scheduler counts by it (decode_rows_read)
         self.walk = decode_walk(cfg, self.pool.cache, kq)
+        # and a window layer's ring (None: the stack has none)
+        self.ring_walk = gen.ring_walk(cfg, self.pool.cache)
         self._decode_jit = jax.jit(
             bind_static(_decode_impl, cfg=cfg, kv_sharding=kv, kv_quant=kq,
                         walk=self.walk),
@@ -739,7 +768,27 @@ class DecodeEngine:
         ``state_bytes_per_slot``)."""
         return sum(a.nbytes // (a.shape[1] * self.cfg.block_size)
                    for n, a in self.pool.cache.items()
-                   if n not in gen.COUNTERS and n != gen.STATE)
+                   if n not in gen.COUNTERS + gen.RINGS and n != gen.STATE)
+
+    @property
+    def ring_bytes_per_slot(self) -> int:
+        """Bytes of the window layers' rings a slot holds beside its rows
+        (a stack of ``layer_types``), whatever the request's length; 0
+        where every layer keeps a row a position."""
+        return sum(a.nbytes // a.shape[1]
+                   for n, a in self.pool.cache.items() if n in gen.RINGS)
+
+    @property
+    def ring_rows_per_slot(self) -> int:
+        """Rows one ring of a slot holds (the window); 0 with no ring."""
+        ring = self.pool.cache.get(gen.RING_K)
+        return 0 if ring is None else int(ring.shape[2])
+
+    @property
+    def ring_planes(self) -> int:
+        """The window layers, each with a ring of keys and one of values."""
+        ring = self.pool.cache.get(gen.RING_K)
+        return 0 if ring is None else int(ring.shape[0])
 
     @property
     def state_bytes_per_slot(self) -> int:
@@ -887,9 +936,12 @@ class DecodeEngine:
         short of the prompt (a hit on the peer must leave >= 1 tail token
         to prefill). Collapses to ``quantized_prefix_len`` for a slot
         that finished prefilling. 0 = nothing shippable."""
-        if self.cfg.mixer_types is not None:
-            # a state cannot be cut at a bucket, and pooled keys lie on
-            # another grid than rows: the peer prefills anew
+        if self.cfg.mixer_types is not None \
+                or self.cfg.layer_types is not None:
+            # a state cannot be cut at a bucket, pooled keys lie on
+            # another grid than rows, and a ring holds the rows before a
+            # request's last position, not a bucket's: the peer prefills
+            # anew
             return 0
         cap = min(frontier, prompt_len - 1)
         best = 0
@@ -919,6 +971,11 @@ class DecodeEngine:
             raise ValueError(
                 "a slot of a hybrid stack is rows and a state: its leading "
                 "rows alone are no request (migratable_rows is 0)")
+        if self.cfg.layer_types is not None:
+            raise ValueError(
+                "a slot of a stack of layer_types is rows and the window "
+                "layers' rings: its leading rows alone are no request "
+                "(migratable_rows is 0)")
         if rows not in self.buckets:
             raise ValueError(
                 f"extract rows {rows} not on the bucket ladder "

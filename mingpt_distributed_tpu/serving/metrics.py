@@ -95,6 +95,14 @@ class ServingMetrics:
             "mingpt_serve_decode_rows_reserved_total",
             help="rows the slots of those decode steps reserve "
                  "(n_slots x block_size a step)")
+        self._ring_rows_read = r.counter(
+            "mingpt_serve_ring_rows_read_total",
+            help="rows of the window layers' rings the decode steps read, "
+                 "all window layers: each live lane's ring in blocks as far "
+                 "as it has written (engine.ring_rows)")
+        self._ring_rows_live = r.counter(
+            "mingpt_serve_ring_rows_live_total",
+            help="of those, the rows inside their lanes' windows")
         self._decode_launches = r.counter(
             "mingpt_serve_decode_launches_total",
             help="decode steps handed to the device")
@@ -224,6 +232,13 @@ class ServingMetrics:
         self._state_bytes_per_slot = r.gauge(
             "mingpt_serve_state_bytes_per_slot",
             help="bytes of recurrent state a slot holds beside its rows")
+        self._ring_bytes_per_slot = r.gauge(
+            "mingpt_serve_ring_bytes_per_slot",
+            help="bytes of the window layers' rings a slot holds beside "
+                 "its rows")
+        self._ring_rows_per_slot = r.gauge(
+            "mingpt_serve_ring_rows_per_slot",
+            help="rows one ring of a slot holds (the window); 0: no ring")
         self._kv_row_width = r.gauge(
             "mingpt_serve_kv_row_width",
             help="last axis of the pool's k leaf: one head's size, or a "
@@ -410,6 +425,14 @@ class ServingMetrics:
         self._decode_rows_read.inc(read)
         self._decode_rows_reserved.inc(reserved)
 
+    def on_ring_rows(self, read: int, live: int) -> None:
+        """A decode step's rows of the window layers' rings, all window
+        layers: those it read and, of them, those inside their lanes'
+        windows (the scheduler calls it beside ``on_decode_rows``, with
+        the program's own rule: ``engine.ring_rows``)."""
+        self._ring_rows_read.inc(read)
+        self._ring_rows_live.inc(live)
+
     def on_decode_launch(self, ahead: bool) -> None:
         """A decode step handed to the device; ``ahead``: before the sync
         of the step before it."""
@@ -552,6 +575,7 @@ class ServingMetrics:
                      loop_passes_source: Optional[Callable[[], Any]] = None,
                      kv_row_width: int = 0, kv_row_tiles: int = 0,
                      head_boundaries_source: Optional[Callable[[], int]] = None,
+                     ring_bytes_per_slot: int = 0, ring_rows_per_slot: int = 0,
                      ) -> None:
         """What the engine's programs read and what a cached token and a
         slot's state cost, known once it is built; ``kv_row_width`` and
@@ -570,6 +594,8 @@ class ServingMetrics:
         self._program_weights_cast.set(program_weights_cast)
         self._kv_bytes_per_row.set(kv_bytes_per_row)
         self._state_bytes_per_slot.set(state_bytes_per_slot)
+        self._ring_bytes_per_slot.set(ring_bytes_per_slot)
+        self._ring_rows_per_slot.set(ring_rows_per_slot)
         self._kv_row_width.set(kv_row_width)
         self._kv_row_tiles.set(kv_row_tiles)
         self._moe_rows_source = moe_rows_source
@@ -593,6 +619,21 @@ class ServingMetrics:
                 "loop_tokens": float(got[1]),
                 "loop_exit_mass": [float(m) / max(float(got[1]), 1.0)
                                    for m in got[2:]]}
+
+    def _ring_summary(self) -> Dict[str, Any]:
+        """The window layers' rings of a stack of ``layer_types``: what a
+        slot holds of them, and the rows the decode steps read of them
+        since the server was built beside those that were inside their
+        lanes' windows. None where no layer keeps a ring."""
+        rows = int(self._ring_rows_per_slot.value)
+        if not rows:
+            return dict.fromkeys((
+                "ring_bytes_per_slot", "ring_rows_per_slot",
+                "ring_rows_read", "ring_rows_live"))
+        return {"ring_bytes_per_slot": int(self._ring_bytes_per_slot.value),
+                "ring_rows_per_slot": rows,
+                "ring_rows_read": int(self._ring_rows_read.value),
+                "ring_rows_live": int(self._ring_rows_live.value)}
 
     def _sparse_summary(self) -> Dict[str, Any]:
         """The sparse layers' decode steps since the server was built: the
@@ -638,6 +679,7 @@ class ServingMetrics:
             "program_weights_cast": int(self._program_weights_cast.value),
             "kv_bytes_per_row": int(self._kv_bytes_per_row.value),
             "state_bytes_per_slot": int(self._state_bytes_per_slot.value),
+            **self._ring_summary(),
             "kv_row_width": int(self._kv_row_width.value),
             "kv_row_tiles": int(self._kv_row_tiles.value),
             "decode_head_boundaries": self._head_boundaries_source()

@@ -107,6 +107,7 @@ from mingpt_distributed_tpu.serving.engine import (
     DecodeEngine,
     DecodeLaunch,
     decode_rows_read,
+    ring_rows,
     sampler_orders,
 )
 from mingpt_distributed_tpu.serving.metrics import ServingMetrics
@@ -346,6 +347,14 @@ class InferenceServer:
                     "speculation rolls rejected tokens back by position, and "
                     "a hybrid stack's state has none: neither the target "
                     "nor the draft may set mixer_types")
+            if cfg.layer_types is not None \
+                    or draft_cfg.layer_types is not None:
+                raise ConfigError(
+                    "speculation writes the rows of the tokens it proposes "
+                    "and rolls the rejected ones back by position: in a "
+                    "window layer's ring they have overwritten the rows of "
+                    "the window's far end, which no roll-back restores; "
+                    "neither the target nor the draft may set layer_types")
             if cfg.n_passes > 1 or draft_cfg.n_passes > 1:
                 raise ConfigError(
                     "speculation (spec_k) is not built for a looped stack "
@@ -375,7 +384,8 @@ class InferenceServer:
             self.engine.kv_bytes_per_row, self.engine.moe_rows,
             self.engine.state_bytes_per_slot, self.engine.sparse_rows,
             self.engine.loop_passes, self.engine.pool.row_width,
-            self.engine.pool.row_tiles, self.engine.head_boundaries)
+            self.engine.pool.row_tiles, self.engine.head_boundaries,
+            self.engine.ring_bytes_per_slot, self.engine.ring_rows_per_slot)
         # post-warmup recompile watchdog over the compiled program families
         # (the merged server-level counts, so draft/verify traces are
         # watched too; armed after warmup(); checked every round)
@@ -767,6 +777,11 @@ class InferenceServer:
         self.metrics.on_decode_rows(
             int(decode_rows_read(pos, live, self.engine.walk)),
             st.n_slots * self.cfg.block_size)
+        if self.engine.ring_walk is not None:
+            read, inside = ring_rows(pos, live, self.engine.ring_walk)
+            self.metrics.on_ring_rows(
+                int(read) * self.engine.ring_planes,
+                int(inside) * self.engine.ring_planes)
         self._flight.append(
             _InFlight(step, [(s, st.handles[s]) for s in lanes]))
         st.positions[lanes] += 1

@@ -53,7 +53,7 @@ __all__ = ["SCOPES", "Filing", "abstract", "compile_programs", "filed_records",
 #: (``tests/test_program_scopes.py`` holds the two lists equal)
 SCOPES = (
     "attn", "mlp", "ce", "optimizer", "sample", "kv_layout", "cached_attn",
-    "latent_attn", "moe_experts", "moe_shared", "lightning_scan",
+    "ring_attn", "latent_attn", "moe_experts", "moe_shared", "lightning_scan",
     "lightning_step", "sparse_select", "sparse_attend", "exit_gate",
     # the parts of a layer and the stack's two ends, in every program that
     # runs them (``models/gpt.py``, ``models/generate.py``): with them
