@@ -53,6 +53,10 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from mingpt_distributed_tpu.ops import flash_attention
 
 # Max tokens routed as one group; actual group size is the largest divisor
 # of S at most this (S itself for small inputs). Groups below MIN_GROUP
@@ -244,40 +248,146 @@ def softmax_routes(h, w_router, *, top_k: int, route_scale: float):
     return chosen.astype(jnp.int32), gates * route_scale, z
 
 
+def _mosaic_compiles() -> bool:
+    """Whether the cached path's blocks go through the Pallas kernel: where
+    Mosaic compiles it. ``flash_attention._interpret`` is asked through its
+    module, at trace time: it is the one name a compile rehearsal steers
+    (``benchmarks/rehearse.py``), so a cell's programs planned for a
+    described chip hold this kernel too."""
+    return not flash_attention._interpret()
+
+
+# What of a core's VMEM (128 MiB on a v5e) the two buffers of an expert's
+# three weight tiles may take; the call asks for them, the blocks' own and
+# the products' room
+_WEIGHT_BUFFERS = 64 << 20
+
+
+def _width_tile(d: int, f: int, itemsize: int) -> int:
+    """Columns of ``F`` a grid step takes: all of them where an expert's
+    three matrices fit their buffers twice over (9.4 MB an expert at 2,048 x
+    768 in bfloat16), else the largest multiple of 128 that divides ``F``
+    and does."""
+    fits = lambda tf: 2 * 3 * d * tf * itemsize <= _WEIGHT_BUFFERS
+    if fits(f):
+        return f
+    return max((tf for tf in range(128, f, 128) if f % tf == 0 and fits(tf)),
+               default=f)
+
+
+def _swiglu_block_kernel(expert_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref):
+    """Grid step (i, j): block i of the layout through columns j of its
+    expert's matrices, ``block()`` of :func:`grouped_swiglu` operand for
+    operand. The down product of a later tile of ``F`` adds to the output
+    block, which stays where it is while i does."""
+    del expert_ref      # the weights' index maps read it
+    rows = x_ref[0]
+    gate = jnp.dot(rows, wg_ref[0].astype(rows.dtype),
+                   preferred_element_type=jnp.float32)
+    up = jnp.dot(rows, wu_ref[0].astype(rows.dtype),
+                 preferred_element_type=jnp.float32)
+    inner = (jax.nn.silu(gate) * up).astype(rows.dtype)
+    part = jnp.dot(inner, wd_ref[0].astype(rows.dtype),
+                   preferred_element_type=jnp.float32)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        o_ref[0] = part
+
+    @pl.when(pl.program_id(1) > 0)
+    def _():
+        o_ref[0] += part
+
+
+# One jitted caller a shape and not one a layer (kanana's decode program
+# writes out five expert layers, each prefill bucket five more), as
+# ``flash_attention._native_forward`` is and for its reason. Its name holds
+# not the kernel's: XLA names instructions after it, and the benchmark's
+# reader finds the kernel by name. What it traced holds what ``_interpret()``
+# said then; a test that steers it clears the cache on both sides.
+@jax.jit
+def _run_blocks(laid, expert, ran, w_gate, w_up, w_down):
+    """The first ``ran`` blocks of ``laid`` (n_blocks, bm, D), block i
+    through expert ``expert[i]`` of the stacked (L*E, D, F) / (L*E, F, D)
+    leaves -> (n_blocks, bm, D) float32. The leaves stay in HBM whole; the
+    grid's first bound is ``ran`` itself, and the pipeline fetches block
+    i + 1's matrices (where its expert is another) while block i is in the
+    MXU. The blocks past ``ran`` are not written: they hold whatever the
+    buffer held."""
+    n_blocks, bm, d = laid.shape
+    f = w_gate.shape[-1]
+    tf = _width_tile(d, f, w_gate.dtype.itemsize)
+    vmem = (2 * 3 * d * tf * w_gate.dtype.itemsize       # the weights' tiles
+            + 2 * bm * d * (laid.dtype.itemsize + 4)     # a block in and out
+            + 4 * bm * (3 * tf + d) * 4 + (4 << 20))     # the products
+    return pl.pallas_call(
+        _swiglu_block_kernel,
+        out_shape=jax.ShapeDtypeStruct((n_blocks, bm, d), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(ran, f // tf),
+            in_specs=[
+                pl.BlockSpec((1, bm, d), lambda i, j, ex: (i, 0, 0)),
+                pl.BlockSpec((1, d, tf), lambda i, j, ex: (ex[i], 0, j)),
+                pl.BlockSpec((1, d, tf), lambda i, j, ex: (ex[i], 0, j)),
+                pl.BlockSpec((1, tf, d), lambda i, j, ex: (ex[i], j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bm, d), lambda i, j, ex: (i, 0, 0)),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem),
+        interpret=flash_attention._interpret(),
+        name="grouped_swiglu",
+    )(expert, laid, w_gate, w_up, w_down)
+
+
 @jax.named_scope("moe_experts")
 def grouped_swiglu(x, chosen, w_gate, w_up, w_down, valid=None, layer=None):
     """The chosen experts on every ``valid`` token: (N, D) tokens and (N, k)
     experts -> ((N, k, D) float32 expert outputs, (E + 3,) int32 counts).
     The weights are one layer's (E, D, F) / (E, F, D) or, with ``layer``,
     the whole stack's (L, E, ...) of which that layer is taken: the loop
-    below then reads its one expert a step straight out of the stacked
-    leaf. (A layer sliced out first is an operand of the loop, and the TPU
-    compiler copies it there whole: 1.1 GB a layer at 128 experts of
-    2,048 x 768.)
+    or the kernel below then reads its one expert a step straight out of
+    the stacked leaf. (A layer sliced out first is an operand of the loop,
+    and the TPU compiler copies it there whole: 1.1 GB a layer at 128
+    experts of 2,048 x 768.)
 
     Nothing is dropped and no expert computes a token that did not choose
     it: the routes of the tokens of ``valid`` (N,) (None: all) are laid out
     grouped by expert, each group padded to whole blocks of ``_row_block``
     rows. The layout's shape is static, the worst case (at most E - 1
     blocks of padding, whatever the load); the blocks that hold a row are
-    its first ``sum_e ceil(size_e / bm)``, and the loop takes those and no
-    other through their expert's three matrices: a block that holds no row
-    runs no dot and reads no weight. With ``layer`` (the cached forward:
-    inference only) the loop's trip count is that number. Without, the
-    call may be differentiated (``gpt.forward`` trains through it) and a
-    loop of unknown length cannot be: the trip count is the layout's, and
-    a ``lax.cond`` a step passes an empty block by (on the chip 2.5-3.5 us
-    at 8 rows a block and 15 at 128, where a block's read is 16: PERF.md,
-    PR 35). A route's result depends on its own token alone, so which
-    other tokens share the call changes nothing for it. A token that is not
-    ``valid`` (a lane without a request, a bucket's padding) has no route
-    laid: its outputs are zeros.
+    its first ``sum_e ceil(size_e / bm)``, and those and no other go
+    through their expert's three matrices: a block that holds no row runs
+    no dot and reads no weight. One algorithm in three forms, chosen by
+    what the call can see:
+
+    * without ``layer`` the call may be differentiated (``gpt.forward``
+      trains through it) and a loop of unknown length cannot be: an XLA loop
+      over the layout's blocks, a ``lax.cond`` a step passing an empty
+      block by (on the chip 2.5-3.5 us at 8 rows a block and 15 at 128,
+      where a block's read is 16: PERF.md, PR 35);
+    * with ``layer`` (the cached forward: inference only), where Mosaic
+      compiles (:func:`_mosaic_compiles`: the chip), one Pallas kernel
+      (:func:`_run_blocks`, ``grouped_swiglu``) whose grid is the blocks
+      that hold a row: the next block's expert is fetched while this one is
+      in the MXU, which nothing in a ``while`` body can be (PERF.md, PR 60);
+    * with ``layer`` elsewhere, the XLA loop with that number as its trip
+      count: the same operands, dtypes and products a block, so the CPU's
+      serving programs are what they were.
+
+    A route's result depends on its own token alone, so which other tokens
+    share the call changes nothing for it. A token that is not ``valid`` (a
+    lane without a request, a bucket's padding) has no route laid: its
+    outputs are zeros, read from the fill and never from the layout, so the
+    blocks the kernel does not write need no zeros.
 
     ``counts[:E]`` are the rows each expert's blocks computed, counted from
     the layout the blocks that ran read; ``counts[E]`` is the routes the
     ``valid`` tokens asked for: the two agree exactly when nothing was
-    dropped. ``counts[E + 1]`` is the blocks the loop took through an
-    expert and ``counts[E + 2]`` the blocks the layout has."""
+    dropped. ``counts[E + 1]`` is the blocks taken through an expert and
+    ``counts[E + 2]`` the blocks the layout has."""
     n, d = x.shape
     k = chosen.shape[1]
     e, first = w_gate.shape[-3], 0
@@ -324,13 +434,16 @@ def grouped_swiglu(x, chosen, w_gate, w_up, w_down, valid=None, layer=None):
             preferred_element_type=jnp.float32), i, 0)
 
     ran = last_block[-1]
-    out = jnp.zeros((n_blocks, bm, d), jnp.float32)
+    zeros = jnp.zeros((n_blocks, bm, d), jnp.float32)
     if layer is None:
         # every block a step, those that hold no row passed by
         out = jax.lax.fori_loop(0, n_blocks, lambda i, out: jax.lax.cond(
-            i < ran, block, lambda i, out: out, i, out), out)
+            i < ran, block, lambda i, out: out, i, out), zeros)
+    elif _mosaic_compiles():
+        out = _run_blocks(laid, (first + block_expert).astype(jnp.int32),
+                          ran, w_gate, w_up, w_down)
     else:
-        out = jax.lax.fori_loop(0, ran, block, out)
+        out = jax.lax.fori_loop(0, ran, block, zeros)
     out = out.reshape(n_blocks * bm, d).at[dest].get(
         mode="fill", fill_value=0).reshape(n, k, d)
 

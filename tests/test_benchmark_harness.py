@@ -65,3 +65,81 @@ def test_the_manifest_s_new_entries_have_readers_and_cells_that_report():
         want = "train" if ".train_" in m["name"] else "serve"
         assert {kinds[w] for w in m["workloads"]} == {want}
         assert len(m["workloads"]) >= (2 if want == "train" else 5)
+
+
+def _experts_evidence(calls):
+    """A serving cell's evidence as ``test_readers_absent`` records it, its
+    trace holding ``calls`` Mosaic calls named after the experts' kernel
+    (30 and 50 us the first two, inside the 1 s window) and a third the
+    window cuts away, its counter of blocks run moved by four."""
+    from benchmarks.harness import trace
+    from benchmarks.tests import test_readers_absent as absent
+    from benchmarks.tests.test_spans import EPOCH_NS, MOSAIC
+
+    whole, filed = absent.recorded("kanana-2-30b-a3b.serve-long-decode")
+    us = 1e3
+    ops = [ev("%fusion.11 = f32[8]{0} fusion()", 10 * us, 40 * us)]
+    for n, (lo, hi) in enumerate([(100, 130), (200, 250)][:calls]):
+        ops.append(ev(f"%grouped_swiglu.{n + 2} = f32[175,8,2048]{{2,1,0}} "
+                      f"custom-call(s32[] %ran){MOSAIC}", lo * us, hi * us))
+    if calls:
+        # another kernel's call that names this one's output, and this
+        # kernel's call outside the window: neither counts
+        ops.append(ev("%flash_fwd.9 = bf16[8]{0} custom-call(f32[8]{0} "
+                      f"%grouped_swiglu.2){MOSAIC}", 300 * us, 320 * us))
+        ops.append(ev(f"%grouped_swiglu.4 = f32[8]{{0}} custom-call(){MOSAIC}",
+                      2e6 * us, 2e6 * us + 70 * us))
+    whole["trace"] = trace.from_planes([
+        Plane("/device:TPU:0", [
+            Line("XLA Modules", [ev("jit__decode_impl(3)", 0, 400 * us)]),
+            Line("XLA Ops", ops)]),
+        Plane("/host:CPU", [Line("python", [ev("bench.window", 0, 1e6 * us)])]),
+        Plane("Task Environment", [], [("profile_start_time", EPOCH_NS)])])
+    for counters, level in ((whole["play"].trace_open, 10),
+                            (whole["play"].trace_close, 14)):
+        counters["moe_blocks_run"] = level
+    return whole, filed
+
+
+def test_the_experts_kernel_s_reader_reads_its_calls_over_the_blocks_run(
+        monkeypatch):
+    """PR 60's reader, which no file of ``benchmarks/tests`` can hold whole
+    (``test_readers_absent.py`` records no serving trace with a Mosaic call
+    and may not be edited): two ``grouped_swiglu`` calls of 30 and 50 us in
+    the window over a counter that moved by four blocks read 20 us a block;
+    with anything it reads taken away, by ``test_readers_absent``'s own
+    list, it reads None or what it read, and never raises; on a trace
+    without such a call (the parent's program) and on a counter that did not
+    move it reads None."""
+    import copy
+
+    from benchmarks.harness import spec
+    from benchmarks.tests import test_readers_absent as absent
+
+    read = spec.load_reader("kernel.grouped_swiglu_us_per_block").read
+    whole, filed = _experts_evidence(2)
+    value = read(copy.copy(whole))
+    assert value == pytest.approx(20.0)
+    for lack, take in absent.LACKS.items():
+        evidence, files = take(copy.copy(whole), filed)
+        absent._filing(monkeypatch, files)
+        got = read(evidence)
+        assert got is None or (lack not in ("nothing", "no-trace")
+                               and isinstance(got, float)), lack
+        if lack in ("no-trace", "no-counter-field", "no-traced-counters",
+                    "no-generator-s-records", "nothing",
+                    "no-run-of-the-program"):   # another trace, no call
+            assert got is None, lack
+        else:
+            assert got == value, lack
+    assert read(_experts_evidence(0)[0]) is None        # the parent's trace
+    still = _experts_evidence(2)[0]
+    still["play"].trace_close["moe_blocks_run"] = 10
+    assert read(still) is None
+    # entered in the manifest for the two cells that run routed experts
+    entry = [m for m in spec.load_manifest()["per_layer"]
+             if m["name"] == "kernel.grouped_swiglu_us_per_block"]
+    assert [(m["layer"], m["source"], m["better"], m["moves"]) for m in entry
+            ] == [("kernel", "device_trace", "lower", "itl_p50_ms")]
+    assert entry[0]["workloads"] == [
+        "kanana-2-30b-a3b.serve-long-decode", "laguna-xs.2.serve-long-decode"]

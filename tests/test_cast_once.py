@@ -519,7 +519,9 @@ def test_decode_program_reads_the_cache_as_it_lies(form, block, one_chip,
                        ops=("select",))
 
 
-def test_the_experts_loop_takes_the_stacked_leaves_as_they_lie(one_chip):
+@pytest.mark.parametrize("form", ["loop", "kernel"])
+def test_the_experts_loop_takes_the_stacked_leaves_as_they_lie(
+        form, one_chip, monkeypatch):
     """A routed model's decode and prefill programs compiled for the chip
     (PR 35): the stacked expert leaves are operands of the experts' loop,
     whose trip count is the blocks that hold a row, and the chip's
@@ -527,8 +529,17 @@ def test_the_experts_loop_takes_the_stacked_leaves_as_they_lie(one_chip):
     size or the stack's is copied, sliced or fused into a new buffer. A
     layer sliced out of the stack before the loop is the control: the
     chip's compiler copies it whole (PR 30: 1.1 GB a layer at kanana's
-    sizes)."""
-    from mingpt_distributed_tpu.ops import moe
+    sizes). Both forms of the cached path (PR 60): the XLA loop, which a
+    process off the chip keeps, and the Pallas kernel the chip's programs
+    hold, one Mosaic call a layer named ``grouped_swiglu`` under the
+    experts' scope and no loop of dots, which Mosaic takes at 8 rows a
+    block (the decode step's) and at the prefill's; the kernel is reached
+    as a compile rehearsal reaches it, through ``flash_attention._interpret``."""
+    from mingpt_distributed_tpu.ops import flash_attention, moe
+
+    if form == "kernel":
+        monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    moe._run_blocks.clear_cache()
 
     cfg = GPTConfig.make(**{
         **WIDE, **WIDE_FORMS["latent"], "n_layer": 3, "n_dense_layers": 1,
@@ -559,8 +570,24 @@ def test_the_experts_loop_takes_the_stacked_leaves_as_they_lie(one_chip):
         _, _, jitted, args, kwargs = [
             p for p in engine.programs() if p[0] == family][-1]
         text = jitted.lower(*on_chip(args), **kwargs).compile().as_text()
-        assert "moe_experts/while/body" in text     # the loop is in it
-        assert not expert_sized(text)
+        kernels = re.findall(
+            r'%([\w.\-]+) = .*custom_call_target="tpu_custom_call".*'
+            r'op_name="([^"]*)"', text)
+        if form == "loop":
+            assert "moe_experts/while/body/dot_general" in text  # the loop
+            assert not kernels
+        else:
+            assert "moe_experts/while/body/dot_general" not in text
+            assert len(kernels) == cfg.n_layer - cfg.n_dense_layers
+            assert all("grouped_swiglu" in name and "moe_experts" in scope
+                       for name, scope in kernels)
+        # at this toy size a two-layer stack (1.5 MB) fits the core's fast
+        # memory whole, and for a Mosaic call the compiler prefetches it
+        # there in slices (``S(1)``): no copy in HBM, and nothing a cell's
+        # 6 GB stack can meet (`rehearse.py compile`, PERF.md, PR 60)
+        assert not [line for line in expert_sized(text)
+                    if form == "loop" or "S(1)" not in line]
+    moe._run_blocks.clear_cache()
 
     def sliced_first(x, chosen, blocks):
         return moe.grouped_swiglu(x, chosen, *(

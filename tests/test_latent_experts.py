@@ -562,6 +562,166 @@ def test_only_the_blocks_that_hold_a_valid_token_s_route_are_run(
         assert counts[e + 1] == -(-k * asked.sum() // bm) > 1
 
 
+@pytest.fixture
+def kernel_path(monkeypatch):
+    """The cached path's blocks through the Pallas kernel, interpreted (off
+    the chip ``grouped_swiglu`` keeps the loop; the kernel's ``interpret``
+    is still ``flash_attention._interpret()``'s word, true here). The
+    kernel's jitted caller forgets its traces on both sides."""
+    monkeypatch.setattr(moe, "_mosaic_compiles", lambda: True)
+    run_blocks = moe._run_blocks
+    run_blocks.clear_cache()
+    yield
+    run_blocks.clear_cache()
+
+
+def stacked_call(x, chosen, w, valid):
+    """``grouped_swiglu`` on layer 1 of a two-layer stack whose layer 0
+    holds the negatives (a wrong ``first`` shows), jitted anew."""
+    given = tuple(jnp.stack([-a, a]) for a in w)
+    out, counts = jax.jit(lambda x, chosen, wg, wu, wd, valid:
+                          moe.grouped_swiglu(x, chosen, wg, wu, wd, valid, 1))(
+        x, chosen, *given, valid)
+    return np.asarray(out), np.asarray(counts)
+
+
+def skewed(n=24, k=2, e=8, f=16):
+    """-> (tokens, the experts' (gate, up, down), each token's k experts by
+    a router that leans to the first three)."""
+    x, w_router, bias, w = routed(n=n, e=e, f=f, skew=4.0)
+    chosen = moe.sigmoid_routes(x, w_router, bias, top_k=k, norm_topk=True,
+                                route_scale=1.0)[0]
+    return x, (w[1], w[0], w[2]), chosen
+
+
+#: bm -> (tokens, routes a token, experts) whose layout rule yields it
+BLOCK_SHAPES = {8: (24, 2, 8), 128: (96, 2, 2), 256: (200, 2, 2)}
+
+
+@pytest.mark.parametrize("held", ["some", "none", "unsaid"])
+@pytest.mark.parametrize("bm", sorted(BLOCK_SHAPES))
+def test_the_kernel_runs_the_blocks_the_loop_runs(bm, held, monkeypatch):
+    """The Pallas kernel (interpret mode) in the loop's place, on the same
+    layout, at every height of block the cells' programs lay (8 rows in a
+    decode step, 128-256 in a prefill): a valid token's outputs are its rows
+    through its experts' matrices exactly, as the loop's are; any other
+    token's are zeros (with no valid token the grid has no step); the counts
+    agree field for field."""
+    n, k, e = BLOCK_SHAPES[bm]
+    assert moe._row_block(n * k, e) == bm
+    x, w, chosen = skewed(n, k, e)
+    valid = {"some": (jnp.arange(n) % 3 != 1) & (jnp.arange(n) < n - 4),
+             "none": jnp.zeros(n, bool), "unsaid": None}[held]
+    looped, loop_counts = stacked_call(x, chosen, w, valid)
+    monkeypatch.setattr(moe, "_mosaic_compiles", lambda: True)
+    out, counts = stacked_call(x, chosen, w, valid)
+
+    np.testing.assert_array_equal(counts, loop_counts)
+    np.testing.assert_array_equal(out, looped)
+    asked = np.ones(n, bool) if valid is None else np.asarray(valid)
+    np.testing.assert_array_equal(out[asked],
+                                  route_by_route(x, chosen, w, bm)[asked])
+    assert not out[~asked].any()
+    assert counts[e] == k * asked.sum()
+    assert (counts[e + 1] == 0) == (held == "none")
+
+
+def test_an_expert_s_blocks_in_a_row_go_through_the_kernel(kernel_path):
+    """One expert holding every route: six blocks of 8 rows in a row read
+    the same three matrices (the pipeline fetches them once), and each
+    block's rows are its own."""
+    n, k, e = 24, 2, 8
+    x, w, _ = skewed(n, k, e)
+    chosen = jnp.full((n, k), 3, jnp.int32)
+    out, counts = stacked_call(x, chosen, w, None)
+    np.testing.assert_array_equal(out, route_by_route(x, chosen, w, 8))
+    assert counts[e + 1] == 6 and counts[3] == n * k
+
+
+def test_what_lies_past_the_blocks_that_ran_is_never_read(kernel_path,
+                                                           monkeypatch):
+    """The kernel writes the blocks that hold a row and no other; whatever
+    the buffer holds past them (on the chip: anything) reaches no output:
+    a route nobody asked for reads the fill, not the layout."""
+    n, e = 24, 8
+    x, w, chosen = skewed(n, 2, e)
+    valid = jnp.arange(n) % 3 != 1
+    clean, counts = stacked_call(x, chosen, w, valid)
+    run_blocks, planted = moe._run_blocks, []
+
+    def with_garbage(laid, expert, ran, *weights):
+        out = run_blocks(laid, expert, ran, *weights)
+        past = jnp.arange(out.shape[0]) >= ran
+        planted.append(out.shape[0])
+        return jnp.where(past[:, None, None], jnp.nan, out)
+    monkeypatch.setattr(moe, "_run_blocks", with_garbage)
+    dirty, dirty_counts = stacked_call(x, chosen, w, valid)
+    assert planted and planted[0] > counts[e + 1]   # there are such blocks
+    np.testing.assert_array_equal(dirty, clean)
+    np.testing.assert_array_equal(dirty_counts, counts)
+
+
+@pytest.mark.parametrize("f,budget,tile", [
+    (768, 64 << 20, 768), (512, 64 << 20, 512), (768, 12 << 20, 384),
+    (768, 5 << 20, 128), (200, 1 << 20, 200)],
+    ids=["kanana-whole", "laguna-whole", "halves", "lanes", "no-divisor"])
+def test_the_kernel_tiles_an_expert_s_width_where_its_buffers_ask(
+        f, budget, tile, monkeypatch):
+    """An expert's three matrices twice over fit the kernel's buffers whole
+    at both cells' sizes; a wider expert takes the largest multiple of 128
+    columns that divides ``F`` and fits."""
+    monkeypatch.setattr(moe, "_WEIGHT_BUFFERS", budget)
+    assert moe._width_tile(2048, f, 2) == tile
+
+
+def test_a_tiled_width_sums_to_the_whole_one_s_product(kernel_path,
+                                                       monkeypatch):
+    """Two tiles of ``F``: the down product of the second adds to the
+    first's in the output block (float32; the sum's order is the only
+    difference from the loop's)."""
+    f = 256
+    x, w, chosen = skewed(f=f)
+    whole, counts = stacked_call(x, chosen, w, None)
+    monkeypatch.setattr(moe, "_WEIGHT_BUFFERS", 2 * 3 * 32 * 128 * 4)
+    assert moe._width_tile(32, f, 4) == 128
+    moe._run_blocks.clear_cache()
+    tiled, tiled_counts = stacked_call(x, chosen, w, None)
+    np.testing.assert_array_equal(tiled_counts, counts)
+    np.testing.assert_allclose(tiled, whole, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(tiled, route_by_route(x, chosen, w, 8),
+                               rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("steered", [False, True], ids=["as-it-is", "steered"])
+def test_off_the_chip_the_cached_path_keeps_the_loop(steered, monkeypatch):
+    """Which of the three forms a call takes follows from what it can see:
+    without ``layer`` (the call ``gpt.forward`` trains through) the static
+    loop with a conditional a step, whatever the backend; with ``layer`` the
+    dynamic loop where Mosaic does not compile (here: the CPU suite's pinned
+    serving programs stand as they were) and the kernel where it does, which
+    is ``flash_attention._interpret``'s word, asked through its module as a
+    compile rehearsal steers it."""
+    from mingpt_distributed_tpu.ops import flash_attention
+    x, _, _, w = routed(n=6)
+    stack = tuple(jnp.stack([a, a]) for a in (w[1], w[0], w[2]))
+    chosen = jnp.zeros((6, 2), jnp.int32)
+    if steered:
+        monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+        moe._run_blocks.clear_cache()
+    cached = str(jax.make_jaxpr(lambda *a: moe.grouped_swiglu(*a, None, 1))(
+        x, chosen, *stack))
+    trained = str(jax.make_jaxpr(lambda *a: moe.grouped_swiglu(*a))(
+        x, chosen, w[1], w[0], w[2]))
+    moe._run_blocks.clear_cache()
+    assert ("pallas_call" in cached) == steered
+    assert ("grouped_swiglu" in cached) == steered
+    assert "pallas_call" not in trained and "cond" in trained
+    if not steered:
+        lowered = jax.jit(lambda *a: moe.grouped_swiglu(*a, None, 1)).lower(
+            x, chosen, *stack).as_text(debug_info=True)
+        assert "moe_experts/while" in lowered
+
+
 def test_a_lane_s_output_does_not_depend_on_which_lanes_are_live():
     """The decode step routes the lanes' rows together; a lane alone, or
     beside other tokens, takes the same experts and gets the same output
