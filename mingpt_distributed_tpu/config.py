@@ -353,7 +353,8 @@ class GPTConfig:
     window_n_head: Optional[int] = None
     # The share of a head the rotary embedding turns, from its first
     # dimension on (published ``partial_rotary_factor``); the rest passes
-    # through. ``layer_types`` only.
+    # through. 0: the kind rotates nothing and carries no position (a
+    # published ``rope_layout`` entry of 0). ``layer_types`` only.
     rope_fraction: float = 1.0
     window_rope_theta: Optional[float] = None   # None = rope_theta
     window_rope_fraction: float = 1.0
@@ -373,6 +374,17 @@ class GPTConfig:
     # ``moe_route_scale``, shared experts beside them, nothing dropped.
     # False: "softmax" is the capacity route.
     moe_dropless: bool = False
+    # What a routed expert's gate goes through before it multiplies the up
+    # projection: "silu" (SwiGLU) or "relu" (ReGLU, ``relu(x W_g) * (x
+    # W_u)``). The dropless route only; ``swiglu`` still says that the MLPs
+    # are gated (the leaves ``w_eg``, ``w_e1``, ``w_e2``).
+    expert_act: str = "silu"
+    # The activations the router scores: "mlp" the MLP's normed input (the
+    # post-attention norm's output, which the experts take too), or "attn"
+    # the attention's normed input, so that a token's experts are known
+    # before its attention has run; the experts still take the MLP's input.
+    # The dropless route only.
+    moe_router_input: str = "mlp"
 
     @classmethod
     def make(cls, **kwargs: Any) -> "GPTConfig":
@@ -487,6 +499,23 @@ class GPTConfig:
                 "the dropless route under softmax scores renormalises the "
                 "chosen experts' probabilities (moe_norm_topk): gates left "
                 "as the softmax over all experts gives them are not written")
+        if self.expert_act not in ("silu", "relu") \
+                or self.moe_router_input not in ("mlp", "attn"):
+            raise ConfigError(
+                f"expert_act {self.expert_act!r} is 'silu' or 'relu' and "
+                f"moe_router_input {self.moe_router_input!r} 'mlp' or 'attn'")
+        if (self.expert_act != "silu" or self.moe_router_input != "mlp") \
+                and not self.dropless:
+            raise ConfigError(
+                "expert_act and moe_router_input are the dropless route's "
+                "(moe_scoring='sigmoid', or moe_dropless): the capacity "
+                "route (ops/moe.moe_mlp) gates by SiLU and scores the MLP's "
+                "input, and a dense MLP has no router")
+        if self.expert_act != "silu" and self.n_shared_experts:
+            raise ConfigError(
+                "expert_act='relu' with shared experts is not written: the "
+                "shared experts' one MLP is ops/layers.mlp_swiglu, and no "
+                "published config says a shared expert of ReLU-gated ones")
         if not 0 <= self.n_dense_layers <= self.n_layer:
             raise ConfigError(
                 f"n_dense_layers={self.n_dense_layers} outside "
@@ -623,7 +652,8 @@ class GPTConfig:
         if not (self.rope and self.rmsnorm and self.swiglu):
             raise ConfigError(
                 "a stack of layer_types rotates by kind, RMS-norms and has "
-                "SwiGLU MLPs: it needs rope, rmsnorm and swiglu")
+                "gated MLPs (swiglu; expert_act says what the experts' gate "
+                "goes through): it needs rope, rmsnorm and swiglu")
         wnh = self.window_n_head or self.n_head
         if wnh % self.kv_heads:
             raise ConfigError(
@@ -631,11 +661,20 @@ class GPTConfig:
                 "KV heads")
         for kind in kinds:
             dim = self.rope_spec(kind)[0]
-            if dim < 2 or dim % 2 or dim > self.head_dim:
+            if dim % 2 or not 0 <= dim <= self.head_dim:
                 raise ConfigError(
                     f"a {kind} layer rotates {dim} of a head's "
                     f"{self.head_dim} dimensions: an even number of them, "
-                    "at least 2")
+                    "or none (a fraction of 0: the kind carries no position)")
+        if not any(self.rope_spec(kind)[0] for kind in kinds):
+            raise ConfigError(
+                "a stack of layer_types in which no kind rotates has no "
+                "position anywhere (there is no position table under rope): "
+                "give one kind a rope fraction above 0")
+        if self.rope_yarn is not None and not self.rope_spec(FULL_ATTN)[0]:
+            raise ConfigError(
+                "rope_yarn blends the full layers' frequencies: with "
+                "rope_fraction 0 they rotate nothing")
         if self.rope_yarn is not None and (
                 len(self.rope_yarn) != 5 or self.rope_yarn[0] <= 1.0
                 or min(self.rope_yarn[1:]) <= 0
@@ -880,6 +919,26 @@ class GPTConfig:
                               "partial_rotary_factor":
                                   self.window_rope_fraction},
                 **out}
+
+    @property
+    def rope_layout(self) -> list:
+        """1 where a layer rotates its queries and keys, 0 where it carries
+        no position (published ``rope_layout``): a layer's kind says."""
+        return [int(bool(self.rope and self.rope_spec(k)[0]))
+                for k in self.layer_types or (None,) * self.n_layer]
+
+    @property
+    def window_layout(self) -> list:
+        """1 where a layer attends a sliding window, 0 where it attends
+        every position before it (published ``sliding_window_layout``)."""
+        return [int(self.kind_window(k) is not None)
+                for k in self.layer_types or (None,) * self.n_layer]
+
+    @property
+    def router_softmax(self) -> bool:
+        """Whether the router's scores are a softmax over the experts
+        (published ``moe_primary_router_apply_softmax``)."""
+        return bool(self.n_experts) and self.moe_scoring == "softmax"
 
     @property
     def mixer_names(self) -> Optional[list]:

@@ -52,10 +52,10 @@ from mingpt_distributed_tpu.ops import sparse_attention as sparse_ops
 Cache = Dict[str, jax.Array]
 
 #: the leaf of a serving pool's cache tree in which the cached forward
-#: counts an expert model's routed rows: (expert layers, E + 3) int32, the
+#: counts an expert model's routed rows: (expert layers, E + 4) int32, the
 #: rows each expert computed and then the routes asked for, the blocks the
-#: experts' loop ran and the blocks its layout has
-#: (ops/moe.grouped_swiglu). It rides in the donated tree so that the
+#: experts' loop ran, the blocks its layout has and the experts that held a
+#: row (ops/moe.grouped_swiglu). It rides in the donated tree so that the
 #: programs add to it in place and nothing is fetched in a round.
 MOE_ROWS = "moe_rows"
 
@@ -233,7 +233,7 @@ def init_moe_rows(cfg: GPTConfig) -> Optional[jax.Array]:
     the dropless route does)."""
     if not cfg.dropless:
         return None
-    return jnp.zeros((cfg.n_layer - cfg.n_dense_layers, cfg.n_experts + 3),
+    return jnp.zeros((cfg.n_layer - cfg.n_dense_layers, cfg.n_experts + 4),
                      jnp.int32)
 
 
@@ -387,6 +387,13 @@ def _cached_block(
     reads the ring as it lies (``attn_ops.ring_attend_step``, by ``walk``,
     here the rings': ``ring_walk``) and hands its row back under the
     rings' names for the caller to write at ``position mod ring_rows``.
+    A kind that rotates nothing (``gpt.layer_rope`` None) caches its keys
+    as projected.
+
+    Where the router reads the attention's input (``cfg.moe_router_input``
+    "attn") the layer's route is made right after the first norm
+    (``gpt.early_route``) and handed to the MLP, whose experts take the
+    second norm's output as ever.
     """
     b, t, _ = x.shape
     nh, kv, hd = cfg.kind_heads(kind)
@@ -397,6 +404,7 @@ def _cached_block(
         raise ValueError(f"a position a lane takes one token a lane, not {t}")
 
     h = gpt.sublayer_input(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
+    route = gpt.early_route(h, blk, cfg)
     with jax.named_scope("qkv"):
         rope = gpt.layer_rope(
             cfg, kind, jnp.asarray(offset)[..., None] + jnp.arange(t)) \
@@ -468,7 +476,8 @@ def _cached_block(
 
     h2 = gpt.sublayer_input(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
     m, _, counts = gpt.mlp_branch(h2, blk, cfg, valid=valid,
-                                  layer=expert_layer, lanes_apart=per_lane)
+                                  layer=expert_layer, lanes_apart=per_lane,
+                                  route=route)
     with jax.named_scope("ffn"):
         return x + m, cache, rows, counts
 

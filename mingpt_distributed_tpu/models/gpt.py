@@ -456,18 +456,39 @@ def latent_qkv(h, blk: Params, cfg: GPTConfig, rope):
 EXPERT_LEAVES = ("w_eg", "w_e1", "w_e2")
 
 
+def _route_options(cfg: GPTConfig) -> dict:
+    return dict(top_k=cfg.moe_top_k, norm_topk=cfg.moe_norm_topk,
+                route_scale=cfg.moe_route_scale, scoring=cfg.moe_scoring)
+
+
+def early_route(h, blk: Params, cfg: GPTConfig):
+    """A dropless expert layer's route where the router reads the
+    attention's normed input ``h`` (B, T, D) (``cfg.moe_router_input``
+    "attn"): (chosen, gates) of ``ops/moe.dropless_routes``, made before the
+    attention so that a token's experts are known while it runs, under the
+    scope ``moe_route``; None for any other layer, whose route
+    ``routed_and_shared`` makes from the MLP's input."""
+    if cfg.moe_router_input != "attn" or "w_router" not in blk:
+        return None
+    with jax.named_scope("moe_route"):
+        return moe.dropless_routes(
+            h.reshape(-1, h.shape[-1]), blk["w_router"], blk.get("e_bias"),
+            **_route_options(cfg))
+
+
 def routed_and_shared(h2, blk: Params, cfg: GPTConfig, valid=None,
-                      layer=None):
+                      layer=None, route=None):
     """The MLP of a dropless expert layer: each token's k routed experts
-    (ops/moe.moe_dropless, the choice and the gates ``cfg.moe_scoring``'s)
-    plus the shared expert every token takes. Returns (out, the route's
-    counts). With ``layer``, the EXPERT_LEAVES of ``blk`` are the whole
-    stack's."""
+    (ops/moe.moe_dropless, the choice and the gates ``cfg.moe_scoring``'s,
+    the gate activation ``cfg.expert_act``'s) plus the shared expert every
+    token takes. ``route``: :func:`early_route`'s, where the router read
+    the attention's input; None: made here from ``h2``. Returns (out, the
+    route's counts). With ``layer``, the EXPERT_LEAVES of ``blk`` are the
+    whole stack's."""
     m, counts = moe.moe_dropless(
         h2, blk["w_router"], blk.get("e_bias"), blk["w_eg"], blk["w_e1"],
-        blk["w_e2"], top_k=cfg.moe_top_k, norm_topk=cfg.moe_norm_topk,
-        route_scale=cfg.moe_route_scale, valid=valid, layer=layer,
-        scoring=cfg.moe_scoring)
+        blk["w_e2"], valid=valid, layer=layer, route=route,
+        act=cfg.expert_act, **_route_options(cfg))
     if "w_sg" in blk:
         with jax.named_scope("moe_shared"):
             m = m + L.mlp_swiglu(h2, blk["w_sg"], blk["w_su"], blk["w_sd"])
@@ -485,8 +506,11 @@ def layer_rope(cfg: GPTConfig, kind: Optional[str], positions):
     """The ``(cos, sin)`` tables a layer of ``kind`` rotates by at
     ``positions`` (``cfg.rope_spec``: the dimensions of a head it turns,
     which the tables' width says to ``apply_rope``; theta; YaRN's blend
-    where the kind has one)."""
+    where the kind has one); None for a kind that rotates nothing, whose
+    queries and keys ``attention_parts`` then leaves as projected."""
     dim, theta, yarn = cfg.rope_spec(kind)
+    if not dim:
+        return None
     if yarn is not None:
         return attn_ops.yarn_rope_tables(positions, dim, theta, *yarn)
     return attn_ops.rope_tables(positions, dim, theta)
@@ -566,17 +590,17 @@ def attention_out(att, blk: Params, cfg: GPTConfig,
 @jax.named_scope("ffn")
 def mlp_branch(h2, blk: Params, cfg: GPTConfig, *, valid=None, layer=None,
                lanes_apart: bool = False, tp_axis: Optional[str] = None,
-               ep_axis: Optional[str] = None):
+               ep_axis: Optional[str] = None, route=None):
     """A layer's MLP over (B, T, D) normed activations, whichever it has,
     and its post-norm: (the branch, the capacity route's load-balancing
     term (zero for any other), a dropless route's counts of the ``valid``
-    tokens' rows (``routed_and_shared``, which ``layer`` is for; None for
-    any other)). ``lanes_apart``: the rows are other users' requests, so
-    the capacity route, where a token's room depends on who else is routed,
-    takes each alone. ``tp_axis``, ``ep_axis``: ``_block``'s manual forms."""
+    tokens' rows (``routed_and_shared``, which ``layer`` and ``route`` are
+    for; None for any other)). ``lanes_apart``: the rows are other users'
+    requests, so the capacity route, where a token's room depends on who
+    else is routed, takes each alone. ``tp_axis``, ``ep_axis``: ``_block``'s manual forms."""
     aux, counts = jnp.zeros((), jnp.float32), None
     if "w_router" in blk and cfg.dropless:
-        m, counts = routed_and_shared(h2, blk, cfg, valid, layer)
+        m, counts = routed_and_shared(h2, blk, cfg, valid, layer, route)
     elif "w_router" in blk:
         def experts(tokens):
             return moe.moe_mlp(
@@ -757,6 +781,7 @@ def _block(
 
     with jax.named_scope("attn"):
         h = sublayer_input(x, blk["ln1_scale"], blk.get("ln1_bias"), cfg)
+        route = early_route(h, blk, cfg)
         if cfg.kv_lora_rank:
             q, k, v = latent_qkv(h, blk, cfg, rope)
         else:
@@ -781,7 +806,8 @@ def _block(
 
     with jax.named_scope("mlp"):
         h2 = sublayer_input(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg)
-        m, aux, _ = mlp_branch(h2, blk, cfg, tp_axis=tp_axis, ep_axis=ep_axis)
+        m, aux, _ = mlp_branch(h2, blk, cfg, tp_axis=tp_axis, ep_axis=ep_axis,
+                               route=route)
         return x + L.dropout(m, cfg.resid_pdrop, k_resid2, deterministic), aux
 
 

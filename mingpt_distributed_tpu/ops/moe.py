@@ -10,7 +10,11 @@
   route computed whatever the load, a shared expert added by the caller.
   See the function. Under ``GPTConfig.moe_dropless`` a "softmax" model takes
   it too (Laguna-XS.2: ``softmax_routes``, the k largest logits, their
-  softmax probabilities renormalised and scaled as gates).
+  softmax probabilities renormalised and scaled as gates). The route
+  (``dropless_routes``) and the experts (``grouped_swiglu``) are two calls,
+  so a router may read other activations than the experts take
+  (``GPTConfig.moe_router_input``), and the experts' gate goes through
+  SiLU or ReLU (``GPTConfig.expert_act``).
 
 The capacity route:
 
@@ -48,8 +52,9 @@ nothing drops (factor >= E/k guarantees it). Training is unaffected.
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -275,18 +280,27 @@ def _width_tile(d: int, f: int, itemsize: int) -> int:
                default=f)
 
 
-def _swiglu_block_kernel(expert_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref):
+#: what an expert's gate goes through (``GPTConfig.expert_act``), and the
+#: name its Pallas kernel is compiled under: a profile's reader finds the
+#: kernel by it
+GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+KERNEL_NAMES = {"silu": "grouped_swiglu", "relu": "grouped_reglu"}
+
+
+def _glu_block_kernel(expert_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, *,
+                      act: str):
     """Grid step (i, j): block i of the layout through columns j of its
     expert's matrices, ``block()`` of :func:`grouped_swiglu` operand for
-    operand. The down product of a later tile of ``F`` adds to the output
-    block, which stays where it is while i does."""
+    operand, the gate through ``GATE_ACTS[act]``. The down product of a
+    later tile of ``F`` adds to the output block, which stays where it is
+    while i does."""
     del expert_ref      # the weights' index maps read it
     rows = x_ref[0]
     gate = jnp.dot(rows, wg_ref[0].astype(rows.dtype),
                    preferred_element_type=jnp.float32)
     up = jnp.dot(rows, wu_ref[0].astype(rows.dtype),
                  preferred_element_type=jnp.float32)
-    inner = (jax.nn.silu(gate) * up).astype(rows.dtype)
+    inner = (GATE_ACTS[act](gate) * up).astype(rows.dtype)
     part = jnp.dot(inner, wd_ref[0].astype(rows.dtype),
                    preferred_element_type=jnp.float32)
 
@@ -305,15 +319,16 @@ def _swiglu_block_kernel(expert_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref):
 # not the kernel's: XLA names instructions after it, and the benchmark's
 # reader finds the kernel by name. What it traced holds what ``_interpret()``
 # said then; a test that steers it clears the cache on both sides.
-@jax.jit
-def _run_blocks(laid, expert, ran, w_gate, w_up, w_down):
+@functools.partial(jax.jit, static_argnames="act")
+def _run_blocks(laid, expert, ran, w_gate, w_up, w_down, act="silu"):
     """The first ``ran`` blocks of ``laid`` (n_blocks, bm, D), block i
     through expert ``expert[i]`` of the stacked (L*E, D, F) / (L*E, F, D)
-    leaves -> (n_blocks, bm, D) float32. The leaves stay in HBM whole; the
-    grid's first bound is ``ran`` itself, and the pipeline fetches block
-    i + 1's matrices (where its expert is another) while block i is in the
-    MXU. The blocks past ``ran`` are not written: they hold whatever the
-    buffer held."""
+    leaves -> (n_blocks, bm, D) float32, under the kernel name of ``act``
+    (KERNEL_NAMES: one body, compiled a gate activation). The leaves stay
+    in HBM whole; the grid's first bound is ``ran`` itself, and the pipeline
+    fetches block i + 1's matrices (where its expert is another) while
+    block i is in the MXU. The blocks past ``ran`` are not written: they
+    hold whatever the buffer held."""
     n_blocks, bm, d = laid.shape
     f = w_gate.shape[-1]
     tf = _width_tile(d, f, w_gate.dtype.itemsize)
@@ -321,7 +336,7 @@ def _run_blocks(laid, expert, ran, w_gate, w_up, w_down):
             + 2 * bm * d * (laid.dtype.itemsize + 4)     # a block in and out
             + 4 * bm * (3 * tf + d) * 4 + (4 << 20))     # the products
     return pl.pallas_call(
-        _swiglu_block_kernel,
+        functools.partial(_glu_block_kernel, act=act),
         out_shape=jax.ShapeDtypeStruct((n_blocks, bm, d), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -338,14 +353,17 @@ def _run_blocks(laid, expert, ran, w_gate, w_up, w_down):
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem),
         interpret=flash_attention._interpret(),
-        name="grouped_swiglu",
+        name=KERNEL_NAMES[act],
     )(expert, laid, w_gate, w_up, w_down)
 
 
 @jax.named_scope("moe_experts")
-def grouped_swiglu(x, chosen, w_gate, w_up, w_down, valid=None, layer=None):
+def grouped_swiglu(x, chosen, w_gate, w_up, w_down, valid=None, layer=None,
+                   act: str = "silu"):
     """The chosen experts on every ``valid`` token: (N, D) tokens and (N, k)
-    experts -> ((N, k, D) float32 expert outputs, (E + 3,) int32 counts).
+    experts -> ((N, k, D) float32 expert outputs, (E + 4,) int32 counts).
+    An expert is ``(act(x W_g) * (x W_u)) W_d``, ``act`` one of GATE_ACTS:
+    SiLU (SwiGLU, the name this function has kept) or ReLU (ReGLU).
     The weights are one layer's (E, D, F) / (E, F, D) or, with ``layer``,
     the whole stack's (L, E, ...) of which that layer is taken: the loop
     or the kernel below then reads its one expert a step straight out of
@@ -370,7 +388,7 @@ def grouped_swiglu(x, chosen, w_gate, w_up, w_down, valid=None, layer=None):
       where a block's read is 16: PERF.md, PR 35);
     * with ``layer`` (the cached forward: inference only), where Mosaic
       compiles (:func:`_mosaic_compiles`: the chip), one Pallas kernel
-      (:func:`_run_blocks`, ``grouped_swiglu``) whose grid is the blocks
+      (:func:`_run_blocks`, KERNEL_NAMES of ``act``) whose grid is the blocks
       that hold a row: the next block's expert is fetched while this one is
       in the MXU, which nothing in a ``while`` body can be (PERF.md, PR 60);
     * with ``layer`` elsewhere, the XLA loop with that number as its trip
@@ -386,8 +404,10 @@ def grouped_swiglu(x, chosen, w_gate, w_up, w_down, valid=None, layer=None):
     ``counts[:E]`` are the rows each expert's blocks computed, counted from
     the layout the blocks that ran read; ``counts[E]`` is the routes the
     ``valid`` tokens asked for: the two agree exactly when nothing was
-    dropped. ``counts[E + 1]`` is the blocks taken through an expert and
-    ``counts[E + 2]`` the blocks the layout has."""
+    dropped. ``counts[E + 1]`` is the blocks taken through an expert,
+    ``counts[E + 2]`` the blocks the layout has and ``counts[E + 3]`` the
+    experts that hold at least one row: the fewest expert weights this
+    call's blocks can read, each expert's three matrices once."""
     n, d = x.shape
     k = chosen.shape[1]
     e, first = w_gate.shape[-3], 0
@@ -428,7 +448,7 @@ def grouped_swiglu(x, chosen, w_gate, w_up, w_down, valid=None, layer=None):
                        preferred_element_type=jnp.float32)
         up = jnp.dot(rows_in, w_up[ex].astype(x.dtype),
                      preferred_element_type=jnp.float32)
-        inner = (jax.nn.silu(gate) * up).astype(x.dtype)
+        inner = (GATE_ACTS[act](gate) * up).astype(x.dtype)
         return jax.lax.dynamic_update_index_in_dim(out, jnp.dot(
             inner, w_down[ex].astype(x.dtype),
             preferred_element_type=jnp.float32), i, 0)
@@ -441,7 +461,7 @@ def grouped_swiglu(x, chosen, w_gate, w_up, w_down, valid=None, layer=None):
             i < ran, block, lambda i, out: out, i, out), zeros)
     elif _mosaic_compiles():
         out = _run_blocks(laid, (first + block_expert).astype(jnp.int32),
-                          ran, w_gate, w_up, w_down)
+                          ran, w_gate, w_up, w_down, act)
     else:
         out = jax.lax.fori_loop(0, ran, block, zeros)
     out = out.reshape(n_blocks * bm, d).at[dest].get(
@@ -451,7 +471,24 @@ def grouped_swiglu(x, chosen, w_gate, w_up, w_down, valid=None, layer=None):
     computed = (jax.nn.one_hot(block_expert, e, dtype=jnp.int32)
                 * in_run[:, None]).sum(0)
     return out, jnp.concatenate([computed, jnp.stack([
-        asked.sum().astype(jnp.int32), ran, jnp.int32(n_blocks)])])
+        asked.sum().astype(jnp.int32), ran, jnp.int32(n_blocks),
+        (computed > 0).sum().astype(jnp.int32)])])
+
+
+def dropless_routes(tokens, w_router, bias, *, top_k: int,
+                    norm_topk: bool = True, route_scale: float = 1.0,
+                    scoring: str = "sigmoid"):
+    """The route of a dropless expert layer by itself: (N, D) normed
+    activations (whichever the router reads: ``GPTConfig.moe_router_input``)
+    -> (chosen experts (N, k) int32, gates (N, k) float32): DeepSeek-V3's
+    (:func:`sigmoid_routes`) or, under ``scoring`` "softmax",
+    :func:`softmax_routes`' (no ``bias``, gates always renormalised)."""
+    if scoring == "softmax":
+        return softmax_routes(
+            tokens, w_router, top_k=top_k, route_scale=route_scale)[:2]
+    return sigmoid_routes(
+        tokens, w_router, bias, top_k=top_k, norm_topk=norm_topk,
+        route_scale=route_scale)[:2]
 
 
 def moe_dropless(
@@ -468,24 +505,24 @@ def moe_dropless(
     valid: jax.Array = None,   # (B, T) bool: the tokens that are routed
     layer: int = None,         # the expert weights are the stack's (L, E, ..)
     scoring: str = "sigmoid",
+    route: Optional[Tuple[jax.Array, jax.Array]] = None,
+    act: str = "silu",
 ) -> Tuple[jax.Array, jax.Array]:
     """The routed part of a dropless expert layer: ``sum_i g_i
     expert_i(x)`` over each ``valid`` token's k experts (zeros for any
-    other), the choice and the gates DeepSeek-V3's (:func:`sigmoid_routes`)
-    or, under ``scoring`` "softmax", :func:`softmax_routes`' (no ``bias``,
-    gates always renormalised). Returns (out (B, T, D), counts (E + 3,)
-    int32: ``grouped_swiglu``, which also says what ``layer`` is for)."""
+    other). The route and the experts are two calls: ``route`` is
+    :func:`dropless_routes`' (chosen, gates) where the caller made it from
+    other activations than the experts take (a router that reads the
+    attention's input), and made here from ``x`` where it is None. Returns
+    (out (B, T, D), counts (E + 4,) int32: ``grouped_swiglu``, which also
+    says what ``layer`` and ``act`` are for)."""
     b, t, d = x.shape
     tokens = x.reshape(b * t, d)
-    if scoring == "softmax":
-        chosen, gates, _ = softmax_routes(
-            tokens, w_router, top_k=top_k, route_scale=route_scale)
-    else:
-        chosen, gates, _ = sigmoid_routes(
-            tokens, w_router, bias, top_k=top_k, norm_topk=norm_topk,
-            route_scale=route_scale)
+    chosen, gates = route if route is not None else dropless_routes(
+        tokens, w_router, bias, top_k=top_k, norm_topk=norm_topk,
+        route_scale=route_scale, scoring=scoring)
     out, counts = grouped_swiglu(
         tokens, chosen, w_gate, w_up, w_down,
-        None if valid is None else valid.reshape(b * t), layer)
+        None if valid is None else valid.reshape(b * t), layer, act)
     out = jnp.einsum("nkd,nk->nd", out, gates)
     return out.astype(x.dtype).reshape(b, t, d), counts

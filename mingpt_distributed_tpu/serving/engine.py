@@ -803,7 +803,7 @@ class DecodeEngine:
 
     def moe_rows(self) -> Optional[np.ndarray]:
         """The routed-rows counter as it stands, fetched from the device:
-        (expert layers, E + 3), or None where the model counts none. The
+        (expert layers, E + 4), or None where the model counts none. The
         one transfer the counter ever costs; no round makes it."""
         return self._counter(gen.MOE_ROWS)
 

@@ -581,7 +581,7 @@ class ServingMetrics:
         slot's state cost, known once it is built; ``kv_row_width`` and
         ``kv_row_tiles`` say which way the pool keeps a row
         (``SlotKVPool.row_width``, ``row_tiles``). ``moe_rows_source``
-        fetches a routed model's (expert layers, E + 3) counter of routed
+        fetches a routed model's (expert layers, E + 4) counter of routed
         rows from the device (``DecodeEngine.moe_rows``) and
         ``sparse_rows_source`` a hybrid stack's (2,) counter of the rows its
         sparse layers' decode steps attended (``DecodeEngine.sparse_rows``),
@@ -653,15 +653,17 @@ class ServingMetrics:
         nothing, and this is where it would show), the busiest expert's
         rows over the mean, the worst layer's, and the blocks the experts'
         loop took through an expert beside the blocks its layouts had (only
-        the blocks that hold a request's route are run). None where the
+        the blocks that hold a request's route are run), and the experts
+        that held at least one row, a call and layer, summed: the fewest
+        reads of an expert's weights those blocks can cost. None where the
         model routes nothing this way."""
         rows = self._moe_rows_source() if self._moe_rows_source else None
         if rows is None:
             return dict.fromkeys((
                 "moe_routed_rows", "moe_dropped_rows",
                 "moe_load_max_over_mean", "moe_blocks_run",
-                "moe_blocks_laid"))
-        computed, (asked, ran, laid) = rows[:, :-3], rows[:, -3:].sum(0)
+                "moe_blocks_laid", "moe_expert_runs"))
+        computed, (asked, ran, laid, held) = rows[:, :-4], rows[:, -4:].sum(0)
         mean = computed.mean(axis=1)
         return {
             "moe_routed_rows": int(computed.sum()),
@@ -671,6 +673,7 @@ class ServingMetrics:
             if (mean > 0).any() else None,
             "moe_blocks_run": int(ran),
             "moe_blocks_laid": int(laid),
+            "moe_expert_runs": int(held),
         }
 
     def summary(self) -> Dict[str, Any]:

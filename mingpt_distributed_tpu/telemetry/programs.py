@@ -53,8 +53,9 @@ __all__ = ["SCOPES", "Filing", "abstract", "compile_programs", "filed_records",
 #: (``tests/test_program_scopes.py`` holds the two lists equal)
 SCOPES = (
     "attn", "mlp", "ce", "optimizer", "sample", "kv_layout", "cached_attn",
-    "ring_attn", "latent_attn", "moe_experts", "moe_shared", "lightning_scan",
-    "lightning_step", "sparse_select", "sparse_attend", "exit_gate",
+    "ring_attn", "latent_attn", "moe_experts", "moe_shared", "moe_route",
+    "lightning_scan", "lightning_step", "sparse_select", "sparse_attend",
+    "exit_gate",
     # the parts of a layer and the stack's two ends, in every program that
     # runs them (``models/gpt.py``, ``models/generate.py``): with them
     # ``attn`` and ``mlp`` are what is left of a training sublayer (the

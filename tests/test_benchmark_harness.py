@@ -67,7 +67,8 @@ def test_the_manifest_s_new_entries_have_readers_and_cells_that_report():
         assert len(m["workloads"]) >= (2 if want == "train" else 5)
 
 
-def _experts_evidence(calls):
+def _experts_evidence(calls, cell="kanana-2-30b-a3b.serve-long-decode",
+                      kernel="grouped_swiglu"):
     """A serving cell's evidence as ``test_readers_absent`` records it, its
     trace holding ``calls`` Mosaic calls named after the experts' kernel
     (30 and 50 us the first two, inside the 1 s window) and a third the
@@ -76,18 +77,18 @@ def _experts_evidence(calls):
     from benchmarks.tests import test_readers_absent as absent
     from benchmarks.tests.test_spans import EPOCH_NS, MOSAIC
 
-    whole, filed = absent.recorded("kanana-2-30b-a3b.serve-long-decode")
+    whole, filed = absent.recorded(cell)
     us = 1e3
     ops = [ev("%fusion.11 = f32[8]{0} fusion()", 10 * us, 40 * us)]
     for n, (lo, hi) in enumerate([(100, 130), (200, 250)][:calls]):
-        ops.append(ev(f"%grouped_swiglu.{n + 2} = f32[175,8,2048]{{2,1,0}} "
+        ops.append(ev(f"%{kernel}.{n + 2} = f32[175,8,2048]{{2,1,0}} "
                       f"custom-call(s32[] %ran){MOSAIC}", lo * us, hi * us))
     if calls:
         # another kernel's call that names this one's output, and this
         # kernel's call outside the window: neither counts
         ops.append(ev("%flash_fwd.9 = bf16[8]{0} custom-call(f32[8]{0} "
-                      f"%grouped_swiglu.2){MOSAIC}", 300 * us, 320 * us))
-        ops.append(ev(f"%grouped_swiglu.4 = f32[8]{{0}} custom-call(){MOSAIC}",
+                      f"%{kernel}.2){MOSAIC}", 300 * us, 320 * us))
+        ops.append(ev(f"%{kernel}.4 = f32[8]{{0}} custom-call(){MOSAIC}",
                       2e6 * us, 2e6 * us + 70 * us))
     whole["trace"] = trace.from_planes([
         Plane("/device:TPU:0", [
@@ -143,3 +144,66 @@ def test_the_experts_kernel_s_reader_reads_its_calls_over_the_blocks_run(
             ] == [("kernel", "device_trace", "lower", "itl_p50_ms")]
     assert entry[0]["workloads"] == [
         "kanana-2-30b-a3b.serve-long-decode", "laguna-xs.2.serve-long-decode"]
+
+
+@pytest.mark.parametrize("kernel", ["grouped_reglu", "grouped_swiglu"])
+def test_the_grouped_kernel_s_roofline_reads_its_calls_under_either_name(
+        monkeypatch, kernel):
+    """PR 61's Mosaic reader, held here for PR 60's reason: two calls of the
+    experts' kernel of 30 and 50 us in the window, under the name of either
+    gate activation, over counters that moved by 24 routed rows through 4
+    experts. The bound is the bytes: four experts of 3 x 2560 x 768 in
+    bfloat16 at the table's HBM peak, 57.6 us of the 80. With anything it
+    reads taken away it reads None or what it read and never raises; without
+    a call, without the counter (the parent's program) and under a
+    configuration that publishes other keys for its widths it reads None."""
+    import copy
+
+    from benchmarks.harness import device, spec
+    from benchmarks.tests import test_readers_absent as absent
+
+    cell = "smallthinker-21b-a3b.serve-past-window"
+    reader = spec.load_reader("kernel.grouped_glu_roofline")
+
+    def evidence(calls=2, rows=24, runs=4, cell=cell):
+        whole, filed = _experts_evidence(calls, cell, kernel)
+        for counters, level in ((whole["play"].trace_open, 0),
+                                (whole["play"].trace_close, 1)):
+            counters["moe_routed_rows"] = 1000 + level * rows
+            counters["moe_expert_runs"] = 500 + level * runs
+        return whole, filed
+
+    whole, filed = evidence()
+    value = reader.read(copy.copy(whole))
+    peaks = device.peaks_for("TPU v5 lite")
+    weights = 4 * 3 * 2560 * 768 * 2
+    assert weights / peaks["hbm_bytes_s"] > 24 * 6 * 2560 * 768 / peaks["flops"]
+    assert value == pytest.approx(100 * weights / peaks["hbm_bytes_s"] / 80e-6)
+    assert 70 < value < 75
+    # many rows through the same four experts: bound by the operations
+    busy = reader.read(evidence(rows=40_000)[0])
+    assert busy == pytest.approx(
+        100 * 40_000 * 6 * 2560 * 768 / peaks["flops"] / 80e-6)
+    for lack, take in absent.LACKS.items():
+        got_evidence, files = take(copy.copy(whole), filed)
+        absent._filing(monkeypatch, files)
+        got = reader.read(got_evidence)
+        assert got is None or got == value, lack
+        if lack in ("no-trace", "no-counter-field", "no-traced-counters",
+                    "nothing"):
+            assert got is None, lack
+    assert reader.read(evidence(calls=0)[0]) is None    # an XLA loop ran them
+    assert reader.read(evidence(runs=0)[0]) is None     # no block ran
+    parent = evidence()[0]["play"]      # its summary has no such field
+    parent.trace_open, parent.trace_close = (
+        {k: v for k, v in c.items() if k != "moe_expert_runs"}
+        for c in (parent.trace_open, parent.trace_close))
+    assert reader.read(dict(whole, play=parent)) is None
+    # kanana's configuration publishes ``moe_intermediate_size``
+    assert reader.read(evidence(
+        cell="kanana-2-30b-a3b.serve-long-decode")[0]) is None
+    entry = [m for m in spec.load_manifest()["per_layer"]
+             if m["name"] == "kernel.grouped_glu_roofline"]
+    assert [(m["unit"], m["layer"], m["source"], m["better"], m["moves"],
+             m["workloads"]) for m in entry] == [
+        ("%", "kernel", "device_trace", "higher", "itl_p50_ms", [cell])]

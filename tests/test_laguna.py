@@ -675,8 +675,10 @@ def test_the_manifest_lists_the_cell_where_it_reports():
     play.trace_close = {"moe_routed_rows": 4096, "steps": 8}
     assert spec.load_reader("moe.rows_per_expert_round").read(
         {"play": play, "cell": cell}) is None
-    # the new readers are listed for this cell and no other
+    # the new readers were listed for this cell and no other; PR 61 appended
+    # the next stack of rings to them
     manifest = spec.load_manifest()
     for metric in manifest["per_layer"]:
         if metric["name"] in NEW_READERS:
-            assert metric["workloads"] == [CELL]
+            assert metric["workloads"] == [
+                CELL, "smallthinker-21b-a3b.serve-past-window"]

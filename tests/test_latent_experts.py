@@ -374,11 +374,16 @@ def test_the_engine_s_programs_agree_with_the_reference(reference):
     # 26 real tokens took 3 routes in each of 2 expert layers; the lanes
     # without a request and the prefill's padding were neither routed nor
     # counted, and the loops ran fewer blocks than their layouts have
+    # (PR 61: a twelfth entry, the experts that held a row, a call, summed)
     counter = eng.moe_rows()
-    assert counter.shape == (2, 11)
+    assert counter.shape == (2, 12)
     assert counter[:, 8].tolist() == [78, 78]
     assert counter[:, :8].sum(1).tolist() == [78, 78]
     assert (counter[:, 9] < counter[:, 10]).all() and (counter[:, 9] > 0).all()
+    # six calls (a prefill and five steps): an expert or more each, at most
+    # eight, and never more than the blocks that ran
+    assert (counter[:, 11] >= 6).all() and (counter[:, 11] <= 48).all()
+    assert (counter[:, 11] <= counter[:, 9]).all()
 
 
 @pytest.mark.parametrize("what,ok", [("as-served", True),
@@ -543,7 +548,7 @@ def test_only_the_blocks_that_hold_a_valid_token_s_route_are_run(
             x, chosen, wg, wu, wd, valid, layer), static_argnums=6)(
         x, chosen, *given[:3], valid, *given[3:])
     out, counts = np.asarray(out), np.asarray(counts)
-    assert out.shape == (n, k, 32) and counts.shape == (e + 3,)
+    assert out.shape == (n, k, 32) and counts.shape == (e + 4,)
     assert np.isfinite(out).all()
 
     asked = np.ones(n, bool) if valid is None else np.asarray(valid)
@@ -556,6 +561,7 @@ def test_only_the_blocks_that_hold_a_valid_token_s_route_are_run(
     assert counts[e] == k * asked.sum() == sizes.sum()
     assert counts[e + 1] == (-(-sizes // bm)).sum()
     assert counts[e + 2] == -(-n * k // bm) + e - 1
+    assert counts[e + 3] == (sizes > 0).sum()       # PR 61: the experts held
     if held == "none":
         assert counts[e + 1] == 0
     elif load == "one-expert":
