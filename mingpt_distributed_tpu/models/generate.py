@@ -185,25 +185,30 @@ def row_tiles(cfg: GPTConfig) -> int:
     return planes * heads * width // 16
 
 
-def cache_walk(cfg: GPTConfig, cache) -> attn_ops.StepWalk:
+def cache_walk(cfg: GPTConfig, cache,
+               whole: bool = True) -> attn_ops.StepWalk:
     """How a decode step walks ``cache``'s slices, from the ``"k"``, ``"v"``
     leaves as the step is handed them (arrays or their
     ``ShapeDtypeStruct``: shape and dtype are all it reads). The leaf whose
     row says how the slices lie goes first: a latent cache's ``"v"`` is the
     latent, ``"k"`` the rope key beside it. The one place that knows which
-    leaves those are, for a bare step and for the serving engine alike."""
+    leaves those are, for a bare step and for the serving engine alike.
+    ``whole``: the leaves are buffers whole on one device as the step gets
+    them (a serving pool that is quantized or sharded over a mesh says no,
+    and keeps the XLA walk: ``attn_ops.step_walk``)."""
     return attn_ops.step_walk(
         [cache[n].shape for n in ("v", "k")], cache["v"].dtype.itemsize,
-        latent=bool(cfg.kv_lora_rank))
+        latent=bool(cfg.kv_lora_rank), whole=whole)
 
 
-def ring_walk(cfg: GPTConfig, cache) -> Optional[attn_ops.StepWalk]:
+def ring_walk(cfg: GPTConfig, cache,
+              whole: bool = True) -> Optional[attn_ops.StepWalk]:
     """:func:`cache_walk` for the window layers' rings of ``cache``; None
     where it holds none."""
     if RING_K not in cache:
         return None
     return attn_ops.step_walk([cache[n].shape for n in RINGS],
-                              cache[RING_K].dtype.itemsize)
+                              cache[RING_K].dtype.itemsize, whole=whole)
 
 
 def init_cache(cfg: GPTConfig, batch: int, dtype=None) -> Cache:
@@ -624,6 +629,7 @@ def _forward_cached_hidden(
     params: gpt.Params, tokens: jax.Array, cache: Cache, offset, cfg: GPTConfig,
     valid: Optional[jax.Array] = None, frontier: Optional[jax.Array] = None,
     walk: Optional[attn_ops.StepWalk] = None,
+    rings: Optional[attn_ops.StepWalk] = None,
 ) -> Tuple[jax.Array, Cache]:
     """Forward (B, T) tokens at absolute position ``offset`` (a scalar, or
     a ``(B,)`` vector of one position a row: see ``_cached_block``) through
@@ -639,8 +645,10 @@ def _forward_cached_hidden(
     decode lane without a request take no routed expert, and what they
     leave in the cache is nobody's to read). ``frontier`` (B,) bounds what
     a step under a position a lane reads of each lane's slice, walked as
-    ``walk`` says (``_cached_block``; a hybrid stack's sparse layers read
-    every row and take no notice).
+    ``walk`` says, a window layer's ring as ``rings`` does
+    (``_cached_block``; None: this cache's own, :func:`cache_walk`,
+    :func:`ring_walk`; a hybrid stack's sparse layers read every row and
+    take no notice).
 
     The layers are a static python loop: every layer's body is in the
     program, with its weights sliced out of their stack at a static index
@@ -682,7 +690,8 @@ def _forward_cached_hidden(
     whole = gpt.EXPERT_LEAVES if cfg.dropless else ()
     if cfg.layer_types is not None:
         x, cache = _forward_cached_kinds(
-            params, x, cache, offset, cfg, valid, frontier, walk, whole)
+            params, x, cache, offset, cfg, valid, frontier, walk, rings,
+            whole)
         return gpt._norm(x, params["lnf_scale"], None, cfg), cache
     # the tokens a pass counts (LOOP_PASSES), where the cache counts any
     counting = LOOP_PASSES in cache
@@ -726,17 +735,17 @@ def _forward_cached_hidden(
 
 
 def _forward_cached_kinds(params, x, cache: Cache, offset, cfg: GPTConfig,
-                          valid, frontier, walk, whole):
+                          valid, frontier, walk, rings, whole):
     """The layers of a stack of ``cfg.layer_types`` over embedded ``x``,
     reading and writing the cache: ``_forward_cached_hidden``'s loop for a
     stack whose attention layers differ in kind. A layer's attention comes
     out of its kind's stack and its MLP out of the dense or the expert
     stack (``gpt.kind_layer_params``); a full layer reads and writes its
     plane of ``"k"``, ``"v"`` by ``walk``, a window layer its plane of the
-    rings by the rings' own walk, and under a position a lane the new rows
-    of each are written together after the last layer, the rings' at
-    ``position mod ring_rows``."""
-    rings = ring_walk(cfg, cache)
+    rings by ``rings`` (None: :func:`ring_walk` of this cache, as None for
+    ``walk`` is its :func:`cache_walk`), and under a position a lane the
+    new rows of each are written together after the last layer, the rings'
+    at ``position mod ring_rows``."""
     rows = {FULL_ATTN: [], WINDOW_ATTN: []}
     counts = []
     for layer in range(cfg.n_layer):
@@ -835,13 +844,14 @@ def _forward_cached(
     params: gpt.Params, tokens: jax.Array, cache: Cache, offset, cfg: GPTConfig,
     valid: Optional[jax.Array] = None, frontier: Optional[jax.Array] = None,
     walk: Optional[attn_ops.StepWalk] = None,
+    rings: Optional[attn_ops.StepWalk] = None,
 ) -> Tuple[jax.Array, Cache]:
     """Forward (B, T) tokens at position ``offset`` through all layers.
     Returns (last-position logits (B, V), cache). Thin composition of
     ``_forward_cached_hidden`` + ``_head_logits`` — the serving engine
     (serving/engine.py) shares the same two pieces."""
     x, cache = _forward_cached_hidden(
-        params, tokens, cache, offset, cfg, valid, frontier, walk)
+        params, tokens, cache, offset, cfg, valid, frontier, walk, rings)
     logits = _head_logits(params, x[:, -1:], cfg)[:, 0]
     return logits, cache
 

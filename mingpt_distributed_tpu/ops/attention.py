@@ -19,12 +19,15 @@ are tested for parity against it.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # large-finite instead of -inf: keeps softmax NaN-free in bf16
 
@@ -317,10 +320,14 @@ LATENT_KV_BLOCK = 1024
 #: tile of its own
 LANE_TILE = 128
 
-#: what a step of the decode step's walk costs whatever it reads, in the
-#: bytes the chip reads meanwhile (6 us and more at 726 GB/s: PERF.md, PR
-#: 45): a lane's block is no larger, and lanes are read together where that
-#: saves more steps than it adds bytes (``step_block``, ``step_plan``)
+#: what a step of the XLA walk costs whatever it reads (:func:`_attend_step`:
+#: six to ten device operations a ``fori_loop`` step), in the bytes the chip
+#: reads meanwhile (6 us and more at 726 GB/s: PERF.md, PR 45): a lane's
+#: block is no larger there, and lanes are read together where that saves
+#: more steps than it adds bytes (``step_block``, ``step_plan``). It governs
+#: the XLA walk alone (a latent pool's, a per-head leaf's, every CPU run's)
+#: and whether slices are walked at all (ONE_PASS_STEPS); in the kernel's
+#: walk a step costs its bytes (KERNEL_STEP_BYTES)
 STEP_COST_BYTES = 4 << 20
 
 #: slices that all lanes' together read in the time of this many steps are
@@ -330,6 +337,14 @@ STEP_COST_BYTES = 4 << 20
 #: bodies, ``setup_s`` +20%). No crossover of the attention's own time: the
 #: chip reads a walked house of 16-32 MiB -60% to +22% of one pass
 ONE_PASS_STEPS = 8
+
+#: keys and values a grid step of the kernel's walk takes of one lane
+#: (:func:`_rows_attend_pairs`): the pipeline fetches the next pair's while
+#: this one's are scored, so a pair costs its bytes and some 0.4 us; larger
+#: blocks over-read more of a lane's last block than the fewer pairs save
+#: (PERF.md, PR 62: the chip favoured 256 rows of 3 KB, 512 of 2 KB, 128-256
+#: of 4 KB)
+KERNEL_STEP_BYTES = 1 << 20
 
 
 def _walked(s: int) -> bool:
@@ -341,29 +356,35 @@ def _walked(s: int) -> bool:
 
 class StepWalk(NamedTuple):
     """What a decode step's walk knows before it sees a position: a slice's
-    rows, the bytes of a cached row (keys and values, a plane) and the rows
+    rows, the bytes of a cached row (keys and values, a plane), the rows
     a step takes of one lane (0: the slices are not walked, every lane's is
-    read whole in one pass). Worked out once from the leaves the step is
-    handed (:func:`step_walk`) and given as it is to the program and to
-    whoever counts what it read."""
+    read whole in one pass) and whether the steps are the kernel's
+    (:func:`_rows_attend_pairs`: every lane read alone, no block for all
+    lanes) or the XLA walk's (:func:`_attend_step`, :func:`step_plan`'s
+    sharing). Worked out once from the leaves the step is handed
+    (:func:`step_walk`) and given as it is to the program and to whoever
+    counts what it read."""
     s: int
     row_bytes: int
     block: int
+    kernel: bool = False
 
 
 def step_block(s: int, row_bytes: int, lanes: int,
                latent: bool = False) -> int:
-    """Rows of one lane's ``s``-row slice a step of the decode step's walk
-    takes (:func:`_attend_step`), from what the code can see; 0: the
-    ``lanes`` slices are not walked. The largest power of two of rows whose
-    bytes do not pass STEP_COST_BYTES, and at most half the slice: a block
-    that is the whole slice is no function of a loop's index, and the
+    """Rows of one lane's ``s``-row slice a step of the XLA walk takes
+    (:func:`_attend_step`), from what the code can see; 0: the ``lanes``
+    slices are not walked, by either walk. The largest power of two of rows
+    whose bytes do not pass STEP_COST_BYTES, and at most half the slice: a
+    block that is the whole slice is no function of a loop's index, and the
     chip's compiler then computes its scores before the loop, whether a
     step runs or not (PERF.md, PR 45). A latent's block is made a buffer of
     its own (``reread``), which all lanes' together must fit in fast
     memory: LATENT_KV_BLOCK, as PR 33 found it. A slice that is no whole
     number of such blocks is one block. Slices that one pass reads, all of
-    them whole, in the time of ONE_PASS_STEPS steps are not walked."""
+    them whole, in the time of ONE_PASS_STEPS steps are not walked. (Where
+    the kernel walks, its own rule gives the block:
+    :func:`kernel_block`.)"""
     if lanes * s * row_bytes <= ONE_PASS_STEPS * STEP_COST_BYTES:
         return 0
     rows = LATENT_KV_BLOCK if latent else min(s // 2, 1 << max(
@@ -371,19 +392,57 @@ def step_block(s: int, row_bytes: int, lanes: int,
     return rows if rows and s % rows == 0 else s
 
 
+def kernel_block(s: int, row_bytes: int) -> int:
+    """Rows of one lane's ``s``-row slice a grid step of the kernel's walk
+    takes: the largest power of two of rows whose bytes do not pass
+    KERNEL_STEP_BYTES, a function of the row's bytes alone (a pair costs
+    its bytes there, so nothing is weighed against a step's cost). 0: the
+    slice is no whole number of such blocks, and keeps the XLA walk (a
+    block is a tile-aligned window of the leaf as it lies, never a
+    remainder)."""
+    rows = 1 << max(0, (KERNEL_STEP_BYTES // row_bytes).bit_length() - 1)
+    return rows if s % rows == 0 else 0
+
+
+def _mosaic_compiles() -> bool:
+    """Whether a walked pool of rows side by side goes through the Pallas
+    kernel: where Mosaic compiles it, as ``moe._mosaic_compiles`` says for
+    the experts' blocks. ``flash_attention._interpret`` is asked through
+    its module (imported here: that module imports this one), at the time a
+    walk is worked out: the one name a compile rehearsal steers."""
+    from mingpt_distributed_tpu.ops import flash_attention
+    return not flash_attention._interpret()
+
+
 def step_walk(leaves: Sequence[Tuple[int, ...]], itemsize: int,
-              latent: bool = False) -> StepWalk:
+              latent: bool = False, whole: bool = True) -> StepWalk:
     """The walk over cache leaves of shapes ``leaves`` ``(L, B, S, heads,
     size)``, ``itemsize`` bytes a number, as the step reads them. A leaf
     that keeps each of several heads narrower than a lane tile an axis
     entry of its own (GPT-2 XL's 25 of 64) lies positions minor on the
     device, and a block cut out of it by a traced index is copied before it
     is read (PERF.md, PR 45: 38 us a 6.5 MB block where the whole slices
-    read in 10 us a lane): such slices are not walked."""
+    read in 10 us a lane): such slices are not walked.
+
+    Two walks, chosen by what the code can see and by nothing else. Rows
+    that hold a position's heads side by side in whole lane tiles, where
+    Mosaic compiles (:func:`_mosaic_compiles`) and the leaves are ``whole``
+    (the pool's own buffers on one device: no dequantized copy, no mesh's
+    shards), are walked by the kernel, a pair at its bytes' cost: small
+    private blocks (:func:`kernel_block`), nothing shared. Everything else
+    that is walked (a latent pool, a per-head leaf of whole tiles a head,
+    any pool on the CPU) keeps the XLA walk, where a step is dear: large
+    blocks (:func:`step_block`), shared where enough lanes need them
+    (:func:`step_plan`)."""
     lanes, s, heads, size = leaves[0][1:]
     row_bytes = itemsize * sum(math.prod(leaf[3:]) for leaf in leaves)
     block = 0 if heads > 1 and size < LANE_TILE \
         else step_block(s, row_bytes, lanes, latent)
+    if block and whole and not latent and heads == 1 \
+            and size % LANE_TILE == 0 and _mosaic_compiles():
+        alone = kernel_block(s, row_bytes)
+        if alone:
+            return StepWalk(s, row_bytes, alone, True)
     return StepWalk(s, row_bytes, block)
 
 
@@ -391,17 +450,21 @@ def step_plan(walk: StepWalk, reach):
     """How a decode step walks its lanes' slices, from their ``reach`` (B,)
     (a lane's position where it holds a request, 0 where it does not):
     ``(shared, need)``. Lane ``b`` is read in blocks of ``walk.block`` rows
-    as far as its reach. Block ``j`` of the slices is read for all lanes
-    in one step where that is the cheaper, a step costing STEP_COST_BYTES
-    beside what it reads: where the lanes that need it, each at a step and
-    a block of its own, would cost more than one step and ``B`` blocks.
-    Fewer lanes need a later block, so those are the first ``shared``
-    blocks; ``need`` (B,) are the blocks each lane is read alone after
-    them. NumPy or ``jnp`` alike."""
-    s, row_bytes, block = walk
+    as far as its reach. In the XLA walk block ``j`` of the slices is read
+    for all lanes in one step where that is the cheaper, a step costing
+    STEP_COST_BYTES beside what it reads: where the lanes that need it,
+    each at a step and a block of its own, would cost more than one step
+    and ``B`` blocks. Fewer lanes need a later block, so those are the
+    first ``shared`` blocks; ``need`` (B,) are the blocks each lane is read
+    alone after them. In the kernel's walk (``walk.kernel``) a pair costs
+    its bytes and sharing has nothing to win: ``shared`` is 0 and ``need``
+    every block up to the lane's reach. NumPy or ``jnp`` alike."""
+    s, row_bytes, block = walk[:3]
+    blocks = (reach + block - 1) // block
+    if walk.kernel:
+        return 0, blocks
     own, whole = (STEP_COST_BYTES + n * block * row_bytes
                   for n in (1, len(reach)))
-    blocks = (reach + block - 1) // block
     # the lanes that need block j of their slice, for every j
     wanted = (blocks[None, :] > np.arange(s // block)[:, None]).sum(-1)
     shared = (wanted >= whole / own).sum()
@@ -410,21 +473,42 @@ def step_plan(walk: StepWalk, reach):
 
 def step_rows_read(walk: StepWalk, reach):
     """Rows a decode step reads of its lanes' slices, a plane, summed over
-    the lanes (:func:`causal_attend_step`, :func:`latent_attend_step`): by
-    :func:`step_plan`, whole blocks up to each lane's reach and, of every
-    lane, the blocks read together; every slice whole where they are not
-    walked. The one rule for the program and for whoever counts what it
-    read: NumPy or ``jnp`` alike."""
+    the lanes (:func:`causal_attend_step`, :func:`ring_attend_step`,
+    :func:`latent_attend_step`): by :func:`step_plan`, whole blocks up to
+    each lane's reach and, of every lane, the blocks read together (none in
+    the kernel's walk: the rows its grid takes); every slice whole where
+    they are not walked. The one rule for the program and for whoever
+    counts what it read: NumPy or ``jnp`` alike."""
     if not walk.block:
         return len(reach) * walk.s
     shared, need = step_plan(walk, reach)
     return (shared * len(reach) + need.sum()) * walk.block
 
 
+def _step_pairs(walk: StepWalk, reach, n: int):
+    """The (lane, block) pairs of a walked decode step, from
+    :func:`step_plan`: ``(shared, ends, lane_of, block_of)``. After the
+    ``shared`` blocks read for all lanes, step ``i`` of the ``ends[-1]``
+    the step has takes block ``block_of[i]`` of lane ``lane_of[i]``'s
+    slice, the lanes in order and each lane's blocks in order: found in
+    ``ends`` (B,), the running sum of the blocks the lanes need, for every
+    pair the walk can take. The one plan of the XLA walk's second loop
+    (:func:`_attend_step`) and of the kernel's grid
+    (:func:`_rows_attend_pairs`)."""
+    shared, need = step_plan(walk, reach.astype(jnp.int32))
+    ends = jnp.cumsum(need)
+    at = jnp.arange(n * (walk.s // walk.block))
+    lane_of = jnp.minimum((ends[None, :] <= at[:, None]).sum(-1),
+                          n - 1).astype(jnp.int32)
+    block_of = (shared + at - (ends - need)[lane_of]).astype(jnp.int32)
+    return shared, ends, lane_of, block_of
+
+
 def _attend_step(take, score, weigh, own, own_value, walk: StepWalk, reach,
                  reread: bool = False):
-    """One softmax in parts for a decode step: a query's cached rows, read
-    as they lie, and its own new row beside them, which starts the running
+    """One softmax in parts for a decode step, the XLA walk: a query's
+    cached rows, read as they lie, and its own new row beside them, which
+    starts the running
     maximum and sum (its score is finite, so a masked row's
     ``exp(NEG_INF - m)`` is 0 exactly and a block of masked rows changes
     nothing). The walk is :func:`step_plan`'s: the blocks enough lanes
@@ -434,8 +518,10 @@ def _attend_step(take, score, weigh, own, own_value, walk: StepWalk, reach,
     alone where no block is shared. Two ``fori_loop`` of one body, their
     trip counts the shared blocks and the pairs (``step_rows_read`` is the
     rows they take); step ``i`` of the second finds its lane and block in
-    the running sum of the blocks the lanes need. Slices that are not
+    :func:`_step_pairs`' vectors. Slices that are not
     walked (``walk.block`` 0) are read whole in one pass, every lane's.
+    (Rows side by side that the kernel walks never come here:
+    :func:`_rows_attend_step`.)
     ``take(lane, lanes, start, size)`` cuts rows ``[start, start + size)``
     of ``lanes`` lanes from ``lane`` on out of the whole cache buffers
     (inside the loop's body: a slice taken before the loop would be copied
@@ -449,7 +535,7 @@ def _attend_step(take, score, weigh, own, own_value, walk: StepWalk, reach,
     chip's compiler to keep in fast memory, and the cache's rows then
     leave HBM once a step and not twice (PERF.md, PR 33: 6.0 -> 4.5 ms).
     Returns (B, ..., d) float32."""
-    n, (s, _, block) = own.shape[0], walk
+    n, s, block = own.shape[0], walk.s, walk.block
     if not block:
         rows = take(0, n, 0, s)
         z = score(rows, 0, n, jnp.arange(s))
@@ -458,13 +544,8 @@ def _attend_step(take, score, weigh, own, own_value, walk: StepWalk, reach,
         acc = weigh(p, rows) + p_own[..., None] * own_value
         return acc / (p.sum(-1) + p_own)[..., None]
 
-    shared, need = step_plan(walk, reach.astype(jnp.int32))
-    ends = jnp.cumsum(need)
-    # step i's lane and first row, for every pair the walk can take
-    at = jnp.arange(n * (s // block))
-    lane_of = jnp.minimum((ends[None, :] <= at[:, None]).sum(-1),
-                          n - 1).astype(jnp.int32)
-    start_of = (shared + at - (ends - need)[lane_of]).astype(jnp.int32) * block
+    shared, ends, lane_of, block_of = _step_pairs(walk, reach, n)
+    start_of = block_of * block
 
     def step(carry, lane, lanes, start):
         rows = take(lane, lanes, start, block)
@@ -528,11 +609,15 @@ def causal_attend_step(
     and ``logit_softcap`` as in ``causal_attention`` (the own row is always
     inside the window). Lane ``b``'s slice is read in blocks as far as
     ``frontier[b]`` (B,), its reach: its position where it holds a request
-    and 0 where it does not (None: every lane to its own position), and
-    a block that enough lanes need for all lanes in one step
-    (:func:`_attend_step`, :func:`step_plan`); a per-head leaf of heads
-    under a lane tile is read whole, every lane's (:func:`step_walk`), and
-    so are slices too few and short for a walk to pay (:func:`step_block`).
+    and 0 where it does not (None: every lane to its own position), by
+    whichever walk ``walk`` holds (:func:`step_walk`). The XLA walk's
+    (:func:`_attend_step`, :func:`step_plan`): large blocks, a block that
+    enough lanes need read for all lanes in one step. The kernel's
+    (``walk.kernel``: rows side by side, where Mosaic compiles): small
+    blocks, every lane alone, the (lane, block) pairs one Pallas kernel a
+    layer (:func:`_rows_attend_pairs`). A per-head leaf of heads under a
+    lane tile is read whole, every lane's (:func:`step_walk`), and so are
+    slices too few and short for a walk to pay (:func:`step_block`).
 
     A cache that keeps each head an axis entry of its own is scored a KV
     head at a time, the grouped queries beside their head. A cache that
@@ -548,27 +633,189 @@ def causal_attend_step(
     out again, positions minor, before it scored it (compile rehearsal,
     PR 39: a copy and a float32 convert of a layer's slice, each leaf).
     Returns (B, 1, H, hd) in q's dtype."""
-    def allowed(pos, k_pos):
-        behind = pos - k_pos
-        seen = behind > 0
-        return seen & (behind < window) if window is not None else seen
-
     return _rows_attend_step(
         q, k_cache, v_cache, layer, k_new, v_new, positions, walk,
-        positions if frontier is None else frontier, allowed, logit_softcap)
+        positions if frontier is None else frontier,
+        (_rows_before, window), logit_softcap)
+
+
+def _rows_before(pos, index, window: Optional[int]):
+    """Which rows of a full layer's slice a lane at ``pos`` sees, by their
+    index, which is their position: those before its own and, under
+    ``window``, within its last ``window`` positions."""
+    behind = pos - index
+    seen = behind > 0
+    return seen & (behind < window) if window is not None else seen
+
+
+def _ring_rows_young(pos, index, w: int):
+    """Which rows of a ``w``-row ring a lane at ``pos`` sees, by their
+    index (:func:`ring_attend_step`): the row at ``index`` is ``1 + (pos -
+    1 - index) mod w`` positions behind, and seen where that is inside the
+    window and no further behind than the request began."""
+    behind = 1 + jnp.mod(pos - 1 - index, w)
+    return (behind < w) & (behind <= pos)
+
+
+def _rows_attend_kernel(layer_ref, lane_ref, block_ref, pos_ref, reach_ref,
+                        q_ref, k_new_ref, v_new_ref, k_ref, v_ref, o_ref,
+                        m_ref, l_ref, acc_ref, *, block: int, scale,
+                        logit_softcap, mask):
+    """Grid step ``i``: block ``block_ref[i]`` of lane ``lane_ref[i]``'s
+    slice against that lane's queries at the rows' width (H, W),
+    :func:`_attend_step`'s ``step`` operand for operand. A lane's first
+    block starts the running maximum, sum and accumulator from its own new
+    row; they stay in VMEM across the lane's blocks, and its last block
+    (the one that holds its reach) writes ``acc / l``."""
+    del layer_ref     # the leaves' index maps read it
+    i = pl.program_id(0)
+    lane, at = lane_ref[i], block_ref[i]
+    # as an einsum's operands: both in the wider of the two dtypes
+    wide = jnp.promote_types(q_ref.dtype, k_ref.dtype)
+    q = q_ref[0].astype(wide)
+    capped = lambda z: softcap(z * scale, logit_softcap)
+
+    @pl.when(at == 0)
+    def _():
+        # the own row's score: one row, so on the VPU, its products exact
+        # in float32 as the MXU's are
+        m_ref[...] = capped(jnp.sum(
+            q.astype(jnp.float32) * k_new_ref[0].astype(jnp.float32), -1,
+            keepdims=True))                                        # (H, 1)
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = jnp.broadcast_to(
+            v_new_ref[0].astype(jnp.float32), acc_ref.shape)
+
+    rule, arg = mask
+    index = at * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    z = capped(jax.lax.dot_general(
+        q, k_ref[...].astype(wide), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32))                       # (H, blk)
+    z = jnp.where(rule(pos_ref[lane], index, arg), z, NEG_INF)
+    m = m_ref[...]
+    m_new = jnp.maximum(m, z.max(-1, keepdims=True))
+    p, fix = jnp.exp(z - m_new), jnp.exp(m - m_new)
+    rows = v_ref[...]
+    m_ref[...] = m_new
+    l_ref[...] = l_ref[...] * fix + p.sum(-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * fix + jnp.dot(
+        p.astype(rows.dtype), rows, preferred_element_type=jnp.float32)
+
+    @pl.when((at + 1) * block >= reach_ref[lane])
+    def _():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+# One jitted caller a shape and not one a layer, as ``moe._run_blocks`` is
+# and for its reason (serve-decode's program writes out twelve layers); the
+# layer is an operand. Its name holds not the kernel's: XLA names
+# instructions after it, and the benchmark's reader finds the kernel by
+# name. What a walk holds is what ``_interpret()`` said when it was worked
+# out; the call asks again, so a test that steers it clears this cache too.
+@functools.partial(jax.jit, static_argnames=(
+    "block", "scale", "mask", "logit_softcap"))
+def _rows_attend_pairs(layer, lane_of, block_of, pairs, positions, reach,
+                       qg, k_new, v_new, k_cache, v_cache, *, block: int,
+                       scale: float, mask, logit_softcap):
+    """The kernel's walk: the first ``pairs`` (lane, block) pairs of
+    :func:`_step_pairs`, pair ``i`` block ``block_of[i]`` (``block`` rows)
+    of lane ``lane_of[i]`` of layer ``layer`` out of the whole ``(L, B, S,
+    1, W)`` leaves as they lie in HBM: the index maps read the prefetched
+    vectors, the grid's bound is ``pairs`` itself, and the pipeline fetches
+    pair ``i + 1`` while pair ``i`` is scored. ``qg`` (B, H, W) are the
+    queries at the rows' width (:func:`spread_queries`), ``scale`` what
+    their scores are multiplied by, ``k_new``, ``v_new`` (B, 1, W) the
+    lanes' own new rows, ``mask`` a ``(rule, argument)`` pair
+    (:func:`_rows_before`, :func:`_ring_rows_young`). Returns (B, H, W) in
+    ``qg``'s dtype; the entries of a lane that has no pair (its ``reach``
+    is 0) are not written and hold whatever the buffer held."""
+    from mingpt_distributed_tpu.ops import flash_attention
+    b, h, w = qg.shape
+    of_lane = lambda i, layer, lane, *_: (lane[i], 0, 0)
+    of_pair = lambda i, layer, lane, at, *_: (layer[0], lane[i], at[i], 0)
+    rows = pl.BlockSpec((None, None, block, w), of_pair)
+    own = pl.BlockSpec((1, 1, w), of_lane)
+    # the leaves less their axis of one head: the device keeps them with
+    # the positions next to the width (tiles of rows by lanes), so this
+    # views the same bytes and the call reads them where they lie
+    k_cache, v_cache = (a.reshape(a.shape[:3] + (w,))
+                        for a in (k_cache, v_cache))
+    return pl.pallas_call(
+        functools.partial(_rows_attend_kernel, block=block, scale=scale,
+                          logit_softcap=logit_softcap, mask=mask),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(pairs,),
+            in_specs=[pl.BlockSpec((1, h, w), of_lane), own, own, rows, rows],
+            out_specs=pl.BlockSpec((1, h, w), of_lane),
+            scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, w), jnp.float32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=flash_attention._interpret(),
+        name="rows_attend",
+    )(layer, lane_of, block_of, positions, reach, qg, k_new, v_new,
+      k_cache, v_cache)
+
+
+def kernel_walks(jaxpr) -> int:
+    """How many walks of a traced program are the kernel's: its
+    ``pallas_call`` equations named ``rows_attend``, at any depth (a decode
+    program holds one a layer whose pool the kernel walks)."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        found += eqn.primitive.name == "pallas_call" \
+            and eqn.params["name"] == "rows_attend"
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            found += kernel_walks(inner)
+    return found
+
+
+def _kernel_attend_step(q, k_cache, v_cache, layer, k_new, v_new, positions,
+                        walk, frontier, mask, logit_softcap):
+    """:func:`_rows_attend_step` where the walk is the kernel's: the pairs
+    of :func:`_step_pairs` (every lane alone, a pair at its bytes' cost)
+    through :func:`_rows_attend_pairs`, the queries at the rows' width and
+    each head keeping its own part of what it averaged, as the XLA walk
+    scores rows side by side. (Scoring a chunk of the row at a time with
+    its own heads' queries alone read no faster and cost 1.5-3 us more a
+    pair: PERF.md, PR 62.)"""
+    b, _, h, hd = q.shape
+    kv = k_cache.shape[-1] // hd
+    _, ends, lane_of, block_of = _step_pairs(walk, frontier, b)
+    out = _rows_attend_pairs(
+        jnp.full((1,), layer, jnp.int32), lane_of, block_of, ends[-1],
+        positions.astype(jnp.int32), frontier.astype(jnp.int32),
+        spread_queries(q, kv)[:, 0], k_new[:, 0], v_new[:, 0], k_cache,
+        v_cache, block=walk.block,
+        scale=float(np.float32(1) / np.sqrt(np.float32(hd))), mask=mask,
+        logit_softcap=logit_softcap)
+    # a lane no pair was taken of attends its own new row alone
+    out = jnp.where((frontier > 0)[:, None, None], out,
+                    v_new[:, 0].astype(q.dtype))
+    return own_part(out[:, None], kv)
 
 
 def _rows_attend_step(q, k_cache, v_cache, layer, k_new, v_new, positions,
-                      walk, frontier, allowed_rows, logit_softcap=None):
+                      walk, frontier, mask, logit_softcap=None):
     """The body of :func:`causal_attend_step` and :func:`ring_attend_step`:
     one query a lane against its slice of per-head rows as they lie and
-    its own new row beside them. ``allowed_rows(pos (n, 1), index (1, s))``
-    says which rows of a slice a lane at ``pos`` sees, by their index in
-    the slice: the one thing the two differ in."""
+    its own new row beside them. ``mask`` is ``(rule, argument)``:
+    ``rule(pos (n, 1), index (1, s), argument)`` says which rows of a slice
+    a lane at ``pos`` sees, by their index in the slice: the one thing the
+    two differ in."""
+    if walk.kernel:
+        return _kernel_attend_step(q, k_cache, v_cache, layer, k_new, v_new,
+                                   positions, walk, frontier, mask,
+                                   logit_softcap)
     b, _, h, hd = q.shape
     kv = math.prod(k_cache.shape[3:]) // hd
     side_by_side = k_cache.shape[3] != kv
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, dtype=jnp.float32))
+    allowed_rows = lambda pos, index: mask[0](pos, index, mask[1])
     if side_by_side:
         # the queries to the rows' width: (B, H, KV x hd)
         qg = spread_queries(q, kv)[:, 0]
@@ -577,6 +824,7 @@ def _rows_attend_step(q, k_cache, v_cache, layer, k_new, v_new, positions,
         # grouped queries beside their KV head: the cache is never repeated
         qg = q.reshape(b, kv, h // kv, hd)
         by_q, by_rows, by_new, scored = "bkgd", "bskd", "bkd", "bkg"
+
 
     def take(lane, lanes, start, size):
         rows = (_lane_rows(k_cache, layer, lane, lanes, start, size),
@@ -634,19 +882,16 @@ def ring_attend_step(
     At ``W`` behind (index ``p mod W``: the row the lane's own will
     replace) it has left the window, and a row further behind than ``p``
     was never written by this request: what a slot's last tenant left there
-    is masked by age as a stale row of a full layer is by position. A lane
+    is masked by age as a stale row of a full layer is by position
+    (:func:`_ring_rows_young`). A lane
     younger than the window so attends what it has written and no more.
     ``frontier`` (B,) as in :func:`causal_attend_step`; a lane's reach into
-    a ring ends at ``W``."""
+    a ring ends at ``W``. The same two walks as a full layer's, by
+    ``walk.kernel``."""
     w = k_ring.shape[2]
-
-    def allowed(pos, index):
-        behind = 1 + jnp.mod(pos - 1 - index, w)
-        return (behind < w) & (behind <= pos)
-
     reach = jnp.minimum(positions if frontier is None else frontier, w)
     return _rows_attend_step(q, k_ring, v_ring, layer, k_new, v_new,
-                             positions, walk, reach, allowed)
+                             positions, walk, reach, (_ring_rows_young, w))
 
 
 @jax.named_scope("latent_attn")

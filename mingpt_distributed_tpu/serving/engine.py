@@ -24,8 +24,9 @@ lifetime:
    learned-position index). Each layer reads its slice of the pool as
    it lies, each lane's new row attended beside it under one softmax: the
    lanes that hold a request alone, each in blocks as far as its own
-   position, a block that enough of them need for all lanes at once
-   (``attn_ops.step_plan``), by the one ``StepWalk`` the engine works
+   position; in the XLA walk a block that enough of them need for all
+   lanes at once, in the kernel's walk (rows side by side on the chip)
+   none (``attn_ops.step_plan``), by the one ``StepWalk`` the engine works
    out of its pool's leaves (``decode_walk``: the program is built with
    it and ``decode_rows_read`` counts by it); after the last layer
    one row-sized ``dynamic_update_slice`` a lane writes all layers' rows
@@ -244,9 +245,14 @@ def decode_frontier(positions, live):
 
 def decode_rows_read(positions, live, walk: attn_ops.StepWalk):
     """Rows of the slots a decode step reads, a plane, summed over the
-    lanes: whole blocks up to each live lane's own position, nothing of a
+    lanes, by whichever of the two rules ``walk`` holds
+    (``attn_ops.step_rows_read`` on :func:`decode_frontier`). The XLA
+    walk's: whole blocks up to each live lane's own position, nothing of a
     lane that is not live but the blocks read for all lanes together
-    (``attn_ops.step_rows_read`` on :func:`decode_frontier`), or every slot
+    (``attn_ops.step_plan``'s sharing, blocks by STEP_COST_BYTES). The
+    kernel's (``walk.kernel``): whole blocks of ``attn_ops.kernel_block``
+    rows up to each live lane's own position and nothing else, the rows
+    the kernel's grid takes. Or every slot
     whole where ``walk`` walks none (rows the device keeps positions minor;
     a pool one pass reads in a few steps' time, ``attn_ops.step_block``; a
     hybrid stack's sparse layers). ``walk`` is the engine's
@@ -261,7 +267,9 @@ def ring_rows(positions, live, walk: attn_ops.StepWalk):
     """(rows read, rows inside their lanes' windows) of a window layer's
     rings in a decode step, a plane, summed over the lanes:
     :func:`decode_rows_read` for a ring of ``walk.s`` rows
-    (``attn_ops.ring_attend_step``). A lane's reach into its ring ends at
+    (``attn_ops.ring_attend_step``), by the same two rules: the XLA walk
+    reads a block that enough lanes need of every lane's ring, the
+    kernel's each lane's ring alone, to its own reach. A lane's reach into its ring ends at
     the ring's size, and of the rows it is read, those of the ``ring - 1``
     positions before its own are inside its window (its own new row is
     attended beside the ring and is none of the ring's). ``walk`` is the
@@ -271,17 +279,21 @@ def ring_rows(positions, live, walk: attn_ops.StepWalk):
             reach.clip(0, walk.s - 1).sum())
 
 
-def decode_walk(cfg: GPTConfig, cache, kv_quant=None) -> attn_ops.StepWalk:
+def decode_walk(cfg: GPTConfig, cache, kv_quant=None,
+                sharded: bool = False) -> attn_ops.StepWalk:
     """The decode step's walk over the pool ``cache``, worked out once from
     the pool's own leaves as the step reads them (``generate.cache_walk``
     on their shapes and the dtype they have after :func:`_dequant_lane`: a
     ``cache_dtype`` of the engine's, or ``cfg.dtype`` out of a quantized
-    pool). A hybrid stack's layers read every row of every slot and take no
-    walk: its rule walks nothing."""
+    pool). A pool that is quantized (the step reads a dequantized copy) or
+    ``sharded`` over a mesh is not whole on one device as it lies, and
+    keeps the XLA walk wherever it runs. A hybrid stack's layers read every
+    row of every slot and take no walk: its rule walks nothing."""
     if cfg.mixer_types is not None:
         return attn_ops.StepWalk(cfg.block_size, 0, 0)
     return gen.cache_walk(cfg, jax.eval_shape(
-        lambda c: _dequant_lane(c, kv_quant, cfg), cache))
+        lambda c: _dequant_lane(c, kv_quant, cfg), cache),
+        whole=kv_quant is None and not sharded)
 
 
 @jax.named_scope("sample")
@@ -465,6 +477,7 @@ def _decode_impl(
     params, cache, tokens, positions, temps, top_ks, top_ps, do_sample,
     seeds, token_index=None, live=None, prev_tokens=None, from_prev=None,
     *, cfg: GPTConfig, kv_sharding=None, kv_quant=None, walk=None,
+    ring_walk=None,
 ):
     """One token for every slot: tokens/positions (S,), sampling arrays
     (S,), request seeds (S,) uint32, the index (S,) of the token each
@@ -486,9 +499,10 @@ def _decode_impl(
     the program has the pool's size but the pool, and nothing a slice's.
     Each lane's reach is worked out here from ``positions`` and ``live``
     (:func:`decode_frontier`) and the attention walks the lanes by it as
-    ``walk`` says, the engine's (:func:`decode_walk`; None: the cache's
-    own, worked out here), which is what :func:`decode_rows_read` counts
-    by: a ``live`` lane is read in blocks as far as
+    ``walk`` says and a window layer's ring as ``ring_walk`` does, the
+    engine's (:func:`decode_walk`, ``generate.ring_walk``; None: the
+    cache's own, worked out here), which is what :func:`decode_rows_read`
+    and :func:`ring_rows` count by: a ``live`` lane is read in blocks as far as
     its own position, a lane that is not live is passed by and attends its
     own new row (and what a block read for all lanes leaves it), a routed
     model lays none of its
@@ -511,7 +525,7 @@ def _decode_impl(
         safe_pos, cfg, valid=None if live is None else live[:, None],
         # a hybrid stack's layers read every row and take no reach
         frontier=None if cfg.mixer_types is not None
-        else decode_frontier(safe_pos, live), walk=walk)
+        else decode_frontier(safe_pos, live), walk=walk, rings=ring_walk)
     cache = _with_counter(_requant_lane(stepped, kv_quant, cfg), stepped)
     nxt = _select_next_slots(logits, lane_keys(seeds, token_index),
                              temps, top_ks, top_ps, do_sample)
@@ -709,14 +723,15 @@ class DecodeEngine:
             donate_argnums=(1,))
         # how the decode step walks this pool: the program is built with it
         # and the scheduler counts by it (decode_rows_read)
-        self.walk = decode_walk(cfg, self.pool.cache, kq)
+        self.walk = decode_walk(cfg, self.pool.cache, kq, kv is not None)
         # and a window layer's ring (None: the stack has none)
-        self.ring_walk = gen.ring_walk(cfg, self.pool.cache)
+        self.ring_walk = gen.ring_walk(
+            cfg, self.pool.cache, whole=kq is None and kv is None)
         self._decode_jit = jax.jit(
             bind_static(_decode_impl, cfg=cfg, kv_sharding=kv, kv_quant=kq,
-                        walk=self.walk),
+                        walk=self.walk, ring_walk=self.ring_walk),
             donate_argnums=(1,))
-        self._head_boundaries: Optional[int] = None
+        self._decode_counts: Optional[Tuple[int, int]] = None
         # prefix copy programs: `rows` is static, so one jit wrapper traces
         # once per bucket-quantized prefix length
         self._extract_jit = jax.jit(
@@ -812,18 +827,32 @@ class DecodeEngine:
         [rows attended, rows at or before the query], or None."""
         return self._counter(gen.SPARSE_ROWS)
 
+    def _counted_in_decode(self) -> Tuple[int, int]:
+        """(:meth:`head_boundaries`, :meth:`kernel_walk_layers`), counted
+        in the decode program's own trace, once: the jit's own where a step
+        has run, which ``warmup`` sees to, so a serving loop's first
+        ``summary()`` traces nothing."""
+        if self._decode_counts is None:
+            (_, _, jitted, args, kwargs), = [
+                p for p in self.programs() if p[0] == "decode"]
+            jaxpr = jitted.trace(*args, **kwargs).jaxpr
+            self._decode_counts = (gpt.head_boundaries(jaxpr),
+                                   attn_ops.kernel_walks(jaxpr))
+        return self._decode_counts
+
     def head_boundaries(self) -> int:
         """Projections of the decode program whose product stands behind
         ``gpt.head_projection``'s boundary (0: the rule passed the model
-        by), counted in the program's own trace, once: the jit's own where
-        a step has run, which ``warmup`` sees to, so a serving loop's first
-        ``summary()`` traces nothing."""
-        if self._head_boundaries is None:
-            (_, _, jitted, args, kwargs), = [
-                p for p in self.programs() if p[0] == "decode"]
-            self._head_boundaries = gpt.head_boundaries(
-                jitted.trace(*args, **kwargs).jaxpr)
-        return self._head_boundaries
+        by)."""
+        return self._counted_in_decode()[0]
+
+    def kernel_walk_layers(self) -> int:
+        """Layers of the decode program whose walk over the pool is the
+        kernel's (``attn_ops.kernel_walks``: the ``rows_attend`` calls its
+        trace holds, a layer each); 0 where every layer keeps the XLA walk
+        or reads its slices in one pass (any CPU run, a latent pool, a
+        hybrid stack)."""
+        return self._counted_in_decode()[1]
 
     def loop_passes(self) -> Optional[np.ndarray]:
         """``generate.LOOP_PASSES`` as it stands, fetched likewise: (2 +
@@ -1040,7 +1069,7 @@ class DecodeEngine:
             *parked, prev=first, from_prev=np.zeros(s, bool)))
         # counted now, off the trace the step above made: a serving loop's
         # first summary() then traces nothing
-        self.head_boundaries()
+        self._counted_in_decode()
         if self.prefix_store is not None:
             for b in self.buckets:
                 if b <= self.prefill_len - 1:

@@ -38,11 +38,20 @@ The decode step reads the buffers as they lie: a layer's slice is never
 selected over, copied or converted on its way into the attention (each
 lane's new row is attended beside the cached ones and written after the
 last layer), and only the slots that hold a request are read, each in
-blocks as far as its own position (a block that enough of them need, for
-all slots at once; a pool one pass reads in a few steps' time whole:
-``attention.step_plan``, ``step_block``, ``engine.decode_rows_read``;
-``ServingMetrics`` counts ``decode_rows_read`` against
-``decode_rows_reserved``).
+blocks as far as its own position, by one of two walks
+(``attention.step_walk`` chooses by what it can see). Rows that hold a
+position's heads side by side, whole on one chip where Mosaic compiles,
+go through a Pallas kernel in which a (slot, block) pair costs its bytes:
+small blocks by the row's bytes alone (``attention.kernel_block``), every
+slot read alone. Everything else (a latent pool, a per-head leaf, a
+quantized or sharded pool, any pool on the CPU) keeps the XLA walk, where
+a step is dear: large blocks by ``attention.STEP_COST_BYTES``
+(``step_block``), a block that enough slots need read for all slots at
+once (``step_plan``). Either way a pool one pass reads in a few steps'
+time is read whole (``step_block``); ``engine.decode_rows_read`` counts by
+the rule the program runs, and ``ServingMetrics`` holds
+``decode_rows_read`` against ``decode_rows_reserved`` and how many layers
+the kernel walks (``decode_kernel_walk_layers``).
 
 Allocation is deterministic (lowest free index first) so a given arrival
 order always produces the same slot placement — the scheduler tests rely

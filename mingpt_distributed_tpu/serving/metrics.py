@@ -239,6 +239,10 @@ class ServingMetrics:
         self._ring_rows_per_slot = r.gauge(
             "mingpt_serve_ring_rows_per_slot",
             help="rows one ring of a slot holds (the window); 0: no ring")
+        self._ring_planes = r.gauge(
+            "mingpt_serve_ring_planes",
+            help="window layers, each with a ring of keys and one of "
+                 "values; 0: no ring")
         self._kv_row_width = r.gauge(
             "mingpt_serve_kv_row_width",
             help="last axis of the pool's k leaf: one head's size, or a "
@@ -252,6 +256,7 @@ class ServingMetrics:
         self._sparse_rows_source: Optional[Callable[[], Any]] = None
         self._loop_passes_source: Optional[Callable[[], Any]] = None
         self._head_boundaries_source: Optional[Callable[[], int]] = None
+        self._kernel_walk_layers_source: Optional[Callable[[], int]] = None
         self._util_sum = 0.0
         self._prefill_rate = RateWindow()
         self._prefill_tokens_per_sec: Optional[float] = None
@@ -576,7 +581,9 @@ class ServingMetrics:
                      kv_row_width: int = 0, kv_row_tiles: int = 0,
                      head_boundaries_source: Optional[Callable[[], int]] = None,
                      ring_bytes_per_slot: int = 0, ring_rows_per_slot: int = 0,
-                     ) -> None:
+                     kernel_walk_layers_source: Optional[
+                         Callable[[], int]] = None,
+                     ring_planes: int = 0) -> None:
         """What the engine's programs read and what a cached token and a
         slot's state cost, known once it is built; ``kv_row_width`` and
         ``kv_row_tiles`` say which way the pool keeps a row
@@ -589,19 +596,25 @@ class ServingMetrics:
         its passes (``DecodeEngine.loop_passes``);
         ``head_boundaries_source`` reads, off the decode program's trace,
         the projections whose product stands behind a boundary
-        (``DecodeEngine.head_boundaries``); only ``summary()`` calls them."""
+        (``DecodeEngine.head_boundaries``) and ``kernel_walk_layers_source``
+        the layers whose walk over the pool is the Pallas kernel's
+        (``DecodeEngine.kernel_walk_layers``: how that kernel engages,
+        beside ``decode_rows_read`` and ``ring_rows_read``, which count by
+        its rule where it does); only ``summary()`` calls them."""
         self._program_weight_bytes.set(program_weight_bytes)
         self._program_weights_cast.set(program_weights_cast)
         self._kv_bytes_per_row.set(kv_bytes_per_row)
         self._state_bytes_per_slot.set(state_bytes_per_slot)
         self._ring_bytes_per_slot.set(ring_bytes_per_slot)
         self._ring_rows_per_slot.set(ring_rows_per_slot)
+        self._ring_planes.set(ring_planes)
         self._kv_row_width.set(kv_row_width)
         self._kv_row_tiles.set(kv_row_tiles)
         self._moe_rows_source = moe_rows_source
         self._sparse_rows_source = sparse_rows_source
         self._loop_passes_source = loop_passes_source
         self._head_boundaries_source = head_boundaries_source
+        self._kernel_walk_layers_source = kernel_walk_layers_source
 
     def _loop_summary(self) -> Dict[str, Any]:
         """A looped stack's passes since the server was built
@@ -622,16 +635,18 @@ class ServingMetrics:
 
     def _ring_summary(self) -> Dict[str, Any]:
         """The window layers' rings of a stack of ``layer_types``: what a
-        slot holds of them, and the rows the decode steps read of them
-        since the server was built beside those that were inside their
+        slot holds of them (its bytes, a ring's rows, the window layers),
+        and the rows the decode steps read of them since the server was
+        built, all window layers, beside those that were inside their
         lanes' windows. None where no layer keeps a ring."""
         rows = int(self._ring_rows_per_slot.value)
         if not rows:
             return dict.fromkeys((
-                "ring_bytes_per_slot", "ring_rows_per_slot",
+                "ring_bytes_per_slot", "ring_rows_per_slot", "ring_planes",
                 "ring_rows_read", "ring_rows_live"))
         return {"ring_bytes_per_slot": int(self._ring_bytes_per_slot.value),
                 "ring_rows_per_slot": rows,
+                "ring_planes": int(self._ring_planes.value),
                 "ring_rows_read": int(self._ring_rows_read.value),
                 "ring_rows_live": int(self._ring_rows_live.value)}
 
@@ -687,6 +702,8 @@ class ServingMetrics:
             "kv_row_tiles": int(self._kv_row_tiles.value),
             "decode_head_boundaries": self._head_boundaries_source()
             if self._head_boundaries_source else None,
+            "decode_kernel_walk_layers": self._kernel_walk_layers_source()
+            if self._kernel_walk_layers_source else None,
             **self._moe_summary(),
             **self._sparse_summary(),
             **self._loop_summary(),

@@ -385,7 +385,8 @@ class InferenceServer:
             self.engine.state_bytes_per_slot, self.engine.sparse_rows,
             self.engine.loop_passes, self.engine.pool.row_width,
             self.engine.pool.row_tiles, self.engine.head_boundaries,
-            self.engine.ring_bytes_per_slot, self.engine.ring_rows_per_slot)
+            self.engine.ring_bytes_per_slot, self.engine.ring_rows_per_slot,
+            self.engine.kernel_walk_layers, self.engine.ring_planes)
         # post-warmup recompile watchdog over the compiled program families
         # (the merged server-level counts, so draft/verify traces are
         # watched too; armed after warmup(); checked every round)

@@ -52,17 +52,21 @@ def walk_in_blocks_with(monkeypatch):
     row beside whose bytes a step's cost is nothing, so a block is read
     for all lanes together only where every lane needs it; ``alone=False``
     a row of one byte, beside which the cost is everything, so any block
-    two lanes need is read for all. Returns the walk of an ``s``-row
-    slice, for a test that hands one to the attention itself."""
+    two lanes need is read for all. ``kernel=True`` makes it the Pallas
+    kernel's walk (in interpret mode here: every lane alone whatever a
+    row's bytes). Returns the walk of an ``s``-row slice, for a test that
+    hands one to the attention itself."""
     from mingpt_distributed_tpu.ops import attention
 
-    def patch(rows, alone=True):
+    def patch(rows, alone=True, kernel=False):
         def walk_of(s):
             return attention.StepWalk(
-                s, 1 << 40 if alone else 1, rows if s % rows == 0 else s)
+                s, 1 << 40 if alone else 1, rows if s % rows == 0 else s,
+                kernel)
         monkeypatch.setattr(
             attention, "step_walk",
-            lambda leaves, itemsize, latent=False: walk_of(leaves[0][2]))
+            lambda leaves, itemsize, latent=False, whole=True:
+            walk_of(leaves[0][2]))
         return walk_of
     return patch
 

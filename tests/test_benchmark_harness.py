@@ -207,3 +207,66 @@ def test_the_grouped_kernel_s_roofline_reads_its_calls_under_either_name(
     assert [(m["unit"], m["layer"], m["source"], m["better"], m["moves"],
              m["workloads"]) for m in entry] == [
         ("%", "kernel", "device_trace", "higher", "itl_p50_ms", [cell])]
+
+
+def test_the_walk_s_kernel_s_roofline_reads_its_calls_over_the_rows_read(
+        monkeypatch):
+    """PR 62's Mosaic reader, held here for PR 60's reason: two
+    ``rows_attend`` calls of 30 and 50 us in the window over counters that
+    moved by 8,192 rows of the full layers, a plane (4,096 B a row, both
+    planes) and 12,288 rows of the rings, all three (2,048 B a row of one):
+    58.7 MB at the table's HBM peak, 71.7 us of the 80. A stack without rings reads the full layers'
+    rows alone. With anything it reads taken away it reads None or what it
+    read and never raises; without a call (the XLA walk: the parent's
+    program), without a gauge, and where no row was read it reads None."""
+    import copy
+
+    from benchmarks.harness import device, spec
+    from benchmarks.tests import test_readers_absent as absent
+
+    cell = "smallthinker-21b-a3b.serve-past-window"
+    reader = spec.load_reader("kernel.rows_attend_roofline")
+
+    def evidence(calls=2, rows=8192, ring=12288, cell=cell, **gauges):
+        whole, filed = _experts_evidence(calls, cell, "rows_attend")
+        for counters, level in ((whole["play"].trace_open, 0),
+                                (whole["play"].trace_close, 1)):
+            counters.update(dict(
+                decode_rows_read=1000 + level * rows,
+                ring_rows_read=None if ring is None else 500 + level * ring,
+                kv_bytes_per_row=4096,
+                ring_bytes_per_slot=None if ring is None else 6144 * 4096,
+                ring_rows_per_slot=None if ring is None else 4096,
+                ring_planes=None if ring is None else 3), **gauges)
+        return whole, filed
+
+    whole, filed = evidence()
+    value = reader.read(copy.copy(whole))
+    peak = device.peaks_for("TPU v5 lite")["hbm_bytes_s"]
+    assert value == pytest.approx(
+        100 * (8192 * 4096 + 12288 * 2048) / peak / 80e-6)
+    assert 85 < value < 95
+    # a stack that keeps no ring (``summary()`` gives None for its fields)
+    assert reader.read(evidence(ring=None, cell="gpt2-124m.serve-decode")[0]) \
+        == pytest.approx(100 * 8192 * 4096 / peak / 80e-6)
+    for lack, take in absent.LACKS.items():
+        got_evidence, files = take(copy.copy(whole), filed)
+        absent._filing(monkeypatch, files)
+        got = reader.read(got_evidence)
+        assert got is None or got == value, lack
+        if lack in ("no-trace", "no-counter-field", "no-traced-counters",
+                    "no-generator-s-records", "nothing",
+                    "no-run-of-the-program"):
+            assert got is None, lack
+    assert reader.read(evidence(calls=0)[0]) is None    # the XLA walk ran
+    assert reader.read(evidence(rows=0, ring=0)[0]) is None
+    assert reader.read(evidence(kv_bytes_per_row=None)[0]) is None
+    assert reader.read(evidence(ring_rows_per_slot=None)[0]) is None
+    assert reader.read(evidence(ring_planes=None)[0]) is None   # the parent's
+    entry = [m for m in spec.load_manifest()["per_layer"]
+             if m["name"] == "kernel.rows_attend_roofline"]
+    assert [(m["unit"], m["layer"], m["source"], m["better"], m["moves"])
+            for m in entry] == [
+        ("%", "kernel", "device_trace", "higher", "itl_p50_ms")]
+    assert entry[0]["workloads"] == [
+        "gpt2-124m.serve-decode", "laguna-xs.2.serve-long-decode", cell]

@@ -367,6 +367,43 @@ def test_the_server_serves_mixed_lengths_and_a_freed_slot_shows_nothing(model):
     assert eng.migratable_rows(60, 60) == 0
 
 
+def test_the_server_s_rings_and_rows_through_the_kernel_s_walk(
+        model, walk_in_blocks):
+    """The same two slots and five requests with every layer's walk the
+    Pallas kernel's (ISSUE 62; interpret mode, blocks of 8 rows: a ring of
+    16 is two, read to a lane's own reach): the full layers' 6 grouped
+    queries a KV head and the rings' 8, a ring that wraps under a long
+    request and is younger than the window under the short one that takes
+    its slot, greedy tokens those of solo ``generate``. The gauge counts
+    the five layers, and the rings' rows are counted by the kernel's rule:
+    no block is read for both lanes, so none of a lane that is not
+    live."""
+    walk_in_blocks(8, kernel=True)
+    cfg, params = model
+    server = InferenceServer(params, cfg, n_slots=2, prefill_len=64,
+                             prefill_buckets=[32, 64], warmup=True)
+    assert server.engine.walk.kernel and server.engine.ring_walk.kernel
+    assert server.engine.ring_walk == (WINDOW, 1 << 40, 8, True)
+    prompts = [tokens_of(cfg, 1, n, seed=n)[0].tolist()
+               for n in (60, 5, 33, 9, 17)]
+    handles = [server.submit(Request(prompt=p, max_new_tokens=40,
+                                     do_sample=False)) for p in prompts]
+    while server.step():
+        pass
+    for p, h in zip(prompts, handles):
+        solo = gen.generate(params, cfg, jnp.asarray([p]), 40)[0, len(p):]
+        assert h.tokens == solo.tolist()
+    s = server.metrics.summary()
+    assert s["decode_kernel_walk_layers"] == cfg.n_layer == 5
+    assert s["ring_planes"] == server.engine.ring_planes == 3
+    # whole blocks of 8 to each live lane's reach: under a block a
+    # lane-step past the rows inside the windows
+    assert 0 < s["ring_rows_live"] < s["ring_rows_read"] \
+        <= s["ring_rows_live"] + 8 * s["tokens_generated"]
+    assert s["ring_rows_read"] % 8 == 0
+    assert server.compile_counts()["decode"] == 1
+
+
 def test_the_ring_s_counters_follow_the_program_s_rule():
     """A ring of 512 rows walked in blocks of 256. Of a lane past the
     window 511 rows are inside it, of a younger lane those it has written,
@@ -667,8 +704,11 @@ def test_the_manifest_lists_the_cell_where_it_reports():
         "kanana-2-30b-a3b.serve-long-decode").per_layer}
     # kanana's lists but the one whose reader finds nothing to read here:
     # it takes the experts a layer from ``first_k_dense_replace`` and
-    # ``n_routed_experts``, which this configuration does not publish
-    assert names == (kanana - {"moe.rows_per_expert_round"}) | set(NEW_READERS)
+    # ``n_routed_experts``, which this configuration does not publish; and
+    # PR 62's reader of the kernel that walks rows side by side (kanana's
+    # latent pool keeps the XLA walk)
+    assert names == (kanana - {"moe.rows_per_expert_round"}) \
+        | set(NEW_READERS) | {"kernel.rows_attend_roofline"}
     assert "engine.decode_hbm_roofline" not in names
     play = serve_cell.Play(n_slots=64, block_size=8192)
     play.trace_open = {"moe_routed_rows": 0, "steps": 0}

@@ -5,7 +5,12 @@ the program (``_attend_step``'s two loops) and for whoever counts
 (``step_rows_read``, ``engine.decode_rows_read``). The walked step against a
 plain softmax over each lane's own rows; NaN planted in every row the rule
 says is not read; the rows the loops take at run time against the rule;
-served tokens against solo ``generate``'s. CPU, tiny, float32."""
+served tokens against solo ``generate``'s. Where rows lie side by side and
+Mosaic compiles, the pairs go through one Pallas kernel a layer (ISSUE 62:
+``_rows_attend_pairs``, every lane alone): the kernel in interpret mode
+against the XLA walk and one pass, the rows its grid takes against the rule,
+its block against the cells' leaves, its served tokens and its gauge. CPU,
+tiny, float32."""
 
 
 import jax
@@ -287,7 +292,7 @@ CELL_LEAVES = {
 def test_the_block_follows_from_the_leaves_shapes(cell, row_bytes, block):
     walk = attn_ops.step_walk(CELL_LEAVES[cell], 2,
                               latent=cell.startswith("kanana"))
-    assert walk == (CELL_LEAVES[cell][0][2], row_bytes, block)
+    assert walk == (CELL_LEAVES[cell][0][2], row_bytes, block, False)
     if block and not cell.startswith("kanana"):
         assert block * row_bytes <= attn_ops.STEP_COST_BYTES
         assert 2 * block <= walk.s
@@ -449,7 +454,8 @@ def test_the_walk_follows_the_dtype_the_pool_is_held_in(lanes, held,
     cfg = GPTConfig.make(model_type="gpt2", dtype="bfloat16")
     pool = pool_of(cfg, lanes, jnp.dtype(held))
     walk = engine_mod.decode_walk(cfg, pool)
-    assert walk == (1024, row_bytes, block) == gen.cache_walk(cfg, pool)
+    assert walk == (1024, row_bytes, block, False) \
+        == gen.cache_walk(cfg, pool)
     reach = np.where(np.arange(lanes) == 1, 300, 0)
     assert engine_mod.decode_rows_read(reach, None, walk) \
         == (block or lanes * 1024)
@@ -481,7 +487,7 @@ def test_an_engine_s_program_and_counter_share_one_walk(options, row_bytes,
     seen = walks_traced(monkeypatch)
     eng = engine_mod.DecodeEngine(params, cfg, n_slots=3,
                                   prefill_buckets=(8, 16, 32), **options)
-    assert eng.walk == (ROWS, row_bytes, 0)
+    assert eng.walk == (ROWS, row_bytes, 0, False)
     s = eng.n_slots
     eng.decode_step(
         np.zeros(s, np.int32), np.array([3, 9, ROWS - 1], np.int32),
@@ -617,3 +623,230 @@ def test_one_decode_program_while_the_live_set_changes_every_round(
     assert len(plans) >= 4
     assert server.compile_counts() == before
     assert all(h.tokens for h in handles)
+
+
+# -- the kernel's walk (ISSUE 62) ----------------------------------------------
+
+#: tiny rows of the three cells the kernel walks, (query heads, KV heads,
+#: head size): heads of 64 side by side, twelve a row as GPT-2 124M's, or
+#: two KV heads with the grouped queries a KV head of laguna's full layers
+#: (6), smallthinker's (7), laguna's rings (8); smallthinker's again with
+#: heads of a whole lane tile, as the cells' own are
+KERNEL_ROWS = {"mha-12": (12, 12, 64), "gqa-6": (12, 2, 64),
+               "gqa-7": (14, 2, 64), "gqa-8": (16, 2, 64),
+               "gqa-7-tiles": (14, 2, 128)}
+#: a full layer's lanes: nothing to read, one row, exactly a block's edge,
+#: just past it, the slot's last row, and a middle one
+FULL_AT = np.array([0, 1, BLOCK, BLOCK + 1, ROWS - 1, 2 * BLOCK + 3], np.int32)
+#: a ring's, of ROWS rows: position 0, younger than the window, at a block's
+#: edge, exactly the window, and twice wrapped (on a block's edge and off it)
+RING_AT = np.array([0, 3, BLOCK, ROWS, 2 * ROWS + BLOCK, 3 * ROWS + 5],
+                   np.int32)
+KERNEL_CASES = {
+    "full": ("full", {}),
+    "full-window-softcap": ("full", dict(window=5, logit_softcap=3.0)),
+    "ring": ("ring", {}),
+}
+
+
+def side_by_side_inputs(heads, kv_heads, hd=64, seed=8):
+    """Queries, two planes of cache and the lanes' new rows, a position's
+    heads side by side. Every cached row is random: what lies at or past a
+    lane's position, or in a ring at an age the request never had, is a
+    stale row its slot's last tenant left."""
+    keys = jax.random.split(jax.random.key(seed), 5)
+    width = kv_heads * hd
+    q = jax.random.normal(keys[0], (LANES, 1, heads, hd))
+    k_cache, v_cache = (jax.random.normal(k, (2, LANES, ROWS, 1, width))
+                        for k in keys[1:3])
+    k_new, v_new = (jax.random.normal(k, (LANES, 1, 1, width))
+                    for k in keys[3:5])
+    return q, k_cache, v_cache, k_new, v_new
+
+
+def step_of(kind, walk, positions, live, **case):
+    """The decode step of a full layer or a ring over plane 1, jitted."""
+    positions = jnp.asarray(positions)
+    frontier = None if live is None else positions * jnp.asarray(live)
+    if kind == "ring":
+        return jax.jit(lambda q, *c: attn_ops.ring_attend_step(
+            q, c[0], c[1], 1, c[2], c[3], positions, walk,
+            frontier=frontier))
+    return jax.jit(lambda q, *c: attn_ops.causal_attend_step(
+        q, c[0], c[1], 1, c[2], c[3], positions, walk, frontier=frontier,
+        **case))
+
+
+@pytest.mark.parametrize("live", ["all", "some"])
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+@pytest.mark.parametrize("rows", sorted(KERNEL_ROWS))
+def test_the_kernel_s_walk_is_the_xla_walk_s_and_one_pass_s(rows, case, live):
+    """One softmax three ways: the Pallas kernel (interpret mode), the XLA
+    walk in the same blocks, and one pass over every lane's whole slice
+    under the same mask. A lane at position 0 attends its own row alone, a
+    lane at a block's edge takes that block and no more, a ring that has
+    wrapped shows every row but the one it will replace and a younger one
+    what it has written; stale rows, the window and the softcap change
+    nothing between them. A lane that is not live attends its own new row
+    alone in both walks (one pass reads it to its position: not judged)."""
+    kind, options = KERNEL_CASES[case]
+    at = RING_AT if kind == "ring" else FULL_AT
+    inputs = side_by_side_inputs(*KERNEL_ROWS[rows])
+    live = LIVE[live]
+    got, walked, whole = (
+        np.asarray(step_of(kind, walk, at, live, **options)(*inputs))
+        for walk in (attn_ops.StepWalk(ROWS, 1 << 40, BLOCK, True),
+                     attn_ops.StepWalk(ROWS, 1 << 40, BLOCK),
+                     attn_ops.StepWalk(ROWS, 1, 0)))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, walked, rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(got[live], whole[live], rtol=2e-5, atol=2e-6)
+    # a lane passed by, or at position 0, attends its own new row alone
+    alone = ~live | (at == 0)
+    heads, kv_heads, hd = KERNEL_ROWS[rows]
+    own = np.repeat(np.asarray(inputs[4]).reshape(LANES, 1, kv_heads, hd),
+                    heads // kv_heads, axis=2)
+    np.testing.assert_allclose(got[alone], own[alone], rtol=1e-6)
+
+
+def blocks_the_step_reads(kind, walk, at, live, inputs):
+    """(LANES, blocks) bool, observed: NaN planted in block ``j`` of every
+    lane's values turns a lane's output to NaN exactly where the step read
+    that block of that lane (a masked row's weight is 0, and 0 x NaN is
+    NaN)."""
+    run = step_of(kind, walk, at, live)
+    assert np.isfinite(np.asarray(run(*inputs))).all()
+    read = []
+    for j in range(ROWS // BLOCK):
+        values = inputs[2].at[1, :, j * BLOCK:(j + 1) * BLOCK].set(jnp.nan)
+        out = np.asarray(run(*inputs[:2], values, *inputs[3:]))
+        read.append(np.isnan(out).any(axis=(1, 2, 3)))
+    return np.stack(read, axis=1)
+
+
+@pytest.mark.parametrize("live", sorted(LIVE))
+@pytest.mark.parametrize("kind", ["full", "ring"])
+def test_the_rows_the_kernel_s_grid_takes_are_the_rule_s(kind, live):
+    """``step_rows_read`` on the kernel's ``StepWalk`` (and the engine's
+    counters over it) = the blocks the kernel's grid is seen to take, a
+    lane: whole blocks up to its reach, none of a lane that is not live,
+    and no block for all lanes, whatever a row's bytes."""
+    at = RING_AT if kind == "ring" else FULL_AT
+    live = LIVE[live]
+    reach = np.minimum(at if live is None else at * live, ROWS)
+    for row_bytes in (1, 1 << 40):    # sharing has nothing to weigh
+        walk = attn_ops.StepWalk(ROWS, row_bytes, BLOCK, True)
+        shared, need = attn_ops.step_plan(walk, reach)
+        assert shared == 0 and (need == -(-reach // BLOCK)).all()
+        ruled = attn_ops.step_rows_read(walk, reach)
+        assert ruled == int(need.sum()) * BLOCK
+        if kind == "ring":
+            assert engine_mod.ring_rows(at, live, walk) == (
+                ruled, int(np.minimum(reach, ROWS - 1).sum()))
+        else:
+            assert engine_mod.decode_rows_read(at, live, walk) == ruled
+    seen = blocks_the_step_reads(kind, walk, at, live,
+                                 side_by_side_inputs(12, 2))
+    assert (seen == (np.arange(ROWS // BLOCK)[None, :] < need[:, None])).all()
+    assert int(seen.sum()) * BLOCK == ruled
+    # the XLA walk over the same lanes shares a block two of them need
+    if int((need > 0).sum()) >= 2:
+        assert attn_ops.step_rows_read(
+            attn_ops.StepWalk(ROWS, 1, BLOCK), reach) > ruled
+
+
+#: the pools the kernel walks on the chip, and those it leaves
+KERNEL_CELL_LEAVES = {
+    "gpt2-124m": ([(12, 64, 1024, 1, 768)] * 2, False),
+    "laguna-full": ([(2, 64, 8192, 1, 1024)] * 2, False),
+    "laguna-rings": ([(3, 64, 512, 1, 1024)] * 2, False),
+    "smallthinker-full": ([(2, 64, 16384, 1, 512)] * 2, False),
+    "smallthinker-rings": ([(3, 64, 4096, 1, 512)] * 2, False),
+    "kanana-2-30b-a3b": (CELL_LEAVES["kanana-2-30b-a3b"], True),
+    "gpt2-xl": (CELL_LEAVES["gpt2-xl"], False),
+    "ouro-2.6b": (CELL_LEAVES["ouro-2.6b"], False),
+    "per-head-128": ([(4, 64, 4096, 8, 128)] * 2, False),
+}
+
+
+@pytest.mark.parametrize("cell, block", [
+    ("gpt2-124m", 256), ("laguna-full", 256), ("laguna-rings", 256),
+    ("smallthinker-full", 512), ("smallthinker-rings", 512),
+    ("kanana-2-30b-a3b", None), ("gpt2-xl", None), ("ouro-2.6b", None),
+    ("per-head-128", None)])
+def test_the_kernel_walks_rows_side_by_side_where_mosaic_compiles(
+        cell, block, monkeypatch):
+    """The choice falls to what the code can see: the leaf's shape, whether
+    Mosaic compiles, whether the pool lies whole on one device. Rows side
+    by side are walked by the kernel in blocks of KERNEL_STEP_BYTES, a
+    function of the row's bytes alone; a latent pool, a per-head leaf and
+    slices that are not walked at all keep what they had, byte for byte;
+    so does everything on the CPU, under a mesh and out of a quantized
+    pool."""
+    leaves, latent = KERNEL_CELL_LEAVES[cell]
+    xla = attn_ops.step_walk(leaves, 2, latent=latent)
+    assert not xla.kernel          # the CPU: Mosaic does not compile here
+    monkeypatch.setattr(attn_ops, "_mosaic_compiles", lambda: True)
+    assert attn_ops.step_walk(leaves, 2, latent=latent, whole=False) == xla
+    walk = attn_ops.step_walk(leaves, 2, latent=latent)
+    if block is None:
+        assert walk == xla
+        return
+    assert walk == (xla.s, xla.row_bytes, block, True)
+    assert block * walk.row_bytes <= attn_ops.KERNEL_STEP_BYTES \
+        < 2 * block * walk.row_bytes
+    assert attn_ops.kernel_block(walk.s, walk.row_bytes) == block
+    # twice the lanes or a step's cost move nothing: the row's bytes alone
+    more = [(leaf[0], 2 * leaf[1]) + leaf[2:] for leaf in leaves]
+    assert attn_ops.step_walk(more, 2).block == block
+    # a slice that is no whole number of blocks keeps the XLA walk
+    assert attn_ops.kernel_block(walk.s + 24, walk.row_bytes) == 0
+    odd = [leaf[:2] + (walk.s + 24,) + leaf[3:] for leaf in leaves]
+    assert not attn_ops.step_walk(odd, 2).kernel
+
+
+@pytest.mark.parametrize("name, kernel", [
+    ("mha", True), ("gqa-rope", True), ("window-softcap", True),
+    ("mha", False)])
+def test_served_tokens_under_the_kernel_s_walk_and_its_gauge(
+        name, kernel, walk_in_blocks):
+    """The decode program with the kernel in every layer (interpret mode)
+    serves solo ``generate``'s greedy tokens through staggered admission
+    and refilled slots, in one program; ``decode_kernel_walk_layers`` says
+    how many layers it walks (0 under the XLA walk), and the rows counted
+    are the kernel's rule's: whole blocks of the live lanes alone."""
+    walk_in_blocks(BLOCK, kernel=kernel)
+    cfg, params = model(name)
+    got, server = served(cfg, params)
+    for tokens, prompt, budget in zip(got, PROMPTS, BUDGETS):
+        assert tokens == solo_greedy(params, cfg, prompt, budget)
+    assert server.engine.walk.kernel is kernel
+    assert server.compile_counts()["decode"] == 1
+    assert server.watchdog.recompiles == 0
+    summary = server.summary()
+    assert summary["decode_kernel_walk_layers"] \
+        == server.engine.kernel_walk_layers() == (cfg.n_layer if kernel else 0)
+    assert 0 < summary["decode_rows_read"] < summary["decode_rows_reserved"]
+    assert summary["decode_rows_read"] % BLOCK == 0
+
+
+@pytest.mark.parametrize("options, kernel", [
+    (dict(), True), (dict(kv_dtype="int8"), False), (dict(tp=2), False)],
+    ids=["whole", "int8", "tp2"])
+def test_a_sharded_or_quantized_pool_keeps_the_xla_walk(options, kernel,
+                                                        monkeypatch):
+    """Where Mosaic compiles, an engine whose pool is quantized (the step
+    reads a dequantized copy) or sharded over a mesh still builds its
+    program with the XLA walk: the kernel reads buffers whole on one
+    device, as they lie."""
+    monkeypatch.setattr(attn_ops, "_mosaic_compiles", lambda: True)
+    monkeypatch.setattr(attn_ops, "ONE_PASS_STEPS", 0)   # tiny slices walked
+    monkeypatch.setattr(attn_ops, "KERNEL_STEP_BYTES", 16 << 10)  # 16 rows
+    cfg, params = model("mha")
+    if "tp" in options:
+        options = dict(mesh=mesh_lib.make_mesh(
+            MeshConfig(dp=1, tp=2), devices=jax.devices()[:2]))
+    engine = InferenceServer(params, cfg, n_slots=3, warmup=False,
+                             prefill_buckets=(8, 16, 32), **options).engine
+    assert engine.walk.kernel is kernel
+    assert engine.walk.block == ROWS // 2
