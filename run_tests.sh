@@ -15,6 +15,21 @@
 # The persistent compilation cache makes repeat runs much cheaper (the
 # suite is compile-dominated: ~40% off the heaviest pipeline cases once
 # warm). Safe to delete .jax_test_cache at any time.
+#
+# THE DRIVER'S RUN IS COLD (.jax_test_cache is git-ignored, so its checkout
+# starts with none): a warm figure says nothing of the limit the suite is
+# judged under. Time a cold run with the driver's own command (`commands` in
+# /root/TESTS_LAST_RUN.json) and an empty cache:
+#   JAX_COMPILATION_CACHE_DIR=$(mktemp -d) bash -c "<that command>"
+# and quote the cold figure beside the warm one in CHANGES.md (ISSUE 63:
+# 1,225 s cold on the parent, 774 s warm). pytest.ini's --durations lines
+# name every run's slowest cases, the driver's too.
+#
+# A new family of served stacks is an entry in tests/stacks.py, a
+# tests/test_<family>.py that names it (STACK = ...) and imports its laws
+# from tests/stack_contract.py, and its peculiar tests: not a copy of the
+# last family's file. Greedy oracles come from tests/oracles.py (one
+# compiled program a stack, not one a prompt length and budget).
 set -euo pipefail
 cd "$(dirname "$0")"
 

@@ -36,9 +36,10 @@ def test_the_manifest_s_new_entries_have_readers_and_cells_that_report():
     cells each. A PR that brings a cell appends its name to those lists and
     its own metrics after them (PR 59), and may edit no file under
     ``benchmarks/``, so tier-1 holds what a later manifest keeps: the
-    thirteen stand together in PR 55's order, each with its reader, its
-    source and cells of its kind that report what it moves. (A ``benchmark``
-    PR restates the case in place: ``PERF.md`` section 7.)"""
+    thirteen are there, each with its reader, its source and cells of its
+    kind that report what it moves; where in the list they stand is a PR's
+    history, which no test holds (ISSUE 63). (A ``benchmark`` PR restates
+    the case in place: ``PERF.md`` section 7.)"""
     import os
 
     from benchmarks.harness import spec
@@ -50,12 +51,9 @@ def test_the_manifest_s_new_entries_have_readers_and_cells_that_report():
         for m in manifest["end_to_end"]}
     kinds = {w["name"]: w["traffic"].split("-")[0]
              for w in manifest["workloads"]}
-    at = [i for i, m in enumerate(manifest["per_layer"])
-          if m["layer"] == "model"]
-    added = [manifest["per_layer"][i] for i in at]
+    added = [m for m in manifest["per_layer"] if m["layer"] == "model"]
     assert sorted(m["name"] for m in added) == sorted(
         (*pr55._DECODE.values(), *pr55._TRAIN.values(), pr55._SHARE))
-    assert at == list(range(at[0], at[0] + len(at)))    # appended together
     for m in added:
         assert os.path.exists(os.path.join(
             spec.ROOT, "benchmarks", "layer_metrics", m["name"] + ".py"))

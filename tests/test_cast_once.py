@@ -23,6 +23,7 @@ given, but for the leaves the cached forward reads only through
   every prefill program as it was.
 """
 
+import functools
 import re
 
 import jax
@@ -81,10 +82,13 @@ ARCHS = {
 }
 
 
+@functools.cache
 def model(arch, dtype):
     """Config and parameters with every leaf away from its initial value:
     biases, norm scales and ``wpe`` start as zeros and ones, where a cast
-    too many would not show."""
+    too many would not show. Made once a session (op by op: the draws of a
+    shape are one small program whatever the architecture, where one jitted
+    ``init`` an architecture costs 1.6 s each)."""
     cfg = GPTConfig.make(**BASE, **ARCHS[arch], dtype=dtype)
     params = gpt.init(jax.random.key(11), cfg)
     leaves, tree = jax.tree.flatten(params)

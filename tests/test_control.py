@@ -19,8 +19,6 @@ The load-bearing guarantees:
 import json
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from mingpt_distributed_tpu.config import GPTConfig
@@ -36,7 +34,6 @@ from mingpt_distributed_tpu.control.importer import (
     import_trace_arrivals,
     trace_arrival_times,
 )
-from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.serving import (
     ReplicaSupervisor,
@@ -53,6 +50,7 @@ from mingpt_distributed_tpu.trafficlab import (
     run_sweep,
     validate_traffic_report,
 )
+from oracles import solo_greedy
 
 TRACE_SCHEMA = "mingpt-trace/1"
 
@@ -64,11 +62,6 @@ def cfg_params():
         embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32",
     )
     return cfg, gpt.init(jax.random.key(0), cfg)
-
-
-def solo_greedy(params, cfg, prompt, n):
-    out = gen.generate(params, cfg, jnp.asarray(prompt, jnp.int32)[None], n)
-    return np.asarray(out)[0, len(prompt):].tolist()
 
 
 # ---------------------------------------------------------------------------

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import stacks
 from mingpt_distributed_tpu.config import GPTConfig
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
@@ -94,8 +95,8 @@ def solo(arch, hist):
     """Solo generate's forward over the whole history: (greedy next token,
     the (L, len, KV, hd) rows it caches)."""
     cfg, params = model(arch)
-    logits, cache = gen._forward_cached(
-        params, jnp.asarray(hist, jnp.int32)[None], gen.init_cache(cfg, 1),
+    logits, cache = stacks.forward_cached(
+        params, np.asarray(hist, np.int32)[None], gen.init_cache(cfg, 1),
         0, cfg)
     rows = {n: np.asarray(cache[n])[:, 0, :len(hist)] for n in ("k", "v")}
     return int(jnp.argmax(logits[0])), rows

@@ -9,6 +9,8 @@ import pytest
 from mingpt_distributed_tpu.config import GPTConfig
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
+import stacks
+from oracles import dense_greedy
 
 
 def cfg_and_params(**kw):
@@ -21,16 +23,63 @@ def cfg_and_params(**kw):
     return cfg, gpt.init(jax.random.key(0), cfg)
 
 
-def dense_greedy(params, cfg, idx, n):
-    """Reference-style loop: full re-forward each step, argmax (the
-    crop-and-append semantics of model.py:322-356, as an oracle)."""
-    idx = jnp.asarray(idx)
-    for _ in range(n):
-        idx_cond = idx[:, -cfg.block_size:]
-        logits, _ = gpt.forward(params, idx_cond, cfg)
-        nxt = jnp.argmax(logits[:, -1], axis=-1)
-        idx = jnp.concatenate([idx, nxt[:, None]], axis=1)
-    return idx
+#: a stack of every family the suite serves: the benchmark's five (kinds of
+#: layer and rings, a router on the attention's input, a hybrid of linear
+#: and sparse layers, a latent cache under routed experts, a looped stack),
+#: the two forms of a row they leave out (grouped rotated heads; a window
+#: under a softcap: ``tests/stacks.py``), and the serving tests' GPT-2
+FAMILIES = {
+    **{name: (lambda s=s: stacks.model(s))
+       for name, s in stacks.STACKS.items()},
+    **{"form-" + form: (lambda form=form: stacks.form_model(form))
+       for form in ("gqa-rope", "window-softcap")},
+    "serving-tiny": cfg_and_params,
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_padding_after_a_position_is_not_read(family):
+    """What ``tests/oracles.py`` relies on: a causal stack's logits at a
+    position are those of the tokens up to it, whatever stands after them
+    in the window (zeros, or any other token), so one program at the
+    block's length serves every prompt length. (The capacity route is not
+    such a stack: ``oracles.solo_greedy`` forwards its exact lengths.)"""
+    cfg, params = FAMILIES[family]()
+    n = cfg.block_size // 3 + 1
+    tokens = stacks.tokens_of(cfg, 2, n, seed=5)
+    exact = np.asarray(stacks.forward(params, tokens, cfg)[0])
+    zeros = stacks.padded(tokens, cfg)
+    sevens = zeros.copy()
+    sevens[:, n:] = 7
+    for window in (zeros, sevens):
+        got = np.asarray(stacks.forward(params, window, cfg)[0])[:, :n]
+        np.testing.assert_allclose(got, exact, atol=1e-5)
+        np.testing.assert_array_equal(got.argmax(-1), exact.argmax(-1))
+
+
+def test_a_test_that_patches_is_served_its_own_trace(monkeypatch, request):
+    """What lets the session share ``stacks.forward`` and the oracle's
+    program: a patch of what they trace is no argument of theirs, so
+    ``tests/conftest.py`` gives a test that asks for ``monkeypatch`` an
+    epoch of its own; its trace sees the patch, and the tests without one
+    are served theirs as before."""
+    import oracles
+    cfg, params = cfg_and_params()
+    tokens = stacks.tokens_of(cfg, 2, 9)
+    own = oracles.EPOCH[0]
+    assert own == request.node.nodeid
+    try:
+        oracles.EPOCH[0] = 0        # as a test that patches nothing
+        plain = np.asarray(stacks.forward(params, tokens, cfg)[0])
+        oracles.EPOCH[0] = own
+        monkeypatch.setattr(gpt, "_norm", lambda x, scale, bias, cfg: x)
+        patched = np.asarray(stacks.forward(params, tokens, cfg)[0])
+        assert np.abs(patched - plain).max() > 1e-3
+        oracles.EPOCH[0] = 0        # the next test: no patched trace is left
+        np.testing.assert_array_equal(
+            stacks.forward(params, tokens, cfg)[0], plain)
+    finally:
+        oracles.EPOCH[0] = own
 
 
 def test_cached_greedy_matches_dense_oracle():

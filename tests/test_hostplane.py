@@ -40,7 +40,6 @@ import numpy as np
 import pytest
 
 from mingpt_distributed_tpu.config import GPTConfig
-from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.parallel.mesh import MeshConfig, make_mesh
 from mingpt_distributed_tpu.serving import Request, VirtualClock
@@ -69,6 +68,7 @@ from mingpt_distributed_tpu.training.faults import (
     LinkPartitioned,
     NetworkFaultInjector,
 )
+from oracles import solo_greedy
 
 
 @pytest.fixture(scope="module")
@@ -78,11 +78,6 @@ def cfg_params():
         embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32",
     )
     return cfg, gpt.init(jax.random.key(0), cfg)
-
-
-def solo_greedy(params, cfg, prompt, n):
-    out = gen.generate(params, cfg, jnp.asarray(prompt, jnp.int32)[None], n)
-    return np.asarray(out)[0, len(prompt):].tolist()
 
 
 def _samples(page_or_registry, family):

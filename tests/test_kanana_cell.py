@@ -13,21 +13,22 @@ import jax
 import numpy as np
 import pytest
 
+import stacks
 from benchmarks import rehearse
-from benchmarks.harness import check, compiles, serve_cell, spec
+from benchmarks.harness import check, serve_cell, spec
+from stack_contract import (  # noqa: F401
+    cell_run, stack,
+    test_the_cell_agrees_with_its_reference_through_the_whole_path)
 
-CELL = "kanana-2-30b-a3b.serve-long-decode"
-SEED = 2_500_000_001        # past 32 signed bits, as the driver's seeds are
+#: the latent stack's entry, for its cell (three layers: the dense one and
+#: two that route, ``STACK.cell_sizes``) and the cell's own assertions
+STACK = dataclasses.replace(stacks.LATENT, seed=2_500_000_001)
 NEW_READERS = ("moe.load_max_over_mean", "moe.dropped_rows",
                "moe.rows_per_expert_round", "kv.bytes_per_live_token")
 
 
-def tiny_cell(**sizes) -> spec.Cell:
-    return rehearse.tiny(spec.load_cell(CELL), sizes=sizes or None)
-
-
 def test_the_configuration_holds_the_published_widths():
-    cell = spec.load_cell(CELL)
+    cell = spec.load_cell(STACK.cell)
     config = cell.config
     assert config["reduced"] == ["num_hidden_layers", "max_position_embeddings"]
     assert (config["hidden_size"], config["kv_lora_rank"],
@@ -45,7 +46,7 @@ def test_the_configuration_holds_the_published_widths():
 
 
 def test_tiny_shrinks_every_size_the_reference_reads():
-    cell = tiny_cell()
+    cell = stacks.tiny_cell(STACK)
     cfg = spec.gpt_config(cell, training=False)
     assert (cfg.n_layer, cfg.n_dense_layers, cfg.kv_lora_rank,
             cfg.qk_head_dim, cfg.dense_width, cfg.expert_width,
@@ -54,32 +55,6 @@ def test_tiny_shrinks_every_size_the_reference_reads():
     assert (config["kv_lora_rank"], config["qk_rope_head_dim"],
             config["head_dim"], config["n_routed_experts"],
             config["intermediate_size"]) == (32, 8, 8, 8, 192)
-
-
-@pytest.fixture(scope="module")
-def cell_run():
-    return serve_cell.run(
-        tiny_cell(n_layer=3), seed=SEED, seconds=1.0, traced=False,
-        devices=jax.devices()[:1], t_process=0.0,
-        compiles=compiles.CompileCounter())
-
-
-def test_the_cell_agrees_with_its_reference_through_the_whole_path(cell_run):
-    """bfloat16 weights and activations, three layers (the dense one and two
-    that route), the engine's own prefill and decode programs: every layer's
-    rotated rope keys and normed latents inside the dense law once the
-    reference has followed the program's routes, no program compiled in the
-    window."""
-    verdict = cell_run["verdict"]
-    assert verdict["ok"], verdict
-    assert verdict["compiled_in_window"] == 0
-    assert len(verdict["cases"]) == 3
-    for case in verdict["cases"]:
-        assert len(case["k_rel_layers"]) == len(case["v_rel_layers"]) == 3
-        assert max(case["k_rel_layers"] + case["v_rel_layers"]) \
-            <= verdict["kv_rel_tol"]
-        assert case["route_banded_layers"][0] == 0      # the dense layer
-    assert cell_run["failed"] == 0 and cell_run["attempted"] > 0
 
 
 def test_the_counters_reach_the_readers(cell_run):
@@ -131,7 +106,7 @@ def test_the_read_row_share_reader_reads_the_scheduler_s_counters(cell_run):
     assert closed["decode_rows_read"] == closed["decode_rows_reserved"]
     traced = dataclasses.replace(run, trace_open=opened, trace_close=closed)
     assert read({"play": traced}) == 100.0
-    cell = spec.load_cell(CELL)
+    cell = spec.load_cell(STACK.cell)
     assert "kv.read_row_share" in {m["name"] for m in cell.per_layer}
     for other in ("gpt2-124m.serve-decode", "minicpm-sala.serve-long-context"):
         assert "kv.read_row_share" not in {
@@ -167,7 +142,7 @@ def test_the_blocks_run_share_reader_reads_the_experts_counters(cell_run):
     assert closed["moe_blocks_laid"] > opened["moe_blocks_laid"]
     traced = dataclasses.replace(run, trace_open=opened, trace_close=closed)
     assert 0.0 < read({"play": traced}) < 100.0
-    cell = spec.load_cell(CELL)
+    cell = spec.load_cell(STACK.cell)
     assert "moe.blocks_run_share" in {m["name"] for m in cell.per_layer}
     for other in ("gpt2-124m.serve-decode", "gpt2-xl.serve-prefill",
                   "minicpm-sala.serve-long-context"):
@@ -179,7 +154,7 @@ def test_the_new_readers_return_none_for_a_dense_cell():
     """On a cell of the parent's (or the parent itself, whose summary lacks
     the fields) there is nothing to read, and no reader raises."""
     cell = rehearse.tiny(spec.load_cell("gpt2-124m.serve-decode"))
-    driver = serve_cell.Driver(cell, SEED, traced=False)
+    driver = serve_cell.Driver(cell, STACK.seed, traced=False)
     reading = driver._counters()
     play = serve_cell.Play(n_slots=4, block_size=128, trace_rounds=3,
                            trace_live_rows=30, trace_open=reading,
@@ -198,9 +173,9 @@ def test_a_lower_precision_fails_the_verdict():
     """The nearest precision below the one the configuration states: the
     reference's own cached rows rounded to 8-bit floats before they are
     compared (what an fp8 pool would hold) lie outside the law."""
-    cell = tiny_cell(n_layer=3)
+    cell = stacks.tiny_cell(STACK, n_layer=3)
     reference = spec.load_reference(cell.config)
-    driver = serve_cell.Driver(cell, SEED, traced=False)
+    driver = serve_cell.Driver(cell, STACK.seed, traced=False)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 384, size=n, dtype=np.int32) for n in (24, 40)]
     good = check.serve_verdict(reference, cell.config, driver.server,
@@ -242,7 +217,7 @@ def test_a_lower_precision_fails_the_verdict():
 
 
 def test_rehearse_runs_the_cell_and_prints_its_routes(capsys):
-    rehearse.rehearse_run(spec.load_cell(CELL))
+    rehearse.rehearse_run(spec.load_cell(STACK.cell))
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert line["agrees_with_reference"] is True and line["failed"] == 0
     assert line["compiled_in_window"] == 0

@@ -6,89 +6,48 @@ kind, a gate a head, and the dropless route under softmax scores, against
 the plain reference ``benchmarks/references/laguna.py``: through
 ``gpt.forward``, the cached forward, ``DecodeEngine`` and
 ``InferenceServer``, and the benchmark's cell through the path the driver
-runs."""
+runs. What every served family proves is ``tests/stack_contract.py``'s; here
+is what is peculiar to this one."""
 
 import dataclasses
-import json
 import math
-import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import rehearse
-from benchmarks.harness import check, compiles, serve_cell, spec
-from mingpt_distributed_tpu.config import (
-    FULL_ATTN, WINDOW_ATTN, ConfigError, GPTConfig)
+import stacks
+from benchmarks.harness import serve_cell, spec
+from mingpt_distributed_tpu.config import FULL_ATTN, WINDOW_ATTN, ConfigError
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.ops import moe
-from mingpt_distributed_tpu.serving import InferenceServer, Request
+from mingpt_distributed_tpu.serving import InferenceServer
 from mingpt_distributed_tpu.serving import engine as engine_lib
 from mingpt_distributed_tpu.serving.engine import DecodeEngine
+from oracles import solo_greedy
+from stack_contract import (  # noqa: F401
+    cell_run, model, pytest_generate_tests, reference, stack,
+    test_a_planted_fault_reads_not_ok,
+    test_combinations_that_are_not_built_are_refused_with_a_sentence,
+    test_in_bfloat16_the_engine_holds_the_check_s_law,
+    test_the_cached_path_is_the_uncached_forward,
+    test_the_cell_agrees_with_its_reference_through_the_whole_path,
+    test_the_configuration_file_holds_the_published_widths,
+    test_the_full_forward_is_the_reference_s,
+    test_the_manifest_lists_the_cell_where_it_reports,
+    test_the_slot_and_the_weights_are_the_size_the_configuration_states,
+    test_training_and_a_split_mesh_are_refused_by_the_forward)
+from stacks import WINDOW, tokens_of
 
-CELL = "laguna-xs.2.serve-long-decode"
-SEED = 2_500_000_001        # past 32 signed bits, as the driver's seeds are
-WINDOW = 16
+STACK = stacks.LAGUNA
 NEW_READERS = ("attention.ring_ms_per_step", "kv.ring_bytes_per_slot",
                "kv.ring_read_row_share")
 
 
-def tiny_cell() -> spec.Cell:
-    return rehearse.tiny(spec.load_cell(CELL))
-
-
-def tiny_cfg(**over) -> GPTConfig:
-    """The cell's own program at ``rehearse.tiny``'s size: five layers (full
-    and dense; window, window, window; full), 6 and 8 query heads over 2 KV
-    heads of 16, a window of 16, 2 of 8 experts beside a shared one."""
-    gpt_config = tiny_cell().config["program"]["gpt_config"]
-    return GPTConfig.make(**{**gpt_config, "dtype": "float32",
-                             "param_dtype": "float32", **over})
-
-
-def sizes_of(cfg: GPTConfig) -> dict:
-    """What the reference reads of a configuration file, from the program's
-    config: the cell's own ``key_map``, applied as ``rehearse.tiny`` does."""
-    key_map = spec.load_cell(CELL).config["program"]["key_map"]
-    return {published: getattr(cfg, field)
-            for published, field in key_map.items()}
-
-
-@pytest.fixture(scope="module")
-def reference():
-    return spec.load_reference(spec.load_cell(CELL).config)
-
-
-@pytest.fixture(scope="module")
-def model():
-    cfg = tiny_cfg()
-    return cfg, gpt.init(jax.random.key(3), cfg)
-
-
-def tokens_of(cfg, batch, t, seed=1):
-    return jax.random.randint(jax.random.key(seed), (batch, t), 0,
-                              cfg.vocab_size)
-
-
 # -- the program against the reference, float32 ------------------------------
-
-def test_the_full_forward_is_the_reference_s(reference, model):
-    cfg, params = model
-    toks = tokens_of(cfg, 2, 100)
-    logits, loss = gpt.forward(params, toks, cfg, targets=toks)
-    w = reference.weights_from_program(params)
-    x, ks, vs, router = reference.hidden(w, toks, sizes_of(cfg))
-    assert reference.cached_layers(sizes_of(cfg)) == (0, 4)
-    assert ks.shape == vs.shape == (2, 2, 100, cfg.kv_heads, cfg.head_dim)
-    assert router.shape == (5, 2, 100, cfg.n_experts)
-    np.testing.assert_allclose(logits, reference.logits(w, x), atol=2e-6)
-    np.testing.assert_allclose(
-        loss, reference.loss(w, toks, toks, sizes_of(cfg)), atol=1e-5)
-
 
 def test_the_reference_s_experts_are_every_expert_under_a_zero_gate(
         reference, model):
@@ -127,37 +86,28 @@ def test_prefill_then_decode_through_the_cache_is_the_reference_s_forward(
     cfg, params = model
     n = n_prompt + steps
     assert n > 2 * WINDOW
-    toks = tokens_of(cfg, 2, n)
+    toks = tokens_of(cfg, 2, cfg.block_size)
     w = reference.weights_from_program(params)
-    x, ks, vs, _ = reference.hidden(w, toks, sizes_of(cfg))
-    ref_logits = reference.logits(w, x)
+    programs = stacks.reference_programs(STACK, stacks.sizes_of(STACK, cfg))
+    # one program for the three cases: what stands after position n the
+    # reference's answers before it do not read
+    x, ks, vs, _ = programs.hidden(w, toks)
+    ref_logits = np.asarray(programs.logits(w, x))
     cache = gen.init_cache(cfg, 2)
     assert cache[gen.RING_K].shape == (3, 2, WINDOW, 1, 2 * 16)
     assert cache["k"].shape == (2, 2, cfg.block_size, 1, 2 * 16)
-    forward = jax.jit(lambda toks, cache, offset: gen._forward_cached(
-        params, toks, cache, offset, cfg))
-    logits, cache = forward(toks[:, :n_prompt], cache, 0)
+    logits, cache = stacks.forward_cached(
+        params, toks[:, :n_prompt], cache, 0, cfg)
     np.testing.assert_allclose(logits, ref_logits[:, n_prompt - 1], atol=2e-6)
     for i in range(n_prompt, n):
-        logits, cache = forward(toks[:, i:i + 1], cache, jnp.full((2,), i))
+        logits, cache = stacks.forward_cached(
+            params, toks[:, i:i + 1], cache, np.full((2,), i), cfg)
         np.testing.assert_allclose(logits, ref_logits[:, i], atol=2e-6)
     # a row keeps its two heads side by side: the same numbers in order
     for name, rows in (("k", ks), ("v", vs)):
+        want = rows[:, :, :n]
         np.testing.assert_allclose(
-            cache[name][:, :, :n].reshape(rows.shape), rows, atol=1e-5)
-
-
-def test_the_cached_path_is_the_uncached_forward(model):
-    """``gpt.forward`` without a cache (every row, the window layers masked
-    by age) against solo ``generate`` (a prefill, then steps of one token
-    under one offset, the ring read rolled into the order of its
-    positions)."""
-    cfg, params = model
-    toks = tokens_of(cfg, 2, 12)
-    out = gen.generate(params, cfg, toks, 50)
-    logits, _ = gpt.forward(params, out[:, :-1], cfg)
-    np.testing.assert_array_equal(
-        out[:, 12:], jnp.argmax(logits[:, 11:], -1))
+            cache[name][:, :, :n].reshape(want.shape), want, atol=1e-5)
 
 
 def test_a_chunk_that_ends_in_padding_leaves_its_real_rows_in_the_ring(model):
@@ -166,9 +116,9 @@ def test_a_chunk_that_ends_in_padding_leaves_its_real_rows_in_the_ring(model):
     alone."""
     cfg, params = model
     toks = tokens_of(cfg, 1, 64)
-    valid = (jnp.arange(64) < 37)[None]
-    prefill = jax.jit(lambda toks, valid: gen._forward_cached_hidden(
-        params, toks, gen.init_cache(cfg, 1), 0, cfg, valid))
+    valid = (np.arange(64) < 37)[None]
+    prefill = lambda toks, valid: stacks.forward_cached_hidden(
+        params, toks, gen.init_cache(cfg, 1), 0, cfg, valid)
     _, padded = prefill(toks, valid)
     _, exact = prefill(toks[:, :37], None)
     for name in gen.RINGS:
@@ -337,17 +287,11 @@ def test_the_server_serves_mixed_lengths_and_a_freed_slot_shows_nothing(model):
     than the window: what the ring held is masked by age), greedy tokens
     those of solo ``generate``."""
     cfg, params = model
-    server = InferenceServer(params, cfg, n_slots=2, prefill_len=64,
-                             prefill_buckets=[32, 64], warmup=True)
+    server = InferenceServer(params, cfg, **STACK.serve, warmup=True)
     prompts = [tokens_of(cfg, 1, n, seed=n)[0].tolist()
                for n in (60, 5, 33, 9, 17)]
-    handles = [server.submit(Request(prompt=p, max_new_tokens=40,
-                                     do_sample=False)) for p in prompts]
-    while server.step():
-        pass
-    for p, h in zip(prompts, handles):
-        solo = gen.generate(params, cfg, jnp.asarray([p]), 40)[0, len(p):]
-        assert h.tokens == solo.tolist()
+    for p, tokens in zip(prompts, stacks.serve(server, prompts, 40)):
+        assert tokens == solo_greedy(params, cfg, p, 40)
     s = server.metrics.summary()
     eng = server.engine
     # three rings of keys and of values: 16 rows of 2 heads of 16, float32
@@ -380,19 +324,13 @@ def test_the_server_s_rings_and_rows_through_the_kernel_s_walk(
     live."""
     walk_in_blocks(8, kernel=True)
     cfg, params = model
-    server = InferenceServer(params, cfg, n_slots=2, prefill_len=64,
-                             prefill_buckets=[32, 64], warmup=True)
+    server = InferenceServer(params, cfg, **STACK.serve, warmup=True)
     assert server.engine.walk.kernel and server.engine.ring_walk.kernel
     assert server.engine.ring_walk == (WINDOW, 1 << 40, 8, True)
     prompts = [tokens_of(cfg, 1, n, seed=n)[0].tolist()
                for n in (60, 5, 33, 9, 17)]
-    handles = [server.submit(Request(prompt=p, max_new_tokens=40,
-                                     do_sample=False)) for p in prompts]
-    while server.step():
-        pass
-    for p, h in zip(prompts, handles):
-        solo = gen.generate(params, cfg, jnp.asarray([p]), 40)[0, len(p):]
-        assert h.tokens == solo.tolist()
+    for p, tokens in zip(prompts, stacks.serve(server, prompts, 40)):
+        assert tokens == solo_greedy(params, cfg, p, 40)
     s = server.metrics.summary()
     assert s["decode_kernel_walk_layers"] == cfg.n_layer == 5
     assert s["ring_planes"] == server.engine.ring_planes == 3
@@ -421,32 +359,8 @@ def test_the_ring_s_counters_follow_the_program_s_rule():
     assert (read, inside) == (512 + 256 + 512 + 512, 511 + 100 + 511 + 300)
 
 
-# -- what is not built is refused, a sentence each ---------------------------
-
-@pytest.mark.parametrize("over, sentence", [
-    (dict(layer_types=["full_attention"] * 4), "for each of the 5 layers"),
-    (dict(layer_types=["sliding_attention"] * 5), "needs a full attention"),
-    (dict(attention_window=None), "set it"),
-    (dict(attention="flash"), "built for attention='einsum'"),
-    (dict(rmsnorm=False), "needs rope, rmsnorm and swiglu"),
-    (dict(window_n_head=7), "not divisible by the 2 KV heads"),
-    (dict(rope_fraction=0.2), "an even number of them"),
-    (dict(rope_yarn=[1.0, 4096, 64, 1, 1.0]), "rope_yarn is"),
-    (dict(head_size=0), "it is positive"),
-    (dict(post_norms=True), "are not written for it"),
-    (dict(pp_microbatches=2), "is not pipelined"),
-    (dict(rope_interleave=True), "no rope_interleave"),
-    (dict(moe_dropless=False, n_shared_experts=0, moe_route_scale=1.0),
-     "routes without dropping"),
-    (dict(moe_dropless=False), "built for the dropless route only"),
-    (dict(moe_norm_topk=False), "renormalises the chosen experts'"),
-    (dict(layer_types=None), "belong to a stack of layer_types"),
-])
-def test_combinations_that_are_not_built_are_refused_with_a_sentence(
-        over, sentence):
-    with pytest.raises(ConfigError, match=sentence):
-        tiny_cfg(**over)
-
+# -- what is not built is refused: the config's sentences are the contract's,
+# by ``STACK.refused``; the engine's are the ring's own -----------------------
 
 @pytest.mark.parametrize("how, sentence", [
     (dict(kv_dtype="int8"), "no scale for a ring"),
@@ -474,179 +388,7 @@ def test_speculation_and_migration_over_a_ring_are_refused(model):
         eng.extract_slot_rows(0, eng.buckets[0])
 
 
-def test_training_and_a_split_mesh_are_refused_by_the_forward(model):
-    cfg, params = model
-    toks = tokens_of(cfg, 1, 16)
-    with pytest.raises(NotImplementedError, match="not trained"):
-        gpt.forward(params, toks, cfg, rng=jax.random.key(0),
-                    deterministic=False)
-    mesh = jax.sharding.Mesh(
-        np.asarray(jax.devices()[:2]).reshape(2), ("tp",))
-    with pytest.raises(NotImplementedError, match="not split over pp or tp"):
-        gpt.forward(params, toks, cfg, mesh=mesh)
-
-
-# -- precision: what the check lets through and what it does not -------------
-
-def bf16_model():
-    cfg = tiny_cfg(dtype="bfloat16", param_dtype="bfloat16")
-    return cfg, gpt.init(jax.random.key(3), cfg)
-
-
-def verdict_of(reference, cfg, params, sizes, weights=None):
-    """``check.serve_verdict`` over three prompts that wrap the window,
-    eight decode steps each. ``weights``: what the reference computes with,
-    where the program's tree is not the model's (a planted fault)."""
-    if weights is not None:
-        reference = types.SimpleNamespace(**{
-            **vars(reference), "weights_from_program": lambda _: weights})
-    server = InferenceServer(params, cfg, n_slots=2, prefill_len=64,
-                             prefill_buckets=[32, 64], warmup=True)
-    rng = np.random.default_rng(SEED)
-    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
-               for n in (21, 40, 60)]
-    return check.serve_verdict(reference, sizes, server, prompts, 8)
-
-
-def test_in_bfloat16_the_engine_holds_the_check_s_law(reference):
-    cfg, params = bf16_model()
-    verdict = verdict_of(reference, cfg, params, sizes_of(cfg))
-    assert verdict["ok"], json.dumps(verdict)[:2000]
-    for case in verdict["cases"]:
-        assert len(case["k_rel_layers"]) == 2      # the full layers' planes
-        assert len(case["route_margin_layers"]) == 5
-        assert set(case["compared"]) >= {"k_in_8bit", "v_in_8bit"}
-
-
-def _no_gate(monkeypatch, cfg, params):
-    real = gpt.attention_out
-    monkeypatch.setattr(gpt, "attention_out", lambda att, blk, *a, **kw: real(
-        att, {n: v for n, v in blk.items() if n != "w_hg"}, *a, **kw))
-    return cfg
-
-
-def _sigmoid_gates(monkeypatch, cfg, params):
-    def routes(h, w_router, *, top_k, route_scale):
-        z = h.astype(jnp.float32) @ w_router.astype(jnp.float32)
-        chosen = jax.lax.top_k(z, top_k)[1]
-        gates = jnp.take_along_axis(jax.nn.sigmoid(z), chosen, axis=-1)
-        return (chosen.astype(jnp.int32),
-                gates / gates.sum(-1, keepdims=True) * route_scale, z)
-
-    monkeypatch.setattr(moe, "softmax_routes", routes)
-    return cfg
-
-
-def _window_one_row_short(monkeypatch, cfg, params):
-    return dataclasses.replace(cfg, attention_window=WINDOW - 1)
-
-
-def _the_other_kind_s_rotation(monkeypatch, cfg, params):
-    real = GPTConfig.rope_spec
-    other = {FULL_ATTN: WINDOW_ATTN, WINDOW_ATTN: FULL_ATTN}
-    monkeypatch.setattr(GPTConfig, "rope_spec",
-                        lambda self, kind=None: real(self, other[kind]))
-    return cfg
-
-
-def _unscaled_routed_sum(monkeypatch, cfg, params):
-    return dataclasses.replace(cfg, moe_route_scale=1.0)
-
-
-@pytest.mark.parametrize("plant", [
-    _no_gate, _sigmoid_gates, _window_one_row_short,
-    _the_other_kind_s_rotation, _unscaled_routed_sum],
-    ids=lambda f: f.__name__.strip("_"))
-def test_a_planted_fault_reads_not_ok(reference, monkeypatch, plant):
-    """The tiny cell's program with one thing wrong, against the reference
-    under the true sizes and the same weights: the verdict is not ``ok``."""
-    cfg, params = bf16_model()
-    sizes = sizes_of(cfg)
-    faulty = plant(monkeypatch, cfg, params)
-    verdict = verdict_of(reference, faulty, params, sizes,
-                         reference.weights_from_program(params))
-    assert not verdict["ok"], json.dumps(verdict["cases"][0]["compared"])
-
-
 # -- the configuration file and the cell ---------------------------------------
-
-def test_the_configuration_file_holds_the_published_widths():
-    cell = spec.load_cell(CELL)
-    config = cell.config
-    assert config["reduced"] == [
-        "num_hidden_layers", "layer_types", "mlp_layer_types",
-        "num_attention_heads_per_layer", "max_position_embeddings"]
-    assert (config["hidden_size"], config["head_dim"],
-            config["num_key_value_heads"], config["num_attention_heads"],
-            config["intermediate_size"], config["num_experts"],
-            config["num_experts_per_tok"], config["moe_intermediate_size"],
-            config["shared_expert_intermediate_size"], config["sliding_window"],
-            config["vocab_size"], config["moe_routed_scaling_factor"]) == (
-        2048, 128, 8, 48, 8192, 256, 8, 512, 512, 512, 100352, 2.5)
-    assert config["num_attention_heads_per_layer"] == [48, 64, 64, 64, 48]
-    assert config["rope_parameters"]["full_attention"]["factor"] == 64
-    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
-        rows = [json.loads(line) for line in f]
-    published = next(r for r in rows if r["name"] == "Laguna-XS.2")["config"]
-    assert config["source"] == next(
-        r for r in rows if r["name"] == "Laguna-XS.2")["source_url"]
-    for key, value in published.items():
-        if key in config["reduced"]:
-            n = config["num_hidden_layers"]
-            assert config[key] == (value[:n] if isinstance(value, list)
-                                   else config[key])
-        else:
-            assert config[key] == value, key
-    cfg = spec.gpt_config(cell, training=False)
-    assert cfg.param_dtype == cfg.dtype == "bfloat16"
-    assert spec.server_options(cell) == {
-        "prefill_len": 4096, "prefill_buckets": [1024, 2048, 4096],
-        "n_slots": cell.found["server"]["n_slots"]}
-    for key in ("weights", "router scoring", "norm_topk_prob", "gating"):
-        assert key in config["assumed"]
-    wrong = dataclasses.replace(cell, config=dict(config, sliding_window=256))
-    with pytest.raises(spec.SpecError, match="sliding_window"):
-        spec.gpt_config(wrong, training=False)
-    wrong = dataclasses.replace(cell, config=dict(
-        config, num_attention_heads_per_layer=[48] * 5))
-    with pytest.raises(spec.SpecError, match="heads_per_layer"):
-        spec.gpt_config(wrong, training=False)
-
-
-def test_the_slot_and_the_weights_are_the_size_the_configuration_states():
-    cfg = spec.gpt_config(spec.load_cell(CELL), training=False)
-    size = {n: int(np.prod(s)) * 2
-            for n, s in gen.cache_leaf_shapes(cfg, 1).items()}
-    assert size["k"] + size["v"] == 2 * 8192 * 4096           # 67.1 MB
-    assert size[gen.RING_K] + size[gen.RING_V] == 6_291_456   # 3 x 512 x 4 KB
-    assert sum(size.values()) == 73_400_320
-    count = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(
-        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))))
-    assert 3.869e9 < count < 3.871e9
-
-
-@pytest.fixture(scope="module")
-def cell_run():
-    return serve_cell.run(
-        tiny_cell(), seed=SEED, seconds=1.0, traced=False,
-        devices=jax.devices()[:1], t_process=0.0,
-        compiles=compiles.CompileCounter())
-
-
-def test_the_cell_agrees_with_its_reference_through_the_whole_path(cell_run):
-    """bfloat16, the engine's own programs, ``serve_cell.Driver`` and
-    ``check.serve_verdict`` as the driver runs them: the full layers' rows
-    inside the twin's law once the reference has followed the program's
-    routes across the window layers, no program compiled in the window."""
-    verdict = cell_run["verdict"]
-    assert verdict["ok"], verdict
-    assert verdict["compiled_in_window"] == 0
-    assert len(verdict["cases"]) == 3
-    for case in verdict["cases"]:
-        assert len(case["k_rel_layers"]) == len(case["v_rel_layers"]) == 2
-        assert case["route_banded_layers"][0] == 0      # the dense layer
-    assert cell_run["failed"] == 0 and cell_run["attempted"] > 0
-
 
 def test_the_ring_s_counters_reach_the_readers(cell_run):
     play = cell_run["evidence"]["play"]
@@ -696,29 +438,13 @@ def test_the_ring_readers_read_two_readings_of_the_counters():
         {"play": play}) is None                       # no decode step
 
 
-def test_the_manifest_lists_the_cell_where_it_reports():
-    cell = spec.load_cell(CELL)
-    assert [m["name"] for m in cell.end_to_end] == ["itl_p50_ms", "setup_s"]
-    names = {m["name"] for m in cell.per_layer}
-    kanana = {m["name"] for m in spec.load_cell(
-        "kanana-2-30b-a3b.serve-long-decode").per_layer}
-    # kanana's lists but the one whose reader finds nothing to read here:
-    # it takes the experts a layer from ``first_k_dense_replace`` and
-    # ``n_routed_experts``, which this configuration does not publish; and
-    # PR 62's reader of the kernel that walks rows side by side (kanana's
-    # latent pool keeps the XLA walk)
-    assert names == (kanana - {"moe.rows_per_expert_round"}) \
-        | set(NEW_READERS) | {"kernel.rows_attend_roofline"}
-    assert "engine.decode_hbm_roofline" not in names
+def test_the_experts_a_round_reader_finds_nothing_to_read_here():
+    """``moe.rows_per_expert_round`` takes the experts a layer from
+    ``first_k_dense_replace`` and ``n_routed_experts``, which this
+    configuration does not publish: it is not listed for the cell
+    (``STACK.absent_readers``), and asked it reads nothing."""
     play = serve_cell.Play(n_slots=64, block_size=8192)
     play.trace_open = {"moe_routed_rows": 0, "steps": 0}
     play.trace_close = {"moe_routed_rows": 4096, "steps": 8}
     assert spec.load_reader("moe.rows_per_expert_round").read(
-        {"play": play, "cell": cell}) is None
-    # the new readers were listed for this cell and no other; PR 61 appended
-    # the next stack of rings to them
-    manifest = spec.load_manifest()
-    for metric in manifest["per_layer"]:
-        if metric["name"] in NEW_READERS:
-            assert metric["workloads"] == [
-                CELL, "smallthinker-21b-a3b.serve-past-window"]
+        {"play": play, "cell": spec.load_cell(STACK.cell)}) is None

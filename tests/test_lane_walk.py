@@ -20,11 +20,12 @@ import pytest
 
 from mingpt_distributed_tpu.config import GPTConfig, MeshConfig
 from mingpt_distributed_tpu.models import generate as gen
-from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.serving import InferenceServer, Request
 from mingpt_distributed_tpu.serving import engine as engine_mod
+import stacks
+from oracles import solo_greedy
 
 BLOCK, ROWS = 8, 32
 #: a lane at every place a block's edge can catch: nothing to read, one row,
@@ -498,37 +499,16 @@ def test_an_engine_s_program_and_counter_share_one_walk(options, row_bytes,
 
 # -- through the model and the server -------------------------------------------
 
-OFF = dict(embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32")
-TINY = dict(n_layer=2, n_head=4, n_embd=32, vocab_size=64, block_size=ROWS,
-            **OFF)
-ROPE = dict(rope=True, swiglu=True, rmsnorm=True, tie_weights=False)
-MODELS = {
-    "mha": dict(TINY, n_embd=128),
-    "gqa-rope": dict(TINY, n_head=8, n_kv_head=4, n_embd=256, **ROPE),
-    "latent-experts": dict(
-        TINY, rope=True, rope_interleave=True, swiglu=True, rmsnorm=True,
-        tie_weights=False, kv_lora_rank=16, qk_nope_head_dim=8,
-        qk_rope_head_dim=4, v_head_dim=8, n_dense_layers=1, ffn_dim=48,
-        n_experts=8, moe_top_k=2, moe_ffn_dim=16, n_shared_experts=2,
-        moe_scoring="sigmoid", moe_route_scale=2.448),
-    "looped": dict(TINY, n_embd=128, n_passes=2, post_norms=True,
-                   exit_gate=True, **ROPE),
-    "window-softcap": dict(TINY, n_embd=128, attention_window=6,
-                           attn_logit_softcap=3.0),
-}
+#: forms of ``tests/stacks.py``'s table (a block of ``ROWS`` rows)
+MODELS = ("gqa-rope", "latent", "looped", "mha", "window-softcap")
 PROMPTS = [[1, 2, 3, 4, 5], list(range(7, 22)), [10, 11, 12, 13],
            list(range(1, 17)) + [40, 41], list(range(1, 10)), [33]]
 BUDGETS = [9, 4, 7, 5, 12, 3]
 
 
 def model(name):
-    cfg = GPTConfig.make(**MODELS[name])
-    return cfg, gpt.init(jax.random.key(1), cfg)
-
-
-def solo_greedy(params, cfg, prompt, n):
-    out = gen.generate(params, cfg, jnp.asarray(prompt, jnp.int32)[None], n)
-    return np.asarray(out)[0, len(prompt):].tolist()
+    assert stacks.FORMS[name]["block_size"] == ROWS
+    return stacks.form_model(name)
 
 
 def served(cfg, params, tp=None, **options):
@@ -556,7 +536,7 @@ def served(cfg, params, tp=None, **options):
 
 
 @pytest.mark.parametrize("cost", sorted(COSTS))
-@pytest.mark.parametrize("name", sorted(MODELS))
+@pytest.mark.parametrize("name", MODELS)
 def test_served_tokens_are_solo_generate_s(name, cost, walk_in_blocks):
     """Greedy tokens of every request are solo ``generate``'s (the parent's
     served tokens, by its own tests), staggered admission and a freed and

@@ -5,7 +5,6 @@ size on the CPU, seeded weights: the program against the plain reference
 absorbed cached forward against the published form, the route's contract,
 and the latent pool through the serving paths that move rows about."""
 
-import dataclasses
 import re
 
 import jax
@@ -13,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.harness import spec
+import stacks
 from mingpt_distributed_tpu.config import ConfigError, GPTConfig, MeshConfig
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
@@ -22,52 +21,45 @@ from mingpt_distributed_tpu.ops import moe
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.serving import InferenceServer, Request
 from mingpt_distributed_tpu.serving.engine import DecodeEngine
+from oracles import solo_greedy
+from stack_contract import (  # noqa: F401
+    pytest_generate_tests, stack, test_a_planted_fault_reads_not_ok,
+    test_combinations_that_are_not_built_are_refused_with_a_sentence,
+    test_in_bfloat16_the_engine_holds_the_check_s_law,
+    test_the_preset_is_the_published_model)
 
-VOCAB, BLOCK = 211, 64
-TINY = dict(
-    n_layer=3, n_head=4, n_embd=64, vocab_size=VOCAB, block_size=BLOCK,
-    embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, attention="einsum",
-    rope=True, rope_theta=500.0, rope_interleave=True, rmsnorm=True,
-    swiglu=True, norm_eps=1e-6, tie_weights=False,
-    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
-    n_dense_layers=1, ffn_dim=96, n_experts=8, moe_top_k=3, moe_ffn_dim=24,
-    n_shared_experts=2, moe_scoring="sigmoid", moe_route_scale=2.448)
-#: the published keys the reference reads, as the tiny program has them
-SIZES = dict(
-    num_attention_heads=4, kv_lora_rank=32, qk_nope_head_dim=16,
-    qk_rope_head_dim=8, v_head_dim=16, rope_theta=500.0,
-    rope_interleave=True, rms_norm_eps=1e-6, first_k_dense_replace=1,
-    num_experts_per_tok=3, norm_topk_prob=True, routed_scaling_factor=2.448,
-    scoring_func="sigmoid", q_lora_rank=None, n_group=1, topk_group=1)
+STACK = stacks.LATENT
+VOCAB, BLOCK = stacks.LATENT_VOCAB, stacks.LATENT_BLOCK
+TINY, SIZES = stacks.LATENT_GPT, stacks.LATENT_SIZES
 
 
 @pytest.fixture(scope="module")
 def reference():
-    return spec.load_reference({"reference": "references/deepseek_v3.py"})
+    return stacks.reference_of(STACK)
 
 
-def model(dtype="float32", param_dtype="float32", **over):
+@pytest.fixture(scope="module")
+def programs():
+    return stacks.reference_programs(STACK, SIZES)
+
+
+def model(**over):
     """Config and parameters with the norms' scales and the bias off their
-    initial values, where a factor left out would not show."""
-    cfg = GPTConfig.make(**{**TINY, "dtype": dtype, "param_dtype": param_dtype,
-                            **over})
-    params = gpt.init(jax.random.key(0), cfg)
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.key(2), len(leaves))
-    leaves = [(a + 0.01 * jax.random.normal(k, a.shape)).astype(a.dtype)
-              for a, k in zip(leaves, keys)]
-    return cfg, jax.tree.unflatten(tree, leaves)
+    initial values, where a factor left out would not show
+    (``STACK.perturbed``); the same objects every time."""
+    return stacks.model(STACK, **over)
 
 
-def tokens_of(n, batch=2, seed=3):
-    return jax.random.randint(jax.random.key(seed), (batch, n), 0, VOCAB)
+def tokens(n, batch=2, seed=3):
+    return stacks.tokens_of(model()[0], batch, n, seed)
 
 
 # -- the reference and the uncached forward ----------------------------------
 
 @pytest.mark.parametrize("told", [False, True],
                          ids=["own-choice", "told-its-own-choice"])
-def test_the_reference_is_the_program_s_forward_in_float32(reference, told):
+def test_the_reference_is_the_program_s_forward_in_float32(reference,
+                                                           programs, told):
     """Two implementations of one set of equations, float32 on both sides:
     logits to 2e-5 and the loss to 1e-5 relative (sums in another order:
     the reference's experts run masked in blocks, the program's grouped).
@@ -76,24 +68,24 @@ def test_the_reference_is_the_program_s_forward_in_float32(reference, told):
     one), and a table that holds the reference's own choice changes
     nothing."""
     cfg, params = model()
-    tokens = tokens_of(40)
-    targets = jnp.where(jnp.arange(40) % 5 == 0, -1, jnp.roll(tokens, -1, 1))
-    want_logits, want_loss = gpt.forward(params, tokens, cfg, targets=targets)
+    seq = tokens(40)
+    targets = np.where(np.arange(40) % 5 == 0, -1, np.roll(seq, -1, 1))
+    want_logits, want_loss = stacks.forward(params, seq, cfg, targets=targets)
     weights = reference.weights_from_program(params)
-    x, ks, vs, router = reference.hidden(weights, tokens, SIZES)
+    x, ks, vs, router = programs.hidden(weights, seq)
     assert router.shape == (3, 2, 40, 8) and router.dtype == np.float32
     dense_row = np.asarray(router[0, 0, 0])
     assert dense_row.tolist() == [1, 1, 1, -1, -1, -1, -1, -1]
     if told:
         own = np.argsort(-np.asarray(router), -1, kind="stable")[..., :3]
-        x, ks, vs, again = reference.hidden(
-            weights, tokens, SIZES, experts=own[..., ::-1].astype(np.int32))
+        x, ks, vs, again = programs.hidden(
+            weights, seq, own[..., ::-1].astype(np.int32))
         np.testing.assert_allclose(again, router, atol=1e-6)
-    np.testing.assert_allclose(reference.logits(weights, x), want_logits,
+    np.testing.assert_allclose(programs.logits(weights, x), want_logits,
                                atol=2e-5)
     assert ks.shape == (3, 2, 40, 1, 8) and vs.shape == (3, 2, 40, 1, 32)
     np.testing.assert_allclose(
-        reference.loss(weights, tokens, targets, SIZES), want_loss, rtol=1e-5)
+        programs.loss(weights, seq, targets), want_loss, rtol=1e-5)
 
 
 def test_the_reference_refuses_what_it_does_not_write(reference):
@@ -102,7 +94,7 @@ def test_the_reference_refuses_what_it_does_not_write(reference):
     for key, value in (("q_lora_rank", 16), ("n_group", 2),
                        ("scoring_func", "softmax")):
         with pytest.raises(ValueError):
-            reference.hidden(weights, tokens_of(8), {**SIZES, key: value})
+            reference.hidden(weights, tokens(8), {**SIZES, key: value})
 
 
 @pytest.mark.parametrize("what", ["dense_blocks.w_down", "blocks.w_sd",
@@ -111,12 +103,12 @@ def test_every_part_of_the_mlp_is_in(what):
     """Zeroing the dense first layer's MLP, the shared expert, the routed
     experts or the bias (which moves the choice) changes the output."""
     cfg, params = model()
-    tokens = tokens_of(24)
-    base, _ = gpt.forward(params, tokens, cfg)
-    stack, leaf = what.split(".")
-    without = {**params, stack: {**params[stack], leaf: jnp.zeros_like(
-        params[stack][leaf])}}
-    got, _ = gpt.forward(without, tokens, cfg)
+    seq = tokens(24)
+    base, _ = stacks.forward(params, seq, cfg)
+    part, leaf = what.split(".")
+    without = {**params, part: {**params[part], leaf: jnp.zeros_like(
+        params[part][leaf])}}
+    got, _ = stacks.forward(without, seq, cfg)
     assert float(jnp.abs(got - base).max()) > 1e-4
 
 
@@ -137,15 +129,16 @@ def test_interleaved_rope_turns_neighbours_and_half_split_does_not():
     np.testing.assert_allclose(got[:, 0], np.asarray(x)[:, 0], atol=1e-7)
 
 
-def test_a_half_split_rope_disagrees_with_the_reference(reference):
+def test_a_half_split_rope_disagrees_with_the_reference(reference, programs):
     cfg, params = model(rope_interleave=False)
-    tokens = tokens_of(24)
-    logits, _ = gpt.forward(params, tokens, cfg)
+    seq = tokens(24)
+    logits, _ = stacks.forward(params, seq, cfg)
     weights = reference.weights_from_program(params)
-    ref = reference.logits(weights, reference.hidden(weights, tokens, SIZES)[0])
+    ref = programs.logits(weights, programs.hidden(weights, seq)[0])
     assert float(jnp.abs(ref - logits).max()) > 1e-3
-    same = reference.logits(weights, reference.hidden(
-        weights, tokens, {**SIZES, "rope_interleave": False})[0])
+    half = stacks.reference_programs(
+        STACK, {**SIZES, "rope_interleave": False})
+    same = half.logits(weights, half.hidden(weights, seq)[0])
     np.testing.assert_allclose(same, logits, atol=2e-5)
 
 
@@ -155,29 +148,32 @@ def cached_logits(cfg, params, seq, n_prompt):
     """Prefill ``seq[:n_prompt]`` then decode the rest a token a step at a
     position a lane: the logits after every step, and the cache."""
     cache = gen.init_cache(cfg, 2)
+    # a jit a call: a test calls this before its patch and after it, inside
+    # one epoch of the shared programs
     step = jax.jit(lambda c, t, o: gen._forward_cached(params, t, c, o, cfg))
     logits, cache = step(cache, seq[:, :n_prompt], 0)
     out = [logits]
     for t in range(n_prompt, seq.shape[1]):
-        logits, cache = step(cache, seq[:, t:t + 1], jnp.full((2,), t))
+        logits, cache = step(cache, seq[:, t:t + 1], np.full((2,), t))
         out.append(logits)
     return jnp.stack(out, 1), cache
 
 
-def test_the_absorbed_cached_forward_equals_the_published_form(reference):
+def test_the_absorbed_cached_forward_equals_the_published_form(reference,
+                                                               programs):
     """Prefill and decode attend the cached latents absorbed (queries through
     W_UK, outputs through W_UV); ``gpt.forward`` and the reference take the
     latent up to per-head keys and values. Float32 both: logits to 1e-4
     (another order of the same sums), the cached rows (the rotated rope key
     and the normed latent, nothing else) to 1e-5."""
     cfg, params = model()
-    seq = tokens_of(30)
+    seq = tokens(30)
     got, cache = cached_logits(cfg, params, seq, 20)
-    want, _ = gpt.forward(params, seq, cfg)
+    want, _ = stacks.forward(params, seq, cfg)
     np.testing.assert_allclose(got, want[:, 19:], atol=1e-4)
     weights = reference.weights_from_program(params)
-    x, ks, vs, _ = reference.hidden(weights, seq, SIZES)
-    np.testing.assert_allclose(reference.logits(weights, x)[:, 19:], got,
+    x, ks, vs, _ = programs.hidden(weights, seq)
+    np.testing.assert_allclose(programs.logits(weights, x)[:, 19:], got,
                                atol=1e-4)
     assert cache["k"].shape == (3, 2, BLOCK, 1, 8)
     assert cache["v"].shape == (3, 2, BLOCK, 1, 32)
@@ -189,7 +185,7 @@ def test_a_long_prefill_walks_the_slice_in_blocks(monkeypatch):
     """Past ``LATENT_KV_BLOCK`` rows a chunk attends block by block under a
     running softmax and stops at its own last row: the same numbers."""
     cfg, params = model()
-    seq = tokens_of(40)
+    seq = tokens(40)
     want, _ = cached_logits(cfg, params, seq, 36)
     monkeypatch.setattr(attn_ops, "LATENT_KV_BLOCK", 16)
     got, _ = cached_logits(cfg, params, seq, 36)
@@ -352,7 +348,7 @@ def test_the_engine_s_programs_agree_with_the_reference(reference):
     cfg, params = model()
     eng = DecodeEngine(params, cfg, n_slots=3, prefill_len=32,
                        prefill_buckets=[16, 32])
-    prompt = np.asarray(tokens_of(21, batch=1, seed=5)[0])
+    prompt = np.asarray(tokens(21, batch=1, seed=5)[0])
     tok, _ = eng.prefill_chunk_call(1, prompt.tolist(), 0, 1.0, None, None,
                                     False, 0)
     seq = prompt.tolist() + [tok]
@@ -386,38 +382,16 @@ def test_the_engine_s_programs_agree_with_the_reference(reference):
     assert (counter[:, 11] <= counter[:, 9]).all()
 
 
-@pytest.mark.parametrize("what,ok", [("as-served", True),
-                                     ("a-float32-reference-of-another-rope",
-                                      False)])
-def test_in_bfloat16_the_engine_holds_the_check_s_law(reference, what, ok):
-    """bfloat16 activations and parameters, as kanana is served: one copy of
-    the weights (``cast_once_params`` returns the tree it was given), and
-    ``check.serve_verdict``'s own law with the routes followed: relative row
-    error under 1.1% x (layers / 12)^0.3, logit gap under 0.05
-    (``harness/check.py`` has the reasons). A reference whose rope is
-    half-split fails it."""
-    import types
-
-    from benchmarks.harness import check
-
-    cfg, params = model("bfloat16", "bfloat16")
-    eng = DecodeEngine(params, cfg, n_slots=3, prefill_len=32,
-                       prefill_buckets=[16, 32])
+def test_a_bfloat16_engine_keeps_one_copy_of_the_weights():
+    """bfloat16 activations and parameters, as kanana is served:
+    ``cast_once_params`` returns the tree it was given. (The check's law in
+    bfloat16, and the reference of another rope that fails it, are the
+    contract's, by ``STACK.verdict_lengths`` and ``STACK.faults``.)"""
+    cfg, params = model(**stacks.BF16)
+    eng = DecodeEngine(params, cfg, **STACK.serve)
     assert eng.program_params is eng.params is params
     assert eng.n_cast_leaves == 0
     assert eng.program_param_bytes == 2 * gpt.param_count(params)
-    rng = np.random.default_rng(1)
-    prompts = [rng.integers(0, VOCAB, size=n, dtype=np.int32) for n in (12, 27)]
-    sizes = SIZES if ok else {**SIZES, "rope_interleave": False}
-    verdict = check.serve_verdict(
-        reference, sizes, types.SimpleNamespace(engine=eng), prompts, 4)
-    assert verdict["ok"] is ok, verdict
-    for case in verdict["cases"]:
-        assert len(case["route_banded_layers"]) == 3
-        assert case["route_banded_layers"][0] == 0      # the dense layer
-        if ok:
-            assert max(case["k_rel_layers"] + case["v_rel_layers"]) \
-                <= verdict["kv_rel_tol"]
 
 
 # -- the route ----------------------------------------------------------------
@@ -734,11 +708,11 @@ def test_a_lane_s_output_does_not_depend_on_which_lanes_are_live():
     (to the last bits: a matmul's row may be summed in another order beside
     other rows, nothing more)."""
     x, w_router, bias, w = routed(n=6, seed=4)
-    run = lambda rows: moe.moe_dropless(
+    run = jax.jit(lambda rows: moe.moe_dropless(
         rows[:, None], w_router, bias, w[1], w[0], w[2], top_k=3,
-        route_scale=2.448)[0][:, 0]
-    routes = lambda rows: moe.sigmoid_routes(
-        rows, w_router, bias, top_k=3, norm_topk=True, route_scale=2.448)[:2]
+        route_scale=2.448)[0][:, 0])
+    routes = jax.jit(lambda rows: moe.sigmoid_routes(
+        rows, w_router, bias, top_k=3, norm_topk=True, route_scale=2.448)[:2])
     together = run(x)
     for lane in range(6):
         np.testing.assert_allclose(run(x[lane:lane + 1])[0], together[lane],
@@ -750,26 +724,8 @@ def test_a_lane_s_output_does_not_depend_on_which_lanes_are_live():
         np.testing.assert_array_equal(a[0], b[0])
 
 
-# -- configuration ------------------------------------------------------------
-
-@pytest.mark.parametrize("change,match", [
-    (dict(attention="flash"), "einsum"),
-    (dict(rmsnorm=False), "rope and rmsnorm"),
-    (dict(qk_rope_head_dim=7), "even"),
-    (dict(kv_lora_rank=0), "set kv_lora_rank"),
-    (dict(swiglu=False), "swiglu"),
-    (dict(moe_scoring="softmax"), "sigmoid"),
-    (dict(moe_scoring="tanh"), "unknown moe_scoring"),
-    (dict(n_dense_layers=4), "n_dense_layers"),
-    (dict(n_experts=0, moe_scoring="softmax", n_shared_experts=0,
-          moe_route_scale=1.0), "lead an expert model"),
-    (dict(param_dtype="float16"), "param_dtype"),
-])
-def test_combinations_that_are_not_built_are_refused_with_a_sentence(
-        change, match):
-    with pytest.raises(ConfigError, match=match):
-        GPTConfig.make(**{**TINY, **change})
-
+# -- configuration: the sentences and the preset are the contract's, by
+# ``STACK.refused`` and ``STACK.published`` ------------------------------------------------------------
 
 @pytest.mark.parametrize("how", ["int8-pool", "tp-over-the-latent"])
 def test_the_engine_refuses_what_the_latent_pool_is_not_built_for(how):
@@ -780,25 +736,8 @@ def test_the_engine_refuses_what_the_latent_pool_is_not_built_for(how):
         DecodeEngine(params, cfg, n_slots=2, **kwargs)
 
 
-def test_the_preset_is_the_published_model():
-    cfg = GPTConfig.make(model_type="kanana-2-30b-a3b-instruct-2601")
-    assert (cfg.n_layer, cfg.n_head, cfg.n_embd, cfg.vocab_size,
-            cfg.block_size) == (48, 32, 2048, 128256, 32768)
-    assert (cfg.kv_lora_rank, cfg.qk_head_dim, cfg.v_head_dim,
-            cfg.dense_width, cfg.expert_width) == (512, 192, 128, 6144, 768)
-    assert cfg.param_dtype == "bfloat16" and cfg.moe_scoring == "sigmoid"
-    # the benchmark's configuration is the preset but for its two cuts
-    program = spec.load_cell(
-        "kanana-2-30b-a3b.serve-long-decode").config["program"]["gpt_config"]
-    cut = GPTConfig.make(**program)
-    assert dataclasses.replace(
-        cfg, model_type=None, n_layer=6, block_size=8192) == cut
-    shapes = gen.cache_leaf_shapes(cut, 64)
-    assert shapes == {"k": (6, 64, 8192, 1, 64), "v": (6, 64, 8192, 1, 512)}
-
-
 def test_parameters_are_made_in_param_dtype_and_a_mesh_still_builds():
-    cfg, _ = model("bfloat16", "bfloat16")
+    cfg, _ = model(**stacks.BF16)
     params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
     assert {a.dtype for a in jax.tree.leaves(params)} == {jnp.dtype("bfloat16")}
     assert params["dense_blocks"]["w_gate"].shape == (1, 64, 96)
@@ -809,8 +748,8 @@ def test_parameters_are_made_in_param_dtype_and_a_mesh_still_builds():
     shardings = mesh_lib.param_shardings(mesh, params)
     assert jax.tree.structure(shardings) == jax.tree.structure(params)
     # a bfloat16 draw is the float32 draw rounded, not another stream
-    f32 = gpt.init(jax.random.key(0), dataclasses.replace(
-        cfg, param_dtype="float32"))
+    f32 = gpt.init(jax.random.key(0),
+                   stacks.tiny_cfg(STACK, dtype="bfloat16"))
     bf16 = gpt.init(jax.random.key(0), cfg)
     np.testing.assert_array_equal(
         np.asarray(f32["blocks"]["w_kv_b"].astype(jnp.bfloat16), np.float32),
@@ -829,9 +768,9 @@ def test_the_training_path_takes_the_new_leaves():
     assert mask["blocks"]["w_kv_b"] and mask["blocks"]["w_sd"]
     assert not mask["blocks"]["e_bias"]
     assert not mask["dense_blocks"]["kv_norm_scale"]
-    tokens = tokens_of(24)
-    grads = jax.grad(lambda p: gpt.forward(
-        p, tokens, cfg, targets=jnp.roll(tokens, -1, 1))[1])(params)
+    seq = tokens(24)
+    grads = jax.jit(jax.grad(lambda p: gpt.forward(
+        p, seq, cfg, targets=np.roll(seq, -1, 1))[1]))(params)
     for path, g in jax.tree_util.tree_leaves_with_path(grads):
         assert bool(jnp.isfinite(g).all()), path
     assert float(jnp.abs(grads["blocks"]["w_router"]).max()) > 0
@@ -865,8 +804,7 @@ def test_slots_are_reused_and_the_tokens_are_solo_generate_s(served):
     of the last one's latents, and every greedy stream is ``generate``'s."""
     cfg, params, prompts, server, tokens = served
     for prompt, got in zip(prompts, tokens):
-        solo = gen.generate(params, cfg, np.asarray([prompt]), 6)
-        assert got == np.asarray(solo)[0, len(prompt):].tolist()
+        assert got == solo_greedy(params, cfg, prompt, 6)
     summary = server.metrics.summary()
     assert summary["kv_bytes_per_row"] == 3 * (8 + 32) * 4
     assert summary["moe_dropped_rows"] == 0
@@ -889,8 +827,7 @@ def test_a_request_s_last_step_at_the_window_s_last_row_is_routed():
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, VOCAB, size=n).tolist() for n in (30, 29)]
     new = [BLOCK - len(p) + 1 for p in prompts]
-    want = [np.asarray(gen.generate(params, cfg, np.asarray([p]), n))[
-        0, len(p):].tolist() for p, n in zip(prompts, new)]
+    want = [solo_greedy(params, cfg, p, n) for p, n in zip(prompts, new)]
     for busy in (1, 2):
         server = InferenceServer(params, cfg, n_slots=2, prefill_len=32)
         handles = server.generate_batch([

@@ -15,6 +15,7 @@ from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.ops import layers as L
 from mingpt_distributed_tpu.ops import moe
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
+from oracles import dense_greedy
 
 
 def test_single_expert_equals_dense_mlp():
@@ -146,8 +147,6 @@ def test_moe_generation_matches_dense_oracle():
     Capacity must not bind (factor=E makes cap >= tokens): capacity-dropped
     tokens depend on how many tokens are evaluated together, so incremental
     decode only matches a full re-forward when nothing is dropped."""
-    from tests.test_generate import dense_greedy
-
     cfg = GPTConfig.make(
         n_layer=2, n_head=2, n_embd=32, vocab_size=50, block_size=32,
         embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32",
@@ -155,6 +154,7 @@ def test_moe_generation_matches_dense_oracle():
     )
     params = gpt.init(jax.random.key(0), cfg)
     prompt = jax.random.randint(jax.random.key(1), (2, 5), 0, 50)
+    # room for every token of a call, however many: the padding drops none
     want = dense_greedy(params, cfg, prompt, 8)
     got = gen.generate(params, cfg, prompt, 8)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
@@ -257,8 +257,6 @@ def test_swiglu_single_expert_equals_dense_swiglu():
 def test_mixtral_style_model_trains_and_generates():
     """llama toggles + MoE together (the Mixtral family): forward, loss,
     grads, and KV-cached generation parity."""
-    from tests.test_generate import dense_greedy
-
     cfg = GPTConfig.make(
         n_layer=2, n_head=2, n_embd=32, vocab_size=50, block_size=32,
         embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32",

@@ -12,40 +12,35 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import stacks
 from benchmarks.references import ouro
 from mingpt_distributed_tpu.config import ConfigError, GPTConfig, MeshConfig
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
-from mingpt_distributed_tpu.serving import InferenceServer, Request
+from mingpt_distributed_tpu.serving import InferenceServer
+from oracles import solo_greedy
 from program_digests import cached_digests
 
-PASSES, LAYERS, BLOCK, VOCAB = 4, 3, 64, 96
-LOOPED = dict(n_layer=LAYERS, n_head=4, n_embd=64, vocab_size=VOCAB,
-              block_size=BLOCK, rope=True, rope_theta=1e6, swiglu=True,
-              rmsnorm=True, norm_eps=1e-6, tie_weights=False, ffn_dim=160,
-              n_passes=PASSES, post_norms=True, exit_gate=True,
-              dtype="float32", embd_pdrop=0.0, resid_pdrop=0.0,
-              attn_pdrop=0.0)
-SIZES = dict(num_attention_heads=4, num_key_value_heads=4, head_dim=16,
-             rope_theta=1e6, rms_norm_eps=1e-6, total_ut_steps=PASSES,
-             early_exit_threshold=1)
+STACK = stacks.OURO
+PASSES, LAYERS, BLOCK, VOCAB = (stacks.OURO_PASSES, stacks.OURO_LAYERS,
+                                stacks.OURO_BLOCK, stacks.OURO_VOCAB)
+LOOPED, SIZES = stacks.LOOPED_GPT, stacks.LOOPED_SIZES
 
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = GPTConfig.make(**LOOPED)
-    params = gpt.init(jax.random.key(0), cfg)
-    tokens = jax.random.randint(jax.random.key(1), (2, 24), 0, VOCAB)
-    return cfg, params, tokens
+    cfg, params = stacks.model(STACK)
+    return cfg, params, stacks.tokens_of(cfg, 2, 24)
 
 
 @pytest.fixture(scope="module")
 def reference(model):
     cfg, params, tokens = model
     weights = ouro.weights_from_program(params)
-    x, ks, vs, gates = ouro.hidden(weights, tokens, SIZES)
-    return ouro.logits(weights, x), ks, vs, gates
+    programs = stacks.reference_programs(STACK, SIZES)
+    x, ks, vs, gates = programs.hidden(weights, tokens)
+    return programs.logits(weights, x), ks, vs, gates
 
 
 def test_init_draws_what_a_missing_part_would_hide(model):
@@ -81,19 +76,19 @@ def test_forward_agrees_with_the_reference(model, reference):
 def cached(cfg, params, tokens, split, counter=True):
     """Prefill ``tokens[:, :split]`` in the chunks ``split`` names, then decode
     the rest a token at a time, the two lanes at their own positions."""
+    forward = stacks.forward_cached
     cache = gen.init_cache(cfg, tokens.shape[0])
     if counter:
         cache[gen.LOOP_PASSES] = gen.init_loop_passes(cfg)
     out, at = [], 0
     for end in split:
-        logits, cache = gen._forward_cached(
-            params, tokens[:, at:end], cache, at, cfg)
+        logits, cache = forward(params, tokens[:, at:end], cache, at, cfg)
         at = end
     out.append(logits)
     for i in range(at, tokens.shape[1] - 1):
-        logits, cache = gen._forward_cached(
+        logits, cache = forward(
             params, tokens[:, i:i + 1], cache,
-            jnp.full((tokens.shape[0],), i), cfg)
+            np.full((tokens.shape[0],), i), cfg)
         out.append(logits)
     return jnp.stack(out, 1), cache, at
 
@@ -129,14 +124,14 @@ def test_lanes_at_different_positions(model, reference):
     lane = lambda c, s: {n: a[:, s:s + 1] for n, a in c.items() if a.ndim == 5}
     for slot, n in ((0, 9), (1, 15)):
         one = dict(lane(cache, slot), **{gen.LOOP_PASSES: cache[gen.LOOP_PASSES]})
-        _, one = gen._forward_cached(params, tokens[slot:slot + 1, :n], one, 0,
-                                     cfg)
+        _, one = stacks.forward_cached(params, tokens[slot:slot + 1, :n], one,
+                                       0, cfg)
         cache = {name: a if a.ndim != 5 else cache[name].at[:, slot].set(a[:, 0])
                  for name, a in one.items()}
-    positions = jnp.asarray([9, 15, BLOCK - 1])
-    live = jnp.asarray([True, True, False])
-    step = jnp.stack([tokens[0, 9], tokens[1, 15], 0])[:, None]
-    logits, cache = gen._forward_cached(
+    positions = np.asarray([9, 15, BLOCK - 1])
+    live = np.asarray([True, True, False])
+    step = np.asarray([tokens[0, 9], tokens[1, 15], 0])[:, None]
+    logits, cache = stacks.forward_cached(
         params, step, cache, positions, cfg, valid=live[:, None])
     np.testing.assert_allclose(logits[0], ref_logits[0, 9], atol=2e-5)
     np.testing.assert_allclose(logits[1], ref_logits[1, 15], atol=2e-5)
@@ -148,8 +143,8 @@ def test_lanes_at_different_positions(model, reference):
 
 # -- negative controls: what the reference would catch ------------------------
 
-def off_by(cfg, params, tokens, reference):
-    logits = gpt.forward(params, tokens, cfg)[0]
+def off_by(cfg, params, tokens, reference, forward=stacks.forward):
+    logits = forward(params, tokens, cfg)[0]
     return float(jnp.abs(logits - reference[0]).max())
 
 
@@ -180,7 +175,8 @@ def test_the_final_norm_not_carried_shows(model, reference, monkeypatch):
         return real(x, scale, bias, c)
 
     monkeypatch.setattr(gpt, "_norm", only_the_last)
-    assert off_by(cfg, params, tokens, reference) > 1e-2
+    # op by op: the patch knows the final norm's scale by the array it is
+    assert off_by(cfg, params, tokens, reference, gpt.forward) > 1e-2
     assert len(calls) == PASSES
 
 
@@ -214,12 +210,12 @@ def test_a_float32_stream_under_bfloat16_matmuls(model, reference):
     kept = dataclasses.replace(bf16, residual_dtype="float32")
     assert (bf16.stream_dtype, kept.stream_dtype) == ("bfloat16", "float32")
     off = {c.stream_dtype: float(jnp.abs(
-        gpt.forward(params, tokens, c)[0] - reference[0]).mean())
+        stacks.forward(params, tokens, c)[0] - reference[0]).mean())
         for c in (bf16, kept)}
     assert off["float32"] < off["bfloat16"]     # 24 sums; 384 at size
     logits, cache, at = cached(kept, params, tokens, (12,))
     assert cache["k"].dtype == jnp.bfloat16 and logits.dtype == jnp.float32
-    full = gpt.forward(params, tokens, kept)[0]
+    full = stacks.forward(params, tokens, kept)[0]
     assert float(jnp.abs(logits - full[:, at - 1:-1]).max()) < 0.05
     # every matmul of a weight runs in bfloat16: no weight is cast up
     text = str(jax.make_jaxpr(lambda p, t: gpt.forward(p, t, kept))(
@@ -281,14 +277,9 @@ def test_speculation_refuses_a_looped_stack(model):
 # -- what works over passes x layers planes, shown -----------------------------
 
 def serve(cfg, params, prompts, **options):
-    server = InferenceServer(params, cfg, n_slots=2, prefill_len=32,
-                             prefill_buckets=[8, 16, 32], warmup=True,
+    server = InferenceServer(params, cfg, **STACK.serve, warmup=True,
                              **options)
-    handles = [server.submit(Request(prompt=p, max_new_tokens=8,
-                                     do_sample=False)) for p in prompts]
-    while any(not h.finished for h in handles):
-        server.step()
-    return [h.tokens for h in handles], server
+    return stacks.serve(server, prompts, 8), server
 
 
 @pytest.fixture(scope="module")
@@ -305,8 +296,7 @@ def served(model):
 def test_the_server_emits_the_solo_tokens_and_counts_its_passes(model, served):
     cfg, params, _ = model
     prompts, tokens, server = served
-    solo = np.asarray(gen.generate(params, cfg, np.asarray(prompts), 8))
-    assert [t for t in tokens] == solo[:, 26:].tolist()
+    assert tokens == [solo_greedy(params, cfg, p, 8) for p in prompts]
     summary = server.metrics.summary()
     assert summary["kv_bytes_per_row"] == PASSES * LAYERS * 2 * 4 * 16 * 4
     assert summary["kv_bytes_per_row"] == server.engine.kv_bytes_per_row
@@ -356,16 +346,16 @@ def test_a_dense_model_carries_no_counter_and_reports_none():
 
 
 def test_one_pass_with_a_gate_counts_one_pass_a_token():
-    cfg = GPTConfig.make(**{**LOOPED, "n_passes": 1})
-    params = gpt.init(jax.random.key(0), cfg)
-    tokens = jax.random.randint(jax.random.key(1), (1, 10), 0, VOCAB)
+    cfg, params = stacks.model(STACK, n_passes=1)
+    tokens = stacks.tokens_of(cfg, 1, 10)
     _, cache, _ = cached(cfg, params, tokens, (6,))
     np.testing.assert_allclose(np.asarray(cache[gen.LOOP_PASSES]),
                                [9.0, 9.0, 9.0], rtol=1e-6)
     weights = ouro.weights_from_program(params)
-    x, *_ = ouro.hidden(weights, tokens, dict(SIZES, total_ut_steps=1))
-    np.testing.assert_allclose(gpt.forward(params, tokens, cfg)[0],
-                               ouro.logits(weights, x), atol=2e-5)
+    programs = stacks.reference_programs(STACK, dict(SIZES, total_ut_steps=1))
+    x, *_ = programs.hidden(weights, tokens)
+    np.testing.assert_allclose(stacks.forward(params, tokens, cfg)[0],
+                               programs.logits(weights, x), atol=2e-5)
 
 
 # -- one pass, no new norm: the programs of before ----------------------------

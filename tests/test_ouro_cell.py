@@ -12,25 +12,25 @@ import jax
 import numpy as np
 import pytest
 
+import stacks
 from benchmarks import rehearse
-from benchmarks.harness import check, compiles, serve_cell, spec
+from benchmarks.harness import check, serve_cell, spec
 from mingpt_distributed_tpu.config import GPTConfig
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.serving import InferenceServer
+from stack_contract import (  # noqa: F401
+    cell_run, stack,
+    test_the_cell_agrees_with_its_reference_through_the_whole_path)
 
-CELL = "ouro-2.6b.serve-looped-decode"
-SEED = 2_500_000_037        # past 32 signed bits, as the driver's seeds are
+STACK = stacks.OURO
 NEW_READERS = ("loop.passes_per_token", "engine.decode_hbm_roofline")
-PASSES, LAYERS = 3, 2       # the tiny cell's
-
-
-def tiny_cell(**sizes) -> spec.Cell:
-    return rehearse.tiny(spec.load_cell(CELL), sizes=sizes or None)
+# the tiny cell's
+PASSES, LAYERS = stacks.OURO_CELL_PASSES, stacks.OURO_CELL_LAYERS
 
 
 def test_the_configuration_holds_every_published_key():
-    cell = spec.load_cell(CELL)
+    cell = spec.load_cell(STACK.cell)
     config = cell.config
     assert config["reduced"] == ["max_position_embeddings"]
     assert (config["num_hidden_layers"], config["hidden_size"],
@@ -54,7 +54,7 @@ def test_the_configuration_holds_every_published_key():
     assert sum(np.prod(s) // 1024 for s in shapes.values()) * 2 == 1_572_864
     cells = [w for w in spec.load_manifest()["workloads"]
              if w["config"] == "ouro-2.6b"]
-    assert [(w["name"], w["chips"]) for w in cells] == [(CELL, 1)]
+    assert [(w["name"], w["chips"]) for w in cells] == [(STACK.cell, 1)]
 
 
 @pytest.mark.parametrize("key,value", [
@@ -63,14 +63,14 @@ def test_the_configuration_holds_every_published_key():
     ("intermediate_size", 8192), ("early_exit_threshold", 0.5),
     ("num_hidden_layers", 24)])
 def test_run_refuses_a_size_the_program_does_not_run(key, value):
-    cell = spec.load_cell(CELL)
+    cell = spec.load_cell(STACK.cell)
     wrong = dataclasses.replace(cell, config=dict(cell.config, **{key: value}))
     with pytest.raises(spec.SpecError, match=key):
         spec.gpt_config(wrong, training=False)
 
 
 def test_tiny_shrinks_every_size_the_reference_reads():
-    cell = tiny_cell()
+    cell = stacks.tiny_cell(STACK)
     cfg = spec.gpt_config(cell, training=False)
     assert (cfg.n_layer, cfg.n_passes, cfg.n_head, cfg.head_dim,
             cfg.dense_width, cfg.cache_planes) == (
@@ -79,33 +79,6 @@ def test_tiny_shrinks_every_size_the_reference_reads():
     assert (config["total_ut_steps"], config["head_dim"],
             config["num_key_value_heads"], config["intermediate_size"]) == (
         PASSES, 32, 3, 256)
-
-
-@pytest.fixture(scope="module")
-def cell_run():
-    return serve_cell.run(
-        tiny_cell(), seed=SEED, seconds=1.0, traced=False,
-        devices=jax.devices()[:1], t_process=0.0,
-        compiles=compiles.CompileCounter())
-
-
-def test_the_cell_agrees_with_its_reference_through_the_whole_path(cell_run):
-    """bfloat16 weights and activations, the engine's own prefill and decode
-    programs: the keys and values of every one of the passes x layers planes
-    inside the twin's tolerance, a plane and over the stack; no program
-    compiled in the window."""
-    verdict = cell_run["verdict"]
-    assert verdict["ok"], verdict
-    assert verdict["compiled_in_window"] == 0
-    assert len(verdict["cases"]) == 3
-    assert {c["bucket"] for c in verdict["cases"]} == {32, 64}
-    for case in verdict["cases"]:
-        assert len(case["k_rel_layers"]) == len(case["v_rel_layers"]) \
-            == len(case["twin_k_rel_layers"]) == PASSES * LAYERS
-        assert 0 < max(case["twin_k_rel"], case["twin_v_rel"]) \
-            < check.SERVE_TWIN_CEILING
-        assert case["kv_ratio"] <= 1.05     # the program reads its twin's
-    assert cell_run["failed"] == 0 and cell_run["attempted"] > 0
 
 
 def test_the_counters_reach_the_readers(cell_run):
@@ -124,7 +97,7 @@ def test_the_counters_reach_the_readers(cell_run):
     # no device trace on the CPU: no time to hold the bytes against
     assert spec.load_reader("engine.decode_hbm_roofline").read(evidence) \
         is None
-    cell = spec.load_cell(CELL)
+    cell = spec.load_cell(STACK.cell)
     assert set(NEW_READERS) <= {m["name"] for m in cell.per_layer}
     assert "engine.prefill_ms_per_ktok" not in {
         m["name"] for m in cell.per_layer}
@@ -157,7 +130,7 @@ def test_the_roofline_s_bytes_are_the_arithmetic_of_the_issue(monkeypatch):
     """4 x 4.93 GB of trunk weights, 0.2 GB of head and five whole slots of
     192 planes: 28.0 GB a step, 34.2 ms at the table's 819 GB/s."""
     reader = spec.load_reader("engine.decode_hbm_roofline")
-    cell = spec.load_cell(CELL)
+    cell = spec.load_cell(STACK.cell)
     layer = 4 * 2048 * 2048 + 3 * 2048 * 5632
     want = 2 * (4 * 48 * layer + 2048 * 49152) + 5 * 1024 * 1_572_864
     assert reader.step_bytes(cell.config, 5 * 1024, 1_572_864) == want
@@ -198,7 +171,7 @@ def server_of(cell, **cfg_change):
     test has patched before the call)."""
     sound = spec.gpt_config(cell, training=False)
     return InferenceServer(
-        serve_cell.init_params(sound, SEED),
+        serve_cell.init_params(sound, STACK.seed),
         dataclasses.replace(sound, **cfg_change), warmup=False,
         **spec.server_options(cell))
 
@@ -250,7 +223,7 @@ def one_pass_dropped(monkeypatch):
 
 @pytest.fixture(scope="module")
 def sound():
-    cell = tiny_cell()
+    cell = stacks.tiny_cell(STACK)
     verdict = verdict_of(cell, server_of(cell))
     assert verdict["ok"], verdict
     return cell, verdict
@@ -323,7 +296,7 @@ def test_a_lower_precision_fails_the_verdict(sound):
 
 
 def test_rehearse_runs_the_cell_and_its_readers(capsys):
-    rehearse.rehearse_run(spec.load_cell(CELL))
+    rehearse.rehearse_run(spec.load_cell(STACK.cell))
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert line["agrees_with_reference"] is True and line["failed"] == 0
     assert line["compiled_in_window"] == 0

@@ -30,12 +30,9 @@ sleep-free and replayable on a virtual clock:
 import json
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from mingpt_distributed_tpu.config import GPTConfig
-from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.parallel.mesh import MeshConfig, make_mesh
 from mingpt_distributed_tpu.serving import Request, VirtualClock
@@ -53,6 +50,7 @@ from mingpt_distributed_tpu.serving.procfleet import (
 from mingpt_distributed_tpu.telemetry import parse_prometheus
 from mingpt_distributed_tpu.telemetry.tracing import TraceRecorder
 from mingpt_distributed_tpu.training.faults import ProcessFaultInjector
+from oracles import solo_greedy
 
 
 @pytest.fixture(scope="module")
@@ -62,11 +60,6 @@ def cfg_params():
         embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32",
     )
     return cfg, gpt.init(jax.random.key(0), cfg)
-
-
-def solo_greedy(params, cfg, prompt, n):
-    out = gen.generate(params, cfg, jnp.asarray(prompt, jnp.int32)[None], n)
-    return np.asarray(out)[0, len(prompt):].tolist()
 
 
 def make_procfleet(cfg_params, n_replicas=2, pspec=None, server_kwargs=None,

@@ -26,7 +26,6 @@ import numpy as np
 import pytest
 
 from mingpt_distributed_tpu.config import GPTConfig
-from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.serving import InferenceServer, Request
 from mingpt_distributed_tpu.serving import engine as engine_mod
@@ -35,6 +34,7 @@ from mingpt_distributed_tpu.serving.engine import (
     sampler_orders,
 )
 from mingpt_distributed_tpu.serving.scheduler import SlotTable
+from oracles import solo_greedy
 
 
 def _two_sort_reference(logits, keys, temps, top_ks, top_ps, do_sample):
@@ -254,11 +254,6 @@ def test_verify_program_compiles_to_no_sort(spec_server):
 # ---------------------------------------------------------------------------
 
 PROMPTS = [[1, 2, 3, 4, 5], [7, 8, 9], [10, 11, 12, 13], [40, 41]]
-
-
-def _solo_greedy(params, cfg, prompt, n):
-    out = gen.generate(params, cfg, jnp.asarray(prompt, jnp.int32)[None], n)
-    return np.asarray(out)[0, len(prompt):].tolist()
 NUCLEUS = dict(do_sample=True, temperature=0.8, top_p=0.9)
 TEMPERATURE = dict(do_sample=True, temperature=1.3)
 
@@ -311,7 +306,7 @@ def test_mixed_requests_compile_nothing_and_count_their_rounds(cfg_params):
     fam = server.metrics.registry.counter(
         "mingpt_recompiles_total", labels=("family",))
     assert sum(child.value for _, child in fam.children()) == 0
-    assert greedy.tokens == _solo_greedy(params, cfg, PROMPTS[0], 12)
+    assert greedy.tokens == solo_greedy(params, cfg, PROMPTS[0], 12)
 
 
 @pytest.mark.parametrize("traffic,sorts", [
@@ -361,7 +356,7 @@ def test_a_finished_requests_lane_counts_nothing(cfg_params):
     server.run_until_drained(max_steps=100)
     assert server.summary()["steps"] > steps + 5
     assert server.summary()["sampler_sorted_rounds"] == sorted_rounds
-    assert greedy.tokens == _solo_greedy(params, cfg, PROMPTS[0], 14)
+    assert greedy.tokens == solo_greedy(params, cfg, PROMPTS[0], 14)
 
 
 # ---------------------------------------------------------------------------
@@ -434,7 +429,7 @@ def test_a_request_that_fills_the_window(cfg_params, two_sort_tokens, kind,
     assert len(handle.tokens) == room
     assert handle.tokens == two_sort_tokens(kind)
     if kind == "greedy":
-        assert handle.tokens == _solo_greedy(params, cfg, PROMPTS[0], room)
+        assert handle.tokens == solo_greedy(params, cfg, PROMPTS[0], room)
     # the first token is the prefill's; each of the others took a round
     assert summary["sampler_sorted_rounds"] == (room - 1 if sorts else 0)
 

@@ -29,6 +29,7 @@ from mingpt_distributed_tpu.serving import (
     Request,
     SlotKVPool,
 )
+from oracles import solo_greedy
 
 
 @pytest.fixture(scope="module")
@@ -38,12 +39,6 @@ def cfg_params():
         embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32",
     )
     return cfg, gpt.init(jax.random.key(0), cfg)
-
-
-def solo_greedy(params, cfg, prompt, n):
-    """The new tokens generate() produces alone on this prompt."""
-    out = gen.generate(params, cfg, jnp.asarray(prompt, jnp.int32)[None], n)
-    return np.asarray(out)[0, len(prompt):].tolist()
 
 
 PROMPTS = [[1, 2, 3, 4, 5], [7, 8, 9], [10, 11, 12, 13], [40, 41], [20, 21, 22]]

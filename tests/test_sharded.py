@@ -22,11 +22,9 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from mingpt_distributed_tpu.config import GPTConfig, MeshConfig
-from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
 from mingpt_distributed_tpu.parallel.zero import per_device_bytes
@@ -40,6 +38,7 @@ from mingpt_distributed_tpu.serving import (
 )
 from mingpt_distributed_tpu.serving.engine import DecodeEngine
 from mingpt_distributed_tpu.training.faults import ServingFaultInjector
+from oracles import solo_greedy
 
 
 @pytest.fixture(scope="module")
@@ -58,12 +57,6 @@ def tree_bytes(tree):
 @pytest.fixture(scope="module")
 def tp2_mesh():
     return mesh_lib.make_mesh(MeshConfig(tp=2), devices=jax.devices()[:2])
-
-
-def solo_greedy(params, cfg, prompt, n):
-    """Unsharded single-device generate(): the tp=1 ground truth."""
-    out = gen.generate(params, cfg, jnp.asarray(prompt, jnp.int32)[None], n)
-    return np.asarray(out)[0, len(prompt):].tolist()
 
 
 PROMPTS = [[1, 2, 3, 4, 5], [7, 8, 9], [10, 11, 12, 13], [40, 41]]

@@ -5,12 +5,11 @@ ReLU-gated experts (``expert_act``), full layers that carry no position
 under, cross while they decode and have passed, against the plain reference
 ``benchmarks/references/smallthinker.py``: through ``gpt.forward``, the
 cached forward, ``InferenceServer`` and the benchmark's cell through the
-path the driver runs."""
+path the driver runs. What every served family proves is
+``tests/stack_contract.py``'s; here is what is peculiar to this one."""
 
 import collections
 import dataclasses
-import json
-import types
 
 import jax
 import jax.numpy as jnp
@@ -18,60 +17,33 @@ import numpy as np
 import pytest
 from program_digests import _abstract_params, _equations, _ids
 
-from benchmarks import rehearse
-from benchmarks.harness import check, compiles, serve_cell, spec
+import stacks
+from benchmarks.harness import check, serve_cell, spec
 from mingpt_distributed_tpu.config import (
     FULL_ATTN, WINDOW_ATTN, ConfigError, GPTConfig)
 from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.ops import moe
-from mingpt_distributed_tpu.serving import InferenceServer, Request
+from mingpt_distributed_tpu.serving import InferenceServer
+from oracles import solo_greedy
+from stack_contract import (  # noqa: F401
+    cell_run, model, pytest_generate_tests, reference, stack,
+    test_a_planted_fault_reads_not_ok,
+    test_combinations_that_are_not_built_are_refused_with_a_sentence,
+    test_in_bfloat16_the_engine_holds_the_check_s_law,
+    test_the_cached_path_is_the_uncached_forward,
+    test_the_cell_agrees_with_its_reference_through_the_whole_path,
+    test_the_full_forward_is_the_reference_s,
+    test_the_manifest_lists_the_cell_where_it_reports,
+    test_the_slot_and_the_weights_are_the_size_the_configuration_states,
+    test_training_and_a_split_mesh_are_refused_by_the_forward)
+from stacks import WINDOW, tokens_of
 
-CELL = "smallthinker-21b-a3b.serve-past-window"
+STACK = stacks.SMALLTHINKER
 LAGUNA = "laguna-xs.2.serve-long-decode"
 KANANA = "kanana-2-30b-a3b.serve-long-decode"
-SEED = 2_610_000_001        # past 32 signed bits, as the driver's seeds are
-WINDOW = 16
 NEW_READERS = ("moe.route_ms_per_step", "kernel.grouped_glu_roofline",
                "moe.expert_runs_per_step")
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-
-
-def tiny_cell() -> spec.Cell:
-    return rehearse.tiny(spec.load_cell(CELL))
-
-
-def tiny_cfg(**over) -> GPTConfig:
-    """The cell's own program at ``rehearse.tiny``'s size: five layers
-    (full, window, window, window, full), 6 query heads over 2 KV heads of
-    16, a window of 16, 2 of 8 ReLU-gated experts in every layer."""
-    gpt_config = tiny_cell().config["program"]["gpt_config"]
-    return GPTConfig.make(**{**gpt_config, "dtype": "float32",
-                             "param_dtype": "float32", **over})
-
-
-def sizes_of(cfg: GPTConfig) -> dict:
-    """What the reference reads of a configuration file, from the program's
-    config: the cell's own ``key_map``, applied as ``rehearse.tiny`` does."""
-    key_map = spec.load_cell(CELL).config["program"]["key_map"]
-    return {published: getattr(cfg, field)
-            for published, field in key_map.items()}
-
-
-@pytest.fixture(scope="module")
-def reference():
-    return spec.load_reference(spec.load_cell(CELL).config)
-
-
-@pytest.fixture(scope="module")
-def model():
-    cfg = tiny_cfg()
-    return cfg, gpt.init(jax.random.key(3), cfg)
-
-
-def tokens_of(cfg, batch, t, seed=1):
-    return jax.random.randint(jax.random.key(seed), (batch, t), 0,
-                              cfg.vocab_size)
 
 
 # -- the program against the reference, float32 ------------------------------
@@ -80,25 +52,6 @@ def tokens_of(cfg, batch, t, seed=1):
 # experts in blocks of 8 rows, the reference in blocks of 512 queries and 128
 # pairs): logits of order 1 agree to a few 1e-7, and 2e-6 is five times what
 # the worst case reads. A fault below moves them by 1e-3 and more.
-
-def test_the_full_forward_is_the_reference_s(reference, model):
-    cfg, params = model
-    toks = tokens_of(cfg, 2, 100)
-    logits, loss = gpt.forward(params, toks, cfg, targets=toks)
-    w = reference.weights_from_program(params)
-    x, ks, vs, router = reference.hidden(w, toks, sizes_of(cfg))
-    assert reference.cached_layers(sizes_of(cfg)) == (0, 4)
-    assert ks.shape == vs.shape == (2, 2, 100, cfg.kv_heads, cfg.head_dim)
-    assert router.shape == (5, 2, 100, cfg.n_experts)
-    np.testing.assert_allclose(logits, reference.logits(w, x), atol=2e-6)
-    np.testing.assert_allclose(
-        loss, reference.loss(w, toks, toks, sizes_of(cfg)), atol=1e-5)
-    # every layer routes, and the reference's own choice under a table of
-    # entries of -1 is its choice under none
-    table = -jnp.ones((5, 2, 100, cfg.moe_top_k), jnp.int32)
-    np.testing.assert_array_equal(
-        x, reference.hidden(w, toks, sizes_of(cfg), experts=table)[0])
-
 
 def test_the_reference_s_experts_are_every_expert_under_a_zero_gate(
         reference, model):
@@ -130,9 +83,9 @@ def lanes_cache(cfg, params, toks, prompts):
     """A cache of ``len(prompts)`` lanes, lane ``b`` prefilled alone with
     the first ``prompts[b]`` tokens of ``toks[b]`` (a prefill has one
     offset for its batch), and each lane's logits after its prompt."""
-    prefill = jax.jit(lambda t: gen._forward_cached(
-        params, t, gen.init_cache(cfg, 1), 0, cfg))
-    lanes = [prefill(toks[b:b + 1, :n]) for b, n in enumerate(prompts)]
+    lanes = [stacks.forward_cached(params, toks[b:b + 1, :n],
+                                   gen.init_cache(cfg, 1), 0, cfg)
+             for b, n in enumerate(prompts)]
     cache = {name: jnp.concatenate([c[name] for _, c in lanes], axis=1)
              for name in lanes[0][1]}
     return cache, jnp.concatenate([lg for lg, _ in lanes])
@@ -146,38 +99,33 @@ def test_lanes_under_crossing_and_past_the_window_decode_in_one_batch(
     own positions: the first stays under the window of 16 (or crosses it
     late), the second crosses it while it decodes, the third starts past
     twice the window. Every step's logits of every lane, and the full
-    layers' rows, against the reference's forward over each lane's whole
-    sequence."""
+    layers' rows, against the reference's forward over the lanes' whole
+    sequences (one program for the three: what stands after a lane's last
+    token it does not read)."""
     cfg, params = model
-    n = max(prompts) + steps
-    toks = tokens_of(cfg, 3, n, seed=sum(prompts))
+    toks = tokens_of(cfg, 3, cfg.block_size, seed=sum(prompts))
     w = reference.weights_from_program(params)
-    sizes = sizes_of(cfg)
+    programs = stacks.reference_programs(STACK, stacks.sizes_of(STACK, cfg))
     assert prompts[1] <= WINDOW < prompts[1] + steps    # crosses it
     assert prompts[2] >= 2 * WINDOW
-    ref_logits, ref_k, ref_v = [], [], []
-    for b, p in enumerate(prompts):
-        x, ks, vs, _ = reference.hidden(w, toks[b:b + 1, :p + steps], sizes)
-        ref_logits.append(reference.logits(w, x)[0])
-        ref_k.append(ks[:, 0])
-        ref_v.append(vs[:, 0])
+    x, ref_k, ref_v, _ = programs.hidden(w, toks)
+    ref_logits = np.asarray(programs.logits(w, x))
     cache, logits = lanes_cache(cfg, params, toks, prompts)
     assert cache[gen.RING_K].shape == (3, 3, WINDOW, 1, 2 * 16)
-    step = jax.jit(lambda t, c, o: gen._forward_cached(params, t, c, o, cfg))
     at = np.asarray(prompts)
     for i in range(steps + 1):
-        for b in range(3):
-            np.testing.assert_allclose(
-                logits[b], ref_logits[b][at[b] - 1], atol=2e-6)
+        np.testing.assert_allclose(
+            logits, ref_logits[np.arange(3), at - 1], atol=2e-6)
         if i == steps:
             break
-        new = jnp.stack([toks[b, at[b]] for b in range(3)])[:, None]
-        logits, cache = step(new, cache, jnp.asarray(at))
+        new = toks[np.arange(3), at][:, None]
+        logits, cache = stacks.forward_cached(params, new, cache, at, cfg)
         at = at + 1
     for b, p in enumerate(prompts):
-        for name, rows in (("k", ref_k[b]), ("v", ref_v[b])):
+        for name, rows in (("k", ref_k), ("v", ref_v)):
+            want = rows[:, b, :p + steps]
             np.testing.assert_allclose(
-                cache[name][:, b, :p + steps].reshape(rows.shape), rows,
+                cache[name][:, b, :p + steps].reshape(want.shape), want,
                 atol=1e-5)
 
 
@@ -190,12 +138,11 @@ def test_a_prompt_longer_than_the_ring_leaves_its_last_rows_there(model):
     every one."""
     cfg, params = model
     toks = tokens_of(cfg, 1, 64)
-    valid = (jnp.arange(64) < 40)[None]
+    valid = (np.arange(64) < 40)[None]
 
     def prefill(cfg, toks, valid):
-        return jax.jit(lambda toks, valid: gen._forward_cached_hidden(
-            params, toks, gen.init_cache(cfg, 1), 0, cfg, valid))(
-                toks, valid)[1]
+        return stacks.forward_cached_hidden(
+            params, toks, gen.init_cache(cfg, 1), 0, cfg, valid)[1]
 
     padded = prefill(cfg, toks, valid)
     exact = prefill(cfg, toks[:, :40], None)
@@ -206,15 +153,6 @@ def test_a_prompt_longer_than_the_ring_leaves_its_last_rows_there(model):
         for p in range(24, 40):
             np.testing.assert_allclose(
                 exact[name][0, 0, p % WINDOW], wide[name][0, 0, p], atol=1e-6)
-
-
-def test_the_cached_path_is_the_uncached_forward(model):
-    cfg, params = model
-    toks = tokens_of(cfg, 2, 12)
-    out = gen.generate(params, cfg, toks, 50)
-    logits, _ = gpt.forward(params, out[:, :-1], cfg)
-    np.testing.assert_array_equal(
-        out[:, 12:], jnp.argmax(logits[:, 11:], -1))
 
 
 # -- a kind without positions ----------------------------------------------
@@ -240,7 +178,7 @@ def test_a_full_layer_rotates_nothing_and_a_window_layer_every_dimension(
     # a stack of full layers alone would be blind to order, which is why
     # a stack in which no kind rotates is refused
     with pytest.raises(ConfigError, match="no kind rotates"):
-        tiny_cfg(window_rope_fraction=0.0)
+        stacks.tiny_cfg(STACK, window_rope_fraction=0.0)
 
 
 # -- the route ---------------------------------------------------------------
@@ -392,19 +330,13 @@ def test_the_server_serves_lanes_on_both_sides_of_the_window(model):
     solo ``generate``; one decode program and one prefill program a
     bucket."""
     cfg, params = model
-    server = InferenceServer(params, cfg, n_slots=2, prefill_len=64,
-                             prefill_buckets=[32, 64], warmup=True)
+    server = InferenceServer(params, cfg, **STACK.serve, warmup=True)
     prompts = [tokens_of(cfg, 1, n, seed=n)[0].tolist()
                for n in (60, 5, 33, 9, 17)]
     news = (40, 6, 40, 30, 3)
-    handles = [server.submit(Request(prompt=p, max_new_tokens=n,
-                                     do_sample=False))
-               for p, n in zip(prompts, news)]
-    while server.step():
-        pass
-    for p, n, h in zip(prompts, news, handles):
-        solo = gen.generate(params, cfg, jnp.asarray([p]), n)[0, len(p):]
-        assert h.tokens == solo.tolist()
+    for p, n, tokens in zip(prompts, news,
+                            stacks.serve(server, prompts, news)):
+        assert tokens == solo_greedy(params, cfg, p, n)
     s = server.metrics.summary()
     assert s["ring_rows_per_slot"] == WINDOW
     assert 0 < s["ring_rows_live"] <= s["ring_rows_read"]
@@ -419,37 +351,7 @@ def test_the_server_serves_lanes_on_both_sides_of_the_window(model):
     assert rows.shape == (5, cfg.n_experts + 4)
 
 
-# -- what is not built is refused, a sentence each ---------------------------
-
-@pytest.mark.parametrize("over, sentence", [
-    (dict(expert_act="gelu"), "is 'silu' or 'relu'"),
-    (dict(moe_router_input="residual"), "'mlp' or 'attn'"),
-    (dict(moe_dropless=False), "are the dropless route's"),
-    (dict(n_shared_experts=1), "with shared experts is not written"),
-    (dict(rope_fraction=0.2), "or none"),
-    (dict(window_rope_fraction=0.0), "no kind rotates"),
-    (dict(rope_yarn=[64.0, 4096, 64, 1, 1.4]), "they rotate nothing"),
-    (dict(swiglu=False), "needs n_experts > 0 and swiglu"),
-    (dict(attention="flash"), "built for attention='einsum'"),
-    (dict(pp_microbatches=2), "is not pipelined"),
-])
-def test_combinations_that_are_not_built_are_refused_with_a_sentence(
-        over, sentence):
-    with pytest.raises(ConfigError, match=sentence):
-        tiny_cfg(**over)
-
-
-def test_training_and_a_split_mesh_are_refused_by_the_forward(model):
-    cfg, params = model
-    toks = tokens_of(cfg, 1, 16)
-    with pytest.raises(NotImplementedError, match="not trained"):
-        gpt.forward(params, toks, cfg, rng=jax.random.key(0),
-                    deterministic=False)
-    mesh = jax.sharding.Mesh(
-        np.asarray(jax.devices()[:2]).reshape(2), ("tp",))
-    with pytest.raises(NotImplementedError, match="not split over pp or tp"):
-        gpt.forward(params, toks, cfg, mesh=mesh)
-
+# -- what is not built is refused: the contract's, by ``STACK.refused`` --------
 
 def test_a_dense_model_s_expert_fields_stay_at_their_defaults():
     with pytest.raises(ConfigError, match="are the dropless route's"):
@@ -460,114 +362,28 @@ def test_a_dense_model_s_expert_fields_stay_at_their_defaults():
     assert not cfg.router_softmax
 
 
-# -- precision: what the check lets through and what it does not -------------
+# -- precision: the check's law and the planted faults are the contract's ----
 
-def bf16_model():
-    cfg = tiny_cfg(dtype="bfloat16", param_dtype="bfloat16")
-    return cfg, gpt.init(jax.random.key(3), cfg)
-
-
-def verdict_of(reference, cfg, params, sizes, weights=None):
-    """``check.serve_verdict`` over three prompts, eight decode steps each:
-    one that stays under the window, one that crosses it in those steps,
-    one that has wrapped it. ``weights``: what the reference computes with,
-    where the program's tree is not the model's (a planted fault)."""
-    if weights is not None:
-        reference = types.SimpleNamespace(**{
-            **vars(reference), "weights_from_program": lambda _: weights})
-    server = InferenceServer(params, cfg, n_slots=2, prefill_len=64,
-                             prefill_buckets=[32, 64], warmup=True)
-    rng = np.random.default_rng(SEED)
-    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
-               for n in (6, 12, 60)]
-    return check.serve_verdict(reference, sizes, server, prompts, 8)
-
-
-def test_in_bfloat16_the_engine_holds_the_check_s_law(reference):
-    cfg, params = bf16_model()
-    verdict = verdict_of(reference, cfg, params, sizes_of(cfg))
-    assert verdict["ok"], json.dumps(verdict)[:2000]
-    for case in verdict["cases"]:
-        assert len(case["k_rel_layers"]) == 2      # the full layers' planes
-        assert len(case["route_margin_layers"]) == 5
-
-
-def _router_on_the_mlp_s_input(monkeypatch, cfg, params):
-    return dataclasses.replace(cfg, moe_router_input="mlp")
-
-
-def _silu_gate(monkeypatch, cfg, params):
-    return dataclasses.replace(cfg, expert_act="silu")
-
-
-def _rotation_on_the_full_layers(monkeypatch, cfg, params):
-    return dataclasses.replace(cfg, rope_fraction=1.0)
-
-
-def _no_rotation_on_the_window_layers(monkeypatch, cfg, params):
-    real = GPTConfig.rope_spec
-    monkeypatch.setattr(
-        GPTConfig, "rope_spec", lambda self, kind=None: (
-            (0,) + real(self, kind)[1:]) if kind == WINDOW_ATTN
-        else real(self, kind))
-    return cfg
-
-
-def _window_one_row_short(monkeypatch, cfg, params):
-    return dataclasses.replace(cfg, attention_window=WINDOW - 1)
-
-
-def _gates_over_all_experts(monkeypatch, cfg, params):
-    def routes(h, w_router, *, top_k, route_scale):
-        z = h.astype(jnp.float32) @ w_router.astype(jnp.float32)
-        chosen = jax.lax.top_k(z, top_k)[1]
-        gates = jnp.take_along_axis(jax.nn.softmax(z, -1), chosen, axis=-1)
-        return chosen.astype(jnp.int32), gates * route_scale, z
-
-    monkeypatch.setattr(moe, "softmax_routes", routes)
-    return cfg
-
-
-@pytest.mark.parametrize("plant", [
-    _router_on_the_mlp_s_input, _silu_gate, _rotation_on_the_full_layers,
-    _no_rotation_on_the_window_layers, _window_one_row_short,
-    _gates_over_all_experts], ids=lambda f: f.__name__.strip("_"))
-def test_a_planted_fault_reads_not_ok(reference, monkeypatch, plant):
-    """The tiny cell's program with one thing wrong, against the reference
-    under the true sizes and the same weights: the verdict is not ``ok``."""
-    cfg, params = bf16_model()
-    sizes = sizes_of(cfg)
-    faulty = plant(monkeypatch, cfg, params)
-    verdict = verdict_of(reference, faulty, params, sizes,
-                         reference.weights_from_program(params))
-    assert not verdict["ok"], json.dumps(verdict["cases"][0]["compared"])
-
-
-@pytest.mark.parametrize("plant", [
-    _router_on_the_mlp_s_input, _silu_gate, _rotation_on_the_full_layers,
-    _no_rotation_on_the_window_layers, _window_one_row_short,
-    _gates_over_all_experts], ids=lambda f: f.__name__.strip("_"))
 def test_a_planted_fault_moves_the_float32_logits(reference, model,
                                                   monkeypatch, plant):
-    """The same faults through ``gpt.forward`` in float32: each moves the
+    """``STACK.faults`` through ``gpt.forward`` in float32: each moves the
     logits by a thousand times the tolerance the program is held to."""
     cfg, params = model
     toks = tokens_of(cfg, 1, 48)
     w = reference.weights_from_program(params)
-    want = reference.logits(w, reference.hidden(w, toks, sizes_of(cfg))[0])
-    faulty = plant(monkeypatch, cfg, params)
-    got, _ = gpt.forward(params, toks, faulty)
+    programs = stacks.reference_programs(STACK, stacks.sizes_of(STACK, cfg))
+    want = programs.logits(w, programs.hidden(w, toks)[0])
+    faulty, _ = plant(monkeypatch, cfg, stacks.sizes_of(STACK, cfg))
+    got, _ = stacks.forward(params, toks, faulty)
     assert float(jnp.abs(got - want).max()) > 2e-3
 
 
 # -- the configuration file and the cell ---------------------------------------
 
 def test_the_configuration_file_holds_the_catalog_row_key_for_key():
-    cell = spec.load_cell(CELL)
+    cell = spec.load_cell(STACK.cell)
     config = cell.config
-    with open(CATALOG) as f:
-        rows = [json.loads(line) for line in f]
-    row = next(r for r in rows if r["name"] == "SmallThinker-21BA3B-Instruct")
+    row = stacks.catalog_row("SmallThinker-21BA3B-Instruct")
     assert config["source"] == row["source_url"]
     assert config["reduced"] == [
         "num_hidden_layers", "rope_layout", "sliding_window_layout"]
@@ -578,11 +394,6 @@ def test_the_configuration_file_holds_the_catalog_row_key_for_key():
             assert config[key] == value[:5] == [0, 1, 1, 1, 0]
         else:
             assert config[key] == value, key
-    manifest = spec.load_manifest()
-    entry = next(c for c in manifest["configs"]
-                 if c["name"] == "smallthinker-21b-a3b")
-    assert entry["reduced"] == config["reduced"]
-    assert entry["source"] == config["source"]
     # every published number is tied to a field or a property of the program
     # (and ``num_experts_per_tok``, the name the yardstick's self-check
     # reads a routed reference's k under: an alias, tied to the same field)
@@ -610,18 +421,6 @@ def test_the_configuration_file_holds_the_catalog_row_key_for_key():
         spec.gpt_config(wrong, training=False)
 
 
-def test_the_slot_and_the_weights_are_the_size_the_configuration_states():
-    cfg = spec.gpt_config(spec.load_cell(CELL), training=False)
-    size = {n: int(np.prod(s)) * 2
-            for n, s in gen.cache_leaf_shapes(cfg, 1).items()}
-    assert size["k"] + size["v"] == 2 * 16384 * 2048          # 67.1 MB
-    assert size[gen.RING_K] + size[gen.RING_V] == 3 * 4096 * 2048
-    assert sum(size.values()) == 92_274_688
-    count = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(
-        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))))
-    assert count == 5 * 398_627_840 + 777_912_320 + 2_560
-
-
 def test_the_mix_deals_one_checked_prompt_past_the_window():
     """The mix's lengths as every seed is dealt them: prompts inside the
     two buckets, three of sixteen past the 4,096 window, and of the two
@@ -629,10 +428,10 @@ def test_the_mix_deals_one_checked_prompt_past_the_window():
     longer than the window, so the ring's wrap is compared on the chip."""
     from benchmarks.harness import traffic
 
-    cell = spec.load_cell(CELL)
-    reqs = traffic.requests(cell.mix, 1000, SEED, rate=cell.found[
+    cell = spec.load_cell(STACK.cell)
+    reqs = traffic.requests(cell.mix, 1000, STACK.seed, rate=cell.found[
         "rate_req_s"], horizon_s=60.0)
-    again = traffic.requests(cell.mix, 1000, SEED + 7, rate=cell.found[
+    again = traffic.requests(cell.mix, 1000, STACK.seed + 7, rate=cell.found[
         "rate_req_s"], horizon_s=60.0)
     lengths = [len(r.prompt) for r in reqs]
     assert lengths == [len(r.prompt) for r in again]
@@ -645,29 +444,6 @@ def test_the_mix_deals_one_checked_prompt_past_the_window():
     ends = [len(r.prompt) + r.max_new_tokens for r in reqs[:16]]
     assert max(ends) <= spec.gpt_config(cell, training=False).block_size
     assert 4 <= sum(e > 4096 for e in ends) <= 8
-
-
-@pytest.fixture(scope="module")
-def cell_run():
-    return serve_cell.run(
-        tiny_cell(), seed=SEED, seconds=1.0, traced=False,
-        devices=jax.devices()[:1], t_process=0.0,
-        compiles=compiles.CompileCounter())
-
-
-def test_the_cell_agrees_with_its_reference_through_the_whole_path(cell_run):
-    """bfloat16, the engine's own programs, ``serve_cell.Driver`` and
-    ``check.serve_verdict`` as the driver runs them: the full layers' rows
-    inside the twin's law once the reference has followed the program's
-    routes, no program compiled in the window."""
-    verdict = cell_run["verdict"]
-    assert verdict["ok"], verdict
-    assert verdict["compiled_in_window"] == 0
-    assert len(verdict["cases"]) == 2
-    for case in verdict["cases"]:
-        assert len(case["k_rel_layers"]) == len(case["v_rel_layers"]) == 2
-        assert len(case["route_banded_layers"]) == 5
-    assert cell_run["failed"] == 0 and cell_run["attempted"] > 0
 
 
 def test_the_new_counter_reaches_the_readers(cell_run):
@@ -723,7 +499,7 @@ def test_the_route_s_reader_reads_its_scope_and_nothing_without_it():
 
 def test_the_roofline_s_operations_and_bytes_are_the_published_widths():
     reader = spec.load_reader("kernel.grouped_glu_roofline")
-    config = spec.load_cell(CELL).config
+    config = spec.load_cell(STACK.cell).config
     peaks = {"flops": 197e12, "hbm_bytes_s": 819e9}
     per_expert = 3 * 2560 * 768 * 2         # 11.8 MB
     # a decode round: 18 lanes x 6 rows through 53 experts reads bytes
@@ -777,8 +553,8 @@ THE_COUNTER_ADDS = ("gt", "reduce_sum", "convert_element_type",
 
 
 def decode_primitives(cell_name):
-    cell = rehearse.tiny(spec.load_cell(cell_name))
-    cfg = GPTConfig.make(**cell.config["program"]["gpt_config"])
+    cfg = GPTConfig.make(
+        **stacks.tiny_cell(cell_name).config["program"]["gpt_config"])
     params = _abstract_params(cfg)
     cache = jax.eval_shape(lambda: dict(
         gen.init_cache(cfg, 3), **{gen.MOE_ROWS: gen.init_moe_rows(cfg)}))
@@ -800,29 +576,3 @@ def test_an_accepted_routed_decode_program_gains_the_counter_alone(cell_name):
                          if now[p] != want[p]}
     assert (cfg.expert_act, cfg.moe_router_input) == ("silu", "mlp")
     assert gen.init_moe_rows(cfg).shape == (expert_layers, cfg.n_experts + 4)
-
-
-def test_the_manifest_lists_the_cell_where_it_reports():
-    cell = spec.load_cell(CELL)
-    assert cell.chips == 1
-    assert [m["name"] for m in cell.end_to_end] == ["itl_p50_ms", "setup_s"]
-    names = {m["name"] for m in cell.per_layer}
-    laguna = {m["name"] for m in spec.load_cell(LAGUNA).per_layer}
-    # laguna's lists but the one that names the SiLU kernel's calls alone
-    # (this cell's kernel is ``grouped_reglu``), and the three new readers
-    assert names == (laguna - {"kernel.grouped_swiglu_us_per_block"}) \
-        | set(NEW_READERS)
-    assert "moe.rows_per_expert_round" not in names
-    manifest = spec.load_manifest()
-    new = [m for m in manifest["per_layer"] if m["name"] in NEW_READERS]
-    # appended, together (PR 62 appended its one reader after them)
-    assert new == manifest["per_layer"][-4:-1]
-    for metric in new:
-        assert metric["workloads"] == [CELL]
-        assert metric["moves"] == "itl_p50_ms"
-    assert [(m["name"], m["unit"], m["layer"]) for m in new] == [
-        ("moe.route_ms_per_step", "ms", "experts"),
-        ("kernel.grouped_glu_roofline", "%", "kernel"),
-        ("moe.expert_runs_per_step", "experts", "experts")]
-    assert manifest["workloads"][-1]["name"] == CELL
-    assert manifest["configs"][-1]["name"] == "smallthinker-21b-a3b"

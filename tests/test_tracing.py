@@ -24,13 +24,10 @@ import json
 import urllib.request
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from mingpt_distributed_tpu import telemetry
 from mingpt_distributed_tpu.config import GPTConfig
-from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.serving import (
     InferenceServer,
@@ -56,6 +53,7 @@ from mingpt_distributed_tpu.telemetry import (
     validate_trace_records,
 )
 from mingpt_distributed_tpu.training.faults import ServingFaultInjector
+from oracles import solo_greedy
 
 
 @pytest.fixture(scope="module")
@@ -65,11 +63,6 @@ def cfg_params():
         embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32",
     )
     return cfg, gpt.init(jax.random.key(0), cfg)
-
-
-def solo_greedy(params, cfg, prompt, n):
-    out = gen.generate(params, cfg, jnp.asarray(prompt, jnp.int32)[None], n)
-    return np.asarray(out)[0, len(prompt):].tolist()
 
 
 def make_fleet(cfg_params, n_replicas=2, spec=None, n_slots=2,

@@ -14,14 +14,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import rehearse
+import stacks
 from benchmarks.harness import check, serve_cell, spec
 from mingpt_distributed_tpu.config import GPTConfig, MeshConfig
 from mingpt_distributed_tpu.models import generate as gen
-from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.parallel import mesh as mesh_lib
-from mingpt_distributed_tpu.serving import InferenceServer, Request
+from mingpt_distributed_tpu.serving import InferenceServer
 from mingpt_distributed_tpu.serving import engine as engine_mod
 from mingpt_distributed_tpu.serving import quant as quant_lib
 from mingpt_distributed_tpu.serving.engine import DecodeEngine
@@ -29,54 +28,12 @@ from mingpt_distributed_tpu.telemetry import render_prometheus
 from program_digests import engine_digest as digest
 from program_digests import forward_digest
 
-OFF = dict(embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0, dtype="float32")
-TINY = dict(n_layer=2, n_head=4, n_embd=32, vocab_size=64, block_size=32,
-            **OFF)
-ROPE = dict(rope=True, swiglu=True, rmsnorm=True, tie_weights=False)
-#: tiny models by what decides the row's shape
-FORMS = {
-    # four heads of 32: one lane tile a row
-    "mha": dict(TINY, n_embd=128),
-    # four heads of 64: two
-    "two-tiles": dict(TINY, n_embd=256),
-    # eight query heads over four KV heads of 32, rotated
-    "gqa-rope": dict(TINY, n_head=8, n_kv_head=4, n_embd=256, **ROPE),
-    "window-softcap": dict(TINY, n_embd=128, attention_window=6,
-                           attn_logit_softcap=3.0),
-    "looped": dict(TINY, n_embd=128, n_passes=2, post_norms=True,
-                   exit_gate=True, **ROPE),
-    # what the rule passes by: a width of no whole tiles (XL's 25 x 64 is
-    # 12.5; here 5 x 64 and 4 x 8), one KV head, heads of 128, a latent, a
-    # hybrid stack's rows beside a state
-    "five-heads-of-64": dict(TINY, n_head=5, n_embd=320),
-    "narrow": dict(TINY),
-    "mqa-rope": dict(TINY, n_kv_head=1, **ROPE),
-    "heads-of-128": dict(TINY, n_head=2, n_embd=256),
-    "looped-heads-of-128": dict(TINY, n_head=2, n_embd=256, n_passes=2,
-                                post_norms=True, exit_gate=True, **ROPE),
-    "latent": dict(TINY, rope=True, rope_interleave=True, swiglu=True,
-                   rmsnorm=True, tie_weights=False, kv_lora_rank=16,
-                   qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
-                   n_dense_layers=1, ffn_dim=48, n_experts=8, moe_top_k=2,
-                   moe_ffn_dim=16, n_shared_experts=2, moe_scoring="sigmoid",
-                   moe_route_scale=2.448),
-    "hybrid": dict(model_type="minicpm-sala-tiny"),
-    # the capacity route (mixtral-tiny's keys): softmax-routed experts, each
-    # with room for its share of the tokens
-    "capacity": dict(TINY, n_kv_head=2, n_experts=4, moe_top_k=2, **ROPE),
-}
+#: the forms of a row and their tiny models: ``tests/stacks.py``'s table
+#: (``program_digests.py`` reads ``FORMS``, ``model`` and ``per_head`` here)
+TINY, FORMS, model = stacks.WIDE_TINY, stacks.FORMS, stacks.form_model
+per_head = stacks.per_head
 #: the forms whose heads lie side by side, which this PR moved
 WIDE = ("mha", "two-tiles", "gqa-rope", "window-softcap", "looped")
-
-
-def model(form, **over):
-    cfg = GPTConfig.make(**{**FORMS[form], **over})
-    return cfg, gpt.init(jax.random.key(1), cfg)
-
-
-def per_head(monkeypatch):
-    """The rule as it was: every head an axis entry of its own."""
-    monkeypatch.setattr(gen, "LANE_TILE", 1)
 
 
 # -- the rule -------------------------------------------------------------------
@@ -160,11 +117,12 @@ def test_the_step_over_a_wide_cache_is_the_step_over_a_per_head_one(
                     for k in keys[3:5])
     positions = jnp.array([0, 13, rows - 1])
     side_by_side = lambda a: a.reshape(*a.shape[:-2], 1, kv_heads * size)
-    want = attn_ops.causal_attend_step(
-        q, k_cache, v_cache, 1, k_new, v_new, positions, walk, **case)
-    got = attn_ops.causal_attend_step(
-        q, side_by_side(k_cache), side_by_side(v_cache), 1,
-        side_by_side(k_new), side_by_side(v_new), positions, walk, **case)
+    step = jax.jit(lambda q, k, v, k_new, v_new, at: (
+        attn_ops.causal_attend_step(q, k, v, 1, k_new, v_new, at, walk,
+                                    **case)))
+    want = step(q, k_cache, v_cache, k_new, v_new, positions)
+    got = step(q, side_by_side(k_cache), side_by_side(v_cache),
+               side_by_side(k_new), side_by_side(v_new), positions)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
 
 
@@ -181,25 +139,28 @@ def test_as_heads_is_a_view_of_the_same_numbers_in_the_same_order():
 def cached_run(cfg, params, tokens, cache):
     """A chunk of 9 and of 6 tokens in two lanes, then three decode steps
     with the lanes at different positions and a third lane parked: every
-    step's logits and the cache at the end."""
+    step's logits and the cache at the end. Under a jit of the call's own:
+    the rule and the walk are patches the config does not show."""
     lane = lambda c, s: {n: a[:, s:s + 1] if a.ndim == 5 else a
                          for n, a in c.items()}
+    forward = jax.jit(lambda tokens, cache, offset, **kw: gen._forward_cached(
+        params, tokens, cache, offset, cfg, **kw))
     out = []
     for slot, n in ((0, 9), (1, 6)):
-        logits, one = gen._forward_cached(
-            params, tokens[slot:slot + 1, :n], lane(cache, slot), 0, cfg)
+        logits, one = forward(tokens[slot:slot + 1, :n], lane(cache, slot), 0)
         out.append(logits)
         cache = {name: a if a.ndim != 5 else cache[name].at[:, slot].set(a[:, 0])
                  for name, a in one.items()}
     positions = np.array([9, 6, cfg.block_size - 1])
-    live = jnp.asarray([True, True, False])
+    live = np.asarray([True, True, False])
     for step in range(3):
-        at = jnp.asarray(positions + np.array([step, step, 0]))
-        feed = jnp.stack([tokens[0, 9 + step], tokens[1, 6 + step],
-                          jnp.zeros((), tokens.dtype)])[:, None]
-        logits, cache = gen._forward_cached(
-            params, feed, cache, at, cfg, valid=live[:, None],
-            frontier=engine_mod.decode_frontier(at, live))
+        at = positions + np.array([step, step, 0])
+        feed = np.asarray([tokens[0, 9 + step], tokens[1, 6 + step], 0],
+                          tokens.dtype)[:, None]
+        logits, cache = forward(
+            feed, cache, at, valid=live[:, None],
+            frontier=engine_mod.decode_frontier(jnp.asarray(at),
+                                                jnp.asarray(live)))
         out.append(logits[:2])
     return out, cache
 
@@ -215,7 +176,7 @@ def test_prefill_then_decode_over_wide_rows_is_the_per_head_cache_s(
     if walk:
         walk_in_blocks(8)
     cfg, params = model(form)
-    tokens = jax.random.randint(jax.random.key(2), (2, 13), 0, cfg.vocab_size)
+    tokens = stacks.tokens_of(cfg, 2, 13, seed=2)
     counters = {gen.LOOP_PASSES: gen.init_loop_passes(cfg)} \
         if gen.init_loop_passes(cfg) is not None else {}
     wide = dict(gen.init_cache(cfg, 3), **counters)
@@ -237,7 +198,7 @@ def test_prefill_then_decode_over_wide_rows_is_the_per_head_cache_s(
         for lane, n in ((0, 9), (1, 6)):    # the chunks' rows: the same bits
             np.testing.assert_array_equal(rows[0, lane, :n],
                                           want_cache[name][0, lane, :n])
-    full, _ = gpt.forward(params, tokens, cfg)
+    full, _ = stacks.forward(params, tokens, cfg)
     np.testing.assert_allclose(got[0][0], full[0, 8], atol=2e-5)
     np.testing.assert_allclose(got[1][0], full[1, 5], atol=2e-5)
     for step in range(3):
@@ -245,134 +206,6 @@ def test_prefill_then_decode_over_wide_rows_is_the_per_head_cache_s(
                                    atol=2e-5)
         np.testing.assert_allclose(got[2 + step][1], full[1, 6 + step],
                                    atol=2e-5)
-
-
-# -- through the pool ------------------------------------------------------------
-
-PROMPTS = [[1, 2, 3, 4, 5], list(range(7, 22)), [10, 11, 12, 13],
-           list(range(1, 17)) + [40, 41], list(range(1, 17)) + [20, 21, 22],
-           list(range(1, 17)) + [33]]
-BUDGETS = [9, 4, 7, 5, 6, 3]
-MECHANISMS = {
-    "plain": dict(),
-    "chunked": dict(prefill_chunk=4),
-    "prefix-store": dict(prefix_cache_mb=8.0),
-    "speculation": dict(spec_k=3),
-    "int8": dict(kv_dtype="int8"),
-    "tp2": dict(tp=2),
-    "int8-tp2": dict(kv_dtype="int8", tp=2),
-    "all-together": dict(prefill_chunk=8, prefix_cache_mb=8.0, spec_k=2, tp=2),
-}
-
-
-def served(cfg, params, tp=None, spec_k=None, **options):
-    """The first five prompts through a 3-slot server, admitted while
-    others decode (lanes at different positions, prompts in two buckets),
-    and once they are done the sixth, whose first 16 tokens two of them
-    had: each request's tokens, and the pool's row leaves at the end."""
-    if tp:
-        options["mesh"] = mesh_lib.make_mesh(
-            MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
-    if spec_k:
-        options.update(spec_k=spec_k, draft_cfg=cfg, draft_params=params)
-    server = InferenceServer(params, cfg, n_slots=3,
-                             prefill_buckets=(8, 16, 32), **options)
-    handles = []
-    for prompt, budget in zip(PROMPTS[:5], BUDGETS):
-        handles.append(server.submit(
-            Request(prompt=prompt, max_new_tokens=budget)))
-        server.step()
-    server.run_until_drained(max_steps=400)
-    handles.append(server.submit(
-        Request(prompt=PROMPTS[5], max_new_tokens=BUDGETS[5])))
-    server.run_until_drained(max_steps=100)
-    pool = {n: np.asarray(a) for n, a in server.engine.pool.cache.items()
-            if a.ndim == 5}
-    return [h.tokens for h in handles], pool, server
-
-
-def solo_greedy(params, cfg, prompt, n):
-    out = gen.generate(params, cfg, jnp.asarray(prompt, jnp.int32)[None], n)
-    return np.asarray(out)[0, len(prompt):].tolist()
-
-
-@pytest.mark.parametrize("mechanism", sorted(MECHANISMS))
-@pytest.mark.parametrize("form", ["mha", "two-tiles", "gqa-rope"])
-def test_the_serving_path_over_wide_rows_is_the_per_head_pool_s(
-        form, mechanism, monkeypatch):
-    """Every mechanism that touches the pool, over the wide row: the tokens
-    are the per-head pool's and its leaves the same to float32 rounding (a
-    scale a row and head either way), and an unquantized pool's tokens are
-    solo ``generate``'s."""
-    options = MECHANISMS[mechanism]
-    cfg, params = model(form)
-    got, got_pool, server = served(cfg, params, **options)
-    width = cfg.kv_heads * cfg.head_dim
-    assert got_pool["k"].shape[3:] == (1, width)
-    assert server.metrics.summary()["kv_row_width"] == width
-    if "prefix_cache_mb" in options:
-        assert server.metrics.prefix_hits >= 1
-        for _, entry in server.engine.prefix_store.entries():
-            assert entry["k"].shape[3:] == (1, width)
-    if "spec_k" in options:
-        assert server.metrics.spec_accepted > 0
-    if options.get("tp"):
-        assert server.engine.kv_shard_count == 2
-    per_head(monkeypatch)
-    want, want_pool, _ = served(cfg, params, **options)
-    assert want_pool["k"].shape[3:] == (cfg.kv_heads, cfg.head_dim)
-    assert got == want
-    assert sorted(got_pool) == sorted(want_pool)
-    if "kv_dtype" in options:
-        got_pool, want_pool = (
-            {n: np.asarray(quant_lib.dequantize(p[n], p[n + "_scale"]))
-             for n in ("k", "v")} for p in (got_pool, want_pool))
-    for name, rows in want_pool.items():
-        # 8 bits a number of |x| <= ~4: a step of 1/32 where a rounding flips
-        np.testing.assert_allclose(
-            got_pool[name].reshape(rows.shape), rows, rtol=0,
-            atol=0.04 if "kv_dtype" in options else 5e-6)
-    if "kv_dtype" not in options:
-        for tokens, prompt, budget in zip(got, PROMPTS, BUDGETS):
-            assert tokens == solo_greedy(params, cfg, prompt, budget)
-
-
-@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["fp32", "int8"])
-@pytest.mark.parametrize("form", ["mha", "gqa-rope"])
-def test_migrated_wide_rows_resume_bit_identical(form, kv_dtype):
-    """A slot's rows out of one engine and into a fresh one, in the pool's
-    own row shape (scales beside them): the same decode, the same pools."""
-    cfg, params = model(form)
-    prompt = list(range(5, 21))
-
-    def engine():
-        return DecodeEngine(params, cfg, n_slots=1,
-                            prefill_buckets=(8, 16, 32), kv_dtype=kv_dtype)
-
-    def decode(eng, tok):
-        out = []
-        for i in range(5):
-            tok = int(eng.decode_step(
-                np.asarray([tok], np.int32),
-                np.asarray([len(prompt) + i], np.int32),
-                np.ones(1, np.float32), np.zeros(1, np.int32),
-                np.ones(1, np.float32), np.zeros(1, bool),
-                np.asarray([11], np.uint32), np.asarray([i], np.int32))[0])
-            out.append(tok)
-        return out
-
-    src, dst = engine(), engine()
-    first, _ = src.prefill_chunk_call(0, prompt, 0, 1.0, None, None, False, 7)
-    entry = src.extract_slot_rows(0, 16)
-    width = cfg.kv_heads * cfg.head_dim
-    assert entry["k"].shape == (cfg.n_layer, 1, 16, 1, width)
-    if kv_dtype:
-        assert entry["k_scale"].shape == (cfg.n_layer, 1, 16, 1, cfg.kv_heads)
-    assert dst.install_slot_rows(0, entry) == 16
-    assert decode(src, int(first)) == decode(dst, int(first))
-    for name in sorted(src.pool.cache):
-        np.testing.assert_array_equal(src.pool.cache[name],
-                                      dst.pool.cache[name])
 
 
 # -- an int8 pool's scales: a row and head -----------------------------------------
@@ -592,10 +425,9 @@ SEED = 3_900_000_001
 
 
 @pytest.fixture(scope="module")
-def tiny_cell():
+def gpt2_cell():
     # four heads of 32: the tiny cell's own three make a row of 96
-    cell = rehearse.tiny(spec.load_cell(CELL),
-                         sizes=dict(n_head=4, n_embd=128))
+    cell = stacks.tiny_cell(CELL, n_head=4, n_embd=128)
     return cell, spec.load_reference(cell.config), \
         serve_cell.Driver(cell, SEED, traced=False)
 
@@ -605,8 +437,8 @@ def check_prompts():
     return [rng.integers(0, 384, size=n, dtype=np.int32) for n in (24, 40)]
 
 
-def test_the_check_passes_the_tiny_gpt2_cell_over_wide_rows(tiny_cell):
-    cell, reference, driver = tiny_cell
+def test_the_check_passes_the_tiny_gpt2_cell_over_wide_rows(gpt2_cell):
+    cell, reference, driver = gpt2_cell
     pool = driver.server.engine.pool
     assert pool.cache["k"].shape[3:] == (1, 128)
     verdict = check.serve_verdict(reference, cell.config, driver.server,
@@ -644,8 +476,8 @@ class Swapping:
 
 
 def test_the_check_fails_a_pool_whose_heads_are_swapped_inside_the_row(
-        tiny_cell):
-    cell, reference, driver = tiny_cell
+        gpt2_cell):
+    cell, reference, driver = gpt2_cell
     bad = check.serve_verdict(
         reference, cell.config,
         types.SimpleNamespace(engine=Swapping(driver.server.engine, 32)),
@@ -690,10 +522,10 @@ def test_summary_says_which_way_the_pool_keeps_a_row(form, width, tiles):
     assert f"mingpt_serve_kv_row_width {width}" in page.replace(".0", "")
 
 
-def test_the_counters_of_a_run_carry_the_two_gauges(tiny_cell):
+def test_the_counters_of_a_run_carry_the_two_gauges(gpt2_cell):
     """``serve_cell.Driver._counters`` takes every numeric field of
     ``summary()``: the gauges reach a run's counters with no edit."""
-    _, _, driver = tiny_cell
+    _, _, driver = gpt2_cell
     counters = driver._counters()
     assert counters["kv_row_width"] == 128
     assert counters["kv_row_tiles"] == 2 * 1

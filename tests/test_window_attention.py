@@ -16,6 +16,7 @@ from mingpt_distributed_tpu.models import generate as gen
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.ops import flash_attention as flash
+from oracles import dense_greedy
 
 
 def qkv(b=2, t=128, h=2, hd=16, seed=0):
@@ -130,12 +131,7 @@ def test_model_forward_and_cached_decode_agree_with_window():
     )
     params = gpt.init(jax.random.key(0), cfg)
     prompt = jax.random.randint(jax.random.key(1), (2, 12), 0, 50)
-
-    idx = jnp.asarray(prompt)
-    for _ in range(10):
-        logits, _ = gpt.forward(params, idx[:, -cfg.block_size:], cfg)
-        idx = jnp.concatenate(
-            [idx, jnp.argmax(logits[:, -1], axis=-1)[:, None]], axis=1)
+    idx = dense_greedy(params, cfg, prompt, 10)
     got = gen.generate(params, cfg, prompt, 10)
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(got))
 
