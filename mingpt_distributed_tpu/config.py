@@ -191,7 +191,10 @@ class GPTConfig:
     # bfloat16 is made in bfloat16 and lives on the device once.
     param_dtype: str = "float32"
     # Rematerialise each block in backward (jax.checkpoint) to trade FLOPs
-    # for HBM.
+    # for HBM. A layer keeps its input and the two values only the flash
+    # kernel can make again, the attention's output and log-sum-exp
+    # (gpt._remat): B x T x D in the compute dtype and B x H x T float32 a
+    # layer over the input alone, for a backward without the forward kernel.
     remat: bool = False
     # GPipe microbatch count when the mesh has pp > 1 stages; 0 = one
     # microbatch per stage. Bubble fraction is (pp-1)/(M+pp-1), so raise M

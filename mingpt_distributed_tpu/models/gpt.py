@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from mingpt_distributed_tpu.config import (
     FULL_ATTN, LIGHTNING, SPARSE, WINDOW_ATTN, GPTConfig)
 from mingpt_distributed_tpu.ops import attention as attn_ops
+from mingpt_distributed_tpu.ops import flash_attention
 from mingpt_distributed_tpu.ops import lightning as lightning_ops
 from mingpt_distributed_tpu.ops import sparse_attention as sparse_ops
 from mingpt_distributed_tpu.ops import layers as L
@@ -264,10 +265,12 @@ def _attention_dispatch(cfg: GPTConfig, mesh=None):
     if cfg.attention == "einsum":
         return attn_ops.causal_attention
     if cfg.attention == "flash":
-        from mingpt_distributed_tpu.ops import flash_attention
-
+        # under remat the layer's checkpoint keeps the forward's pair
+        # (_remat), so the forward rule has to hand it on under its names
+        attend = functools.partial(flash_attention.causal_attention,
+                                   keep_pair=cfg.remat)
         if mesh is None:
-            return flash_attention.causal_attention
+            return attend
 
         # A compiled Pallas kernel is a Mosaic custom call, and a custom
         # call has no partitioning rule: left to GSPMD, q/k/v are gathered
@@ -292,11 +295,11 @@ def _attention_dispatch(cfg: GPTConfig, mesh=None):
                 # no kernel under attention dropout: the op routes this
                 # call to the einsum oracle, which is plain HLO — GSPMD
                 # partitions it and draws the masks per global row
-                return flash_attention.causal_attention(
+                return attend(
                     q, k, v, attn_pdrop=attn_pdrop, dropout_key=dropout_key,
                     deterministic=False, **kw)
             return jax.shard_map(
-                lambda q, k, v: flash_attention.causal_attention(q, k, v, **kw),
+                lambda q, k, v: attend(q, k, v, **kw),
                 mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                 check_vma=False,
             )(q, k, v)
@@ -737,6 +740,19 @@ def _hybrid_block(x, blk: Params, cfg: GPTConfig, kind: str) -> jax.Array:
     return hybrid_mlp(x, mixed, blk, cfg)
 
 
+def _remat(fn):
+    """``fn`` under ``cfg.remat``: its backward runs the layer's forward
+    again, but for the two values the flash forward names (its output and
+    its log-sum-exp: ``_attention_dispatch`` asks it to, ``keep_pair``),
+    which are kept: q, k and v come back from one matmul each, and that
+    pair only from the kernel, which so runs once a step. A body that names
+    nothing (the einsum attention, a hybrid layer, a ring) keeps nothing
+    but its inputs."""
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(
+            flash_attention.SAVED_OUT, flash_attention.SAVED_LSE))
+
+
 def _block(
     x: jax.Array,
     blk: Params,
@@ -904,7 +920,7 @@ def forward(
         for layer in range(cfg.n_layer):
             kind, blk, _ = hybrid_layer_params(params, cfg, layer)
             step = functools.partial(_hybrid_block, cfg=cfg, kind=kind)
-            x = (jax.checkpoint(step) if cfg.remat else step)(x, blk)
+            x = (_remat(step) if cfg.remat else step)(x, blk)
         return _head_and_loss(params, x, cfg, targets, return_logits,
                               jnp.zeros((), jnp.float32), mesh=mesh)
 
@@ -953,7 +969,7 @@ def forward(
         xs = (params["blocks"], layer_keys[n_dense:])
         xs_dense = (params.get("dense_blocks"), layer_keys[:n_dense])
 
-    step = jax.checkpoint(body) if cfg.remat else body
+    step = _remat(body) if cfg.remat else body
 
     def run_stack(carry, xs, n):
         """``n`` stacked layers of one kind over the carry."""
@@ -1114,7 +1130,7 @@ def forward(
                             key, jax.lax.axis_index("sp")
                         )
                     return run(carry, blk, key), None
-            step_pp = jax.checkpoint(body_pp) if cfg.remat else body_pp
+            step_pp = _remat(body_pp) if cfg.remat else body_pp
             (y, aux), _ = jax.lax.scan(
                 step_pp, (x_mb, jnp.zeros((), jnp.float32)), xs_local,
                 unroll=cfg.scan_unroll,

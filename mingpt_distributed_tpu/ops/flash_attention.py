@@ -56,6 +56,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -72,6 +73,38 @@ NEG_INF = -1e30
 # cotangent contract are unchanged. exp2(x * LOG2E) == exp(x).
 LOG2E = 1.4426950408889634
 INV_LOG2E = 1.0 / LOG2E
+
+#: The names ``causal_attention``'s forward rules put on the two values only
+#: the kernel can make again, its output and its log-sum-exp, where the call
+#: sits in a layer under ``jax.checkpoint`` (``keep_pair``). The policy of
+#: models/gpt.py saves them (what ``GPTConfig.remat`` means): the backward
+#: makes q, k and v again by their matmuls and hands the backward kernel
+#: this pair as the forward left it, so the forward kernel runs once a step
+#: and not twice.
+SAVED_OUT = "flash_out"
+SAVED_LSE = "flash_lse"
+
+
+def _kept(out, lse):
+    """The forward's pair as a checkpoint keeps it. What a rule returns as
+    the primal output and keeps as residuals is this one value, so the value
+    a policy saves is the value the backward rule reads (``_as_written``
+    puts the axis back). The log-sum-exp is kept without the kernels'
+    trailing 1, which the device pads to 128 lanes: kept as the kernel
+    wrote it, XL's 48 layers would hold 5.2 GB a chip for 0.04 GB of
+    numbers. The barrier ties that dense copy to the output the layer goes
+    on with: left free, the TPU scheduler puts it after the last layer's
+    forward and every padded buffer lives until then (XL's step planned at
+    15.73 GB so, against 10.43 at the parent and 11.80 tied: compile
+    rehearsals, PR 64). The two copies cost a pass over the padded buffer
+    each (0.28 ms a copy at the 124M cell's 201 MB: my chip run, PR 64),
+    which is why a step without a checkpoint does not make them."""
+    out, lse = jax.lax.optimization_barrier((out, lse[..., 0]))
+    return checkpoint_name(out, SAVED_OUT), checkpoint_name(lse, SAVED_LSE)
+
+
+def _as_written(lse, keep_pair):
+    return lse[..., None] if keep_pair else lse
 
 
 def _scores_base2(q, kblk, scale, softcap):
@@ -601,21 +634,24 @@ def _flash_bwd(q, k, v, out, lse, do, scale, block, causal=True, dlse=None,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, scale: float, block: int, window=None, softcap=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, scale: float, block: int, window=None, softcap=None,
+           keep_pair: bool = False):
     out, _ = _flash_fwd(q, k, v, scale, block, window=window, softcap=softcap)
     return out
 
 
-def _flash_fwd_rule(q, k, v, scale, block, window, softcap):
+def _flash_fwd_rule(q, k, v, scale, block, window, softcap, keep_pair):
     out, lse = _flash_fwd(q, k, v, scale, block, window=window, softcap=softcap)
+    if keep_pair:
+        out, lse = _kept(out, lse)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(scale, block, window, softcap, res, do):
+def _flash_bwd_rule(scale, block, window, softcap, keep_pair, res, do):
     q, k, v, out, lse = res
-    dq, dk, dv = _flash_bwd(q, k, v, out, lse, do, scale, block,
-                            window=window, softcap=softcap)
+    dq, dk, dv = _flash_bwd(q, k, v, out, _as_written(lse, keep_pair), do,
+                            scale, block, window=window, softcap=softcap)
     return dq, dk, dv
 
 
@@ -639,6 +675,10 @@ def flash_with_lse(q, k, v, scale: float, block: int, causal: bool = True,
     cross-chunk hops). Rows left with no live key under an offset band
     return garbage ``out`` and lse ~= NEG_INF — callers MUST merge by lse
     (the weight underflows to exactly 0), not read ``out`` directly.
+
+    Its pair carries no checkpoint name (SAVED_OUT, SAVED_LSE): a ring would
+    save one a hop, no cell runs one and nothing has measured it, so under
+    ``remat`` a ring's hops run again in the backward.
     """
     return _flash_fwd(q, k, v, scale, block, causal, window=window,
                       softcap=softcap, q_offset=q_offset)
@@ -1404,22 +1444,24 @@ def _native_backward(q, k, v, out, lse, do, h, scale, block, window,
                           window=window, softcap=softcap)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_btd(q, k, v, h: int, scale: float, block: int, window=None,
-               softcap=None):
+               softcap=None, keep_pair: bool = False):
     out, _ = _native_forward(q, k, v, h, scale, block, window, softcap)
     return out
 
 
-def _flash_btd_fwd_rule(q, k, v, h, scale, block, window, softcap):
+def _flash_btd_fwd_rule(q, k, v, h, scale, block, window, softcap, keep_pair):
     out, lse = _native_forward(q, k, v, h, scale, block, window, softcap)
+    if keep_pair:
+        out, lse = _kept(out, lse)
     return out, (q, k, v, out, lse)
 
 
-def _flash_btd_bwd_rule(h, scale, block, window, softcap, res, do):
+def _flash_btd_bwd_rule(h, scale, block, window, softcap, keep_pair, res, do):
     q, k, v, out, lse = res
-    return _native_backward(q, k, v, out, lse, do, h, scale, block, window,
-                            softcap)
+    return _native_backward(q, k, v, out, _as_written(lse, keep_pair), do, h,
+                            scale, block, window, softcap)
 
 
 _flash_btd.defvjp(_flash_btd_fwd_rule, _flash_btd_bwd_rule)
@@ -1436,6 +1478,7 @@ def causal_attention(
     kv_offset: int | jax.Array = 0,
     window: Optional[int] = None,
     logit_softcap: Optional[float] = None,
+    keep_pair: bool = False,
 ) -> jax.Array:
     """Drop-in for ops.attention.causal_attention, flash-accelerated.
 
@@ -1445,6 +1488,10 @@ def causal_attention(
     tested for parity against it. ``window`` enables sliding-window
     (banded) attention — the kernel skips and never fetches blocks outside
     the band, so compute scales with T*window instead of T^2.
+    ``keep_pair`` says the call sits in a layer under ``jax.checkpoint``
+    (models/gpt.py sets it from ``cfg.remat``): the forward rule then names
+    its output and log-sum-exp (SAVED_OUT, SAVED_LSE; ``_kept``) for the
+    checkpoint's policy to save. The fallback names nothing.
     """
     b, t, h, hd = q.shape
     s = k.shape[1]
@@ -1488,7 +1535,8 @@ def causal_attention(
         if _btd_pack(h, hd) is not None:
             out2 = _flash_btd(
                 q.reshape(b, t, h * hd), k.reshape(b, t, h * hd),
-                v.reshape(b, t, h * hd), h, scale, block, win, cap)
+                v.reshape(b, t, h * hd), h, scale, block, win, cap,
+                keep_pair)
             return out2.reshape(b, t, h, hd)
         else:
             # Odd head counts (gpt2-xl's 25) can't pair sub-heads evenly;
@@ -1505,9 +1553,10 @@ def causal_attention(
                 jnp.concatenate([q.reshape(b, t, h * hd), zpad], axis=-1),
                 jnp.concatenate([k.reshape(b, t, h * hd), zpad], axis=-1),
                 jnp.concatenate([v.reshape(b, t, h * hd), zpad], axis=-1),
-                hp, scale, block, win, cap)
+                hp, scale, block, win, cap, keep_pair)
             return out2[..., :h * hd].reshape(b, t, h, hd)
     # (B, T, H, hd) -> (B*H, T, hd)
     to_bh = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, hd)
-    out = _flash(to_bh(q), to_bh(k), to_bh(v), scale, block, win, cap)
+    out = _flash(to_bh(q), to_bh(k), to_bh(v), scale, block, win, cap,
+                 keep_pair)
     return out.reshape(b, h, t, hd).transpose(0, 2, 1, 3)

@@ -11,7 +11,8 @@ from mingpt_distributed_tpu.config import GPTConfig
 from mingpt_distributed_tpu.models import gpt
 from mingpt_distributed_tpu.ops import attention as attn_ops
 from mingpt_distributed_tpu.ops import flash_attention as flash
-from program_digests import kernel_matmuls, kernels_digest, pallas_calls
+from program_digests import (_equations, kernel_matmuls, kernels_digest,
+                             pallas_calls)
 
 
 def qkv(b=2, t=128, h=4, kv=None, hd=32, seed=0, dtype=jnp.float32):
@@ -395,6 +396,66 @@ def test_backward_is_chosen_from_the_shape(shape, fused, monkeypatch):
     calls = [len(pallas_calls(jaxpr, n)) for n in (
         "flash_fwd", "flash_bwd_fused", "flash_bwd_dq", "flash_bwd_dkv")]
     assert calls == ([1, 1, 0, 0] if fused else [1, 0, 1, 1])
+
+
+# --- the forward's pair kept through a checkpoint (PR 64) --------------------
+
+
+@pytest.mark.parametrize("layout", ["native", "bh"])
+@pytest.mark.parametrize("n_head", [2, 3])     # 3: zero heads pad the pair
+@pytest.mark.parametrize("unroll", [False, True])
+@pytest.mark.parametrize("remat", [False, True])
+def test_under_remat_the_forward_kernel_runs_once_a_layer(
+        remat, unroll, n_head, layout, monkeypatch):
+    """A layer under ``remat`` is computed again in the backward but for
+    the two values the forward rules name (SAVED_OUT, SAVED_LSE), which
+    ``gpt._remat``'s policy keeps: the gradient holds ``flash_fwd`` once a
+    layer, where a bare ``jax.checkpoint`` held it twice, and the backward
+    kernel once a layer, as the plain step does. A scanned stack holds a
+    layer's calls once (the forward's scan and the backward's), an unrolled
+    one once a layer. Traced, never run."""
+    monkeypatch.delenv("FLASH_BLOCK", raising=False)
+    monkeypatch.setenv("FLASH_LAYOUT", "bh" if layout == "bh" else "auto")
+    layers = 2
+    cfg = GPTConfig.make(
+        n_layer=layers, n_head=n_head, n_embd=64 * n_head, vocab_size=65,
+        block_size=128, dtype="float32", attention="flash", remat=remat,
+        unroll_layers=unroll, embd_pdrop=0.0, resid_pdrop=0.0,
+        attn_pdrop=0.0)
+    params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
+    tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, tok: gpt.forward(p, tok, cfg, targets=tok)[1]))(
+            params, tokens)
+    backward = {"native": ["flash_bwd_fused"],
+                "bh": ["flash_bwd_dq", "flash_bwd_dkv"]}[layout]
+    calls = [len(pallas_calls(jaxpr, n)) for n in ["flash_fwd"] + backward]
+    once = layers if unroll else 1
+    assert calls == [once] * len(calls)
+    # the pair is named (and its log-sum-exp laid out densely) only where a
+    # checkpoint is there to keep it: the plain step is the parent's
+    names = [str(e.params["name"]) for e in _equations(jaxpr.jaxpr)
+             if e.primitive.name == "name"]
+    assert sorted(names) == sorted(
+        [flash.SAVED_OUT, flash.SAVED_LSE] * once if remat else [])
+
+
+@pytest.mark.parametrize("n_head,layout", [(2, "native"), (3, "native"),
+                                           (2, "bh")])
+def test_the_kept_pair_changes_no_number(n_head, layout, monkeypatch):
+    """``keep_pair`` hands the backward rule the log-sum-exp without its
+    trailing 1 and tied to the output; the axis goes back on before the
+    kernel: output and gradients are the plain call's bit for bit."""
+    monkeypatch.delenv("FLASH_BLOCK", raising=False)
+    monkeypatch.setenv("FLASH_LAYOUT", "bh" if layout == "bh" else "auto")
+    q, k, v = qkv(b=1, t=256, h=n_head, hd=64, seed=11)
+
+    def run(keep):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(jnp.square(flash.causal_attention(
+                q, k, v, keep_pair=keep))), argnums=(0, 1, 2))(q, k, v)
+
+    jax.tree.map(np.testing.assert_array_equal, run(False), run(True))
 
 
 # --- the staircase of a diagonal cell (PR 54) --------------------------------

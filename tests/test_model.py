@@ -76,11 +76,23 @@ def test_dropout_train_vs_eval():
     np.testing.assert_array_equal(le, le2)  # eval is deterministic
 
 
-def test_remat_matches_plain():
-    cfg = small_cfg()
-    cfg_r = small_cfg(remat=True)
+@pytest.mark.parametrize("attention,t,unroll", [
+    ("einsum", 16, False),
+    ("flash", 128, False),   # a tileable T: the kernels run (interpret mode)
+    ("flash", 128, True),
+])
+def test_remat_matches_plain(attention, t, unroll):
+    """``remat`` changes what a step keeps, not what it computes. Under the
+    flash kernels the saved pair (SAVED_OUT, SAVED_LSE) is what the forward
+    made and everything else is made again by the same equations on the
+    same inputs: layer by layer (``unroll_layers``) the loss and every
+    gradient leaf are the plain step's bit for bit. A scanned stack's
+    backward body is one program that XLA fuses anew around the
+    recomputation, so there the gradients agree to rounding."""
+    kw = dict(attention=attention, block_size=t, unroll_layers=unroll)
+    cfg, cfg_r = small_cfg(**kw), small_cfg(remat=True, **kw)
     params = gpt.init(jax.random.key(0), cfg)
-    tokens = jax.random.randint(jax.random.key(1), (2, 16), 0, 65)
+    tokens = jax.random.randint(jax.random.key(1), (2, t), 0, 65)
 
     def loss_of(c):
         def f(p):
@@ -89,10 +101,40 @@ def test_remat_matches_plain():
 
     l0, g0 = jax.value_and_grad(loss_of(cfg))(params)
     l1, g1 = jax.value_and_grad(loss_of(cfg_r))(params)
+    if unroll:
+        assert float(l0) == float(l1)
+        jax.tree.map(np.testing.assert_array_equal, g0, g1)
+        return
     np.testing.assert_allclose(float(l0), float(l1), rtol=1e-6)
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6), g0, g1
     )
+
+
+@pytest.mark.parametrize("unroll,remat,want", [
+    (False, False, (27, 2, 422)),
+    (False, True, (34, 3, 534)),
+    (True, False, (51, 3, 863)),
+    (True, True, (65, 5, 1061)),
+])
+def test_remat_without_a_named_value_recomputes_the_whole_layer(
+        unroll, remat, want):
+    """The rule's other half: ``gpt._remat``'s policy saves two names, and a
+    body that holds neither (the einsum attention) keeps nothing but its
+    inputs. Its gradient's ``dot_general``s, ``exp``s and equations in all
+    are PR 64's parent's, which wrapped the layer in a bare
+    ``jax.checkpoint``: a PR that changes a layer's equations counts them
+    again, and the ``remat`` rows move with the plain ones."""
+    cfg = small_cfg(attention="einsum", remat=remat, unroll_layers=unroll)
+    params = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, tok: gpt.forward(p, tok, cfg, targets=tok)[1]))(
+            params, tokens)
+    names = [e.primitive.name for e in _equations(jaxpr.jaxpr)]
+    assert "name" not in names
+    assert (names.count("dot_general"), names.count("exp"),
+            len(names)) == want
 
 
 def test_llama_mode_forward_and_causality():
